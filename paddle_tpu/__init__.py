@@ -18,7 +18,6 @@ Layer map (mirrors SURVEY.md §1, TPU-first):
 
 __version__ = "0.1.0"
 
-from . import jax_compat  # noqa: F401  (must precede any jax.shard_map use)
 from . import fluid  # noqa: F401
 from . import reader  # noqa: F401
 from . import dataset  # noqa: F401

@@ -449,16 +449,12 @@ def drain_requested() -> bool:
 def reinit_collective(coordinator_address=None, num_processes=None,
                       process_id=None):
     """Re-run the `jax.distributed` bootstrap after a membership change in
-    the collective lane (a preempted host rejoining, or the job resized).
-    Tears down an existing initialization when the running jax exposes
-    `shutdown`/`is_initialized` (the compat shim's concern: older
-    releases lack both — there a pre-initialized runtime raises, which is
-    surfaced rather than swallowed).  Defaults come from the launcher env
-    contract (PADDLE_TRAINER_ENDPOINTS / PADDLE_TRAINERS_NUM /
-    PADDLE_TRAINER_ID), exactly what fleet.init reads."""
+    the collective lane (a preempted host rejoining, or the job resized),
+    tearing down an existing initialization first.  Defaults come from
+    the launcher env contract (PADDLE_TRAINER_ENDPOINTS /
+    PADDLE_TRAINERS_NUM / PADDLE_TRAINER_ID), exactly what fleet.init
+    reads."""
     import jax
-
-    from paddle_tpu import jax_compat
 
     if coordinator_address is None:
         eps = [e for e in os.environ.get(
@@ -470,7 +466,9 @@ def reinit_collective(coordinator_address=None, num_processes=None,
         process_id = int(os.environ.get("PADDLE_TRAINER_ID", "0") or 0)
     if coordinator_address is None or num_processes <= 1:
         return False  # single-process job: nothing to re-form
-    jax_compat.distributed_reinit(
+    if jax.distributed.is_initialized():
+        jax.distributed.shutdown()
+    jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes), process_id=int(process_id))
     from paddle_tpu.observability import events

@@ -9,15 +9,20 @@ list through env vars:
 
 (the contract `fleet.init(PaddleCloudRoleMaker(is_collective=True))`
 reads; multi-host jax.distributed coordination derives from the same
-endpoints).  TPU differences from the reference: a process drives a
-chip, not a CUDA card — `--nproc_per_node` names the count directly
-(`--selected_gpus` is accepted as an alias for script parity) — and
-failure of any local rank tears the whole node's group down instead of
-leaking survivors.
+endpoints).  TPU differences from the reference: ONE process drives
+every chip of its host (`with_data_parallel` / the mesh runners span
+them), so `--nproc_per_node` defaults to 1; name a larger count
+explicitly (`--selected_gpus` is accepted as an alias for script parity)
+for CPU-mesh ranks.  Failure of any local rank tears the whole node's
+group down instead of leaking survivors.
+
+A chip belongs to one process at a time, so this parent never
+initializes a JAX backend — it does not count devices, it only spawns
+and supervises (tests/test_toplevel_parity.py runs it with a backend
+tripwire).
 
 Usage:
-    python -m paddle_tpu.distributed.launch --nproc_per_node=4 \
-        train.py --your-args
+    python -m paddle_tpu.distributed.launch train.py --your-args
 """
 
 from __future__ import annotations
@@ -41,8 +46,8 @@ def _parse_args(argv=None):
     parser.add_argument("--started_port", type=int, default=6170,
                         help="first endpoint port on each node")
     parser.add_argument("--nproc_per_node", type=int, default=None,
-                        help="processes (devices) per node; default = "
-                             "local device count")
+                        help="processes per node; default 1 — one "
+                             "process drives all of its host's chips")
     parser.add_argument("--selected_gpus", type=str, default=None,
                         help="reference-script alias: its length sets "
                              "nproc_per_node, values export "
@@ -61,22 +66,12 @@ def _parse_args(argv=None):
     return parser.parse_args(argv)
 
 
-def _local_device_count():
-    try:
-        from paddle_tpu.fluid import core
-
-        return max(1, core.get_tpu_device_count())
-    except Exception:
-        return 1
-
-
 def start_procs(args):
     node_ips = [ip.strip() for ip in args.cluster_node_ips.split(",") if ip]
     node_id = node_ips.index(args.node_ip)
     selected = ([g.strip() for g in args.selected_gpus.split(",")]
                 if args.selected_gpus else None)
-    nproc = (args.nproc_per_node or (len(selected) if selected else None)
-             or _local_device_count())
+    nproc = args.nproc_per_node or (len(selected) if selected else 1)
     if selected and len(selected) < nproc:
         raise ValueError(
             f"--selected_gpus names {len(selected)} devices but "

@@ -21,8 +21,7 @@ Scope and caveats:
   the mesh runners keep the warm-cache story.
 - the payload embeds a machine-compiled executable: the key includes
   backend platform, device kind and the jaxlib version, and the cache
-  dir must not be shared across heterogeneous hosts (the same contract
-  as the fingerprinted FLAGS_compile_cache_dir default).
+  dir must not be shared across heterogeneous hosts.
 - every failure path (toolchain without the API, stale/corrupt file,
   cross-version payload) warns once and falls back to the normal
   compile path — a broken cache dir must never stop a run.
@@ -116,33 +115,21 @@ def program_fingerprint(program):
 # kernel-implementation override envs: these select WHAT gets lowered
 # for the same program (Pallas vs XLA reference paths), so a serialized
 # executable is only valid under the same settings — a key without them
-# would silently serve a Pallas-path executable to a PT_PAGED_NO_PALLAS
-# debug run (or the inverse in production)
-_IMPL_ENVS = ("PT_PAGED_NO_PALLAS", "PT_FLASH_FORCE_PALLAS",
-              "PT_FLASH_NO_PALLAS", "PT_FUSED_UPDATE_IMPL",
-              "PT_FUSED_BIAS_ACT_IMPL", "PT_RNG_IMPL")
+# would silently serve a Pallas-path executable to a
+# PT_FUSED_UPDATE_IMPL=xla debug run (or the inverse in production)
+_IMPL_ENVS = ("PT_FLASH_FORCE_PALLAS", "PT_FUSED_UPDATE_IMPL",
+              "PT_RNG_IMPL")
 
 
 def _platform_tag():
     import jax
+    import jaxlib
 
     from .platform_utils import default_platform
 
-    try:
-        import jaxlib
-
-        jl = getattr(jaxlib, "__version__", "?")
-    except Exception:  # pragma: no cover
-        jl = "?"
-    plat = default_platform() or "?"
-    kind = ""
-    try:
-        devs = jax.devices()
-        kind = devs[0].device_kind if devs else ""
-    except Exception:  # pragma: no cover - backend init failure
-        pass
     impls = ",".join(f"{e}={os.environ.get(e, '')}" for e in _IMPL_ENVS)
-    return f"{plat}|{kind}|jax{jax.__version__}|jaxlib{jl}|{impls}"
+    return (f"{default_platform()}|{jax.devices()[0].device_kind}|"
+            f"jax{jax.__version__}|jaxlib{jaxlib.__version__}|{impls}")
 
 
 def executable_key(program, arg_specs, fetch_names):

@@ -150,3 +150,14 @@ class CompiledProgram:
                     "step once first")
         return executor.cost_analysis(self._program, feed,
                                       fetch_list=fetch_list, scope=scope)
+
+    def lower(self, executor, feed, fetch_list=None, scope=None):
+        """AOT-lower the step :meth:`_run` dispatches for this feed
+        (``.as_text()`` / ``.compile()`` the result): the data-parallel
+        runner's sharded step when one was built, else the plain
+        executor's — the same routing as :meth:`cost_analysis`."""
+        if self._dp_runner is not None:
+            return self._dp_runner.lower(executor, feed,
+                                         fetch_list=fetch_list, scope=scope)
+        return executor.lower(self._program, feed, fetch_list=fetch_list,
+                              scope=scope)

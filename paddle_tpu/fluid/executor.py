@@ -639,97 +639,49 @@ class BlockPlan:
 
 
 _cache_dir_last = object()  # sentinel: not yet applied
+_cache_listener_on = False
 
 
-def _purge_prefingerprint_cache(cache_dir):
-    """Delete loose cache entries left in the parent xla_cache/ dir by
-    versions that predated per-host-CPU fingerprinting: XLA:CPU AOT
-    artifacts baked for another machine make the loader warn (and can
-    SIGILL) on every run that touches them."""
-    import os as _os
-
-    parent = _os.path.dirname(cache_dir)
-    if _os.path.basename(parent) != "xla_cache":
-        return  # custom cache dir: nothing to migrate
-    try:
-        for name in _os.listdir(parent):
-            path = _os.path.join(parent, name)
-            if (name.endswith(("-cache", "-atime"))
-                    and _os.path.isfile(path)):
-                _os.unlink(path)
-    except OSError:
-        pass
+def _book_persistent_cache(event, **_kw):
+    """jax.monitoring listener: jax's own persistent-cache hit/miss
+    events land on pt_compile_cache_total{path="xla_persistent"} — how a
+    second process reports that its compiles came off disk."""
+    result = {"/jax/compilation_cache/cache_hits": "hit",
+              "/jax/compilation_cache/cache_misses": "miss"}.get(event)
+    if result:
+        _m_cache().labels(path="xla_persistent", result=result).inc()
 
 
 def _apply_compile_cache():
-    """Point jax at a persistent on-disk compilation cache
-    (FLAGS_compile_cache_dir; SURVEY §7 hard part 6) so re-runs of the same
-    program skip the 20-40s first XLA compile.  Applied lazily before each
-    compile and re-applied when the flag changes — never fatal (a broken
-    cache dir must not stop a run)."""
-    global _cache_dir_last
+    """Point jax at a persistent on-disk compilation cache so re-runs of
+    the same program skip the first XLA compile (SURVEY §7 hard part 6).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside and jax reads it itself: nothing is set here.  Otherwise
+    FLAGS_compile_cache_dir (default ``<checkout>/.jax_cache``; "" =
+    off) is applied lazily before each compile and re-applied when the
+    flag changes.  The path is part of jax's cache key, so it is a fixed
+    one — no host fingerprint, pid or time in it."""
+    global _cache_dir_last, _cache_listener_on
+    import jax
+
+    if not _cache_listener_on:
+        jax.monitoring.register_event_listener(_book_persistent_cache)
+        _cache_listener_on = True
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     from . import flags as _flags
 
     cache_dir = _flags.flag("compile_cache_dir")
     if cache_dir == _cache_dir_last:
         return
     _cache_dir_last = cache_dir
-    try:
-        import jax
-
-        if not cache_dir:
-            jax.config.update("jax_compilation_cache_dir", None)
-            return
-        import os as _os
-
-        _os.makedirs(cache_dir, exist_ok=True)
-        _purge_prefingerprint_cache(cache_dir)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache everything that took meaningful compile time
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as e:  # pragma: no cover - environment-specific
-        import warnings
-
-        warnings.warn(f"persistent compile cache disabled: {e}")
-
-
-# serializes _persistent_cache_optout users: the jax compilation-cache
-# switch is process-global, so an unlocked flip-and-restore from two
-# threads (serving warmup vs the decode scheduler's first dispatch)
-# could restore the cache to ON mid-way through a stamped program's
-# compile — re-exposing exactly the brittle deserialize the stamp
-# exists to avoid
-_cache_optout_lock = threading.RLock()
-
-
-@contextlib.contextmanager
-def _persistent_cache_optout(program, first_dispatch):
-    """Disable the jax compilation cache around a compile of a program
-    stamped `_no_persistent_compile_cache` on platforms where
-    DESERIALIZING such a program's cache entry corrupts the heap
-    (platform_utils.persistent_cache_deserialize_brittle — the
-    jaxlib-0.4.3x XLA:CPU line vs the decode lane's paged
-    gather/scatter programs).  No-op after the block's first dispatch
-    (the executable is resident; the cache is only consulted at
-    compile time) and everywhere the deserialize path is healthy."""
-    if not first_dispatch or not getattr(
-            program, "_no_persistent_compile_cache", False):
-        yield
+    if not cache_dir:
+        jax.config.update("jax_compilation_cache_dir", None)
         return
-    from .platform_utils import persistent_cache_deserialize_brittle
-
-    if not persistent_cache_deserialize_brittle():
-        yield
-        return
-    import jax
-
-    with _cache_optout_lock:
-        prev = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_compilation_cache", prev)
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache everything that took meaningful compile time
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 class _FeedScopeView:
@@ -774,13 +726,17 @@ class _JitExecutable:
     the FLAGS_check_nan_inf scan.  Subclasses provide `plan`, `label`,
     `_jitted`, `donated_names`, `readonly_names`."""
 
-    def _jit_args(self, scope, feeds, step):
+    def _jit_args(self, scope, feeds, step, shardings=(None, None)):
         """The (donated, readonly, feeds, step) pytrees run() passes to the
         jitted body, as abstract ShapeDtypeStructs — enough for AOT
-        lowering without touching device memory."""
+        lowering without touching device memory.  ``shardings`` =
+        (state, feed) places the scope-resident arrays and the feeds
+        explicitly (see :meth:`lower`); None leaves placement to jit."""
         import jax
 
-        def spec(n, v):
+        state_s, feed_s = shardings
+
+        def spec(n, v, sharding):
             if v is None:
                 # same guard as run(): name the variable instead of letting
                 # np.asarray(None) produce an opaque object-dtype error
@@ -788,13 +744,25 @@ class _JitExecutable:
                     f"variable {n!r} is read by this program but absent "
                     "from the current scope")
             a = np.asarray(v) if not hasattr(v, "dtype") else v
-            return jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                        sharding=sharding)
 
-        donated = {n: spec(n, scope.get(n)) for n in self.donated_names}
-        readonly = {n: spec(n, scope.get(n)) for n in self.readonly_names}
-        feed_vals = {k: spec(k, v) for k, v in feeds.items()}
+        donated = {n: spec(n, scope.get(n), state_s)
+                   for n in self.donated_names}
+        readonly = {n: spec(n, scope.get(n), state_s)
+                    for n in self.readonly_names}
+        feed_vals = {k: spec(k, v, feed_s) for k, v in feeds.items()}
         return donated, readonly, feed_vals, jax.ShapeDtypeStruct(
-            (), np.uint32)
+            (), np.uint32, sharding=state_s)
+
+    def lower(self, scope, feeds, shardings=(None, None)):
+        """AOT-lower this step (``.compile()`` the result) with every
+        argument placed by ``shardings`` = (state, feed).  Shardings
+        over ``jax.experimental.topologies`` devices, inside
+        ``platform_utils.lowering_for("tpu")``, compile the step for a
+        TPU from a host that has none (tests/test_mosaic_aot.py)."""
+        return self._jitted.lower(
+            *self._jit_args(scope, feeds, 0, shardings))
 
     def cost_analysis(self, scope, feeds, step=0):
         """XLA's per-executable cost model for this step: flops, bytes
@@ -803,7 +771,7 @@ class _JitExecutable:
         future run; the executable cache makes this free after a warmup.
         TPU analog of the reference's per-op profiler tables
         (platform/profiler.cc) at whole-program granularity."""
-        lowered = self._jitted.lower(*self._jit_args(scope, feeds, step))
+        lowered = self.lower(scope, feeds)
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")  # donation unsupported on CPU
             compiled = lowered.compile()
@@ -867,14 +835,13 @@ class _CompiledBlock(_JitExecutable):
         # AOT-loaded/compiled executable (fluid/aot_cache.py) — when
         # set, run() dispatches it instead of the lazy jit
         self._aot = None
-        self._dispatched = False  # first dispatch = lazy-compile point
 
     def setup_aot(self, scope, feeds):
         """FLAGS_aot_cache_dir path: try to DESERIALIZE this signature's
         executable ("aot_hit" — no trace, no compile); on a cache miss,
         AOT-compile now and serialize it for the next restart
         ("aot_saved").  Returns the outcome ("aot_hit" / "aot_saved" /
-        None = disabled or failed, lazy jit takes over)."""
+        None = disabled, or compiled but not serializable)."""
         from . import aot_cache
 
         if not aot_cache.enabled():
@@ -891,19 +858,13 @@ class _CompiledBlock(_JitExecutable):
             _m_compile_seconds().labels(path="single", phase="aot_load") \
                 .inc(_time.perf_counter() - t0)  # observability: allow
             return "aot_hit"
-        try:
-            t0 = _time.perf_counter()  # observability: allow
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore")  # donation unsupported on CPU
-                with _persistent_cache_optout(self.plan.program, True):
-                    compiled = self._jitted.lower(*args).compile()
-            _m_compile_seconds().labels(
-                path="single", phase="aot_compile").inc(
-                _time.perf_counter() - t0)  # observability: allow
-        except Exception as e:  # resilience: allow — best-effort cache
-            warnings.warn(f"AOT compile for {self.label} failed "
-                          f"({e!r}); lazy jit path takes over")
-            return None
+        t0 = _time.perf_counter()  # observability: allow
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")  # donation unsupported on CPU
+            compiled = self._jitted.lower(*args).compile()
+        _m_compile_seconds().labels(
+            path="single", phase="aot_compile").inc(
+            _time.perf_counter() - t0)  # observability: allow
         if aot_cache.save(key, compiled):
             self._aot = compiled
             return "aot_saved"
@@ -942,12 +903,9 @@ class _CompiledBlock(_JitExecutable):
                 with ph.phase("dispatch"):
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU backend
-                        with _persistent_cache_optout(
-                                self.plan.program, not self._dispatched):
-                            fetches, out_writes = (self._aot or self._jitted)(
-                                donated, readonly, feed_vals, np.uint32(step)
-                            )
-                        self._dispatched = True
+                        fetches, out_writes = (self._aot or self._jitted)(
+                            donated, readonly, feed_vals, np.uint32(step)
+                        )
                 with ph.phase("device_wait"):
                     ph.wait((fetches, out_writes))
                 with ph.phase("fetch_sync"):
@@ -1038,7 +996,7 @@ class _CompiledChain(_JitExecutable):
     the TPU analog of the reference C++ trainer's tight loop
     (multi_trainer.cc — no Python between steps): one host→device
     dispatch per `n_steps` instead of per step, which matters exactly
-    when dispatch is expensive (remote/tunneled devices, small steps).
+    when dispatch is expensive relative to the step (small steps).
     """
 
     def __init__(self, program, block, feed_names, fetch_names, place,
@@ -1182,6 +1140,24 @@ class Executor:
                 "no compiled executable for this (program, feed, "
                 "fetch_list) signature — run the step once first")
         return cb.cost_analysis(scope, feed)
+
+    def lower(self, program, feed, fetch_list=None, scope=None,
+              sharding=None):
+        """AOT-lower the step :meth:`run` would dispatch for this
+        (program, feed, fetch_list) — same feed coercion, graph passes
+        and health transpile — without executing it; ``sharding`` places
+        every argument (_JitExecutable.lower)."""
+        scope = scope or global_scope()
+        feed = self._coerce_feed(program, feed)
+        fetch_names = [f.name if isinstance(f, Variable) else f
+                       for f in (fetch_list or [])]
+        self._graph_passes(program, fetch_names)
+        sent = self._health(program)
+        if sent is not None:
+            sent.ensure_state(scope)
+        cb = _CompiledBlock(program, program.global_block(), feed.keys(),
+                            fetch_names, self.place, scope)
+        return cb.lower(scope, feed, (sharding, sharding))
 
     def close(self):
         self._cache.clear()
@@ -1387,8 +1363,8 @@ class Executor:
         the executor step counter advances per iteration so random-op
         streams match), but with a single host→device dispatch — the
         reference C++ trainer's no-Python-between-steps loop
-        (multi_trainer.cc), which on a remote/tunneled TPU removes the
-        per-step round-trip entirely.
+        (multi_trainer.cc), which removes the per-step host round-trip
+        entirely.
 
         stacked_feed=True: each feed array carries a leading [n_steps]
         axis, one slice consumed per iteration (the infeed pattern).

@@ -12,7 +12,6 @@ __all__ = ["run_check"]
 def run_check():
     from . import (Executor, Program, default_startup_program, layers,
                    optimizer, program_guard)
-    from .framework import TPUPlace, CPUPlace
     import jax
 
     main = Program()
@@ -23,8 +22,7 @@ def run_check():
         loss = layers.mean(hidden)
         optimizer.SGD(learning_rate=0.01).minimize(loss)
 
-    place = TPUPlace(0) if jax.default_backend() != "cpu" else CPUPlace()
-    exe = Executor(place)
+    exe = Executor()
     exe.run(startup)
     out = exe.run(main,
                   feed={"install_check_x": np.ones((2, 2), dtype="float32")},

@@ -33,8 +33,8 @@ class ParallelExecutor:
             exec_strategy=exec_strategy or ExecutionStrategy(),
             share_vars_from=getattr(share_vars_from, "_compiled",
                                     share_vars_from))
-        place = (framework.TPUPlace(0) if use_cuda else framework.CPUPlace())
-        self._exe = Executor(place)
+        # use_cuda = "the accelerator if this host has one"
+        self._exe = Executor(None if use_cuda else framework.CPUPlace())
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
         feed = feed if feed is not None else feed_dict

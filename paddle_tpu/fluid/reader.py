@@ -87,23 +87,13 @@ class PyReader:
             places=places)
 
     # -- iteration -----------------------------------------------------------
-    def _device(self):
-        try:
-            import jax
-
-            return jax.devices()[0]
-        except Exception:  # pragma: no cover
-            return None
-
     def _put_ahead(self, feed):
         """Issue async H2D for every array in the feed (device put-ahead)."""
         if not self.use_double_buffer:
             return feed
         import jax
 
-        dev = self._device()
-        if dev is None:
-            return feed
+        dev = jax.devices()[0]
         return {k: jax.device_put(v, dev) for k, v in feed.items()}
 
     def __call__(self):

@@ -241,9 +241,11 @@ class AnalysisPredictor:
         from paddle_tpu.fluid.executor import Scope, scope_guard
 
         self._config = config
-        place = fluid.TPUPlace(0) if config._use_tpu else fluid.CPUPlace()
         self._scope = Scope()
-        self._exe = fluid.Executor(place)
+        # _use_tpu = "the accelerator if this host has one" (Executor()'s
+        # default place); disable_gpu() pins the CPU
+        self._exe = fluid.Executor(
+            None if config._use_tpu else fluid.CPUPlace())
         with scope_guard(self._scope):
             if config._model_dir:
                 prog, feeds, fetches = fluid.io.load_inference_model(

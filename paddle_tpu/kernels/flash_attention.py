@@ -15,13 +15,7 @@ from .primitives.flash import (  # noqa: F401
     _bwd_dq_kernel, _causal_mask, _ceil_to, _flash, _fwd_kernel,
     _pallas_bwd, _pallas_fwd, attention_reference, flash_attention,
 )
-from .primitives.contract import is_tpu_platform as _contract_is_tpu
 
 __all__ = ["flash_attention", "attention_reference", "DEFAULT_BLOCK",
            "NEG_INF"]
 
-
-def _is_tpu_platform():
-    """Legacy probe (PT_FLASH_NO_PALLAS escape hatch) — now the shared
-    contract helper."""
-    return _contract_is_tpu("PT_FLASH_NO_PALLAS")

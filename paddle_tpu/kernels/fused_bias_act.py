@@ -1,4 +1,4 @@
-"""Fused bias + GeLU + dropout — the FFN elementwise chain as one kernel.
+"""Fused bias + GeLU + dropout — the FFN elementwise chain as one op.
 
 Operator Fusion in XLA (arXiv:2301.13062) names the bias+activation+
 dropout chain as a pattern XLA's automatic fusion usually gets right
@@ -7,145 +7,52 @@ program layer emits (three ops, two HBM-materialized intermediates: the
 biased pre-activation and the activation output).  The
 ``fuse_bias_act_dropout`` program pass (paddle_tpu/passes/) rewrites the
 ``elementwise_add -> gelu -> [dropout]`` chain to ONE
-``fused_bias_act_dropout`` op whose lowering lands here:
+``fused_bias_act_dropout`` op whose lowering lands here: one jnp chain,
+which XLA fuses into a single loop (and into the producing matmul's
+epilogue where it can) — the intermediates live in registers.
 
-- **pure-XLA fallback** (default off-TPU): one jitted jnp chain — the
-  single-op boundary guarantees XLA fuses it, the intermediates live in
-  registers.
-- **Pallas** (default on TPU; ``interpret`` for CPU tests): a blockwise
-  VMEM kernel over the ``(rows, hidden)`` view, the
-  ``kernels/fused_update.py`` TILE/VMEM pattern — bias add, GeLU and the
-  dropout mask application of each row tile never leave VMEM.
+There is no Pallas form.  The one that existed could not lower the
+exact GeLU BERT and GPT use (Mosaic has no ``erf``), and a hand kernel
+for a pure elementwise chain only adds a fusion barrier plus an fp32
+round-trip XLA does not need; the pass report names the form (``xla``).
 
-The dropout MASK is drawn OUTSIDE the kernel (``jax.random.bernoulli``
-on the op's per-op/per-step key): it must materialize anyway as the op's
-``Mask`` output (the backward op reapplies it, exactly like the
-standalone dropout op), so the kernel consumes it as a uint8 input and
-the HBM saving is the two fp32 intermediates, not the mask.
+The dropout MASK is drawn with ``jax.random.bernoulli`` on the op's
+per-op/per-step key and materializes as the op's ``Mask`` output (the
+backward op reapplies it, exactly like the standalone dropout op).
 
 Numerics contract: ``gelu(x + bias) [* mask * 1/(1-p)]`` term-for-term
 the composed ops' math (``jax.nn.gelu`` with the same ``approximate``
 flag, upscale_in_train dropout semantics) — the program pass's 20-step
-parity gate runs against the unfused chain.  ``bytes_saved`` models the
-avoided HBM round-trips: 8 bytes/element per fused-away intermediate
-(one fp32 write + one read), i.e. 8·n for add→gelu and 16·n when the
-dropout leg is absorbed too.
+parity gate runs against the unfused chain.
 """
 
 from __future__ import annotations
 
-import os
-
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
-__all__ = ["impl", "bytes_saved", "fused_bias_gelu_dropout"]
+__all__ = ["KERNEL_FORM", "fused_bias_gelu_dropout"]
 
-_TILE_ROWS = 32  # int8/uint8 min sublane tile; f32 tiles (8) divide it
-
-
-def impl():
-    """Resolve the kernel implementation: ``PT_FUSED_BIAS_ACT_IMPL`` =
-    ``xla`` | ``pallas`` | ``interpret`` | ``auto`` (default).  ``auto``
-    picks Pallas on TPU backends and pure XLA elsewhere — the fallback
-    the container (no TPU, no Mosaic) always takes."""
-    mode = os.environ.get("PT_FUSED_BIAS_ACT_IMPL", "auto").strip().lower()
-    if mode in ("xla", "pallas", "interpret"):
-        return mode
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:
-        return "xla"
-
-
-def bytes_saved(n_elements, with_dropout):
-    """Modeled HBM bytes one fused forward avoids per step: each
-    fused-away fp32 intermediate (the biased pre-activation; plus the
-    activation output when dropout is absorbed) is one full write + one
-    read = 8 bytes/element."""
-    return (16 if with_dropout else 8) * int(n_elements)
+# the implementation every site takes, as the pass report and
+# chip_smoke.py name it
+KERNEL_FORM = "xla"
 
 
 def _gelu(x, approximate):
     return jax.nn.gelu(x, approximate=bool(approximate))
 
 
-def _pallas_able(h):
-    """The Pallas kernel wants the hidden (lane) dim to be a lane
-    multiple; anything else rides the XLA fallback (a 3-op elementwise
-    chain XLA fuses by itself once it is one computation)."""
-    return int(h) % 128 == 0 and impl() in ("pallas", "interpret")
-
-
-def _tile_rows(n_rows, h):
-    """Row tile through the primitives tile table (pinned-table hook;
-    _TILE_ROWS stays the default).  A pinned value that does not divide
-    the padded row count falls back rather than mislaunching."""
-    from .primitives import autotune
-
-    tile = autotune.tile_for(
-        "fused_bias_act",
-        autotune.shape_signature(rows=n_rows, h=h),
-        {"rows": _TILE_ROWS})
-    rows = int(tile["rows"])
-    return rows if rows > 0 and n_rows % rows == 0 else _TILE_ROWS
-
-
-def _pallas_chain(x2, b2, m2, scale, approximate, interpret):
-    """gelu(x+bias) [* mask * scale] over [R, H] row tiles in VMEM —
-    launched through the primitives contract."""
-    from .primitives import contract
-    from .primitives.contract import Block
-
-    R, H = x2.shape
-    with_mask = m2 is not None
-    rows = _tile_rows(R, H)
-
-    def kernel(*refs):
-        i = 0
-        x_ref = refs[i]; i += 1
-        b_ref = refs[i]; i += 1
-        m_ref = None
-        if with_mask:
-            m_ref = refs[i]; i += 1
-        o_ref = refs[i]
-        y = _gelu(x_ref[:].astype(jnp.float32)
-                  + b_ref[:].astype(jnp.float32), approximate)
-        if with_mask:
-            y = y * m_ref[:].astype(jnp.float32) * scale
-        o_ref[:] = y
-
-    def spec(shape):
-        if shape[0] == R:
-            return Block((rows, H), lambda i: (i, 0))
-        return Block(tuple(shape), lambda i: (0,) * len(shape))
-
-    ins = [x2, b2] + ([m2] if with_mask else [])
-    launch = contract.make_spec(
-        "fused_bias_act",
-        grid=(R // rows,),
-        in_specs=[spec(a.shape) for a in ins],
-        out_specs=[spec((R, H))],
-        out_shape=[((R, H), jnp.float32)],
-        interpret=interpret,
-    )
-    return contract.primitive_call(kernel, launch, *ins)
-
-
 def fused_bias_gelu_dropout(x, bias, *, dropout_prob=0.0, is_test=False,
                             approximate=False, rng_key=None):
     """The fused forward: ``gelu(x + bias)`` with optional UPSCALED
-    dropout (the only semantics the op accepts — the Pallas branch and
-    the mask-replay backward bake the 1/(1-p) factor in).  ``bias``
+    dropout (the only semantics the op accepts — the mask-replay
+    backward bakes the 1/(1-p) factor in).  ``bias``
     broadcasts on the LAST axis (the fc bias convention).  Returns
     ``(out, mask_uint8)``; the mask is all-ones when dropout is
     off/test-mode (the standalone dropout op's convention), and ``None``
     when ``dropout_prob == 0`` so callers that never declared a Mask
     output pay nothing."""
     shape = jnp.shape(x)
-    h = int(shape[-1])
     p = float(dropout_prob)
     scale = 1.0 / max(1.0 - p, 1e-8)
     live = p > 0.0 and not is_test
@@ -155,28 +62,10 @@ def fused_bias_gelu_dropout(x, bias, *, dropout_prob=0.0, is_test=False,
             raise ValueError("dropout_prob > 0 in train mode needs rng_key")
         mask = jax.random.bernoulli(rng_key, 1.0 - p, shape)
 
-    if _pallas_able(h):
-        rows = int(np.prod(shape[:-1], dtype=np.int64)) if len(shape) > 1 \
-            else 1
-        rpad = (-rows) % _TILE_ROWS
-        x2 = jnp.reshape(x, (rows, h)).astype(jnp.float32)
-        b2 = jnp.reshape(bias, (1, h)).astype(jnp.float32)
-        m2 = None
-        if live:
-            m2 = jnp.reshape(mask, (rows, h)).astype(jnp.uint8)
-        if rpad:
-            x2 = jnp.pad(x2, ((0, rpad), (0, 0)))
-            if m2 is not None:
-                m2 = jnp.pad(m2, ((0, rpad), (0, 0)))
-        y2 = _pallas_chain(x2, b2, m2, scale, approximate,
-                           interpret=impl() == "interpret")
-        out = y2[:rows].reshape(shape).astype(x.dtype)
-    else:
-        y = _gelu(x.astype(jnp.float32)
-                  + bias.astype(jnp.float32), approximate)
-        if live:
-            y = y * mask.astype(jnp.float32) * scale
-        out = y.astype(x.dtype)
+    y = _gelu(x.astype(jnp.float32) + bias.astype(jnp.float32), approximate)
+    if live:
+        y = y * mask.astype(jnp.float32) * scale
+    out = y.astype(x.dtype)
     if p <= 0.0:
         return out, None
     if mask is None:  # test mode: the identity mask the dropout op saves

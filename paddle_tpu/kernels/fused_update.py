@@ -1,8 +1,9 @@
 """Fused dequant→optimizer-update→requant step kernels.
 
-docs/PERF.md pins part of the ~54 ms per-step residue on the optimizer
-leg's fp32 HBM round-trip: the quantized all-reduce dequantizes the
-gradient bucket into a full fp32 buffer, the optimizer op reads it back,
+The motivating cost (modeled, never measured on the chip — PERF.md) is
+the optimizer leg's fp32 HBM round-trip: the quantized all-reduce
+dequantizes the gradient bucket into a full fp32 buffer, the optimizer
+op reads it back,
 writes the fp32 updated parameter, and (under ZeRO-1 + zero_gather_quant)
 the gather wrapper reads THAT back to requantize it for the wire.  This
 module fuses the chain so neither fp32 image materializes
@@ -93,15 +94,13 @@ __all__ = [
 def impl():
     """Resolve the kernel implementation: ``PT_FUSED_UPDATE_IMPL`` =
     ``xla`` | ``pallas`` | ``interpret`` | ``auto`` (default).  ``auto``
-    picks Pallas on TPU backends and pure XLA elsewhere — the fallback
-    the container (no TPU, no Mosaic) always takes."""
+    picks Pallas where traces lower for a TPU and pure XLA elsewhere."""
     mode = os.environ.get("PT_FUSED_UPDATE_IMPL", "auto").strip().lower()
     if mode in ("xla", "pallas", "interpret"):
         return mode
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:
-        return "xla"
+    from .primitives.contract import is_tpu_platform
+
+    return "pallas" if is_tpu_platform() else "xla"
 
 
 def bytes_saved(n_elements):

@@ -16,13 +16,7 @@ from .primitives.paged import (  # noqa: F401
     paged_attention_quant, paged_attention_quant_reference,
     paged_attention_reference,
 )
-from .primitives.contract import is_tpu_platform as _contract_is_tpu
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_quant", "paged_attention_quant_reference"]
 
-
-def _is_tpu_platform():
-    """Legacy probe (PT_PAGED_NO_PALLAS escape hatch) — now the shared
-    contract helper."""
-    return _contract_is_tpu("PT_PAGED_NO_PALLAS")

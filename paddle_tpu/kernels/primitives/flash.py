@@ -412,8 +412,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         bias = jnp.broadcast_to(bias.reshape(bh, -1), (bh, s)).astype(jnp.float32)
 
     mode, interpret = contract.resolve_mode(
-        force, no_pallas_env="PT_FLASH_NO_PALLAS",
-        force_env="PT_FLASH_FORCE_PALLAS")
+        "flash_attention", force, force_env="PT_FLASH_FORCE_PALLAS")
     if mode == "pallas":
         block = _select_block(q, k, v, bias, causal, scale, interpret)
         q, k, v, bias = _pad_to_block(q, k, v, bias, block)

@@ -25,12 +25,13 @@ Two implementations (the shared resolve_mode dispatch):
 - **XLA reference** (CPU fallback + numerics oracle): gather the pages
   (`k_pages[page_table]`), mask positions past each query's length with
   the same -1e9 the fused causal softmax op uses, `jax.nn.softmax`.
-- **Pallas kernel**: grid (B, heads, logical pages) with the page
-  dimension innermost; the page table and per-row start offsets ride as
-  scalar prefetch so each K/V block's index_map resolves the PHYSICAL
-  page id — the kernel never sees a gathered copy of the pool.  Online
-  softmax (running max/sum in VMEM scratch) over the pages, blocks past
-  the row's length skipped entirely (`pl.when`), fp32 accumulation.
+- **Pallas kernel**: grid (B, logical pages) with the page dimension
+  innermost, one whole page of every head per step; the page table and
+  per-row start offsets ride as scalar prefetch so each K/V block's
+  index_map resolves the PHYSICAL page id — the kernel never sees a
+  gathered copy of the pool.  Online softmax (running max/sum in VMEM
+  scratch, per head) over the pages, blocks past the row's length
+  skipped entirely (`pl.when`), fp32 accumulation.
 
 Shapes:
   q           [B, n_heads, T, d]   T = 1 (decode step) or the prefill
@@ -97,10 +98,20 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B, n_heads, logical pages), pages innermost; the
-# page table + q_start ride as scalar prefetch so the K/V BlockSpecs
-# resolve physical page ids — the pool is never gathered into a copy.
+# Pallas kernel: grid (B, logical pages), pages innermost; the page
+# table + q_start ride as scalar prefetch so the K/V BlockSpecs resolve
+# physical page ids — the pool is never gathered into a copy.
+#
+# Mosaic tiling: a block's last two dims must be (8, 128)-divisible or
+# span the array's.  The pool is [P, page, n, d] with d = 64 on the real
+# models, so a per-head block (.., 1, d) cannot lower.  The pool is
+# VIEWED as [P, page, n*d] (a free row-major reshape — the layout in
+# serving/kv_pool.py is untouched) and each grid step takes one whole
+# page of every head, (1, page, n*d); the kernel walks the heads as
+# static d-wide lane slices of that block.
 # ---------------------------------------------------------------------------
+
+_SUBLANES = 8
 
 
 def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
@@ -118,13 +129,15 @@ def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
         p, v, preferred_element_type=jnp.float32)
 
 
-def _paged_kernel(page_table_ref, q_start_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, page_size, t, n_blocks,
-                  sm_scale):
+def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, load_kv, *, page_size, t, n, n_blocks, sm_scale):
+    """The grid-step body both pool forms share; ``load_kv(h)`` returns
+    head h's fp32 (K, V) [page, d] tiles of the current page."""
     from jax.experimental import pallas as pl
 
     bi = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
+    tp = q_ref.shape[2]  # t rounded up to a sublane multiple
 
     @pl.when(pi == 0)
     def _init():
@@ -138,74 +151,89 @@ def _paged_kernel(page_table_ref, q_start_ref, q_ref, k_ref, v_ref, o_ref,
     # LAST query of the block (global key limit = start + t - 1)
     @pl.when(pi * page_size <= start + t - 1)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                      # [T, d]
-        k = k_ref[...].reshape(page_size, -1).astype(jnp.float32)
-        v = v_ref[...].reshape(page_size, -1).astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         kpos = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page_size), 1)
+            jnp.int32, (tp, page_size), 1)
         qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page_size), 0)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        _online_softmax_step(s, v, acc_ref, m_ref, l_ref)
+            jnp.int32, (tp, page_size), 0)
+        visible = kpos <= qpos
+        for h in range(n):
+            q = q_ref[0, h].astype(jnp.float32)                 # [tp, d]
+            k, v = load_kv(h)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+            s = jnp.where(visible, s * sm_scale, NEG_INF)
+            _online_softmax_step(s, v, acc_ref.at[h], m_ref.at[h],
+                                 l_ref.at[h])
 
     @pl.when(pi == n_blocks - 1)
     def _finish():
-        l = l_ref[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        for h in range(n):
+            l = l_ref[h]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc_ref[h] / l_safe[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_spec(b, n, t, d, page_size, max_pages, out_dtype, interpret,
-                name, extra_kv_specs=()):
-    """The shared launch spec of the fp and int8 paged kernels: q block
-    + one (physical page, head) K/V block per grid step, resolved
-    through the prefetched page table."""
+def _paged_kernel(page_table_ref, q_start_ref, q_ref, k_ref, v_ref, o_ref,
+                  acc_ref, m_ref, l_ref, *, d, **kw):
+    def load_kv(h):
+        lanes = slice(h * d, (h + 1) * d)
+        return (k_ref[0, :, lanes].astype(jnp.float32),
+                v_ref[0, :, lanes].astype(jnp.float32))
+
+    _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, load_kv, **kw)
+
+
+def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
+                interpret):
+    """Launch ``kernel`` over q [B, n, T, d] and ``pools`` — each a
+    [P, page, n, w] array (w = d for K/V payloads, 1 for the int8
+    scales) passed in its flat [P, page, n*w] view."""
+    b, n, t, d = q.shape
+    page_size = pools[0].shape[1]
+    max_pages = page_table.shape[1]
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    if tp != t:  # a T=1 decode row rides as one zero-padded sublane tile
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
 
     # index_map signature under scalar prefetch: grid indices first,
     # then one ref per prefetched operand
-    def q_map(bi, hi, pi, pt, qs):
-        return (bi, hi, 0, 0)
+    def q_map(bi, pi, pt, qs):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, pi, pt, qs):
+    def kv_map(bi, pi, pt, qs):
         # read THROUGH the table: the physical page this (row, logical
         # page) pair maps to — the pool is never gathered
-        return (pt[bi, pi], 0, hi, 0)
+        return (pt[bi, pi], 0, 0)
 
-    kv_block = Block((1, page_size, 1, d), kv_map)
-    in_specs = [Block((1, 1, t, d), q_map)]
-    if extra_kv_specs:
-        in_specs.extend(extra_kv_specs)
-    else:
-        in_specs.extend([kv_block, kv_block])
-    return contract.make_spec(
+    flat = [x.reshape(x.shape[0], page_size, -1) for x in pools]
+    spec = contract.make_spec(
         name,
-        grid=(b, n, max_pages),
-        in_specs=in_specs,
-        out_specs=[Block((1, 1, t, d), q_map)],
-        out_shape=[((b, n, t, d), out_dtype)],
+        grid=(b, max_pages),
+        in_specs=[Block((1, n, tp, d), q_map)]
+        + [Block((1, page_size, x.shape[2]), kv_map) for x in flat],
+        out_specs=[Block((1, n, tp, d), q_map)],
+        out_shape=[((b, n, tp, d), q.dtype)],
         scratch=[
-            Vmem((t, d), jnp.float32),
-            Vmem((t, 128), jnp.float32),
-            Vmem((t, 128), jnp.float32),
+            Vmem((n, tp, d), jnp.float32),
+            Vmem((n, tp, 128), jnp.float32),
+            Vmem((n, tp, 128), jnp.float32),
         ],
         num_scalar_prefetch=2,
         interpret=interpret,
-    ), kv_map
+    )
+    out = contract.primitive_call(
+        functools.partial(kernel, page_size=page_size, t=t, n=n, d=d,
+                          n_blocks=max_pages, sm_scale=scale),
+        spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32), q,
+        *flat)
+    return out[:, :, :t, :]
 
 
 def _pallas_paged(q, k_pages, v_pages, page_table, q_start, scale,
                   interpret):
-    b, n, t, d = q.shape
-    page_size = k_pages.shape[1]
-    max_pages = page_table.shape[1]
-    kernel = functools.partial(_paged_kernel, page_size=page_size, t=t,
-                               n_blocks=max_pages, sm_scale=scale)
-    spec, _ = _paged_spec(b, n, t, d, page_size, max_pages, q.dtype,
-                          interpret, "paged_attention")
-    return contract.primitive_call(
-        kernel, spec, page_table.astype(jnp.int32),
-        q_start.astype(jnp.int32), q, k_pages, v_pages)
+    return _paged_call(_paged_kernel, "paged_attention", q,
+                       (k_pages, v_pages), page_table, q_start, scale,
+                       interpret)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
@@ -222,8 +250,7 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
         raise ValueError(
             f"paged_attention: K pool dtype {k_pages.dtype} != V pool "
             f"dtype {v_pages.dtype} — the pool must be one dtype")
-    mode, interpret = contract.resolve_mode(
-        force, no_pallas_env="PT_PAGED_NO_PALLAS")
+    mode, interpret = contract.resolve_mode("paged_attention", force)
     if mode == "pallas":
         return _pallas_paged(q, k_pages, v_pages, page_table, q_start,
                              scale, interpret)
@@ -241,47 +268,21 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
 def _paged_quant_kernel(page_table_ref, q_start_ref, q_ref,
                         khi_ref, klo_ref, ksc_ref,
                         vhi_ref, vlo_ref, vsc_ref, o_ref,
-                        acc_ref, m_ref, l_ref, *, page_size, t, n_blocks,
-                        sm_scale):
-    from jax.experimental import pallas as pl
-
-    bi = pl.program_id(0)
-    pi = pl.program_id(2)
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    start = q_start_ref[bi]
-
-    @pl.when(pi * page_size <= start + t - 1)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)                      # [T, d]
+                        acc_ref, m_ref, l_ref, *, d, **kw):
+    def load_kv(h):
+        lanes = slice(h * d, (h + 1) * d)
 
         def deq(hi_ref, lo_ref, sc_ref):
             # dequant in VMEM: fp32 K/V exists only block-at-a-time
-            hi = hi_ref[...].reshape(page_size, -1).astype(jnp.float32)
-            lo = lo_ref[...].reshape(page_size, -1).astype(jnp.float32)
-            sc = sc_ref[...].reshape(page_size, 1)
-            return (hi + lo * (1.0 / RESID_DIV)) * sc
+            hi = hi_ref[0, :, lanes].astype(jnp.float32)
+            lo = lo_ref[0, :, lanes].astype(jnp.float32)
+            return (hi + lo * (1.0 / RESID_DIV)) * sc_ref[0, :, h:h + 1]
 
-        k = deq(khi_ref, klo_ref, ksc_ref)
-        v = deq(vhi_ref, vlo_ref, vsc_ref)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        kpos = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page_size), 1)
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page_size), 0)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        _online_softmax_step(s, v, acc_ref, m_ref, l_ref)
+        return (deq(khi_ref, klo_ref, ksc_ref),
+                deq(vhi_ref, vlo_ref, vsc_ref))
 
-    @pl.when(pi == n_blocks - 1)
-    def _finish():
-        l = l_ref[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+    _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
+                l_ref, load_kv, **kw)
 
 
 def paged_attention_quant_reference(q, k_hi, k_lo, k_scale, v_hi, v_lo,
@@ -297,25 +298,9 @@ def paged_attention_quant_reference(q, k_hi, k_lo, k_scale, v_hi, v_lo,
 
 def _pallas_paged_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo, v_scale,
                         page_table, q_start, scale, interpret):
-    b, n, t, d = q.shape
-    page_size = k_hi.shape[1]
-    max_pages = page_table.shape[1]
-    kernel = functools.partial(_paged_quant_kernel, page_size=page_size,
-                               t=t, n_blocks=max_pages, sm_scale=scale)
-    base_spec, kv_map = _paged_spec(b, n, t, d, page_size, max_pages,
-                                    q.dtype, interpret,
-                                    "paged_attention_quant")
-    kv_block = Block((1, page_size, 1, d), kv_map)
-    sc_block = Block((1, page_size, 1, 1), kv_map)
-    spec = base_spec._replace(in_specs=(
-        base_spec.in_specs[0],
-        kv_block, kv_block, sc_block,    # K hi / lo / scale
-        kv_block, kv_block, sc_block,    # V hi / lo / scale
-    ))
-    return contract.primitive_call(
-        kernel, spec, page_table.astype(jnp.int32),
-        q_start.astype(jnp.int32), q, k_hi, k_lo, k_scale,
-        v_hi, v_lo, v_scale)
+    return _paged_call(_paged_quant_kernel, "paged_attention_quant", q,
+                       (k_hi, k_lo, k_scale, v_hi, v_lo, v_scale),
+                       page_table, q_start, scale, interpret)
 
 
 def paged_attention_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo, v_scale,
@@ -334,8 +319,7 @@ def paged_attention_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo, v_scale,
                 f"paged_attention_quant: {nm} dtype {arr.dtype} != int8 "
                 f"— the quant pool stores the dual-int8 wire format "
                 f"(serving/kv_pool.py KVPool(dtype='int8'))")
-    mode, interpret = contract.resolve_mode(
-        force, no_pallas_env="PT_PAGED_NO_PALLAS")
+    mode, interpret = contract.resolve_mode("paged_attention_quant", force)
     if mode == "pallas":
         return _pallas_paged_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo,
                                    v_scale, page_table, q_start, scale,

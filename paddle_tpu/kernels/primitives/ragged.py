@@ -200,8 +200,7 @@ def ragged_attention(q, k, v, lengths, causal=False, sm_scale=None,
     lengths = jnp.reshape(lengths, (bh,)).astype(jnp.int32)
 
     mode, interpret = contract.resolve_mode(
-        force, no_pallas_env="PT_FLASH_NO_PALLAS",
-        force_env="PT_FLASH_FORCE_PALLAS")
+        "ragged_attention", force, force_env="PT_FLASH_FORCE_PALLAS")
     if mode == "pallas":
         block = _select_block(q, k, v, lengths, causal, scale, interpret)
         s_pad = _ceil_to(s, block)

@@ -232,8 +232,22 @@ def build_bert_pretrain(cfg: BertConfig = None, is_test=False):
     return feeds, total_loss, mean_mlm_loss, nsp_acc
 
 
-def make_fake_batch(cfg: BertConfig, batch, seq_len, n_masked=None, seed=0):
-    """Synthetic pretraining batch with the right shapes/dtypes."""
+def make_fake_batch(cfg: BertConfig, batch, seq_len, n_masked=None, seed=0,
+                    shards=1):
+    """Synthetic pretraining batch with the right shapes/dtypes.
+
+    ``mask_pos`` indexes the FLAT [rows * seq_len] activations of the
+    device that gathers it, so a data-parallel feed passes ``shards`` =
+    the device count: each of the ``shards`` equal batch slices then
+    carries positions local to its own rows.  Out-of-range positions or
+    masked indices read no error on the device — they read garbage and
+    the loss is NaN — so the sizes are checked here."""
+    if seq_len > cfg.max_position:
+        raise ValueError(
+            f"seq_len {seq_len} exceeds the model's max_position "
+            f"{cfg.max_position}")
+    if batch % shards:
+        raise ValueError(f"batch {batch} does not split over {shards} shards")
     rng = np.random.RandomState(seed)
     n_masked = n_masked or max(1, seq_len // 8)
     return {
@@ -242,6 +256,7 @@ def make_fake_batch(cfg: BertConfig, batch, seq_len, n_masked=None, seed=0):
         "sent_ids": rng.randint(0, cfg.type_vocab_size, (batch, seq_len)).astype("int64"),
         "input_mask": np.ones((batch, seq_len), dtype="float32"),
         "mask_label": rng.randint(0, cfg.vocab_size, (batch * n_masked, 1)).astype("int64"),
-        "mask_pos": rng.randint(0, batch * seq_len, (batch * n_masked, 1)).astype("int64"),
+        "mask_pos": rng.randint(0, batch // shards * seq_len,
+                                (batch * n_masked, 1)).astype("int64"),
         "labels": rng.randint(0, 2, (batch, 1)).astype("int64"),
     }

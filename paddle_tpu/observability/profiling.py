@@ -345,23 +345,22 @@ def device_peaks():
     """(platform, peak_flops/s, peak_hbm_bytes/s, peak_ici_bytes/s) for
     the process's device 0.  FLAGS_device_peak_flops /
     FLAGS_device_peak_bandwidth / FLAGS_device_peak_ici_bandwidth
-    (nonzero) override the table entry-wise.  Reads jax only when it is
-    ALREADY imported — a /profilez scrape must never initialize a TPU
-    runtime."""
+    (nonzero) override the table entry-wise.  A TPU whose kind is not in
+    the table is an error — it never borrows another device's numbers.
+    Reads jax only when it is ALREADY imported — a /profilez scrape must
+    never initialize a TPU runtime."""
     platform, peaks = "cpu", _CPU_PEAKS
     jx = sys.modules.get("jax")
     if jx is not None:
-        try:
-            dev = jx.devices()[0]
-            platform = dev.platform
-            if platform == "tpu":
-                kind = getattr(dev, "device_kind", "").lower()
-                for pat, p in _TPU_PEAKS:
-                    if pat in kind:
-                        peaks = p
-                        break
-        except Exception:
-            pass
+        dev = jx.devices()[0]
+        platform = dev.platform
+        if platform == "tpu":
+            kind = dev.device_kind.lower()
+            peaks = next((p for pat, p in _TPU_PEAKS if pat in kind), None)
+            if peaks is None:
+                raise ValueError(
+                    f"no peaks recorded for TPU kind {dev.device_kind!r} "
+                    f"— add it to _TPU_PEAKS with its source")
     flops = float(_flag("device_peak_flops", 0) or 0) or peaks[0]
     bw = float(_flag("device_peak_bandwidth", 0) or 0) or peaks[1]
     ici = float(_flag("device_peak_ici_bandwidth", 0) or 0) or peaks[2]

@@ -43,9 +43,9 @@ def _rng_impl():
         # someone pinning streams for reproducibility must not silently
         # get the platform default because of a typo
         raise ValueError(f"PT_RNG_IMPL={forced!r}: use 'rbg' or 'threefry'")
-    from paddle_tpu.fluid.platform_utils import TPU_PLATFORMS, default_platform
+    from paddle_tpu.fluid.platform_utils import is_tpu
 
-    return "rbg" if default_platform() in TPU_PLATFORMS else "threefry2x32"
+    return "rbg" if is_tpu() else "threefry2x32"
 
 
 def op_rng_key(ctx, attrs):

@@ -438,8 +438,8 @@ def _fusion_conv_inception(ctx, x, filters, biases, attrs):
 # fused bias + GeLU + dropout (TPU-native, no reference analog): the
 # graph-optimization pass layer (paddle_tpu/passes/fuse_bias_act.py)
 # rewrites the FFN `elementwise_add -> gelu -> [dropout]` chain to this
-# one op — Pallas blockwise kernel on TPU, pure-XLA fallback elsewhere
-# (kernels/fused_bias_act.py).  The dropout mask is SAVED (Mask output,
+# one op, a single XLA fusion (kernels/fused_bias_act.py).  The dropout
+# mask is SAVED (Mask output,
 # uint8, the standalone dropout op's convention) so forward and backward
 # agree exactly; `rng_op_index` pins the mask stream to the absorbed
 # dropout op's pre-fusion identity, which is what makes the fused
@@ -481,8 +481,8 @@ def _fused_bias_act_dropout(ctx, x, bias, attrs):
     impl_ = attrs.get("dropout_implementation", "upscale_in_train")
     if p > 0.0 and impl_ != "upscale_in_train":
         # the pass only ever emits upscale semantics; a hand-built
-        # downgrade desc must fail loudly — the Pallas branch and the
-        # mask-replay backward both bake the upscale factor in
+        # downgrade desc must fail loudly — the mask-replay backward
+        # bakes the upscale factor in
         raise NotImplementedError(
             "fused_bias_act_dropout supports "
             f"dropout_implementation='upscale_in_train', got {impl_!r}")

@@ -727,6 +727,27 @@ class DataParallelRunner:
                 "fetch_list) signature — run the step once first")
         return cb.cost_analysis(scope, feed)
 
+    def lower(self, executor, feed, fetch_list=None, scope=None, mesh=None):
+        """AOT-lower the sharded step :meth:`run` would dispatch, over
+        ``mesh`` (default: this runner's) with parameters replicated and
+        feeds batch-split — a mesh of ``jax.experimental.topologies``
+        devices compiles the four-chip step without a chip
+        (_JitExecutable.lower).  Transpiler lane only."""
+        from paddle_tpu.fluid import executor as ex
+
+        from .gspmd.specs import named_sharding
+
+        scope = scope or ex.global_scope()
+        feed = executor._coerce_feed(self.program, feed or {})
+        fetch_names = [f.name if not isinstance(f, str) else f
+                       for f in (fetch_list or [])]
+        mesh = mesh or self.mesh
+        cb = _ShardedBlock(self.program, feed.keys(), fetch_names, mesh,
+                           scope)
+        return cb.lower(scope, feed, (
+            named_sharding(mesh, ()),
+            named_sharding(mesh, (pmesh.DATA_AXIS,))))
+
 
 class _ShardedBlock(_JitExecutable):
     """One (program-version, feed-signature) → sharded XLA executable.

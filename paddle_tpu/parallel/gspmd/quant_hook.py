@@ -65,9 +65,9 @@ _QUANT_IMPLS = ("auto", "shard_map", "custom_partitioning")
 
 
 def resolve_quant_impl(impl=None):
-    """Resolve FLAGS_gspmd_quant_impl: ``auto`` = custom_partitioning on
-    TPU backends, the shard_map island everywhere else (the documented
-    0.4.3x CPU fallback)."""
+    """Resolve FLAGS_gspmd_quant_impl: ``auto`` = custom_partitioning
+    where traces lower for a TPU, the shard_map island everywhere
+    else."""
     if impl in (None, "auto"):
         from paddle_tpu.fluid import flags as _flags
 
@@ -77,13 +77,9 @@ def resolve_quant_impl(impl=None):
             f"gspmd_quant_impl must be one of {_QUANT_IMPLS}, got {impl!r}")
     if impl != "auto":
         return impl
-    try:
-        import jax
+    from paddle_tpu.fluid.platform_utils import is_tpu
 
-        return ("custom_partitioning" if jax.default_backend() == "tpu"
-                else "shard_map")
-    except Exception:
-        return "shard_map"
+    return "custom_partitioning" if is_tpu() else "shard_map"
 
 
 class QuantHookPlan:
@@ -476,15 +472,9 @@ class QuantHookPlan:
 
             return plain
 
-        reduce_quant, is_quant = _cp_sum_reducer(
+        reduce_quant = _cp_sum_reducer(
             self.mesh, axis, self.block_size, self.algo,
             self.crossover_kb)
-        if not is_quant:
-            # demoted to XLA's fp32 all-reduce (warned inside the
-            # builder): the modeled int8 bytes must NOT book — this
-            # metric exists precisely to expose silent fp32 wire traffic
-            self.wire_bytes_per_step = 0
-            self.bucket_report = []
 
         def with_cp_reduce(scope_vals, feeds, step):
             carry, grads, bucket, _fusedq, stacked = mapped(
@@ -508,25 +498,11 @@ class QuantHookPlan:
 
 def _cp_sum_reducer(mesh, axis, block_size, algo, crossover_kb):
     """`jnp.sum(x, axis=0)` over shard-stacked partials, carrying a
-    `jax.custom_partitioning` rule whose per-device lowering is the
-    dual-int8 adaptive ring — the TPU-native spelling of the hook.
-    Returns ``(reducer, is_quant)``: falls back to the plain sum (XLA's
-    own fp32 all-reduce, ``is_quant=False`` so the caller zeroes the
-    modeled int8 bytes) with a warning when the toolchain cannot build
-    the rule (the documented 0.4.3x path never reaches here:
-    resolve_quant_impl keeps ``auto`` on the island off-TPU)."""
+    `custom_partitioning` rule whose per-device lowering is the
+    dual-int8 adaptive ring — the TPU-native spelling of the hook."""
     import jax.numpy as jnp
-
-    from paddle_tpu import jax_compat
-
-    cp = jax_compat.get_custom_partitioning()
-    if cp is None:
-        warnings.warn(
-            "jax.custom_partitioning unavailable on this toolchain; "
-            "gspmd quant hook falling back to XLA's fp32 all-reduce for "
-            "the reduction (set FLAGS_gspmd_quant_impl=shard_map for the "
-            "int8 island)")
-        return (lambda x: jnp.sum(x, axis=0)), False
+    from jax.experimental.custom_partitioning import (
+        custom_partitioning as cp)
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -552,16 +528,9 @@ def _cp_sum_reducer(mesh, axis, block_size, algo, crossover_kb):
 
         return mesh, lower_fn, res_sh, arg_sh
 
-    try:
-        qsum.def_partition(partition=_partition,
-                           infer_sharding_from_operands=_infer)
-        return qsum, True
-    except Exception as e:  # toolchain-specific signature drift
-        warnings.warn(
-            f"custom_partitioning rule construction failed ({e}); gspmd "
-            "quant hook falling back to XLA's fp32 all-reduce — set "
-            "FLAGS_gspmd_quant_impl=shard_map for the int8 island")
-        return (lambda x: jnp.sum(x, axis=0)), False
+    qsum.def_partition(partition=_partition,
+                       infer_sharding_from_operands=_infer)
+    return qsum
 
 
 def plan_quant_hook(plan, program, mesh, policy, block_size=None,

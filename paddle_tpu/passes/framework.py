@@ -445,7 +445,8 @@ def attribute_costs(build_fn, feed, fetch_list, spec=None, place=None,
     pass's measured bytes reduction (when positive) on
     ``pt_pass_bytes_saved_total{pass}``.  With ``want_hlo`` the final
     stage's optimized HLO text rides along (the fusion-proof surface).
-    CPU-measurable; on-chip MFU capture is the docs/PERF.md placeholder.
+    CPU-measurable cost-model counts; the on-chip effect is not
+    measured (PERF.md).
     """
     names = resolve_passes(spec)
     stages = [names[:i] for i in range(len(names) + 1)]

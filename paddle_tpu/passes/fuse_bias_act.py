@@ -5,8 +5,8 @@ The fc layer emits ``mul`` + ``elementwise_add`` + activation as three
 ops; with the hidden-dropout that follows in transformer FFN blocks, the
 chain materializes up to two activation-sized fp32 intermediates per
 block.  The fused op (ops/fused_ops.py -> kernels/fused_bias_act.py)
-runs the whole chain in one kernel — Pallas blockwise VMEM tiles on TPU,
-a single XLA fusion elsewhere.
+runs the whole chain as a single XLA fusion; the report entry names the
+form (``"kernel": "xla"``) so a run reports it instead of assuming it.
 
 Match contract:
 
@@ -70,7 +70,10 @@ class FuseBiasActDropoutPass(ProgramPass):
         if not matches:
             return {"changed": False, "sites": 0}
         modeled = self._rewrite(program, block, matches)
+        from paddle_tpu.kernels.fused_bias_act import KERNEL_FORM
+
         return {"changed": True, "sites": len(matches),
+                "kernel": KERNEL_FORM,
                 "modeled_bytes_saved": modeled,
                 "dropout_sites": sum(1 for m in matches if m["dropout"])}
 

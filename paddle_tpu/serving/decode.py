@@ -331,15 +331,6 @@ class DecodeEngine:
                     f"({sorted(claimed[0] ^ claimed[1])}) — the shared "
                     f"scope cannot satisfy both")
             _quantize_scope_weights(self.scope, dec_prog)
-        # jaxlib-0.4.3x XLA:CPU corrupts the heap DESERIALIZING these
-        # paged gather/scatter programs from the persistent compilation
-        # cache (warm cache aborted 5/5; fresh compiles pass) — the
-        # executor skips the cache for stamped programs exactly on the
-        # affected platforms (platform_utils.
-        # persistent_cache_deserialize_brittle), so real-TPU replicas
-        # keep the warm-cache + AOT restart story
-        dec_prog._no_persistent_compile_cache = True
-        pf_prog._no_persistent_compile_cache = True
         self._exe = fluid.Executor(place)
 
         self._queue = collections.deque()   # prefill-pending, FIFO
@@ -548,17 +539,34 @@ class DecodeEngine:
                 for p in prompts]
         return [f.result(timeout=timeout) for f in futs]
 
+    def _warm_prefill_args(self):
+        return dict(
+            tokens=[0], pos0=0, table_row=self.pool.padded_table(None),
+            write_pages=np.zeros(self.prefill_chunk // self.pool.page_size,
+                                 np.int32), valid=1)
+
     def warmup(self):
         """Compile (or AOT-load) both executables outside the request
         path: one all-inactive decode step + one trash-page prefill
         chunk.  Writes land only on the pool's trash page.  Returns the
         number of executables warmed (2)."""
-        self._run_prefill_feed(
-            tokens=[0], pos0=0, table_row=self.pool.padded_table(None),
-            write_pages=np.zeros(self.prefill_chunk // self.pool.page_size,
-                                 np.int32), valid=1, warm=True)
+        self._run_prefill_feed(**self._warm_prefill_args(), warm=True)
         self._run_decode_feed([], warm=True)
         return 2
+
+    def lower(self, sharding=None):
+        """AOT-lower the two executables :meth:`warmup` compiles, without
+        running them: ``[prefill_chunk, decode_step]`` (Executor.lower —
+        ``sharding`` over a topology device compiles them chip-free)."""
+        return [
+            self._exe.lower(self._pf_prog,
+                            self._prefill_feed(**self._warm_prefill_args()),
+                            [self._pf_fetch], scope=self.scope,
+                            sharding=sharding),
+            self._exe.lower(self._dec_prog, self._decode_feed([]),
+                            [self._dec_fetch], scope=self.scope,
+                            sharding=sharding),
+        ]
 
     def drain(self, timeout=None):
         """Graceful drain (the decode lane's half of the
@@ -799,14 +807,13 @@ class DecodeEngine:
                     self._queue.remove(req)
                 self._ready.append(req)
 
-    def _run_prefill_feed(self, tokens, pos0, table_row, write_pages,
-                          valid, warm=False):
+    def _prefill_feed(self, tokens, pos0, table_row, write_pages, valid):
         c = self.prefill_chunk
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
         pos = np.minimum(pos0 + np.arange(c, dtype=np.int64),
                          self.cfg.max_position - 1)[None, :]
-        feed = {
+        return {
             "pf_tok": tok,
             "pf_pos": pos,
             "pf_page_table": table_row[None, :].astype(np.int32),
@@ -814,6 +821,11 @@ class DecodeEngine:
             "pf_qstart": np.asarray([pos0], np.int32),
             "pf_last_idx": np.asarray([max(valid - 1, 0)], np.int64),
         }
+
+    def _run_prefill_feed(self, tokens, pos0, table_row, write_pages,
+                          valid, warm=False):
+        feed = self._prefill_feed(tokens, pos0, table_row, write_pages,
+                                  valid)
         with self._exec_lock:
             t0 = time.monotonic()  # observability: allow
             (out,) = self._exe.run(self._pf_prog, feed=feed,
@@ -893,7 +905,7 @@ class DecodeEngine:
         for i, req in now_done:
             self._finish(i, req)
 
-    def _run_decode_feed(self, active, warm=False):
+    def _decode_feed(self, active):
         ps, pgs = self.pool_slots, self.pool.page_size
         tok = np.zeros((ps, 1), np.int64)
         pos = np.zeros((ps, 1), np.int64)
@@ -907,9 +919,12 @@ class DecodeEngine:
             table[i] = self.pool.padded_table(req.seq_id)
             wpage[i] = self.pool.table(req.seq_id)[p // pgs]
             woff[i] = p % pgs
-        feed = {"dec_tok": tok, "dec_pos": pos,
+        return {"dec_tok": tok, "dec_pos": pos,
                 "dec_page_table": table.astype(np.int32),
                 "dec_write_page": wpage, "dec_write_off": woff}
+
+    def _run_decode_feed(self, active, warm=False):
+        feed = self._decode_feed(active)
         with self._exec_lock:
             t0 = time.monotonic()  # observability: allow
             (out,) = self._exe.run(self._dec_prog, feed=feed,

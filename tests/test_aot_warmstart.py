@@ -156,16 +156,16 @@ def test_aot_cache_key_stability_and_fallback(tmp_path):
 
     # kernel-impl override envs select WHAT lowers for the same
     # program, so they are part of the key — a Pallas-path executable
-    # must never be served to a PT_PAGED_NO_PALLAS debug run
-    prev = os.environ.get("PT_PAGED_NO_PALLAS")
-    os.environ["PT_PAGED_NO_PALLAS"] = "1"
+    # must never be served to a PT_FUSED_UPDATE_IMPL=xla debug run
+    prev = os.environ.get("PT_FUSED_UPDATE_IMPL")
+    os.environ["PT_FUSED_UPDATE_IMPL"] = "xla"
     try:
         assert aot_cache.executable_key(build(), spec, ["out"]) != k1
     finally:
         if prev is None:
-            os.environ.pop("PT_PAGED_NO_PALLAS", None)
+            os.environ.pop("PT_FUSED_UPDATE_IMPL", None)
         else:
-            os.environ["PT_PAGED_NO_PALLAS"] = prev
+            os.environ["PT_FUSED_UPDATE_IMPL"] = prev
 
     assert aot_cache.available()
     fluid.set_flags({"FLAGS_aot_cache_dir": str(tmp_path)})

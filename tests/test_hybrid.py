@@ -6,17 +6,6 @@ single-device and multi-device, assert per-step losses match.
 """
 
 import numpy as np
-import pytest
-
-import cpu_mesh
-
-# the bert dp×mp×sp program is the reliable trigger of the 0.4.3x
-# XLA:CPU GSPMD heap corruption — one abort here kills the whole pytest
-# session (see cpu_mesh.gspmd_cpu_heap_broken)
-pytestmark = pytest.mark.skipif(
-    cpu_mesh.gspmd_cpu_heap_broken(),
-    reason="XLA:CPU 0.4.3x heap corruption on multi-axis GSPMD "
-           "(nondeterministic abort; skipped to keep the session alive)")
 
 from paddle_tpu import fluid
 from paddle_tpu.fluid.executor import Scope, scope_guard

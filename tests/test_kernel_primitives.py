@@ -72,17 +72,40 @@ def test_contract_multi_output_and_scratch():
 
 def test_resolve_mode_cpu_semantics(monkeypatch):
     # CPU default: XLA reference, no interpreter
-    assert contract.resolve_mode(None) == ("reference", False)
+    assert contract.resolve_mode("t", None) == ("reference", False)
     # forced pallas off-TPU runs the kernel under the interpreter
-    assert contract.resolve_mode("pallas") == ("pallas", True)
-    assert contract.resolve_mode("reference") == ("reference", False)
+    assert contract.resolve_mode("t", "pallas") == ("pallas", True)
+    assert contract.resolve_mode("t", "reference") == ("reference", False)
     # force_env engages the kernel off-TPU (the CPU parity lane)
     monkeypatch.setenv("PT_TEST_FORCE_PALLAS", "1")
     assert contract.resolve_mode(
-        None, force_env="PT_TEST_FORCE_PALLAS") == ("pallas", True)
+        "t", None, force_env="PT_TEST_FORCE_PALLAS") == ("pallas", True)
     monkeypatch.setenv("PT_TEST_FORCE_PALLAS", "0")
     assert contract.resolve_mode(
-        None, force_env="PT_TEST_FORCE_PALLAS") == ("reference", False)
+        "t", None, force_env="PT_TEST_FORCE_PALLAS") == ("reference", False)
+
+
+def test_resolve_mode_books_what_it_chose_and_follows_lowering_target():
+    """Every decision lands on pt_kernel_dispatch_total — what
+    chip_smoke.py reads instead of assuming a form — and inside
+    lowering_for("tpu") the default is COMPILED Pallas (the chip-free
+    AOT lane), not the interpreter."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.fluid.platform_utils import lowering_for
+
+    def booked(mode):
+        fam = obs.snapshot().get("pt_kernel_dispatch_total") or {}
+        return fam.get("samples", {}).get(("t_book", mode), 0)
+
+    before = {m: booked(m) for m in ("reference", "interpret", "pallas")}
+    contract.resolve_mode("t_book", None)
+    contract.resolve_mode("t_book", "pallas")
+    with lowering_for("tpu"):
+        assert contract.resolve_mode("t_book", None) == ("pallas", False)
+    assert contract.resolve_mode("t_book", None) == ("reference", False)
+    after = {m: booked(m) for m in before}
+    assert {m: after[m] - before[m] for m in before} == {
+        "reference": 2, "interpret": 1, "pallas": 1}
 
 
 # ---------------------------------------------------------------------------

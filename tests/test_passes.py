@@ -328,8 +328,7 @@ def test_cost_attribution_books_bytes_reduction(monkeypatch):
     bytes_accessed reduction from cost_analysis for fuse_attention
     (CPU-measurable across the kernel boundary — PT_FLASH_FORCE_PALLAS
     engages the blockwise kernel in interpret mode, so the S×S tensor's
-    absence is visible to the cost model; on-chip MFU capture is the
-    docs/PERF.md placeholder), and the measured delta lands on
+    absence is visible to the cost model), and the measured delta lands on
     pt_pass_bytes_saved_total{pass}."""
     from paddle_tpu import observability as obs
 

@@ -1,6 +1,6 @@
 """tools/perf_compare.py (ISSUE 11 CI satellite): threshold
 classification — regression, win, within-noise, missing-field tolerance
-— against synthetic records AND the real BENCH_r0x.json fixtures."""
+— against synthetic records, bare and in the driver-artifact shape."""
 
 import json
 import sys
@@ -145,25 +145,36 @@ def test_methodology_tokens_do_not_mismatch(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# the real fixtures on disk
+# the driver-artifact shape ({"parsed": {...}} around the bench line)
 # ---------------------------------------------------------------------------
 
 
-def test_real_bench_fixtures_compare(capsys):
-    old, new = str(REPO / "BENCH_r04.json"), str(REPO / "BENCH_r05.json")
+def _driver_artifact(value):
+    parsed = {"metric": "bert_base_pretrain_tokens_per_sec", "value": value,
+              "unit": "tokens/sec/chip", "vs_baseline": 0.0,
+              "config": "bert-base b128 s128 bf16-policy devfeed pipelined",
+              "tflops_per_sec": 0.01}
+    return {"n": 5, "cmd": "python bench.py", "rc": 0,
+            "tail": json.dumps(parsed) + "\n", "parsed": parsed}
+
+
+def test_driver_artifacts_compare(tmp_path, capsys):
+    old = _write(tmp_path, "r04.json", _driver_artifact(7000.0))
+    new = _write(tmp_path, "r05.json", _driver_artifact(6714.5))
     rc = perf_compare.main([old, new, "--threshold-pct", "5", "--json"])
     out = json.loads(capsys.readouterr().out)
     assert rc in (0, 1)
-    assert out["metric"] == "bert_tiny_pretrain_tokens_per_sec"
+    assert out["metric"] == "bert_base_pretrain_tokens_per_sec"
     statuses = {r["field"]: r["status"] for r in out["rows"]}
-    # the headline value is present and classified on both real records
+    # the headline value is present and classified on both records
     assert statuses["value"] in ("win", "regression", "within-noise")
-    # fields the old records predate are tolerated, not fatal
+    # fields the records predate are tolerated, not fatal
     assert statuses["latency_seconds.p50"] == "missing"
 
 
-def test_real_fixture_vs_scaled_regression(tmp_path):
-    real = perf_compare.load_record(str(REPO / "BENCH_r05.json"))
+def test_driver_artifact_vs_scaled_regression(tmp_path):
+    path = _write(tmp_path, "r05.json", _driver_artifact(6714.5))
+    real = perf_compare.load_record(path)
     worse = dict(real, value=real["value"] * 0.5)
     rc = perf_compare.main([
         _write(tmp_path, "old.json", real),

@@ -816,7 +816,7 @@ def test_zero1_quantized_weight_gather_subprocess():
     within the dual-int8 bound, training converges, and the per-step
     payload books under pt_collective_payload_bytes_total
     {collective="zero_gather_quant"}.  Runs in a SUBPROCESS: the 0.4.3x
-    XLA:CPU GSPMD heap corruption (cpu_mesh.gspmd_cpu_heap_broken) is a
+    XLA:CPU GSPMD heap corruption is a
     nondeterministic abort — isolation keeps a bad roll from killing the
     whole pytest session, unlike tests/test_hybrid.py's blanket skip,
     which would leave this feature with zero executed coverage."""

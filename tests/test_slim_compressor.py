@@ -6,9 +6,6 @@ nas/light_nas_strategy.py.
 """
 
 import numpy as np
-import pytest
-
-import cpu_mesh
 
 from paddle_tpu import fluid
 from paddle_tpu.fluid.contrib import slim
@@ -76,12 +73,6 @@ compressor:
     assert ctx.eval_results[acc.name][-1] > 0.7, ctx.eval_results
 
 
-@pytest.mark.skipif(
-    cpu_mesh.gspmd_cpu_heap_broken(),
-    reason="XLA:CPU 0.4.3x heap corruption: the resume's second "
-           "Compressor run aborts under BOTH runtimes (same class as "
-           "test_hybrid — reproduces on clean HEAD; one abort kills "
-           "every test after this file)")
 def test_compressor_checkpoint_resume(tmp_path):
     cfg_text = """
 version: 1.0
@@ -135,14 +126,6 @@ compressor:
     assert sorted(os.listdir(ckpt)) == ["0", "1", "2", "3"]
 
 
-@pytest.mark.skipif(
-    cpu_mesh.gspmd_cpu_heap_broken(),
-    reason="XLA:CPU 0.4.3x heap corruption: the QuantizationStrategy "
-           "Compressor run segfaults in FULL-SUITE runs (2/2 tier-1 "
-           "sessions killed at this test with both a stale and a fresh "
-           "compile cache; standalone it only crashes when the persistent "
-           "compile cache is poisoned) — same containment class as "
-           "test_compressor_checkpoint_resume above")
 def test_quantization_strategy_pipeline(tmp_path):
     cfg = tmp_path / "quant.yaml"
     cfg.write_text("""
@@ -252,12 +235,6 @@ def test_sa_controller_handles_fixed_dims():
     assert ctrl2.next_tokens() == [0, 0]
 
 
-@pytest.mark.skipif(
-    cpu_mesh.gspmd_cpu_heap_broken(),
-    reason="XLA:CPU 0.4.3x heap corruption: the resume's second "
-           "Compressor run aborts full-suite sessions — same class as "
-           "test_quantization_strategy_pipeline (one abort kills every "
-           "test after this file)")
 def test_quantization_resume_keeps_scale_state(tmp_path):
     """Checkpoint resume of a QAT run must re-apply the transform BEFORE
     loading, so saved scale statistics land in matching vars."""

@@ -152,6 +152,36 @@ def test_launch_collective_env_contract(tmp_path):
             eps[int(rid)]
 
 
+def test_launch_parent_holds_no_backend_and_defaults_to_one_process(
+        tmp_path):
+    """A chip belongs to one process: a launcher parent that initializes
+    a JAX backend (to count devices, say) holds the chip its children
+    need.  Run the launcher's __main__ and look: no backend came up in
+    it.  With no count given it starts ONE process, which drives every
+    chip of its host."""
+    script = tmp_path / "child.py"
+    script.write_text(_COLLECTIVE_CHILD)
+    log_dir = tmp_path / "logs"
+    parent = textwrap.dedent(f"""
+        import runpy, sys
+        sys.argv = ["launch", "--started_port=7331",
+                    "--log_dir={log_dir}", "{script}"]
+        runpy.run_module("paddle_tpu.distributed.launch",
+                         run_name="__main__")
+        from jax._src import xla_bridge  # no public "is a backend up?"
+        assert not xla_bridge._backends, xla_bridge._backends
+    """)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", parent],
+                         capture_output=True, text=True, timeout=120,
+                         cwd=repo, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert [f for f in os.listdir(log_dir)
+            if f.startswith("workerlog")] == ["workerlog.0"]
+    seen = json.loads((log_dir / "workerlog.0").read_text().strip())
+    assert seen["PADDLE_TRAINERS_NUM"] == "1"
+
+
 def test_launch_rejects_short_selected_gpus(tmp_path):
     """Mis-sized --selected_gpus must fail BEFORE spawning anything (a
     partial group would block forever in collective rendezvous)."""

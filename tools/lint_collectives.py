@@ -36,8 +36,7 @@ gspmd core (``parallel/gspmd/specs|executor|quant_hook.py``; the
 pipeline policy itself stays LINTED so its collectives must ride the
 kernels surface or carry an explicit allow); the sharding check
 additionally sanctions ``parallel/hybrid.py`` (its `_spec` is the
-classic lane's one minting site) and ``jax_compat.py`` (the
-cross-version accessor).
+classic lane's one minting site).
 
 Suppress a deliberate finding with ``# collective: allow`` on the same
 line or the line above (e.g. the ring-attention kernel's own ppermute
@@ -81,7 +80,6 @@ EXEMPT = (
 # the sanctioned sharding-placement surface (raw-sharding check only)
 EXEMPT_SHARDING = EXEMPT + (
     "paddle_tpu/parallel/hybrid.py",
-    "paddle_tpu/jax_compat.py",
 )
 
 RAW_COLLECTIVES = ("ppermute", "psum")

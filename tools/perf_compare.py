@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Mechanical before/after for BENCH records: diff two BENCH_*.json
-files and exit nonzero on regression (docs/PERF.md "perf-compare").
+files and exit nonzero on regression.
 
 The on-chip capture sessions (and CI) get a deterministic verdict
 instead of a human eyeballing two JSON blobs: every comparable metric is

@@ -80,6 +80,73 @@ def _m_cost(kind):
         f"executable, per signature", labels=("signature",))
 
 
+def _m_program_compile_seconds():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_program_compile_seconds_total",
+        "Seconds from an executable-cache miss to the end of the "
+        "signature's first run (plan, trace, XLA compile or cache load, "
+        "first execution), by the program's jit name and where the "
+        "executable came from: miss (compiled, no persistent cache "
+        "consulted), persistent_hit / persistent_miss (jax's on-disk "
+        "cache), aot_hit (deserialized)", labels=("program", "outcome"))
+
+
+# what every lane's run_steps chain (chain_step_body) is jitted as
+CHAIN_NAME = "train_chain"
+
+
+def jit_name(program, ops):
+    """The stable ``__name__`` a program's body is jitted under, so a
+    device trace reads ``jit_<name>`` instead of ``jit_fn``: the name its
+    builder gave the Program (the decode engine's ``decode_step`` /
+    ``prefill_chunk``), else ``train_step`` where an optimizer's ops
+    survive pruning, ``startup`` where no op reads anything, else
+    ``program``."""
+    name = getattr(program, "name", None)
+    if name:
+        return "".join(c if c.isalnum() or c == "_" else "_"
+                       for c in str(name))
+    if any(op.attrs.get("op_role") == "optimize" for op in ops):
+        return "train_step"
+    if ops and not any(op.input_arg_names for op in ops):
+        return "startup"
+    return "program"
+
+
+# jax's persistent-cache events seen so far in this process: a compile
+# span reads the pair before and after to name where its executable
+# came from
+_persistent_seen = {"hit": 0, "miss": 0}
+
+
+@contextlib.contextmanager
+def compile_span(lane, number=None):
+    """Span + counter around a signature's first run, from the
+    executable-cache miss to the end of the first execution.  Yields a
+    dict: the lane sets ``["program"]`` (the plan's jit name) once the
+    plan is built, and ``["outcome"] = "aot_hit"`` where it knows better
+    than the persistent cache's events."""
+    from paddle_tpu.observability import profiling as _profiling
+
+    hit0, miss0 = _persistent_seen["hit"], _persistent_seen["miss"]
+    booked = {"program": "program", "outcome": None}
+    with _profiling.span("compile", lane, number=number) as sp:
+        try:
+            yield booked
+        finally:
+            if booked["outcome"] is None:
+                booked["outcome"] = (
+                    "persistent_miss" if _persistent_seen["miss"] > miss0
+                    else "persistent_hit" if _persistent_seen["hit"] > hit0
+                    else "miss")
+            sp.note = f"{booked['program']}:{booked['outcome']}"
+    _m_program_compile_seconds().labels(
+        program=booked["program"],
+        outcome=booked["outcome"]).inc(sp.seconds)
+
+
 def _record_step(path, seconds, first_run):
     """Book one step into the shared step/compile metrics, the step-time
     attribution layer (per-signature stats, MFU, flight recorder —
@@ -571,6 +638,7 @@ class BlockPlan:
                 f"fetch target(s) {bad_fetch} are not produced by this program "
                 f"(not an op output, feed, or scope variable)"
             )
+        self.name = jit_name(program, self.ops)
         wset = set(writes)
         self.donated_names = [n for n in scope_reads if n in wset]
         self.readonly_names = [n for n in scope_reads if n not in wset]
@@ -598,16 +666,26 @@ class BlockPlan:
     def make_body(self, mesh_axes=()):
         """fn(donated, readonly, feeds, step) -> (fetches, out_writes).
         Fetches cover jit_fetch_names only; host-op-produced fetches are
-        filled in by assemble_fetches after run_host_ops."""
+        filled in by assemble_fetches after run_host_ops.  The function
+        is named ``self.name`` (`jit_name`)."""
         fetch_names, write_names = self.jit_fetch_names, self.write_names
+        name = self.name
 
         def fn(donated, readonly, feeds, step):
-            env = self.trace_env(donated, readonly, feeds, step,
-                                 mesh_axes=mesh_axes)
+            import jax
+
+            # one scope around the whole step, not per op: every HLO
+            # op's metadata then starts with the program's name
+            with jax.named_scope(name):
+                env = self.trace_env(donated, readonly, feeds, step,
+                                     mesh_axes=mesh_axes)
             fetches = [env[n] for n in fetch_names]
             out_writes = {n: env[n] for n in write_names if n in env}
             return fetches, out_writes
 
+        # jax names the executable jit_<__name__>: what a device trace's
+        # "XLA Modules" line and the benchmark's idle gaps print
+        fn.__name__ = fn.__qualname__ = name
         return fn
 
     def run_host_ops(self, scope, place=None, feeds=None):
@@ -649,6 +727,7 @@ def _book_persistent_cache(event, **_kw):
     result = {"/jax/compilation_cache/cache_hits": "hit",
               "/jax/compilation_cache/cache_misses": "miss"}.get(event)
     if result:
+        _persistent_seen[result] += 1
         _m_cache().labels(path="xla_persistent", result=result).inc()
 
 
@@ -884,7 +963,8 @@ class _CompiledBlock(_JitExecutable):
         # misattribution is what this layer exists to remove.  Phase
         # brackets of the same name accumulate, so fetch_sync spans both
         # the scope write-back (inside timed_run) and the host tail.
-        with _profiling.step_phases("single", self.label) as ph:
+        with _profiling.step_phases("single", self.label,
+                                    number=step) as ph:
             with _prof.timed_run(self.label, self._prof_state) as timer:
                 with ph.phase("feed_prep"):
                     # pre-stage host ops (distributed lookup/prefetch)
@@ -928,6 +1008,11 @@ class _CompiledBlock(_JitExecutable):
                 # program order
                 self.plan.run_host_ops(scope, self.place, feeds=feeds)
                 out = self.plan.assemble_fetches(fetches, scope)
+                # release the step's argument arrays inside the span (the
+                # donated ones are dead buffers by now): several hundred
+                # array objects cost ms to free, which would otherwise
+                # fall between this recorder and the caller's clock
+                del donated, readonly, feed_vals
         return out
 
 def _check_nan_inf(plan, label, out_writes, fetches):
@@ -984,6 +1069,7 @@ def chain_step_body(body, n_steps, stacked_feed):
         return body(d, readonly, feed_at(feeds, n - 1),
                     step0 + np.uint32(n - 1))
 
+    chained.__name__ = chained.__qualname__ = CHAIN_NAME
     return chained
 
 
@@ -1040,7 +1126,8 @@ class _CompiledChain(_JitExecutable):
 
         from . import profiler as _prof
 
-        with _profiling.step_phases("chain", self.label) as ph:
+        with _profiling.step_phases("chain", self.label,
+                                    number=step) as ph:
             with _prof.timed_run(self.label, self._prof_state) as timer:
                 with ph.phase("feed_prep"):
                     device = self.place.jax_device()
@@ -1079,6 +1166,7 @@ class _CompiledChain(_JitExecutable):
                     _check_nan_inf(self.plan, self.label, out_writes,
                                    fetches)
                 out = self.plan.assemble_fetches(fetches, scope)
+                del donated, readonly, feed_vals  # freed inside the span
         return out
 
 
@@ -1285,48 +1373,28 @@ class Executor:
         if program is None:
             program = framework.default_main_program()
         scope = scope or global_scope()
-        feed = self._coerce_feed(program, feed)
-        fetch_list = list(fetch_list or [])
-        fetch_names = [f.name if isinstance(f, Variable) else f for f in fetch_list]
 
         import time as _time
 
-        block = program.global_block()
-        self._graph_passes(program, fetch_names)  # before cache key
-        sent = self._health(program)  # may transpile: before cache key
-        key = self._cache_key(program, feed, fetch_names)
-        cb = self._cache.get(key)
-        if cb is None:
-            from . import profiler as _prof
+        from paddle_tpu.observability import profiling as _profiling
 
-            # static verification rides the compile boundary: pay it
-            # once per executable, never on steady-state steps
-            self._verify_preflight(program, feed, fetch_names, scope)
-            if sent is not None:
-                sent.ensure_state(scope)  # before BlockPlan scope checks
-            t0 = _time.perf_counter()  # observability: allow
-            cb = _CompiledBlock(program, block, feed.keys(), fetch_names, self.place, scope)
-            self._cache[key] = cb
-            self._cache[(key, "pin")] = program  # hold program ref: id() stays unique
-            trace_s = _time.perf_counter() - t0  # observability: allow
-            _prof._record("trace", cb.label, trace_s)
-            _m_compile_seconds().labels(path="single",
-                                        phase="trace").inc(trace_s)
-            # AOT path (FLAGS_aot_cache_dir): a deserialized executable
-            # books "aot_hit" — NOT "miss" — and its first run carries
-            # no compile, so the jit_first_run booking is skipped too
-            # (the zero-compile-restart contract the decode lane's
-            # acceptance measures).  An AOT save still counts as a miss
-            # (the compile ran, booked under phase="aot_compile").
-            aot = cb.setup_aot(scope, feed)
-            if aot == "aot_hit":
-                _m_cache().labels(path="single", result="aot_hit").inc()
-            else:
-                _m_cache().labels(path="single", result="miss").inc()
-            if aot is not None:
-                cb._obs_ran = True  # first run has no lazy compile
-        else:
-            _m_cache().labels(path="single", result="hit").inc()
+        step = self._step
+        # everything between the caller and the recorder that
+        # _CompiledBlock.run opens: feed coercion, graph passes, health,
+        # the cache key and the cache hit
+        with _profiling.span("lookup", "single", number=step):
+            feed = self._coerce_feed(program, feed)
+            fetch_list = list(fetch_list or [])
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
+            block = program.global_block()
+            self._graph_passes(program, fetch_names)  # before cache key
+            sent = self._health(program)  # may transpile: before cache key
+            key = self._cache_key(program, feed, fetch_names)
+            cb = self._cache.get(key)
+            if cb is not None:
+                _m_cache().labels(path="single", result="hit").inc()
+
         # run timing ("compile+run" on a signature's first run — jit compiles
         # lazily — then "run") is recorded inside _CompiledBlock.run so every
         # execution path shares the instrumentation
@@ -1341,9 +1409,47 @@ class Executor:
 
         from paddle_tpu.health import run_guarded
 
-        fetches = run_guarded(sent, scope, fetch_names, attempt)
+        if cb is not None:
+            fetches = run_guarded(sent, scope, fetch_names, attempt)
+        else:
+            from . import profiler as _prof
+
+            # a signature's first run, from the miss to the end of the
+            # first execution, is one `compile` span
+            with compile_span("single", number=step) as booked:
+                # static verification rides the compile boundary: pay it
+                # once per executable, never on steady-state steps
+                self._verify_preflight(program, feed, fetch_names, scope)
+                if sent is not None:
+                    sent.ensure_state(scope)  # before BlockPlan scope checks
+                t0 = _time.perf_counter()  # observability: allow
+                cb = _CompiledBlock(program, block, feed.keys(), fetch_names, self.place, scope)
+                self._cache[key] = cb
+                self._cache[(key, "pin")] = program  # hold program ref: id() stays unique
+                booked["program"] = cb.plan.name
+                trace_s = _time.perf_counter() - t0  # observability: allow
+                _prof._record("trace", cb.label, trace_s)
+                _m_compile_seconds().labels(path="single",
+                                            phase="trace").inc(trace_s)
+                # AOT path (FLAGS_aot_cache_dir): a deserialized executable
+                # books "aot_hit" — NOT "miss" — and its first run carries
+                # no compile, so the jit_first_run booking is skipped too
+                # (the zero-compile-restart contract the decode lane's
+                # acceptance measures).  An AOT save still counts as a miss
+                # (the compile ran, booked under phase="aot_compile").
+                aot = cb.setup_aot(scope, feed)
+                if aot == "aot_hit":
+                    _m_cache().labels(path="single", result="aot_hit").inc()
+                    booked["outcome"] = "aot_hit"
+                else:
+                    _m_cache().labels(path="single", result="miss").inc()
+                if aot is not None:
+                    cb._obs_ran = True  # first run has no lazy compile
+                fetches = run_guarded(sent, scope, fetch_names, attempt)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            # where a lane that fetches really waits for the device
+            with _profiling.span("fetch_wait", "single", number=step):
+                return [np.asarray(f) for f in fetches]
         return fetches
 
     def run_steps(
@@ -1382,47 +1488,34 @@ class Executor:
         program = program if program is not None \
             else framework.default_main_program()
         scope = scope or global_scope()
-        feed = self._coerce_feed(program, feed)
-        if stacked_feed:
-            bad = {k: np.shape(v) for k, v in feed.items()
-                   if not np.shape(v) or np.shape(v)[0] != int(n_steps)}
-            if bad:
-                raise ValueError(
-                    f"stacked_feed arrays need a leading [{n_steps}] "
-                    f"axis; got {bad}")
-        fetch_list = list(fetch_list or [])
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in fetch_list]
-        # FLAT key extension: key[0] stays id(program) so compiled_for()
-        # (and anything else scanning the cache by program) sees chain
-        # executables too
-        self._graph_passes(program, fetch_names)  # before cache key
-        sent = self._health(program)  # may transpile: before cache key
-        key = self._cache_key(program, feed, fetch_names) + (
-            "chain", int(n_steps), bool(stacked_feed))
         import time as _time
 
-        cc = self._cache.get(key)
-        if cc is None:
-            from . import profiler as _prof
+        from paddle_tpu.observability import profiling as _profiling
 
-            _m_cache().labels(path="chain", result="miss").inc()
-            self._verify_preflight(program, feed, fetch_names, scope,
-                                   stacked_feed=bool(stacked_feed))
-            if sent is not None:
-                sent.ensure_state(scope)
-            t0 = _time.perf_counter()  # observability: allow
-            cc = _CompiledChain(program, program.global_block(),
-                                feed.keys(), fetch_names, self.place,
-                                scope, int(n_steps), bool(stacked_feed))
-            self._cache[key] = cc
-            self._cache[(key, "pin")] = program
-            trace_s = _time.perf_counter() - t0  # observability: allow
-            _prof._record("trace", cc.label, trace_s)
-            _m_compile_seconds().labels(path="chain",
-                                        phase="trace").inc(trace_s)
-        else:
-            _m_cache().labels(path="chain", result="hit").inc()
+        step = self._step
+        with _profiling.span("lookup", "chain", number=step):
+            feed = self._coerce_feed(program, feed)
+            if stacked_feed:
+                bad = {k: np.shape(v) for k, v in feed.items()
+                       if not np.shape(v) or np.shape(v)[0] != int(n_steps)}
+                if bad:
+                    raise ValueError(
+                        f"stacked_feed arrays need a leading [{n_steps}] "
+                        f"axis; got {bad}")
+            fetch_list = list(fetch_list or [])
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
+            # FLAT key extension: key[0] stays id(program) so
+            # compiled_for() (and anything else scanning the cache by
+            # program) sees chain executables too
+            self._graph_passes(program, fetch_names)  # before cache key
+            sent = self._health(program)  # may transpile: before cache key
+            key = self._cache_key(program, feed, fetch_names) + (
+                "chain", int(n_steps), bool(stacked_feed))
+            cc = self._cache.get(key)
+            if cc is not None:
+                _m_cache().labels(path="chain", result="hit").inc()
+
         # sentinel at CHAIN granularity: a mid-chain bad step was masked
         # in-graph; post_step books it via the cumulative counter, and a
         # rollback restores the pre-CHAIN state and replays the chain
@@ -1437,10 +1530,34 @@ class Executor:
 
         from paddle_tpu.health import run_guarded
 
-        fetches = run_guarded(sent, scope, fetch_names, attempt,
-                              chain=int(n_steps) > 1)
+        if cc is not None:
+            fetches = run_guarded(sent, scope, fetch_names, attempt,
+                                  chain=int(n_steps) > 1)
+        else:
+            from . import profiler as _prof
+
+            with compile_span("chain", number=step) as booked:
+                _m_cache().labels(path="chain", result="miss").inc()
+                self._verify_preflight(program, feed, fetch_names, scope,
+                                       stacked_feed=bool(stacked_feed))
+                if sent is not None:
+                    sent.ensure_state(scope)
+                t0 = _time.perf_counter()  # observability: allow
+                cc = _CompiledChain(program, program.global_block(),
+                                    feed.keys(), fetch_names, self.place,
+                                    scope, int(n_steps), bool(stacked_feed))
+                self._cache[key] = cc
+                self._cache[(key, "pin")] = program
+                booked["program"] = CHAIN_NAME
+                trace_s = _time.perf_counter() - t0  # observability: allow
+                _prof._record("trace", cc.label, trace_s)
+                _m_compile_seconds().labels(path="chain",
+                                            phase="trace").inc(trace_s)
+                fetches = run_guarded(sent, scope, fetch_names, attempt,
+                                      chain=int(n_steps) > 1)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with _profiling.span("fetch_wait", "chain", number=step):
+                return [np.asarray(f) for f in fetches]
         return fetches
 
     # ------------------------------------------------------------------

@@ -310,14 +310,15 @@ _DEFS = {
     "FLAGS_health_loss_scale_init": (65536.0, float, True),
     "FLAGS_health_scale_growth_steps": (1000, int, True),
     # step-time attribution (observability/profiling.py,
-    # docs/OBSERVABILITY.md "Step-time attribution").  profile_phases
-    # decomposes every executed step into feed_prep / dispatch /
-    # device_wait / fetch_sync phase spans (pt_step_phase_seconds +
-    # chrome-trace phase spans).  Off by default: the device_wait phase
-    # needs a per-step block_until_ready, which serializes the
-    # donated-buffer dispatch pipelining the fetch-free training loop
-    # (and the benched methodology) relies on — opt in per run, and the
-    # PT_BENCH_PHASES A/B rung gates its overhead on the syncfetch lane.
+    # docs/OBSERVABILITY.md "Step-time attribution").  The feed_prep /
+    # dispatch / device_wait / fetch_sync phase spans of every executed
+    # step are ALWAYS recorded (pt_step_phase_seconds, the span ring);
+    # profile_phases keeps only what changes timing: the per-step
+    # block_until_ready that makes device_wait read real device time.
+    # Off by default: that block serializes the donated-buffer dispatch
+    # pipelining the fetch-free training loop (and the benched
+    # methodology) relies on — opt in per run, and the PT_BENCH_PHASES
+    # A/B rung gates its overhead on the syncfetch lane.
     "FLAGS_profile_phases": (False, _parse_bool, True),
     # flight recorder: bounded ring of the last N steps' attribution
     # records (phase breakdowns, queue depth, health events), dumped as

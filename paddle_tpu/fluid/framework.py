@@ -541,6 +541,9 @@ class Program:
         self._seed = None
         self.random_seed = 0
         self._is_test = False
+        # what the executor compiles this program as (``jit_<name>`` in a
+        # device trace); None lets it derive train_step/startup/program
+        self.name = None
         # parity knobs referenced by user scripts
         self._fleet_opt = None
         self.op_role_var = []
@@ -591,6 +594,7 @@ class Program:
             _seed=self._seed,
             random_seed=self.random_seed,
             _is_test=for_test,
+            name=self.name,
             _fleet_opt=None,
             op_role_var=[],
             _params_grads=list(self._params_grads),

@@ -135,12 +135,22 @@ def start_profiler(state="All", tracer_option=None, trace_dir=None):
         import jax
 
         jax.profiler.start_trace(trace_dir)
+    # the one span path: every observability.profiling span (executor
+    # phases, the decode scheduler's turn) lands in this session's
+    # events, and with a trace_dir also in the xplane as a
+    # TraceAnnotation above the device ops
+    from paddle_tpu.observability import profiling as _profiling
+
+    _profiling.set_span_export(True, trace_dir is not None)
 
 
 def stop_profiler(sorted_key=None, profile_path=None):
     if not _STATE["enabled"]:
         return
     _STATE["enabled"] = False
+    from paddle_tpu.observability import profiling as _profiling
+
+    _profiling.set_span_export(False, False)
     if _STATE["trace_dir"] is not None:
         import jax
 

@@ -65,4 +65,7 @@ def wrap_body(program, body):
             gated_writes[name] = jnp.where(found, ov, nv)
         return fetches, gated_writes
 
+    # the executable is named after the function jax.jit is handed: keep
+    # the body's (BlockPlan.make_body: train_step, decode_step, ...)
+    gated.__name__ = gated.__qualname__ = getattr(body, "__name__", "gated")
     return gated

@@ -101,6 +101,7 @@ def primitive_call(kernel, spec, *operands):
             kernel, grid_spec=grid_spec,
             out_shape=out_shape[0] if single else out_shape,
             interpret=spec.interpret,
+            name=spec.name,
         )(*operands)
     return pl.pallas_call(                             # kernel: allow
         kernel,
@@ -110,6 +111,7 @@ def primitive_call(kernel, spec, *operands):
         out_shape=out_shape[0] if single else out_shape,
         scratch_shapes=scratch,
         interpret=spec.interpret,
+        name=spec.name,
     )(*operands)
 
 

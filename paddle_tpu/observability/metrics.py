@@ -24,6 +24,8 @@ and `native`, which must stay importable without jax.
 
 from __future__ import annotations
 
+import bisect
+
 import math
 import threading
 
@@ -100,11 +102,7 @@ class _Child:
         with self._family._lock:
             # first bucket whose upper bound contains v (le semantics);
             # falls through to the +Inf bucket
-            idx = len(self._family.buckets)
-            for i, ub in enumerate(self._family.buckets):
-                if v <= ub:
-                    idx = i
-                    break
+            idx = bisect.bisect_left(self._family.buckets, v)
             self._bucket_counts[idx] += 1
             self._sum += v
             self._count += 1

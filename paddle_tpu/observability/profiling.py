@@ -17,14 +17,27 @@ pairs anywhere else):
                  signature's first run), ``device_wait``
                  (`block_until_ready` delta = device execution the host
                  had to wait out), ``fetch_sync`` (scope write-back +
-                 host ops).  Exported as
-                 ``pt_step_phase_seconds{phase,lane}`` histograms and
-                 per-phase chrome-trace spans (kind ``phase``) merged
-                 into the PT_TRACE timeline.  FLAGS_profile_phases
-                 gates the per-phase work (and the per-step
-                 `block_until_ready` the device_wait phase needs); with
-                 it off the recorder still times the step total so
-                 per-signature stats and the flight recorder stay live.
+                 host ops).  Phases are ALWAYS recorded and never block:
+                 FLAGS_profile_phases keeps only what changes timing,
+                 the per-step `block_until_ready` of ``device_wait``
+                 (without it that phase reads ~0 and the wait shows
+                 where the lane really fetches).  Exported as
+                 ``pt_step_phase_seconds{phase,lane}`` histograms and,
+                 while a fluid.profiler session is open, chrome-trace
+                 spans (kind ``phase``) on the PT_TRACE timeline.
+
+- spans          `span(name, lane)` is the one span primitive: the
+                 recorder's phases, the executor's ``lookup`` /
+                 ``compile`` / ``fetch_wait`` and the decode scheduler's
+                 turn are all `_PhaseSpan`s.  Each records name, lane,
+                 start and end (`time.perf_counter_ns`), its own id, the
+                 id of the span open around it on this thread (0 for a
+                 root), the turn or step number of its tree and an
+                 optional note, into a bounded ring (`spans()`,
+                 `SPAN_RING` entries) whose `span_clock()` pair lets a
+                 reader move it onto another clock
+                 (benchmark/readers/host_gap.py lays it on the device
+                 trace's).
 
 - MFU/roofline   `note_cost` (fed by `_JitExecutable.cost_analysis`) and
                  `note_collectives` (fed by compiled-HLO inspection)
@@ -67,6 +80,7 @@ fluid.flags and fluid.profiler are imported lazily inside functions.
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import re
@@ -79,7 +93,8 @@ from . import metrics as _metrics
 from . import tracing as _tracing
 
 __all__ = [
-    "step_phases", "NullRecorder", "note_step", "note_cost",
+    "step_phases", "NullRecorder", "span", "spans", "span_clock",
+    "set_span_export", "SPAN_RING", "note_step", "note_cost",
     "note_collectives",
     "note_health_event", "device_peaks", "roofline",
     "hlo_inventory", "hlo_collective_bytes", "hlo_collective_counts",
@@ -163,22 +178,132 @@ def _phases_enabled():
 
 _tls = threading.local()
 
+# The span ring.  32768 entries: one scheduler turn of the decode lane is
+# ~23 spans (9 of the scheduler, 7 of the executor under each of its two
+# runs) at ~5 turns a second (PERF.md §5, cell 2), so 60 s are ~7000
+# spans; the ring holds that 4.7 times over, and a training lane's ~60
+# spans a second for nine minutes.  An entry is the tuple
+# (name, lane, start_ns, end_ns, id, parent_id, number, note).
+SPAN_RING = 32768
+_ring = collections.deque(maxlen=SPAN_RING)
+_span_ids = itertools.count(1)
+# one reading of both clocks, so a reader can move the ring's
+# perf_counter_ns stamps onto the wall clock (or, through it, another)
+_clock_pair = (time.time_ns(), time.perf_counter_ns())
+# [chrome-trace export on, jax TraceAnnotation on] — set by
+# fluid.profiler.start_profiler / stop_profiler (set_span_export)
+_export = [False, False]
+
+
+def spans():
+    """The recorded spans, oldest first (a ring of the last ``SPAN_RING``
+    = 32768: over four minutes of the decode lane's ~115 spans a second,
+    so 60 s of cell 2 fit four times over): tuples ``(name, lane,
+    start_ns, end_ns, id, parent_id, number, note)`` on the
+    `time.perf_counter_ns` clock.  ``parent_id`` is 0 for a root; ``number`` is the turn or step
+    number of the span's tree (a child inherits its parent's)."""
+    return list(_ring)
+
+
+def span_clock():
+    """``(time.time_ns(), time.perf_counter_ns())`` read together once:
+    wall_ns = start_ns + (pair[0] - pair[1])."""
+    return _clock_pair
+
+
+def set_span_export(chrome, annotate):
+    """fluid.profiler's session switch: ``chrome`` records every span as
+    a chrome-trace event of kind ``phase``; ``annotate`` (a session with
+    a ``trace_dir``, host tracer on) also makes every span a
+    `jax.profiler.TraceAnnotation`, so XProf shows the turn above the
+    device ops.  Outside a session neither costs anything."""
+    _export[0], _export[1] = bool(chrome), bool(annotate)
+
+
+_phase_fam = [None, -1]  # the histogram family, the registry epoch it is of
+
+
+def _phase_child(name, lane):
+    """The histogram series of one (phase, lane), without the kwargs
+    `labels()` lookup on the hot path (the family's own child table is
+    the cache, so `clear()` and a registry reset stay honoured)."""
+    reg = _metrics.REGISTRY
+    if _phase_fam[1] != reg._epoch or _phase_fam[0] is None:
+        _phase_fam[0], _phase_fam[1] = _m_phase(), reg._epoch
+    fam = _phase_fam[0]
+    return (fam._children.get((name, lane))
+            or fam.labels(phase=name, lane=lane))
+
 
 class _PhaseSpan:
-    __slots__ = ("_rec", "_name", "_t0")
+    """One host span.  Never blocks, always recorded."""
 
-    def __init__(self, rec, name):
+    __slots__ = ("_rec", "name", "lane", "id", "parent", "number", "note",
+                 "t0", "t1", "_ann")
+
+    def __init__(self, name, lane, number=None, rec=None):
         self._rec = rec
-        self._name = name
+        self.name = name
+        self.lane = lane
+        self.number = number
+        self.note = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self.id = next(_span_ids)
+        if stack:
+            top = stack[-1]
+            self.parent, self.number = top.id, top.number
+        else:
+            self.parent = 0
+        stack.append(self)
+        self._ann = None
+        if _export[1]:
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, et, ev, tb):
-        dur = time.perf_counter() - self._t0
-        self._rec._spans.append((self._name, self._t0, dur))
+        self.t1 = t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        if _tls.stack:  # empty only after a reset() under an open span
+            _tls.stack.pop()
+        _ring.append((self.name, self.lane, self.t0, t1, self.id,
+                      self.parent, self.number, self.note))
+        if self._rec is not None:
+            # the recorder books its phases once a step, summed by name
+            self._rec._spans.append(self)
+        else:
+            _phase_child(self.name, self.lane).observe((t1 - self.t0) / 1e9)
+        if _export[0]:
+            _chrome_record(self)
         return False
+
+    @property
+    def seconds(self):
+        return (self.t1 - self.t0) / 1e9
+
+
+def _chrome_record(sp):
+    # only reached inside a fluid.profiler session (set_span_export)
+    from paddle_tpu.fluid import profiler as _prof
+
+    _prof._record("phase", f"{sp.lane}:{sp.name}", sp.seconds,
+                  start=sp.t0 / 1e9)
+
+
+def span(name, lane, number=None):
+    """A host span outside a `step_phases` recorder (the executor's
+    ``lookup`` / ``compile`` / ``fetch_wait``, the decode scheduler's
+    turn).  ``number`` is the turn or step number of a ROOT span's tree;
+    a span opened inside another takes its parent's."""
+    return _PhaseSpan(name, lane, number)
 
 
 class _NullSpan:
@@ -193,36 +318,41 @@ _NULL_SPAN = _NullSpan()
 
 
 class StepPhaseRecorder:
-    """Times one executed step.  With FLAGS_profile_phases on, `phase()`
-    brackets record the four named sub-phases and `wait()` blocks on the
-    dispatched arrays so the device_wait phase measures real device
-    time; with it off both are no-ops and only the step total (and the
-    signature label) is deposited for `note_step` — per-signature stats
-    and the flight recorder keep working at zero sync cost, preserving
-    async dispatch pipelining."""
+    """Times one executed step.  `phase()` brackets always record the
+    named sub-phases as spans (ring + ``pt_step_phase_seconds``) and
+    never block.  FLAGS_profile_phases (``detailed``) keeps only what
+    changes timing: `wait()` then blocks on the dispatched arrays so the
+    device_wait phase measures real device time; with it off `wait()` is
+    a no-op, preserving async dispatch pipelining, and the step total
+    (and the signature label) is what `note_step` gets as wall time."""
 
-    __slots__ = ("lane", "label", "detailed", "_spans", "_t0")
+    __slots__ = ("lane", "label", "detailed", "number", "blocked",
+                 "_spans", "_t0")
 
-    def __init__(self, lane, label, detailed):
+    def __init__(self, lane, label, detailed, number=None):
         self.lane = lane
         self.label = label
         self.detailed = detailed
-        self._spans = []  # (phase, start, dur)
+        self.number = number
+        # True once the recorder's interval is known to hold the device's
+        # completion (wait() blocked, or the lane fetched inside it):
+        # only then may note_step read device time off it
+        self.blocked = False
+        self._spans = []
 
     def __enter__(self):
         self._t0 = time.perf_counter()
         return self
 
     def phase(self, name):
-        if not self.detailed:
-            return _NULL_SPAN
-        return _PhaseSpan(self, name)
+        return _PhaseSpan(name, self.lane, self.number, self)
 
     def wait(self, arrays):
         """Block until the dispatched device work completes — called
-        inside the ``device_wait`` phase bracket.  A no-op with phases
-        off: the per-step sync would serialize the donated-buffer
-        dispatch pipeline the fetch-free training loop relies on."""
+        inside the ``device_wait`` phase bracket.  A no-op with
+        FLAGS_profile_phases off: the per-step sync would serialize the
+        donated-buffer dispatch pipeline the fetch-free training loop
+        relies on."""
         if not self.detailed:
             return
         try:
@@ -231,30 +361,22 @@ class StepPhaseRecorder:
             jax.block_until_ready(arrays)
         except Exception:  # non-jax values (host-op outputs)
             pass
+        self.blocked = True
 
     def __exit__(self, et, ev, tb):
         if et is not None:
             return False
         total = time.perf_counter() - self._t0
-        phases = {}
-        for name, _start, dur in self._spans:
-            phases[name] = phases.get(name, 0.0) + dur
-        if self._spans:
-            try:
-                from paddle_tpu.fluid import profiler as _prof
-
-                for name, start, dur in self._spans:
-                    _prof._record("phase", f"{self.lane}:{name}", dur,
-                                  start=start)
-            except Exception:
-                pass
-            fam = _m_phase()
-            for name, dur in phases.items():
-                fam.labels(phase=name, lane=self.lane).observe(dur)
+        phases, starts = {}, {}
+        for sp in self._spans:
+            phases[sp.name] = phases.get(sp.name, 0.0) + sp.seconds
+            starts.setdefault(sp.name, sp.t0 / 1e9 - self._t0)
+        for name, dur in phases.items():
+            _phase_child(name, self.lane).observe(dur)
         # hand the breakdown to note_step (same thread, the lane books
         # its pt_step_seconds sample immediately after run() returns)
-        _tls.pending = (self.lane, self.label,
-                        phases if self._spans else None, total)
+        _tls.pending = (self.lane, self.label, phases or None, total,
+                        self.blocked, self._t0, starts)
         return False
 
 
@@ -266,6 +388,7 @@ class NullRecorder:
     the way it is already kept out of the latency SLO histogram)."""
 
     detailed = False
+    blocked = False
 
     def __enter__(self):
         return self
@@ -280,13 +403,15 @@ class NullRecorder:
         pass
 
 
-def step_phases(lane, label, enabled=True):
+def step_phases(lane, label, enabled=True, number=None):
     """The one entry point every execution lane wraps its dispatch in.
     ``enabled=False`` returns the NullRecorder (warmup/precompile
-    dispatches that must not enter the attribution stats)."""
+    dispatches that must not enter the attribution stats).  ``number``
+    is the step number its phases carry when no span is open around
+    them."""
     if not enabled:
         return NullRecorder()
-    return StepPhaseRecorder(lane, label, _phases_enabled())
+    return StepPhaseRecorder(lane, label, _phases_enabled(), number)
 
 
 def _pop_pending(lane):
@@ -618,9 +743,9 @@ def note_step(lane, seconds=None, first_run=False):
     `step_phases(...)` recorder deposited on this thread (if any);
     ``seconds=None`` uses the recorder's own step total."""
     pending = _pop_pending(lane)
-    label, phases = lane, None
+    label, phases, blocked, t0, starts = lane, None, True, None, None
     if pending is not None:
-        _plane, label, phases, total = pending
+        _plane, label, phases, total, blocked, t0, starts = pending
         if seconds is None:
             seconds = total
     if seconds is None:
@@ -638,15 +763,23 @@ def note_step(lane, seconds=None, first_run=False):
             prev = s["ema_step_s"]
             s["ema_step_s"] = (seconds if prev is None else
                                prev + (1.0 - _EMA_BETA) * (seconds - prev))
-            device_s = seconds
-            if phases:
-                # device time = dispatch + device_wait: the span from
-                # handing the step to jax to the computation's completion
-                device_s = (phases.get("dispatch", 0.0)
-                            + phases.get("device_wait", 0.0)) or seconds
-            s["device_s_sum"] += device_s
-            s["device_steps"] += 1
-            _update_mfu(s)
+            if blocked:
+                # device time needs an interval that held the device's
+                # completion: a caller's own measurement (no recorder),
+                # a blocked device_wait, or a lane that fetched inside
+                # its recorder.  An async step's wall time is only its
+                # enqueue (41.5 ms of a 128 ms BERT step, PERF.md), and
+                # pt_mfu read off it over-states three-fold: leave the
+                # gauges unset instead
+                device_s = seconds
+                if phases:
+                    # dispatch + device_wait: from handing the step to
+                    # jax to the computation's completion
+                    device_s = (phases.get("dispatch", 0.0)
+                                + phases.get("device_wait", 0.0)) or seconds
+                s["device_s_sum"] += device_s
+                s["device_steps"] += 1
+                _update_mfu(s)
             # slow-step z-score over the per-lane rolling EMA (the PR-10
             # EMA machinery applied to wall time)
             zthresh = float(_flag("profile_slow_step_zscore", 8.0) or 0)
@@ -667,6 +800,10 @@ def note_step(lane, seconds=None, first_run=False):
            "seconds": round(seconds, 6), "first_run": bool(first_run)}
     if phases:
         rec["phases"] = {k: round(v, 6) for k, v in phases.items()}
+        # where each phase began, seconds after the step's own start
+        # (perf_counter ``t0``): a postmortem can lay the step out
+        rec["t0"] = round(t0, 6)
+        rec["phase_starts"] = {k: round(v, 6) for k, v in starts.items()}
     qd = _queue_depth_sample()
     if qd is not None:
         rec["prefetch_queue_depth"] = qd
@@ -911,3 +1048,5 @@ def reset():
         _lane_ema.clear()
     _flight = FlightRecorder()
     _tls.pending = None
+    _tls.stack = []
+    _ring.clear()

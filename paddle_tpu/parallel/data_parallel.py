@@ -639,14 +639,25 @@ class DataParallelRunner:
         from paddle_tpu.fluid.executor import (_m_cache, _m_compile_seconds,
                                                _record_step)
 
+        from paddle_tpu.observability import profiling as _profiling
+
         scope = scope or ex.global_scope()
-        feed = executor._coerce_feed(self.program, feed or {})
-        fetch_names = [f.name if not isinstance(f, str) else f for f in (fetch_list or [])]
-        for k, v in feed.items():
-            if np.shape(v) and np.shape(v)[0] % self.num_devices != 0:
-                raise ValueError(
-                    f"feed {k!r} batch {np.shape(v)[0]} not divisible by "
-                    f"{self.num_devices} devices")
+        step = executor._step
+        with _profiling.span("lookup", "dp", number=step):
+            feed = executor._coerce_feed(self.program, feed or {})
+            fetch_names = [f.name if not isinstance(f, str) else f
+                           for f in (fetch_list or [])]
+            for k, v in feed.items():
+                if np.shape(v) and np.shape(v)[0] % self.num_devices != 0:
+                    raise ValueError(
+                        f"feed {k!r} batch {np.shape(v)[0]} not divisible by "
+                        f"{self.num_devices} devices")
+            cb = None
+            if self._gspmd_exec is None:
+                key = self._cache_key(feed, fetch_names)
+                cb = self._cache.get(key)
+                if cb is not None:
+                    _m_cache().labels(path="dp", result="hit").inc()
         if self._gspmd_exec is not None:
             out = self._gspmd_exec.run(scope=scope, feed=feed,
                                        fetch_list=fetch_names,
@@ -654,19 +665,7 @@ class DataParallelRunner:
             executor._step += 1
             return out
         sent = self._sentinel
-        key = self._cache_key(feed, fetch_names)
-        cb = self._cache.get(key)
-        if cb is None:
-            _m_cache().labels(path="dp", result="miss").inc()
-            if sent is not None:
-                sent.ensure_state(scope)  # before BlockPlan scope checks
-            t0 = _time.perf_counter()  # observability: allow
-            cb = _ShardedBlock(self.program, feed.keys(), fetch_names, self.mesh, scope)
-            self._cache[key] = cb
-            _m_compile_seconds().labels(
-                path="dp", phase="trace").inc(_time.perf_counter() - t0)  # observability: allow
-        else:
-            _m_cache().labels(path="dp", result="hit").inc()
+
         def attempt():
             first_run = not getattr(cb, "_obs_ran", False)
             t0 = _time.perf_counter()  # observability: allow
@@ -680,9 +679,26 @@ class DataParallelRunner:
 
         from paddle_tpu.health import run_guarded
 
-        fetches = run_guarded(sent, scope, fetch_names, attempt)
+        if cb is not None:
+            fetches = run_guarded(sent, scope, fetch_names, attempt)
+        else:
+            # the signature's first run as one `compile` span: says of a
+            # slow set-up whether it was a compile, and whose
+            with ex.compile_span("dp", number=step) as booked:
+                _m_cache().labels(path="dp", result="miss").inc()
+                if sent is not None:
+                    sent.ensure_state(scope)  # before BlockPlan scope checks
+                t0 = _time.perf_counter()  # observability: allow
+                cb = _ShardedBlock(self.program, feed.keys(), fetch_names,
+                                   self.mesh, scope)
+                self._cache[key] = cb
+                booked["program"] = cb.plan.name
+                _m_compile_seconds().labels(
+                    path="dp", phase="trace").inc(_time.perf_counter() - t0)  # observability: allow
+                fetches = run_guarded(sent, scope, fetch_names, attempt)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with _profiling.span("fetch_wait", "dp", number=step):
+                return [np.asarray(f) for f in fetches]
         return fetches
 
     def _report_throughput(self, feed, step_s):
@@ -802,6 +818,8 @@ class _ShardedBlock(_JitExecutable):
                      {n: P() for n in self.write_names})
         sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                 out_specs=out_specs, check_vma=False)
+        # same naming rule as the single-device lane: jit_train_step
+        sharded.__name__ = sharded.__qualname__ = plan.name
         self._jitted = jax.jit(sharded, donate_argnums=(0,))
         self.mesh = mesh
         self.label = f"dp_block@{id(self):x}"
@@ -817,7 +835,7 @@ class _ShardedBlock(_JitExecutable):
         # step_phases outermost; timed_run keeps its historic region
         # (staging..scope-writes) so the "run" span never absorbs the
         # host RPC tail — fetch_sync brackets accumulate across both
-        with _profiling.step_phases("dp", self.label) as ph:
+        with _profiling.step_phases("dp", self.label, number=step) as ph:
             with _prof.timed_run(f"dp_block@{id(self):x}",
                                  self._prof_state) as timer:
                 with ph.phase("feed_prep"):
@@ -842,4 +860,5 @@ class _ShardedBlock(_JitExecutable):
                 # drop them
                 self.plan.run_host_ops(scope)
                 out = self.plan.assemble_fetches(fetches, scope)
+                del donated, readonly  # freed inside the span
         return out

@@ -34,6 +34,13 @@ Scheduling loop (one scheduler thread per engine):
   3. run ONE decode step over the active slots; finished sequences
      (eos / max_new_tokens) resolve their futures and free pages+slot
 
+Every iteration is one ``turn`` span (observability/profiling.py, lane
+``decode``) whose children name where the host's time goes:
+``prefill.pages``, ``prefill.feed_build``, ``prefill.run``, ``admit``,
+``decode.pages``, ``decode.feed_build``, ``decode.run``, ``emit``; the
+executor's own phases are children of the two ``*.run`` spans, which also
+feed ``pt_decode_step_seconds`` and ``pt_decode_phase_seconds_total``.
+
 Eviction under pool pressure: when a page allocation fails, the
 YOUNGEST other live sequence is evicted — its pages return to the pool,
 its request re-queues for re-prefill of prompt + already-generated
@@ -57,6 +64,7 @@ import time
 
 import numpy as np
 
+from paddle_tpu.observability import profiling as _profiling
 from paddle_tpu.observability import reqtrace as _reqtrace
 
 from .errors import PoolExhaustedError, ServingOverloadError
@@ -107,6 +115,53 @@ def _m_prefill_chunks():
         "pt_decode_prefill_chunks_total",
         "Prefill chunk executions (a P-token prompt is "
         "ceil(P/chunk) of these)", labels=("engine",))
+
+
+def _m_turns():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_turns_total",
+        "Scheduler turns run (<=1 prefill chunk, admissions, <=1 decode "
+        "step each)", labels=("engine",))
+
+
+def _m_turn_seconds():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_turn_seconds_total",
+        "Wall seconds of scheduler turns by part: prefill_run and "
+        "decode_run are the two blocking executor runs (the *.run "
+        "spans), sched is the rest of the turn — pages, feed building, "
+        "admission, token emission", labels=("engine", "part"))
+
+
+def _m_queue_wait():
+    from paddle_tpu import observability as obs
+
+    return obs.histogram(
+        "pt_decode_queue_wait_seconds",
+        "Arrival to the start of the request's first prefill chunk",
+        labels=("engine",))
+
+
+def _m_ttft():
+    from paddle_tpu import observability as obs
+
+    return obs.histogram(
+        "pt_decode_ttft_seconds",
+        "Arrival to the request's first token (the prefill's seed "
+        "token; resumed requests have none)", labels=("engine",))
+
+
+def _m_token_gap():
+    from paddle_tpu import observability as obs
+
+    return obs.histogram(
+        "pt_decode_token_gap_seconds",
+        "Gap between two consecutive tokens of one request, as the "
+        "scheduler appends them", labels=("engine",))
 
 
 def _m_slot_occupancy():
@@ -175,7 +230,7 @@ class DecodeRequest:
 
     __slots__ = ("prompt", "max_new_tokens", "eos_id", "tenant", "future",
                  "seq_id", "generated", "prefilled", "t_arrival",
-                 "span", "t_first")
+                 "span", "t_first", "t_admit", "token_times")
 
     def __init__(self, prompt, max_new_tokens, eos_id, tenant):
         self.prompt = [int(t) for t in prompt]
@@ -190,6 +245,10 @@ class DecodeRequest:
         # token (sampled, not yet written to the pool)
         self.prefilled = 0       # tokens whose K/V sit in the pool
         self.t_arrival = time.monotonic()  # observability: allow
+        self.t_admit = None  # start of the first prefill chunk
+        # monotonic seconds, one per element of `generated` (a resumed
+        # request's replayed prefix carries its arrival time)
+        self.token_times = []
 
     @property
     def written_target(self):
@@ -306,6 +365,9 @@ class DecodeEngine:
                 pool_dtype=pool_dtype, attn_force=attn_force)
         self._dec_prog, self._dec_fetch = dec_prog, dec_tok.name
         self._pf_prog, self._pf_fetch = pf_prog, pf_tok.name
+        # what a device trace calls the two executables:
+        # jit_decode_step / jit_prefill_chunk
+        dec_prog.name, pf_prog.name = "decode_step", "prefill_chunk"
         if int8_weights:
             # opt-in dual-int8 WEIGHT storage (docs/KERNELS.md): both
             # programs route their matmul weights through
@@ -357,6 +419,9 @@ class DecodeEngine:
         self._exec_lock = threading.Lock()
         self._next_seq = 0
         self._steps = 0
+        self._turns = 0
+        # seconds inside the *.run spans of the turn under way
+        self._turn_run_s = 0.0
         self._tokens = 0
         self._evictions = 0
         # tenant labels this lane has minted on the cross-lane
@@ -385,6 +450,13 @@ class DecodeEngine:
         self._pages_gauge = _m_pages_in_use().labels(engine=e)
         self._evict_ctr = _m_evictions().labels(engine=e)
         self._depth = _m_queue_depth().labels(engine=e)
+        self._turn_ctr = _m_turns().labels(engine=e)
+        self._turn_part = {
+            p: _m_turn_seconds().labels(engine=e, part=p)
+            for p in ("sched", "prefill_run", "decode_run")}
+        self._queue_wait = _m_queue_wait().labels(engine=e)
+        self._ttft = _m_ttft().labels(engine=e)
+        self._token_gap = _m_token_gap().labels(engine=e)
         # the SAME cross-lane families engine.py owns: end-to-end decode
         # request latency (submit -> future resolve, exemplar-bearing)
         # and admitted-request counts, with this engine's name as model
@@ -448,6 +520,7 @@ class DecodeEngine:
                     f"decode: resume prefix ({len(prefix)}) exceeds "
                     f"max_new_tokens ({max_new_tokens})")
             req.generated = prefix
+            req.token_times = [req.t_arrival] * len(prefix)
             if req.done():
                 # the victim died with its stream already complete:
                 # nothing to decode — resolve without spending a prefill
@@ -704,22 +777,31 @@ class DecodeEngine:
         flushes queued work typed FIRST, so only already-running
         sequences consume further device steps."""
         self._check_metrics_epoch()
-        if not self._draining and self._drain_on_sigterm:
-            from paddle_tpu.distributed import elastic
+        self._turns += 1
+        self._turn_run_s = 0.0
+        with _profiling.span("turn", "decode", number=self._turns) as turn:
+            if not self._draining and self._drain_on_sigterm:
+                from paddle_tpu.distributed import elastic
 
-            if elastic.drain_requested():
+                if elastic.drain_requested():
+                    with self._cv:
+                        self._draining = True
+            if self._draining:
+                self._flush_for_drain()
+            self._prefill_one_chunk()
+            with _profiling.span("admit", "decode"):
+                self._admit_ready()
+            self._decode_step()
+            with _profiling.span("emit", "decode"):
+                self._occupancy.set(
+                    sum(s is not None for s in self._slots)
+                    / self.pool_slots)
+                self._pages_gauge.set(self.pool.pages_in_use())
                 with self._cv:
-                    self._draining = True
-        if self._draining:
-            self._flush_for_drain()
-        self._prefill_one_chunk()
-        self._admit_ready()
-        self._decode_step()
-        self._occupancy.set(
-            sum(s is not None for s in self._slots) / self.pool_slots)
-        self._pages_gauge.set(self.pool.pages_in_use())
-        with self._cv:
-            self._depth.set(len(self._queue))
+                    self._depth.set(len(self._queue))
+        self._turn_ctr.inc()
+        self._turn_part["sched"].inc(
+            max(turn.seconds - self._turn_run_s, 0.0))
 
     # -- eviction -----------------------------------------------------------
 
@@ -766,46 +848,63 @@ class DecodeEngine:
             req = self._queue[0] if self._queue else None
         if req is None:
             return
-        if req.seq_id is None:
-            req.seq_id = self._next_seq
-            self._next_seq += 1
-            self.pool.open_seq(req.seq_id)
-            self._live_order.append(req)
-        tokens = req.tokens_to_write()
-        total = len(tokens)
-        ctx_len = req.prefilled
-        valid = min(self.prefill_chunk, total - ctx_len)
-        self._ensure_pages(req, ctx_len + valid)
-        pgs = self.pool.page_size
-        table = self.pool.table(req.seq_id)
-        first_lp = ctx_len // pgs
-        write_pages = np.full(self.prefill_chunk // pgs, TRASH_PAGE,
-                              np.int32)
-        for j in range(len(write_pages)):
-            lp = first_lp + j
-            if lp < len(table) and lp * pgs < ctx_len + valid:
-                write_pages[j] = table[lp]
+        with _profiling.span("prefill.pages", "decode"):
+            if req.t_admit is None:
+                req.t_admit = time.monotonic()  # observability: allow — latency anchor
+                self._queue_wait.observe(
+                    max(req.t_admit - req.t_arrival, 0.0))
+            if req.seq_id is None:
+                req.seq_id = self._next_seq
+                self._next_seq += 1
+                self.pool.open_seq(req.seq_id)
+                self._live_order.append(req)
+            tokens = req.tokens_to_write()
+            total = len(tokens)
+            ctx_len = req.prefilled
+            valid = min(self.prefill_chunk, total - ctx_len)
+            self._ensure_pages(req, ctx_len + valid)
+            pgs = self.pool.page_size
+            table = self.pool.table(req.seq_id)
+            first_lp = ctx_len // pgs
+            write_pages = np.full(self.prefill_chunk // pgs, TRASH_PAGE,
+                                  np.int32)
+            for j in range(len(write_pages)):
+                lp = first_lp + j
+                if lp < len(table) and lp * pgs < ctx_len + valid:
+                    write_pages[j] = table[lp]
         next_tok = self._run_prefill_feed(
             tokens=tokens[ctx_len:ctx_len + valid], pos0=ctx_len,
             table_row=self.pool.padded_table(req.seq_id),
             write_pages=write_pages, valid=valid)
-        req.prefilled = ctx_len + valid
-        if req.prefilled == total:
-            if not req.generated:
-                # fresh prompt: the prefill's argmax seeds the stream
-                req.generated.append(int(next_tok))
-                self._tok_ctr.inc()
-                self._tokens += 1
-                # first token exists now: TTFT anchor (resumed requests
-                # arrive with a prefix, so theirs stays None — a replay
-                # is not a first-token experience)
-                req.t_first = time.monotonic()  # observability: allow
-            with self._cv:
-                # remove by identity, not popleft: an eviction during
-                # _ensure_pages may have re-queued a victim AHEAD of us
-                if req in self._queue:
-                    self._queue.remove(req)
-                self._ready.append(req)
+        with _profiling.span("emit", "decode"):
+            req.prefilled = ctx_len + valid
+            if req.prefilled == total:
+                if not req.generated:
+                    # fresh prompt: the prefill's argmax seeds the
+                    # stream.  First token exists now: TTFT anchor
+                    # (resumed requests arrive with a prefix, so theirs
+                    # stays None — a replay is not a first-token
+                    # experience)
+                    req.t_first = time.monotonic()  # observability: allow — latency anchor
+                    self._emit_token(req, int(next_tok), req.t_first)
+                    self._ttft.observe(
+                        max(req.t_first - req.t_arrival, 0.0))
+                with self._cv:
+                    # remove by identity, not popleft: an eviction during
+                    # _ensure_pages may have re-queued a victim AHEAD of us
+                    if req in self._queue:
+                        self._queue.remove(req)
+                    self._ready.append(req)
+
+    def _emit_token(self, req, token, now):
+        """Append one token to the request's stream, where every token
+        is appended: `generated` and `token_times` grow together."""
+        req.generated.append(token)
+        if req.token_times:
+            self._token_gap.observe(max(now - req.token_times[-1], 0.0))
+        req.token_times.append(now)
+        self._tok_ctr.inc()
+        self._tokens += 1
 
     def _prefill_feed(self, tokens, pos0, table_row, write_pages, valid):
         c = self.prefill_chunk
@@ -824,16 +923,18 @@ class DecodeEngine:
 
     def _run_prefill_feed(self, tokens, pos0, table_row, write_pages,
                           valid, warm=False):
-        feed = self._prefill_feed(tokens, pos0, table_row, write_pages,
-                                  valid)
+        with _profiling.span("prefill.feed_build", "decode"):
+            feed = self._prefill_feed(tokens, pos0, table_row, write_pages,
+                                      valid)
         with self._exec_lock:
-            t0 = time.monotonic()  # observability: allow
-            (out,) = self._exe.run(self._pf_prog, feed=feed,
-                                   fetch_list=[self._pf_fetch],
-                                   scope=self.scope)
+            with _profiling.span("prefill.run", "decode") as run:
+                (out,) = self._exe.run(self._pf_prog, feed=feed,
+                                       fetch_list=[self._pf_fetch],
+                                       scope=self.scope)
         if not warm:
-            self._phase["prefill"].inc(
-                max(time.monotonic() - t0, 0.0))  # observability: allow
+            self._phase["prefill"].inc(run.seconds)
+            self._turn_part["prefill_run"].inc(run.seconds)
+            self._turn_run_s += run.seconds
             self._chunks.inc()
         return int(np.asarray(out).reshape(-1)[0])
 
@@ -847,27 +948,27 @@ class DecodeEngine:
                     self._slots[i] = ready.popleft()
 
     def _decode_step(self):
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
-        # a request satisfiable by prefill alone (max_new_tokens=1, or
-        # eos as the seed token) finishes without a decode step
-        for i, req in list(active):
-            if req.done():
-                self._finish(i, req)
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
-        if not active:
-            return
-        for i, req in active:
-            # an EARLIER iteration's eviction may have already removed
-            # this request from its slot — touching its freed seq would
-            # re-allocate pages for a sequence that re-queued
-            if self._slots[i] is not req:
-                continue
-            self._ensure_pages(req, req.written_target + 1)
-        # re-read: _ensure_pages may have evicted some of `active`
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None]
+        with _profiling.span("decode.pages", "decode"):
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None]
+            # a request satisfiable by prefill alone (max_new_tokens=1,
+            # or eos as the seed token) finishes without a decode step
+            for i, req in list(active):
+                if req.done():
+                    self._finish(i, req)
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None]
+            for i, req in active:
+                # an EARLIER iteration's eviction may have already
+                # removed this request from its slot — touching its freed
+                # seq would re-allocate pages for a sequence that
+                # re-queued
+                if self._slots[i] is not req:
+                    continue
+                self._ensure_pages(req, req.written_target + 1)
+            # re-read: _ensure_pages may have evicted some of `active`
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None]
         if not active:
             return
         # deterministic mid-decode death (`replica_kill:` rules): raise
@@ -889,21 +990,20 @@ class DecodeEngine:
             if bspan is not None:
                 bspan.finish("error", error=e)
             raise
-        if bspan is not None:
-            for _, req in active:
-                if req.span is not None:
-                    req.span.link(bspan)
-            bspan.finish("ok")
-        now_done = []
-        for i, req in active:
-            nt = int(next_toks[i])
-            req.generated.append(nt)
-            self._tok_ctr.inc()
-            self._tokens += 1
-            if req.done():
-                now_done.append((i, req))
-        for i, req in now_done:
-            self._finish(i, req)
+        with _profiling.span("emit", "decode"):
+            if bspan is not None:
+                for _, req in active:
+                    if req.span is not None:
+                        req.span.link(bspan)
+                bspan.finish("ok")
+            now = time.monotonic()  # observability: allow — latency anchor
+            now_done = []
+            for i, req in active:
+                self._emit_token(req, int(next_toks[i]), now)
+                if req.done():
+                    now_done.append((i, req))
+            for i, req in now_done:
+                self._finish(i, req)
 
     def _decode_feed(self, active):
         ps, pgs = self.pool_slots, self.pool.page_size
@@ -924,16 +1024,18 @@ class DecodeEngine:
                 "dec_write_page": wpage, "dec_write_off": woff}
 
     def _run_decode_feed(self, active, warm=False):
-        feed = self._decode_feed(active)
+        with _profiling.span("decode.feed_build", "decode"):
+            feed = self._decode_feed(active)
         with self._exec_lock:
-            t0 = time.monotonic()  # observability: allow
-            (out,) = self._exe.run(self._dec_prog, feed=feed,
-                                   fetch_list=[self._dec_fetch],
-                                   scope=self.scope)
-            dt = max(time.monotonic() - t0, 0.0)  # observability: allow
+            with _profiling.span("decode.run", "decode") as run:
+                (out,) = self._exe.run(self._dec_prog, feed=feed,
+                                       fetch_list=[self._dec_fetch],
+                                       scope=self.scope)
         if not warm:
-            self._phase["decode"].inc(dt)
-            self._step_hist.observe(dt)
+            self._phase["decode"].inc(run.seconds)
+            self._step_hist.observe(run.seconds)
+            self._turn_part["decode_run"].inc(run.seconds)
+            self._turn_run_s += run.seconds
             self._steps += 1
         return np.asarray(out).reshape(-1)
 

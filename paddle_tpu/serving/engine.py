@@ -802,6 +802,10 @@ class _ModelLane:
             with ph.phase("dispatch"):
                 outputs = self.predictor.run_feed_dict(feed,
                                                        validate=False)
+            # a lane that fetches: the outputs are host arrays, so the
+            # dispatch phase held the device's completion (pt_mfu may
+            # read device time off this step)
+            ph.blocked = True
             # booked only after the run succeeds: a failed batch must
             # not count phantom warm/cold dispatches (each retry would
             # re-book "cold" and drag the /servez hit rate toward 0)

@@ -78,22 +78,49 @@ def test_recorder_deposits_phases_and_total(attribution):
     assert ("dispatch", "single") in keys
 
 
-def test_recorder_disabled_still_tracks_signature(attribution):
+def test_recorder_flag_off_records_phases_and_never_blocks(
+        attribution, monkeypatch):
+    """The flag keeps only the per-step block: with it off the phases
+    are still recorded (histogram, ring, flight record) and `wait()`
+    syncs nothing."""
+    import jax
+
+    blocks = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(x) or x)
     fluid.set_flags({"FLAGS_profile_phases": False})
+    fam = obs.REGISTRY.get("pt_step_phase_seconds")
+    if fam is not None:
+        fam.clear()
     with profiling.step_phases("dp", "sig-b") as ph:
         with ph.phase("dispatch"):
             pass
-        ph.wait(None)  # must be a no-op, not a device sync
+        with ph.phase("device_wait"):
+            ph.wait(jax.numpy.zeros(2))  # must be a no-op, not a sync
+    assert not blocks and ph.blocked is False
     profiling.note_step("dp", first_run=False)
     s = profiling.signature_stats()["sig-b"]
     assert s["steps"] == 1 and s["lane"] == "dp"
-    # no phase samples were booked for this lane
-    fam = obs.REGISTRY.get("pt_step_phase_seconds")
-    if fam is not None:
-        assert not any(k[1] == "dp" for k in fam._snapshot()["samples"])
-    # flight ring recorded the step without a phases dict
+    # an unblocked step's wall time is its enqueue: no device time, so
+    # pt_mfu cannot be refreshed from it
+    assert s["device_steps"] == 0
+    keys = set(obs.REGISTRY.get("pt_step_phase_seconds")
+               ._snapshot()["samples"])
+    assert {("dispatch", "dp"), ("device_wait", "dp")} <= keys
+    assert [sp[0] for sp in profiling.spans()] == ["dispatch",
+                                                   "device_wait"]
     rec = profiling.flight_recorder().snapshot()[-1]
-    assert rec["label"] == "sig-b" and "phases" not in rec
+    assert rec["label"] == "sig-b"
+    assert set(rec["phases"]) == set(rec["phase_starts"]) == {
+        "dispatch", "device_wait"}
+    # flag on: the same bracket blocks, and the step counts as measured
+    fluid.set_flags({"FLAGS_profile_phases": True})
+    with profiling.step_phases("dp", "sig-b") as ph:
+        with ph.phase("device_wait"):
+            ph.wait(jax.numpy.zeros(2))
+    assert len(blocks) == 1 and ph.blocked is True
+    profiling.note_step("dp", first_run=False)
+    assert profiling.signature_stats()["sig-b"]["device_steps"] == 1
 
 
 def test_note_step_first_run_excluded_from_ema(attribution):
@@ -337,7 +364,9 @@ def test_dp_phase_breakdown_sums_to_step_wall(attribution):
     phase_sum = sum(
         h["sum"] for key, h in
         snap["pt_step_phase_seconds"]["samples"].items()
-        if key[1] == "dp")
+        # the recorder's four phases: `lookup` and `fetch_wait` run
+        # before it opens and after it closes, outside pt_step_seconds
+        if key[1] == "dp" and key[0] in profiling.PHASES)
     step_hist = snap["pt_step_seconds"]["samples"][("dp",)]
     assert step_hist["count"] == 20
     # the acceptance bar: the named phases account for the step time —

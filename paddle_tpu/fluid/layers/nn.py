@@ -1418,11 +1418,12 @@ def moe_ffn(x, num_experts, d_ff, top_k=2, act="gelu", param_attr=None,
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start,
                     sm_scale=None, force=None, name=None):
-    """Attention of q [B, n_heads, T, d] against pool K/V read THROUGH a
-    per-sequence page table (decode serving lane, docs/SERVING.md
-    "Decode lane"; kernels/paged_attention.py — Pallas on TPU, lax
-    gather reference on CPU).  Query i of row b attends global key
-    positions j <= q_start[b] + i."""
+    """Attention of q [B, n_heads, T, d] against pool K/V
+    [num_pages, page_size, n_heads*d] read THROUGH a per-sequence page
+    table (decode serving lane, docs/SERVING.md "Decode lane";
+    kernels/paged_attention.py — Pallas on TPU, lax gather reference on
+    CPU).  Query i of row b attends global key positions
+    j <= q_start[b] + i."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}
@@ -1440,8 +1441,10 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start,
 
 
 def kv_cache_write(pages, new, page_idx, offset, name=None):
-    """Scatter one decode step's K or V rows (new [B, n, d]) into the
-    KV pool at per-slot (page_idx[b], offset[b]) coordinates; returns
+    """Scatter one decode step's K or V rows (new [B, n, d], flattened
+    to the pool's [B, n*d] rows) into the KV pool
+    [num_pages, page_size, n*d] at per-slot (page_idx[b], offset[b])
+    coordinates; returns
     the updated pool var (aliasing `pages` — XLA buffer donation, the
     pool is never doubled).  Payload dtype must match the pool dtype
     (trace-time error otherwise — the mixed-precision guard)."""
@@ -1493,9 +1496,11 @@ def ragged_attention(q, k, v, lengths, causal=False, sm_scale=None,
 def paged_attention_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo, v_scale,
                           page_table, q_start, sm_scale=None, force=None,
                           name=None):
-    """paged_attention over the dual-int8 pool (hi/lo int8 + per-vector
-    fp32 scale; docs/KERNELS.md "int8 KV") — dequant happens inside the
-    kernel, fp32 K/V never materializes outside VMEM."""
+    """paged_attention over the dual-int8 pool (hi/lo int8
+    [num_pages, page_size, n*d] + one fp32 scale a head_dim vector
+    [num_pages, page_size, n]; docs/KERNELS.md "int8 KV") — dequant
+    happens inside the kernel, fp32 K/V never materializes outside
+    VMEM."""
     helper = LayerHelper("paged_attention_quant", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}

@@ -6,9 +6,10 @@ scale/254 resolution, one fp32 scale per block, symmetric ±127) and
 applies it to STORAGE instead of the collective wire:
 
 - **KV cache** — :func:`quantize_lastdim` treats each ``head_dim``
-  vector as one block (scale per (page, slot, head)), so the pool vars
-  become hi/lo int8 ``[P, pgs, n, d]`` + scale fp32 ``[P, pgs, n, 1]``
-  and the paged kernel dequantizes per-block in VMEM
+  vector as one block (scale per (page, slot, head)); the write ops
+  flatten the heads of what it returns into the pool's lane dimension,
+  so the pool vars are hi/lo int8 ``[P, pgs, n*d]`` + scale fp32
+  ``[P, pgs, n]`` and the paged kernel dequantizes per-block in VMEM
   (primitives/paged.py paged_attention_quant).  Quantization happens
   ONCE at KV append (ops/decode_ops.py kv_cache_write_quant).
 - **Weights** — :func:`quantize_weight` keeps the flat

@@ -36,7 +36,16 @@ Two implementations (the shared resolve_mode dispatch):
 Shapes:
   q           [B, n_heads, T, d]   T = 1 (decode step) or the prefill
                                    chunk length
-  k/v_pages   [num_pages, page_size, n_heads, d]
+  k/v_pages   [num_pages, page_size, n_heads * d] — heads side by side
+              in the lane dimension (head h is lanes h*d..(h+1)*d).
+              The pool is stored, written and read in THIS shape and
+              no other: its default TPU layout is row-major with
+              (8, 128) tiles of (page_size, n_heads*d), which is what
+              the kernel's blocks address.  Declared with the heads
+              apart, [.., n_heads, 64], XLA:TPU puts the page index
+              minor-most (a 64-wide minor dimension would pad to 128
+              lanes) and every executable copies the whole pool into
+              the kernel's layout and back (PERF.md finding 4).
   page_table  [B, max_pages] int32 — physical page of each logical page
   q_start     [B] int32 — tokens already in the cache BEFORE this q
               block; query i of row b attends keys at global positions
@@ -75,13 +84,16 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
     pool is comparable with the whole-sequence program token for
     token."""
     b, n, t, d = q.shape
+    _check_pool_shapes("paged_attention", q, k_pages=k_pages,
+                       v_pages=v_pages)
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     l_max = max_pages * page_size
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
 
     def gathered(pages):
-        g = pages[page_table]                      # [B, MAXP, PGS, n, d]
+        # the heads come apart on the gathered pages, never on the pool
+        g = pages[page_table]                      # [B, MAXP, PGS, n*d]
         g = g.reshape(b, l_max, n, d)
         return jnp.transpose(g, (0, 2, 1, 3))      # [B, n, L, d]
 
@@ -103,15 +115,34 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 # physical page ids — the pool is never gathered into a copy.
 #
 # Mosaic tiling: a block's last two dims must be (8, 128)-divisible or
-# span the array's.  The pool is [P, page, n, d] with d = 64 on the real
-# models, so a per-head block (.., 1, d) cannot lower.  The pool is
-# VIEWED as [P, page, n*d] (a free row-major reshape — the layout in
-# serving/kv_pool.py is untouched) and each grid step takes one whole
-# page of every head, (1, page, n*d); the kernel walks the heads as
-# static d-wide lane slices of that block.
+# span the array's.  d = 64 on the real models, so a per-head block
+# (.., 1, d) cannot lower: each grid step takes one whole page of every
+# head, (1, page, n*d), of the pool AS IT IS STORED, and the kernel
+# walks the heads as static d-wide lane slices of that block.  Nothing
+# here reshapes the pool — a reshape of [P, page, n, d] to this shape
+# is "free" only in row-major order, which is not the layout XLA:TPU
+# gives the 4-D array (tests/test_mosaic_aot.py holds the compiled
+# executables to zero pool-shaped copies).
 # ---------------------------------------------------------------------------
 
 _SUBLANES = 8
+
+
+def _check_pool_shapes(op, q, scales=(), **pools):
+    """The pool has ONE shape, [num_pages, page_size, n_heads*head_dim]
+    (an int8 pool's scales [num_pages, page_size, n_heads]); a caller
+    that holds the heads apart is refused, not reshaped."""
+    n, d = q.shape[1], q.shape[3]
+    for what, x in pools.items():
+        lanes = n if what in scales else n * d
+        if x.ndim != 3 or x.shape[2] != lanes:
+            raise ValueError(
+                f"{op}: {what} has shape {tuple(x.shape)}, but the KV "
+                f"pool is stored [num_pages, page_size, {lanes}] — "
+                f"{n} heads side by side in the last dimension, so that "
+                f"no executable copies the pool between layouts; "
+                f"reshape a gathered page if the heads are needed "
+                f"apart, never the pool (docs/SERVING.md 'Decode lane')")
 
 
 def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
@@ -186,8 +217,8 @@ def _paged_kernel(page_table_ref, q_start_ref, q_ref, k_ref, v_ref, o_ref,
 def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
                 interpret):
     """Launch ``kernel`` over q [B, n, T, d] and ``pools`` — each a
-    [P, page, n, w] array (w = d for K/V payloads, 1 for the int8
-    scales) passed in its flat [P, page, n*w] view."""
+    [P, page, n*w] array (w = d for K/V payloads, 1 for the int8
+    scales), taken as stored."""
     b, n, t, d = q.shape
     page_size = pools[0].shape[1]
     max_pages = page_table.shape[1]
@@ -205,12 +236,11 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
         # page) pair maps to — the pool is never gathered
         return (pt[bi, pi], 0, 0)
 
-    flat = [x.reshape(x.shape[0], page_size, -1) for x in pools]
     spec = contract.make_spec(
         name,
         grid=(b, max_pages),
         in_specs=[Block((1, n, tp, d), q_map)]
-        + [Block((1, page_size, x.shape[2]), kv_map) for x in flat],
+        + [Block((1, page_size, x.shape[2]), kv_map) for x in pools],
         out_specs=[Block((1, n, tp, d), q_map)],
         out_shape=[((b, n, tp, d), q.dtype)],
         scratch=[
@@ -225,7 +255,7 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
         functools.partial(kernel, page_size=page_size, t=t, n=n, d=d,
                           n_blocks=max_pages, sm_scale=scale),
         spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32), q,
-        *flat)
+        *pools)
     return out[:, :, :t, :]
 
 
@@ -246,6 +276,8 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
     Pallas (interpret mode off-TPU, for tests); "reference" → XLA."""
     d = q.shape[-1]
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
+    _check_pool_shapes("paged_attention", q, k_pages=k_pages,
+                       v_pages=v_pages)
     if k_pages.dtype != v_pages.dtype:
         raise ValueError(
             f"paged_attention: K pool dtype {k_pages.dtype} != V pool "
@@ -290,8 +322,14 @@ def paged_attention_quant_reference(q, k_hi, k_lo, k_scale, v_hi, v_lo,
                                     sm_scale=None):
     """Numerics oracle: dequantize the whole pool, then the fp32
     reference (fine on the CPU rung; the kernel never does this)."""
-    k_pages = dequantize_lastdim(k_hi, k_lo, k_scale)
-    v_pages = dequantize_lastdim(v_hi, v_lo, v_scale)
+    d = q.shape[-1]
+
+    def deq(hi, lo, scale):
+        # one scale a head: repeat it over the head's d lanes
+        return dequantize_lastdim(hi, lo, jnp.repeat(scale, d, axis=-1))
+
+    k_pages = deq(k_hi, k_lo, k_scale)
+    v_pages = deq(v_hi, v_lo, v_scale)
     return paged_attention_reference(q, k_pages, v_pages, page_table,
                                      q_start, sm_scale=sm_scale)
 
@@ -307,11 +345,17 @@ def paged_attention_quant(q, k_hi, k_lo, k_scale, v_hi, v_lo, v_scale,
                           page_table, q_start, *, sm_scale=None,
                           force=None):
     """paged_attention over a dual-int8 pool: hi/lo int8
-    [P, page_size, n, d] + per-vector fp32 scale [P, page_size, n, 1]
-    (primitives/int8.py quantize_lastdim layout).  Dequant happens
-    inside the kernel — fp32 K/V never materializes outside VMEM."""
+    [P, page_size, n*d] + one fp32 scale per (page, slot, head)
+    head_dim vector [P, page_size, n] (primitives/int8.py
+    quantize_lastdim, the heads flattened into the lane dimension like
+    the fp pool's).  Dequant happens inside the kernel — fp32 K/V never
+    materializes outside VMEM."""
     d = q.shape[-1]
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
+    _check_pool_shapes("paged_attention_quant", q,
+                       scales=("k_scale", "v_scale"), k_hi=k_hi, k_lo=k_lo,
+                       k_scale=k_scale, v_hi=v_hi, v_lo=v_lo,
+                       v_scale=v_scale)
     for nm, arr in (("k_hi", k_hi), ("k_lo", k_lo), ("v_hi", v_hi),
                     ("v_lo", v_lo)):
         if arr.dtype != jnp.int8:

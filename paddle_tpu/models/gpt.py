@@ -603,11 +603,15 @@ def kv_pool_quant_var_names(num_layers, prefix=KV_POOL_PREFIX):
 
 def _declare_pool_vars(cfg: GPTConfig, num_pages, page_size, dtype,
                        prefix=KV_POOL_PREFIX):
-    n, d = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+    # [P, pgs, n*d]: the heads side by side in the lane dimension, the
+    # ONE shape of the pool (kernels/primitives/paged.py "Shapes") — its
+    # default TPU layout is the one the paged kernel reads, so neither
+    # executable copies the pool
+    n, h = cfg.num_heads, cfg.hidden_size
     block = fluid.default_main_program().global_block()
     if dtype == "int8":
-        # dual-int8 pool: hi/lo int8 [P, pgs, n, d] + fp32 scale
-        # [P, pgs, n, 1] per K/V (kernels/primitives/int8.py layout)
+        # dual-int8 pool: hi/lo int8 [P, pgs, n*d] + one fp32 scale a
+        # head [P, pgs, n] per K/V (kernels/primitives/int8.py layout)
         out = []
         for k_names, v_names in kv_pool_quant_var_names(cfg.num_layers,
                                                         prefix):
@@ -615,13 +619,13 @@ def _declare_pool_vars(cfg: GPTConfig, num_pages, page_size, dtype,
             for hi_n, lo_n, sc_n in (k_names, v_names):
                 layer.append(tuple([
                     block.create_var(name=hi_n,
-                                     shape=[num_pages, page_size, n, d],
+                                     shape=[num_pages, page_size, h],
                                      dtype="int8", persistable=True),
                     block.create_var(name=lo_n,
-                                     shape=[num_pages, page_size, n, d],
+                                     shape=[num_pages, page_size, h],
                                      dtype="int8", persistable=True),
                     block.create_var(name=sc_n,
-                                     shape=[num_pages, page_size, n, 1],
+                                     shape=[num_pages, page_size, n],
                                      dtype="float32", persistable=True),
                 ]))
             out.append(tuple(layer))
@@ -630,7 +634,7 @@ def _declare_pool_vars(cfg: GPTConfig, num_pages, page_size, dtype,
     for kn, vn in kv_pool_var_names(cfg.num_layers, prefix):
         out.append(tuple(
             block.create_var(name=nm,
-                             shape=[num_pages, page_size, n, d],
+                             shape=[num_pages, page_size, h],
                              dtype=dtype, persistable=True)
             for nm in (kn, vn)))
     return out

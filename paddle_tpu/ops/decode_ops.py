@@ -18,6 +18,14 @@ All three are inference-only (grad=None — generation programs are never
 differentiated) and the writes alias their pool input (XLA buffer
 donation: the pool updates in place, never doubled).
 
+Shape contract: a pool var is [num_pages, page_size, n_heads*head_dim]
+— the heads side by side in the lane dimension, the one shape the pool
+is stored, written and read in (kernels/primitives/paged.py "Shapes"
+says why: it is the shape whose default TPU layout the paged kernel's
+blocks address, so no executable copies the pool between layouts).  The
+writes take their payload with the heads apart, [.., n, d], as the
+model's projections hand it over, and flatten the PAYLOAD.
+
 Dtype contract: the pool's dtype is stamped at creation
 (KVPool(dtype=...)) and the write lowerings REFUSE a mismatched payload
 at trace time — a bf16-AMP prefill feeding an fp32 pool fails loudly
@@ -30,6 +38,23 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from paddle_tpu.fluid.registry import simple_op
+
+
+def _rows(x):
+    """A payload [R, n, w] as the pool's rows [R, n*w]."""
+    return x.reshape(x.shape[0], -1)
+
+
+def _page_blocks(op, x, page_size):
+    """A chunk's payload [C, n, w] as whole pool pages
+    [C/page_size, page_size, n*w]."""
+    c = x.shape[0]
+    if c % page_size:
+        raise ValueError(
+            f"{op}: chunk length {c} is not a multiple of the pool page "
+            f"size {page_size} — the prefill chunk must cover whole "
+            f"pages")
+    return x.reshape(c // page_size, page_size, -1)
 
 
 def _check_pool_dtype(op, pages, new):
@@ -45,13 +70,13 @@ def _check_pool_dtype(op, pages, new):
 @simple_op("kv_cache_write", ["Pages", "New", "PageIdx", "Offset"],
            ["PagesOut"], grad=None, inplace={"PagesOut": "Pages"})
 def _kv_cache_write(ctx, pages, new, page_idx, offset, attrs):
-    """One decode step's write: new [B, n, d] lands at
-    pages[page_idx[b], offset[b]] per slot b.  Inactive slots point at
-    the pool's trash page (page 0); duplicate trash coordinates are
-    benign — nothing ever attends them."""
+    """One decode step's write: new [B, n, d] lands, flattened to
+    [B, n*d], at pages[page_idx[b], offset[b]] per slot b.  Inactive
+    slots point at the pool's trash page (page 0); duplicate trash
+    coordinates are benign — nothing ever attends them."""
     _check_pool_dtype("kv_cache_write", pages, new)
     return pages.at[page_idx.astype(jnp.int32),
-                    offset.astype(jnp.int32)].set(new)
+                    offset.astype(jnp.int32)].set(_rows(new))
 
 
 @simple_op("kv_cache_write_pages", ["Pages", "New", "PageIdx"],
@@ -63,14 +88,7 @@ def _kv_cache_write_pages(ctx, pages, new, page_idx, attrs):
     page id; rows past a sequence's length inside a REAL page are
     masked by every reader (attention masks j <= q_start + i)."""
     _check_pool_dtype("kv_cache_write_pages", pages, new)
-    page_size = pages.shape[1]
-    c = new.shape[0]
-    if c % page_size:
-        raise ValueError(
-            f"kv_cache_write_pages: chunk length {c} is not a multiple "
-            f"of the pool page size {page_size} — the prefill chunk "
-            f"must cover whole pages")
-    blocks = new.reshape(c // page_size, page_size, *new.shape[1:])
+    blocks = _page_blocks("kv_cache_write_pages", new, pages.shape[1])
     return pages.at[page_idx.astype(jnp.int32)].set(blocks)
 
 
@@ -91,9 +109,10 @@ def _paged_attention(ctx, q, k_pages, v_pages, page_table, q_start,
 
 # ---------------------------------------------------------------------------
 # int8-pool forms (docs/KERNELS.md "int8 KV"): the pool rides as three
-# vars per K/V — hi/lo int8 [P, pgs, n, d] + per-vector fp32 scale
-# [P, pgs, n, 1] (primitives/int8.py quantize_lastdim).  Quantization
-# happens ONCE here at append; readers dequantize inside the kernel.
+# vars per K/V — hi/lo int8 [P, pgs, n*d] + one fp32 scale per head_dim
+# vector [P, pgs, n] (primitives/int8.py quantize_lastdim, taken on the
+# payload while its heads are still apart).  Quantization happens ONCE
+# here at append; readers dequantize inside the kernel.
 # ---------------------------------------------------------------------------
 
 
@@ -120,8 +139,9 @@ def _kv_cache_write_quant(ctx, hi, lo, scale, new, page_idx, offset,
     q_hi, q_lo, q_sc = _quantize_payload("kv_cache_write_quant", hi, new)
     pi = page_idx.astype(jnp.int32)
     off = offset.astype(jnp.int32)
-    return (hi.at[pi, off].set(q_hi), lo.at[pi, off].set(q_lo),
-            scale.at[pi, off].set(q_sc))
+    return (hi.at[pi, off].set(_rows(q_hi)),
+            lo.at[pi, off].set(_rows(q_lo)),
+            scale.at[pi, off].set(_rows(q_sc)))
 
 
 @simple_op("kv_cache_write_pages_quant",
@@ -132,23 +152,11 @@ def _kv_cache_write_pages_quant(ctx, hi, lo, scale, new, page_idx,
                                 attrs):
     """kv_cache_write_pages for the int8 pool: quantize the chunk
     [C, n, d] per vector, scatter whole pages of hi/lo/scale."""
-    q_hi, q_lo, q_sc = _quantize_payload("kv_cache_write_pages_quant",
-                                         hi, new)
-    page_size = hi.shape[1]
-    c = new.shape[0]
-    if c % page_size:
-        raise ValueError(
-            f"kv_cache_write_pages_quant: chunk length {c} is not a "
-            f"multiple of the pool page size {page_size} — the prefill "
-            f"chunk must cover whole pages")
+    op = "kv_cache_write_pages_quant"
+    q_hi, q_lo, q_sc = _quantize_payload(op, hi, new)
     pi = page_idx.astype(jnp.int32)
-    n_pages = c // page_size
-
-    def paged(x):
-        return x.reshape(n_pages, page_size, *x.shape[1:])
-
-    return (hi.at[pi].set(paged(q_hi)), lo.at[pi].set(paged(q_lo)),
-            scale.at[pi].set(paged(q_sc)))
+    return tuple(pool.at[pi].set(_page_blocks(op, x, hi.shape[1]))
+                 for pool, x in ((hi, q_hi), (lo, q_lo), (scale, q_sc)))
 
 
 @simple_op("paged_attention_quant",

@@ -2,13 +2,15 @@
 
 vLLM-style paged memory for KV caches on the executor's scope model:
 the pool is one persistable program var per (layer, K/V) shaped
-``[num_pages, page_size, n_heads, head_dim]``, donated by the executor
-every step so it updates in place; a sequence's cache is a LIST of page
-ids (its page table), not a contiguous slab.  Admission, growth and
-eviction therefore move ZERO cache memory — they edit host-side page
-lists — and the decode step stays one fixed-shape executable
-(models/gpt.py build_gpt_decode_step) no matter how sequences come and
-go.
+``[num_pages, page_size, n_heads * head_dim]`` (the heads side by side
+in the last dimension: the shape whose default TPU layout the paged
+kernel reads, kernels/primitives/paged.py "Shapes"), donated by the
+executor every step so it updates in place; a sequence's cache is a
+LIST of page ids (its page table), not a contiguous slab.  Admission,
+growth and eviction therefore move ZERO cache memory — they edit
+host-side page lists — and the decode step stays one fixed-shape
+executable (models/gpt.py build_gpt_decode_step) no matter how
+sequences come and go.
 
 Page 0 is the TRASH page: never allocated, the write target of inactive
 decode slots and padded prefill tails.  Readers can't observe it —
@@ -85,12 +87,14 @@ class KVPool:
         resident pool; a rebuild with a different pool_dtype must NOT,
         or every later write trips the dtype guard blaming the
         payload)."""
-        shape = (self.num_pages, self.page_size, self.num_heads,
-                 self.head_dim)
+        # the one place that knows the heads and their width apart: the
+        # pool itself holds them flattened into its last dimension
+        shape = (self.num_pages, self.page_size,
+                 self.num_heads * self.head_dim)
         if self.dtype == "int8":
-            # dual-int8 pool: hi/lo int8 + per-vector fp32 scale per
-            # K/V (docs/KERNELS.md "int8 KV")
-            sc_shape = shape[:-1] + (1,)
+            # dual-int8 pool: hi/lo int8 + one fp32 scale a head_dim
+            # vector per K/V (docs/KERNELS.md "int8 KV")
+            sc_shape = shape[:-1] + (self.num_heads,)
             for k_names, v_names in self.quant_var_names:
                 for hi_n, lo_n, sc_n in (k_names, v_names):
                     for name, shp, dt in ((hi_n, shape, "int8"),

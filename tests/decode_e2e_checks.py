@@ -16,6 +16,7 @@ failure; main() runs all of them and prints one
 Run directly for debugging: ``python tests/decode_e2e_checks.py [names]``.
 """
 
+import functools
 import os
 import sys
 
@@ -39,10 +40,10 @@ from paddle_tpu.models import gpt  # noqa: E402
 CFG = dict(num_layers=2, hidden_dropout=0.0, use_flash_attention=False)
 
 
-def build_fixture():
+def build_fixture(cfg=None):
     """One tiny GPT trained for 30 steps, plus the whole-sequence greedy
     reference ids for 4 prompts — the parity oracle every check shares."""
-    cfg = gpt.GPTConfig.tiny(**CFG)
+    cfg = cfg or gpt.GPTConfig.tiny(**CFG)
     data = gpt.make_fake_lm_batch(cfg, 8, 10, seed=3)
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
@@ -89,6 +90,48 @@ def check_parity_greedy_bit_exact(cfg, scope, prompts, ref_ids):
     finally:
         eng.close()
     np.testing.assert_array_equal(np.asarray(outs), ref_ids)
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_fixture():
+    """build_fixture at 20 heads of 64, shared by the checks that ask."""
+    return build_fixture(gpt.GPTConfig(
+        vocab_size=256, hidden_size=1280, num_heads=20,
+        intermediate_size=256, max_position=32, **CFG))
+
+
+def _check_parity_at_benchmark_heads(attn_force):
+    """The parity gate at GPT-2-large's own head geometry: 20 heads of
+    64 in a 1280-wide lane dimension (two layers, a small vocabulary and
+    FFN), so that the split of the pool's flat last dimension into heads
+    — 64-lane slices inside the kernel, a reshape of the gathered pages
+    in the XLA reference — is checked where the benchmark runs it."""
+    cfg, scope, prompts, ref_ids = _wide_fixture()
+    eng = serving.DecodeEngine(cfg, scope=scope, pool_slots=4,
+                               page_size=4, prefill_chunk=4, max_len=16,
+                               attn_force=attn_force,
+                               name=f"wide-{attn_force}",
+                               auto_start=False)
+    try:
+        pool = np.asarray(scope.get(eng.pool.var_names[0][0]))
+        assert pool.shape == (eng.pool.num_pages, 4, 1280), pool.shape
+        eng.warmup()
+        eng.start()
+        outs = eng.generate([list(p) for p in prompts],
+                            max_new_tokens=6, timeout=600)
+    finally:
+        eng.close()
+    np.testing.assert_array_equal(np.asarray(outs), ref_ids)
+
+
+def check_parity_heads20x64_pallas(cfg, scope, prompts, ref_ids):
+    """Pallas kernel, interpret mode (the chip's algorithm on the CPU)."""
+    _check_parity_at_benchmark_heads("pallas")
+
+
+def check_parity_heads20x64_reference(cfg, scope, prompts, ref_ids):
+    """XLA reference (what a CPU engine dispatches to)."""
+    _check_parity_at_benchmark_heads("reference")
 
 
 def check_zero_steady_state_compiles(cfg, scope, prompts, ref_ids):
@@ -228,6 +271,8 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
     per-step logprob row within a tight bound and agree on every greedy
     argmax — quantization happens once per append, so the error does
     not compound across steps."""
+    from paddle_tpu.serving.kv_pool import KVPool
+
     n, d = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     page_size, max_pages, num_pages, steps = 4, 8, 9, 20
 
@@ -243,20 +288,11 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
 
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
-        # install both pools by hand (the engine's pool.install job)
-        for kn, vn in gpt.kv_pool_var_names(cfg.num_layers, "@KVF@"):
-            for nm in (kn, vn):
-                scope.set(nm, np.zeros(
-                    (num_pages, page_size, n, d), np.float32))
-        for k_names, v_names in gpt.kv_pool_quant_var_names(
-                cfg.num_layers, "@KVQ@"):
-            for hi_n, lo_n, sc_n in (k_names, v_names):
-                scope.set(hi_n, np.zeros(
-                    (num_pages, page_size, n, d), np.int8))
-                scope.set(lo_n, np.zeros(
-                    (num_pages, page_size, n, d), np.int8))
-                scope.set(sc_n, np.zeros(
-                    (num_pages, page_size, n, 1), np.float32))
+        # both pools in the one shape every pool var has (KVPool.install
+        # is the one place that knows heads and their width apart)
+        for dtype, prefix in (("float32", "@KVF@"), ("int8", "@KVQ@")):
+            KVPool(cfg.num_layers, n, d, num_pages, page_size, max_pages,
+                   dtype=dtype, prefix=prefix).install(scope)
 
         toks = np.random.RandomState(0).randint(
             1, cfg.vocab_size, steps)
@@ -338,6 +374,8 @@ CHECKS = {
     "int8_kv_logprob_drift": check_int8_kv_logprob_drift,
     "int8_weights_generate_matches_fp32":
         check_int8_weights_generate_matches_fp32,
+    "parity_heads20x64_pallas": check_parity_heads20x64_pallas,
+    "parity_heads20x64_reference": check_parity_heads20x64_reference,
     "zero_steady_state_compiles": check_zero_steady_state_compiles,
     "eviction_under_pressure_matches_unpressured":
         check_eviction_under_pressure_matches_unpressured,

@@ -74,7 +74,8 @@ def test_pool_padded_table_and_install():
     scope = fluid.Scope()
     p.install(scope)
     arr = scope.get(p.var_names[0][0])
-    assert arr.shape == (9, 4, 4, 16) and str(arr.dtype) == "float32"
+    # the pool's one shape: 4 heads of 16 side by side in the last dim
+    assert arr.shape == (9, 4, 64) and str(arr.dtype) == "float32"
     # idempotent on shape match: the resident pool is kept
     scope.set(p.var_names[0][0], arr + 1.0)
     p.install(scope)
@@ -96,8 +97,9 @@ def test_pool_padded_table_and_install():
 
 def _paged_case(seed=0, b=3, n=2, d=8, pgs=4, maxp=3, t=1):
     rng = np.random.RandomState(seed)
-    k_pages = rng.randn(8, pgs, n, d).astype("float32")
-    v_pages = rng.randn(8, pgs, n, d).astype("float32")
+    # drawn with the heads apart, handed over in the pool's one shape
+    k_pages = rng.randn(8, pgs, n, d).astype("float32").reshape(8, pgs, -1)
+    v_pages = rng.randn(8, pgs, n, d).astype("float32").reshape(8, pgs, -1)
     pt = np.array([[1, 2, 3], [4, 5, 0], [6, 7, 0]], np.int32)[:b]
     q_start = np.array([9, 5, 2], np.int32)[:b]
     q = rng.randn(b, n, t, d).astype("float32")
@@ -110,11 +112,11 @@ def test_paged_attention_reference_matches_dense():
     q, kp, vp, pt, qs = _paged_case()
     out = np.asarray(pa.paged_attention(q, kp, vp, pt, qs,
                                         force="reference"))
-    d = q.shape[-1]
+    n, d = q.shape[1], q.shape[-1]
     for b in range(q.shape[0]):
         L = qs[b] + 1
-        ks = kp[pt[b]].reshape(-1, *kp.shape[2:]).transpose(1, 0, 2)[:, :L]
-        vs = vp[pt[b]].reshape(-1, *vp.shape[2:]).transpose(1, 0, 2)[:, :L]
+        ks = kp[pt[b]].reshape(-1, n, d).transpose(1, 0, 2)[:, :L]
+        vs = vp[pt[b]].reshape(-1, n, d).transpose(1, 0, 2)[:, :L]
         s = np.einsum("ntd,nld->ntl", q[b], ks) / np.sqrt(d)
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
@@ -146,7 +148,7 @@ def test_kv_cache_write_dtype_guard():
     from paddle_tpu.ops.decode_ops import (_kv_cache_write,
                                            _kv_cache_write_pages)
 
-    pages = jnp.zeros((4, 2, 2, 4), jnp.float32)
+    pages = jnp.zeros((4, 2, 2 * 4), jnp.float32)
     new16 = jnp.zeros((3, 2, 4), jnp.bfloat16)
     idx = jnp.zeros(3, jnp.int32)
     with pytest.raises(ValueError, match="does not match the KV pool"):
@@ -162,6 +164,36 @@ def test_kv_cache_write_dtype_guard():
     assert np.asarray(out)[0, 1].max() == 1.0
 
 
+def test_kv_cache_write_trash_duplicates_leave_other_pages_alone():
+    """A decode step with ONE live slot: the idle slots all write the
+    trash page at (0, 0) — duplicate scatter coordinates — and the live
+    one writes (3, 1).  On the flat pool every other page, and every
+    other row of page 3, comes back bit-identical, and the live row
+    holds its heads side by side."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.decode_ops import _kv_cache_write
+
+    rng = np.random.RandomState(11)
+    n, d = 3, 4
+    base = rng.randn(5, 2, n * d).astype("float32")
+    new = rng.randn(4, n, d).astype("float32")
+    page_idx = jnp.asarray([0, 3, 0, 0], jnp.int32)
+    offset = jnp.asarray([0, 1, 0, 0], jnp.int32)
+    out = np.asarray(_kv_cache_write(None, jnp.asarray(base),
+                                     jnp.asarray(new), page_idx, offset,
+                                     {}))
+    assert out.shape == base.shape
+    np.testing.assert_array_equal(out[3, 1], new[1].reshape(-1))
+    np.testing.assert_array_equal(out[3, 1].reshape(n, d)[2], new[1, 2])
+    touched = np.zeros(base.shape[:2], bool)
+    touched[0, 0] = touched[3, 1] = True
+    np.testing.assert_array_equal(out[~touched], base[~touched])
+    # the trash row holds one of the idle slots' payloads, whole
+    assert any(np.array_equal(out[0, 0], new[b].reshape(-1))
+               for b in (0, 2, 3))
+
+
 def test_kv_cache_write_ops_numeric():
     """Program-level numeric pin for both pool-write ops:
     layers.kv_cache_write scatters per-slot (page, offset) rows and
@@ -172,21 +204,21 @@ def test_kv_cache_write_ops_numeric():
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         blk = main.global_block()
-        pool_t = blk.create_var(name="kvw_pool_t", shape=[5, 2, 2, 3],
+        pool_t = blk.create_var(name="kvw_pool_t", shape=[5, 2, 2 * 3],
                                 dtype="float32", persistable=True)
         new = fluid.data("kvw_new", [3, 2, 3], False, dtype="float32")
         pg = fluid.data("kvw_pg", [3], False, dtype="int32")
         off = fluid.data("kvw_off", [3], False, dtype="int32")
         L.kv_cache_write(pool_t, new, pg, off)
-        pool_p = blk.create_var(name="kvw_pool_p", shape=[5, 2, 2, 3],
+        pool_p = blk.create_var(name="kvw_pool_p", shape=[5, 2, 2 * 3],
                                 dtype="float32", persistable=True)
         chunk = fluid.data("kvw_chunk", [4, 2, 3], False,
                            dtype="float32")
         cpg = fluid.data("kvw_cpg", [2], False, dtype="int32")
         L.kv_cache_write_pages(pool_p, chunk, cpg)
     rng = np.random.RandomState(7)
-    base = rng.randn(5, 2, 2, 3).astype("float32")
-    new_v = rng.randn(3, 2, 3).astype("float32")
+    base = rng.randn(5, 2, 2 * 3).astype("float32")
+    new_v = rng.randn(3, 2, 3).astype("float32")    # [B, n, d]
     pg_v = np.array([1, 3, 3], np.int32)
     off_v = np.array([0, 1, 0], np.int32)
     chunk_v = rng.randn(4, 2, 3).astype("float32")
@@ -205,9 +237,9 @@ def test_kv_cache_write_ops_numeric():
         back_p = np.asarray(scope.get("kvw_pool_p"))
     want_t = base.copy()
     for b in range(3):
-        want_t[pg_v[b], off_v[b]] = new_v[b]
+        want_t[pg_v[b], off_v[b]] = new_v[b].reshape(-1)
     want_p = base.copy()
-    want_p[cpg_v] = chunk_v.reshape(2, 2, 2, 3)
+    want_p[cpg_v] = chunk_v.reshape(2, 2, 2 * 3)
     np.testing.assert_array_equal(np.asarray(got_t), want_t)
     np.testing.assert_array_equal(np.asarray(got_p), want_p)
     np.testing.assert_array_equal(back_t, want_t)
@@ -313,6 +345,15 @@ def test_decode_parity_greedy_bit_exact(e2e):
     attention) reproduces the whole-sequence build_gpt_generate lane's
     token ids EXACTLY — same weights, same prompts (child check)."""
     _e2e_check(e2e, "parity_greedy_bit_exact")
+
+
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
+def test_decode_parity_at_benchmark_head_geometry(e2e, impl):
+    """The same gate at 20 heads of 64 (GPT-2-large's geometry, two
+    layers): the flat pool's lane dimension splits into the heads it was
+    written from, in the Pallas kernel (interpret mode) and in the XLA
+    reference alike (child checks)."""
+    _e2e_check(e2e, f"parity_heads20x64_{impl}")
 
 
 def test_decode_zero_steady_state_compiles(e2e):

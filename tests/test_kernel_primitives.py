@@ -19,6 +19,7 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from paddle_tpu.kernels import primitives as prims
 from paddle_tpu.kernels.primitives import autotune, contract
@@ -250,33 +251,97 @@ def test_flash_pinned_tile_table_end_to_end(monkeypatch, tmp_path):
         autotune.clear_cache()
 
 
-def test_paged_interpret_parity():
-    b, n, d = 2, 2, 32
+def _flat(pages):
+    """A pool built with the heads apart, [P, page, n, w], in the one
+    shape the pool is stored in: [P, page, n*w]."""
+    return pages.reshape(pages.shape[0], pages.shape[1], -1)
+
+
+def _paged_oracle(q, k_pages, v_pages, page_table, q_start):
+    """Dense numpy attention over pools held with the HEADS APART
+    ([P, page, n, d]): independent of how the primitive splits the flat
+    lane dimension into heads."""
+    q, k_pages, v_pages = (np.asarray(x, np.float64)
+                           for x in (q, k_pages, v_pages))
+    b, n, t, d = q.shape
+    out = np.zeros(q.shape)
+    for bi in range(b):
+        k = k_pages[page_table[bi]].reshape(-1, n, d)      # [L, n, d]
+        v = v_pages[page_table[bi]].reshape(-1, n, d)
+        for i in range(t):
+            keys = int(q_start[bi]) + i + 1
+            for h in range(n):
+                s = k[:keys, h] @ q[bi, h, i] / np.sqrt(d)
+                p = np.exp(s - s.max())
+                out[bi, h, i] = (p / p.sum()) @ v[:keys, h]
+    return out
+
+
+# (n, d, t): two heads of 32; five heads of 64 (a lane dimension that is
+# not a multiple of 128, heads at 64-lane offsets as on the real models);
+# a prefill chunk of 8 queries
+@pytest.mark.parametrize("n,d,t", [(2, 32, 1), (5, 64, 1), (2, 32, 8)])
+def test_paged_interpret_parity(n, d, t):
+    b = 2
     page_size, max_pages, num_pages = 8, 4, 9
-    q = _rand((b, n, 1, d), seed=0)
+    q = _rand((b, n, t, d), seed=0)
     k_pages = _rand((num_pages, page_size, n, d), seed=1)
     v_pages = _rand((num_pages, page_size, n, d), seed=2)
     rng = np.random.RandomState(3)
     page_table = np.zeros((b, max_pages), np.int32)
-    page_table[0, :3] = rng.choice(np.arange(1, num_pages), 3, False)
-    page_table[1, :2] = rng.choice(np.arange(1, num_pages), 2, False)
+    page_table[0, :4] = rng.choice(np.arange(1, num_pages), 4, False)
+    page_table[1, :3] = rng.choice(np.arange(1, num_pages), 3, False)
     q_start = np.array([19, 12], np.int32)
-    got = prims.paged_attention(q, k_pages, v_pages, page_table, q_start,
-                                force="pallas")
-    want = prims.paged_attention_reference(q, k_pages, v_pages,
-                                           page_table, q_start)
+    got = prims.paged_attention(q, _flat(k_pages), _flat(v_pages),
+                                page_table, q_start, force="pallas")
+    want = prims.paged_attention_reference(q, _flat(k_pages),
+                                           _flat(v_pages), page_table,
+                                           q_start)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-5)
+    # and both split the lane dimension into the heads it was built from
+    np.testing.assert_allclose(
+        np.asarray(want),
+        _paged_oracle(q, k_pages, v_pages, page_table, q_start),
+        atol=1e-5, rtol=1e-5)
 
 
-def test_paged_quant_interpret_parity():
+@pytest.mark.parametrize("force", ["pallas", "reference"])
+def test_paged_attention_refuses_a_pool_with_the_heads_apart(force):
+    """ONE pool shape: the primitive does not reshape a 4-D pool (that
+    reshape is what every executable paid a whole-pool copy for), it
+    names the shape it got and the one it takes."""
     b, n, d = 2, 2, 32
+    q = _rand((b, n, 1, d), seed=0)
+    pool = _rand((9, 8, n, d), seed=1)
+    page_table = np.zeros((b, 4), np.int32)
+    q_start = np.array([3, 5], np.int32)
+    with pytest.raises(ValueError, match=r"\(9, 8, 2, 32\).*"
+                                        r"\[num_pages, page_size, 64\]"):
+        prims.paged_attention(q, pool, pool, page_table, q_start,
+                              force=force)
+    hi, lo, sc = prims.quantize_lastdim(jnp.asarray(pool))
+    with pytest.raises(ValueError, match=r"k_scale has shape "
+                                        r"\(9, 8, 2, 1\)"):
+        prims.paged_attention_quant(q, _flat(hi), _flat(lo), sc, _flat(hi),
+                                    _flat(lo), sc, page_table, q_start,
+                                    force=force)
+
+
+@pytest.mark.parametrize("n,d", [(2, 32), (5, 64)])
+def test_paged_quant_interpret_parity(n, d):
+    b = 2
     page_size, max_pages, num_pages = 8, 4, 9
     q = _rand((b, n, 1, d), seed=0)
     k_pages = _rand((num_pages, page_size, n, d), seed=1)
     v_pages = _rand((num_pages, page_size, n, d), seed=2)
-    k_hi, k_lo, k_sc = prims.quantize_lastdim(jnp.asarray(k_pages))
-    v_hi, v_lo, v_sc = prims.quantize_lastdim(jnp.asarray(v_pages))
+    # one scale per head_dim vector, taken while the heads are apart
+    k_hi, k_lo, k_sc = map(_flat, prims.quantize_lastdim(
+        jnp.asarray(k_pages)))
+    v_hi, v_lo, v_sc = map(_flat, prims.quantize_lastdim(
+        jnp.asarray(v_pages)))
+    assert k_hi.shape == (num_pages, page_size, n * d)
+    assert k_sc.shape == (num_pages, page_size, n)
     page_table = np.zeros((b, max_pages), np.int32)
     page_table[0, :3] = (1, 4, 7)
     page_table[1, :2] = (2, 5)
@@ -289,10 +354,10 @@ def test_paged_quant_interpret_parity():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-5)
     # and the dual-int8 dequant stays CLOSE to the fp pool it encodes
-    fp = prims.paged_attention_reference(q, k_pages, v_pages, page_table,
-                                         q_start)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(fp),
-                               atol=5e-3, rtol=5e-3)
+    np.testing.assert_allclose(
+        np.asarray(got),
+        _paged_oracle(q, k_pages, v_pages, page_table, q_start),
+        atol=5e-3, rtol=5e-3)
 
 
 def test_ragged_interpret_parity():

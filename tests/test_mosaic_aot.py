@@ -11,6 +11,8 @@ chip_smoke.py runs (BERT-base / GPT-base heads and widths; whole
 programs are cut in depth only, to keep the compile in seconds).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -79,7 +81,7 @@ def test_ragged_attention(chip, dtype):
 def _paged_shapes(t, page, dtype):
     b = 8 if t == 1 else 1          # decode step : prefill chunk
     max_pages = 512 // page
-    pool = ((8 * max_pages + 1, page, HEADS, HEAD_DIM), dtype)
+    pool = ((8 * max_pages + 1, page, HEADS * HEAD_DIM), dtype)
     return (((b, HEADS, t, HEAD_DIM), jnp.float32 if dtype == jnp.int8
              else dtype), pool,
             ((b, max_pages), jnp.int32), ((b,), jnp.int32))
@@ -96,7 +98,7 @@ def test_paged_attention(chip, dtype, t, page):
 @pytest.mark.parametrize("t,page", [(1, 32), (32, 32), (1, 16), (128, 128)])
 def test_paged_attention_int8_pool(chip, t, page):
     q, pool, table, start = _paged_shapes(t, page, jnp.int8)
-    scale = (pool[0][:3] + (1,), jnp.float32)
+    scale = (pool[0][:2] + (HEADS,), jnp.float32)
     hlo = _compile(prims.paged_attention_quant, chip, q, pool, pool, scale,
                    pool, pool, scale, table, start)
     assert _mosaic_calls(hlo) == 1
@@ -173,13 +175,61 @@ def test_bert_data_parallel_step_over_four_chips(topo):
     assert "all-reduce" in hlo and "replica_groups={{0,1,2,3}}" in hlo
 
 
+def _pool_copies(hlo, num_pages, page):
+    """Shapes of the pool tensors the compiled module copies whole:
+    every ``copy`` or ``transpose`` whose result carries the pool's
+    leading dimensions (at either rank: ``513,32,1280`` and
+    ``513,32,20,64`` alike), and every ``copy-start`` that changes the
+    layout (one that keeps it is the compiler's own prefetch into
+    another memory space, ``S(1)``, of a buffer that fits there)."""
+    pool = re.compile(rf"\[({num_pages},{page}(?:,\d+)+)\](\{{[\d,]*)")
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = (.+?) (copy|copy-start|transpose)\(", line)
+        arrays = pool.findall(m.group(1)) if m else []
+        if arrays and not (m.group(2) == "copy-start"
+                           and arrays[0][1] == arrays[1][1]):
+            found.append(arrays[0][0])
+    return found
+
+
+def _pool_parameters(hlo, shape):
+    """(parameter number, layout) of every entry parameter of ``shape``."""
+    entry = hlo[hlo.index("ENTRY "):]
+    return [(int(num), layout) for layout, num in re.findall(
+        rf"= \w+\[{shape}\](\{{[^}}]*\}}) parameter\((\d+)\)", entry)]
+
+
+def _aliased_parameters(hlo):
+    """Parameter numbers the module's ``input_output_alias`` donates."""
+    header = hlo[:hlo.index("\n")]
+    return {int(n) for n in re.findall(r"\((\d+), \{\}", header)}
+
+
+# (hidden, heads, layers, slots, max_len): GPT-base as chip_smoke.py
+# serves it, and GPT-2-large as benchmark/configs/gpt2-large.json serves
+# it (the benchmark's widths, pool and slots; two of its 36 layers)
+_ENGINES = {"gpt-base": (768, 12, 2, 8, 512),
+            "gpt2-large": (1280, 20, 2, 16, 1024)}
+
+
 @pytest.mark.parametrize("pool_dtype", ["float32", "int8"])
-def test_decode_engine_executables(chip, pool_dtype):
-    """GPT-base as chip_smoke.py serves it (page 32, max_len 512, 8
-    slots): the prefill chunk and the decode step, one paged-attention
-    Mosaic call per layer each."""
-    cfg = gpt.GPTConfig(vocab_size=50304, hidden_size=768, num_heads=12,
-                        num_layers=2, max_position=512)
+@pytest.mark.parametrize("size", list(_ENGINES))
+def test_decode_engine_executables(chip, size, pool_dtype):
+    """The prefill chunk and the decode step at page 32: one
+    paged-attention Mosaic call per layer each, and the KV pool goes
+    through both UNCOPIED — every pool parameter arrives row-major (the
+    layout the kernel's blocks address), is donated, and no copy or
+    transpose of a whole pool tensor is left in the compiled HLO.  With
+    the heads apart, [pages, page, 20, 64], the float32 gpt2-large case
+    compiled to 12 such copies in the decode step and 4 in the chunk
+    (PERF.md finding 4): four fifths of the device's time."""
+    hidden, heads, layers, slots, max_len = _ENGINES[size]
+    page = 32
+    cfg = gpt.GPTConfig(vocab_size=50304, hidden_size=hidden,
+                        num_heads=heads, num_layers=layers,
+                        max_position=max_len)
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         lm, lm_start = fluid.Program(), fluid.Program()
@@ -187,13 +237,35 @@ def test_decode_engine_executables(chip, pool_dtype):
             gpt.build_gpt_lm(cfg, is_test=True)
         fluid.Executor(fluid.CPUPlace()).run(lm_start)
         engine = serving.DecodeEngine(
-            cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=8,
-            page_size=32, max_len=512, pool_dtype=pool_dtype,
-            name=f"aot-{pool_dtype}", auto_start=False)
+            cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=slots,
+            page_size=page, max_len=max_len, pool_dtype=pool_dtype,
+            name=f"aot-{size}-{pool_dtype}", auto_start=False)
+        # K and V of every layer; the int8 pool holds each as hi and lo
+        payload = f"{engine.pool.num_pages},{page},{hidden}"
+        n_payload = 2 * layers * (2 if pool_dtype == "int8" else 1)
+        scales = f"{engine.pool.num_pages},{page},{heads}"
+        n_scales = 2 * layers if pool_dtype == "int8" else 0
+        relaid = {scales} if n_scales else set()
         try:
             with lowering_for("tpu"):
                 for lowered in engine.lower(sharding=chip):
                     hlo = lowered.compile().as_text()
                     assert _mosaic_calls(hlo) == cfg.num_layers
+                    assert set(_pool_copies(hlo, engine.pool.num_pages,
+                                            page)) <= relaid
+                    params = _pool_parameters(hlo, payload)
+                    assert len(params) == n_payload
+                    assert [lay for _, lay in params
+                            if not lay.startswith("{2,1,0")] == []
+                    # the int8 pool's scales are donated like the rest,
+                    # but [pages, page, heads] is NOT held to row-major:
+                    # 20 lanes of 128 would pad it sixfold, so XLA keeps
+                    # the page index minor-most and relays these (1/64 of
+                    # the fp32 pool's bytes) around the kernel: the one
+                    # shape `relaid` lets through (PERF.md section 7)
+                    scale_params = _pool_parameters(hlo, scales)
+                    assert len(scale_params) == n_scales
+                    assert {num for num, _ in params + scale_params} <= \
+                        _aliased_parameters(hlo)
         finally:
             engine.close()

@@ -699,9 +699,11 @@ def test_kv_cache_write_quant_scatter_and_resolution():
     reconstruct within dual-int8 resolution (~14.6 bits)."""
     rng = np.random.RandomState(3)
     P, pgs, n, d = 3, 4, 2, 8
-    hi = np.ones((P, pgs, n, d), "int8") * 7
-    lo = np.ones((P, pgs, n, d), "int8") * -3
-    sc = np.full((P, pgs, n, 1), 0.5, "float32")
+    # the pool's one shape: heads side by side in the last dimension,
+    # one scale a head
+    hi = np.ones((P, pgs, n * d), "int8") * 7
+    lo = np.ones((P, pgs, n * d), "int8") * -3
+    sc = np.full((P, pgs, n), 0.5, "float32")
     new = (rng.randn(2, n, d) * 4).astype("float32")
     page_idx = np.array([2, 0], "int32")
     offset = np.array([1, 3], "int32")
@@ -713,7 +715,9 @@ def test_kv_cache_write_quant_scatter_and_resolution():
         {"HiOut": ["ho"], "LoOut": ["lu"], "ScaleOut": ["so"]})
     ho, lu, so = got["ho"], got["lu"], got["so"]
     assert ho.dtype == np.int8 and lu.dtype == np.int8
-    recon = _dual_int8_recon(ho, lu, so)
+    assert ho.shape == hi.shape and so.shape == sc.shape
+    recon = _dual_int8_recon(ho.reshape(P, pgs, n, d),
+                             lu.reshape(P, pgs, n, d), so[..., None])
     for b in range(2):
         p, o = int(page_idx[b]), int(offset[b])
         np.testing.assert_allclose(
@@ -739,9 +743,9 @@ def test_kv_cache_write_pages_quant_whole_pages():
     a non-multiple chunk fails by name."""
     rng = np.random.RandomState(4)
     P, pgs, n, d = 4, 2, 2, 8
-    hi = np.zeros((P, pgs, n, d), "int8")
-    lo = np.zeros((P, pgs, n, d), "int8")
-    sc = np.ones((P, pgs, n, 1), "float32")
+    hi = np.zeros((P, pgs, n * d), "int8")
+    lo = np.zeros((P, pgs, n * d), "int8")
+    sc = np.ones((P, pgs, n), "float32")
     new = (rng.randn(4, n, d) * 2).astype("float32")  # 2 whole pages
     page_idx = np.array([3, 1], "int32")
     got = _run_one_op(
@@ -749,7 +753,9 @@ def test_kv_cache_write_pages_quant_whole_pages():
         {"Hi": [("h", hi)], "Lo": [("l", lo)], "Scale": [("s", sc)],
          "New": [("nw", new)], "PageIdx": [("pi", page_idx)]},
         {"HiOut": ["ho"], "LoOut": ["lu"], "ScaleOut": ["so"]})
-    recon = _dual_int8_recon(got["ho"], got["lu"], got["so"])
+    recon = _dual_int8_recon(got["ho"].reshape(P, pgs, n, d),
+                             got["lu"].reshape(P, pgs, n, d),
+                             got["so"][..., None])
     chunk = new.reshape(2, pgs, n, d)
     for i, p in enumerate((3, 1)):
         np.testing.assert_allclose(

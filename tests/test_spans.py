@@ -293,8 +293,8 @@ def test_pallas_call_receives_the_spec_name(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call", spy)
     rng = np.random.RandomState(0)
     pgs, n, d = 4, 2, 8
-    kp = rng.randn(8, pgs, n, d).astype("float32")
-    vp = rng.randn(8, pgs, n, d).astype("float32")
+    kp = rng.randn(8, pgs, n * d).astype("float32")
+    vp = rng.randn(8, pgs, n * d).astype("float32")
     pt = np.array([[1, 2, 3], [4, 5, 0]], np.int32)
     qs = np.array([9, 5], np.int32)
     q = rng.randn(2, n, 1, d).astype("float32")

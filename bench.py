@@ -1038,11 +1038,12 @@ def measure_ragged_serving(size):
     # modeled KV-pool HBM: the same decode-lane pool at fp32 vs dual-int8
     # (pure accounting — no device memory moves here)
     from paddle_tpu.serving.kv_pool import KVPool
+    from paddle_tpu.serving.lane import kv_rows
 
     num_pages, page_size = 65, 16
     pools = {
-        dt: KVPool(n_layers, heads, head_dim, num_pages, page_size,
-                   max_pages_per_seq=16, dtype=dt)
+        dt: KVPool(n_layers, kv_rows(heads, head_dim, dt), num_pages,
+                   page_size, max_pages_per_seq=16)
         for dt in ("float32", "int8")
     }
     kv_bytes = {

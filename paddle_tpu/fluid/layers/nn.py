@@ -18,6 +18,9 @@ __all__ = [
     "paged_attention", "kv_cache_write", "kv_cache_write_pages",
     "ragged_attention", "paged_attention_quant", "kv_cache_write_quant",
     "kv_cache_write_pages_quant",
+    "weight_matmul", "headwise_matmul", "rms_norm", "swiglu",
+    "rope_interleaved", "dsa_indexer_scores", "dsa_topk_select",
+    "sparse_mla_attention", "moe_ffn_held",
     "conv2d", "conv3d", "conv2d_transpose", "pool2d",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "dropout",
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
@@ -1544,3 +1547,143 @@ def kv_cache_write_pages_quant(hi, lo, scale, new, page_idx, name=None):
                      outputs={"HiOut": [hi], "LoOut": [lo],
                               "ScaleOut": [scale]})
     return hi, lo, scale
+
+
+# ---------------------------------------------------------------------------
+# latent attention, learned sparse attention, held experts (ops/mla_ops.py;
+# models/glm.py).  Inference-only; results are float32.
+# ---------------------------------------------------------------------------
+
+
+def _out_f32(helper, op, inputs, attrs=None):
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(op, inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs or {})
+    return out
+
+
+def weight_matmul(x, size, param_attr=None, dtype="float32", name=None):
+    """x [.., K] @ W [K, size], W stored in ``dtype`` and the product
+    taken in it with float32 accumulation (no bias)."""
+    helper = LayerHelper("weight_matmul", name=name)
+    w = helper.create_parameter(param_attr, shape=[x.shape[-1], size],
+                                dtype=dtype,
+                                default_initializer=Normal(0.0, 0.02))
+    return _out_f32(helper, "weight_matmul", {"X": [x], "W": [w]})
+
+
+def headwise_matmul(x, size, param_attr=None, dtype="float32", name=None):
+    """x [B, T, H, a] times one [a, size] matrix a head, W [H, a, size]."""
+    helper = LayerHelper("headwise_matmul", name=name)
+    w = helper.create_parameter(
+        param_attr, shape=[x.shape[-2], x.shape[-1], size], dtype=dtype,
+        default_initializer=Normal(0.0, 0.02))
+    return _out_f32(helper, "headwise_matmul", {"X": [x], "W": [w]})
+
+
+def rms_norm(x, epsilon=1e-5, param_attr=None, dtype="float32", name=None):
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, shape=[x.shape[-1]],
+                                    dtype=dtype,
+                                    default_initializer=Constant(1.0))
+    return _out_f32(helper, "rms_norm", {"X": [x], "Scale": [scale]},
+                    {"epsilon": float(epsilon)})
+
+
+def swiglu(gate, up, name=None):
+    """silu(gate) * up."""
+    return _out_f32(LayerHelper("swiglu", name=name), "swiglu",
+                    {"Gate": [gate], "Up": [up]})
+
+
+def rope_interleaved(x, pos, theta, rotary_dim, name=None):
+    """Rotary embedding on the first ``rotary_dim`` entries of x's last
+    dimension, pairs interleaved; x [B, T, d] or [B, T, H, d], pos [B, T]."""
+    return _out_f32(LayerHelper("rope_interleaved", name=name),
+                    "rope_interleaved", {"X": [x], "Pos": [pos]},
+                    {"theta": float(theta), "rotary_dim": int(rotary_dim)})
+
+
+def dsa_indexer_scores(q, w, index_pages, page_table, q_start, force=None,
+                       name=None):
+    """Indexer scores sum_j w_j relu(q_j . k) of q [B, T, Hi, Di] against
+    the paged indexer cache [num_pages, page_size, Di] → [B, T, Lp]
+    float32 (kernels/primitives/dsa.py)."""
+    attrs = {} if force is None else {"force": force}
+    return _out_f32(LayerHelper("dsa_indexer_scores", name=name),
+                    "dsa_indexer_scores",
+                    {"Q": [q], "W": [w], "IndexPages": [index_pages],
+                     "PageTable": [page_table], "QStart": [q_start]}, attrs)
+
+
+def dsa_topk_select(scores, k, force=None, name=None):
+    """Additive mask of the k largest scores a query (exact)."""
+    attrs = {"k": int(k)}
+    if force is not None:
+        attrs["force"] = force
+    return _out_f32(LayerHelper("dsa_topk_select", name=name),
+                    "dsa_topk_select", {"Scores": [scores]}, attrs)
+
+
+def sparse_mla_attention(q_latent, q_rope, latent_pages, page_table,
+                         selected, q_start, sm_scale, force=None, name=None):
+    """Latent-space attention over the selected rows of the paged latent
+    cache [num_pages, page_size, C + R] → [B, T, H, C] float32."""
+    attrs = {"sm_scale": float(sm_scale)}
+    if force is not None:
+        attrs["force"] = force
+    return _out_f32(LayerHelper("sparse_mla_attention", name=name),
+                    "sparse_mla_attention",
+                    {"QLatent": [q_latent], "QRope": [q_rope],
+                     "LatentPages": [latent_pages],
+                     "PageTable": [page_table], "Selected": [selected],
+                     "QStart": [q_start]}, attrs)
+
+
+def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 row_valid=None, stats=None, dtype="float32", force=None,
+                 name=None):
+    """One chip's share of an expert-parallel SwiGLU expert layer over
+    x [B, T, D] (ops/mla_ops.py moe_ffn_held): the router scores all
+    ``num_experts`` (sigmoid, selection bias, ``top_k`` picks,
+    normalised, scaled); this chip holds experts ``first_expert ..
+    first_expert + held_experts`` and adds up the picks that land on
+    them.  ``stats`` [held_experts + 2] int32 persistable, added to in
+    place: the picks each held expert got and the picks that went
+    elsewhere, over the rows ``row_valid`` marks (> 0), and the held
+    experts this call touched."""
+    helper = LayerHelper("moe_ffn_held", name=name)
+    d = x.shape[-1]
+    pname = name or helper.name
+    init = Normal(0.0, 0.02)
+
+    def param(suffix, shape, dt=dtype, initializer=init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{pname}_{suffix}", initializer=initializer),
+            shape=shape, dtype=dt)
+
+    inputs = {
+        "X": [x],
+        "RouterW": [param("router.w_0", [d, num_experts])],
+        "RouterBias": [param("router.b_0", [num_experts],
+                             initializer=Constant(0.0))],
+        "WGate": [param("experts_gate.w_0", [held_experts, d, d_ff])],
+        "WUp": [param("experts_up.w_0", [held_experts, d, d_ff])],
+        "WDown": [param("experts_down.w_0", [held_experts, d_ff, d])],
+    }
+    out = helper.create_variable_for_type_inference("float32")
+    outputs = {"Out": [out]}
+    if row_valid is not None:
+        inputs["RowValid"] = [row_valid]
+    if stats is not None:
+        inputs["Stats"] = [stats]
+        outputs["StatsOut"] = [stats]
+    attrs = {"top_k": int(top_k), "first_expert": int(first_expert),
+             "routed_scaling_factor": float(routed_scaling_factor),
+             "norm_topk_prob": bool(norm_topk_prob)}
+    if force is not None:
+        attrs["force"] = force
+    helper.append_op("moe_ffn_held", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return out

@@ -8,6 +8,9 @@ serving lane lowers through:
   ragged     variable-length dense attention (serving prefill form)
   paged      page-table attention, fp32 and int8 pools (decode form)
   int8       dual-int8 storage quantization (weights + KV cache)
+  dsa        learned sparse attention over a paged latent cache:
+             indexer scores, exact top-k selection, latent attention
+  grouped    grouped matrix product over rows sorted by group (experts)
 
 Raw ``pl.pallas_call`` / ``pltpu`` outside this package is a lint
 error (tools/lint_kernels.py) unless marked ``# kernel: allow``.
@@ -31,6 +34,14 @@ from .int8 import (  # noqa: F401
     book_bytes_saved, bytes_saved, dequantize_lastdim, dequantize_weight,
     dual_int8_bytes, quantize_lastdim, quantize_weight,
 )
+from .dsa import (  # noqa: F401
+    dsa_indexer_scores, dsa_indexer_scores_reference, dsa_topk_select,
+    dsa_topk_select_reference, sparse_mla_attention,
+    sparse_mla_attention_reference,
+)
+from .grouped import (  # noqa: F401
+    grouped_matmul, grouped_matmul_reference,
+)
 from .paged import (  # noqa: F401
     paged_attention, paged_attention_quant,
     paged_attention_quant_reference, paged_attention_reference,
@@ -50,4 +61,8 @@ __all__ = [
     "quantize_lastdim", "dequantize_lastdim", "quantize_weight",
     "dequantize_weight", "dual_int8_bytes", "bytes_saved",
     "book_bytes_saved",
+    "dsa_indexer_scores", "dsa_indexer_scores_reference",
+    "dsa_topk_select", "dsa_topk_select_reference",
+    "sparse_mla_attention", "sparse_mla_attention_reference",
+    "grouped_matmul", "grouped_matmul_reference",
 ]

@@ -145,9 +145,11 @@ def _check_pool_shapes(op, q, scales=(), **pools):
                 f"apart, never the pool (docs/SERVING.md 'Decode lane')")
 
 
-def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
+def _online_softmax_step(s, v, acc_ref, m_ref, l_ref, p_dtype=None):
     """One kv-block update of the running (max, sum, acc) state — the
-    shared online-softmax spelling of every attention primitive."""
+    shared online-softmax spelling of every attention primitive.
+    ``p_dtype`` rounds the probabilities to the values' storage dtype
+    for the MXU (a bf16 cache); None keeps them float32."""
     m_prev, l_prev = m_ref[...], l_ref[...]
     s_max = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, jnp.broadcast_to(s_max, m_prev.shape))
@@ -157,7 +159,8 @@ def _online_softmax_step(s, v, acc_ref, m_ref, l_ref):
     l_ref[...] = l_prev * alpha + jnp.broadcast_to(
         jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
     acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p if p_dtype is None else p.astype(p_dtype), v,
+        preferred_element_type=jnp.float32)
 
 
 def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,

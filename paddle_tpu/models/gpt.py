@@ -44,6 +44,22 @@ class GPTConfig:
         d.update(kw)
         return cls(**d)
 
+    def decode_lane(self):
+        """This model's decode-lane declaration (serving/lane.py): a K
+        and a V row a token a layer, and the two paged programs below."""
+        import functools
+
+        from paddle_tpu.serving import lane
+
+        return lane.DecodeLane(
+            num_layers=self.num_layers, max_position=self.max_position,
+            cache_rows=functools.partial(
+                lane.kv_rows, self.num_heads,
+                self.hidden_size // self.num_heads),
+            build_decode_step=functools.partial(build_gpt_decode_step, self),
+            build_prefill_chunk=functools.partial(build_gpt_prefill_chunk,
+                                                  self))
+
 
 def _fc(x, size, name, act=None, init_std=0.02, nfd=2):
     return layers.fc(
@@ -577,72 +593,26 @@ def make_fake_lm_batch(cfg: GPTConfig, batch, seq_len, seed=0):
 # buffers, so the pool updates in place across steps, never copied.
 # ---------------------------------------------------------------------------
 
-KV_POOL_PREFIX = "@KVPOOL@"
-
-
-def kv_pool_var_names(num_layers, prefix=KV_POOL_PREFIX):
-    """The per-layer (K, V) pool var names the decode-lane programs and
-    serving.kv_pool.KVPool agree on."""
-    return [(f"{prefix}k_l{i}", f"{prefix}v_l{i}")
-            for i in range(num_layers)]
-
-
-def kv_pool_quant_var_names(num_layers, prefix=KV_POOL_PREFIX):
-    """The per-layer ((k_hi, k_lo, k_scale), (v_hi, v_lo, v_scale)) var
-    names of the dual-int8 pool (docs/KERNELS.md "int8 KV").  Each fp
-    pool var splits into an int8 hi/lo pair plus a per-vector fp32
-    scale; the triples keep the fp var name as their stem so dumps stay
-    greppable."""
-    out = []
-    for kn, vn in kv_pool_var_names(num_layers, prefix):
-        out.append(tuple(
-            (f"{nm}__qhi", f"{nm}__qlo", f"{nm}__scale")
-            for nm in (kn, vn)))
-    return out
-
-
 def _declare_pool_vars(cfg: GPTConfig, num_pages, page_size, dtype,
-                       prefix=KV_POOL_PREFIX):
-    # [P, pgs, n*d]: the heads side by side in the lane dimension, the
-    # ONE shape of the pool (kernels/primitives/paged.py "Shapes") — its
-    # default TPU layout is the one the paged kernel reads, so neither
-    # executable copies the pool
-    n, h = cfg.num_heads, cfg.hidden_size
-    block = fluid.default_main_program().global_block()
+                       prefix=None):
+    """Per layer ``(K, V)`` pool vars — for the int8 pool
+    ``((k_hi, k_lo, k_scale), (v_hi, v_lo, v_scale))`` — declared from
+    the model's cache rows (serving/lane.py: K and V, the heads side by
+    side, so that neither executable copies the pool)."""
+    from paddle_tpu.serving import lane
+
+    rows = lane.kv_rows(cfg.num_heads, cfg.hidden_size // cfg.num_heads,
+                        dtype)
+    layers_ = lane.declare_pool_vars(rows, cfg.num_layers, num_pages,
+                                     page_size, prefix or lane.POOL_PREFIX)
     if dtype == "int8":
-        # dual-int8 pool: hi/lo int8 [P, pgs, n*d] + one fp32 scale a
-        # head [P, pgs, n] per K/V (kernels/primitives/int8.py layout)
-        out = []
-        for k_names, v_names in kv_pool_quant_var_names(cfg.num_layers,
-                                                        prefix):
-            layer = []
-            for hi_n, lo_n, sc_n in (k_names, v_names):
-                layer.append(tuple([
-                    block.create_var(name=hi_n,
-                                     shape=[num_pages, page_size, h],
-                                     dtype="int8", persistable=True),
-                    block.create_var(name=lo_n,
-                                     shape=[num_pages, page_size, h],
-                                     dtype="int8", persistable=True),
-                    block.create_var(name=sc_n,
-                                     shape=[num_pages, page_size, n],
-                                     dtype="float32", persistable=True),
-                ]))
-            out.append(tuple(layer))
-        return out
-    out = []
-    for kn, vn in kv_pool_var_names(cfg.num_layers, prefix):
-        out.append(tuple(
-            block.create_var(name=nm,
-                             shape=[num_pages, page_size, h],
-                             dtype=dtype, persistable=True)
-            for nm in (kn, vn)))
-    return out
+        return [(v[:3], v[3:]) for v in layers_]
+    return layers_
 
 
 def build_gpt_decode_step(cfg: GPTConfig, pool_slots, num_pages,
                           page_size, max_pages, pool_dtype="float32",
-                          pool_prefix=KV_POOL_PREFIX, attn_force=None):
+                          pool_prefix=None, attn_force=None):
     """ONE token-level decode step over the paged KV pool — the single
     fixed-shape executable the continuous-batching scheduler dispatches
     every step (zero steady-state recompiles: every feed shape below is
@@ -727,7 +697,7 @@ def build_gpt_decode_step(cfg: GPTConfig, pool_slots, num_pages,
 
 def build_gpt_prefill_chunk(cfg: GPTConfig, chunk_len, num_pages,
                             page_size, max_pages, pool_dtype="float32",
-                            pool_prefix=KV_POOL_PREFIX, attn_force=None):
+                            pool_prefix=None, attn_force=None):
     """One prefill CHUNK of a single sequence through the paged pool —
     the phase-split half of the decode lane: long prompts stream
     through this fixed-shape executable `ceil(P/chunk_len)` times

@@ -29,5 +29,6 @@ from . import nn_extra_ops  # noqa: F401
 from . import detection_extra_ops  # noqa: F401
 from . import fused_ops  # noqa: F401
 from . import decode_ops  # noqa: F401
+from . import mla_ops  # noqa: F401
 from . import compat_ops  # noqa: F401
 from . import interop_tail_ops  # noqa: F401

@@ -490,9 +490,15 @@ def _ragged_attention(ctx, q, k, v, lengths, attrs):
 @simple_op("moe_ffn", ["X", "GateW", "W1", "B1", "W2", "B2"], ["Out"],
            optional=("B1", "B2"))
 def _moe_ffn(ctx, x, gate_w, w1, b1, w2, b2, attrs):
-    """Mixture-of-experts FFN with top-k gating (no reference analog — the
-    reference has no MoE; this is the expert-parallel building block,
-    SURVEY.md §2.8 'Expert parallel').
+    """Mixture-of-experts FFN with SOFTMAX top-k gating: softmax over all
+    experts' logits, the top_k probabilities kept and renormalised to sum
+    to 1, biased two-matrix experts with GELU (or ReLU) — the
+    GShard/Switch-style gate, trainable (auto grad).  No reference analog
+    — the reference has no MoE; this is the expert-parallel building
+    block, SURVEY.md §2.8 'Expert parallel'.  The sigmoid-scored,
+    bias-selected gate over SwiGLU experts of which a process holds a
+    share (inference only, grouped product instead of dense dispatch) is
+    ``moe_ffn_held`` (ops/mla_ops.py).
 
     Dense-dispatch formulation: every expert runs over every token and the
     gate weights combine them.  That trades FLOPs for a perfectly static,

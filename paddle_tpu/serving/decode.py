@@ -8,16 +8,23 @@ STEP over a paged KV pool (serving/kv_pool.py), with a prefill/decode
 phase split so long prompts chunk through a separate fixed-shape
 prefill executable and never stall the running decode step.
 
+The engine serves any model that hands it a decode-lane declaration
+(serving/lane.py: the cache rows a token leaves in each layer, and the
+builders of the two programs below) from its config's ``decode_lane()``:
+models/gpt.py (a K and a V row, dense paged attention) and models/glm.py
+(a latent row and an indexer key, learned sparse attention, held
+experts) go through the same engine, scheduler and pool.
+
 Execution model — exactly TWO compiled signatures in steady state:
 
-  prefill chunk   models/gpt.py build_gpt_prefill_chunk — [1, C] tokens
-                  of ONE sequence, K/V written into whole pool pages,
-                  attention over the sequence's prefix through its page
-                  table.  A P-token prompt is ceil(P/C) calls.
-  decode step     models/gpt.py build_gpt_decode_step — [pool_slots]
+  prefill chunk   the lane's ``build_prefill_chunk`` — [1, C] tokens of
+                  ONE sequence, its cache rows written into whole pool
+                  pages, attention over the sequence's prefix through
+                  its page table.  A P-token prompt is ceil(P/C) calls.
+  decode step     the lane's ``build_decode_step`` — [pool_slots]
                   one-token rows (batch-slot dim padded to the pool
-                  size), scatter-write + gather-read against the page
-                  tables, greedy argmax out.
+                  size), scatter-write + read against the page tables,
+                  greedy argmax out.
 
 Every feed shape is static, so after the first prefill chunk and the
 first decode step NOTHING recompiles — the decode-bench rung
@@ -37,7 +44,8 @@ Scheduling loop (one scheduler thread per engine):
 Every iteration is one ``turn`` span (observability/profiling.py, lane
 ``decode``) whose children name where the host's time goes:
 ``prefill.pages``, ``prefill.feed_build``, ``prefill.run``, ``admit``,
-``decode.pages``, ``decode.feed_build``, ``decode.run``, ``emit``; the
+``decode.pages``, ``decode.feed_build``, ``decode.run``, ``emit`` (which
+also books the pool's bytes in use by declared row tensor); the
 executor's own phases are children of the two ``*.run`` spans, which also
 feed ``pt_decode_step_seconds`` and ``pt_decode_phase_seconds_total``.
 
@@ -183,6 +191,16 @@ def _m_pages_in_use():
         labels=("engine",))
 
 
+def _m_cache_bytes():
+    from paddle_tpu import observability as obs
+
+    return obs.gauge(
+        "pt_decode_cache_bytes",
+        "Device bytes the pages in use hold, by declared cache row "
+        "tensor (k / v; latent / index), over every layer",
+        labels=("engine", "row"))
+
+
 def _m_evictions():
     from paddle_tpu import observability as obs
 
@@ -273,9 +291,12 @@ class DecodeRequest:
 
 
 class DecodeEngine:
-    """Continuous-batching greedy decode over a trained GPT's parameters.
+    """Continuous-batching greedy decode over a model's parameters.
 
-    ``scope`` must already hold the model parameters (train in-process,
+    ``cfg`` is the model's config object; its ``decode_lane()`` returns
+    the decode-lane declaration (serving/lane.py) the engine builds its
+    pool and its two programs from.  ``scope`` must already hold the
+    model parameters (train in-process,
     or `fluid.io`-load a checkpoint into it); the engine installs the
     pool vars beside them and builds its two programs against the same
     names, so the decode lane and the training/whole-sequence lanes
@@ -283,7 +304,8 @@ class DecodeEngine:
 
     Sizing: ``pool_slots`` concurrent decoding sequences;
     ``max_len`` >= prompt + max_new_tokens per request (defaults to
-    cfg.max_position); ``num_pages`` defaults to every slot at full
+    the lane's max_position); ``prefill_chunk`` and ``pool_dtype``
+    default to the lane's; ``num_pages`` defaults to every slot at full
     length (+1 trash) — shrink it to exercise eviction, the lane stays
     correct (requests replay deterministically)."""
 
@@ -296,24 +318,27 @@ class DecodeEngine:
         from paddle_tpu import fluid
         from paddle_tpu.fluid import flags as _flags
         from paddle_tpu.fluid.executor import global_scope
-        from paddle_tpu.models import gpt as _gpt
 
+        lane = cfg.decode_lane()
         if pool_dtype is None:
             # FLAGS_int8_kv_cache picks the dual-int8 pool
             # (docs/KERNELS.md "int8 KV"); explicit pool_dtype wins
             pool_dtype = ("int8" if _flags.flag("int8_kv_cache")
-                          else "float32")
+                          else lane.pool_dtype)
         self.cfg = cfg
+        self.lane = lane
         self.name = name
         self.scope = scope if scope is not None else global_scope()
         self.pool_slots = int(pool_slots)
         page_size = int(page_size)
-        max_len = int(max_len if max_len is not None else cfg.max_position)
-        if max_len > cfg.max_position:
+        max_len = int(max_len if max_len is not None else lane.max_position)
+        if max_len > lane.max_position:
             raise ValueError(
                 f"max_len {max_len} exceeds the model's max_position "
-                f"{cfg.max_position}")
+                f"{lane.max_position}")
         max_pages = -(-max_len // page_size)
+        if prefill_chunk is None:
+            prefill_chunk = lane.prefill_chunk
         if prefill_chunk is None:
             prefill_chunk = min(max(page_size, 32), max_len)
             prefill_chunk -= prefill_chunk % page_size
@@ -334,10 +359,9 @@ class DecodeEngine:
         self.prefill_chunk = prefill_chunk
         self._max_queue = int(_flags.flag("serving_max_queue")
                               if max_queue is None else max_queue)
-        self.pool = KVPool(cfg.num_layers, cfg.num_heads,
-                           cfg.hidden_size // cfg.num_heads,
-                           num_pages, page_size, max_pages,
-                           dtype=pool_dtype)
+        rows = lane.cache_rows(pool_dtype)
+        self.pool = KVPool(lane.num_layers, rows, num_pages, page_size,
+                           max_pages)
         self.pool.install(self.scope)
         if pool_dtype == "int8":
             # book the modeled HBM saving of the dual-int8 pool vs the
@@ -345,23 +369,28 @@ class DecodeEngine:
             # proven against (docs/OBSERVABILITY.md)
             from paddle_tpu.kernels import primitives as _prims
 
+            fp32 = KVPool(lane.num_layers, lane.cache_rows("float32"),
+                          num_pages, page_size, max_pages)
             _prims.book_bytes_saved(
                 "kv_cache",
-                self.pool.modeled_bytes_fp32() - self.pool.modeled_bytes())
+                fp32.modeled_bytes() - self.pool.modeled_bytes())
+        # the lane's device counters: on the device, fetched by no step
+        self._counters_seen = {}
+        self._install_device_counters()
 
         # two programs, two fixed-shape executables — built once, against
         # the SAME parameter names the training lanes use
         dec_prog, dec_start = fluid.Program(), fluid.Program()
         with fluid.program_guard(dec_prog, dec_start), \
                 fluid.unique_name.guard():
-            self._dec_feeds, dec_tok, _ = _gpt.build_gpt_decode_step(
-                cfg, self.pool_slots, num_pages, page_size, max_pages,
+            self._dec_feeds, dec_tok, _ = lane.build_decode_step(
+                self.pool_slots, num_pages, page_size, max_pages,
                 pool_dtype=pool_dtype, attn_force=attn_force)
         pf_prog, pf_start = fluid.Program(), fluid.Program()
         with fluid.program_guard(pf_prog, pf_start), \
                 fluid.unique_name.guard():
-            self._pf_feeds, pf_tok, _ = _gpt.build_gpt_prefill_chunk(
-                cfg, prefill_chunk, num_pages, page_size, max_pages,
+            self._pf_feeds, pf_tok, _ = lane.build_prefill_chunk(
+                prefill_chunk, num_pages, page_size, max_pages,
                 pool_dtype=pool_dtype, attn_force=attn_force)
         self._dec_prog, self._dec_fetch = dec_prog, dec_tok.name
         self._pf_prog, self._pf_fetch = pf_prog, pf_tok.name
@@ -457,6 +486,9 @@ class DecodeEngine:
         self._queue_wait = _m_queue_wait().labels(engine=e)
         self._ttft = _m_ttft().labels(engine=e)
         self._token_gap = _m_token_gap().labels(engine=e)
+        self._cache_bytes = {
+            row: _m_cache_bytes().labels(engine=e, row=row.name)
+            for row in self.pool.rows}
         # the SAME cross-lane families engine.py owns: end-to-end decode
         # request latency (submit -> future resolve, exemplar-bearing)
         # and admitted-request counts, with this engine's name as model
@@ -625,6 +657,8 @@ class DecodeEngine:
         number of executables warmed (2)."""
         self._run_prefill_feed(**self._warm_prefill_args(), warm=True)
         self._run_decode_feed([], warm=True)
+        # the warm chunk's one trash-page token is no traffic
+        self._install_device_counters()
         return 2
 
     def lower(self, sharding=None):
@@ -796,7 +830,13 @@ class DecodeEngine:
                 self._occupancy.set(
                     sum(s is not None for s in self._slots)
                     / self.pool_slots)
-                self._pages_gauge.set(self.pool.pages_in_use())
+                in_use = self.pool.pages_in_use()
+                self._pages_gauge.set(in_use)
+                # bytes in use by declared row tensor: part of `emit`, so
+                # that the turn's children stay the eight the benchmark's
+                # idle split knows (readers/host_gap.py)
+                for row, gauge in self._cache_bytes.items():
+                    gauge.set(self.pool.row_bytes(row, in_use))
                 with self._cv:
                     self._depth.set(len(self._queue))
         self._turn_ctr.inc()
@@ -911,7 +951,7 @@ class DecodeEngine:
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
         pos = np.minimum(pos0 + np.arange(c, dtype=np.int64),
-                         self.cfg.max_position - 1)[None, :]
+                         self.lane.max_position - 1)[None, :]
         return {
             "pf_tok": tok,
             "pf_pos": pos,
@@ -1038,6 +1078,36 @@ class DecodeEngine:
             self._turn_run_s += run.seconds
             self._steps += 1
         return np.asarray(out).reshape(-1)
+
+    def _install_device_counters(self):
+        """The lane's device counters beside the pool: zeros on the
+        device (kept if already there with the declared length); what
+        they hold now is what the next booking counts from."""
+        import jax.numpy as jnp
+
+        for name, length in self.lane.device_counters:
+            cur = self.scope.get(name)
+            if cur is None or tuple(np.shape(cur)) != (length,):
+                self.scope.set(name, jnp.zeros((length,), jnp.int32))
+            self._counters_seen[name] = np.asarray(
+                self.scope.get(name)).astype(np.int64)
+
+    def book_device_counters(self):
+        """Read the lane's device counters and hand what each gained
+        since the last reading to the lane's ``book_counters``.  Only a
+        caller that asks pays for it (a trace reader, a test): the
+        programs add to the counters in place and no step fetches them,
+        so this read waits for the step under way."""
+        if not self.lane.device_counters:
+            return
+        with self._exec_lock:
+            now = {name: np.asarray(self.scope.get(name)).astype(np.int64)
+                   for name, _ in self.lane.device_counters}
+        # int32 on the device: a difference survives a wrap
+        gained = {name: (now[name] - self._counters_seen[name]) % (1 << 32)
+                  for name in now}
+        self._counters_seen = now
+        self.lane.book_counters(self.name, gained)
 
     def _finish(self, slot, req):
         self._slots[slot] = None

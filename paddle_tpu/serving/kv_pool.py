@@ -1,20 +1,20 @@
-"""Paged KV-cache slot pool — the decode lane's memory allocator.
+"""Paged cache slot pool — the decode lane's memory allocator.
 
-vLLM-style paged memory for KV caches on the executor's scope model:
-the pool is one persistable program var per (layer, K/V) shaped
-``[num_pages, page_size, n_heads * head_dim]`` (the heads side by side
-in the last dimension: the shape whose default TPU layout the paged
-kernel reads, kernels/primitives/paged.py "Shapes"), donated by the
-executor every step so it updates in place; a sequence's cache is a
-LIST of page ids (its page table), not a contiguous slab.  Admission,
-growth and eviction therefore move ZERO cache memory — they edit
-host-side page lists — and the decode step stays one fixed-shape
-executable (models/gpt.py build_gpt_decode_step) no matter how
-sequences come and go.
+vLLM-style paged memory for a model's cache on the executor's scope
+model: the pool is one persistable program var per (layer, declared
+cache row) shaped ``[num_pages, page_size, width]`` — a K and a V row
+with the heads side by side for dense attention, a latent row and an
+indexer key for latent sparse attention (serving/lane.py ``CacheRow``);
+every row tensor lives under ONE page table.  The executor donates the
+vars every step so they update in place; a sequence's cache is a LIST
+of page ids (its page table), not a contiguous slab.  Admission, growth
+and eviction therefore move ZERO cache memory — they edit host-side
+page lists — and the decode step stays one fixed-shape executable no
+matter how sequences come and go.
 
 Page 0 is the TRASH page: never allocated, the write target of inactive
 decode slots and padded prefill tails.  Readers can't observe it —
-paged attention masks every position past a row's own length.
+every attention masks positions past a row's own length.
 
 This module is the pure allocator (page lists, free-list reuse,
 accounting); scheduling policy — WHO gets evicted under pressure — lives
@@ -31,6 +31,7 @@ import collections
 import numpy as np
 
 from .errors import PoolExhaustedError
+from .lane import POOL_PREFIX, pool_var_names
 
 __all__ = ["KVPool", "PoolExhaustedError"]
 
@@ -40,36 +41,28 @@ TRASH_PAGE = 0
 class KVPool:
     """Host-side page allocator + the device-resident pool vars.
 
-    ``num_pages`` INCLUDES the trash page, so ``num_pages - 1`` pages
-    are allocatable; a single sequence needs up to ``max_pages_per_seq``
-    of them (the constructor enforces one sequence always fits —
-    otherwise eviction could never unblock the allocator)."""
+    ``rows`` is the model's declaration of what a token leaves in each
+    layer (``lane.CacheRow`` name, width, dtype); ``num_pages`` INCLUDES
+    the trash page, so ``num_pages - 1`` pages are allocatable; a single
+    sequence needs up to ``max_pages_per_seq`` of them (the constructor
+    enforces one sequence always fits — otherwise eviction could never
+    unblock the allocator)."""
 
-    def __init__(self, num_layers, num_heads, head_dim, num_pages,
-                 page_size, max_pages_per_seq, dtype="float32",
-                 prefix=None):
-        from paddle_tpu.models.gpt import KV_POOL_PREFIX, kv_pool_var_names
-
+    def __init__(self, num_layers, rows, num_pages, page_size,
+                 max_pages_per_seq, prefix=None):
         if num_pages - 1 < max_pages_per_seq:
             raise ValueError(
                 f"KV pool of {num_pages} pages (1 reserved for trash) "
                 f"cannot hold one full sequence of {max_pages_per_seq} "
                 f"pages — raise num_pages or lower max_len")
         self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        self.rows = list(rows)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.max_pages_per_seq = int(max_pages_per_seq)
-        self.dtype = dtype
-        self.prefix = KV_POOL_PREFIX if prefix is None else prefix
-        self.var_names = kv_pool_var_names(self.num_layers, self.prefix)
-        if dtype == "int8":
-            from paddle_tpu.models.gpt import kv_pool_quant_var_names
-            self.quant_var_names = kv_pool_quant_var_names(
-                self.num_layers, self.prefix)
-        else:
-            self.quant_var_names = None
+        self.prefix = POOL_PREFIX if prefix is None else prefix
+        self.var_names = pool_var_names(self.rows, self.num_layers,
+                                        self.prefix)
         # LIFO free list: a just-freed page is the next one handed out,
         # so a churning slot's working set stays the same physical pages
         self._free = collections.deque(range(1, self.num_pages))
@@ -84,60 +77,36 @@ class KVPool:
     def install(self, scope):
         """Zero the pool vars into `scope` (idempotent on shape AND
         dtype match — an engine rebuild over a live scope keeps the
-        resident pool; a rebuild with a different pool_dtype must NOT,
+        resident pool; a rebuild with a different pool dtype must NOT,
         or every later write trips the dtype guard blaming the
-        payload)."""
-        # the one place that knows the heads and their width apart: the
-        # pool itself holds them flattened into its last dimension
-        shape = (self.num_pages, self.page_size,
-                 self.num_heads * self.head_dim)
-        if self.dtype == "int8":
-            # dual-int8 pool: hi/lo int8 + one fp32 scale a head_dim
-            # vector per K/V (docs/KERNELS.md "int8 KV")
-            sc_shape = shape[:-1] + (self.num_heads,)
-            for k_names, v_names in self.quant_var_names:
-                for hi_n, lo_n, sc_n in (k_names, v_names):
-                    for name, shp, dt in ((hi_n, shape, "int8"),
-                                          (lo_n, shape, "int8"),
-                                          (sc_n, sc_shape, "float32")):
-                        cur = scope.get(name)
-                        if (cur is None
-                                or tuple(np.shape(cur)) != shp
-                                or np.asarray(cur).dtype != np.dtype(dt)):
-                            scope.set(name, np.zeros(shp, dtype=dt))
-            return
-        want = np.dtype(self.dtype)
-        for kn, vn in self.var_names:
-            for name in (kn, vn):
+        payload).  The zeros are made on the device: a pool is
+        gigabytes, and host zeros would cross to the chip."""
+        import jax.numpy as jnp
+
+        for names in self.var_names:
+            for name, row in zip(names, self.rows):
+                shape = (self.num_pages, self.page_size, row.width)
                 cur = scope.get(name)
                 if (cur is None or tuple(np.shape(cur)) != shape
-                        or np.asarray(cur).dtype != want):
-                    scope.set(name, np.zeros(shape, dtype=self.dtype))
+                        or str(getattr(cur, "dtype", "")) != row.dtype):
+                    scope.set(name, jnp.zeros(shape, dtype=row.dtype))
 
     # -- modeled bytes ------------------------------------------------------
 
+    def row_bytes(self, row, pages=None):
+        """Device bytes of one declared row tensor over every layer:
+        resident (``pages`` None) or of ``pages`` pages."""
+        import jax.numpy as jnp  # its dtypes know bfloat16
+
+        pages = self.num_pages if pages is None else pages
+        return (pages * self.page_size * row.width
+                * jnp.dtype(row.dtype).itemsize * self.num_layers)
+
     def modeled_bytes(self):
-        """Modeled device bytes of the resident pool across all layers
-        and both K/V — dual-int8 accounting when dtype == 'int8'
-        (kernels/primitives/int8.py dual_int8_bytes with a per-head_dim
-        scale block), plain dtype-width bytes otherwise."""
-        n_vec = self.num_pages * self.page_size * self.num_heads
-        n_elems = n_vec * self.head_dim
-        per_var = (self._dual_int8_bytes(n_elems)
-                   if self.dtype == "int8"
-                   else n_elems * np.dtype(self.dtype).itemsize)
-        return per_var * 2 * self.num_layers
-
-    def modeled_bytes_fp32(self):
-        """The same pool's modeled bytes at fp32 — the denominator of
-        the int8 saving claim (bench.py PT_BENCH_RAGGED rung)."""
-        n_elems = (self.num_pages * self.page_size * self.num_heads
-                   * self.head_dim)
-        return n_elems * 4 * 2 * self.num_layers
-
-    def _dual_int8_bytes(self, n_elems):
-        from paddle_tpu.kernels import primitives as _prims
-        return _prims.dual_int8_bytes(n_elems, self.head_dim)
+        """Device bytes of the resident pool: every declared row tensor
+        of every layer (for the dual-int8 rows that is kernels/
+        primitives/int8.py's ``dual_int8_bytes``)."""
+        return sum(self.row_bytes(row) for row in self.rows)
 
     # -- allocation ---------------------------------------------------------
 

@@ -1,0 +1,124 @@
+"""The decode-lane declaration: what a model file hands
+``serving.DecodeEngine`` so that one engine, one scheduler and one
+``KVPool`` serve it (docs/SERVING.md "Decode-lane declaration").
+
+A model declares
+
+- the **cache rows** a token leaves in each layer: a list of named row
+  tensors of any width and dtype (``CacheRow``).  Dense multi-head
+  attention leaves a K and a V row (``kv_rows``); latent attention with
+  a learned indexer leaves a latent row and an indexer key.  The pool
+  allocates every row tensor ``[num_pages, page_size, width]`` under ONE
+  page table;
+- the **two program builders** of the lane's two fixed-shape
+  executables, a decode step over the pool's slots and a prefill chunk
+  of one sequence, both against the model's own parameter names and the
+  engine's feed names (``dec_*`` / ``pf_*``, models/gpt.py).
+
+The model's config class returns the declaration from ``decode_lane()``;
+the engine asks for nothing else, so ``serving/decode.py`` imports no
+model module.
+"""
+
+from __future__ import annotations
+
+import collections
+
+__all__ = ["CacheRow", "DecodeLane", "DeviceCounter", "POOL_PREFIX",
+           "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars"]
+
+POOL_PREFIX = "@KVPOOL@"
+
+# one row tensor of the cache: ``width`` values of ``dtype`` a token a layer
+CacheRow = collections.namedtuple("CacheRow", ("name", "width", "dtype"))
+# an int32 vector of ``length`` counts that the lane's programs add to in
+# place, under the persistable var ``name``
+DeviceCounter = collections.namedtuple("DeviceCounter", ("name", "length"))
+
+
+def lane_padded(width):
+    """``width`` rounded up to whole 128-lane tiles: the width to STORE a
+    cache row at.  XLA:TPU lays ``[pages, page, 576]`` out with the page
+    index minor-most (576 would pad to 640 lanes anyway), and every
+    executable then copies the whole pool into the row-major layout its
+    kernels read and back (PERF.md finding 4; asked of the compiler
+    chip-free, PR 27: ten whole-pool copies and 6.1 GB of temporaries in
+    one decode step).  Stored 640 wide the default layout is row-major
+    and nothing is copied; the pad lanes hold zeros."""
+    return -(-int(width) // 128) * 128
+
+
+def kv_rows(num_heads, head_dim, dtype="float32"):
+    """The rows dense multi-head attention leaves: K and V, the heads
+    side by side (kernels/primitives/paged.py "Shapes").  ``int8`` is the
+    dual-int8 pool (docs/KERNELS.md "int8 KV"): hi and lo int8 rows plus
+    one float32 scale a head, for K and for V."""
+    width = int(num_heads) * int(head_dim)
+    if dtype == "int8":
+        return [CacheRow(f"{kv}__{part}", w, dt) for kv in ("k", "v")
+                for part, w, dt in (("qhi", width, "int8"),
+                                    ("qlo", width, "int8"),
+                                    ("scale", int(num_heads), "float32"))]
+    return [CacheRow("k", width, dtype), CacheRow("v", width, dtype)]
+
+
+def pool_var_names(rows, num_layers, prefix=POOL_PREFIX):
+    """Per layer, the pool var name of each declared row, in order."""
+    return [tuple(f"{prefix}{row.name}_l{i}" for row in rows)
+            for i in range(int(num_layers))]
+
+
+def declare_pool_vars(rows, num_layers, num_pages, page_size,
+                      prefix=POOL_PREFIX):
+    """The pool's persistable vars in the program being built: per layer
+    one ``[num_pages, page_size, width]`` var a declared row.  That shape
+    keeps the default row-major TPU layout for any width of 128 or more
+    (and the kernels read it as stored), so no executable copies a pool
+    tensor (PERF.md finding 4)."""
+    from paddle_tpu import fluid
+
+    block = fluid.default_main_program().global_block()
+    return [tuple(block.create_var(
+        name=name, shape=[int(num_pages), int(page_size), row.width],
+        dtype=row.dtype, persistable=True)
+        for name, row in zip(names, rows))
+        for names in pool_var_names(rows, num_layers, prefix)]
+
+
+class DecodeLane:
+    """A model's decode-lane declaration.
+
+    ``cache_rows(pool_dtype)`` -> [CacheRow]: what a token leaves in each
+    of ``num_layers`` layers at that storage dtype (raise for a dtype the
+    model has no kernels for).
+    ``build_decode_step(pool_slots, num_pages, page_size, max_pages,
+    pool_dtype=, attn_force=)`` and ``build_prefill_chunk(chunk_len,
+    num_pages, page_size, max_pages, pool_dtype=, attn_force=)`` build
+    into the default main program and return ``(feed_names, next_tok,
+    logprobs)``.
+    ``pool_dtype`` / ``prefill_chunk``: the model's defaults where the
+    engine is given none.
+    ``device_counters``: ``[DeviceCounter]`` the programs keep on the
+    device: persistable int32 vectors they add to in place and no step
+    fetches.  The engine installs them as zeros beside the pool and
+    reads them only when ``DecodeEngine.book_device_counters()`` is
+    called (a trace reader, a test), handing what each gained to ``book_counters(engine_name, {name: gained})``,
+    the model's own mapping onto metric families.  What they count is
+    the model's business: the engine knows their names and lengths."""
+
+    def __init__(self, *, num_layers, max_position, cache_rows,
+                 build_decode_step, build_prefill_chunk,
+                 pool_dtype="float32", prefill_chunk=None,
+                 device_counters=(), book_counters=None):
+        self.num_layers = int(num_layers)
+        self.max_position = int(max_position)
+        self.cache_rows = cache_rows
+        self.build_decode_step = build_decode_step
+        self.build_prefill_chunk = build_prefill_chunk
+        self.pool_dtype = pool_dtype
+        self.prefill_chunk = prefill_chunk
+        self.device_counters = list(device_counters)
+        self.book_counters = book_counters
+        if self.device_counters and book_counters is None:
+            raise ValueError("DecodeLane: device_counters without "
+                             "book_counters would never be read")

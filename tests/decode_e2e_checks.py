@@ -272,6 +272,7 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
     argmax — quantization happens once per append, so the error does
     not compound across steps."""
     from paddle_tpu.serving.kv_pool import KVPool
+    from paddle_tpu.serving.lane import kv_rows
 
     n, d = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     page_size, max_pages, num_pages, steps = 4, 8, 9, 20
@@ -291,8 +292,8 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
         # both pools in the one shape every pool var has (KVPool.install
         # is the one place that knows heads and their width apart)
         for dtype, prefix in (("float32", "@KVF@"), ("int8", "@KVQ@")):
-            KVPool(cfg.num_layers, n, d, num_pages, page_size, max_pages,
-                   dtype=dtype, prefix=prefix).install(scope)
+            KVPool(cfg.num_layers, kv_rows(n, d, dtype), num_pages,
+                   page_size, max_pages, prefix=prefix).install(scope)
 
         toks = np.random.RandomState(0).randint(
             1, cfg.vocab_size, steps)

@@ -10,6 +10,7 @@ from paddle_tpu import fluid, serving
 from paddle_tpu.models import gpt
 from paddle_tpu.serving.errors import PoolExhaustedError
 from paddle_tpu.serving.kv_pool import KVPool
+from paddle_tpu.serving.lane import kv_rows
 
 CFG = dict(num_layers=2, hidden_dropout=0.0, use_flash_attention=False)
 
@@ -20,7 +21,7 @@ CFG = dict(num_layers=2, hidden_dropout=0.0, use_flash_attention=False)
 
 
 def _pool(num_pages=9, page_size=4, max_pages=4):
-    return KVPool(num_layers=2, num_heads=4, head_dim=16,
+    return KVPool(num_layers=2, rows=kv_rows(4, 16),
                   num_pages=num_pages, page_size=page_size,
                   max_pages_per_seq=max_pages)
 
@@ -59,7 +60,7 @@ def test_pool_exhaustion_and_lifo_reuse():
 
 def test_pool_rejects_sub_sequence_sizing():
     with pytest.raises(ValueError, match="cannot hold one full"):
-        KVPool(num_layers=1, num_heads=2, head_dim=8, num_pages=4,
+        KVPool(num_layers=1, rows=kv_rows(2, 8), num_pages=4,
                page_size=4, max_pages_per_seq=4)
 
 
@@ -83,8 +84,8 @@ def test_pool_padded_table_and_install():
     # ... but NOT on a dtype change: a rebuild with a different
     # pool_dtype must re-install, or every later write trips the dtype
     # guard blaming the payload instead of the stale resident pool
-    p16 = KVPool(num_layers=2, num_heads=4, head_dim=16, num_pages=9,
-                 page_size=4, max_pages_per_seq=4, dtype="float16")
+    p16 = KVPool(num_layers=2, rows=kv_rows(4, 16, "float16"), num_pages=9,
+                 page_size=4, max_pages_per_seq=4)
     p16.install(scope)
     re = np.asarray(scope.get(p16.var_names[0][0]))
     assert str(re.dtype) == "float16" and re.max() == 0.0

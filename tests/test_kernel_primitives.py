@@ -489,15 +489,16 @@ def test_kv_pool_modeled_bytes_halved():
     model the pool is at most 55% (head_dim >= 32) — the counter-proven
     half of the int8-KV acceptance."""
     from paddle_tpu.serving.kv_pool import KVPool
+    from paddle_tpu.serving.lane import kv_rows
 
-    pool = KVPool(num_layers=2, num_heads=2, head_dim=32, num_pages=17,
-                  page_size=8, max_pages_per_seq=8, dtype="int8")
-    fp32 = pool.modeled_bytes_fp32()
+    pool = KVPool(num_layers=2, rows=kv_rows(2, 32, "int8"), num_pages=17,
+                  page_size=8, max_pages_per_seq=8)
+    fp32 = 17 * 8 * 2 * 32 * 4 * 2 * 2  # elements x 4 bytes x K,V x layers
     q = pool.modeled_bytes()
     assert q <= 0.55 * fp32
     # and the fp32 pool models exactly its dtype width
-    pool_fp = KVPool(num_layers=2, num_heads=2, head_dim=32, num_pages=17,
-                     page_size=8, max_pages_per_seq=8, dtype="float32")
+    pool_fp = KVPool(num_layers=2, rows=kv_rows(2, 32), num_pages=17,
+                     page_size=8, max_pages_per_seq=8)
     assert pool_fp.modeled_bytes() == fp32
 
 

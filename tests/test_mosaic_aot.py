@@ -26,7 +26,7 @@ from paddle_tpu.fluid.platform_utils import lowering_for
 from paddle_tpu.kernels import fused_update
 from paddle_tpu.kernels import primitives as prims
 from paddle_tpu.kernels.quantized_collectives import quantize_block_scaled
-from paddle_tpu.models import bert, gpt
+from paddle_tpu.models import bert, glm, gpt
 from paddle_tpu.parallel.data_parallel import DataParallelRunner
 
 HEADS, HEAD_DIM = 12, 64  # BERT-base and GPT-base
@@ -269,3 +269,114 @@ def test_decode_engine_executables(chip, size, pool_dtype):
                         _aliased_parameters(hlo)
         finally:
             engine.close()
+
+
+# ---------------------------------------------------------------------------
+# GLM-5 through the decode lane (benchmark/configs/glm-5-ep16.json): the
+# three sparse-attention operations and the grouped product at the
+# published widths, and the engine's two executables
+# ---------------------------------------------------------------------------
+
+_GLM_PAGES, _GLM_PAGE, _GLM_MAX_PAGES = 4129, 128, 258
+
+
+@pytest.mark.parametrize("b,t", [(16, 1), (1, 512)])
+def test_dsa_kernels_at_glm5_widths(chip, b, t):
+    """Indexer scores (32 heads of 128 over the paged indexer cache),
+    the exact top-2048 selection and latent attention (64 heads, rows
+    stored 640 wide) as a decode step and as a 512-token chunk see
+    them, at a 33k-token page table."""
+    bf = jnp.bfloat16
+    table, starts = ((b, _GLM_MAX_PAGES), jnp.int32), ((b,), jnp.int32)
+    padded = 264 * _GLM_PAGE
+    hlo = _compile(
+        lambda q, w, pages, pt, qs: prims.dsa_indexer_scores(q, w, pages,
+                                                             pt, qs),
+        chip, ((b, t, 32, 128), bf), ((b, t, 32), jnp.float32),
+        ((_GLM_PAGES, _GLM_PAGE, 128), bf), table, starts)
+    assert _mosaic_calls(hlo) == 1 and "%dsa_indexer_scores" in hlo
+    assert f"f32[{b},{t},{padded}]" in hlo
+    hlo = _compile(lambda s: prims.dsa_topk_select(s, 2048), chip,
+                   ((b, t, padded), jnp.float32))
+    assert _mosaic_calls(hlo) == 1 and "%dsa_topk_select" in hlo
+    assert "sort(" not in hlo and "approx" not in hlo.lower()
+    hlo = _compile(
+        lambda ql, qr, pages, pt, sel, qs: prims.sparse_mla_attention(
+            ql, qr, pages, pt, sel, qs, sm_scale=1.0 / 16),
+        chip, ((b, t, 64, 512), jnp.float32), ((b, t, 64, 64), jnp.float32),
+        ((_GLM_PAGES, _GLM_PAGE, 640), bf), table,
+        ((b, t, padded), jnp.float32), starts)
+    assert _mosaic_calls(hlo) == 1 and "%sparse_mla_attention" in hlo
+    assert _pool_copies(hlo, _GLM_PAGES, _GLM_PAGE) == []
+
+
+@pytest.mark.parametrize("rows", [128, 4096])
+@pytest.mark.parametrize("k,n", [(6144, 2048), (2048, 6144)])
+def test_grouped_matmul_at_glm5_widths(chip, rows, k, n):
+    """The expert layer's product over 16 held experts: a decode step's
+    128 pick rows and a chunk's 4096."""
+    hlo = _compile(lambda x, w, g: prims.grouped_matmul(x, w, g), chip,
+                   ((rows, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16),
+                   ((16,), jnp.int32))
+    assert _mosaic_calls(hlo) == 1 and "%grouped_matmul" in hlo
+
+
+def test_glm_decode_engine_executables(chip):
+    """The prefill chunk and the decode step of GLM-5 at the benchmark's
+    widths, pool and slots (two of its five layers: the dense one and an
+    expert one): per layer one indexer, one selection and one attention
+    Mosaic call,
+    three grouped products an expert layer, and BOTH kinds of cache
+    state go through both executables UNCOPIED — every latent and
+    indexer pool parameter arrives row-major, is donated, and no copy
+    or transpose of a whole pool tensor is left.  With the latent row
+    stored 576 wide the decode step compiled to ten whole-pool copies
+    and 6.1 GB of temporaries (serving/lane.py lane_padded)."""
+    import json
+    import os
+
+    import ml_dtypes
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5-ep16.json")) as f:
+        config = json.load(f)
+    args = dict(config["builder"]["config_args"], num_hidden_layers=2)
+    cfg = glm.GLMConfig(**args)
+    lm, lm_start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
+        glm.build_glm_lm(cfg)
+    scope = fluid.Scope()
+    for p in lm.global_block().all_parameters():
+        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                 else np.dtype(p.dtype))
+        # shapes are all a lowering reads: no 1.5 B parameters on the host
+        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                          tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-glm",
+        auto_start=False)
+    assert engine.prefill_chunk == 512
+    assert engine.pool.num_pages == _GLM_PAGES
+    try:
+        with lowering_for("tpu"):
+            for lowered in engine.lower(sharding=chip):
+                hlo = lowered.compile().as_text()
+                assert hlo.count("%dsa_indexer_scores") >= 2
+                assert hlo.count("%sparse_mla_attention") >= 2
+                assert hlo.count("%dsa_topk_select") >= 2
+                assert _mosaic_calls(hlo) == 2 * 3 + 3
+                assert _pool_copies(hlo, _GLM_PAGES, _GLM_PAGE) == []
+                pools = []
+                for width in (640, 128):
+                    params = _pool_parameters(
+                        hlo, f"{_GLM_PAGES},{_GLM_PAGE},{width}")
+                    assert len(params) == 2        # one a layer
+                    assert [lay for _, lay in params
+                            if not lay.startswith("{2,1,0")] == []
+                    pools += params
+                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
+    finally:
+        engine.close()

@@ -19,10 +19,12 @@ host between steps.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import logging
 import threading
 import warnings
+import weakref
 
 import numpy as np
 
@@ -91,6 +93,17 @@ def _m_program_compile_seconds():
         "executable came from: miss (compiled, no persistent cache "
         "consulted), persistent_hit / persistent_miss (jax's on-disk "
         "cache), aot_hit (deserialized)", labels=("program", "outcome"))
+
+
+def _m_staged_arrays():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_exec_staged_arrays_total",
+        "Arguments (scope reads and feeds) the single and chain lanes "
+        "handed to their executables, added once a run: kind=any counts "
+        "all of them, kind=put those that needed a jax.device_put (a "
+        "resident, committed array needs none)", labels=("lane", "kind"))
 
 
 # what every lane's run_steps chain (chain_step_body) is jitted as
@@ -780,22 +793,88 @@ class _FeedScopeView:
         self._scope.set(name, value)
 
 
-def _stage_scope_reads(scope, names, device):
-    """Fetch `names` from `scope` onto `device`, failing with the variable's
-    NAME on a miss — a cached plan may classify a var as a scope read
-    against a scope that held it; None reaching jax.device_put would
-    surface as an opaque pytree/TypeError instead."""
+def _forget_view(owner, name, ref):
+    """Weakref callback: the scope's array died, its view goes with it."""
+    exe = owner()
+    if exe is not None and exe._views.get(name, (None,))[0] is ref:
+        del exe._views[name]
+
+
+def _stage_scope_reads(scope, names, device, keeper=None):
+    """`names` from `scope` as arguments committed to `device`, and how many
+    of them needed a ``jax.device_put``.  The scope is left as it is: other
+    lanes read it too, and their jits take an uncommitted array but refuse
+    one committed elsewhere.
+
+    A committed ``jax.Array`` whose only device is `device` (what a step
+    wrote back) is passed by identity.  An uncommitted one that lives there
+    (what a jitted initializer or ``jnp.zeros`` returns) is put once — the
+    put makes a committed view of the same buffer — and, where the caller
+    names a `keeper` (the executable: ``_views`` {name: (weakref to the
+    scope's array, its view)}), later runs take the view for as long as the
+    scope's array lives.  It cannot go as it is: committedness is part of a
+    jitted call's signature, so a step would compile again once its own
+    committed outputs come back as inputs.  Everything else is put on every
+    run: an array resident elsewhere (a copy nobody should keep), and host
+    values (numpy, scalars, what ``get_tensor().set`` stores), whose
+    in-place change must be seen.
+
+    Fails with the variable's NAME on a miss — a cached plan may classify
+    a var as a scope read against a scope that held it; None reaching
+    jax.device_put would surface as an opaque pytree/TypeError instead."""
     import jax
 
-    staged = {}
+    target = {device}
+    views = None if keeper is None else keeper._views
+    staged, n_put = {}, 0
     for n in names:
         v = scope.get(n)
         if v is None:
             raise ValueError(
                 f"variable {n!r} is read by this program but absent "
                 "from the current scope")
+        keep = False
+        if isinstance(v, jax.Array):
+            if v.committed:
+                if v.sharding.device_set == target:
+                    staged[n] = v
+                    continue
+            elif views is not None:
+                hit = views.get(n)
+                if hit is not None and hit[0]() is v:
+                    staged[n] = hit[1]
+                    continue
+                keep = v.sharding.device_set == target
         staged[n] = jax.device_put(v, device)
-    return staged
+        n_put += 1
+        if keep:
+            # weak both ways: the view neither outlives the array it views
+            # nor ties the executable into a cycle
+            views[n] = (weakref.ref(v, functools.partial(
+                _forget_view, weakref.ref(keeper), n)), staged[n])
+    return staged, n_put
+
+
+def _stage_args(lane, exe, scope, feeds):
+    """The (donated, readonly, feeds) arguments of one run of `exe`'s
+    jitted body, every one committed to its place's device (so each
+    program keeps ONE signature, and the feeds pin the computation to a
+    place that is not the default device), booked once a run into
+    ``pt_exec_staged_arrays_total{lane}``.  Only read-only arrays get a
+    kept view: a donated one is replaced in the scope by the step's own
+    committed output."""
+    import jax
+
+    device = exe.place.jax_device()
+    donated, n_d = _stage_scope_reads(scope, exe.donated_names, device)
+    readonly, n_r = _stage_scope_reads(scope, exe.readonly_names, device,
+                                       keeper=exe)
+    feed_vals = {k: jax.device_put(v, device) for k, v in feeds.items()}
+    staged = _m_staged_arrays()
+    staged.labels(lane=lane, kind="put").inc(n_d + n_r + len(feed_vals))
+    staged.labels(lane=lane, kind="any").inc(
+        len(donated) + len(readonly) + len(feed_vals))
+    return donated, readonly, feed_vals
 
 
 class _JitExecutable:
@@ -911,6 +990,7 @@ class _CompiledBlock(_JitExecutable):
         self.place = place
         self.label = f"program@{id(program):x}/v{program._version}"
         self._prof_state = {"ran": False}
+        self._views = {}  # _stage_scope_reads: committed views kept
         # AOT-loaded/compiled executable (fluid/aot_cache.py) — when
         # set, run() dispatches it instead of the lazy jit
         self._aot = None
@@ -971,15 +1051,8 @@ class _CompiledBlock(_JitExecutable):
                     # populate the scope vars the device step is about
                     # to read
                     self.plan.run_host_pre_ops(scope, feeds, self.place)
-                    device = self.place.jax_device()
-                    donated = _stage_scope_reads(scope,
-                                                 self.donated_names,
-                                                 device)
-                    readonly = _stage_scope_reads(scope,
-                                                  self.readonly_names,
-                                                  device)
-                    feed_vals = {k: jax.device_put(v, device)
-                                 for k, v in feeds.items()}
+                    donated, readonly, feed_vals = _stage_args(
+                        "single", self, scope, feeds)
                 with ph.phase("dispatch"):
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU backend
@@ -1118,6 +1191,7 @@ class _CompiledChain(_JitExecutable):
         self.label = (f"program@{id(program):x}/v{program._version}"
                       f"/chain{n}")
         self._prof_state = {"ran": False}
+        self._views = {}  # _stage_scope_reads: committed views kept
 
     def run(self, scope, feeds, step):
         import jax
@@ -1130,14 +1204,8 @@ class _CompiledChain(_JitExecutable):
                                     number=step) as ph:
             with _prof.timed_run(self.label, self._prof_state) as timer:
                 with ph.phase("feed_prep"):
-                    device = self.place.jax_device()
-                    donated = _stage_scope_reads(scope,
-                                                 self.plan.donated_names,
-                                                 device)
-                    readonly = _stage_scope_reads(
-                        scope, self.plan.readonly_names, device)
-                    feed_vals = {k: jax.device_put(v, device)
-                                 for k, v in feeds.items()}
+                    donated, readonly, feed_vals = _stage_args(
+                        "chain", self, scope, feeds)
                 with ph.phase("dispatch"):
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU

@@ -2,11 +2,8 @@
 PY ?= python
 REPO := $(dir $(abspath $(lastword $(MAKEFILE_LIST))))
 
-.PHONY: test test-book chip-smoke test-onchip bench int8-bench \
-	serve-bench decode-bench ragged-bench health-bench phase-bench \
-	pass-bench pipeline-bench autotune recovery-drill recovery-bench \
-	serve-drill \
-	perf-compare lint-api lint-resilience lint-observability \
+.PHONY: test test-book chip-smoke test-onchip benchmark recovery-drill \
+	serve-drill lint-api lint-resilience lint-observability \
 	lint-collectives lint-passes lint-kernels analyze
 
 test:            ## full suite on the 8-device virtual CPU mesh (~8 min)
@@ -22,50 +19,16 @@ test-onchip:     ## curated pytest subset on the chip (needs a TPU)
 	PADDLE_TPU_TEST_REAL=1 PYTHONPATH=$(REPO) \
 	  $(PY) -m pytest tests/test_onchip_smoke.py -m onchip -q
 
-bench:           ## one-line JSON headline; non-zero exit without a TPU
-	PYTHONPATH=$(REPO) $(PY) bench.py
-
-int8-bench:      ## int8 vs bf16 vs fp32 dense-serving A/B
-	PYTHONPATH=$(REPO) $(PY) tools/bench_int8_serve.py
-
-serve-bench:     ## serving-engine load generator (throughput + p50/p99)
-	PYTHONPATH=$(REPO) PT_BENCH_SERVE=1 $(PY) bench.py
-
-decode-bench:    ## decode-lane load-gen: tokens/s vs naive, steady-state compiles==0, p99
-	PYTHONPATH=$(REPO) PT_BENCH_DECODE=1 $(PY) bench.py
-
-ragged-bench:    ## bucketed-padded vs ragged serving A/B + modeled fp32/int8 KV bytes
-	PYTHONPATH=$(REPO) PT_BENCH_RAGGED=1 $(PY) bench.py
-
-health-bench:    ## health-sentinel on/off A/B (overhead gate <=2% p50)
-	PYTHONPATH=$(REPO) PT_BENCH_HEALTH=1 $(PY) bench.py
-
-phase-bench:     ## phase-instrumentation on/off A/B (overhead within noise)
-	PYTHONPATH=$(REPO) PT_BENCH_PHASES=1 $(PY) bench.py
-
-pass-bench:      ## graph-passes on/off A/B + per-pass cost attribution
-	PYTHONPATH=$(REPO) PT_BENCH_PASSES=1 $(PY) bench.py
-
-pipeline-bench:  ## pipeline-as-policy A/B: PipelineRunner vs PipelinePolicy, gpipe vs 1f1b, microbatch sweep
-	PYTHONPATH=$(REPO) PT_BENCH_PIPELINE=1 $(PY) bench.py
-
-autotune:        ## mesh autotuner sweep: enumerate→rank→measure, report + pinned-winner re-run
-	PYTHONPATH=$(REPO) PT_BENCH_AUTOTUNE=1 $(PY) bench.py
+# one cell of BENCHMARK.json, one run, on the chip (PERF.md has the account):
+#   make benchmark CELL=gpt2-large.closed16-mixed SEED=1
+benchmark:       ## the harness of record: one cell of BENCHMARK.json; non-zero exit without a TPU
+	$(PY) benchmark/run.py --workload $(CELL) --seed $(SEED) --seconds 40 --trace 0
 
 recovery-drill:  ## fast in-process preempt→restore drill (window restore + parity)
 	JAX_PLATFORMS=cpu $(PY) -m paddle_tpu.distributed.recovery
 
-recovery-bench:  ## measured recovery rung: per-phase seconds + MTTR into the bench record
-	PYTHONPATH=$(REPO) PT_BENCH_RECOVERY=1 $(PY) bench.py
-
 serve-drill:     ## serving fault drills: replica_kill failover (token-exact), canary promotion, hedging
-	PYTHONPATH=$(REPO) PT_BENCH_SERVE_DRILL=1 $(PY) bench.py
-
-# diff two bench records (one JSON line each, as `make bench` prints),
-# exit nonzero on regression:
-#   make perf-compare OLD=a.json NEW=b.json [PC_ARGS=--threshold-pct=10]
-perf-compare:    ## regression gate between two bench records
-	$(PY) tools/perf_compare.py $(OLD) $(NEW) $(PC_ARGS)
+	$(PY) -m paddle_tpu.serving.drill
 
 lint-api:        ## fail if the public API surface drifted from API.spec
 	$(PY) tools/gen_api_spec.py --check

@@ -38,10 +38,8 @@ of hoped-for:
   the fast rung — train, simulate a preemption by dropping every live
   object, restore through the persisted rollback window, and assert
   final-state parity against an uninterrupted baseline.  Books the
-  restore/first_step phases (detect/relaunch are multi-process-only).
-
-The PT_BENCH_RECOVERY bench rung records the in-process drill's phases
-and MTTR in BENCH_*.json (`make recovery-bench`).
+  restore/first_step phases (detect/relaunch are multi-process-only)
+  and prints the report with its per-phase seconds and MTTR.
 """
 
 from __future__ import annotations
@@ -375,7 +373,7 @@ def run_drill(roles, watch_endpoints, *, spec=None, rules=None,
 
 
 # ---------------------------------------------------------------------------
-# the fast in-process drill (make recovery-drill / PT_BENCH_RECOVERY)
+# the fast in-process drill (make recovery-drill)
 # ---------------------------------------------------------------------------
 
 

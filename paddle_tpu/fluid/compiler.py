@@ -133,7 +133,7 @@ class CompiledProgram:
         """XLA cost/memory analysis of the step this compiled program runs:
         routes to the data-parallel runner's sharded executable when one
         was built, else to the plain executor's (single-device fallthrough
-        path) — callers (bench quant rung) need not know which ran."""
+        path) — callers need not know which ran."""
         if self._dp_runner is not None:
             return self._dp_runner.cost_analysis(executor, feed,
                                                  fetch_list=fetch_list,

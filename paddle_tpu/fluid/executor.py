@@ -947,8 +947,7 @@ class _JitExecutable:
         except Exception:  # backend without memory analysis
             pass
         # publish the cost-model headline numbers as per-signature gauges
-        # (docs/OBSERVABILITY.md) — the standing form of the bench rung's
-        # one-off bytes_accessed capture
+        # (docs/OBSERVABILITY.md)
         sig = getattr(self, "label", f"exe@{id(self):x}")
         for kind, key in (("flops", "flops"),
                           ("bytes_accessed", "bytes accessed"),
@@ -1105,7 +1104,7 @@ def _check_nan_inf(plan, label, out_writes, fetches):
 class HostOpsUnsupported(ValueError):
     """Raised when an on-device step chain meets a program whose host ops
     (RPC/IO) need the host between steps.  A distinct type so fallback
-    logic (train_from_dataset chaining, bench chain mode) can classify
+    logic (train_from_dataset chaining) can classify
     it exactly instead of matching error text."""
 
 
@@ -1252,7 +1251,7 @@ class Executor:
         self._step = 0
         self._sentinels: dict = {}  # id(program) -> HealthSentinel|None
         # opt-in /metricsz endpoint (FLAGS_metrics_port): every process
-        # that runs programs — trainer, pserver, bench child — exposes
+        # that runs programs — trainer, pserver, benchmark runner — exposes
         # itself; a no-op when the flag is 0 or a server already runs
         from paddle_tpu.observability import exposition as _expo
 

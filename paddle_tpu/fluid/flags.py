@@ -122,20 +122,18 @@ _DEFS = {
     # FLAGS_quant_allreduce_crossover_kb KB of fp32 payload take the ring
     # (the bidirectional one when the axis/payload clear bidir_eligible)
     "FLAGS_quant_allreduce_algo": ("auto", str, True),
-    # crossover default MEASURED, not guessed: the PT_BENCH_QUANTAR
-    # hop-latency sub-rung (bench._hop_latency_bench, r8) on the 8-device
-    # CPU mesh put the first ring win at 256 KB of fp32 payload (oneshot
-    # 43.3 ms vs ring 37.9 ms; per-hop ~2.7 ms) — replaces the prior
-    # 512 KB guess; on the chip: not measured — this flag stays the
-    # override either way
+    # crossover default: 256 KB of fp32 payload is where the ring first
+    # beat oneshot on the 8-device CPU mesh (r8; a container reading, no
+    # evidence for the chip); on the chip: not measured — this flag stays
+    # the override either way
     "FLAGS_quant_allreduce_crossover_kb": (256, int, True),
     # ready-order bucket dispatch (parallel/data_parallel.py): each
     # quantized gradient bucket's collective is emitted immediately after
     # the last gradient it covers is produced, so XLA's async collective
     # scheduling overlaps the ring hops with the remaining backward
     # compute.  Off = every gradient collective defers to after the full
-    # backward (the PT_BENCH_OVERLAP A/B baseline).  On by default for
-    # the quant path.
+    # backward (the no-overlap baseline of an on/off A/B).  On by default
+    # for the quant path.
     "FLAGS_overlap_allreduce": (True, _parse_bool, True),
     # graph-optimization pass layer (paddle_tpu/passes/, docs/PASSES.md):
     # program passes run between construction and executor compile on
@@ -159,8 +157,8 @@ _DEFS = {
     # HybridParallelRunner through the one jit-partitioned executor —
     # sharding policies + XLA-inserted collectives instead of the
     # transpiler's per-gradient c_allreduce rewrite.  Off by default
-    # while the transpiler lane remains the benched baseline; flip per
-    # run or per runner via gspmd=True.
+    # while the transpiler lane is the one the benchmark's dp4 cell runs
+    # (ROADMAP D2); flip per run or per runner via gspmd=True.
     "FLAGS_gspmd_executor": (False, _parse_bool, True),
     # mesh-autotuner pin (parallel/autotune.py, docs/AUTOTUNE.md): path
     # to a committed autotune_report.json whose measured winner both
@@ -304,7 +302,7 @@ _DEFS = {
     # dynamic loss scaling (update_loss_scaling semantics): multiply the
     # backward seed by @HEALTH@loss_scale, unscale at the optimizer
     # edge, halve on every bad step, double after N consecutive good
-    # steps.  Off by default — bf16 (the benched policy) has fp32's
+    # steps.  Off by default — bf16 (the benchmark's policy) has fp32's
     # exponent range, so scaling is an fp16-parity knob.
     "FLAGS_health_loss_scaling": (False, _parse_bool, True),
     "FLAGS_health_loss_scale_init": (65536.0, float, True),
@@ -316,9 +314,8 @@ _DEFS = {
     # profile_phases keeps only what changes timing: the per-step
     # block_until_ready that makes device_wait read real device time.
     # Off by default: that block serializes the donated-buffer dispatch
-    # pipelining the fetch-free training loop (and the benched
-    # methodology) relies on — opt in per run, and the PT_BENCH_PHASES
-    # A/B rung gates its overhead on the syncfetch lane.
+    # pipelining the fetch-free training loop relies on — opt in per
+    # run.
     "FLAGS_profile_phases": (False, _parse_bool, True),
     # flight recorder: bounded ring of the last N steps' attribution
     # records (phase breakdowns, queue depth, health events), dumped as
@@ -357,7 +354,7 @@ _DEFS = {
     # the chip it is not measured (PERF.md).
     "FLAGS_reqtrace": (True, _parse_bool, True),
     # completed-trace ring capacity (the tail-sampling window /tracez
-    # and the trace-derived bench quantiles read from)
+    # and reqtrace.request_quantiles read from)
     "FLAGS_reqtrace_ring": (256, int, True),
     # background SLO burn-rate evaluation period (observability/slo.py);
     # the drill drives evaluate() itself at sub-second scale

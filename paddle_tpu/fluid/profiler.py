@@ -171,8 +171,8 @@ def reset_profiler():
 def get_events():
     """Recorded (kind, name, start_s, dur_s) events of the last/current
     profiling session, with start relative to the session epoch (clamped to
-    0 for spans entered before start_profiler).  Consumed by
-    tools/timeline.py for chrome://tracing export.  Spans recorded with
+    0 for spans entered before start_profiler).  export_chrome_trace
+    writes them for chrome://tracing.  Spans recorded with
     args keep the 4-tuple shape here (back-compat); the args surface only
     in export_chrome_trace."""
     t0 = _STATE["t0"] or 0.0

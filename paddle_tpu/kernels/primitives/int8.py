@@ -17,9 +17,8 @@ applies it to STORAGE instead of the collective wire:
   quantization happens once at model load
   (passes/int8_weights.py).
 
-Distinct from the int8 COMPUTE path (fluid/contrib/ptq,
-tools/bench_int8_serve.py — real int8 MXU contraction after
-calibration): here the matmul still runs fp32/bf16, int8 only halves
+Distinct from the int8 COMPUTE path (fluid/contrib/ptq — real int8
+MXU contraction after calibration): here the matmul still runs fp32/bf16, int8 only halves
 the BYTES AT REST.  fp32→dual-int8 is 4n → 2n + 4n/block bytes, i.e.
 ~2× for block ≥ 32; the realized saving books on
 ``pt_int8_bytes_saved_total{kind}``.

@@ -84,11 +84,10 @@ def quant_padded_elems(n_elements, n_devices, block_size=DEFAULT_BLOCK_SIZE,
 def wire_bytes(n_elements, block_size=DEFAULT_BLOCK_SIZE, dual_int8=True,
                n_devices=2, algo="oneshot"):
     """Per-device ICI payload of one quantized all-reduce of
-    ``n_elements`` fp values — the standing collective-bytes metric the
-    EQuARX bench rung captured as a one-off (pure python; used by the
-    data-parallel transpiler to report
-    ``pt_collective_payload_bytes_total`` and by the bench rung to record
-    every algorithm's bytes).
+    ``n_elements`` fp values — the standing collective-bytes metric
+    (pure python; used by the data-parallel transpiler to report
+    ``pt_collective_payload_bytes_total`` and every algorithm's modeled
+    bytes).
 
     ``algo="oneshot"``: both phase boundaries (scatter all_to_all, gather
     all_gather) move the full padded tensor once — int8 hi (+ int8

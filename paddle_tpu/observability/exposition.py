@@ -21,7 +21,7 @@ dependencies), serving:
 Enable per process with ``FLAGS_metrics_port`` (env ``FLAGS_metrics_port``
 seeds it like every flag); 0 = off.  `ensure_from_flags()` is called from
 the executor's construction path, so any process that runs a program —
-trainer, pserver, bench child — exposes itself when asked to.
+trainer, pserver, benchmark runner — exposes itself when asked to.
 """
 
 from __future__ import annotations

@@ -313,8 +313,9 @@ def hist_quantile(hist, q):
     best bound a fixed-bucket histogram can give).  q=1.0 is the max
     estimate; returns None on an empty histogram.
 
-    This is what puts p50/p95/max step-time summaries into BENCH_*.json
-    (bench.py metrics digest) instead of sums alone."""
+    This is what turns a latency or step-time histogram into p50/p95/max
+    summaries (``/servez``, ``profiling.attribution_digest``) instead of
+    sums alone."""
     if not 0.0 <= float(q) <= 1.0:
         raise ValueError(f"quantile must be in [0, 1], got {q!r}")
     buckets = list(hist.get("buckets") or ())

@@ -71,7 +71,7 @@ pairs anywhere else):
                  MFU + roofline verdict, per-lane phase p50/p95, the
                  feed-bound verdict (prefetcher stall vs step time), and
                  flight-recorder state.  `attribution_digest()` is the
-                 same payload compacted for BENCH_*.json records.
+                 same payload compacted to one JSON-able dict.
 
 Import cost is stdlib-only (the observability-package contract); jax,
 fluid.flags and fluid.profiler are imported lazily inside functions.
@@ -894,7 +894,7 @@ def hlo_collective_counts(hlo):
 
 
 # ---------------------------------------------------------------------------
-# /profilez + the bench digest
+# /profilez + the attribution digest
 # ---------------------------------------------------------------------------
 
 
@@ -995,10 +995,9 @@ def profilez_payload():
 
 
 def attribution_digest():
-    """The compact attribution record every BENCH_*.json embeds: phase
-    quantiles, per-signature MFU + roofline verdict, and the feed-bound
-    fraction — so a perf record names WHERE its step time went and
-    `tools/perf_compare.py` can diff it mechanically."""
+    """The compact attribution record: phase quantiles, per-signature
+    MFU + roofline verdict, and the feed-bound fraction — WHERE the step
+    time went, as one JSON-able dict."""
     sigs = {}
     for label, s in signature_stats().items():
         ent = {"lane": s["lane"], "steps": s["steps"]}

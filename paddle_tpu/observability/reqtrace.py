@@ -443,7 +443,7 @@ def _quantile(sorted_vals, q):
 def request_quantiles(qs=(0.5, 0.99)):
     """Per-request latency / TTFT / TPOT quantiles computed from the
     COMPLETED-TRACE ring (the span tree, not the aggregate histogram)
-    — what the bench rungs embed as trace-derived truth."""
+    — the trace-derived counterpart of the histogram's quantiles."""
     with _lock:
         snap = [(t.get("latency_s"), t["spans"]) for t in _ring
                 if t.get("status") == "ok"]

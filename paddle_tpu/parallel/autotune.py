@@ -790,8 +790,8 @@ def stamp_gspmd_vs_transpiler(report, transpiler_p50_s, rel_tol=0.05):
 
 def policy_summary(mesh, policy):
     """``pp2.dp2.mp2/tp2d`` — mesh dims (canonical axis order, elided
-    axes printed at 1) + the policy's class name.  The token bench
-    records and `describe_policy` consumers stamp so sweeps across
+    axes printed at 1) + the policy's class name.  The token
+    `describe_policy` consumers stamp so sweeps across
     factorizations stay distinguishable after the fact."""
     shape = dict(getattr(mesh, "shape", {}) or {})
     dims = ".".join(f"{ax}{int(shape.get(ax, 1))}"

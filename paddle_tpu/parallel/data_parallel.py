@@ -256,7 +256,7 @@ def transpile_data_parallel(program, loss_name, num_devices,
     collective algorithm is resolved HERE — at transpile time, per bucket
     size, via kernels.ring_collectives.select_allreduce_algo — and
     stamped onto the op's `algo` attr, so the lowering runs exactly what
-    the wire-bytes accounting (and the bench record) models.  "auto"
+    the wire-bytes accounting models.  "auto"
     sends small buckets through the one-shot O(1)-launch form and large
     ones through the ppermute ring (2*(n-1)/n of payload bytes, int8 on
     every hop) — the BIDIRECTIONAL ring (`ring_bidir`, both ICI
@@ -268,8 +268,8 @@ def transpile_data_parallel(program, loss_name, num_devices,
     order of the backward), so XLA's async collective scheduling can
     overlap the ring hops with the remaining backward compute.  Off =
     every gradient collective (bucketed AND per-grad fp32) defers to
-    after the full backward — the no-overlap baseline the
-    PT_BENCH_OVERLAP A/B rung measures against.  The schedule lands in
+    after the full backward — the no-overlap baseline an on/off A/B of
+    this flag compares against.  The schedule lands in
     ``program._overlap_schedule`` (per-bucket insert point + the fraction
     of the backward already executed at dispatch) and feeds
     ``pt_overlap_buckets_ready_total``.
@@ -491,12 +491,11 @@ def transpile_data_parallel(program, loss_name, num_devices,
         collective_bytes = {k: 0 for k in collective_bytes}
         fused_saved_bytes = 0
     program._collective_bytes_per_step = collective_bytes
-    # per-bucket algorithm/size report for the PT_BENCH_QUANTAR rung —
-    # lets the bench record BOTH algorithms' modeled bytes beside the one
-    # that actually ran
+    # per-bucket algorithm/size report: BOTH algorithms' modeled bytes
+    # beside the one that actually ran
     program._quant_allreduce_plan = quant_plan if quant_grads else None
-    # ready-order scheduling report (the transpile summary): feeds the
-    # bench record and pt_overlap_buckets_ready_total
+    # ready-order scheduling report (the transpile summary): feeds
+    # pt_overlap_buckets_ready_total
     program._overlap_schedule = schedule if quant_grads else None
     program._fused_update_bytes_saved = fused_saved_bytes
     program._bump_version()
@@ -723,8 +722,8 @@ class DataParallelRunner:
 
     def cost_analysis(self, executor, feed, fetch_list=None, scope=None):
         """XLA cost/memory analysis of the sharded step executable (the
-        single-device Executor.cost_analysis counterpart): flops and —
-        the quantized-collective bench rung's metric — bytes accessed.
+        single-device Executor.cost_analysis counterpart): flops and
+        bytes accessed.
         The (feed, fetch) signature must have run once already."""
         from paddle_tpu.fluid import executor as ex
 

@@ -64,7 +64,7 @@ def prep_feed(feed, fetch_list):
 # The parser lives in observability/profiling.py now (promoted into the
 # general per-category HLO inventory the MFU/roofline accounting reads);
 # re-exported from the module imports above because this module is where
-# the GSPMD acceptance gates and the bench rungs historically import it.
+# the GSPMD acceptance gates import it.
 # ---------------------------------------------------------------------------
 
 

@@ -635,7 +635,7 @@ class PipelinePlan:
         """The per-stage schedule report stamped on the program
         (`program._pipeline_schedule`), the `_overlap_schedule` way:
         bubble fraction per microbatch count, boundary payloads, stash
-        depth — what the bench record and docs table read."""
+        depth — what the docs table reads."""
         from paddle_tpu.kernels import pipeline_collectives as pcol
 
         S, M = self.S, self.M

@@ -31,7 +31,7 @@ except that pass).
 
 Cost attribution: the eager report carries the structural delta (op
 inventory, sites, statically-modeled bytes).  ``attribute_costs``
-(bench + acceptance tests) measures the REAL per-pass
+(acceptance tests) measures the REAL per-pass
 ``cost_analysis`` delta — flops, bytes_accessed, compiled-HLO op
 inventory — by compiling each pipeline prefix, and books the measured
 bytes reduction on ``pt_pass_bytes_saved_total{pass}``.
@@ -288,7 +288,7 @@ class PassManager:
 
     ``run(program, ctx)`` applies each pass, validates it, records the
     per-pass report entry into ``program._pass_report`` (a list — one
-    entry per application, so a bench record or test can read exactly
+    entry per application, so a caller or test can read exactly
     what happened), books the pt_pass_* metrics, and enforces the
     idempotence contract when ``selfcheck`` (default: the
     ``PT_PASS_SELFCHECK`` env) is on."""

@@ -27,8 +27,8 @@ Execution model — exactly TWO compiled signatures in steady state:
                   greedy argmax out.
 
 Every feed shape is static, so after the first prefill chunk and the
-first decode step NOTHING recompiles — the decode-bench rung
-(`make decode-bench`) measures exactly that, plus per-token latency
+first decode step NOTHING recompiles (the benchmark's decode cells
+count compiles inside the window), and per-token latency is
 independent of prompt length (prefill cost is paid in the prefill
 phase, the decode step touches only page tables).
 

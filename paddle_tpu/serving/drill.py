@@ -1,5 +1,5 @@
-"""FaultPlan-driven serving fault drills (`make serve-drill`,
-PT_BENCH_SERVE_DRILL=1).
+"""FaultPlan-driven serving fault drills (`make serve-drill`, i.e.
+`python -m paddle_tpu.serving.drill`).
 
 The PR-14 recovery-drill precedent, applied to serving: every claim the
 resilience layer makes is MEASURED here, deterministically, with the
@@ -27,8 +27,7 @@ FaultPlan grammar — not asserted from code reading.
                       recorded.
 
 Each drill returns a plain report dict; `run_drill()` composes them and
-`python -m paddle_tpu.serving.drill` prints one JSON report (the bench
-rung parses the same shape).
+`python -m paddle_tpu.serving.drill` prints one JSON report.
 
 These drills build real engines and compile real (tiny) programs — the
 subprocess test wrapper (tests/test_serve_drill.py) runs them in a
@@ -411,8 +410,8 @@ def hedge_drill(n_requests=12, hedge_ms=30, slow_wait_ms=300,
 
 def run_drill(include=("failover", "promotion_clean",
                        "promotion_rollback", "hedge")):
-    """Compose the serving drills into one report (the `make
-    serve-drill` / PT_BENCH_SERVE_DRILL surface)."""
+    """Compose the serving drills into one report (what `make
+    serve-drill` prints)."""
     report = {}
     if "failover" in include:
         report["failover"] = failover_drill()

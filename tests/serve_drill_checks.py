@@ -11,8 +11,7 @@ compile-free.
 Each check runs one `paddle_tpu.serving.drill` drill and raises unless
 the drill's own `ok` gate holds; main() prints one
 ``SERVE_DRILL_RESULT {json}`` line mapping check name -> "ok" |
-traceback (plus a ``reports`` section with the raw drill reports, which
-the bench rung reuses).
+traceback (plus a ``reports`` section with the raw drill reports).
 
 Run directly for debugging: ``python tests/serve_drill_checks.py
 [names]``.

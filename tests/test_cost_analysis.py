@@ -1,4 +1,4 @@
-"""Executor cost-analysis introspection (tools/profile_step.py's engine):
+"""Executor cost-analysis introspection:
 Executor.compiled_for + _CompiledBlock.cost_analysis expose XLA's cost
 model (flops / bytes accessed) and memory analysis for a compiled step —
 the whole-program TPU analog of the reference's per-op profiler tables

@@ -160,9 +160,8 @@ def test_elastic_flags_roundtrip(monkeypatch):
 
 def test_quant_allreduce_algo_flags_roundtrip(monkeypatch):
     """The size-adaptive collective-selection flags register with their
-    documented defaults (auto; 256 KB crossover — MEASURED by the
-    PT_BENCH_QUANTAR hop-latency sub-rung on the 8-device CPU mesh,
-    replacing the original 512 KB guess; ZeRO gather quant off) and
+    documented defaults (auto; 256 KB crossover — read on the 8-device
+    CPU mesh, not on the chip; ZeRO gather quant off) and
     round-trip through env bootstrap and get/set like every other flag
     (ISSUE 5 satellite, crossover retuned in ISSUE 8)."""
     import importlib

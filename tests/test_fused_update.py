@@ -153,11 +153,30 @@ def test_fused_momentum_pallas_interpret_matches_xla(monkeypatch):
     assert np.abs(deq[0] - deq[1]).max() <= max(lsb, 1e-6)
 
 
-def test_transpiler_rewrites_momentum_to_fused(monkeypatch):
+@pytest.mark.parametrize("hidden,classes,atol", [
+    (16, 16, 1e-6), (6, 3, 1e-5)], ids=["whole_blocks", "ragged_members"])
+def test_transpiler_rewrites_momentum_to_fused(monkeypatch, hidden,
+                                               classes, atol):
     """FLAGS_fused_update + quant bucketing absorbs momentum ops like
     sgd/adam: the DP transpile emits fused_momentum_quant_grad with the
     bucket's wire-format inputs, and a 20-step fused-vs-unfused momentum
-    run agrees ≤ 1e-6 (the mechanical-parity gate of the satellite)."""
+    run agrees.
+
+    How closely is set by the quantization grid, not by the update (its
+    arithmetic is term for term the momentum op's).  The fused bucket
+    packs each member block-ALIGNED, the unfused one end to end, so a
+    member that is not a whole number of blocks (block 16 here; 6- and
+    3-wide layers) shares blocks, and scales, with other neighbours on
+    the two sides.  ``whole_blocks``: every member is whole blocks, the
+    grids coincide and the runs are bit-identical (measured: 0.0 in
+    every parameter and velocity at steps 0 and 19), held to the 1e-6
+    this test always named.  ``ragged_members``: each step's reduced
+    gradient differs by up to one dual-int8 step of its block (velocities
+    3e-6..8e-6 apart after ONE step from one state), and the loss gap
+    grows with the steps that accumulate it: 4.8e-7 after 5, 1.85e-6
+    after 20, 4.1e-6 after 40, 5.5e-6 after 60 and after 80, on losses
+    falling 1.10 -> 0.19.  1e-5 holds the 20-step run to twice what 80
+    steps reach."""
     from paddle_tpu import fluid
 
     def build_and_losses(fused):
@@ -173,8 +192,8 @@ def test_transpiler_rewrites_momentum_to_fused(monkeypatch):
                 x = fluid.layers.data(name="x", shape=[8],
                                       dtype="float32")
                 y = fluid.layers.data(name="y", shape=[1], dtype="int64")
-                h = fluid.layers.fc(x, size=6, act="relu")
-                pred = fluid.layers.fc(h, size=3, act="softmax")
+                h = fluid.layers.fc(x, size=hidden, act="relu")
+                pred = fluid.layers.fc(h, size=classes, act="softmax")
                 loss = fluid.layers.mean(
                     fluid.layers.cross_entropy(pred, y))
                 fluid.optimizer.Momentum(0.1, 0.9).minimize(loss)
@@ -237,7 +256,7 @@ def test_transpiler_rewrites_momentum_to_fused(monkeypatch):
     assert "momentum" not in t_fused  # every momentum op was absorbed
     assert "c_allreduce_quant_keep" in t_fused
     assert "momentum" in t_plain
-    np.testing.assert_allclose(l_fused, l_plain, atol=1e-6, rtol=0)
+    np.testing.assert_allclose(l_fused, l_plain, atol=atol, rtol=0)
     assert l_fused[-1] < l_fused[0]
 
 

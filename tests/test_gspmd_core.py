@@ -777,7 +777,9 @@ def test_gspmd_bert_tiny_20_step_acceptance_subprocess():
     lt = np.asarray(_run_bert_arm("transpiler"))
     lg = np.asarray(_run_bert_arm("gspmd"))
     lz = np.asarray(_run_bert_arm("quant_zero1"))
-    assert len(lt) == 20 and lt[-1] < lt[0]
+    # each step's loss is on a fresh noisy batch, so the descent check
+    # compares means, not two single steps
+    assert len(lt) == 20 and lt[-5:].mean() < lt[:5].mean()
     assert np.max(np.abs(lt - lg)) <= 1e-5
     assert np.max(np.abs(lt - lz)) <= 1e-3
 

@@ -21,7 +21,7 @@ BUCKETS = [16, 32]
 def _ragged(cfg, rng, bucket, lo, batch=4):
     """Batch padded to `bucket`; true source lengths uniform in
     (lo, bucket], target lengths = source - 1, label_weight zeroes the
-    padding (the bench.measure_nmt construction)."""
+    padding."""
     data = tfm.make_fake_batch(cfg, batch=batch, src_len=bucket,
                                trg_len=bucket - 1,
                                seed=int(rng.randint(1 << 30)))

@@ -86,8 +86,7 @@ def test_histogram_bucket_boundaries():
 def test_hist_quantile():
     """PromQL histogram_quantile semantics over hist_data(): linear
     interpolation inside the winning bucket, lower bound 0 for the first,
-    the +Inf bucket clamped to the largest finite le, None on empty —
-    what puts p50/p95/max step-time summaries in BENCH_*.json."""
+    the +Inf bucket clamped to the largest finite le, None on empty."""
     reg = metrics.MetricsRegistry()
     h = reg.histogram("hq_seconds", "h", buckets=(0.1, 1.0, 10.0))
     # empty histogram: no estimate
@@ -105,7 +104,7 @@ def test_hist_quantile():
     assert metrics.hist_quantile(data, 0.0) == pytest.approx(0.0)
     with pytest.raises(ValueError):
         metrics.hist_quantile(data, 1.5)
-    # exported on the package root (bench.py reaches it as
+    # exported on the package root (callers reach it as
     # obs.hist_quantile)
     assert obs.hist_quantile is metrics.hist_quantile
 

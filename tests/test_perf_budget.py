@@ -17,8 +17,9 @@ budgets asserted on the lowered program:
    changing any op-output dtype (the r4 verdict's invisible case) fails
    here by name.
 3. A compiled-step tripwire: XLA cost-model flops stay within a factor
-   of the analytic FLOPs model (bench._bert_train_flops_per_step), so an
-   accidentally doubled compute path can't land silently.
+   of the analytic FLOPs model (benchmark/flops.py
+   bert_train_flops_per_step), so an accidentally doubled compute path
+   can't land silently.
 
 The budgets are pinned below, beside the tests that enforce them.  The
 island internals (softmax/LN fp32 statistics) are deliberately NOT
@@ -213,7 +214,7 @@ def test_cost_model_flops_track_analytic_model(flagship):
     doubled compute path (duplicate backward, un-deduped recompute) lands
     outside the band.  Uses the persistent XLA compile cache, so steady-
     state CI cost is a cache load."""
-    import bench
+    from benchmark import flops as work
 
     comp = flagship["fp32"]["lowered"].compile()
     ca = comp.cost_analysis()
@@ -221,7 +222,15 @@ def test_cost_model_flops_track_analytic_model(flagship):
         ca = ca[0]
     flops = ca.get("flops", 0.0)
     cfg = flagship["fp32"]["cfg"]
-    analytic = bench._bert_train_flops_per_step(cfg, BATCH, SEQ)
+    # the benchmark's model takes the source's config.json names and the
+    # job's sizes; make_fake_batch masks max(1, seq_len // 8) a sequence
+    analytic = work.bert_train_flops_per_step(
+        {"hidden_size": cfg.hidden_size,
+         "intermediate_size": cfg.intermediate_size,
+         "num_hidden_layers": cfg.num_layers,
+         "vocab_size": cfg.vocab_size},
+        {"batch": BATCH, "seq_len": SEQ,
+         "masked_per_seq": max(1, SEQ // 8)})
     assert analytic > 0
     # measured 2026-08-01: 1.347e9 vs analytic 1.114e9 (1.21x)
     assert 1.0 <= flops / analytic <= 2.0, (

@@ -419,7 +419,7 @@ def test_profilez_served_through_real_scrape(attribution):
     assert page["feed"]["stall_fraction"] >= 0.0
     assert page["flight_recorder"]["size"] > 0
     assert page["device"]["phases_enabled"] is True
-    # the bench digest mirrors the same surface
+    # the attribution digest mirrors the same surface
     digest = profiling.attribution_digest()
     assert set(digest) == {"phase_seconds", "signatures", "feed",
                            "flight_recorder"}

@@ -31,8 +31,7 @@ SEQ_BUCKETS = [4, 8, 16]
 @pytest.fixture(scope="module")
 def ragged_model(tmp_path_factory):
     """One-layer ragged-attention scorer: ids [-1, -1] int64 + per-row
-    lens [-1] int32 (the bench.py measure_ragged_serving model, one
-    layer)."""
+    lens [-1] int32, one layer."""
     d = str(tmp_path_factory.mktemp("ragged_model"))
     head_dim = HIDDEN // HEADS
     main, startup = fluid.Program(), fluid.Program()

@@ -72,6 +72,34 @@ def test_run_steps_matches_sequential_runs():
                                rtol=1e-5)
 
 
+def test_fetch_free_steps_train_and_keep_two_signatures():
+    """The training pattern the benchmark's trainer uses: steps dispatched
+    WITHOUT a fetch pipeline through the donated parameter chain and the
+    last one fetches the loss.  That is two executables (no fetch, fetch),
+    a repeat compiles neither again, and the fetch-free steps train:
+    3 of them + 1 fetching step == 4 fetching steps."""
+    main, startup, loss = _build(with_dropout=False)
+    feed = _feed(np.random.RandomState(1))
+
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        seq = [float(exe.run(main, feed=feed, fetch_list=[loss])[0])
+               for _ in range(4)]
+        assert len(exe.compiled_for(main)) == 1
+
+    with scope_guard(Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        for round_ in range(2):
+            for _ in range(3):
+                assert exe.run(main, feed=feed, fetch_list=[]) == []
+            last, = exe.run(main, feed=feed, fetch_list=[loss])
+            assert len(exe.compiled_for(main)) == 2, round_
+            if round_ == 0:
+                np.testing.assert_allclose(float(last), seq[-1], rtol=1e-6)
+
+
 def test_run_steps_stacked_feed_matches_distinct_batches():
     main, startup, loss = _build(with_dropout=False)
     rng = np.random.RandomState(1)

@@ -1116,29 +1116,6 @@ def test_submit_returns_future_rows(saved_model):
         np.testing.assert_allclose(y, expect[:3], rtol=1e-4)
 
 
-def test_bench_serve_rung_record(monkeypatch):
-    """PT_BENCH_SERVE=1 produces a BENCH record with serving throughput
-    and latency quantiles (acceptance criterion) — run in-process at a
-    tiny size so the rung's record shape is covered in tier-1."""
-    import bench
-
-    monkeypatch.setenv("PT_BENCH_SERVE", "1")
-    monkeypatch.setenv("PT_BENCH_SERVE_CLIENTS", "4")
-    monkeypatch.setenv("PT_BENCH_SERVE_REQUESTS", "24")
-    monkeypatch.setenv("PT_BENCH_SERVE_TIMEOUT_MS", "10")
-    rec = bench.measure("tiny")
-    assert rec["metric"] == "serving_requests_per_sec"
-    assert rec["value"] > 0 and rec["unit"] == "req/s"
-    assert rec["latency_seconds"]["p50"] is not None
-    assert rec["latency_seconds"]["p99"] is not None
-    assert rec["latency_seconds"]["p99"] >= rec["latency_seconds"]["p50"]
-    assert rec["mean_batch_size"] is not None
-    assert rec["client_errors"] == []
-    assert "serve mlp" in rec["config"]
-    # warmed executables did the serving: no cold compile in the rung
-    assert rec["executable_cache"].get("bench,cold", 0) == 0
-
-
 def test_servez_reregisters_after_unregister(saved_model):
     """track_engine has no registered-once latch: an
     unregister_page('/servez') (test cleanup, page reset) must not leave

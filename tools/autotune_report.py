@@ -6,7 +6,7 @@ cost attribution, the winner and its pin line).  Two reports → a
 mechanical diff: did the winner change, did a measured candidate's p50
 regress past the noise threshold, did the prediction error drift.
 
-Exit codes (the perf_compare convention):
+Exit codes:
   0  printed / diffed, no winner change and no measured regression
   1  diff found a winner change or a measured p50 regression
   2  unreadable / schema-mismatched input
@@ -81,11 +81,6 @@ def show(rep):
         print(f"  gspmd_vs_transpiler: win_or_tie={gvt.get('win_or_tie')} "
               f"(gspmd {_fmt_s(gvt.get('gspmd_p50_s'))} vs transpiler "
               f"{_fmt_s(gvt.get('transpiler_p50_s'))})")
-    pr = rep.get("pinned_rerun")
-    if pr:
-        print(f"  pinned_rerun: p50={_fmt_s(pr.get('p50_s'))} "
-              f"ratio={pr.get('p50_ratio')} "
-              f"steady_state_compiles={pr.get('steady_state_compiles')}")
 
 
 def diff(old, new, threshold_pct):

@@ -13,7 +13,8 @@ boundary; a deliberate site elsewhere carries ``# kernel: allow``).
 What the contract buys:
 
 - **One launch idiom.**  Block specs are ``Block(shape, index_map)``
-  tuples and scratch is ``Vmem(shape, dtype)`` — pure data, no pallas
+  tuples and scratch is ``Vmem(shape, dtype)`` (``Smem`` and ``DmaSem``
+  for a kernel that issues its own copies) — pure data, no pallas
   import needed to BUILD a spec, so specs can be constructed (and
   tested) without a kernel backend present at all.
 - **Interpret mode.**  ``interpret=True`` runs the same kernel
@@ -47,6 +48,12 @@ Block = namedtuple("Block", ("shape", "index_map"))
 
 # A VMEM scratch allocation as data.
 Vmem = namedtuple("Vmem", ("shape", "dtype"))
+
+# What a kernel that copies from an unblocked operand itself needs
+# beside VMEM (paged.py's decode row): scalars that outlive a grid step,
+# in SMEM, and an array of DMA semaphores, one per copy stream in flight.
+Smem = namedtuple("Smem", ("shape", "dtype"))
+DmaSem = namedtuple("DmaSem", ("shape",))
 
 # One primitive launch as data.  `out_shape` entries are (shape, dtype)
 # pairs; `in_specs`/`out_specs` are Block tuples (one out entry per
@@ -86,7 +93,13 @@ def primitive_call(kernel, spec, *operands):
     out_specs = [block(b) for b in spec.out_specs]
     out_shape = [jax.ShapeDtypeStruct(tuple(s), d)
                  for s, d in spec.out_shape]
-    scratch = [pltpu.VMEM(tuple(v.shape), v.dtype) for v in spec.scratch]
+    def scratch_shape(v):
+        if isinstance(v, DmaSem):
+            return pltpu.SemaphoreType.DMA(tuple(v.shape))
+        space = pltpu.SMEM if isinstance(v, Smem) else pltpu.VMEM
+        return space(tuple(v.shape), v.dtype)
+
+    scratch = [scratch_shape(v) for v in spec.scratch]
     single = len(out_specs) == 1
 
     if spec.num_scalar_prefetch:

@@ -25,13 +25,21 @@ Two implementations (the shared resolve_mode dispatch):
 - **XLA reference** (CPU fallback + numerics oracle): gather the pages
   (`k_pages[page_table]`), mask positions past each query's length with
   the same -1e9 the fused causal softmax op uses, `jax.nn.softmax`.
-- **Pallas kernel**: grid (B, logical pages) with the page dimension
-  innermost, one whole page of every head per step; the page table and
-  per-row start offsets ride as scalar prefetch so each K/V block's
-  index_map resolves the PHYSICAL page id — the kernel never sees a
-  gathered copy of the pool.  Online softmax (running max/sum in VMEM
-  scratch, per head) over the pages, blocks past the row's length
-  skipped entirely (`pl.when`), fp32 accumulation.
+- **Pallas kernel**: the page table and per-row start offsets ride as
+  scalar prefetch, so the kernel resolves PHYSICAL page ids itself and
+  never sees a gathered copy of the pool.  It reads G pages at a time
+  (256 keys at page 32), concatenated on the key axis before ONE score
+  / online-softmax / value update in float32, and touches no page past
+  a row's length.  Two bodies, chosen from ``q``'s shape at trace time
+  and booked on ``pt_paged_attention_form_total``:
+  **heads-batched** for a decode row (T == 1) — grid (B,), the pools
+  left in HBM, the row's live pages copied G at a time into two VMEM
+  slots (the next group, or the next row's first, in flight while this
+  one is scored), all heads in one block-diagonal product;
+  **per-head** for a prefill chunk (T > 1) and for the int8 pool —
+  grid (B, pages / G), the pool passed G times with one BlockSpec a
+  page, heads as d-wide lane slices.  The comment block over the
+  kernels says what each buys.
 
 Shapes:
   q           [B, n_heads, T, d]   T = 1 (decode step) or the prefill
@@ -64,8 +72,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import contract
-from .contract import Block, Vmem
+from . import autotune, contract
+from .contract import Block, DmaSem, Smem, Vmem
 from .int8 import RESID_DIV, dequantize_lastdim
 
 NEG_INF = -1e9  # the fused causal softmax op's mask constant — shared so
@@ -110,22 +118,65 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B, logical pages), pages innermost; the page
-# table + q_start ride as scalar prefetch so the K/V BlockSpecs resolve
-# physical page ids — the pool is never gathered into a copy.
+# Pallas kernels.  The page table + q_start ride as scalar prefetch so
+# that physical page ids are resolved on the chip — the pool is never
+# gathered into a copy.
 #
 # Mosaic tiling: a block's last two dims must be (8, 128)-divisible or
 # span the array's.  d = 64 on the real models, so a per-head block
-# (.., 1, d) cannot lower: each grid step takes one whole page of every
-# head, (1, page, n*d), of the pool AS IT IS STORED, and the kernel
-# walks the heads as static d-wide lane slices of that block.  Nothing
-# here reshapes the pool — a reshape of [P, page, n, d] to this shape
-# is "free" only in row-major order, which is not the layout XLA:TPU
-# gives the 4-D array (tests/test_mosaic_aot.py holds the compiled
-# executables to zero pool-shaped copies).
+# (.., 1, d) cannot lower: what is read is one whole page of every head,
+# (page, n*d), of the pool AS IT IS STORED.  Nothing here reshapes the
+# pool — a reshape of [P, page, n, d] to this shape is "free" only in
+# row-major order, which is not the layout XLA:TPU gives the 4-D array
+# (tests/test_mosaic_aot.py holds the compiled executables to zero
+# pool-shaped copies).
+#
+# What a grid step carries (PERF.md section 6, PR 30; GPT-2-large's
+# decode step, 16 rows of ~350 tokens, one page = 164 KB of K and of V =
+# 0.4 us of HBM time).  Until PR 30 a step was ONE page and a Python loop
+# over the heads: 20 x ([8,64] x [64,32] dot, where, max, two exp, sum,
+# [8,32] x [32,64] dot) on sub-vreg tiles, ~2 us a live page and a dead
+# step for every page of the table past a row's length — 600 us a call,
+# 11.8% of the pool's bandwidth.  Now G pages (256 keys) meet ONE score /
+# softmax / value update, in one of two bodies:
+#
+#   heads-batched  (T == 1, the decode step; _paged_heads_kernel)
+#       The n query rows ride as a block-diagonal [n, n*d] operand (row
+#       h holds q_h in lanes h*d..(h+1)*d, zeros elsewhere), so
+#       S = Q . K^T is all heads' scores in one product with the pages
+#       as stored — no d-wide lane slices — the softmax runs once over
+#       [n, G*page] and the diagonal d-wide blocks of P . V are taken
+#       once a row.  The zeros add exact zeros: the products are those
+#       of the per-head form.  Grid (B,): the pools stay in HBM
+#       (Block(None)) and the kernel copies a row's LIVE pages itself,
+#       G at a time, into one of two VMEM slots while the other slot is
+#       scored; a row's last group starts the next row's first.  Nothing
+#       is paid for a page past a row's length, and a copy is waited for
+#       only where the bytes are what takes the time: 118 us a call, 60%
+#       of the pool's bandwidth (76% at 1024-token rows).  With a
+#       BlockSpec a page instead (the per-head body's launch) the same
+#       body took 160 us: a dead step costs ~1 us at 19 operands, and a
+#       row's first fetch is exposed behind the dead steps of the row
+#       before.
+#   per-head       (T > 1, the prefill chunk; the int8 pool at any T;
+#                   _paged_body)
+#       Grid (B, pages / G); the pool is passed G times, the j-th copy's
+#       index map resolving the step's j-th page (dsa.py's idiom), heads
+#       as static d-wide lane slices of the G pages.  A chunk is one
+#       row: what it gains is 4 grid steps instead of 32 and 256-key
+#       dots (64 -> 35 us a call).  The int8 pool's scales are one a
+#       head, not one a lane, so its decode row stays here too.
 # ---------------------------------------------------------------------------
 
 _SUBLANES = 8
+# keys scored at once, and the VMEM the two slots (or the double-buffered
+# blocks) of one pool form may take: G = pages_per_step is the most pages
+# inside both — 8 at page 32.  On the chip G = 4 / 8 / 16 read 123 / 118
+# / 113 us a decode call at GPT-2-large's mixed contexts and 300 / 270 /
+# 259 at 1024 tokens (PERF.md section 6, PR 30): flat, so the smaller
+# VMEM footprint wins
+_KEYS_PER_STEP = 256
+_BLOCK_VMEM_BYTES = 8 << 20
 
 
 def _check_pool_shapes(op, q, scales=(), **pools):
@@ -163,33 +214,44 @@ def _online_softmax_step(s, v, acc_ref, m_ref, l_ref, p_dtype=None):
         preferred_element_type=jnp.float32)
 
 
+def _step_pages(refs):
+    """One pool's pages of the current grid step, concatenated on the
+    key axis: [G * page, width].  Loaded whole, once a step: a head's
+    lanes are sliced from the value (sliced from the refs, the 2 x G
+    loads a head made a 36-layer program 8 s slower to trace)."""
+    tiles = [r[0] for r in refs]
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+
+
+def _init_state(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
 def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, load_kv, *, page_size, t, n, n_blocks, sm_scale):
-    """The grid-step body both pool forms share; ``load_kv(h)`` returns
-    head h's fp32 (K, V) [page, d] tiles of the current page."""
+                l_ref, load_step, *, keys, t, n, n_steps, sm_scale):
+    """The per-head grid-step body both pool forms share;
+    ``load_step()`` reads the step's pages and returns ``load_kv``, whose
+    ``load_kv(h)`` is head h's fp32 (K, V) [keys, d] tiles of them."""
     from jax.experimental import pallas as pl
 
     bi = pl.program_id(0)
     pi = pl.program_id(1)
     tp = q_ref.shape[2]  # t rounded up to a sublane multiple
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
+    pl.when(pi == 0)(lambda: _init_state(acc_ref, m_ref, l_ref))
     start = q_start_ref[bi]
 
-    # the block is live iff its first key position is attendable by the
+    # the step is live iff its first key position is attendable by the
     # LAST query of the block (global key limit = start + t - 1)
-    @pl.when(pi * page_size <= start + t - 1)
+    @pl.when(pi * keys <= start + t - 1)
     def _step():
-        kpos = pi * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (tp, page_size), 1)
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (tp, page_size), 0)
+        kpos = pi * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (tp, keys), 1)
+        qpos = start + jax.lax.broadcasted_iota(jnp.int32, (tp, keys), 0)
         visible = kpos <= qpos
+        load_kv = load_step()
         for h in range(n):
             q = q_ref[0, h].astype(jnp.float32)                 # [tp, d]
             k, v = load_kv(h)
@@ -198,7 +260,7 @@ def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
             _online_softmax_step(s, v, acc_ref.at[h], m_ref.at[h],
                                  l_ref.at[h])
 
-    @pl.when(pi == n_blocks - 1)
+    @pl.when(pi == n_steps - 1)
     def _finish():
         for h in range(n):
             l = l_ref[h]
@@ -206,27 +268,206 @@ def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
             o_ref[0, h] = (acc_ref[h] / l_safe[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_kernel(page_table_ref, q_start_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, d, **kw):
-    def load_kv(h):
-        lanes = slice(h * d, (h + 1) * d)
-        return (k_ref[0, :, lanes].astype(jnp.float32),
-                v_ref[0, :, lanes].astype(jnp.float32))
+def _paged_kernel(page_table_ref, q_start_ref, q_ref, *refs, d, n_sub,
+                  **kw):
+    def load_step():
+        k, v = _step_pages(refs[:n_sub]), _step_pages(refs[n_sub:2 * n_sub])
 
-    _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, load_kv, **kw)
+        def load_kv(h):
+            lanes = slice(h * d, (h + 1) * d)
+            return (k[:, lanes].astype(jnp.float32),
+                    v[:, lanes].astype(jnp.float32))
+
+        return load_kv
+
+    _paged_body(page_table_ref, q_start_ref, q_ref, *refs[2 * n_sub:],
+                load_step, **kw)
+
+
+def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
+                        o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref,
+                        l_ref, *, d, n_sub, page_size, n_rows, sm_scale):
+    """The heads-batched body of a decode row (T == 1), one grid step a
+    row: q_ref is the row [1, n*d] of all heads' queries, the state
+    [rows, ...] holds head h in row h (rows = n rounded up to a sublane
+    multiple; a padding row scores zeros against every key and is
+    dropped at the end).  The pools stay in HBM; the row's LIVE pages,
+    and no others, are copied ``n_sub`` at a time into one of two VMEM
+    slots while the other slot's keys are scored, and the row's last
+    group starts the next row's first, so that a copy is waited for only
+    where the pool's bytes are what takes the time."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bi = pl.program_id(0)
+    rows, width = acc_ref.shape
+    keys = n_sub * page_size
+
+    def each_page(row, group, slot, act):
+        """``act`` on the (K, V) copies of every LIVE page of one group
+        of ``row``: a start and its wait walk the same pages."""
+        first = group * n_sub
+        live = jnp.clip(jax.lax.div(q_start_ref[row], page_size) + 1 - first,
+                        0, n_sub)
+
+        def page(j, carry):
+            src = page_table_ref[row, first + j]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for which, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(
+                    pool.at[src], buf.at[slot, dst], sem.at[slot, which]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page, 0)
+
+    def start(row, group, slot):
+        each_page(row, group, slot, lambda copy: copy.start())
+
+    def wait(row, group, slot):
+        each_page(row, group, slot, lambda copy: copy.wait())
+
+    @pl.when(bi == 0)
+    def _first():
+        # a page never copied is scored too (masked): it may hold stale
+        # keys, never a NaN, which an exact 0 of P would not silence
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    q_start = q_start_ref[bi]
+    groups = jax.lax.div(q_start, keys) + 1
+    first_slot = slot_ref[0]
+    _init_state(acc_ref, m_ref, l_ref)
+
+    # [rows, n*d] bool: the d lanes of row h's own head
+    first_lane = d * jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    own_lanes = (lane >= first_lane) & (lane < first_lane + d)
+    q = jnp.where(own_lanes, jnp.broadcast_to(
+        q_ref[0].astype(jnp.float32), (rows, width)), 0.0)
+
+    def score_group(g, carry):
+        slot = jax.lax.rem(first_slot + g, 2)
+        wait(bi, g, slot)
+
+        # into the other slot: this row's next group or, behind its
+        # last, the next row's first
+        more = g + 1 < groups
+
+        @pl.when(more | (bi + 1 < n_rows))
+        def _next():
+            start(jnp.where(more, bi, jnp.minimum(bi + 1, n_rows - 1)),
+                  jnp.where(more, g + 1, 0), 1 - slot)
+
+        s = jax.lax.dot_general(
+            q, k_buf[slot].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [rows, keys]
+        kpos = g * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        s = jnp.where(kpos <= q_start, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, v_buf[slot].astype(jnp.float32), acc_ref,
+                             m_ref, l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, groups, score_group, 0)
+    slot_ref[0] = jax.lax.rem(first_slot + groups, 2)
+
+    l = l_ref[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    out = jnp.where(own_lanes, acc_ref[...] / l_safe[:, :1], 0.0)
+    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _pages_per_step(name, q, page_size, max_pages, pools):
+    """G: the pages one grid step reads — from the shapes, or from a
+    pinned entry of the tile table (autotune.py) for this signature."""
+    b, n, t, d = q.shape
+    page_bytes = sum(page_size * x.shape[2] * x.dtype.itemsize
+                     for x in pools)
+    g = max(1, _KEYS_PER_STEP // page_size)
+    while g > 1 and 2 * g * page_bytes > _BLOCK_VMEM_BYTES:
+        g //= 2
+    tile = autotune.tile_for(
+        name, autotune.shape_signature(b=b, n=n, t=t, d=d, page=page_size,
+                                       pages=max_pages),
+        {"pages_per_step": g})
+    return max(1, min(int(tile["pages_per_step"]), max_pages))
+
+
+def _book_form(primitive, form, pages_per_step):
+    """Count one trace-time choice of kernel body on
+    ``pt_paged_attention_form_total{primitive, form, pages_per_step}``."""
+    from paddle_tpu.observability import metrics as obs
+
+    obs.counter(
+        "pt_paged_attention_form_total",
+        "Trace-time choices of the paged-attention Pallas kernel's body "
+        "(heads_batched = all heads in one product, a decode row; "
+        "per_head = heads as lane slices, a prefill chunk or the int8 "
+        "pool) and the pages one grid step reads",
+        labels=("primitive", "form", "pages_per_step"),
+    ).labels(primitive=primitive, form=form,
+             pages_per_step=str(pages_per_step)).inc()
+
+
+def _heads_batched_call(name, q, pools, page_table, q_start, scale,
+                        interpret, g):
+    """A decode row's launch (T == 1): grid (B,), the pools unblocked."""
+    b, n, _, d = q.shape
+    page_size = pools[0].shape[1]
+    rows = -(-n // _SUBLANES) * _SUBLANES
+
+    def row_map(bi, pt, qs):
+        return (bi, 0, 0)
+
+    spec = contract.make_spec(
+        name,
+        grid=(b,),
+        in_specs=[Block((1, 1, n * d), row_map)]
+        + [Block(None, None) for _ in pools],
+        out_specs=[Block((1, 1, n * d), row_map)],
+        out_shape=[((b, 1, n * d), q.dtype)],
+        scratch=[Vmem((2, g * page_size, n * d), x.dtype) for x in pools]
+        + [DmaSem((2, len(pools))), Smem((1,), jnp.int32),
+           Vmem((rows, n * d), jnp.float32),
+           Vmem((rows, 128), jnp.float32),
+           Vmem((rows, 128), jnp.float32)],
+        num_scalar_prefetch=2,
+        interpret=interpret,
+    )
+    out = contract.primitive_call(
+        functools.partial(_paged_heads_kernel, d=d, n_sub=g,
+                          page_size=page_size, n_rows=b, sm_scale=scale),
+        spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32),
+        q.reshape(b, 1, n * d), *pools)     # T == 1: the same bytes
+    return out.reshape(b, n, 1, d)
 
 
 def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
-                interpret):
-    """Launch ``kernel`` over q [B, n, T, d] and ``pools`` — each a
+                interpret, heads_batched=False):
+    """Launch over q [B, n, T, d] and ``pools`` — each a
     [P, page, n*w] array (w = d for K/V payloads, 1 for the int8
-    scales), taken as stored."""
+    scales), taken as stored.  ``kernel`` is the pool form's per-head
+    body; with ``heads_batched`` a decode row (T == 1) takes the
+    heads-batched one instead."""
     b, n, t, d = q.shape
     page_size = pools[0].shape[1]
     max_pages = page_table.shape[1]
+    g = _pages_per_step(name, q, page_size, max_pages, pools)
+    if heads_batched and t == 1:
+        _book_form(name, "heads_batched", g)
+        return _heads_batched_call(name, q, pools, page_table, q_start,
+                                   scale, interpret, g)
+    _book_form(name, "per_head", g)
+    steps = -(-max_pages // g)
+    page_table = page_table.astype(jnp.int32)
+    if steps * g != max_pages:  # whole steps: pad with the trash page
+        page_table = jnp.pad(page_table,
+                             ((0, 0), (0, steps * g - max_pages)))
     tp = -(-t // _SUBLANES) * _SUBLANES
-    if tp != t:  # a T=1 decode row rides as one zero-padded sublane tile
+    if tp != t:  # a short block rides zero-padded to a sublane tile
         q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
 
     # index_map signature under scalar prefetch: grid indices first,
@@ -234,16 +475,19 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
     def q_map(bi, pi, pt, qs):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, pi, pt, qs):
-        # read THROUGH the table: the physical page this (row, logical
-        # page) pair maps to — the pool is never gathered
-        return (pt[bi, pi], 0, 0)
+    def kv_map(j):
+        # read THROUGH the table: the physical page of the step's j-th
+        # logical page — the pool is never gathered.  Past a row's length
+        # the table holds the trash page, so consecutive dead blocks
+        # repeat one block index and are not fetched again
+        return lambda bi, pi, pt, qs: (pt[bi, pi * g + j], 0, 0)
 
     spec = contract.make_spec(
         name,
-        grid=(b, max_pages),
+        grid=(b, steps),
         in_specs=[Block((1, n, tp, d), q_map)]
-        + [Block((1, page_size, x.shape[2]), kv_map) for x in pools],
+        + [Block((1, page_size, x.shape[2]), kv_map(j))
+           for x in pools for j in range(g)],
         out_specs=[Block((1, n, tp, d), q_map)],
         out_shape=[((b, n, tp, d), q.dtype)],
         scratch=[
@@ -255,10 +499,10 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
         interpret=interpret,
     )
     out = contract.primitive_call(
-        functools.partial(kernel, page_size=page_size, t=t, n=n, d=d,
-                          n_blocks=max_pages, sm_scale=scale),
-        spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32), q,
-        *pools)
+        functools.partial(kernel, d=d, n_sub=g, keys=g * page_size, t=t,
+                          n=n, n_steps=steps, sm_scale=scale),
+        spec, page_table, q_start.astype(jnp.int32), q,
+        *[x for x in pools for _ in range(g)])
     return out[:, :, :t, :]
 
 
@@ -266,7 +510,7 @@ def _pallas_paged(q, k_pages, v_pages, page_table, q_start, scale,
                   interpret):
     return _paged_call(_paged_kernel, "paged_attention", q,
                        (k_pages, v_pages), page_table, q_start, scale,
-                       interpret)
+                       interpret, heads_batched=True)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
@@ -300,24 +544,27 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
 # ---------------------------------------------------------------------------
 
 
-def _paged_quant_kernel(page_table_ref, q_start_ref, q_ref,
-                        khi_ref, klo_ref, ksc_ref,
-                        vhi_ref, vlo_ref, vsc_ref, o_ref,
-                        acc_ref, m_ref, l_ref, *, d, **kw):
-    def load_kv(h):
-        lanes = slice(h * d, (h + 1) * d)
+def _paged_quant_kernel(page_table_ref, q_start_ref, q_ref, *refs, d,
+                        n_sub, **kw):
+    def load_step():
+        khi, klo, ksc, vhi, vlo, vsc = (
+            _step_pages(refs[i * n_sub:(i + 1) * n_sub]) for i in range(6))
 
-        def deq(hi_ref, lo_ref, sc_ref):
-            # dequant in VMEM: fp32 K/V exists only block-at-a-time
-            hi = hi_ref[0, :, lanes].astype(jnp.float32)
-            lo = lo_ref[0, :, lanes].astype(jnp.float32)
-            return (hi + lo * (1.0 / RESID_DIV)) * sc_ref[0, :, h:h + 1]
+        def load_kv(h):
+            lanes = slice(h * d, (h + 1) * d)
 
-        return (deq(khi_ref, klo_ref, ksc_ref),
-                deq(vhi_ref, vlo_ref, vsc_ref))
+            def deq(hi, lo, sc):
+                # dequant in VMEM: fp32 K/V exists only block-at-a-time
+                return ((hi[:, lanes].astype(jnp.float32)
+                         + lo[:, lanes].astype(jnp.float32)
+                         * (1.0 / RESID_DIV)) * sc[:, h:h + 1])
 
-    _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
-                l_ref, load_kv, **kw)
+            return deq(khi, klo, ksc), deq(vhi, vlo, vsc)
+
+        return load_kv
+
+    _paged_body(page_table_ref, q_start_ref, q_ref, *refs[6 * n_sub:],
+                load_step, **kw)
 
 
 def paged_attention_quant_reference(q, k_hi, k_lo, k_scale, v_hi, v_lo,

@@ -306,6 +306,152 @@ def test_paged_interpret_parity(n, d, t):
         atol=1e-5, rtol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# the two bodies of the paged kernel (PR 30): a grid step reads G pages;
+# a decode row (T = 1) takes all heads in one block-diagonal product,
+# a chunk (T > 1) the per-head body.  Page 8 and G = 4 here, so a step
+# is 32 keys and the table's 10 pages are padded to 12.
+# ---------------------------------------------------------------------------
+
+_PG, _G, _MAXP = 8, 4, 10            # max_len 80; _MAXP % _G != 0
+_NPAGES = 5 * _MAXP + 1              # pages for five full rows
+
+
+@pytest.fixture
+def pages_per_step(monkeypatch, tmp_path):
+    """Pin G through the tile table, the only way a caller can."""
+    def pin(g):
+        tf = tmp_path / f"tiles_{g}.json"
+        tf.write_text(json.dumps(
+            {name: {"*": {"pages_per_step": g}}
+             for name in ("paged_attention", "paged_attention_quant")}))
+        monkeypatch.setenv(autotune.ENV_TABLE, str(tf))
+        autotune.clear_cache()
+
+    yield pin
+    monkeypatch.delenv(autotune.ENV_TABLE, raising=False)
+    autotune.clear_cache()
+
+
+def _booked_forms(primitive="paged_attention"):
+    from paddle_tpu import observability as obs
+
+    fam = obs.snapshot().get("pt_paged_attention_form_total") or {}
+    return {k[1:]: v for k, v in fam.get("samples", {}).items()
+            if k[0] == primitive}
+
+
+def _paged_pool_case(n, d, t, q_starts, seed=0, inactive=()):
+    """Pools whose TRASH page (0) holds large values, so a masked key
+    that leaked would show; live pages in shuffled physical order, every
+    dead entry the shared trash page; ``inactive`` rows get an all-trash
+    table."""
+    b = len(q_starts)
+    rng = np.random.RandomState(seed)
+    q = _rand((b, n, t, d), seed=seed + 1)
+    k_pages = _rand((_NPAGES, _PG, n * d), seed=seed + 2)
+    v_pages = _rand((_NPAGES, _PG, n * d), seed=seed + 3)
+    k_pages[0] = 50.0
+    v_pages[0] = 1000.0
+    free = list(rng.permutation(np.arange(1, _NPAGES)))
+    page_table = np.zeros((b, _MAXP), np.int32)
+    for i, start in enumerate(q_starts):
+        if i not in inactive:
+            for j in range((start + t - 1) // _PG + 1):
+                page_table[i, j] = free.pop()
+    return q, k_pages, v_pages, page_table, np.asarray(q_starts, np.int32)
+
+
+def _assert_paged_parity(case, form, g):
+    before = _booked_forms().get((form, str(g)), 0)
+    got = prims.paged_attention(*case, force="pallas")
+    want = prims.paged_attention_reference(*case)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=1e-5)
+    assert _booked_forms().get((form, str(g)), 0) == before + 1
+
+
+# contexts (keys the decode row sees = q_start + 1) around a page's and
+# a grid step's edges, and the longest the table holds
+@pytest.mark.parametrize("n", [12, 20])
+@pytest.mark.parametrize("context", [
+    1, _PG - 1, _PG, _PG + 1, _G * _PG - 1, _G * _PG, _G * _PG + 1,
+    _MAXP * _PG])
+def test_paged_decode_row_parity(pages_per_step, context, n):
+    """T = 1: the heads-batched body, beside a second row of another
+    length in the same batch."""
+    pages_per_step(_G)
+    case = _paged_pool_case(n, 64, 1, [context - 1, 44], seed=context)
+    _assert_paged_parity(case, "heads_batched", _G)
+
+
+@pytest.mark.parametrize("n", [12, 20])
+@pytest.mark.parametrize("q_start", [0, 1, _PG, _G * _PG - 1,
+                                     _G * _PG, _MAXP * _PG - 32])
+def test_paged_chunk_parity(pages_per_step, q_start, n):
+    """T = 32 (a prefill chunk of four pages): the per-head body."""
+    pages_per_step(_G)
+    case = _paged_pool_case(n, 64, 32, [q_start], seed=q_start)
+    _assert_paged_parity(case, "per_head", _G)
+
+
+@pytest.mark.parametrize("t", [1, 32])
+@pytest.mark.parametrize("g", [1, 2, 3, _MAXP, None])
+def test_paged_pages_per_step(pages_per_step, g, t):
+    """Any G gives the same attention: one page a step, a G that does
+    not divide the table, the whole table in one step, and the G the
+    shapes give (None: 256 keys a step, capped by the table)."""
+    if g is not None:
+        pages_per_step(g)
+    starts = [0, 7, 8, 30, 47] if t == 1 else [0, 9, 16, 40, 48]
+    case = _paged_pool_case(5, 64, t, starts, seed=7)
+    _assert_paged_parity(case, "heads_batched" if t == 1 else "per_head",
+                         _MAXP if g is None else g)
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_paged_inactive_slot_and_shared_trash(pages_per_step, t):
+    """A slot with no sequence (all-trash table, q_start 0) beside live
+    rows: it reads what the reference reads (the trash page's first
+    key), and no live row sees the trash page's values."""
+    pages_per_step(_G)
+    case = _paged_pool_case(12, 64, t, [0, 39, 0, 17], seed=11,
+                            inactive=(0, 2))
+    assert not case[3][0].any() and not case[3][2].any()
+    _assert_paged_parity(case, "heads_batched" if t == 1 else "per_head",
+                         _G)
+    got = np.asarray(prims.paged_attention(*case, force="pallas"))
+    assert np.abs(got[[1, 3]]).max() < 10.0       # V's trash reads 1000
+    if t == 1:
+        np.testing.assert_allclose(got[[0, 2]], 1000.0)
+
+
+def test_paged_quant_keeps_the_per_head_body(pages_per_step):
+    """The int8 pool's decode row stays on the per-head body (its scales
+    are one a head, not one a lane) and reads G pages a step too."""
+    pages_per_step(3)
+    n, d = 5, 64
+    q = _rand((2, n, 1, d), seed=0)
+    k_pages = _rand((9, 8, n, d), seed=1)
+    v_pages = _rand((9, 8, n, d), seed=2)
+    k_hi, k_lo, k_sc = map(_flat, prims.quantize_lastdim(
+        jnp.asarray(k_pages)))
+    v_hi, v_lo, v_sc = map(_flat, prims.quantize_lastdim(
+        jnp.asarray(v_pages)))
+    page_table = np.array([[1, 4, 7, 2], [3, 5, 0, 0]], np.int32)
+    q_start = np.array([30, 12], np.int32)
+    before = _booked_forms("paged_attention_quant").get(("per_head", "3"), 0)
+    got = prims.paged_attention_quant(q, k_hi, k_lo, k_sc, v_hi, v_lo,
+                                      v_sc, page_table, q_start,
+                                      force="pallas")
+    want = prims.paged_attention_quant_reference(
+        q, k_hi, k_lo, k_sc, v_hi, v_lo, v_sc, page_table, q_start)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-5)
+    assert _booked_forms("paged_attention_quant").get(
+        ("per_head", "3"), 0) == before + 1
+
+
 @pytest.mark.parametrize("force", ["pallas", "reference"])
 def test_paged_attention_refuses_a_pool_with_the_heads_apart(force):
     """ONE pool shape: the primitive does not reshape a 4-D pool (that

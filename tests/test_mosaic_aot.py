@@ -78,11 +78,11 @@ def test_ragged_attention(chip, dtype):
     assert _mosaic_calls(hlo) == 1
 
 
-def _paged_shapes(t, page, dtype):
-    b = 8 if t == 1 else 1          # decode step : prefill chunk
-    max_pages = 512 // page
-    pool = ((8 * max_pages + 1, page, HEADS * HEAD_DIM), dtype)
-    return (((b, HEADS, t, HEAD_DIM), jnp.float32 if dtype == jnp.int8
+def _paged_shapes(t, page, dtype, heads=HEADS, slots=8, max_len=512):
+    b = slots if t == 1 else 1      # decode step : prefill chunk
+    max_pages = max_len // page
+    pool = ((slots * max_pages + 1, page, heads * HEAD_DIM), dtype)
+    return (((b, heads, t, HEAD_DIM), jnp.float32 if dtype == jnp.int8
              else dtype), pool,
             ((b, max_pages), jnp.int32), ((b,), jnp.int32))
 
@@ -93,6 +93,20 @@ def test_paged_attention(chip, dtype, t, page):
     q, pool, table, start = _paged_shapes(t, page, dtype)
     hlo = _compile(prims.paged_attention, chip, q, pool, pool, table, start)
     assert _mosaic_calls(hlo) == 1
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_paged_attention_at_the_benchmark_cell_shapes(chip, t):
+    """gpt2-large.closed16-mixed's own call: 16 slots (a decode step) or
+    one 32-token chunk, 20 heads of 64, page 32, a 32-page table, the
+    pool f32[513,32,1280] — read eight pages at a time, by the kernel's
+    own copies (the decode row) or eight BlockSpecs a pool (the chunk)."""
+    q, pool, table, start = _paged_shapes(t, 32, jnp.float32, heads=20,
+                                          slots=16, max_len=1024)
+    assert pool[0] == (513, 32, 1280) and table[0] == (q[0][0], 32)
+    hlo = _compile(prims.paged_attention, chip, q, pool, pool, table, start)
+    assert _mosaic_calls(hlo) == 1 and "%paged_attention" in hlo
+    assert _pool_copies(hlo, 513, 32) == []
 
 
 @pytest.mark.parametrize("t,page", [(1, 32), (32, 32), (1, 16), (128, 128)])
@@ -207,6 +221,31 @@ def _aliased_parameters(hlo):
     return {int(n) for n in re.findall(r"\((\d+), \{\}", header)}
 
 
+def _long_hlo(compiled):
+    """The compiled module with every operand's shape printed, which is
+    how a device trace names an operation (``%x = f32[..] custom-call(
+    s32[16,32]{..} %table, ..)``) and so what the benchmark's trace
+    patterns are written against; ``as_text()`` leaves them out."""
+    from jax._src.lib import xla_client
+
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    options.print_metadata = False
+    return compiled.runtime_executable().hlo_modules()[0].to_string(options)
+
+
+def _roofline_pattern():
+    """The regular expression ``paged_attn_roofline.serve`` finds the
+    decode step's paged-attention calls by, read from the benchmark."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           "paged_attn_roofline.serve.json")) as f:
+        return re.compile(json.load(f)["pattern"])
+
+
 # (hidden, heads, layers, slots, max_len): GPT-base as chip_smoke.py
 # serves it, and GPT-2-large as benchmark/configs/gpt2-large.json serves
 # it (the benchmark's widths, pool and slots; two of its 36 layers)
@@ -246,10 +285,20 @@ def test_decode_engine_executables(chip, size, pool_dtype):
         scales = f"{engine.pool.num_pages},{page},{heads}"
         n_scales = 2 * layers if pool_dtype == "int8" else 0
         relaid = {scales} if n_scales else set()
+        # the yardstick's view (benchmark/layer_metrics/
+        # paged_attn_roofline.serve.json): its pattern names the decode
+        # step's paged calls, one a layer, and none of the chunk's — a
+        # kernel change that moved the page table from the call's first
+        # operand, or split the call, would turn the metric null
+        seen_by_roofline = []
         try:
             with lowering_for("tpu"):
                 for lowered in engine.lower(sharding=chip):
-                    hlo = lowered.compile().as_text()
+                    compiled = lowered.compile()
+                    hlo = compiled.as_text()
+                    seen_by_roofline.append(sum(
+                        1 for line in _long_hlo(compiled).splitlines()
+                        if _roofline_pattern().search(line)))
                     assert _mosaic_calls(hlo) == cfg.num_layers
                     assert set(_pool_copies(hlo, engine.pool.num_pages,
                                             page)) <= relaid
@@ -267,6 +316,7 @@ def test_decode_engine_executables(chip, size, pool_dtype):
                     assert len(scale_params) == n_scales
                     assert {num for num, _ in params + scale_params} <= \
                         _aliased_parameters(hlo)
+            assert seen_by_roofline == [0, cfg.num_layers]
         finally:
             engine.close()
 

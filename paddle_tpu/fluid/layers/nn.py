@@ -20,7 +20,7 @@ __all__ = [
     "kv_cache_write_pages_quant",
     "weight_matmul", "headwise_matmul", "rms_norm", "swiglu",
     "rope_interleaved", "dsa_indexer_scores", "dsa_topk_select",
-    "sparse_mla_attention", "moe_ffn_held",
+    "sparse_mla_attention", "moe_ffn_held", "rope_half", "sigmoid_gate",
     "conv2d", "conv3d", "conv2d_transpose", "pool2d",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "dropout",
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
@@ -1420,16 +1420,20 @@ def moe_ffn(x, num_experts, d_ff, top_k=2, act="gelu", param_attr=None,
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start,
-                    sm_scale=None, force=None, name=None):
+                    sm_scale=None, force=None, name=None, window=None):
     """Attention of q [B, n_heads, T, d] against pool K/V
-    [num_pages, page_size, n_heads*d] read THROUGH a per-sequence page
+    [num_pages, page_size, n_kv_heads*d] (n_kv_heads = n_heads, or a
+    divisor of it: grouped-query heads) read THROUGH a per-sequence page
     table (decode serving lane, docs/SERVING.md "Decode lane";
     kernels/paged_attention.py — Pallas on TPU, lax gather reference on
     CPU).  Query i of row b attends global key positions
-    j <= q_start[b] + i."""
+    j <= q_start[b] + i and, with ``window``, j > q_start[b] + i -
+    window."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}
+    if window is not None:
+        attrs["window"] = int(window)
     if sm_scale is not None:
         attrs["sm_scale"] = float(sm_scale)
     if force is not None:
@@ -1602,6 +1606,19 @@ def rope_interleaved(x, pos, theta, rotary_dim, name=None):
     return _out_f32(LayerHelper("rope_interleaved", name=name),
                     "rope_interleaved", {"X": [x], "Pos": [pos]},
                     {"theta": float(theta), "rotary_dim": int(rotary_dim)})
+
+
+def rope_half(x, pos, theta, name=None):
+    """Rotary embedding in the ``rotate_half`` form over the whole last
+    dimension of x [B, T, H, d]; pos [B, T] (ops/gqa_ops.py)."""
+    return _out_f32(LayerHelper("rope_half", name=name), "rope_half",
+                    {"X": [x], "Pos": [pos]}, {"theta": float(theta)})
+
+
+def sigmoid_gate(x, gate, name=None):
+    """x * sigmoid(gate), elementwise."""
+    return _out_f32(LayerHelper("sigmoid_gate", name=name), "sigmoid_gate",
+                    {"X": [x], "Gate": [gate]})
 
 
 def dsa_indexer_scores(q, w, index_pages, page_table, q_start, force=None,

@@ -41,11 +41,33 @@ Two implementations (the shared resolve_mode dispatch):
   page, heads as d-wide lane slices.  The comment block over the
   kernels says what each buys.
 
+**Grouped-query heads and window layers** (PR 31).  The pools may hold
+fewer K/V heads than ``q`` has query heads (``n_q = g x n_kv``: query
+head j reads K/V head ``j // g``), and ``window=W`` bounds the keys from
+below: query i of row b sees ``max(0, q_start[b] + i - W + 1) ..
+q_start[b] + i``.  Either turns the launch into the grouped form of the
+two bodies — the decode row still copies its live pages itself, now
+starting at the first page its window reaches, with the g query heads of
+a K/V head as g rows on that head's lanes; the chunk gets a grid axis a
+K/V head (a (page, d) lane block of the pool as stored, so d must be a
+multiple of 128 on the chip) and scores the group's g x T query rows at
+once, over the steps between its first query's window and its last
+query, and no others.  Pages below the bound are neither fetched nor
+scored: **a table entry wholly below a row's window may be anything**
+(the allocator points it at the trash page once the page is given
+back, serving/kv_pool.py); what the kernel assumes of the trash page
+is only that it holds finite numbers.  The kernel's ``name=`` says
+which kind it serves (``paged_attention``, ``.._grouped``,
+``.._window``, ``.._grouped_window``), in the HLO and on the dispatch
+and form counters.  Plain multi-head attention over the whole context
+(g = 1, no window) traces exactly what it did.
+
 Shapes:
   q           [B, n_heads, T, d]   T = 1 (decode step) or the prefill
                                    chunk length
-  k/v_pages   [num_pages, page_size, n_heads * d] — heads side by side
-              in the lane dimension (head h is lanes h*d..(h+1)*d).
+  k/v_pages   [num_pages, page_size, n_kv_heads * d] — heads side by
+              side in the lane dimension (head h is lanes h*d..(h+1)*d);
+              n_kv_heads divides n_heads.
               The pool is stored, written and read in THIS shape and
               no other: its default TPU layout is row-major with
               (8, 128) tiles of (page_size, n_heads*d), which is what
@@ -58,6 +80,7 @@ Shapes:
   q_start     [B] int32 — tokens already in the cache BEFORE this q
               block; query i of row b attends keys at global positions
               j <= q_start[b] + i (its own K/V must already be written)
+              and, with ``window``, j > q_start[b] + i - window
 
 Page 0 of the pool is the allocator's trash page (writes of inactive
 slots land there); a row's mask only ever exposes positions below its
@@ -84,16 +107,16 @@ __all__ = ["paged_attention", "paged_attention_reference",
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
-                              sm_scale=None):
+                              sm_scale=None, window=None):
     """Materializing XLA implementation: CPU fallback + numerics oracle.
 
     Mirrors the composed attention path's op spelling (matmul — scale —
     -1e9 mask — jax.nn.softmax — matmul) so greedy decode through the
     pool is comparable with the whole-sequence program token for
-    token."""
+    token.  Grouped-query pools and ``window`` as ``paged_attention``."""
     b, n, t, d = q.shape
-    _check_pool_shapes("paged_attention", q, k_pages=k_pages,
-                       v_pages=v_pages)
+    n_kv = _check_pool_shapes("paged_attention", q, grouped=True,
+                              k_pages=k_pages, v_pages=v_pages)
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     l_max = max_pages * page_size
@@ -101,9 +124,11 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 
     def gathered(pages):
         # the heads come apart on the gathered pages, never on the pool
-        g = pages[page_table]                      # [B, MAXP, PGS, n*d]
-        g = g.reshape(b, l_max, n, d)
-        return jnp.transpose(g, (0, 2, 1, 3))      # [B, n, L, d]
+        g = pages[page_table]                      # [B, MAXP, PGS, n_kv*d]
+        g = g.reshape(b, l_max, n_kv, d)
+        g = jnp.transpose(g, (0, 2, 1, 3))         # [B, n_kv, L, d]
+        # query head j reads K/V head j // (n / n_kv)
+        return g if n_kv == n else jnp.repeat(g, n // n_kv, axis=1)
 
     k = gathered(k_pages)
     v = gathered(v_pages)
@@ -112,7 +137,10 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
     kpos = jax.lax.broadcasted_iota(jnp.int32, (b, n, t, l_max), 3)
     qpos = (q_start.astype(jnp.int32)[:, None, None, None]
             + jax.lax.broadcasted_iota(jnp.int32, (b, n, t, l_max), 2))
-    s = jnp.where(kpos <= qpos, s, jnp.asarray(NEG_INF, s.dtype))
+    visible = kpos <= qpos
+    if window is not None:
+        visible &= kpos > qpos - int(window)
+    s = jnp.where(visible, s, jnp.asarray(NEG_INF, s.dtype))
     p = jax.nn.softmax(s, axis=-1)
     return jnp.matmul(p, v.astype(jnp.float32)).astype(q.dtype)
 
@@ -166,6 +194,17 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 #       row: what it gains is 4 grid steps instead of 32 and 256-key
 #       dots (64 -> 35 us a call).  The int8 pool's scales are one a
 #       head, not one a lane, so its decode row stays here too.
+#   grouped        (n_q = g x n_kv heads and/or a window; PR 31)
+#       T == 1: the heads-batched body with the g query heads of K/V
+#       head j as g rows on lanes j*d..(j+1)*d, the first group copied
+#       starting at the page the row's window reaches.  T > 1
+#       (_paged_kv_head_kernel): grid (B, n_kv, query tiles, steps), a
+#       K/V head's (page, d) lane block a page, the group's g x tq query
+#       rows stacked into one [g*tq, d] operand, so that a chunk of 512
+#       queries of 6 heads meets a K page as a 3072-row product; the
+#       steps start at the tile's first query's window and the grid has
+#       only as many as a window and a tile span.  A bf16 pool feeds
+#       the MXU in bf16 (float32 accumulation, float32 softmax state).
 # ---------------------------------------------------------------------------
 
 _SUBLANES = 8
@@ -179,13 +218,20 @@ _KEYS_PER_STEP = 256
 _BLOCK_VMEM_BYTES = 8 << 20
 
 
-def _check_pool_shapes(op, q, scales=(), **pools):
+def _check_pool_shapes(op, q, scales=(), grouped=False, **pools):
     """The pool has ONE shape, [num_pages, page_size, n_heads*head_dim]
     (an int8 pool's scales [num_pages, page_size, n_heads]); a caller
-    that holds the heads apart is refused, not reshaped."""
+    that holds the heads apart is refused, not reshaped.  ``grouped``
+    also takes n_kv*head_dim lanes for an n_kv that divides n_heads;
+    returns the pools' K/V heads."""
     n, d = q.shape[1], q.shape[3]
+    n_kv = n
     for what, x in pools.items():
         lanes = n if what in scales else n * d
+        if (grouped and x.ndim == 3 and x.shape[2] and x.shape[2] % d == 0
+                and n % (x.shape[2] // d) == 0):
+            n_kv = x.shape[2] // d
+            continue
         if x.ndim != 3 or x.shape[2] != lanes:
             raise ValueError(
                 f"{op}: {what} has shape {tuple(x.shape)}, but the KV "
@@ -194,6 +240,7 @@ def _check_pool_shapes(op, q, scales=(), **pools):
                 f"no executable copies the pool between layouts; "
                 f"reshape a gathered page if the heads are needed "
                 f"apart, never the pool (docs/SERVING.md 'Decode lane')")
+    return n_kv
 
 
 def _online_softmax_step(s, v, acc_ref, m_ref, l_ref, p_dtype=None):
@@ -284,9 +331,20 @@ def _paged_kernel(page_table_ref, q_start_ref, q_ref, *refs, d, n_sub,
                 load_step, **kw)
 
 
+def _mxu(x):
+    """A pool tile as the MXU takes it: bfloat16 as stored, anything
+    else in float32."""
+    return x if x.dtype == jnp.bfloat16 else x.astype(jnp.float32)
+
+
+def _p_dtype(pool_dtype):
+    return jnp.bfloat16 if pool_dtype == jnp.bfloat16 else None
+
+
 def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
                         o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref,
-                        l_ref, *, d, n_sub, page_size, n_rows, sm_scale):
+                        l_ref, *, d, n_sub, page_size, n_rows, sm_scale,
+                        heads_per_kv=None, window=None):
     """The heads-batched body of a decode row (T == 1), one grid step a
     row: q_ref is the row [1, n*d] of all heads' queries, the state
     [rows, ...] holds head h in row h (rows = n rounded up to a sublane
@@ -295,7 +353,12 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
     and no others, are copied ``n_sub`` at a time into one of two VMEM
     slots while the other slot's keys are scored, and the row's last
     group starts the next row's first, so that a copy is waited for only
-    where the pool's bytes are what takes the time."""
+    where the pool's bytes are what takes the time.
+
+    The grouped form (``heads_per_kv`` = g, not None): q_ref and o_ref
+    are [rows, d], query head h a row of its own on the lanes of K/V
+    head h // g.  With ``window`` a row's groups count from the first
+    page its window reaches, and keys below the window are masked."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -303,10 +366,17 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
     rows, width = acc_ref.shape
     keys = n_sub * page_size
 
+    def first_page(row):
+        """The first page ``row``'s window reaches."""
+        return jax.lax.div(
+            jnp.maximum(q_start_ref[row] - (window - 1), 0), page_size)
+
     def each_page(row, group, slot, act):
         """``act`` on the (K, V) copies of every LIVE page of one group
         of ``row``: a start and its wait walk the same pages."""
         first = group * n_sub
+        if window is not None:
+            first = first + first_page(row)
         live = jnp.clip(jax.lax.div(q_start_ref[row], page_size) + 1 - first,
                         0, n_sub)
 
@@ -337,16 +407,31 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
         start(0, 0, 0)
 
     q_start = q_start_ref[bi]
-    groups = jax.lax.div(q_start, keys) + 1
+    if window is None:
+        groups = jax.lax.div(q_start, keys) + 1
+        first_key = 0
+    else:
+        groups = jax.lax.div(
+            jax.lax.div(q_start, page_size) - first_page(bi), n_sub) + 1
+        first_key = first_page(bi) * page_size
     first_slot = slot_ref[0]
     _init_state(acc_ref, m_ref, l_ref)
 
     # [rows, n*d] bool: the d lanes of row h's own head
     first_lane = d * jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    if heads_per_kv is not None:    # row h on the lanes of head h // g
+        first_lane = d * jax.lax.div(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 0), heads_per_kv)
     lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
     own_lanes = (lane >= first_lane) & (lane < first_lane + d)
-    q = jnp.where(own_lanes, jnp.broadcast_to(
-        q_ref[0].astype(jnp.float32), (rows, width)), 0.0)
+    if heads_per_kv is None:
+        q = jnp.where(own_lanes, jnp.broadcast_to(
+            q_ref[0].astype(jnp.float32), (rows, width)), 0.0)
+    else:
+        q = jnp.where(own_lanes, jnp.tile(
+            q_ref[0].astype(jnp.float32), (1, width // d)), 0.0)
+    if k_buf.dtype == jnp.bfloat16:
+        q = q.astype(jnp.bfloat16)
 
     def score_group(g, carry):
         slot = jax.lax.rem(first_slot + g, 2)
@@ -362,13 +447,18 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
                   jnp.where(more, g + 1, 0), 1 - slot)
 
         s = jax.lax.dot_general(
-            q, k_buf[slot].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            q, _mxu(k_buf[slot]), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [rows, keys]
         kpos = g * keys + jax.lax.broadcasted_iota(
             jnp.int32, (rows, keys), 1)
-        s = jnp.where(kpos <= q_start, s * sm_scale, NEG_INF)
-        _online_softmax_step(s, v_buf[slot].astype(jnp.float32), acc_ref,
-                             m_ref, l_ref)
+        if window is None:
+            visible = kpos <= q_start
+        else:
+            kpos = kpos + first_key
+            visible = (kpos <= q_start) & (kpos > q_start - window)
+        s = jnp.where(visible, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, _mxu(v_buf[slot]), acc_ref, m_ref, l_ref,
+                             p_dtype=_p_dtype(v_buf.dtype))
         return carry
 
     jax.lax.fori_loop(0, groups, score_group, 0)
@@ -376,8 +466,85 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
 
     l = l_ref[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = jnp.where(own_lanes, acc_ref[...] / l_safe[:, :1], 0.0)
-    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if heads_per_kv is None:
+        out = jnp.where(own_lanes, acc_ref[...] / l_safe[:, :1], 0.0)
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:   # row h's d lanes, from under its K/V head
+        normed = acc_ref[...] / l_safe[:, :1]
+        kv_head = jax.lax.div(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, d), 0), heads_per_kv)
+        out = jnp.zeros((rows, d), jnp.float32)
+        for j in range(width // d):
+            out = out + jnp.where(kv_head == j,
+                                  normed[:, j * d:(j + 1) * d], 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _first_step(start, window, keys):
+    """The first step of ``keys`` keys that a query at ``start`` with
+    ``window`` reaches."""
+    return jax.lax.div(jnp.maximum(start - (window - 1), 0), keys)
+
+
+def _paged_kv_head_kernel(page_table_ref, q_start_ref, q_ref, *refs, n_sub,
+                          keys, tq, heads_per_kv, n_steps, window,
+                          sm_scale):
+    """The grouped body of a chunk (T > 1): one grid step is one K/V
+    head (its d lanes of ``n_sub`` pages), one tile of ``tq`` queries
+    and one step of ``keys`` keys.  q_ref / o_ref [g*tq, d]: row
+    r*tq + i is query i of the group's r-th head.  The tile's steps
+    count from the one its first query's window reaches."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:n_sub], refs[n_sub:2 * n_sub]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * n_sub:]
+    bi, qi, pi = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    rows = heads_per_kv * tq
+
+    pl.when(pi == 0)(lambda: _init_state(acc_ref, m_ref, l_ref))
+    start = q_start_ref[bi] + qi * tq      # the tile's first query
+    step = pi if window is None else _first_step(start, window, keys) + pi
+
+    @pl.when(step * keys <= start + tq - 1)
+    def _step():
+        kpos = step * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        qpos = start + jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 0), tq)
+        visible = kpos <= qpos
+        if window is not None:
+            visible &= kpos > qpos - window
+        k, v = _mxu(_step_pages(k_refs)), _mxu(_step_pages(v_refs))
+        s = jax.lax.dot_general(
+            q_ref[0, 0, 0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [rows, keys]
+        s = jnp.where(visible, s * sm_scale, NEG_INF)
+        _online_softmax_step(s, v, acc_ref, m_ref, l_ref,
+                             p_dtype=_p_dtype(v.dtype))
+
+    @pl.when(pi == n_steps - 1)
+    def _finish():
+        l = l_ref[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, 0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+
+
+# query rows (heads of a group x queries of a tile) one grid step of the
+# grouped chunk body scores: its [rows, keys] float32 scores and
+# probabilities, the accumulator and the double-buffered q and o blocks
+# stay inside ~10 MB of VMEM at d = 128
+_QUERY_ROWS_PER_STEP = 1536
+
+
+def _query_tile(tp, heads_per_kv):
+    """Queries a tile: the whole (padded) block where its group's rows
+    fit, else the largest sublane-multiple divisor of it that does."""
+    for tiles in range(1, tp // _SUBLANES + 1):
+        tq = tp // tiles
+        if (tp % tiles == 0 and tq % _SUBLANES == 0
+                and heads_per_kv * tq <= _QUERY_ROWS_PER_STEP):
+            return tq
+    return _SUBLANES
 
 
 def _pages_per_step(name, q, page_size, max_pages, pools):
@@ -413,25 +580,35 @@ def _book_form(primitive, form, pages_per_step):
 
 
 def _heads_batched_call(name, q, pools, page_table, q_start, scale,
-                        interpret, g):
-    """A decode row's launch (T == 1): grid (B,), the pools unblocked."""
+                        interpret, g, heads_per_kv=None, window=None):
+    """A decode row's launch (T == 1): grid (B,), the pools unblocked.
+    ``heads_per_kv`` (not None) is the grouped form: q and the output
+    ride [rows, d], a query head a row."""
     b, n, _, d = q.shape
     page_size = pools[0].shape[1]
+    width = pools[0].shape[2]
     rows = -(-n // _SUBLANES) * _SUBLANES
 
     def row_map(bi, pt, qs):
         return (bi, 0, 0)
 
+    if heads_per_kv is None:
+        q_block, q_rows = (1, 1, n * d), q.reshape(b, 1, n * d)
+        kw = {}
+    else:
+        q_block = (1, rows, d)
+        q_rows = jnp.pad(q.reshape(b, n, d), ((0, 0), (0, rows - n), (0, 0)))
+        kw = {"heads_per_kv": heads_per_kv, "window": window}
     spec = contract.make_spec(
         name,
         grid=(b,),
-        in_specs=[Block((1, 1, n * d), row_map)]
+        in_specs=[Block(q_block, row_map)]
         + [Block(None, None) for _ in pools],
-        out_specs=[Block((1, 1, n * d), row_map)],
-        out_shape=[((b, 1, n * d), q.dtype)],
-        scratch=[Vmem((2, g * page_size, n * d), x.dtype) for x in pools]
+        out_specs=[Block(q_block, row_map)],
+        out_shape=[((b,) + q_block[1:], q.dtype)],
+        scratch=[Vmem((2, g * page_size, width), x.dtype) for x in pools]
         + [DmaSem((2, len(pools))), Smem((1,), jnp.int32),
-           Vmem((rows, n * d), jnp.float32),
+           Vmem((rows, width), jnp.float32),
            Vmem((rows, 128), jnp.float32),
            Vmem((rows, 128), jnp.float32)],
         num_scalar_prefetch=2,
@@ -439,19 +616,86 @@ def _heads_batched_call(name, q, pools, page_table, q_start, scale,
     )
     out = contract.primitive_call(
         functools.partial(_paged_heads_kernel, d=d, n_sub=g,
-                          page_size=page_size, n_rows=b, sm_scale=scale),
+                          page_size=page_size, n_rows=b, sm_scale=scale,
+                          **kw),
         spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32),
-        q.reshape(b, 1, n * d), *pools)     # T == 1: the same bytes
+        q_rows, *pools)                     # T == 1: the same bytes
+    if heads_per_kv is not None:
+        out = out[:, :n]
     return out.reshape(b, n, 1, d)
 
 
+def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret, g,
+                  heads_per_kv, window):
+    """The grouped chunk's launch (T > 1): grid (B, n_kv, query tiles,
+    steps), a K/V head's lane block a page."""
+    b, n, t, d = q.shape
+    page_size = pools[0].shape[1]
+    n_kv = n // heads_per_kv
+    max_pages = page_table.shape[1]
+    keys = g * page_size
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    tq = _query_tile(tp, heads_per_kv)
+    tiles = tp // tq
+    steps = -(-max_pages // g)
+    if window is not None:  # a window and a tile span so many steps
+        steps = min(steps, -(-(int(window) + tq - 1) // keys) + 1)
+    # whole steps, and the steps a window's first may run past the
+    # table: pad with the trash page
+    pad = (-(-max_pages // g) + steps) * g - max_pages
+    page_table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
+    if tp != t:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
+    # [B, n_kv, tiles, g*tq, d]: row r*tq + i of a tile is query i of
+    # the group's r-th head
+    rows = heads_per_kv * tq
+    q = q.reshape(b, n_kv, heads_per_kv, tiles, tq, d).transpose(
+        0, 1, 3, 2, 4, 5).reshape(b, n_kv, tiles, rows, d)
+
+    def q_map(bi, hj, qi, pi, pt, qs):
+        return (bi, hj, qi, 0, 0)
+
+    def kv_map(j):
+        def index(bi, hj, qi, pi, pt, qs):
+            step = pi if window is None else _first_step(
+                qs[bi] + qi * tq, window, keys) + pi
+            return (pt[bi, step * g + j], 0, hj)
+        return index
+
+    spec = contract.make_spec(
+        name,
+        grid=(b, n_kv, tiles, steps),
+        in_specs=[Block((1, 1, 1, rows, d), q_map)]
+        + [Block((1, page_size, d), kv_map(j))
+           for _ in pools for j in range(g)],
+        out_specs=[Block((1, 1, 1, rows, d), q_map)],
+        out_shape=[((b, n_kv, tiles, rows, d), q.dtype)],
+        scratch=[Vmem((rows, d), jnp.float32),
+                 Vmem((rows, 128), jnp.float32),
+                 Vmem((rows, 128), jnp.float32)],
+        num_scalar_prefetch=2,
+        interpret=interpret,
+    )
+    out = contract.primitive_call(
+        functools.partial(_paged_kv_head_kernel, n_sub=g, keys=keys, tq=tq,
+                          heads_per_kv=heads_per_kv, n_steps=steps,
+                          window=window, sm_scale=scale),
+        spec, page_table, q_start.astype(jnp.int32), q,
+        *[x for x in pools for _ in range(g)])
+    out = out.reshape(b, n_kv, tiles, heads_per_kv, tq, d).transpose(
+        0, 1, 3, 2, 4, 5).reshape(b, n, tp, d)
+    return out[:, :, :t, :]
+
+
 def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
-                interpret, heads_batched=False):
+                interpret, heads_batched=False, heads_per_kv=None,
+                window=None):
     """Launch over q [B, n, T, d] and ``pools`` — each a
     [P, page, n*w] array (w = d for K/V payloads, 1 for the int8
     scales), taken as stored.  ``kernel`` is the pool form's per-head
     body; with ``heads_batched`` a decode row (T == 1) takes the
-    heads-batched one instead."""
+    heads-batched one instead.  ``heads_per_kv`` (not None) asks for
+    the grouped form of either."""
     b, n, t, d = q.shape
     page_size = pools[0].shape[1]
     max_pages = page_table.shape[1]
@@ -459,7 +703,12 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
     if heads_batched and t == 1:
         _book_form(name, "heads_batched", g)
         return _heads_batched_call(name, q, pools, page_table, q_start,
-                                   scale, interpret, g)
+                                   scale, interpret, g, heads_per_kv,
+                                   window)
+    if heads_per_kv is not None:
+        _book_form(name, "kv_head", g)
+        return _kv_head_call(name, q, pools, page_table, q_start, scale,
+                             interpret, g, heads_per_kv, window)
     _book_form(name, "per_head", g)
     steps = -(-max_pages // g)
     page_table = page_table.astype(jnp.int32)
@@ -507,34 +756,52 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
 
 
 def _pallas_paged(q, k_pages, v_pages, page_table, q_start, scale,
-                  interpret):
-    return _paged_call(_paged_kernel, "paged_attention", q,
-                       (k_pages, v_pages), page_table, q_start, scale,
-                       interpret, heads_batched=True)
+                  interpret, name="paged_attention", heads_per_kv=None,
+                  window=None):
+    return _paged_call(_paged_kernel, name, q, (k_pages, v_pages),
+                       page_table, q_start, scale, interpret,
+                       heads_batched=True, heads_per_kv=heads_per_kv,
+                       window=window)
+
+
+def kernel_name(heads_per_kv, window):
+    """What the kernel that serves this kind of layer is called, in the
+    HLO and on the dispatch and form counters."""
+    return ("paged_attention" + ("_grouped" if heads_per_kv > 1 else "")
+            + ("" if window is None else "_window"))
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
-                    sm_scale=None, force=None):
+                    sm_scale=None, force=None, window=None):
     """Attention of q [B, n, T, d] against pool K/V read through
     `page_table` [B, max_pages]; query i of row b attends global key
-    positions j <= q_start[b] + i.
+    positions j <= q_start[b] + i and, with a static ``window``,
+    j > q_start[b] + i - window.  The pools hold n or any divisor of n
+    K/V heads (grouped-query attention).
 
     force: None → Pallas on TPU, XLA reference elsewhere; "pallas" →
     Pallas (interpret mode off-TPU, for tests); "reference" → XLA."""
-    d = q.shape[-1]
+    n, d = q.shape[1], q.shape[-1]
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
-    _check_pool_shapes("paged_attention", q, k_pages=k_pages,
-                       v_pages=v_pages)
-    if k_pages.dtype != v_pages.dtype:
+    n_kv = _check_pool_shapes("paged_attention", q, grouped=True,
+                              k_pages=k_pages, v_pages=v_pages)
+    if k_pages.dtype != v_pages.dtype or k_pages.shape != v_pages.shape:
         raise ValueError(
-            f"paged_attention: K pool dtype {k_pages.dtype} != V pool "
-            f"dtype {v_pages.dtype} — the pool must be one dtype")
-    mode, interpret = contract.resolve_mode("paged_attention", force)
+            f"paged_attention: K pool {k_pages.dtype}{k_pages.shape} != V "
+            f"pool {v_pages.dtype}{v_pages.shape} — the pool must be one "
+            f"dtype and one shape")
+    window = None if window is None else int(window)
+    name = kernel_name(n // n_kv, window)
+    mode, interpret = contract.resolve_mode(name, force)
     if mode == "pallas":
+        # plain multi-head attention over the whole context keeps the
+        # launch it had; anything else takes the grouped form
+        plain = n_kv == n and window is None
         return _pallas_paged(q, k_pages, v_pages, page_table, q_start,
-                             scale, interpret)
+                             scale, interpret, name,
+                             None if plain else n // n_kv, window)
     return paged_attention_reference(q, k_pages, v_pages, page_table,
-                                     q_start, sm_scale=scale)
+                                     q_start, sm_scale=scale, window=window)
 
 
 # ---------------------------------------------------------------------------

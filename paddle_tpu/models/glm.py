@@ -46,9 +46,7 @@ from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import Constant, Normal
 from paddle_tpu.fluid.param_attr import ParamAttr
 
-EXPERT_STATS_PREFIX = "@MOESTATS@"
-# the decode lane's two programs, each with pick counters of its own
-STATS_PROGRAMS = ("decode", "prefill")
+from . import moe_stats
 
 
 class GLMConfig:
@@ -143,74 +141,9 @@ class GLMConfig:
             build_prefill_chunk=functools.partial(build_glm_prefill_chunk,
                                                   self),
             pool_dtype=self.dtype, prefill_chunk=self.prefill_chunk,
-            device_counters=[
-                lane.DeviceCounter(expert_stats_var_name(i, program),
-                                   self.held_experts + 2)
-                for i in self.moe_layers for program in STATS_PROGRAMS],
-            book_counters=functools.partial(book_expert_stats, self))
-
-
-def expert_stats_var_name(layer, program):
-    """The pick counter of expert layer ``layer`` in ``program`` (one of
-    STATS_PROGRAMS): int32 [held_experts + 2], ops/mla_ops.py
-    ``moe_ffn_held`` "Stats"."""
-    return f"{EXPERT_STATS_PREFIX}l{layer}@{program}"
-
-
-def _m_moe_picks():
-    from paddle_tpu import observability as obs
-
-    return obs.counter(
-        "pt_moe_picks_total",
-        "Router picks of valid tokens by where they landed: held (an "
-        "expert this chip holds), absent (an expert of another chip), "
-        "any (both).  Counted on the device, booked when "
-        "DecodeEngine.book_device_counters() is called",
-        labels=("engine", "where"))
-
-
-def _m_moe_expert_tokens():
-    from paddle_tpu import observability as obs
-
-    return obs.counter(
-        "pt_moe_expert_tokens_total",
-        "Picks each held expert got (its tokens), by layer and expert "
-        "id.  Counted on the device, booked with pt_moe_picks_total",
-        labels=("engine", "layer", "expert"))
-
-
-def _m_moe_touched():
-    from paddle_tpu import observability as obs
-
-    return obs.counter(
-        "pt_moe_experts_touched_total",
-        "Held experts that got at least one pick, summed over the "
-        "expert layers of every run of the program (decode step / "
-        "prefill chunk): the expert weights a run had to read.  Counted "
-        "on the device, booked with pt_moe_picks_total",
-        labels=("engine", "program"))
-
-
-def book_expert_stats(cfg, engine, gained):
-    """``DecodeLane.book_counters`` of this model: what the pick counters
-    gained (``{var name: int64 [held + 2]}``: picks by held expert,
-    picks on absent experts, held experts touched) onto the three
-    ``pt_moe_*`` families."""
-    picks, experts, touched = (_m_moe_picks(), _m_moe_expert_tokens(),
-                               _m_moe_touched())
-    for layer in cfg.moe_layers:
-        for program in STATS_PROGRAMS:
-            g = gained[expert_stats_var_name(layer, program)]
-            held, absent = int(g[:-2].sum()), int(g[-2])
-            picks.labels(engine=engine, where="held").inc(held)
-            picks.labels(engine=engine, where="absent").inc(absent)
-            picks.labels(engine=engine, where="any").inc(held + absent)
-            touched.labels(engine=engine, program=program).inc(int(g[-1]))
-            for e, n in enumerate(g[:-2]):
-                if n:
-                    experts.labels(
-                        engine=engine, layer=str(layer),
-                        expert=str(cfg.first_expert + e)).inc(int(n))
+            device_counters=moe_stats.expert_stats_counters(self),
+            book_counters=functools.partial(moe_stats.book_expert_stats,
+                                            self))
 
 
 # ---------------------------------------------------------------------------
@@ -305,12 +238,8 @@ def _ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
     xf = _rms(x, name + "_ffn_norm", cfg)
     if layer < cfg.first_k_dense_replace:
         return _swiglu_ffn(xf, cfg.intermediate_size, name + "_ffn", cfg)
-    # the layer's pick counter in this program: persistable, updated in
-    # place, fetched by no step (the engine installs and reads it)
-    stats = fluid.default_main_program().global_block().create_var(
-        name=expert_stats_var_name(layer, counted_as),
-        shape=[cfg.held_experts + 2], dtype="int32",
-        persistable=True) if counted_as else None
+    stats = (moe_stats.expert_stats_var(cfg, layer, counted_as)
+             if counted_as else None)
     routed = layers.moe_ffn_held(
         xf, cfg.n_routed_experts, cfg.held_experts,
         cfg.moe_intermediate_size, cfg.num_experts_per_tok,

@@ -99,12 +99,14 @@ def _paged_attention(ctx, q, k_pages, v_pages, page_table, q_start,
                      attrs):
     """Attention of q [B, n, T, d] against the pool through the page
     table — kernels/primitives/paged.py (Pallas on TPU, lax gather
-    reference on CPU; attrs["force"] pins an implementation)."""
+    reference on CPU; attrs["force"] pins an implementation,
+    attrs["window"] bounds the keys from below)."""
     from paddle_tpu.kernels import primitives as _prims
 
     return _prims.paged_attention(
         q, k_pages, v_pages, page_table, q_start,
-        sm_scale=attrs.get("sm_scale"), force=attrs.get("force"))
+        sm_scale=attrs.get("sm_scale"), force=attrs.get("force"),
+        window=attrs.get("window"))
 
 
 # ---------------------------------------------------------------------------

@@ -45,12 +45,16 @@ Every iteration is one ``turn`` span (observability/profiling.py, lane
 ``decode``) whose children name where the host's time goes:
 ``prefill.pages``, ``prefill.feed_build``, ``prefill.run``, ``admit``,
 ``decode.pages``, ``decode.feed_build``, ``decode.run``, ``emit`` (which
-also books the pool's bytes in use by declared row tensor); the
+also books the pool's bytes in use by declared row tensor and its pages
+by cache kind); a lane with window layers gives their dead pages back
+in a second ``prefill.pages`` / ``decode.pages`` span right after the
+run that moved the sequence on; the
 executor's own phases are children of the two ``*.run`` spans, which also
 feed ``pt_decode_step_seconds`` and ``pt_decode_phase_seconds_total``.
 
-Eviction under pool pressure: when a page allocation fails, the
-YOUNGEST other live sequence is evicted — its pages return to the pool,
+Eviction under pool pressure: when a page allocation fails (of any
+cache kind), the YOUNGEST other live sequence is evicted — its pages of
+every kind return to the pool,
 its request re-queues for re-prefill of prompt + already-generated
 tokens (greedy decode is deterministic, so the replay reproduces the
 same stream; the generated prefix is kept, not re-decoded).  Booked on
@@ -76,7 +80,8 @@ from paddle_tpu.observability import profiling as _profiling
 from paddle_tpu.observability import reqtrace as _reqtrace
 
 from .errors import PoolExhaustedError, ServingOverloadError
-from .kv_pool import KVPool, TRASH_PAGE
+from . import lane as _lane
+from .kv_pool import FREED_WHY, KVPool, TRASH_PAGE
 
 __all__ = ["DecodeEngine", "DecodeRequest"]
 
@@ -189,6 +194,34 @@ def _m_pages_in_use():
         "pt_decode_kv_pages_in_use",
         "KV pool pages currently allocated to live sequences",
         labels=("engine",))
+
+
+def _m_kind_pages_in_use():
+    from paddle_tpu import observability as obs
+
+    return obs.gauge(
+        "pt_kv_pages_in_use",
+        "KV pool pages currently allocated to live sequences, by cache "
+        "kind (full / window<W>)", labels=("engine", "kind"))
+
+
+def _m_pages_alloc():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_kv_pages_alloc_total",
+        "KV pool pages handed to sequences, by cache kind",
+        labels=("engine", "kind"))
+
+
+def _m_pages_freed():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_kv_pages_freed_total",
+        "KV pool pages given back, by cache kind and why: window (they "
+        "fell wholly below a live sequence's window), end (the request "
+        "finished) or evict", labels=("engine", "kind", "why"))
 
 
 def _m_cache_bytes():
@@ -307,7 +340,11 @@ class DecodeEngine:
     the lane's max_position); ``prefill_chunk`` and ``pool_dtype``
     default to the lane's; ``num_pages`` defaults to every slot at full
     length (+1 trash) — shrink it to exercise eviction, the lane stays
-    correct (requests replay deterministically)."""
+    correct (requests replay deterministically).  A lane that declares
+    window layers (``lane.layer_windows``) gets a pool tensor size a
+    cache kind: ``num_pages`` is the ``full`` kind's, a window kind has
+    ``pool_slots x lane.window_pages_per_seq`` pages (+1 trash) and never
+    more than ``num_pages``."""
 
     def __init__(self, cfg, *, scope=None, place=None, pool_slots=4,
                  page_size=16, prefill_chunk=None, max_len=None,
@@ -360,9 +397,16 @@ class DecodeEngine:
         self._max_queue = int(_flags.flag("serving_max_queue")
                               if max_queue is None else max_queue)
         rows = lane.cache_rows(pool_dtype)
-        self.pool = KVPool(lane.num_layers, rows, num_pages, page_size,
-                           max_pages)
+        windows = lane.layer_windows
+        self.pool = KVPool(
+            lane.num_layers, rows, num_pages, page_size, max_pages,
+            layer_windows=windows, window_pages={
+                _lane.kind_name(w): self.pool_slots
+                * _lane.window_pages_per_seq(w, prefill_chunk, page_size) + 1
+                for w in set(windows or ()) if w is not None})
         self.pool.install(self.scope)
+        if windows is not None:  # the builders size each layer by its kind
+            num_pages = self.pool.pages_by_kind()
         if pool_dtype == "int8":
             # book the modeled HBM saving of the dual-int8 pool vs the
             # fp32 pool it replaces — the counter the int8-KV claim is
@@ -370,7 +414,7 @@ class DecodeEngine:
             from paddle_tpu.kernels import primitives as _prims
 
             fp32 = KVPool(lane.num_layers, lane.cache_rows("float32"),
-                          num_pages, page_size, max_pages)
+                          self.pool.num_pages, page_size, max_pages)
             _prims.book_bytes_saved(
                 "kv_cache",
                 fp32.modeled_bytes() - self.pool.modeled_bytes())
@@ -489,6 +533,19 @@ class DecodeEngine:
         self._cache_bytes = {
             row: _m_cache_bytes().labels(engine=e, row=row.name)
             for row in self.pool.rows}
+        kinds = self.pool.kinds
+        self._kind_pages = {
+            k: _m_kind_pages_in_use().labels(engine=e, kind=k)
+            for k in kinds}
+        self._kind_alloc = {
+            k: _m_pages_alloc().labels(engine=e, kind=k) for k in kinds}
+        self._kind_freed = {
+            (k, why): _m_pages_freed().labels(engine=e, kind=k, why=why)
+            for k in kinds for why in FREED_WHY}
+        # the pool's running totals as last booked on those counters
+        self._kind_booked = {
+            k: {"alloc_total": 0, "freed": dict.fromkeys(FREED_WHY, 0)}
+            for k in kinds}
         # the SAME cross-lane families engine.py owns: end-to-end decode
         # request latency (submit -> future resolve, exemplar-bearing)
         # and admitted-request counts, with this engine's name as model
@@ -645,10 +702,10 @@ class DecodeEngine:
         return [f.result(timeout=timeout) for f in futs]
 
     def _warm_prefill_args(self):
+        trash = np.zeros(self.prefill_chunk // self.pool.page_size, np.int32)
         return dict(
-            tokens=[0], pos0=0, table_row=self.pool.padded_table(None),
-            write_pages=np.zeros(self.prefill_chunk // self.pool.page_size,
-                                 np.int32), valid=1)
+            tokens=[0], pos0=0, seq_id=None,
+            write_pages={k: trash for k in self.pool.kinds}, valid=1)
 
     def warmup(self):
         """Compile (or AOT-load) both executables outside the request
@@ -830,18 +887,33 @@ class DecodeEngine:
                 self._occupancy.set(
                     sum(s is not None for s in self._slots)
                     / self.pool_slots)
-                in_use = self.pool.pages_in_use()
-                self._pages_gauge.set(in_use)
-                # bytes in use by declared row tensor: part of `emit`, so
-                # that the turn's children stay the eight the benchmark's
-                # idle split knows (readers/host_gap.py)
-                for row, gauge in self._cache_bytes.items():
-                    gauge.set(self.pool.row_bytes(row, in_use))
+                self._pages_gauge.set(self.pool.pages_in_use())
+                # bytes in use by declared row tensor and pages by cache
+                # kind: part of `emit`, so that the turn's children stay
+                # the eight the benchmark's idle split knows
+                # (readers/host_gap.py)
+                self._book_pool()
                 with self._cv:
                     self._depth.set(len(self._queue))
         self._turn_ctr.inc()
         self._turn_part["sched"].inc(
             max(turn.seconds - self._turn_run_s, 0.0))
+
+    def _book_pool(self):
+        """The pool's state onto the gauges, and what its running totals
+        gained since the last booking onto the page counters."""
+        kinds = self.pool.kind_stats()
+        in_use = {k: st["pages_in_use"] for k, st in kinds.items()}
+        for row, gauge in self._cache_bytes.items():
+            gauge.set(self.pool.row_bytes(row, in_use))
+        for k, st in kinds.items():
+            booked = self._kind_booked[k]
+            self._kind_pages[k].set(st["pages_in_use"])
+            self._kind_alloc[k].inc(st["alloc_total"]
+                                    - booked["alloc_total"])
+            for why, n in st["freed"].items():
+                self._kind_freed[k, why].inc(n - booked["freed"][why])
+            self._kind_booked[k] = st
 
     # -- eviction -----------------------------------------------------------
 
@@ -854,7 +926,7 @@ class DecodeEngine:
             if req is protect:
                 continue
             self._live_order.remove(req)
-            self.pool.free_seq(req.seq_id)
+            self.pool.free_seq(req.seq_id, why="evict")
             req.seq_id = None
             req.prefilled = 0
             for i, s in enumerate(self._slots):
@@ -904,20 +976,23 @@ class DecodeEngine:
             valid = min(self.prefill_chunk, total - ctx_len)
             self._ensure_pages(req, ctx_len + valid)
             pgs = self.pool.page_size
-            table = self.pool.table(req.seq_id)
             first_lp = ctx_len // pgs
-            write_pages = np.full(self.prefill_chunk // pgs, TRASH_PAGE,
-                                  np.int32)
-            for j in range(len(write_pages)):
-                lp = first_lp + j
-                if lp < len(table) and lp * pgs < ctx_len + valid:
-                    write_pages[j] = table[lp]
+            write_pages = {}
+            for kind in self.pool.kinds:
+                table = self.pool.table(req.seq_id, kind)
+                pages = np.full(self.prefill_chunk // pgs, TRASH_PAGE,
+                                np.int32)
+                for j in range(len(pages)):
+                    lp = first_lp + j
+                    if lp < len(table) and lp * pgs < ctx_len + valid:
+                        pages[j] = table[lp]
+                write_pages[kind] = pages
         next_tok = self._run_prefill_feed(
             tokens=tokens[ctx_len:ctx_len + valid], pos0=ctx_len,
-            table_row=self.pool.padded_table(req.seq_id),
-            write_pages=write_pages, valid=valid)
+            seq_id=req.seq_id, write_pages=write_pages, valid=valid)
+        req.prefilled = ctx_len + valid
+        self._release_below_window("prefill.pages", [(req, req.prefilled)])
         with _profiling.span("emit", "decode"):
-            req.prefilled = ctx_len + valid
             if req.prefilled == total:
                 if not req.generated:
                     # fresh prompt: the prefill's argmax seeds the
@@ -946,25 +1021,40 @@ class DecodeEngine:
         self._tok_ctr.inc()
         self._tokens += 1
 
-    def _prefill_feed(self, tokens, pos0, table_row, write_pages, valid):
+    def _release_below_window(self, span, moved):
+        """After a run moved sequences on (``moved``: (request, tokens it
+        now holds in the pool)): window layers' pages that no later
+        query of theirs can see go back to the pool, under one of the
+        scheduler's own span names."""
+        if self.lane.layer_windows is None:
+            return
+        with _profiling.span(span, "decode"):
+            for req, length in moved:
+                self.pool.release(req.seq_id, length)
+
+    def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid):
+        """One chunk's feed: a page table and the chunk's write pages a
+        cache kind (``lane.kind_feed``: the ``full`` kind under the plain
+        names)."""
         c = self.prefill_chunk
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
         pos = np.minimum(pos0 + np.arange(c, dtype=np.int64),
                          self.lane.max_position - 1)[None, :]
-        return {
-            "pf_tok": tok,
-            "pf_pos": pos,
-            "pf_page_table": table_row[None, :].astype(np.int32),
-            "pf_write_pages": write_pages.astype(np.int32),
-            "pf_qstart": np.asarray([pos0], np.int32),
-            "pf_last_idx": np.asarray([max(valid - 1, 0)], np.int64),
-        }
+        feed = {"pf_tok": tok, "pf_pos": pos}
+        for kind in self.pool.kinds:
+            feed[_lane.kind_feed("pf_page_table", kind)] = \
+                self.pool.padded_table(seq_id, kind)[None, :].astype(np.int32)
+            feed[_lane.kind_feed("pf_write_pages", kind)] = \
+                write_pages[kind].astype(np.int32)
+        feed["pf_qstart"] = np.asarray([pos0], np.int32)
+        feed["pf_last_idx"] = np.asarray([max(valid - 1, 0)], np.int64)
+        return feed
 
-    def _run_prefill_feed(self, tokens, pos0, table_row, write_pages,
+    def _run_prefill_feed(self, tokens, pos0, seq_id, write_pages,
                           valid, warm=False):
         with _profiling.span("prefill.feed_build", "decode"):
-            feed = self._prefill_feed(tokens, pos0, table_row, write_pages,
+            feed = self._prefill_feed(tokens, pos0, seq_id, write_pages,
                                       valid)
         with self._exec_lock:
             with _profiling.span("prefill.run", "decode") as run:
@@ -1044,24 +1134,34 @@ class DecodeEngine:
                     now_done.append((i, req))
             for i, req in now_done:
                 self._finish(i, req)
+        self._release_below_window(
+            "decode.pages", [(req, req.written_target) for i, req in active
+                             if self._slots[i] is req])
 
     def _decode_feed(self, active):
+        """One step's feed: a page table and the slots' write pages a
+        cache kind (``lane.kind_feed``)."""
         ps, pgs = self.pool_slots, self.pool.page_size
         tok = np.zeros((ps, 1), np.int64)
         pos = np.zeros((ps, 1), np.int64)
-        table = np.tile(self.pool.padded_table(None), (ps, 1))
-        wpage = np.zeros(ps, np.int32)
         woff = np.zeros(ps, np.int32)
         for i, req in active:
             p = req.written_target  # the pending token's position
             tok[i, 0] = req.generated[-1]
             pos[i, 0] = p
-            table[i] = self.pool.padded_table(req.seq_id)
-            wpage[i] = self.pool.table(req.seq_id)[p // pgs]
             woff[i] = p % pgs
-        return {"dec_tok": tok, "dec_pos": pos,
-                "dec_page_table": table.astype(np.int32),
-                "dec_write_page": wpage, "dec_write_off": woff}
+        feed = {"dec_tok": tok, "dec_pos": pos}
+        for kind in self.pool.kinds:
+            table = np.tile(self.pool.padded_table(None, kind), (ps, 1))
+            wpage = np.zeros(ps, np.int32)
+            for i, req in active:
+                table[i] = self.pool.padded_table(req.seq_id, kind)
+                wpage[i] = table[i][pos[i, 0] // pgs]
+            feed[_lane.kind_feed("dec_page_table", kind)] = \
+                table.astype(np.int32)
+            feed[_lane.kind_feed("dec_write_page", kind)] = wpage
+        feed["dec_write_off"] = woff
+        return feed
 
     def _run_decode_feed(self, active, warm=False):
         with _profiling.span("decode.feed_build", "decode"):

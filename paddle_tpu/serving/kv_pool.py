@@ -4,24 +4,45 @@ vLLM-style paged memory for a model's cache on the executor's scope
 model: the pool is one persistable program var per (layer, declared
 cache row) shaped ``[num_pages, page_size, width]`` — a K and a V row
 with the heads side by side for dense attention, a latent row and an
-indexer key for latent sparse attention (serving/lane.py ``CacheRow``);
-every row tensor lives under ONE page table.  The executor donates the
-vars every step so they update in place; a sequence's cache is a LIST
-of page ids (its page table), not a contiguous slab.  Admission, growth
-and eviction therefore move ZERO cache memory — they edit host-side
-page lists — and the decode step stays one fixed-shape executable no
-matter how sequences come and go.
+indexer key for latent sparse attention (serving/lane.py ``CacheRow``).
+The executor donates the vars every step so they update in place; a
+sequence's cache is a LIST of page ids (its page table), not a
+contiguous slab.  Admission, growth and eviction therefore move ZERO
+cache memory — they edit host-side page lists — and the decode step
+stays one fixed-shape executable no matter how sequences come and go.
 
-Page 0 is the TRASH page: never allocated, the write target of inactive
-decode slots and padded prefill tails.  Readers can't observe it —
-every attention masks positions past a row's own length.
+**Kinds.**  Every layer leaves one KIND of cache (serving/lane.py
+``layer_windows``): ``full`` — a token's rows stay until its request
+ends — or ``window<W>`` — the layer attends the last W tokens only.  The
+pool keeps one page list a kind a sequence (every row tensor of every
+layer of a kind lives under that kind's page table), its own free list
+a kind, and sizes a kind's tensors by that kind's worst case: every
+slot at full length for ``full``, ``lane.window_pages_per_seq`` pages a
+slot for a window kind.  A model that declares nothing has the one kind
+``full`` and this allocator does what it always did.
+
+**When a window page is freed.**  ``release(seq_id, length)`` — called
+by the scheduler after a prefill chunk or a decode step has moved the
+sequence on to ``length`` tokens — gives back every logical page of a
+window kind that lies WHOLLY below ``length - W`` (no later query can
+see a key of it) while the request lives; its table entry becomes the
+trash page.  A page index stays logical: a table is as wide for a
+window kind as for ``full``, with trash below the window.
+
+Page 0 of every kind is the TRASH page: never allocated, the write
+target of inactive decode slots and padded prefill tails, and what a
+released entry of a window kind's table points at.  Readers can't
+observe it — every attention masks positions past a row's own length
+and, in a window layer, positions below its window (the kernel does
+not even fetch those pages).
 
 This module is the pure allocator (page lists, free-list reuse,
 accounting); scheduling policy — WHO gets evicted under pressure — lives
 in `serving/decode.py`.  Freed pages are reused LIFO so the hot pages of
 a churning slot stay the same physical pages across steps (cross-step
 slot reuse: the steady-state working set stops growing once warm, which
-`reused_allocs` makes visible).
+`reused_allocs` makes visible), and a page a window gave back is the
+next one handed out.
 """
 
 from __future__ import annotations
@@ -31,11 +52,40 @@ import collections
 import numpy as np
 
 from .errors import PoolExhaustedError
-from .lane import POOL_PREFIX, pool_var_names
+from .lane import POOL_PREFIX, kind_name, kinds_of, pool_var_names
 
 __all__ = ["KVPool", "PoolExhaustedError"]
 
 TRASH_PAGE = 0
+FREED_WHY = ("window", "end", "evict")
+
+
+class _Kind:
+    """One cache kind's allocator state: its pages, its free list, a
+    page list a sequence."""
+
+    def __init__(self, name, window, num_pages, layers):
+        self.name = name
+        self.window = window
+        self.num_pages = int(num_pages)
+        self.layers = layers            # how many layers leave this kind
+        # LIFO free list: a just-freed page is the next one handed out,
+        # so a churning slot's working set stays the same physical pages
+        self.free = collections.deque(range(1, self.num_pages))
+        self.tables = {}                # seq_id -> [page ids], logical order
+        self.first_live = {}            # seq_id -> first page not released
+        self.ever_used = set()          # pages that have ever been allocated
+        self.alloc_total = 0
+        self.reused_allocs = 0          # allocations served by a reused page
+        self.freed = dict.fromkeys(FREED_WHY, 0)
+
+    def in_use(self):
+        return (self.num_pages - 1) - len(self.free)
+
+    def give_back(self, pages, why):
+        for p in reversed(pages):
+            self.free.append(p)
+        self.freed[why] += len(pages)
 
 
 class KVPool:
@@ -46,10 +96,17 @@ class KVPool:
     the trash page, so ``num_pages - 1`` pages are allocatable; a single
     sequence needs up to ``max_pages_per_seq`` of them (the constructor
     enforces one sequence always fits — otherwise eviction could never
-    unblock the allocator)."""
+    unblock the allocator).
+
+    ``layer_windows`` (``lane.DecodeLane.layer_windows``: per layer None
+    or W) declares the layers' cache kinds; ``num_pages`` is then the
+    ``full`` kind's, and a window kind gets ``window_pages[kind]``
+    pages (trash included; never more than ``num_pages``, and
+    ``num_pages`` where none is given)."""
 
     def __init__(self, num_layers, rows, num_pages, page_size,
-                 max_pages_per_seq, prefix=None):
+                 max_pages_per_seq, prefix=None, layer_windows=None,
+                 window_pages=None):
         if num_pages - 1 < max_pages_per_seq:
             raise ValueError(
                 f"KV pool of {num_pages} pages (1 reserved for trash) "
@@ -63,14 +120,33 @@ class KVPool:
         self.prefix = POOL_PREFIX if prefix is None else prefix
         self.var_names = pool_var_names(self.rows, self.num_layers,
                                         self.prefix)
-        # LIFO free list: a just-freed page is the next one handed out,
-        # so a churning slot's working set stays the same physical pages
-        self._free = collections.deque(range(1, self.num_pages))
-        self._tables = {}           # seq_id -> [page ids]
-        self._ever_used = set()     # pages that have ever been allocated
-        self.alloc_total = 0
-        self.free_total = 0
-        self.reused_allocs = 0      # allocations served by a reused page
+        windows = ([None] * self.num_layers if layer_windows is None
+                   else list(layer_windows))
+        self.layer_kinds = [kind_name(w) for w in windows]
+        self._kinds = {}
+        for w in kinds_of(windows):
+            name = kind_name(w)
+            pages = self.num_pages if w is None else min(
+                self.num_pages,
+                int((window_pages or {}).get(name, self.num_pages)))
+            self._kinds[name] = _Kind(name, w, pages,
+                                      self.layer_kinds.count(name))
+        self.kinds = list(self._kinds)
+
+    def _kind(self, kind=None):
+        return self._kinds[self.kinds[0] if kind is None else kind]
+
+    def pages_by_kind(self):
+        """{kind: pages of its tensors, trash included}."""
+        return {k.name: k.num_pages for k in self._kinds.values()}
+
+    # one-kind views of the counters, as they always read
+    alloc_total = property(lambda self: sum(
+        k.alloc_total for k in self._kinds.values()))
+    free_total = property(lambda self: sum(
+        sum(k.freed.values()) for k in self._kinds.values()))
+    reused_allocs = property(lambda self: sum(
+        k.reused_allocs for k in self._kinds.values()))
 
     # -- device arrays ------------------------------------------------------
 
@@ -83,9 +159,10 @@ class KVPool:
         gigabytes, and host zeros would cross to the chip."""
         import jax.numpy as jnp
 
-        for names in self.var_names:
+        for names, kind in zip(self.var_names, self.layer_kinds):
             for name, row in zip(names, self.rows):
-                shape = (self.num_pages, self.page_size, row.width)
+                shape = (self._kinds[kind].num_pages, self.page_size,
+                         row.width)
                 cur = scope.get(name)
                 if (cur is None or tuple(np.shape(cur)) != shape
                         or str(getattr(cur, "dtype", "")) != row.dtype):
@@ -95,12 +172,17 @@ class KVPool:
 
     def row_bytes(self, row, pages=None):
         """Device bytes of one declared row tensor over every layer:
-        resident (``pages`` None) or of ``pages`` pages."""
+        resident (``pages`` None), or of ``pages`` pages — a number (of
+        every layer) or ``{kind: pages}``."""
         import jax.numpy as jnp  # its dtypes know bfloat16
 
-        pages = self.num_pages if pages is None else pages
-        return (pages * self.page_size * row.width
-                * jnp.dtype(row.dtype).itemsize * self.num_layers)
+        if pages is None:
+            pages = self.pages_by_kind()
+        elif not isinstance(pages, dict):
+            pages = dict.fromkeys(self.kinds, pages)
+        return (self.page_size * row.width * jnp.dtype(row.dtype).itemsize
+                * sum(pages[k.name] * k.layers
+                      for k in self._kinds.values()))
 
     def modeled_bytes(self):
         """Device bytes of the resident pool: every declared row tensor
@@ -111,73 +193,123 @@ class KVPool:
     # -- allocation ---------------------------------------------------------
 
     def open_seq(self, seq_id):
-        if seq_id in self._tables:
+        if seq_id in self._kind().tables:
             raise ValueError(f"sequence {seq_id!r} already open")
-        self._tables[seq_id] = []
+        for k in self._kinds.values():
+            k.tables[seq_id] = []
+            k.first_live[seq_id] = 0
 
     def ensure_capacity(self, seq_id, n_tokens):
-        """Grow `seq_id`'s page table to cover `n_tokens` positions.
-        Raises PoolExhaustedError — with the shortfall named — when the
-        free list runs dry; the caller (the scheduler) evicts and
-        retries."""
-        table = self._tables[seq_id]
+        """Grow `seq_id`'s page table, of every kind, to cover `n_tokens`
+        positions (a window kind allocates nothing below what it has
+        released).  Raises PoolExhaustedError — with the kind and the
+        shortfall named — when a free list runs dry; the caller (the
+        scheduler) evicts and retries.  Returns the first kind's
+        table."""
         need = -(-int(n_tokens) // self.page_size)  # ceil
         if need > self.max_pages_per_seq:
             raise ValueError(
                 f"sequence {seq_id!r} needs {need} pages for "
                 f"{n_tokens} tokens, above max_pages_per_seq="
                 f"{self.max_pages_per_seq}")
-        while len(table) < need:
-            if not self._free:
-                raise PoolExhaustedError(
-                    f"KV pool out of pages: sequence {seq_id!r} needs "
-                    f"{need - len(table)} more (of {need}) but 0 of "
-                    f"{self.num_pages - 1} allocatable pages are free "
-                    f"— evict a sequence or grow the pool")
-            page = self._free.pop()
-            if page in self._ever_used:
-                self.reused_allocs += 1
-            self._ever_used.add(page)
-            self.alloc_total += 1
-            table.append(page)
-        return table
+        for k in self._kinds.values():
+            table = k.tables[seq_id]
+            while len(table) < need:
+                if len(table) < k.first_live[seq_id]:
+                    table.append(TRASH_PAGE)    # below the window already
+                    continue
+                if not k.free:
+                    raise PoolExhaustedError(
+                        f"KV pool out of pages of kind {k.name!r}: "
+                        f"sequence {seq_id!r} needs "
+                        f"{need - len(table)} more (of {need}) but 0 of "
+                        f"{k.num_pages - 1} allocatable pages are free "
+                        f"— evict a sequence or grow the pool")
+                page = k.free.pop()
+                if page in k.ever_used:
+                    k.reused_allocs += 1
+                k.ever_used.add(page)
+                k.alloc_total += 1
+                table.append(page)
+        return self._kind().tables[seq_id]
 
-    def free_seq(self, seq_id):
-        """Return every page of `seq_id` to the free list (LIFO)."""
-        pages = self._tables.pop(seq_id, [])
-        for p in reversed(pages):
-            self._free.append(p)
-        self.free_total += len(pages)
-        return len(pages)
+    def release(self, seq_id, length):
+        """`seq_id` now holds `length` tokens: give back, in every window
+        kind, the logical pages WHOLLY below ``length - W`` — no later
+        query sees a key of them — and point their table entries at the
+        trash page.  The freed pages are reusable at once.  Returns how
+        many pages went back."""
+        n = 0
+        for k in self._kinds.values():
+            if k.window is None or seq_id not in k.tables:
+                continue
+            table = k.tables[seq_id]
+            first = k.first_live[seq_id]
+            upto = max(first, (int(length) - k.window) // self.page_size)
+            dead = [table[lp] for lp in range(first, min(upto, len(table)))]
+            table[first:first + len(dead)] = [TRASH_PAGE] * len(dead)
+            k.first_live[seq_id] = upto
+            k.give_back(dead, "window")
+            n += len(dead)
+        return n
+
+    def free_seq(self, seq_id, why="end"):
+        """Return every page of `seq_id`, of every kind, to the free
+        lists (LIFO).  ``why``: ``end`` (the request finished) or
+        ``evict``."""
+        n = 0
+        for k in self._kinds.values():
+            pages = [p for p in k.tables.pop(seq_id, [])
+                     if p != TRASH_PAGE]
+            k.first_live.pop(seq_id, None)
+            k.give_back(pages, why)
+            n += len(pages)
+        return n
 
     # -- views --------------------------------------------------------------
 
-    def table(self, seq_id):
-        return list(self._tables[seq_id])
+    def table(self, seq_id, kind=None):
+        return list(self._kind(kind).tables[seq_id])
 
     def live_seqs(self):
-        return list(self._tables)
+        return list(self._kind().tables)
 
-    def pages_in_use(self):
-        return (self.num_pages - 1) - len(self._free)
+    def pages_in_use(self, kind=None):
+        """Pages allocated to live sequences: of ``kind``, or of every
+        kind together."""
+        if kind is not None:
+            return self._kinds[kind].in_use()
+        return sum(k.in_use() for k in self._kinds.values())
 
-    def padded_table(self, seq_id=None):
-        """One row of the decode feed: the sequence's page table padded
-        with the trash page to max_pages_per_seq (all-trash when
-        seq_id is None — the inactive-slot row)."""
+    def padded_table(self, seq_id=None, kind=None):
+        """One row of the decode feed: the sequence's page table of
+        ``kind`` (the first kind if None) padded with the trash page to
+        max_pages_per_seq (all-trash when seq_id is None — the
+        inactive-slot row)."""
         row = np.full(self.max_pages_per_seq, TRASH_PAGE, np.int32)
         if seq_id is not None:
-            pages = self._tables[seq_id]
+            pages = self._kind(kind).tables[seq_id]
             row[:len(pages)] = pages
         return row
 
+    def kind_stats(self):
+        """Per kind: pages, pages in use, and the running totals of pages
+        allocated and of pages freed by reason."""
+        return {k.name: {"pages_total": k.num_pages - 1,
+                         "pages_in_use": k.in_use(),
+                         "alloc_total": k.alloc_total,
+                         "freed": dict(k.freed)}
+                for k in self._kinds.values()}
+
     def stats(self):
         return {
-            "pages_total": self.num_pages - 1,
+            "pages_total": sum(k.num_pages - 1
+                               for k in self._kinds.values()),
             "pages_in_use": self.pages_in_use(),
             "page_size": self.page_size,
-            "live_seqs": len(self._tables),
+            "live_seqs": len(self._kind().tables),
             "alloc_total": self.alloc_total,
             "free_total": self.free_total,
             "reused_allocs": self.reused_allocs,
+            "kinds": self.kind_stats(),
         }

@@ -8,8 +8,14 @@ A model declares
   tensors of any width and dtype (``CacheRow``).  Dense multi-head
   attention leaves a K and a V row (``kv_rows``); latent attention with
   a learned indexer leaves a latent row and an indexer key.  The pool
-  allocates every row tensor ``[num_pages, page_size, width]`` under ONE
-  page table;
+  allocates every row tensor ``[num_pages, page_size, width]``;
+- optionally the **kind** of cache each layer leaves (``layer_windows``):
+  ``full`` (a token's rows stay until its request ends) or ``window`` with
+  its W (a layer that attends the last W tokens: rows wholly below a
+  sequence's window are given back while the request lives).  The pool
+  keeps ONE page list a kind a sequence, so every layer of a kind reads
+  through the same page table; a model that declares nothing has the one
+  kind ``full`` and the feeds it always had;
 - the **two program builders** of the lane's two fixed-shape
   executables, a decode step over the pool's slots and a prefill chunk
   of one sequence, both against the model's own parameter names and the
@@ -25,6 +31,8 @@ from __future__ import annotations
 import collections
 
 __all__ = ["CacheRow", "DecodeLane", "DeviceCounter", "POOL_PREFIX",
+           "FULL", "kind_name", "kind_feed", "kinds_of",
+           "window_pages_per_seq",
            "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars"]
 
 POOL_PREFIX = "@KVPOOL@"
@@ -34,6 +42,36 @@ CacheRow = collections.namedtuple("CacheRow", ("name", "width", "dtype"))
 # an int32 vector of ``length`` counts that the lane's programs add to in
 # place, under the persistable var ``name``
 DeviceCounter = collections.namedtuple("DeviceCounter", ("name", "length"))
+
+
+FULL = "full"
+
+
+def kind_name(window):
+    """The name of a layer's cache kind: ``full`` (``window`` None), or
+    ``window<W>`` for a layer that attends the last W tokens."""
+    return FULL if window is None else f"window{int(window)}"
+
+
+def kinds_of(layer_windows):
+    """The distinct windows of ``layer_windows`` in the order the pool
+    and the feeds keep their kinds: ``full`` (None) first, then the
+    windows by size."""
+    return sorted(set(layer_windows), key=lambda w: (w is not None, w))
+
+
+def kind_feed(feed, kind):
+    """The feed that carries ``feed`` (a page table, a write page) for
+    cache kind ``kind``: the name itself for ``full``, as every one-kind
+    lane feeds it, ``<feed>@<kind>`` for a window kind."""
+    return feed if kind == FULL else f"{feed}@{kind}"
+
+
+def window_pages_per_seq(window, chunk, page_size):
+    """The most pages one sequence holds in a window kind: the W keys
+    the chunk's first query sees and the chunk itself, in whole pages,
+    and one more for a window that starts inside a page."""
+    return -(-(int(window) + int(chunk)) // int(page_size)) + 1
 
 
 def lane_padded(width):
@@ -69,20 +107,29 @@ def pool_var_names(rows, num_layers, prefix=POOL_PREFIX):
 
 
 def declare_pool_vars(rows, num_layers, num_pages, page_size,
-                      prefix=POOL_PREFIX):
+                      prefix=POOL_PREFIX, layer_windows=None):
     """The pool's persistable vars in the program being built: per layer
     one ``[num_pages, page_size, width]`` var a declared row.  That shape
     keeps the default row-major TPU layout for any width of 128 or more
     (and the kernels read it as stored), so no executable copies a pool
-    tensor (PERF.md finding 4)."""
+    tensor (PERF.md finding 4).  With ``layer_windows`` (a lane of more
+    than one cache kind) ``num_pages`` is ``{kind: pages}`` and layer i's
+    vars have its kind's."""
     from paddle_tpu import fluid
 
     block = fluid.default_main_program().global_block()
+
+    def pages(layer):
+        if layer_windows is None:
+            return int(num_pages)
+        return int(num_pages[kind_name(layer_windows[layer])])
+
     return [tuple(block.create_var(
-        name=name, shape=[int(num_pages), int(page_size), row.width],
+        name=name, shape=[pages(layer), int(page_size), row.width],
         dtype=row.dtype, persistable=True)
         for name, row in zip(names, rows))
-        for names in pool_var_names(rows, num_layers, prefix)]
+        for layer, names in enumerate(
+            pool_var_names(rows, num_layers, prefix))]
 
 
 class DecodeLane:
@@ -98,6 +145,14 @@ class DecodeLane:
     logprobs)``.
     ``pool_dtype`` / ``prefill_chunk``: the model's defaults where the
     engine is given none.
+    ``layer_windows``: per layer, None (kind ``full``) or the W of a layer
+    that attends the last W tokens only (kind ``window<W>``).  Left out,
+    every layer is ``full``.  Declared, the pool keeps one page list a
+    kind a sequence and sizes each kind's tensors by that kind's worst
+    case; the builders are handed ``num_pages`` as ``{kind: pages}`` and
+    take one page table and one set of write pages a kind
+    (``kind_feed``: ``dec_page_table`` for ``full``,
+    ``dec_page_table@window<W>`` for a window kind).
     ``device_counters``: ``[DeviceCounter]`` the programs keep on the
     device: persistable int32 vectors they add to in place and no step
     fetches.  The engine installs them as zeros beside the pool and
@@ -109,7 +164,8 @@ class DecodeLane:
     def __init__(self, *, num_layers, max_position, cache_rows,
                  build_decode_step, build_prefill_chunk,
                  pool_dtype="float32", prefill_chunk=None,
-                 device_counters=(), book_counters=None):
+                 device_counters=(), book_counters=None,
+                 layer_windows=None):
         self.num_layers = int(num_layers)
         self.max_position = int(max_position)
         self.cache_rows = cache_rows
@@ -119,6 +175,13 @@ class DecodeLane:
         self.prefill_chunk = prefill_chunk
         self.device_counters = list(device_counters)
         self.book_counters = book_counters
+        self.layer_windows = (None if layer_windows is None
+                              else list(layer_windows))
+        if (self.layer_windows is not None
+                and len(self.layer_windows) != self.num_layers):
+            raise ValueError(
+                f"DecodeLane: layer_windows names {len(self.layer_windows)} "
+                f"layers of {self.num_layers}")
         if self.device_counters and book_counters is None:
             raise ValueError("DecodeLane: device_counters without "
                              "book_counters would never be read")

@@ -1,0 +1,117 @@
+"""A model with one cache kind builds the programs and feeds the names it
+did before the pool had kinds (PR 31): the decode lane's two executables
+of `models/gpt.py` (float32 and int8 pools) and `models/glm.py` lower to
+the HLO they lowered at the parent commit, compared BY TEXT — both in the
+XLA form of their attention and with the Pallas kernels interpreted
+(`attn_force="pallas"`), where the text holds the paged kernel's own
+body: plain multi-head attention over the whole context traces what it
+traced.
+
+The digests below are of `lowered.as_text()` at commit 67a584c (PR 30's
+tree), made by this file's `digests()` there.  After a deliberate change
+to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
+and paste its output over GOLDEN, saying in the commit why they moved.
+"""
+
+import hashlib
+import json
+
+import ml_dtypes
+import numpy as np
+import pytest
+
+from paddle_tpu import fluid, serving
+from paddle_tpu.models import glm, gpt
+
+GOLDEN = {
+    "gpt.float32.None.prefill": "e4de139bba244c34c30e3378cea1230b90f392d45e9ccf67a0d043c67755d999",
+    "gpt.float32.None.decode": "8535d14ebf7212bdae543d6edac2532ed15a3d0596735d6f6c4335bc33cda3f8",
+    "gpt.int8.None.prefill": "12933a5e197e4aa7654c2e35518d1f01aecd462ade5f36f12413b0007bcd8c5e",
+    "gpt.int8.None.decode": "0892ca85181c45072ab0d457155baa5a1a467e22d81c67888cac767bf54f69b6",
+    "glm.None.prefill": "a42a84a33db2b0b0b740b907c65e7f89d9ac9e7dc67a53b8abb99e50fa945b22",
+    "glm.None.decode": "246eecbf9f8d71f6aa3c18bdf96f4272caad508300e3604a8d278bc211a19dc5",
+    "gpt.float32.pallas.prefill": "4dc7dc62aab88691a43f8bd2292b9e7eafe0f0c3d1ad1ddd3bf911c7f9887706",
+    "gpt.float32.pallas.decode": "931f17d5b326722c8c62cc0222397248c73d072475d72e437d830427d102cfd8",
+    "gpt.int8.pallas.prefill": "6c60c7216eb5c3bdf1ddf181932dce89b726895a1a580d4007cfcf4796f59142",
+    "gpt.int8.pallas.decode": "5b1236c43d90c101518a07f823c0b878bcd2344b3f6cc813df068e8a3a84ae6c",
+    "glm.pallas.prefill": "a695455c35aba4cd6d8ff6a4618f0296cb090b09ceb30089460b82e510b105ce",
+    "glm.pallas.decode": "25c52cd11fe804460cd7cd2a5593d60810a2f70d23a07b4e6713b6c7b1e6eac3"
+}
+
+
+def _zero_scope(build):
+    lm, start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(lm, start), fluid.unique_name.guard():
+        build()
+    scope = fluid.Scope()
+    for p in lm.global_block().all_parameters():
+        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                 else np.dtype(p.dtype))
+        scope.set(p.name, np.zeros(tuple(p.shape), dtype))
+    return scope
+
+
+def _lowered(cfg, scope, force, **kw):
+    eng = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=3, page_size=4,
+        max_len=32, attn_force=force, auto_start=False, name="hlo", **kw)
+    try:
+        return dict(zip(("prefill", "decode"),
+                        (low.as_text() for low in eng.lower())))
+    finally:
+        eng.close()
+
+
+def texts(model, force):
+    """{case: HLO text} of one model's two executables."""
+    force = None if force == "None" else force
+    if model == "glm":
+        cfg = glm.GLMConfig.tiny()
+        low = _lowered(cfg, _zero_scope(lambda: glm.build_glm_lm(cfg)),
+                       force, prefill_chunk=8)
+        return {f"glm.{force}.{which}": t for which, t in low.items()}
+    cfg = gpt.GPTConfig.tiny()
+    out = {}
+    for pool_dtype in ("float32", "int8"):
+        low = _lowered(
+            cfg, _zero_scope(lambda: gpt.build_gpt_lm(cfg, is_test=True)),
+            force, prefill_chunk=8, pool_dtype=pool_dtype)
+        out.update({f"gpt.{pool_dtype}.{force}.{which}": t
+                    for which, t in low.items()})
+    return out
+
+
+def digests():
+    return {case: hashlib.sha256(text.encode()).hexdigest()
+            for model in ("gpt", "glm") for force in ("None", "pallas")
+            for case, text in texts(model, force).items()}
+
+
+@pytest.mark.parametrize("force", ["None", "pallas"])
+@pytest.mark.parametrize("model", ["gpt", "glm"])
+def test_one_kind_lanes_lower_the_hlo_they_lowered(model, force):
+    got = {case: hashlib.sha256(text.encode()).hexdigest()
+           for case, text in texts(model, force).items()}
+    assert got and got == {case: GOLDEN[case] for case in got}
+
+
+def test_one_kind_lanes_feed_the_names_they_fed():
+    cfg = gpt.GPTConfig.tiny()
+    eng = serving.DecodeEngine(
+        cfg, scope=_zero_scope(lambda: gpt.build_gpt_lm(cfg, is_test=True)),
+        place=fluid.CPUPlace(), pool_slots=3, page_size=4, max_len=32,
+        auto_start=False, name="feeds")
+    try:
+        assert eng.pool.kinds == ["full"]
+        assert list(eng._decode_feed([])) == [
+            "dec_tok", "dec_pos", "dec_page_table", "dec_write_page",
+            "dec_write_off"]
+        assert list(eng._prefill_feed(**eng._warm_prefill_args())) == [
+            "pf_tok", "pf_pos", "pf_page_table", "pf_write_pages",
+            "pf_qstart", "pf_last_idx"]
+    finally:
+        eng.close()
+
+
+if __name__ == "__main__":
+    print(json.dumps(digests(), indent=4))

@@ -430,3 +430,114 @@ def test_glm_decode_engine_executables(chip):
                 assert {num for num, _ in pools} <= _aliased_parameters(hlo)
     finally:
         engine.close()
+
+
+# ---------------------------------------------------------------------------
+# Trinity through the decode lane (benchmark/configs/
+# trinity-large-ep8.json): the grouped-query and window forms of the
+# paged kernel at the published widths, and the engine's two executables
+# over a pool with a size a cache kind
+# ---------------------------------------------------------------------------
+
+_TRI_PAGES = {"full": 16 * 262 + 1, "window4096": 16 * 37 + 1}
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+@pytest.mark.parametrize("b,t", [(16, 1), (1, 512)])
+def test_paged_attention_grouped_and_window_at_trinity_widths(chip, b, t,
+                                                              window):
+    """48 query heads on 8 K/V heads of 128 over a bf16 pool
+    [pages, 128, 1024] at a 33k-token page table, as a decode step and
+    as a 512-token chunk see them; the kernel's name says which kind of
+    layer it serves."""
+    import functools
+
+    pages = _TRI_PAGES["full" if window is None else "window4096"]
+    pool = ((pages, 128, 1024), jnp.bfloat16)
+    hlo = _compile(
+        functools.partial(prims.paged_attention, window=window), chip,
+        ((b, 48, t, 128), jnp.float32), pool, pool, ((b, 262), jnp.int32),
+        ((b,), jnp.int32))
+    name = "paged_attention_grouped" + ("" if window is None else "_window")
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == 1
+    assert _pool_copies(hlo, pages, 128) == []
+
+
+def test_trinity_decode_engine_executables(chip):
+    """The prefill chunk and the decode step of Trinity at the
+    benchmark's widths, pool and slots (three of its five layers: the
+    dense sliding one, a full and a sliding expert layer): a paged call
+    a layer, named by its kind — which is what the benchmark's two
+    roofline patterns read —, three grouped products an expert layer,
+    each kind's pool tensors at that kind's size, donated, row-major and
+    UNCOPIED."""
+    import json
+    import os
+
+    import ml_dtypes
+
+    from paddle_tpu.models import trinity
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-ep8.json")) as f:
+        config = json.load(f)
+    args = dict(config["builder"]["config_args"], num_hidden_layers=3,
+                layer_types=["sliding_attention", "full_attention",
+                             "sliding_attention"])
+    cfg = trinity.TrinityConfig(**args)
+    lm, lm_start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
+        trinity.build_trinity_lm(cfg)
+    scope = fluid.Scope()
+    for p in lm.global_block().all_parameters():
+        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                 else np.dtype(p.dtype))
+        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                          tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-trinity",
+        auto_start=False)
+    assert engine.prefill_chunk == 512
+    assert engine.pool.pages_by_kind() == _TRI_PAGES
+    patterns = {
+        kind: re.compile(harness_json(root, f"{kind}_attn_roofline.serve")
+                         ["pattern"])
+        for kind in ("full", "window")}
+    try:
+        with lowering_for("tpu"):
+            for lowered in engine.lower(sharding=chip):
+                compiled = lowered.compile()
+                hlo = compiled.as_text()
+                lines = [line.strip()
+                         for line in _long_hlo(compiled).splitlines()]
+                assert sum(bool(patterns["full"].search(x))
+                           for x in lines) == 1
+                assert sum(bool(patterns["window"].search(x))
+                           for x in lines) == 2
+                assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
+                assert _mosaic_calls(hlo) == 3 + 6
+                pools = []
+                for kind, layers in (("full", 1), ("window4096", 2)):
+                    pages = _TRI_PAGES[kind]
+                    assert _pool_copies(hlo, pages, 128) == []
+                    params = _pool_parameters(hlo, f"{pages},128,1024")
+                    assert len(params) == 2 * layers       # K and V
+                    assert [lay for _, lay in params
+                            if not lay.startswith("{2,1,0")] == []
+                    pools += params
+                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
+    finally:
+        engine.close()
+
+
+def harness_json(root, metric):
+    import json
+    import os
+
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           metric + ".json")) as f:
+        return json.load(f)

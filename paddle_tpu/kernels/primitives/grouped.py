@@ -4,8 +4,8 @@ group: ``out[rows of group g] = lhs[rows of group g] @ rhs[g]``.
 The expert layer's product (ops/mla_ops.py ``moe_ffn_held``): the picks
 that land on the experts held here are sorted by expert, ``group_sizes``
 says how many each expert got, and only the row tiles a group touches
-are computed — an expert nobody picked costs nothing, and no expert
-runs on a token that did not pick it.
+are computed — an expert nobody picked is neither computed nor read,
+and no expert runs on a token that did not pick it.
 
 Shapes:
   lhs          [M, K]    rows sorted by group; rows past
@@ -20,10 +20,13 @@ Two implementations (the shared resolve_mode dispatch):
 - **Pallas kernel**: the megablox schedule (``make_group_metadata`` of
   ``jax.experimental.pallas.ops.tpu.megablox``): one grid visit per
   (row tile, group) pair that shares rows, at most ``M/tm + G - 1`` of
-  them; the group's id rides as scalar prefetch so the weight block's
-  index_map resolves the expert, and rows of a tile that belong to
-  another group are masked on the store.  Launched through the contract
-  under its own name, so a device trace shows ``grouped_matmul``.
+  them, and the grid's visit axis ends at the visits this call has (a
+  traced extent, as megablox's own): a call streams the weights of the
+  experts it touched, each once a row tile, and nothing else.  The
+  group's id rides as scalar prefetch so the weight block's index_map
+  resolves the expert, and rows of a tile that belong to another group
+  are masked on the store.  Launched through the contract under its own
+  name, so a device trace shows ``grouped_matmul``.
 """
 
 from __future__ import annotations
@@ -38,10 +41,16 @@ from .contract import Block, Vmem
 
 __all__ = ["grouped_matmul", "grouped_matmul_reference"]
 
+# A decode step gives an expert a row or two, yet a smaller row tile buys
+# nothing: 128 x 1024 x 1024 is 1.4 us on a v5e's MXU, under the 2.6 us
+# its 2 MiB weight block takes to arrive, so a step is bound by the
+# block's bytes and the padding rows ride for free
 ROW_TILE = 128
-# (tk, tn) by the chip's reading at the expert widths (PERF.md, PR 27:
-# 6144 x 2048 up and 2048 x 6144 down, 128 rows): 1024 x 1024 and
-# 2048 x 512 were the fastest of four, all within 10%
+# (tk, tn): a call's bytes do not depend on the block, and neither does
+# its time: at both expert cells' widths (3072 x 3072; 6144 x 2048 up and
+# 2048 x 6144 down), decode rows and a chunk's, blocks of 1 to 4.5 MiB
+# read within 5% of each other with live visits streaming at ~750 GB/s
+# (PERF.md, PR 32; PR 27 read "within 10%" under the dead visits' traffic)
 _K_TILES = (1024, 512, 256, 128)
 _N_TILES = (1024, 512, 256, 128)
 
@@ -106,7 +115,6 @@ def _pallas_gmm(lhs, rhs, group_sizes, interpret):
         group_sizes=group_sizes.astype(jnp.int32), m=m + pad, tm=tm,
         start_group=jnp.int32(0), num_nonzero_groups=groups,
         visit_empty_groups=False)
-    max_visits = group_ids.shape[0]
 
     def lhs_map(ni, vi, ki, off, gid, tid, nv):
         return (tid[vi], ki)
@@ -117,9 +125,15 @@ def _pallas_gmm(lhs, rhs, group_sizes, interpret):
     def out_map(ni, vi, ki, off, gid, tid, nv):
         return (tid[vi], ni)
 
+    # the schedule holds M/tm + G - 1 visits and this call has `visits`
+    # of them.  A block's copy is issued outside the body, whenever its
+    # index moves, so a step the body skips still streams a weight block:
+    # the grid ends at the live visits (a traced extent, as megablox
+    # sizes its own).  A call with no row keeps one visit, which the body
+    # skips, so that no axis is ever empty
     spec = contract.make_spec(
         "grouped_matmul",
-        grid=(n // tn, max_visits, k // tk),
+        grid=(n // tn, jnp.maximum(visits, 1), k // tk),
         in_specs=[Block((tm, tk), lhs_map), Block((1, tk, tn), rhs_map)],
         out_specs=[Block((tm, tn), out_map)],
         out_shape=[((m + pad, n), jnp.float32)],

@@ -590,11 +590,20 @@ def test_topk_select_is_exact(case, force):
         assert (got[..., :9] == 0.0).all()
 
 
-@pytest.mark.parametrize("sizes", [[3, 0, 10, 5, 2], [0, 0, 0, 0, 0],
-                                   [40, 0, 0, 0, 0], [1, 1, 1, 1, 130]])
-def test_grouped_matmul_pallas_against_oracle(sizes):
+# one block a side (k = 16, n = 24), then three 128-tiles a side under two
+# row tiles, where a grid step no group owns would move a block index:
+# no row at all, empty groups at the front, in the middle and at the end,
+# a group across the row tiles' edge, and both row tiles full
+@pytest.mark.parametrize("sizes,k,n", [
+    ([3, 0, 10, 5, 2], 16, 24), ([0, 0, 0, 0, 0], 16, 24),
+    ([40, 0, 0, 0, 0], 16, 24), ([1, 1, 1, 1, 130], 16, 24),
+    ([0, 0, 0, 0, 0], 384, 384), ([0, 0, 40, 30, 0], 384, 384),
+    ([50, 0, 0, 60, 0], 384, 384), ([100, 0, 56, 0, 90], 384, 384),
+    ([0, 120, 16, 0, 1], 384, 384), ([1, 0, 0, 0, 0], 384, 384),
+    ([0, 0, 0, 0, 256], 384, 384), ([128, 0, 0, 128, 0], 384, 384)])
+def test_grouped_matmul_pallas_against_oracle(sizes, k, n):
     rng = np.random.RandomState(2)
-    m, k, n = max(40, sum(sizes) + 7), 16, 24
+    m = max(40, sum(sizes) + 7) if k == 16 else 256
     lhs = rng.randn(m, k).astype(np.float32)
     rhs = rng.randn(len(sizes), k, n).astype(np.float32)
     want = np.zeros((m, n), np.float32)
@@ -607,3 +616,4 @@ def test_grouped_matmul_pallas_against_oracle(sizes):
                                    jnp.asarray(sizes, jnp.int32),
                                    force=force)
         np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
+        assert (np.asarray(got)[sum(sizes):] == 0.0).all()

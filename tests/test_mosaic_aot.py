@@ -360,14 +360,16 @@ def test_dsa_kernels_at_glm5_widths(chip, b, t):
     assert _pool_copies(hlo, _GLM_PAGES, _GLM_PAGE) == []
 
 
-@pytest.mark.parametrize("rows", [128, 4096])
-@pytest.mark.parametrize("k,n", [(6144, 2048), (2048, 6144)])
-def test_grouped_matmul_at_glm5_widths(chip, rows, k, n):
-    """The expert layer's product over 16 held experts: a decode step's
-    128 pick rows and a chunk's 4096."""
+@pytest.mark.parametrize("groups,rows,k,n", [
+    (16, 128, 6144, 2048), (16, 4096, 6144, 2048), (16, 128, 2048, 6144),
+    (16, 4096, 2048, 6144), (32, 128, 3072, 3072), (32, 2048, 3072, 3072)])
+def test_grouped_matmul_at_glm5_widths(chip, groups, rows, k, n):
+    """The expert layer's product over GLM-5's 16 held experts (a decode
+    step's 128 pick rows and a chunk's 4096) and Trinity's 32 (128 and
+    2048), the grid's visit extent a traced number."""
     hlo = _compile(lambda x, w, g: prims.grouped_matmul(x, w, g), chip,
-                   ((rows, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16),
-                   ((16,), jnp.int32))
+                   ((rows, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16),
+                   ((groups,), jnp.int32))
     assert _mosaic_calls(hlo) == 1 and "%grouped_matmul" in hlo
 
 

@@ -21,6 +21,8 @@ __all__ = [
     "weight_matmul", "headwise_matmul", "rms_norm", "swiglu",
     "rope_interleaved", "dsa_indexer_scores", "dsa_topk_select",
     "sparse_mla_attention", "moe_ffn_held", "rope_half", "sigmoid_gate",
+    "paged_mla_attention", "mla_chunk_attention", "bicubic_resize_table",
+    "rope_2d_interleaved", "vit_attention", "select_embedding_rows",
     "conv2d", "conv3d", "conv2d_transpose", "pool2d",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "dropout",
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
@@ -1655,6 +1657,85 @@ def sparse_mla_attention(q_latent, q_rope, latent_pages, page_table,
                      "LatentPages": [latent_pages],
                      "PageTable": [page_table], "Selected": [selected],
                      "QStart": [q_start]}, attrs)
+
+
+def paged_mla_attention(q_latent, q_rope, latent_pages, page_table, q_start,
+                        sm_scale, force=None, name=None):
+    """Latent-space attention over every visible row of the paged latent
+    cache [num_pages, page_size, >= C + R] -> [B, T, H, C] float32 (the
+    decode step's form; kernels/primitives/mla.py)."""
+    attrs = {"sm_scale": float(sm_scale)}
+    if force is not None:
+        attrs["force"] = force
+    return _out_f32(LayerHelper("paged_mla_attention", name=name),
+                    "paged_mla_attention",
+                    {"QLatent": [q_latent], "QRope": [q_rope],
+                     "LatentPages": [latent_pages],
+                     "PageTable": [page_table], "QStart": [q_start]}, attrs)
+
+
+def mla_chunk_attention(q_nope, q_rope, latent_pages, page_table, q_start,
+                        kv_lora_rank, v_head_dim, sm_scale, k_attr=None,
+                        v_attr=None, dtype="float32", force=None, name=None):
+    """Head-space attention of q [B, T, H, nope | R] over every visible
+    row of the paged latent cache, the rows up-projected inside the
+    kernel by the two halves of the KV up-projection, ``k_attr``
+    [H, nope, C] and ``v_attr`` [H, C, v] (the parameters
+    ``headwise_matmul`` holds in the decode step) -> [B, T, H, v]
+    float32 (the prefill chunk's form; kernels/primitives/mla.py)."""
+    helper = LayerHelper("mla_chunk_attention", name=name)
+    heads, nope = q_nope.shape[-2], q_nope.shape[-1]
+    init = Normal(0.0, 0.02)
+    w_uk = helper.create_parameter(
+        k_attr, shape=[heads, nope, int(kv_lora_rank)], dtype=dtype,
+        default_initializer=init)
+    w_uv = helper.create_parameter(
+        v_attr, shape=[heads, int(kv_lora_rank), int(v_head_dim)],
+        dtype=dtype, default_initializer=init)
+    attrs = {"sm_scale": float(sm_scale)}
+    if force is not None:
+        attrs["force"] = force
+    return _out_f32(helper, "mla_chunk_attention",
+                    {"QNope": [q_nope], "QRope": [q_rope],
+                     "LatentPages": [latent_pages],
+                     "PageTable": [page_table], "QStart": [q_start],
+                     "WUk": [w_uk], "WUv": [w_uv]}, attrs)
+
+
+def bicubic_resize_table(table, out_h, out_w, name=None):
+    """A learned [h0, w0, D] position table resized (bicubic, a = -0.75,
+    half-pixel centres) to [out_h * out_w, D] float32."""
+    return _out_f32(LayerHelper("bicubic_resize_table", name=name),
+                    "bicubic_resize_table", {"X": [table]},
+                    {"out_h": int(out_h), "out_w": int(out_w)})
+
+
+def rope_2d_interleaved(x, grid_h, grid_w, theta, name=None):
+    """Rotary embedding of x [N, H, d] by each patch's (row, column) on a
+    (grid_h, grid_w) grid in row-major order (ops/vision_ops.py)."""
+    return _out_f32(LayerHelper("rope_2d_interleaved", name=name),
+                    "rope_2d_interleaved", {"X": [x]},
+                    {"grid_h": int(grid_h), "grid_w": int(grid_w),
+                     "theta": float(theta)})
+
+
+def vit_attention(q, k, v, sm_scale, dtype="float32", force=None, name=None):
+    """Bidirectional attention inside one image: q, k, v [N, H, d] ->
+    [N, H, d] float32, operands rounded to ``dtype``
+    (kernels/primitives/vit.py)."""
+    attrs = {"sm_scale": float(sm_scale), "dtype": dtype}
+    if force is not None:
+        attrs["force"] = force
+    return _out_f32(LayerHelper("vit_attention", name=name), "vit_attention",
+                    {"Q": [q], "K": [k], "V": [v]}, attrs)
+
+
+def select_embedding_rows(emb, rows, idx, name=None):
+    """A chunk's input rows: ``emb`` [B, T, D], or, where ``idx`` [B, T]
+    is >= 0, that row of the staged image rows ``rows`` [R, 1, D]."""
+    return _out_f32(LayerHelper("select_embedding_rows", name=name),
+                    "select_embedding_rows",
+                    {"Emb": [emb], "Rows": [rows], "Idx": [idx]})
 
 
 def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,

@@ -11,6 +11,9 @@ serving lane lowers through:
   dsa        learned sparse attention over a paged latent cache:
              indexer scores, exact top-k selection, latent attention
   grouped    grouped matrix product over rows sorted by group (experts)
+  mla        dense latent attention over a paged latent cache: latent
+             space for the decode step, head space for the prefill chunk
+  vit        bidirectional attention inside one image (a vision tower)
 
 Raw ``pl.pallas_call`` / ``pltpu`` outside this package is a lint
 error (tools/lint_kernels.py) unless marked ``# kernel: allow``.
@@ -42,6 +45,10 @@ from .dsa import (  # noqa: F401
 from .grouped import (  # noqa: F401
     grouped_matmul, grouped_matmul_reference,
 )
+from .mla import (  # noqa: F401
+    mla_chunk_attention, mla_chunk_attention_reference,
+    paged_mla_attention, paged_mla_attention_reference,
+)
 from .paged import (  # noqa: F401
     paged_attention, paged_attention_quant,
     paged_attention_quant_reference, paged_attention_reference,
@@ -49,6 +56,7 @@ from .paged import (  # noqa: F401
 from .ragged import (  # noqa: F401
     ragged_attention, ragged_attention_reference,
 )
+from .vit import vit_attention, vit_attention_reference  # noqa: F401
 
 __all__ = [
     "Block", "KernelSpec", "Vmem", "make_spec", "primitive_call",
@@ -65,4 +73,7 @@ __all__ = [
     "dsa_topk_select", "dsa_topk_select_reference",
     "sparse_mla_attention", "sparse_mla_attention_reference",
     "grouped_matmul", "grouped_matmul_reference",
+    "paged_mla_attention", "paged_mla_attention_reference",
+    "mla_chunk_attention", "mla_chunk_attention_reference",
+    "vit_attention", "vit_attention_reference",
 ]

@@ -1,0 +1,560 @@
+"""Kimi-VL-style vision-language decoder (``model_type: kimi_vl``), Fluid
+graph-building style: a DeepSeek-V3-shaped decoder with multi-head
+latent attention over EVERY visible row (no query low-rank path, no
+indexer), sigmoid-routed experts all held here, behind a MoonViT
+native-resolution tower and an MLP projector whose rows stand in for the
+media placeholder's embedding.
+
+  x0[p]    E[tok[p]], except at a position that holds an image row (the
+           media placeholder id): there the next row of the projector's
+           output for the request's images, in order.  Positions are
+           plain 1-D for every token.
+  block    a = x + Attn(RMS(x)); y = a + F(RMS(a))
+  Attn(u)  q = u W_q -> H x [nope | rope]; [c | k_r] = u W_kva;
+           c <- RMS(c); RoPE (interleaved pairs) on k_r (one row shared
+           by all heads) and on each head's rope dims of q.  A token's
+           cache row is [c | k_r].  Two forms of the same numbers
+           (kernels/primitives/mla.py): the decode step in LATENT space,
+           q~_h = q_nope,h W_uk,h, scores (q~_h . c + q_rope,h . k_r) /
+           sqrt(nope + rope), o_h = (P_h c) W_uv,h; the prefill chunk in
+           HEAD space, k_h = [c W_uk,h | k_r], v_h = c W_uv,h, built
+           from the cached rows inside the kernel.  Then W_o.
+  F        the first ``first_k_dense_replace`` layers: SwiGLU.  The
+           others: s = sigmoid(u W_r) in float32; picks = top-k of s + b
+           (no group limit); gates scaling x s / (sum of the picked s +
+           1e-20); the picks on the ``held_experts`` experts this process
+           holds (all of them by default: ops/mla_ops.py
+           ``moe_ffn_held``), plus ONE shared SwiGLU of width
+           n_shared_experts x moe_intermediate_size.
+  head     final RMSNorm, untied lm_head.
+  tower    an image of (grid_h, grid_w) patches of 14 x 14 x 3 values:
+           z = patch W_p + b_p + table resized (bicubic) to the grid;
+           pre-LayerNorm blocks z += W_o Attn2d(LN z); z += fc1
+           gelu_tanh(fc0 LN z), every linear with bias, attention
+           bidirectional inside the image with 2-D RoPE on q and k
+           (ops/vision_ops.py); a final LayerNorm; 2 x 2 neighbouring
+           patches side by side, one image row a 4 patches (row-major
+           over the merged grid); projector LN a patch, Linear + exact
+           GeLU + Linear to the decoder's width.
+
+Four builders on the same parameter names: ``build_kimi_vl_lm`` (a whole
+text sequence, its caches program-local), ``build_kimi_vl_decode_step``,
+``build_kimi_vl_prefill_chunk`` and ``build_kimi_vl_vision_encoder`` (the
+decode lane's executables; ``KimiVLConfig.decode_lane()`` hands them to
+``serving.DecodeEngine``, the encoder as the lane's ``ImageEncoder``).
+Matrices are stored in ``cfg.dtype`` (bfloat16 in the serving lane) and
+multiplied in it with float32 accumulation; norms, biases, the router's
+product, the position table and activations between ops are float32;
+cache rows are ``cfg.dtype``, staged image rows float32.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+from paddle_tpu import fluid
+from paddle_tpu.fluid import layers
+from paddle_tpu.fluid.initializer import Constant
+from paddle_tpu.fluid.param_attr import ParamAttr
+
+from . import moe_stats
+from .glm import _attr, _linear, _rms, _rope, _swiglu_ffn  # same decoder parts
+
+
+class KimiVLConfig:
+    def __init__(self, vocab_size=163840, hidden_size=2048,
+                 num_hidden_layers=27, first_k_dense_replace=1,
+                 intermediate_size=11264, moe_intermediate_size=1408,
+                 num_attention_heads=16, kv_lora_rank=512,
+                 qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                 n_routed_experts=64, num_experts_per_tok=6,
+                 n_shared_experts=2, routed_scaling_factor=2.446,
+                 norm_topk_prob=True, rms_norm_eps=1e-5, rope_theta=8e5,
+                 max_position_embeddings=131072,
+                 media_placeholder_token_id=163605, vt_hidden_size=1152,
+                 vt_num_hidden_layers=27, vt_num_attention_heads=16,
+                 vt_intermediate_size=4304, patch_size=14,
+                 init_pos_emb_height=64, init_pos_emb_width=64,
+                 merge_kernel_size=(2, 2), vt_rope_theta=1e4,
+                 vt_layer_norm_eps=1e-5, num_channels=3, image_grids=(),
+                 held_experts=None, first_expert=0, dtype="bfloat16",
+                 prefill_chunk=None, initializer_range=0.02):
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.num_hidden_layers = num_hidden_layers
+        self.first_k_dense_replace = first_k_dense_replace
+        self.intermediate_size = intermediate_size
+        self.moe_intermediate_size = moe_intermediate_size
+        self.num_attention_heads = num_attention_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.n_routed_experts = n_routed_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.n_shared_experts = n_shared_experts
+        self.routed_scaling_factor = routed_scaling_factor
+        self.norm_topk_prob = norm_topk_prob
+        self.rms_norm_eps = rms_norm_eps
+        self.rope_theta = rope_theta
+        self.max_position_embeddings = max_position_embeddings
+        self.media_placeholder_token_id = media_placeholder_token_id
+        self.vt_hidden_size = vt_hidden_size
+        self.vt_num_hidden_layers = vt_num_hidden_layers
+        self.vt_num_attention_heads = vt_num_attention_heads
+        self.vt_intermediate_size = vt_intermediate_size
+        self.patch_size = patch_size
+        self.init_pos_emb_height = init_pos_emb_height
+        self.init_pos_emb_width = init_pos_emb_width
+        self.merge_kernel_size = tuple(merge_kernel_size)
+        self.vt_rope_theta = vt_rope_theta
+        self.vt_layer_norm_eps = vt_layer_norm_eps
+        self.num_channels = num_channels
+        # the patch grids (grid_h, grid_w) whose encoders warm-up compiles
+        self.image_grids = [tuple(int(n) for n in g) for g in image_grids]
+        self.held_experts = (n_routed_experts if held_experts is None
+                             else held_experts)
+        self.first_expert = first_expert
+        self.dtype = dtype
+        self.prefill_chunk = prefill_chunk
+        self.initializer_range = initializer_range
+        if self.merge_kernel_size != (2, 2):
+            raise ValueError("KimiVLConfig: 2 x 2 patches an image row")
+        if vt_hidden_size % vt_num_attention_heads or (
+                vt_hidden_size // vt_num_attention_heads) % 4:
+            raise ValueError("KimiVLConfig: a tower head holds whole "
+                             "(column, row) pairs of rotary pairs")
+
+    @classmethod
+    def tiny(cls, **kw):
+        d = dict(vocab_size=96, hidden_size=64, num_hidden_layers=3,
+                 first_k_dense_replace=1, intermediate_size=96,
+                 moe_intermediate_size=24, num_attention_heads=4,
+                 kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+                 n_shared_experts=1, max_position_embeddings=128,
+                 media_placeholder_token_id=95, vt_hidden_size=48,
+                 vt_num_hidden_layers=2, vt_num_attention_heads=4,
+                 vt_intermediate_size=80, patch_size=2,
+                 init_pos_emb_height=4, init_pos_emb_width=4,
+                 image_grids=((4, 4), (2, 6)), dtype="float32")
+        d.update(kw)
+        return cls(**d)
+
+    @property
+    def moe_layers(self):
+        return list(range(self.first_k_dense_replace,
+                          self.num_hidden_layers))
+
+    @property
+    def patch_values(self):
+        return self.num_channels * self.patch_size ** 2
+
+    def image_rows(self, grid):
+        """Image rows (prompt positions) of an image of ``grid`` patches."""
+        gh, gw = grid
+        if gh % 2 or gw % 2:
+            raise ValueError(f"an image's patch grid {tuple(grid)} must be "
+                             f"even both ways (2 x 2 patches a row)")
+        return (gh // 2) * (gw // 2)
+
+    def patchify(self, pixels):
+        """pixels [H, W, channels] -> (patches [gh * gw, patch_values]
+        float32 in row-major grid order, each (channel, y, x)-major as a
+        convolution's filter sees it; (gh, gw))."""
+        pixels = np.asarray(pixels, np.float32)
+        p = self.patch_size
+        h, w, ch = pixels.shape
+        if h % (2 * p) or w % (2 * p) or ch != self.num_channels:
+            raise ValueError(
+                f"an image must be [H, W, {self.num_channels}] with H and W "
+                f"multiples of {2 * p}, got {pixels.shape}")
+        gh, gw = h // p, w // p
+        patches = pixels.reshape(gh, p, gw, p, ch).transpose(0, 2, 4, 1, 3)
+        return (np.ascontiguousarray(patches.reshape(gh * gw, -1)),
+                (gh, gw))
+
+    def cache_rows(self, pool_dtype=None):
+        """What a token leaves in each layer: the latent row
+        [c_kv | k_rope], stored at whole lane tiles (576 -> 640)."""
+        from paddle_tpu.serving.lane import CacheRow, lane_padded
+
+        dtype = pool_dtype or self.dtype
+        if dtype == "int8":
+            raise ValueError(
+                "models/kimi_vl.py: no int8 form of the latent cache (the "
+                "dual-int8 pool is dense K/V's, models/gpt.py)")
+        return [CacheRow("latent", lane_padded(
+            self.kv_lora_rank + self.qk_rope_head_dim), dtype)]
+
+    def decode_lane(self):
+        """This model's decode-lane declaration (serving/lane.py)."""
+        from paddle_tpu.serving import lane
+
+        def prepare(pixels):
+            patches, grid = self.patchify(pixels)
+            return lane.PreparedImage(grid, {"enc_patches": patches},
+                                      self.image_rows(grid))
+
+        return lane.DecodeLane(
+            num_layers=self.num_hidden_layers,
+            max_position=self.max_position_embeddings,
+            cache_rows=self.cache_rows,
+            build_decode_step=functools.partial(build_kimi_vl_decode_step,
+                                                self),
+            build_prefill_chunk=functools.partial(
+                build_kimi_vl_prefill_chunk, self),
+            pool_dtype=self.dtype, prefill_chunk=self.prefill_chunk,
+            device_counters=moe_stats.expert_stats_counters(self),
+            book_counters=functools.partial(moe_stats.book_expert_stats,
+                                            self),
+            encoder=lane.ImageEncoder(
+                build=functools.partial(build_kimi_vl_vision_encoder, self),
+                prepare=prepare, shapes=self.image_grids,
+                rows_of=self.image_rows, row_width=self.hidden_size,
+                placeholder_id=self.media_placeholder_token_id))
+
+
+# ---------------------------------------------------------------------------
+# decoder pieces
+# ---------------------------------------------------------------------------
+
+
+def _linear_b(x, size, name, cfg):
+    """x W + b: W in ``cfg.dtype``, the bias float32."""
+    bias = layers.create_parameter(
+        [size], "float32", attr=_attr(name + ".b_0", cfg, Constant(0.0)))
+    return layers.elementwise_add(_linear(x, size, name, cfg), bias)
+
+
+def _attention(x, pos, page_table, q_start, pool, write, shape, cfg, name,
+               attn_force):
+    """Latent attention over every visible row; writes the token's cache
+    row first (a query sees its own position).  A decode step (T = 1)
+    takes the latent-space form, a chunk the head-space form: the same
+    parameters either way."""
+    L = layers
+    b, t = shape
+    heads = cfg.num_attention_heads
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    (latent_pool,) = pool
+    xa = _rms(x, name + "_attn_norm", cfg)
+    q = L.reshape(_linear(xa, heads * (nope + rope), name + "_q", cfg),
+                  shape=[b, t, heads, nope + rope])
+    q_nope, q_rope = L.split(q, [nope, rope], dim=-1)
+    q_rope = _rope(q_rope, pos, cfg)
+    c_kv, k_rope = L.split(
+        _linear(xa, cfg.kv_lora_rank + rope, name + "_kv_a", cfg),
+        [cfg.kv_lora_rank, rope], dim=-1)
+    latent = [_rms(c_kv, name + "_kv_a_norm", cfg), _rope(k_rope, pos, cfg)]
+    pad = latent_pool.shape[2] - cfg.kv_lora_rank - rope
+    if pad:  # the row is stored at whole lane tiles (lane.lane_padded)
+        latent.append(L.fill_constant(shape=[b, t, pad], value=0.0,
+                                      dtype="float32"))
+    write(latent_pool, L.cast(L.concat(latent, axis=2), latent_pool.dtype))
+
+    scale = float(nope + rope) ** -0.5
+    k_attr = _attr(name + "_kv_b_k.w_0", cfg)
+    v_attr = _attr(name + "_kv_b_v.w_0", cfg)
+    if t == 1:
+        q_lat = L.headwise_matmul(q_nope, cfg.kv_lora_rank,
+                                  param_attr=k_attr, dtype=cfg.dtype)
+        o_lat = L.paged_mla_attention(q_lat, q_rope, latent_pool,
+                                      page_table, q_start, sm_scale=scale,
+                                      force=attn_force)
+        o = L.headwise_matmul(o_lat, cfg.v_head_dim, param_attr=v_attr,
+                              dtype=cfg.dtype)
+    else:
+        o = L.mla_chunk_attention(
+            q_nope, q_rope, latent_pool, page_table, q_start,
+            cfg.kv_lora_rank, cfg.v_head_dim, sm_scale=scale, k_attr=k_attr,
+            v_attr=v_attr, dtype=cfg.dtype, force=attn_force)
+    return _linear(L.reshape(o, shape=[b, t, heads * cfg.v_head_dim]),
+                   cfg.hidden_size, name + "_o", cfg)
+
+
+def _ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
+    xf = _rms(x, name + "_ffn_norm", cfg)
+    if layer < cfg.first_k_dense_replace:
+        return _swiglu_ffn(xf, cfg.intermediate_size, name + "_ffn", cfg)
+    stats = (moe_stats.expert_stats_var(cfg, layer, counted_as)
+             if counted_as else None)
+    routed = layers.moe_ffn_held(
+        xf, cfg.n_routed_experts, cfg.held_experts,
+        cfg.moe_intermediate_size, cfg.num_experts_per_tok,
+        first_expert=cfg.first_expert,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        norm_topk_prob=cfg.norm_topk_prob, row_valid=row_valid, stats=stats,
+        dtype=cfg.dtype, force=attn_force, name=name + "_moe")
+    shared = _swiglu_ffn(
+        xf, cfg.n_shared_experts * cfg.moe_intermediate_size,
+        name + "_shared", cfg)
+    return layers.elementwise_add(routed, shared)
+
+
+def _decoder(tok, pos, page_table, q_start, pools, write, row_valid, shape,
+             cfg, attn_force=None, counted_as=None, image_rows=None):
+    """Embedding (image rows where ``image_rows`` = (staged rows, index a
+    position) says so) and every block over tok/pos [B, T] -> hidden
+    [B, T, D] (before the final norm)."""
+    L = layers
+    b, t = shape
+    emb = L.embedding(tok, size=[cfg.vocab_size, cfg.hidden_size],
+                      param_attr=_attr("kimi_embed.w_0", cfg),
+                      dtype=cfg.dtype)
+    x = L.cast(L.reshape(emb, shape=[b, t, cfg.hidden_size]), "float32")
+    if image_rows is not None:
+        x = L.select_embedding_rows(x, *image_rows)
+    for layer in range(cfg.num_hidden_layers):
+        name = f"kimi_layer_{layer}"
+        x = L.elementwise_add(x, _attention(
+            x, pos, page_table, q_start, pools[layer], write, shape, cfg,
+            name, attn_force))
+        x = L.elementwise_add(x, _ffn(x, layer, row_valid, counted_as, cfg,
+                                      name, attn_force))
+    return x
+
+
+def _next_token(h, cfg):
+    """h [N, 1, D] -> (greedy next token [N] int64, logprobs [N, V])."""
+    L = layers
+    logits = L.reshape(_linear(_rms(h, "kimi_final_norm", cfg),
+                               cfg.vocab_size, "kimi_head", cfg),
+                       shape=[-1, cfg.vocab_size])
+    logp = L.log_softmax(logits)
+    return L.argmax(logp, axis=-1), logp
+
+
+def _declare_pools(cfg, num_pages, page_size, pool_dtype):
+    from paddle_tpu.serving import lane
+
+    return lane.declare_pool_vars(
+        cfg.cache_rows(pool_dtype), cfg.num_hidden_layers, num_pages,
+        page_size)
+
+
+# ---------------------------------------------------------------------------
+# the decoder's three builders
+# ---------------------------------------------------------------------------
+
+
+def build_kimi_vl_decode_step(cfg: KimiVLConfig, pool_slots, num_pages,
+                              page_size, max_pages, pool_dtype=None,
+                              attn_force=None):
+    """ONE token-level decode step over the paged latent cache: the
+    feeds, the output and the slot semantics of models/gpt.py
+    build_gpt_decode_step.  A decoded token is never an image row."""
+    L = layers
+    ps = int(pool_slots)
+    tok = fluid.data("dec_tok", [ps, 1], False, dtype="int64")
+    pos = fluid.data("dec_pos", [ps, 1], False, dtype="int64")
+    page_table = fluid.data("dec_page_table", [ps, int(max_pages)], False,
+                            dtype="int32")
+    write_page = fluid.data("dec_write_page", [ps], False, dtype="int32")
+    write_off = fluid.data("dec_write_off", [ps], False, dtype="int32")
+    pools = _declare_pools(cfg, num_pages, page_size, pool_dtype)
+    q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")
+
+    def write(pool, rows):                                 # rows [PS, 1, w]
+        L.kv_cache_write(pool, rows, write_page, write_off)
+
+    x = _decoder(tok, pos, page_table, q_start, pools, write, write_page,
+                 (ps, 1), cfg, attn_force, counted_as="decode")
+    next_tok, logp = _next_token(x, cfg)
+    feeds = ["dec_tok", "dec_pos", "dec_page_table", "dec_write_page",
+             "dec_write_off"]
+    return feeds, next_tok, logp
+
+
+def _chunk(cfg, c, page_table, write_pages, q_start, last_idx, pools,
+           attn_force, counted_as="prefill", image_rows=None):
+    """One sequence's chunk of ``c`` tokens through the blocks; returns
+    the hidden state of every position [1, C, D]."""
+    L = layers
+    tok = fluid.data("pf_tok", [1, c], False, dtype="int64")
+    pos = fluid.data("pf_pos", [1, c], False, dtype="int64")
+
+    def write(pool, rows):                                 # rows [1, C, w]
+        L.kv_cache_write_pages(
+            pool, L.reshape(rows, shape=[c, 1, -1]), write_pages)
+
+    row_valid = L.cast(L.less_equal(L.range(0, c, 1, "int64"), last_idx),
+                       "int32")
+    return _decoder(tok, pos, page_table, q_start, pools, write, row_valid,
+                    (1, c), cfg, attn_force, counted_as, image_rows)
+
+
+def build_kimi_vl_prefill_chunk(cfg: KimiVLConfig, chunk_len, num_pages,
+                                page_size, max_pages, pool_dtype=None,
+                                attn_force=None, image_rows=None):
+    """One prefill CHUNK of a single sequence through the paged cache:
+    the feeds, the output and the page-write semantics of models/gpt.py
+    build_gpt_prefill_chunk, and one feed more, ``pf_row_idx`` [1, C]
+    int32: -1 where the position is a token, else the row of the staged
+    image rows (``image_rows``: the engine's name and row count of that
+    var, serving/lane.py ``ImageEncoder``) that stands at the position.
+    A chunk with no image position feeds -1 throughout: one executable."""
+    L = layers
+    c = int(chunk_len)
+    if c % int(page_size):
+        raise ValueError(
+            f"prefill chunk_len {c} must be a multiple of page_size "
+            f"{page_size} (chunks write whole pages)")
+    page_table = fluid.data("pf_page_table", [1, int(max_pages)], False,
+                            dtype="int32")
+    write_pages = fluid.data("pf_write_pages", [c // int(page_size)], False,
+                             dtype="int32")
+    q_start = fluid.data("pf_qstart", [1], False, dtype="int32")
+    last_idx = fluid.data("pf_last_idx", [1], False, dtype="int64")
+    pools = _declare_pools(cfg, num_pages, page_size, pool_dtype)
+    feeds = ["pf_tok", "pf_pos", "pf_page_table", "pf_write_pages",
+             "pf_qstart", "pf_last_idx"]
+    rows = None
+    if image_rows is not None:
+        from paddle_tpu.serving import lane
+
+        rows = (lane.declare_row_staging(image_rows, cfg.hidden_size),
+                fluid.data("pf_row_idx", [1, c], False, dtype="int32"))
+        feeds.append("pf_row_idx")
+    x = _chunk(cfg, c, page_table, write_pages, q_start, last_idx, pools,
+               attn_force, image_rows=rows)
+    flat = L.reshape(x, shape=[-1, cfg.hidden_size])
+    h_last = L.reshape(L.gather(flat, last_idx),
+                       shape=[-1, 1, cfg.hidden_size])
+    next_tok, logp = _next_token(h_last, cfg)
+    return feeds, next_tok, logp
+
+
+def build_kimi_vl_lm(cfg: KimiVLConfig = None, is_test=True, seq_len=None,
+                     page_size=None, attn_force=None):
+    """A whole TEXT sequence in one pass: logprobs [S, V] of every
+    position of ``pf_tok`` [1, S].  The same blocks as the decode lane's
+    chunk over a cache that lives and dies inside the program (identity
+    page table).  Inference only."""
+    del is_test
+    L = layers
+    cfg = cfg or KimiVLConfig()
+    c = int(seq_len or cfg.prefill_chunk or 128)
+    page = int(page_size or min(c, 128))
+    if c % page:
+        raise ValueError(f"seq_len {c} must be a multiple of page {page}")
+    n = c // page
+    page_table = L.reshape(L.cast(L.range(1, n + 1, 1, "int64"), "int32"),
+                           shape=[1, n])
+    write_pages = L.reshape(page_table, shape=[n])
+    q_start = L.fill_constant(shape=[1], value=0, dtype="int32")
+    last_idx = L.fill_constant(shape=[1], value=c - 1, dtype="int64")
+    pools = [tuple(L.fill_constant(shape=[n + 1, page, row.width], value=0.0,
+                                   dtype=row.dtype)
+                   for row in cfg.cache_rows())
+             for _ in range(cfg.num_hidden_layers)]
+    x = _chunk(cfg, c, page_table, write_pages, q_start, last_idx, pools,
+               attn_force, counted_as=None)
+    _, logp = _next_token(L.reshape(x, shape=[c, 1, cfg.hidden_size]), cfg)
+    return logp
+
+
+# ---------------------------------------------------------------------------
+# the tower
+# ---------------------------------------------------------------------------
+
+
+def _ln(x, name, cfg):
+    return layers.layer_norm(
+        x, begin_norm_axis=len(x.shape) - 1, epsilon=cfg.vt_layer_norm_eps,
+        param_attr=ParamAttr(name=name + ".scale",
+                             initializer=Constant(1.0)),
+        bias_attr=ParamAttr(name=name + ".bias", initializer=Constant(0.0)))
+
+
+def pos_table_var_name(grid):
+    """The position table resized to ``grid``: a persistable var the
+    encoder's prepare program writes once and its run program reads."""
+    return f"kimi_vit_pos@{grid[0]}x{grid[1]}"
+
+
+def _tower(patches, pos, grid, cfg, attn_force):
+    """patches [N, patch_values], pos [N, width] -> image rows
+    [N / 4, hidden_size]."""
+    L = layers
+    gh, gw = grid
+    n, width = gh * gw, cfg.vt_hidden_size
+    heads = cfg.vt_num_attention_heads
+    d = width // heads
+    z = L.elementwise_add(_linear_b(patches, width, "kimi_vit_patch", cfg),
+                          pos)
+    for layer in range(cfg.vt_num_hidden_layers):
+        name = f"kimi_vit_layer_{layer}"
+        qkv = L.reshape(_linear_b(_ln(z, name + "_ln0", cfg), 3 * width,
+                                  name + "_qkv", cfg),
+                        shape=[n, 3 * heads, d])
+        q, k, v = L.split(qkv, 3, dim=1)
+        o = L.vit_attention(
+            L.rope_2d_interleaved(q, gh, gw, cfg.vt_rope_theta),
+            L.rope_2d_interleaved(k, gh, gw, cfg.vt_rope_theta), v,
+            sm_scale=float(d) ** -0.5, dtype=cfg.dtype, force=attn_force)
+        z = L.elementwise_add(z, _linear_b(
+            L.reshape(o, shape=[n, width]), width, name + "_o", cfg))
+        hidden = L.gelu(_linear_b(_ln(z, name + "_ln1", cfg),
+                                  cfg.vt_intermediate_size, name + "_fc0",
+                                  cfg), approximate=True)
+        z = L.elementwise_add(z, _linear_b(hidden, width, name + "_fc1",
+                                           cfg))
+    z = _ln(_ln(z, "kimi_vit_final_ln", cfg), "kimi_proj_ln", cfg)
+    # 2 x 2 neighbouring patches side by side, row-major over the merged
+    # grid and inside a row
+    merged = L.reshape(
+        L.transpose(L.reshape(z, shape=[gh // 2, 2, gw // 2, 2, width]),
+                    perm=[0, 2, 1, 3, 4]), shape=[n // 4, 4 * width])
+    hidden = L.gelu(_linear_b(merged, 4 * width, "kimi_proj_fc0", cfg))
+    return _linear_b(hidden, cfg.hidden_size, "kimi_proj_fc1", cfg)
+
+
+def _pos_table_param(cfg):
+    return layers.create_parameter(
+        [cfg.init_pos_emb_height, cfg.init_pos_emb_width,
+         cfg.vt_hidden_size], "float32",
+        attr=_attr("kimi_vit_pos.w_0", cfg))
+
+
+def build_kimi_vl_vision_encoder(cfg: KimiVLConfig, grid_h, grid_w,
+                                 staging_rows, attn_force=None):
+    """The tower and the projector over ONE image of (grid_h, grid_w)
+    patches, its rows written into the engine's staged image rows
+    (``staging_rows`` of them; serving/lane.py ``ImageEncoder``) at the
+    fed places: feeds ``enc_patches`` [N, patch_values] float32 and
+    ``enc_row_idx`` [N / 4] int32.
+
+    Returns ``(feed names, the prepare program)``.  The prepare
+    program resizes the learned position table to this grid
+    into a persistable var of its own; the engine runs it ONCE, when it
+    builds this shape's encoder, and no encoder run resizes anything."""
+    from paddle_tpu.serving import lane
+
+    L = layers
+    grid = (int(grid_h), int(grid_w))
+    n, n_rows = grid[0] * grid[1], cfg.image_rows(grid)
+    prepare = fluid.Program()
+    with fluid.program_guard(prepare, fluid.Program()):
+        block = prepare.global_block()
+        resized = block.create_var(
+            name=pos_table_var_name(grid), shape=[n, cfg.vt_hidden_size],
+            dtype="float32", persistable=True)
+        L.assign(L.bicubic_resize_table(_pos_table_param(cfg), *grid),
+                 output=resized)
+    prepare.name = "vision_encoder_prepare"
+
+    patches = fluid.data("enc_patches", [n, cfg.patch_values], False,
+                         dtype="float32")
+    row_idx = fluid.data("enc_row_idx", [n_rows], False, dtype="int32")
+    pos = fluid.default_main_program().global_block().create_var(
+        name=pos_table_var_name(grid), shape=[n, cfg.vt_hidden_size],
+        dtype="float32", persistable=True)
+    rows = _tower(patches, pos, grid, cfg, attn_force)
+    staging = lane.declare_row_staging(staging_rows, cfg.hidden_size)
+    L.kv_cache_write(staging, L.reshape(rows, shape=[n_rows, 1, -1]),
+                     row_idx, L.fill_constant(shape=[n_rows], value=0,
+                                              dtype="int32"))
+    return ["enc_patches", "enc_row_idx"], prepare

@@ -31,5 +31,6 @@ from . import fused_ops  # noqa: F401
 from . import decode_ops  # noqa: F401
 from . import mla_ops  # noqa: F401
 from . import gqa_ops  # noqa: F401
+from . import vision_ops  # noqa: F401
 from . import compat_ops  # noqa: F401
 from . import interop_tail_ops  # noqa: F401

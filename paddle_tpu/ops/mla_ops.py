@@ -10,6 +10,10 @@ decode lane (models/glm.py): what the older zoo had no op for.
   dsa_indexer_scores, dsa_topk_select, sparse_mla_attention
                      learned sparse attention over the paged indexer and
                      latent caches (kernels/primitives/dsa.py)
+  paged_mla_attention, mla_chunk_attention
+                     dense latent attention over the paged latent cache,
+                     in latent space (decode step) and in head space
+                     (prefill chunk) (kernels/primitives/mla.py)
   moe_ffn_held       the expert layer of ONE chip of an expert-parallel
                      deployment: routes over every expert, computes the
                      picks that land on the experts it holds
@@ -108,6 +112,30 @@ def _sparse_mla_attention(ctx, q_lat, q_rope, latent_pages, page_table,
 
     return _prims.sparse_mla_attention(
         q_lat, q_rope, latent_pages, page_table, selected, q_start,
+        sm_scale=attrs["sm_scale"], force=attrs.get("force"))
+
+
+@simple_op("paged_mla_attention",
+           ["QLatent", "QRope", "LatentPages", "PageTable", "QStart"],
+           ["Out"], grad=None)
+def _paged_mla_attention(ctx, q_lat, q_rope, latent_pages, page_table,
+                         q_start, attrs):
+    from paddle_tpu.kernels import primitives as _prims
+
+    return _prims.paged_mla_attention(
+        q_lat, q_rope, latent_pages, page_table, q_start,
+        sm_scale=attrs["sm_scale"], force=attrs.get("force"))
+
+
+@simple_op("mla_chunk_attention",
+           ["QNope", "QRope", "LatentPages", "PageTable", "QStart", "WUk",
+            "WUv"], ["Out"], grad=None)
+def _mla_chunk_attention(ctx, q_nope, q_rope, latent_pages, page_table,
+                         q_start, w_uk, w_uv, attrs):
+    from paddle_tpu.kernels import primitives as _prims
+
+    return _prims.mla_chunk_attention(
+        q_nope, q_rope, latent_pages, page_table, q_start, w_uk, w_uv,
         sm_scale=attrs["sm_scale"], force=attrs.get("force"))
 
 

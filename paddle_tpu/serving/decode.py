@@ -15,7 +15,24 @@ models/gpt.py (a K and a V row, dense paged attention) and models/glm.py
 (a latent row and an indexer key, learned sparse attention, held
 experts) go through the same engine, scheduler and pool.
 
-Execution model — exactly TWO compiled signatures in steady state:
+A lane may also declare an image encoder (serving/lane.py
+``ImageEncoder``: a vision tower and its projector; models/kimi_vl.py).
+A request may then carry images, whose encoded rows stand at the prompt
+positions that hold the model's placeholder id.  The encoder runs INSIDE
+the turn loop, one image at most a turn, just ahead of the first prefill
+chunk that needs the image's rows (a chunk that needs a second image
+waits a turn; the decode step of the turn runs all the same).  The rows
+never leave the device: the encoder's program writes them into the
+engine's one row-staging var (as large as the largest declared image
+and one chunk, whatever the number of requests), the chunk's program
+reads them from there by an index a position, nothing is fetched and
+nothing is put, and a place is written over as soon as the chunk that
+held its position has run.  An evicted request replays from token 0 and
+its images are encoded again.  One executable an image shape, every
+declared shape compiled by ``warmup()``.
+
+Execution model — exactly TWO compiled signatures in steady state (and
+one more an image shape where the lane declares an encoder):
 
   prefill chunk   the lane's ``build_prefill_chunk`` — [1, C] tokens of
                   ONE sequence, its cache rows written into whole pool
@@ -44,6 +61,8 @@ Scheduling loop (one scheduler thread per engine):
 Every iteration is one ``turn`` span (observability/profiling.py, lane
 ``decode``) whose children name where the host's time goes:
 ``prefill.pages``, ``prefill.feed_build``, ``prefill.run``, ``admit``,
+``encode.feed_build``, ``encode.run`` (an encoder run, where the lane has
+one: dispatched and not waited for, so its span is the host's side only),
 ``decode.pages``, ``decode.feed_build``, ``decode.run``, ``emit`` (which
 also books the pool's bytes in use by declared row tensor and its pages
 by cache kind); a lane with window layers gives their dead pages back
@@ -128,6 +147,26 @@ def _m_prefill_chunks():
         "pt_decode_prefill_chunks_total",
         "Prefill chunk executions (a P-token prompt is "
         "ceil(P/chunk) of these)", labels=("engine",))
+
+
+def _m_encoder_runs():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_encoder_runs_total",
+        "Image-encoder executions inside the scheduler's turn loop, by "
+        "image shape (one image a run; a replay after an eviction "
+        "encodes again)", labels=("engine", "shape"))
+
+
+def _m_prompt_tokens():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_prompt_tokens_total",
+        "Prompt positions of admitted requests by what stands there: "
+        "image (a row of an image encoder's output) or text (a token's "
+        "embedding)", labels=("engine", "kind"))
 
 
 def _m_turns():
@@ -281,10 +320,17 @@ class DecodeRequest:
 
     __slots__ = ("prompt", "max_new_tokens", "eos_id", "tenant", "future",
                  "seq_id", "generated", "prefilled", "t_arrival",
-                 "span", "t_first", "t_admit", "token_times")
+                 "span", "t_first", "t_admit", "token_times", "images",
+                 "image_spans")
 
-    def __init__(self, prompt, max_new_tokens, eos_id, tenant):
+    def __init__(self, prompt, max_new_tokens, eos_id, tenant, images=(),
+                 image_spans=()):
         self.prompt = [int(t) for t in prompt]
+        # the request's images as their encoder takes them
+        # (lane.PreparedImage) and, for each, (first prompt position,
+        # rows): where its rows stand
+        self.images = list(images)
+        self.image_spans = list(image_spans)
         self.max_new_tokens = int(max_new_tokens)
         self.eos_id = None if eos_id is None else int(eos_id)
         self.tenant = tenant
@@ -422,6 +468,21 @@ class DecodeEngine:
         self._counters_seen = {}
         self._install_device_counters()
 
+        # a lane with an image encoder: the one row-staging var beside the
+        # pool, and the chunk's feed that indexes it
+        self._enc = lane.encoder
+        self._attn_force = attn_force
+        self._encoders = {}     # image shape -> (program, feed names)
+        chunk_kw = {}
+        if self._enc is not None:
+            self._max_image_rows = max(self._enc.rows_of(s)
+                                       for s in self._enc.shapes)
+            self._staging_rows = self._max_image_rows + prefill_chunk
+            chunk_kw["image_rows"] = self._staging_rows
+            self._install_row_staging()
+        self._reset_staging()
+        self._staged_live_max = 0
+
         # two programs, two fixed-shape executables — built once, against
         # the SAME parameter names the training lanes use
         dec_prog, dec_start = fluid.Program(), fluid.Program()
@@ -435,7 +496,7 @@ class DecodeEngine:
                 fluid.unique_name.guard():
             self._pf_feeds, pf_tok, _ = lane.build_prefill_chunk(
                 prefill_chunk, num_pages, page_size, max_pages,
-                pool_dtype=pool_dtype, attn_force=attn_force)
+                pool_dtype=pool_dtype, attn_force=attn_force, **chunk_kw)
         self._dec_prog, self._dec_fetch = dec_prog, dec_tok.name
         self._pf_prog, self._pf_fetch = pf_prog, pf_tok.name
         # what a device trace calls the two executables:
@@ -517,7 +578,10 @@ class DecodeEngine:
         self._tok_ctr = _m_tokens().labels(engine=e)
         self._step_hist = _m_step_seconds().labels(engine=e)
         self._phase = {p: _m_phase_seconds().labels(engine=e, phase=p)
-                       for p in ("prefill", "decode")}
+                       for p in ("prefill", "decode", "encode")}
+        self._prompt_tokens = {
+            k: _m_prompt_tokens().labels(engine=e, kind=k)
+            for k in ("image", "text")}
         self._chunks = _m_prefill_chunks().labels(engine=e)
         self._occupancy = _m_slot_occupancy().labels(engine=e)
         self._pages_gauge = _m_pages_in_use().labels(engine=e)
@@ -526,7 +590,7 @@ class DecodeEngine:
         self._turn_ctr = _m_turns().labels(engine=e)
         self._turn_part = {
             p: _m_turn_seconds().labels(engine=e, part=p)
-            for p in ("sched", "prefill_run", "decode_run")}
+            for p in ("sched", "prefill_run", "decode_run", "encode_run")}
         self._queue_wait = _m_queue_wait().labels(engine=e)
         self._ttft = _m_ttft().labels(engine=e)
         self._token_gap = _m_token_gap().labels(engine=e)
@@ -561,14 +625,52 @@ class DecodeEngine:
     # -- public API ---------------------------------------------------------
 
     def submit(self, prompt, max_new_tokens, eos_id=None,
-               tenant="default"):
+               tenant="default", images=None):
         """Enqueue one greedy generation; returns a Future resolving to
         the generated token ids (list[int])."""
         return self.submit_request(prompt, max_new_tokens, eos_id=eos_id,
-                                   tenant=tenant).future
+                                   tenant=tenant, images=images).future
+
+    def _place_images(self, prompt, images):
+        """The request's images as their encoder takes them, and where
+        each one's rows stand: the prompt's placeholder ids, in order,
+        one unbroken run an image, checked against the images' row
+        counts here, at admission."""
+        enc = self._enc
+        if enc is None:
+            if images:
+                raise ValueError(
+                    f"decode engine {self.name!r}: the model's decode lane "
+                    f"declares no image encoder, the request carries "
+                    f"{len(images)} image(s)")
+            return [], []
+        images = [im if isinstance(im, _lane.PreparedImage)
+                  else enc.prepare(im) for im in images or ()]
+        held = np.flatnonzero(np.asarray(prompt) == enc.placeholder_id)
+        rows = sum(im.rows for im in images)
+        if len(held) != rows:
+            raise ValueError(
+                f"decode: the prompt holds {len(held)} placeholder ids "
+                f"({enc.placeholder_id}), its {len(images)} image(s) give "
+                f"{rows} rows")
+        spans, at = [], 0
+        for k, im in enumerate(images):
+            if im.rows > self._max_image_rows:
+                raise ValueError(
+                    f"decode: image {k} of shape {tuple(im.shape)} has "
+                    f"{im.rows} rows, the largest declared shape "
+                    f"{self._max_image_rows}")
+            run = held[at:at + im.rows]
+            if int(run[-1]) - int(run[0]) != im.rows - 1:
+                raise ValueError(
+                    f"decode: image {k}'s {im.rows} placeholder ids are "
+                    f"not one unbroken run of the prompt")
+            spans.append((int(run[0]), im.rows))
+            at += im.rows
+        return images, spans
 
     def submit_request(self, prompt, max_new_tokens, eos_id=None,
-                       tenant="default", prefix=None):
+                       tenant="default", prefix=None, images=None):
         """`submit` returning the `DecodeRequest` itself (the router's
         surface: it needs the request's live `generated` progress to
         fail a victim over, not just the future).
@@ -581,7 +683,12 @@ class DecodeEngine:
         uninterrupted one (docs/SERVING.md "Resilience").
         ``max_new_tokens`` stays the ORIGINAL budget: the prefix counts
         toward it, so a resumed request finishes at the same total
-        length."""
+        length.
+
+        ``images`` (a lane with an image encoder): the request's images,
+        in the order their rows stand in the prompt, each as the lane's
+        ``ImageEncoder.prepare`` takes it or already prepared
+        (``lane.PreparedImage``)."""
         self._check_metrics_epoch()
         prompt = list(prompt)
         if not prompt:
@@ -601,7 +708,9 @@ class DecodeEngine:
         # quota scan and the metric label must see ONE spelling — an
         # int-tenant caller must not bypass its own quota
         tenant = str(tenant)
-        req = DecodeRequest(prompt, max_new_tokens, eos_id, tenant)
+        images, spans = self._place_images(prompt, images)
+        req = DecodeRequest(prompt, max_new_tokens, eos_id, tenant, images,
+                            spans)
         if prefix:
             prefix = [int(t) for t in prefix]
             if len(prefix) > int(max_new_tokens):
@@ -662,6 +771,9 @@ class DecodeEngine:
             self._depth.set(len(self._queue))
             self._cv.notify_all()
         self._requests_family.labels(model=self.name, tenant=tenant).inc()
+        image_rows = sum(n for _, n in spans)
+        self._prompt_tokens["image"].inc(image_rows)
+        self._prompt_tokens["text"].inc(len(prompt) - image_rows)
         return req
 
     def _serve_span(self, req, tenant):
@@ -694,11 +806,13 @@ class DecodeEngine:
         return ServingOverloadError(msg, reason=reason)
 
     def generate(self, prompts, max_new_tokens, eos_id=None,
-                 timeout=None):
+                 timeout=None, images=None):
         """Blocking convenience: submit every prompt, wait for all.
-        Returns list[list[int]] of generated ids."""
-        futs = [self.submit(p, max_new_tokens, eos_id=eos_id)
-                for p in prompts]
+        Returns list[list[int]] of generated ids.  ``images``: per
+        prompt, its images (or None)."""
+        images = images if images is not None else [None] * len(prompts)
+        futs = [self.submit(p, max_new_tokens, eos_id=eos_id, images=im)
+                for p, im in zip(prompts, images)]
         return [f.result(timeout=timeout) for f in futs]
 
     def _warm_prefill_args(self):
@@ -708,20 +822,33 @@ class DecodeEngine:
             write_pages={k: trash for k in self.pool.kinds}, valid=1)
 
     def warmup(self):
-        """Compile (or AOT-load) both executables outside the request
-        path: one all-inactive decode step + one trash-page prefill
-        chunk.  Writes land only on the pool's trash page.  Returns the
-        number of executables warmed (2)."""
+        """Compile (or AOT-load) every executable outside the request
+        path: one all-inactive decode step, one trash-page prefill
+        chunk and, where the lane declares an image encoder, one run of
+        every declared image shape over zeros.  Writes land only on the
+        pool's trash page and in the row staging, which no request owns
+        yet.  Returns the number of executables warmed (2, and one a
+        declared image shape)."""
         self._run_prefill_feed(**self._warm_prefill_args(), warm=True)
         self._run_decode_feed([], warm=True)
+        shapes = self._enc.shapes if self._enc is not None else ()
+        for shape in shapes:
+            self._run_encoder_feed(self._warm_image(shape), 0, warm=True)
         # the warm chunk's one trash-page token is no traffic
         self._install_device_counters()
-        return 2
+        return 2 + len(shapes)
 
     def lower(self, sharding=None):
-        """AOT-lower the two executables :meth:`warmup` compiles, without
-        running them: ``[prefill_chunk, decode_step]`` (Executor.lower —
-        ``sharding`` over a topology device compiles them chip-free)."""
+        """AOT-lower the executables :meth:`warmup` compiles, without
+        running them: ``[prefill_chunk, decode_step]`` and then one
+        encoder a declared image shape (Executor.lower — ``sharding``
+        over a topology device compiles them chip-free)."""
+        encoders = []
+        for shape in (self._enc.shapes if self._enc is not None else ()):
+            prog, _ = self._encoder_for(shape)
+            encoders.append(self._exe.lower(
+                prog, self._encoder_feed(self._warm_image(shape), 0), [],
+                scope=self.scope, sharding=sharding))
         return [
             self._exe.lower(self._pf_prog,
                             self._prefill_feed(**self._warm_prefill_args()),
@@ -730,7 +857,7 @@ class DecodeEngine:
             self._exe.lower(self._dec_prog, self._decode_feed([]),
                             [self._dec_fetch], scope=self.scope,
                             sharding=sharding),
-        ]
+        ] + encoders
 
     def drain(self, timeout=None):
         """Graceful drain (the decode lane's half of the
@@ -839,6 +966,7 @@ class DecodeEngine:
                 self.pool.free_seq(req.seq_id)
                 req.seq_id = None
                 req.prefilled = 0
+                self._drop_staging_of(req)
             if req.future.set_running_or_notify_cancel():
                 req.future.set_exception(self._reject(
                     "draining",
@@ -929,6 +1057,7 @@ class DecodeEngine:
             self.pool.free_seq(req.seq_id, why="evict")
             req.seq_id = None
             req.prefilled = 0
+            self._drop_staging_of(req)  # the replay encodes again
             for i, s in enumerate(self._slots):
                 if s is req:
                     self._slots[i] = None
@@ -987,9 +1116,15 @@ class DecodeEngine:
                     if lp < len(table) and lp * pgs < ctx_len + valid:
                         pages[j] = table[lp]
                 write_pages[kind] = pages
+        row_idx = None
+        if req.image_spans:
+            row_idx = self._stage_image_rows(req, ctx_len, valid)
+            if row_idx is None:
+                return  # the chunk needs one more image: the next turn's
         next_tok = self._run_prefill_feed(
             tokens=tokens[ctx_len:ctx_len + valid], pos0=ctx_len,
-            seq_id=req.seq_id, write_pages=write_pages, valid=valid)
+            seq_id=req.seq_id, write_pages=write_pages, valid=valid,
+            row_idx=row_idx)
         req.prefilled = ctx_len + valid
         self._release_below_window("prefill.pages", [(req, req.prefilled)])
         with _profiling.span("emit", "decode"):
@@ -1032,10 +1167,144 @@ class DecodeEngine:
             for req, length in moved:
                 self.pool.release(req.seq_id, length)
 
-    def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid):
+    # -- image rows -----------------------------------------------------------
+
+    def _install_row_staging(self):
+        """The row-staging var beside the pool: zeros on the device (kept
+        if already there in this shape)."""
+        import jax.numpy as jnp
+
+        shape = (self._staging_rows, 1, self._enc.row_width)
+        cur = self.scope.get(_lane.ROW_STAGING)
+        if cur is None or tuple(np.shape(cur)) != shape:
+            self.scope.set(_lane.ROW_STAGING, jnp.zeros(shape, jnp.float32))
+
+    def _reset_staging(self):
+        """Nothing staged: whose rows the staging holds (one request's,
+        the one whose prompt is being prefilled), where each staged
+        image's first row lies, and where the next image goes."""
+        self._staged_for = None
+        self._staged = {}        # image number -> place of its first row
+        self._staging_head = 0
+
+    def _drop_staging_of(self, req):
+        if self._staged_for is req:
+            self._reset_staging()
+
+    def _encoder_for(self, shape):
+        """The shape's encoder program, built (and its prepare program
+        run, once) the first time the shape is asked for."""
+        from paddle_tpu import fluid
+
+        shape = tuple(shape)
+        if shape not in self._encoders:
+            prog, start = fluid.Program(), fluid.Program()
+            with fluid.program_guard(prog, start), \
+                    fluid.unique_name.guard():
+                feeds, prepare = self._enc.build(
+                    *shape, self._staging_rows,
+                    attn_force=self._attn_force)
+            # what a device trace calls it: jit_vision_encoder
+            prog.name = "vision_encoder"
+            if prepare is not None:
+                with self._exec_lock:
+                    self._exe.run(prepare, feed={}, fetch_list=[],
+                                  scope=self.scope)
+            self._encoders[shape] = (prog, feeds)
+        return self._encoders[shape]
+
+    def _warm_image(self, shape):
+        """An image of ``shape`` that is all zeros, as its encoder takes
+        it (the shapes of one prepared image of the shape's first run)."""
+        prog, feeds = self._encoder_for(shape)
+        block = prog.global_block()
+        return _lane.PreparedImage(
+            tuple(shape),
+            {n: np.zeros(tuple(block.var(n).shape), block.var(n).dtype)
+             for n in feeds if n != self._enc.places_feed},
+            self._enc.rows_of(shape))
+
+    def _encoder_feed(self, image, place):
+        feed = dict(image.feeds)
+        feed[self._enc.places_feed] = (
+            (place + np.arange(image.rows)) % self._staging_rows
+        ).astype(np.int32)
+        return feed
+
+    def _run_encoder_feed(self, image, place, warm=False):
+        """One image through its shape's encoder, its rows written into
+        the staging from ``place`` on (around its end).  Dispatched, not
+        waited for: the chunk that reads the rows is ordered behind it
+        on the device."""
+        prog, _ = self._encoder_for(image.shape)
+        with _profiling.span("encode.feed_build", "decode"):
+            feed = self._encoder_feed(image, place)
+        with self._exec_lock:
+            with _profiling.span("encode.run", "decode") as run:
+                self._exe.run(prog, feed=feed, fetch_list=[],
+                              scope=self.scope)
+        if not warm:
+            self._phase["encode"].inc(run.seconds)
+            self._turn_part["encode_run"].inc(run.seconds)
+            self._turn_run_s += run.seconds
+            _m_encoder_runs().labels(
+                engine=self.name,
+                shape="x".join(map(str, image.shape))).inc()
+
+    def _stage_image_rows(self, req, ctx_len, valid):
+        """The chunk's row index [1, C] (the staged row that stands at
+        each position, -1 at a token's), after running the encoder for
+        the first image the chunk needs and the staging lacks: one image
+        a turn.  None where the chunk needs yet another: no chunk this
+        turn.
+
+        The staging is a ring that one request's images enter whole, in
+        prompt order, each where the last one ended; a row is dead once
+        the chunk that held its position has run.  When an image is
+        encoded, every live row before it lies inside the chunk under
+        way, so the largest image and one chunk of rows always
+        suffice."""
+        if self._staged_for is not req:
+            self._reset_staging()
+            self._staged_for = req
+        end = ctx_len + valid
+        spans, size = req.image_spans, self._staging_rows
+        for k in [k for k in self._staged
+                  if spans[k][0] + spans[k][1] <= ctx_len]:
+            del self._staged[k]  # its last chunk has run
+        needed = [k for k, (s, n) in enumerate(spans)
+                  if s < end and s + n > ctx_len]
+        missing = [k for k in needed if k not in self._staged]
+        if missing:
+            k = missing[0]
+            live = sum(n - min(max(ctx_len - s, 0), n)
+                       for s, n in (spans[j] for j in self._staged))
+            rows = spans[k][1]
+            if live + rows > size:
+                raise RuntimeError(
+                    f"decode engine {self.name!r}: {live} live staged rows "
+                    f"and an image of {rows} do not fit the row staging "
+                    f"of {size}")
+            self._staged_live_max = max(self._staged_live_max, live + rows)
+            self._run_encoder_feed(req.images[k], self._staging_head)
+            self._staged[k] = self._staging_head
+            self._staging_head = (self._staging_head + rows) % size
+            if len(missing) > 1:
+                return None
+        idx = np.full((1, self.prefill_chunk), -1, np.int32)
+        for k in needed:
+            s, n = spans[k]
+            lo, hi = max(s, ctx_len), min(s + n, end)
+            idx[0, lo - ctx_len:hi - ctx_len] = (
+                self._staged[k] + np.arange(lo - s, hi - s)) % size
+        return idx
+
+    def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid,
+                      row_idx=None):
         """One chunk's feed: a page table and the chunk's write pages a
         cache kind (``lane.kind_feed``: the ``full`` kind under the plain
-        names)."""
+        names); for a lane with an image encoder also the staged row a
+        position (-1 throughout for a chunk of tokens)."""
         c = self.prefill_chunk
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
@@ -1049,13 +1318,17 @@ class DecodeEngine:
                 write_pages[kind].astype(np.int32)
         feed["pf_qstart"] = np.asarray([pos0], np.int32)
         feed["pf_last_idx"] = np.asarray([max(valid - 1, 0)], np.int64)
+        if self._enc is not None:
+            feed[self._enc.index_feed] = (
+                np.full((1, c), -1, np.int32) if row_idx is None
+                else row_idx)
         return feed
 
     def _run_prefill_feed(self, tokens, pos0, seq_id, write_pages,
-                          valid, warm=False):
+                          valid, warm=False, row_idx=None):
         with _profiling.span("prefill.feed_build", "decode"):
             feed = self._prefill_feed(tokens, pos0, seq_id, write_pages,
-                                      valid)
+                                      valid, row_idx)
         with self._exec_lock:
             with _profiling.span("prefill.run", "decode") as run:
                 (out,) = self._exe.run(self._pf_prog, feed=feed,
@@ -1282,4 +1555,11 @@ class DecodeEngine:
             "tokens": self._tokens,
             "evictions": self._evictions,
             "kv_pool": self.pool.stats(),
+            # a lane with an image encoder: the row staging's size and
+            # the most live rows it ever held (else None)
+            "image_rows": None if self._enc is None else {
+                "staging_rows": self._staging_rows,
+                "largest_image_rows": self._max_image_rows,
+                "live_max": self._staged_live_max,
+                "shapes_built": sorted(self._encoders)},
         }

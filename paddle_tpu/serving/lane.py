@@ -19,7 +19,20 @@ A model declares
 - the **two program builders** of the lane's two fixed-shape
   executables, a decode step over the pool's slots and a prefill chunk
   of one sequence, both against the model's own parameter names and the
-  engine's feed names (``dec_*`` / ``pf_*``, models/gpt.py).
+  engine's feed names (``dec_*`` / ``pf_*``, models/gpt.py);
+- optionally an **image encoder** (``ImageEncoder``): a third builder,
+  one executable an image shape, whose output rows stand at the prompt
+  positions that hold the model's placeholder id.  A request may then
+  carry images; the scheduler runs the encoder inside its turn loop, one
+  image at most a turn, just ahead of the first prefill chunk that needs
+  the image's rows.  The rows never leave the device: an encoder run
+  writes them into the engine's **row staging** var (``ROW_STAGING``, one
+  for the engine, as large as the largest declared image and one chunk),
+  the chunk reads them from there by an index a position (the lane's one
+  feed more), and a place is written over once the chunk that held its
+  position has run.  An eviction replays from token 0 and encodes again.
+  A lane that declares no encoder builds, feeds and compiles exactly
+  what it did before lanes could.
 
 The model's config class returns the declaration from ``decode_lane()``;
 the engine asks for nothing else, so ``serving/decode.py`` imports no
@@ -31,6 +44,8 @@ from __future__ import annotations
 import collections
 
 __all__ = ["CacheRow", "DecodeLane", "DeviceCounter", "POOL_PREFIX",
+           "ImageEncoder", "PreparedImage", "ROW_STAGING",
+           "declare_row_staging",
            "FULL", "kind_name", "kind_feed", "kinds_of",
            "window_pages_per_seq",
            "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars"]
@@ -42,6 +57,15 @@ CacheRow = collections.namedtuple("CacheRow", ("name", "width", "dtype"))
 # an int32 vector of ``length`` counts that the lane's programs add to in
 # place, under the persistable var ``name``
 DeviceCounter = collections.namedtuple("DeviceCounter", ("name", "length"))
+
+
+# one image as its encoder takes it: ``shape`` names the executable (a
+# patch grid), ``feeds`` are the encoder's input arrays, ``rows`` the
+# prompt positions its output fills
+PreparedImage = collections.namedtuple("PreparedImage",
+                                       ("shape", "feeds", "rows"))
+# the engine's staged image rows, float32 [rows, 1, row_width]
+ROW_STAGING = "@IMGROWS@"
 
 
 FULL = "full"
@@ -132,6 +156,59 @@ def declare_pool_vars(rows, num_layers, num_pages, page_size,
             pool_var_names(rows, num_layers, prefix))]
 
 
+def declare_row_staging(rows, width, name=ROW_STAGING):
+    """The engine's staged image rows in the program being built:
+    persistable float32 ``[rows, 1, width]``, a page of one row each, so
+    that an encoder writes it with ``kv_cache_write`` (in place, as the
+    pool is written) and a chunk reads it with
+    ``select_embedding_rows``."""
+    from paddle_tpu import fluid
+
+    return fluid.default_main_program().global_block().create_var(
+        name=name, shape=[int(rows), 1, int(width)], dtype="float32",
+        persistable=True)
+
+
+class ImageEncoder:
+    """A lane's image encoder (a vision tower and its projector).
+
+    ``build(grid_h, grid_w, staging_rows, attn_force=)`` builds ONE
+    image shape's program into the default main program and returns
+    ``(feed names, prepare program or None)``: the program writes the
+    image's ``rows_of(shape)`` output rows, ``row_width`` wide, into the
+    row staging var (``declare_row_staging(staging_rows, row_width)``)
+    at the places its ``places_feed`` [rows] int32 names; nothing of it
+    is fetched.  The prepare program, where there is one, is run once
+    when the engine builds the shape (a position table resized to the
+    shape, say).
+    ``prepare(image)`` -> ``PreparedImage``: a caller's image as the
+    encoder's input feeds (host work, done as the request is submitted).
+    ``shapes``: the shapes ``DecodeEngine.warmup`` compiles; another
+    shape, of no more rows than the largest of these, compiles when its
+    first image arrives.
+    ``placeholder_id``: the prompt token whose positions image rows
+    fill, in order.  ``index_feed``: the prefill chunk's feed [1, C]
+    int32 that names, a position, the staged row standing there, or -1
+    for a token (the chunk builder is handed ``image_rows=`` the staging
+    var's rows and declares it)."""
+
+    def __init__(self, *, build, prepare, shapes, rows_of, row_width,
+                 placeholder_id, index_feed="pf_row_idx",
+                 places_feed="enc_row_idx"):
+        self.build = build
+        self.prepare = prepare
+        self.shapes = [tuple(s) for s in shapes]
+        self.rows_of = rows_of
+        self.row_width = int(row_width)
+        self.placeholder_id = int(placeholder_id)
+        self.index_feed = index_feed
+        self.places_feed = places_feed
+        if not self.shapes:
+            raise ValueError("ImageEncoder: declare the image shapes to "
+                             "compile at warm-up (the largest sizes the "
+                             "row staging)")
+
+
 class DecodeLane:
     """A model's decode-lane declaration.
 
@@ -159,13 +236,15 @@ class DecodeLane:
     reads them only when ``DecodeEngine.book_device_counters()`` is
     called (a trace reader, a test), handing what each gained to ``book_counters(engine_name, {name: gained})``,
     the model's own mapping onto metric families.  What they count is
-    the model's business: the engine knows their names and lengths."""
+    the model's business: the engine knows their names and lengths.
+    ``encoder``: an ``ImageEncoder``, or None (no request carries images
+    and nothing is built, fed or compiled for them)."""
 
     def __init__(self, *, num_layers, max_position, cache_rows,
                  build_decode_step, build_prefill_chunk,
                  pool_dtype="float32", prefill_chunk=None,
                  device_counters=(), book_counters=None,
-                 layer_windows=None):
+                 layer_windows=None, encoder=None):
         self.num_layers = int(num_layers)
         self.max_position = int(max_position)
         self.cache_rows = cache_rows
@@ -175,6 +254,7 @@ class DecodeLane:
         self.prefill_chunk = prefill_chunk
         self.device_counters = list(device_counters)
         self.book_counters = book_counters
+        self.encoder = encoder
         self.layer_windows = (None if layer_windows is None
                               else list(layer_windows))
         if (self.layer_windows is not None

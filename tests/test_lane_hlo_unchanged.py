@@ -11,7 +11,9 @@ The digests below are of `lowered.as_text()` at commit 67a584c (PR 30's
 tree), made by this file's `digests()` there, but for `glm.pallas.*`,
 which PR 32 moved by design: their text holds the interpreted grouped
 product, whose grid now ends at the live visits (a traced extent); the
-ten others are the proof that nothing else moved.  After a deliberate change
+ten others are the proof that nothing else moved.  The `trinity.*` four
+are of commit 3ff1d2d (PR 32's tree), made before PR 33 gave a lane its
+optional encoder: a lane that declares none builds what it built.  After a deliberate change
 to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
@@ -24,7 +26,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu import fluid, serving
-from paddle_tpu.models import glm, gpt
+from paddle_tpu.models import glm, gpt, trinity
 
 GOLDEN = {
     "gpt.float32.None.prefill": "e4de139bba244c34c30e3378cea1230b90f392d45e9ccf67a0d043c67755d999",
@@ -38,8 +40,15 @@ GOLDEN = {
     "gpt.int8.pallas.prefill": "6c60c7216eb5c3bdf1ddf181932dce89b726895a1a580d4007cfcf4796f59142",
     "gpt.int8.pallas.decode": "5b1236c43d90c101518a07f823c0b878bcd2344b3f6cc813df068e8a3a84ae6c",
     "glm.pallas.prefill": "82d1a4b860f5534399f27243c4d074eda21b97c8e0631a0d11a05962706af923",
-    "glm.pallas.decode": "865787bd0491c7c45bd9fe403f0537cf4e60e57544253b2d09018178b6a58c15"
+    "glm.pallas.decode": "865787bd0491c7c45bd9fe403f0537cf4e60e57544253b2d09018178b6a58c15",
+    "trinity.None.prefill": "6a0acaf7ee2c08877896d839f89c39e243d3bc10d4e001c30b769978814375b6",
+    "trinity.None.decode": "ce29f09c2160a3c759dab784c8df9ca47db24d0ccc773a70bb2dfbefbf9175fb",
+    "trinity.pallas.prefill": "cbb052cc583019c889beb82edc4bed3f5e94c17bf1a7dde125e6adcec01b1510",
+    "trinity.pallas.decode": "901a6a9d530abca4c35470742261f788f866b9a119c3b2f2da2f720e07e03861"
 }
+
+
+MODELS = ("gpt", "glm", "trinity")
 
 
 def _zero_scope(build):
@@ -73,6 +82,12 @@ def texts(model, force):
         low = _lowered(cfg, _zero_scope(lambda: glm.build_glm_lm(cfg)),
                        force, prefill_chunk=8)
         return {f"glm.{force}.{which}": t for which, t in low.items()}
+    if model == "trinity":
+        cfg = trinity.TrinityConfig.tiny()
+        low = _lowered(
+            cfg, _zero_scope(lambda: trinity.build_trinity_lm(cfg)), force,
+            prefill_chunk=8)
+        return {f"trinity.{force}.{which}": t for which, t in low.items()}
     cfg = gpt.GPTConfig.tiny()
     out = {}
     for pool_dtype in ("float32", "int8"):
@@ -86,12 +101,12 @@ def texts(model, force):
 
 def digests():
     return {case: hashlib.sha256(text.encode()).hexdigest()
-            for model in ("gpt", "glm") for force in ("None", "pallas")
+            for model in MODELS for force in ("None", "pallas")
             for case, text in texts(model, force).items()}
 
 
 @pytest.mark.parametrize("force", ["None", "pallas"])
-@pytest.mark.parametrize("model", ["gpt", "glm"])
+@pytest.mark.parametrize("model", MODELS)
 def test_one_kind_lanes_lower_the_hlo_they_lowered(model, force):
     got = {case: hashlib.sha256(text.encode()).hexdigest()
            for case, text in texts(model, force).items()}

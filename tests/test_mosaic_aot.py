@@ -543,3 +543,118 @@ def harness_json(root, metric):
     with open(os.path.join(root, "benchmark", "layer_metrics",
                            metric + ".json")) as f:
         return json.load(f)
+
+
+# ---------------------------------------------------------------------------
+# Kimi-VL through the decode lane (benchmark/configs/kimi-vl-a3b-ep1.json):
+# dense latent attention in its two forms and the tower's bidirectional
+# attention at the published widths, and the engine's executables: the
+# decode step, the prefill chunk, one encoder an image shape
+# ---------------------------------------------------------------------------
+
+_KIMI_PAGES, _KIMI_PAGE = 16 * 262 + 1, 128
+
+
+def test_mla_kernels_at_kimi_widths(chip):
+    bf = jnp.bfloat16
+    pool = ((_KIMI_PAGES, _KIMI_PAGE, 640), bf)
+    hlo = _compile(
+        lambda ql, qr, pages, pt, qs: prims.paged_mla_attention(
+            ql, qr, pages, pt, qs, sm_scale=192 ** -0.5),
+        chip, ((16, 1, 16, 512), jnp.float32), ((16, 1, 16, 64), jnp.float32),
+        pool, ((16, 262), jnp.int32), ((16,), jnp.int32))
+    assert _mosaic_calls(hlo) == 1 and "%paged_mla_attention" in hlo
+    hlo = _compile(
+        lambda qn, qr, pages, pt, qs, uk, uv: prims.mla_chunk_attention(
+            qn, qr, pages, pt, qs, uk, uv, sm_scale=192 ** -0.5),
+        chip, ((1, 512, 16, 128), jnp.float32),
+        ((1, 512, 16, 64), jnp.float32), pool, ((1, 262), jnp.int32),
+        ((1,), jnp.int32), ((16, 128, 512), bf), ((16, 512, 128), bf))
+    assert _mosaic_calls(hlo) == 1 and "%mla_chunk_attention" in hlo
+    assert _pool_copies(hlo, _KIMI_PAGES, _KIMI_PAGE) == []
+
+
+@pytest.mark.parametrize("patches", [1024, 4096, 6144])
+def test_vit_attention_at_the_tower_shapes(chip, patches):
+    qkv = ((16, patches, 128), jnp.bfloat16)
+    hlo = _compile(lambda q, k, v: prims.vit_attention(
+        q, k, v, sm_scale=72 ** -0.5), chip, qkv, qkv, qkv)
+    assert _mosaic_calls(hlo) == 1 and "%vit_attention" in hlo
+
+
+def test_kimi_vl_decode_engine_executables(chip):
+    """The prefill chunk, the decode step and the three encoders of
+    Kimi-VL at the benchmark's widths, pool, slots and image shapes (two
+    of its six decoder layers, the dense one and an expert one, and two
+    of the tower's six blocks): a layer one latent-attention Mosaic call
+    (latent space in the step, head space in the chunk), three grouped
+    products an expert layer, one attention call a tower block; the
+    latent pool and the row staging go through every executable that
+    writes them UNCOPIED and donated."""
+    import json
+    import os
+
+    import ml_dtypes
+
+    from paddle_tpu.models import kimi_vl
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-vl-a3b-ep1.json")) as f:
+        config = json.load(f)
+    args = dict(config["builder"]["config_args"], num_hidden_layers=2,
+                vt_num_hidden_layers=2)
+    cfg = kimi_vl.KimiVLConfig(**args)
+    programs = []
+    for build in (lambda: kimi_vl.build_kimi_vl_lm(cfg),
+                  lambda: programs.append(
+                      kimi_vl.build_kimi_vl_vision_encoder(cfg, 4, 4, 8)[1])):
+        prog, start = fluid.Program(), fluid.Program()
+        with fluid.program_guard(prog, start), fluid.unique_name.guard():
+            build()
+        programs.append(prog)
+    scope = fluid.Scope()
+    for prog in programs:
+        for p in prog.global_block().all_parameters():
+            dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                     else np.dtype(p.dtype))
+            # shapes are all a lowering reads
+            scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                              tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-kimi",
+        auto_start=False)
+    assert engine.prefill_chunk == 512
+    assert engine.pool.num_pages == _KIMI_PAGES
+    assert engine.stats()["image_rows"]["staging_rows"] == 1536 + 512
+    staging = "2048,1,2048"
+    try:
+        with lowering_for("tpu"):
+            chunk, step, *encoders = [
+                low.compile().as_text()
+                for low in engine.lower(sharding=chip)]
+        assert len(encoders) == 3
+        assert chunk.count("%mla_chunk_attention") >= 2
+        assert "%paged_mla_attention" not in chunk
+        assert step.count("%paged_mla_attention") >= 2
+        assert "%mla_chunk_attention" not in step
+        for hlo in (chunk, step):
+            assert _mosaic_calls(hlo) == 2 + 3
+            assert _pool_copies(hlo, _KIMI_PAGES, _KIMI_PAGE) == []
+            params = _pool_parameters(
+                hlo, f"{_KIMI_PAGES},{_KIMI_PAGE},640")
+            assert len(params) == 2                # one a layer
+            assert [lay for _, lay in params
+                    if not lay.startswith("{2,1,0")] == []
+            assert {num for num, _ in params} <= _aliased_parameters(hlo)
+        # the chunk reads the staged rows, an encoder writes them in place
+        assert len(_pool_parameters(chunk, staging)) == 1
+        assert _pool_parameters(step, staging) == []
+        for hlo in encoders:
+            assert _mosaic_calls(hlo) == 2 and "%vit_attention" in hlo
+            (row,) = _pool_parameters(hlo, staging)
+            assert row[0] in _aliased_parameters(hlo)
+    finally:
+        engine.close()

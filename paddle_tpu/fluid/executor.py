@@ -102,8 +102,11 @@ def _m_staged_arrays():
         "pt_exec_staged_arrays_total",
         "Arguments (scope reads and feeds) the single and chain lanes "
         "handed to their executables, added once a run: kind=any counts "
-        "all of them, kind=put those that needed a jax.device_put (a "
-        "resident, committed array needs none)", labels=("lane", "kind"))
+        "all of them, kind=put those that needed a jax.device_put, "
+        "kind=kept the scope reads served from the executor's kept "
+        "staging (the scope still holds the object staged last time), "
+        "kind=host the feeds handed to the call as host arrays",
+        labels=("lane", "kind"))
 
 
 # what every lane's run_steps chain (chain_step_body) is jitted as
@@ -793,31 +796,46 @@ class _FeedScopeView:
         self._scope.set(name, value)
 
 
+class _Kept(dict):
+    """An Executor's kept staging, shared by its executables (they run on
+    one place): ``{name: (weakref to the scope's object, what went to the
+    call in its stead or None where it went itself)}``.  A dict that can
+    be referenced weakly, for `_forget_view`."""
+
+    __slots__ = ("__weakref__",)
+
+
 def _forget_view(owner, name, ref):
     """Weakref callback: the scope's array died, its view goes with it."""
-    exe = owner()
-    if exe is not None and exe._views.get(name, (None,))[0] is ref:
-        del exe._views[name]
+    kept = owner()
+    if kept is not None and kept.get(name, (None,))[0] is ref:
+        del kept[name]
 
 
-def _stage_scope_reads(scope, names, device, keeper=None):
-    """`names` from `scope` as arguments committed to `device`, and how many
-    of them needed a ``jax.device_put``.  The scope is left as it is: other
-    lanes read it too, and their jits take an uncommitted array but refuse
-    one committed elsewhere.
+def _stage_scope_reads(scope, names, device, kept, views=False):
+    """`names` from `scope` as arguments committed to `device`; how many of
+    them needed a ``jax.device_put``, and how many `kept` served.  The
+    scope is left as it is: other lanes read it too, and their jits take an
+    uncommitted array but refuse one committed elsewhere.
 
-    A committed ``jax.Array`` whose only device is `device` (what a step
-    wrote back) is passed by identity.  An uncommitted one that lives there
-    (what a jitted initializer or ``jnp.zeros`` returns) is put once — the
-    put makes a committed view of the same buffer — and, where the caller
-    names a `keeper` (the executable: ``_views`` {name: (weakref to the
-    scope's array, its view)}), later runs take the view for as long as the
-    scope's array lives.  It cannot go as it is: committedness is part of a
-    jitted call's signature, so a step would compile again once its own
-    committed outputs come back as inputs.  Everything else is put on every
-    run: an array resident elsewhere (a copy nobody should keep), and host
-    values (numpy, scalars, what ``get_tensor().set`` stores), whose
-    in-place change must be seen.
+    `kept` says what each name's scope object was staged as last time, and
+    holds while ``scope.get(n)`` IS that object: identity, never the name
+    alone, so ``scope.set``, ``get_tensor().set``, another lane's write and
+    another scope under the same names are all seen.  An executable's run
+    notes its write-back there (`_write_back`), so a donated name takes the
+    committed output this program, or another of the same executor, left.
+
+    Otherwise: a committed ``jax.Array`` whose only device is `device` goes
+    by identity.  An uncommitted one that lives there (what a jitted
+    initializer or ``jnp.zeros`` returns) is put once: the put makes a
+    committed view of the same buffer, which `kept` holds, with `views`,
+    for as long as the scope's array lives (not for a donated name, whose
+    view dies in the call).  It cannot go as it is: committedness is part
+    of a jitted call's signature, so a step would compile again once its
+    own committed outputs come back as inputs.  Everything else is put on
+    every run and never kept: an array resident elsewhere (a copy nobody
+    should keep), and host values (numpy, scalars, what
+    ``get_tensor().set`` stores), whose in-place change must be seen.
 
     Fails with the variable's NAME on a miss — a cached plan may classify
     a var as a scope read against a scope that held it; None reaching
@@ -825,56 +843,100 @@ def _stage_scope_reads(scope, names, device, keeper=None):
     import jax
 
     target = {device}
-    views = None if keeper is None else keeper._views
-    staged, n_put = {}, 0
+    # Scope.get is its own dict's get (local only); a bound one saves a
+    # Python call a name
+    get, hit_of = scope._vars.get, kept.get
+    staged, n_put, n_kept = {}, 0, 0
     for n in names:
-        v = scope.get(n)
+        v = get(n)
         if v is None:
             raise ValueError(
                 f"variable {n!r} is read by this program but absent "
                 "from the current scope")
-        keep = False
-        if isinstance(v, jax.Array):
-            if v.committed:
-                if v.sharding.device_set == target:
-                    staged[n] = v
-                    continue
-            elif views is not None:
-                hit = views.get(n)
-                if hit is not None and hit[0]() is v:
-                    staged[n] = hit[1]
-                    continue
-                keep = v.sharding.device_set == target
+        hit = hit_of(n)
+        if hit is not None and hit[0]() is v:
+            staged[n] = v if hit[1] is None else hit[1]
+            n_kept += 1
+            continue
+        resident = (isinstance(v, jax.Array)
+                    and v.sharding.device_set == target)
+        if resident and v.committed:
+            # no callback: a dead reference serves nothing, and the
+            # name's next staging replaces it
+            kept[n] = (weakref.ref(v), None)
+            staged[n] = v
+            continue
         staged[n] = jax.device_put(v, device)
         n_put += 1
-        if keep:
+        if resident and views:
             # weak both ways: the view neither outlives the array it views
-            # nor ties the executable into a cycle
-            views[n] = (weakref.ref(v, functools.partial(
-                _forget_view, weakref.ref(keeper), n)), staged[n])
-    return staged, n_put
+            # nor ties the table into a cycle
+            kept[n] = (weakref.ref(v, functools.partial(
+                _forget_view, weakref.ref(kept), n)), staged[n])
+    return staged, n_put, n_kept
+
+
+def _stage_feeds(feeds, device, pinned):
+    """`feeds` as they go to the call, and how many were put / went as host
+    arrays.  Where the call is `pinned` (it has an argument committed to
+    `device`, which fixes where it runs and where its uncommitted
+    arguments go), a host feed rides the call: the transfer is the call's
+    own, and a ``jax.device_put`` beforehand buys nothing.  Where nothing
+    pins, the feeds are put and pin it themselves (a place that is not the
+    default device).  A ``jax.Array`` already committed there goes by
+    identity (the dataset prefetcher's); any other is put, so that a
+    program keeps one signature whatever array it is fed."""
+    import jax
+
+    target = {device}
+    vals, n_put, n_host = {}, 0, 0
+    for k, v in feeds.items():
+        if isinstance(v, jax.Array):
+            if not (v.committed and v.sharding.device_set == target):
+                v = jax.device_put(v, device)
+                n_put += 1
+        elif pinned:
+            n_host += 1
+        else:
+            v = jax.device_put(v, device)
+            n_put += 1
+        vals[k] = v
+    return vals, n_put, n_host
 
 
 def _stage_args(lane, exe, scope, feeds):
     """The (donated, readonly, feeds) arguments of one run of `exe`'s
-    jitted body, every one committed to its place's device (so each
-    program keeps ONE signature, and the feeds pin the computation to a
-    place that is not the default device), booked once a run into
-    ``pt_exec_staged_arrays_total{lane}``.  Only read-only arrays get a
-    kept view: a donated one is replaced in the scope by the step's own
-    committed output."""
-    import jax
-
+    jitted body, booked once a run into
+    ``pt_exec_staged_arrays_total{lane}``.  Every scope read arrives
+    committed to the place's device (so each program keeps ONE signature)
+    and so pins the call; see `_stage_scope_reads` and `_stage_feeds`."""
     device = exe.place.jax_device()
-    donated, n_d = _stage_scope_reads(scope, exe.donated_names, device)
-    readonly, n_r = _stage_scope_reads(scope, exe.readonly_names, device,
-                                       keeper=exe)
-    feed_vals = {k: jax.device_put(v, device) for k, v in feeds.items()}
+    donated, put_d, kept_d = _stage_scope_reads(
+        scope, exe.donated_names, device, exe._kept)
+    readonly, put_r, kept_r = _stage_scope_reads(
+        scope, exe.readonly_names, device, exe._kept, views=True)
+    feed_vals, put_f, n_host = _stage_feeds(
+        feeds, device, pinned=bool(donated or readonly))
     staged = _m_staged_arrays()
-    staged.labels(lane=lane, kind="put").inc(n_d + n_r + len(feed_vals))
-    staged.labels(lane=lane, kind="any").inc(
-        len(donated) + len(readonly) + len(feed_vals))
+    # every kind on every run, a 0 too: a reader of put / any finds both
+    for kind, n in (("put", put_d + put_r + put_f),
+                    ("kept", kept_d + kept_r), ("host", n_host),
+                    ("any", len(donated) + len(readonly) + len(feed_vals))):
+        staged.labels(lane=lane, kind=kind).inc(n)
     return donated, readonly, feed_vals
+
+
+def _write_back(kept, scope, out_writes):
+    """A run's scope writes, noted in `kept` where they came back committed
+    (a jitted step's, whose committed arguments placed it on the place's
+    device): the next run that reads a name, this program's or another's,
+    takes the array as it is.  A start-up program's outputs and an
+    AOT-compiled step's come back uncommitted, and are staged as any
+    uncommitted array."""
+    for n, v in out_writes.items():
+        scope.set(n, v)
+        if v.committed:
+            kept[n] = (weakref.ref(v), None)
 
 
 class _JitExecutable:
@@ -969,7 +1031,8 @@ class _JitExecutable:
 class _CompiledBlock(_JitExecutable):
     """One (program-version, feed-signature) → jitted XLA executable."""
 
-    def __init__(self, program, block, feed_names, fetch_names, place, scope):
+    def __init__(self, program, block, feed_names, fetch_names, place, scope,
+                 kept):
         import jax
 
         plan = BlockPlan(program, block, feed_names, fetch_names, scope,
@@ -989,7 +1052,7 @@ class _CompiledBlock(_JitExecutable):
         self.place = place
         self.label = f"program@{id(program):x}/v{program._version}"
         self._prof_state = {"ran": False}
-        self._views = {}  # _stage_scope_reads: committed views kept
+        self._kept = kept  # the executor's, see _stage_scope_reads
         # AOT-loaded/compiled executable (fluid/aot_cache.py) — when
         # set, run() dispatches it instead of the lazy jit
         self._aot = None
@@ -1061,8 +1124,7 @@ class _CompiledBlock(_JitExecutable):
                 with ph.phase("device_wait"):
                     ph.wait((fetches, out_writes))
                 with ph.phase("fetch_sync"):
-                    for n, v in out_writes.items():
-                        scope.set(n, v)
+                    _write_back(self._kept, scope, out_writes)
                     # block on scope writes too — a run with an empty
                     # fetch_list (or a startup run) would otherwise
                     # record async-dispatch time only
@@ -1158,7 +1220,7 @@ class _CompiledChain(_JitExecutable):
     """
 
     def __init__(self, program, block, feed_names, fetch_names, place,
-                 scope, n_steps, stacked_feed):
+                 scope, n_steps, stacked_feed, kept):
         import jax
 
         plan = BlockPlan(program, block, feed_names, fetch_names, scope,
@@ -1190,7 +1252,7 @@ class _CompiledChain(_JitExecutable):
         self.label = (f"program@{id(program):x}/v{program._version}"
                       f"/chain{n}")
         self._prof_state = {"ran": False}
-        self._views = {}  # _stage_scope_reads: committed views kept
+        self._kept = kept  # the executor's, see _stage_scope_reads
 
     def run(self, scope, feeds, step):
         import jax
@@ -1213,8 +1275,7 @@ class _CompiledChain(_JitExecutable):
                 with ph.phase("device_wait"):
                     ph.wait((fetches, out_writes))
                 with ph.phase("fetch_sync"):
-                    for n, v in out_writes.items():
-                        scope.set(n, v)
+                    _write_back(self._kept, scope, out_writes)
                     timer.done(fetches, out_writes)
             with ph.phase("fetch_sync"):
                 # the host tail rides the trailing fetch_sync bracket
@@ -1248,6 +1309,7 @@ class Executor:
     def __init__(self, place=None):
         self.place = place if place is not None else framework._current_expected_place()
         self._cache: dict = {}
+        self._kept = _Kept()  # what its executables staged last
         self._step = 0
         self._sentinels: dict = {}  # id(program) -> HealthSentinel|None
         # opt-in /metricsz endpoint (FLAGS_metrics_port): every process
@@ -1311,11 +1373,12 @@ class Executor:
         if sent is not None:
             sent.ensure_state(scope)
         cb = _CompiledBlock(program, program.global_block(), feed.keys(),
-                            fetch_names, self.place, scope)
+                            fetch_names, self.place, scope, self._kept)
         return cb.lower(scope, feed, (sharding, sharding))
 
     def close(self):
         self._cache.clear()
+        self._kept.clear()
         self._sentinels.clear()
 
     def _graph_passes(self, program, fetch_names=()):
@@ -1490,7 +1553,8 @@ class Executor:
                 if sent is not None:
                     sent.ensure_state(scope)  # before BlockPlan scope checks
                 t0 = _time.perf_counter()  # observability: allow
-                cb = _CompiledBlock(program, block, feed.keys(), fetch_names, self.place, scope)
+                cb = _CompiledBlock(program, block, feed.keys(), fetch_names,
+                                    self.place, scope, self._kept)
                 self._cache[key] = cb
                 self._cache[(key, "pin")] = program  # hold program ref: id() stays unique
                 booked["program"] = cb.plan.name
@@ -1612,7 +1676,8 @@ class Executor:
                 t0 = _time.perf_counter()  # observability: allow
                 cc = _CompiledChain(program, program.global_block(),
                                     feed.keys(), fetch_names, self.place,
-                                    scope, int(n_steps), bool(stacked_feed))
+                                    scope, int(n_steps), bool(stacked_feed),
+                                    self._kept)
                 self._cache[key] = cc
                 self._cache[(key, "pin")] = program
                 booked["program"] = CHAIN_NAME

@@ -178,13 +178,17 @@ def _phases_enabled():
 
 _tls = threading.local()
 
-# The span ring.  32768 entries: one scheduler turn of the decode lane is
-# ~23 spans (9 of the scheduler, 7 of the executor under each of its two
-# runs) at ~5 turns a second (PERF.md §5, cell 2), so 60 s are ~7000
-# spans; the ring holds that 4.7 times over, and a training lane's ~60
-# spans a second for nine minutes.  An entry is the tuple
+# The span ring.  131072 entries: one scheduler turn of the decode lane is
+# ~24 spans (9 of the scheduler, 7 of the executor under each of its two
+# runs), and since PR 34 the benchmark's fastest serve cell makes ~70 turns
+# a second (PERF.md §5, cell 6): ~1700 spans a second, of which the ring
+# holds 78 s.  The benchmark's reader looks back from the end of a run over
+# the second half of its 40-s window (benchmark/readers/host_gap.py): the
+# 32768 entries of PR 25, sized when a turn took 130 ms, held 19.5 s of
+# that cell and lost the traced interval.  A training lane's ~60 spans a
+# second fit for half an hour.  An entry is the tuple
 # (name, lane, start_ns, end_ns, id, parent_id, number, note).
-SPAN_RING = 32768
+SPAN_RING = 131072
 _ring = collections.deque(maxlen=SPAN_RING)
 _span_ids = itertools.count(1)
 # one reading of both clocks, so a reader can move the ring's
@@ -197,8 +201,8 @@ _export = [False, False]
 
 def spans():
     """The recorded spans, oldest first (a ring of the last ``SPAN_RING``
-    = 32768: over four minutes of the decode lane's ~115 spans a second,
-    so 60 s of cell 2 fit four times over): tuples ``(name, lane,
+    = 131072: 78 s of the decode lane's fastest cell, ~1700 spans a
+    second): tuples ``(name, lane,
     start_ns, end_ns, id, parent_id, number, note)`` on the
     `time.perf_counter_ns` clock.  ``parent_id`` is 0 for a root; ``number`` is the turn or step
     number of the span's tree (a child inherits its parent's)."""

@@ -379,7 +379,12 @@ def test_rpc_span_propagates_to_server_journal(el_flags):
         srv.bump_version()
         cli.get_param("w")
         cli.send_grad("g", np.ones(2, np.float32))
-        spans = srv.drain_spans()
+        # the server journals a frame AFTER it replied to it: wait for
+        # the last frame's entry, then both are in
+        spans, deadline = srv.drain_spans(), time.monotonic() + 5
+        while len(spans) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+            spans += srv.drain_spans()
         cmds = [c for c, *_ in spans]
         assert "get_param" in cmds and "send_grad" in cmds
         pid_hex = f"{os.getpid():x}"
@@ -411,6 +416,9 @@ def test_serve_spans_reach_profiler_and_events(el_flags, tmp_path,
     try:
         srv.publish("w", np.ones(2, np.float32))
         srv.bump_version()
+        # the server journals a frame AFTER it replied to it: the second
+        # reply says the first frame's entry is written
+        cli.get_param("w")
         cli.get_param("w")
         _drain_server_spans(srv)
         trace = str(tmp_path / "trace.json")

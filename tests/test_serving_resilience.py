@@ -506,7 +506,9 @@ def test_promote_drift_gate_rolls_back_canary():
         report = promote(
             router, WeightSet({"w": np.ones(2, np.float32)}),
             probe_prompts=[[1]], probe_max_new_tokens=2,
-            gates=PromotionGates(max_drift=0.0))  # any flip rolls back
+            # any flip rolls back; a fake replica answers in microseconds,
+            # so the latency ratio is the sandbox's load, not the swap's
+            gates=PromotionGates(max_drift=0.0, max_latency_ratio=None))
         assert report["outcome"] == "rolled_back"
         assert report["rolled_back_on"] == "r0"
         assert "drift" in report["reasons"][0]

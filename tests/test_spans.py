@@ -187,6 +187,23 @@ def test_ring_stays_bounded():
         < 5e9  # the pair reads both clocks at one instant
 
 
+def test_ring_holds_the_half_window_a_reader_looks_back_over(engine):
+    """The benchmark's reader takes the ring at the END of a run and needs
+    the spans of an interval traced 20 s before the window closed
+    (benchmark/readers/host_gap.py).  A turn that runs a chunk and a step,
+    at the 70 turns a second of the benchmark's fastest serve cell since
+    PR 34, with as much again to spare for faster turns: PR 25's 32768
+    entries held 19.5 s and the cell lost three metrics."""
+    req = engine.submit_request([5, 6, 7], 5)
+    profiling.reset()
+    engine._step_once()
+    a_turn = len(profiling.spans())
+    assert a_turn >= 23  # 9 of the scheduler, 7 under each of two runs
+    assert profiling.SPAN_RING >= a_turn * 70 * 20 * 2
+    while not req.future.done():
+        engine._step_once()
+
+
 def test_a_span_costs_under_5_microseconds():
     def batch(n=2000):
         t0 = time.perf_counter_ns()

@@ -31,9 +31,17 @@ import numpy as np
 from . import framework, registry
 from .framework import Program, Variable
 
+from paddle_tpu.observability import profiling as _profiling
+
 logger = logging.getLogger(__name__)
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard", "as_numpy"]
+
+# every lane imports this module before it builds, compiles or runs
+# anything (and `framework` above has imported jax): from here on a
+# collection is counted and a compile stage is a span beneath whatever
+# span is open (also the caller's own jax, under no span)
+_profiling.install_runtime_hooks()
 
 
 # ---------------------------------------------------------------------------

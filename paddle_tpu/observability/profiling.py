@@ -39,6 +39,18 @@ pairs anywhere else):
                  (benchmark/readers/host_gap.py lays it on the device
                  trace's).
 
+- runtime hooks  what the Python runtime and jax do INSIDE a span, and
+                 report through hooks of their own
+                 (`install_runtime_hooks`, on like the spans are): XLA's
+                 trace, lower, backend-compile and cache-read stages
+                 (`jax.monitoring`) enter the same ring as finished
+                 children of the span open on the reporting thread
+                 (`child_span`: ``xla.<stage>``), with
+                 ``pt_xla_stage_seconds_total{stage,under}`` beside
+                 them; the interpreter's collections (`gc.callbacks`)
+                 are counted only, in
+                 ``pt_host_gc_seconds_total{generation}``.
+
 - MFU/roofline   `note_cost` (fed by `_JitExecutable.cost_analysis`) and
                  `note_collectives` (fed by compiled-HLO inspection)
                  join the measured device seconds with a per-platform
@@ -62,7 +74,9 @@ pairs anywhere else):
                  events.  `dump_flight_record()` writes a JSONL
                  postmortem; automatic dumps fire on a slow-step
                  z-score over the per-lane rolling EMA
-                 (FLAGS_profile_slow_step_zscore) and on health-sentinel
+                 (FLAGS_profile_slow_step_zscore; the slow step's record
+                 names under ``beneath`` every compile stage that ended
+                 inside it) and on health-sentinel
                  bad steps (`note_health_event`, wired from
                  health/sentinel.py) — a wedged or anomalous run leaves
                  evidence instead of one opaque histogram.
@@ -80,6 +94,7 @@ fluid.flags and fluid.profiler are imported lazily inside functions.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
@@ -93,7 +108,8 @@ from . import metrics as _metrics
 from . import tracing as _tracing
 
 __all__ = [
-    "step_phases", "NullRecorder", "span", "spans", "span_clock",
+    "step_phases", "NullRecorder", "span", "child_span", "spans",
+    "span_clock", "install_runtime_hooks", "RING_MIN_NS",
     "set_span_export", "SPAN_RING", "note_step", "note_cost",
     "note_collectives",
     "note_health_event", "device_peaks", "roofline",
@@ -286,7 +302,7 @@ class _PhaseSpan:
         else:
             _phase_child(self.name, self.lane).observe((t1 - self.t0) / 1e9)
         if _export[0]:
-            _chrome_record(self)
+            _chrome_record(self.name, self.lane, self.t0, t1)
         return False
 
     @property
@@ -294,12 +310,12 @@ class _PhaseSpan:
         return (self.t1 - self.t0) / 1e9
 
 
-def _chrome_record(sp):
+def _chrome_record(name, lane, t0_ns, t1_ns):
     # only reached inside a fluid.profiler session (set_span_export)
     from paddle_tpu.fluid import profiler as _prof
 
-    _prof._record("phase", f"{sp.lane}:{sp.name}", sp.seconds,
-                  start=sp.t0 / 1e9)
+    _prof._record("phase", f"{lane}:{name}", (t1_ns - t0_ns) / 1e9,
+                  start=t0_ns / 1e9)
 
 
 def span(name, lane, number=None):
@@ -308,6 +324,27 @@ def span(name, lane, number=None):
     turn).  ``number`` is the turn or step number of a ROOT span's tree;
     a span opened inside another takes its parent's."""
     return _PhaseSpan(name, lane, number)
+
+
+def child_span(name, lane, t0_ns, t1_ns, note=None):
+    """Append a FINISHED span (both ends on `time.perf_counter_ns`) to
+    the ring, as a child of the span open on this thread: its id as
+    ``parent`` and its ``number`` (0 and None where none is open).  For
+    work that reports itself through a hook when it is over (a compile
+    stage).  Same tuple, same ring and same
+    ``pt_step_phase_seconds{phase=name,lane}`` as `span`.  Returns the
+    new span's id."""
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        parent, number = stack[-1].id, stack[-1].number
+    else:
+        parent, number = 0, None
+    sid = next(_span_ids)
+    _ring.append((name, lane, t0_ns, t1_ns, sid, parent, number, note))
+    _phase_child(name, lane).observe((t1_ns - t0_ns) / 1e9)
+    if _export[0]:
+        _chrome_record(name, lane, t0_ns, t1_ns)
+    return sid
 
 
 class _NullSpan:
@@ -424,6 +461,172 @@ def _pop_pending(lane):
         _tls.pending = None
         return pending
     return None
+
+
+# ---------------------------------------------------------------------------
+# runtime hooks: collections counted, XLA's stages beneath the open span
+# ---------------------------------------------------------------------------
+
+# A trace nested in another that lasted less than this is counted and kept
+# OUT of the ring: one step's trace holds thousands of nested `jnp` traces,
+# which would shorten the ring's horizon for nothing a reader can place.
+# A constant, not a knob.
+RING_MIN_NS = 500_000
+
+_GC_GENERATIONS = ("0", "1", "2", "any")
+_XLA_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_XLA_STAGES = {
+    _XLA_TRACE: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+_XLA_UNDER = ("compile", "dispatch", "other", "none")
+
+_xla_listening = [False]  # jax keeps no public list of its listeners
+_hooks_lock = threading.Lock()
+_gc_t0 = [0]
+_runtime = [None, -1]  # the bound series, the registry epoch they are of
+
+
+def _runtime_series():
+    """The hooks' counter series, every one created at 0 (a window in
+    which nothing was collected or compiled reads 0, not nothing) and
+    bound again after a registry reset."""
+    reg = _metrics.REGISTRY
+    if _runtime[1] == reg._epoch and _runtime[0] is not None:
+        return _runtime[0]
+    gc_s = _metrics.counter(
+        "pt_host_gc_seconds_total",
+        "Seconds this process spent inside the interpreter's cyclic "
+        "garbage collections (gc.callbacks start to stop; every thread "
+        "stands still meanwhile), by the generation collected and "
+        "generation=any for all of them", labels=("generation",))
+    xla_s = _metrics.counter(
+        "pt_xla_stage_seconds_total",
+        "Seconds inside jax's compile stages (jax.monitoring): trace "
+        "(self time: a trace nested in another is taken out of it), "
+        "lower, backend_compile, and cache_read, which is a PART of "
+        "backend_compile (a persistent-cache hit's read) and no addend; "
+        "under = compile or dispatch where a span of that name was open "
+        "on the thread (compile first), other under another span, none "
+        "under none", labels=("stage", "under"))
+    series = {
+        "gc": {g: gc_s.labels(generation=g) for g in _GC_GENERATIONS},
+        "xla": {(st, u): xla_s.labels(stage=st, under=u)
+                for st in _XLA_STAGES.values() for u in _XLA_UNDER},
+    }
+    _runtime[0], _runtime[1] = series, reg._epoch
+    return series
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` entry.  Runs on the thread whose allocation set the
+    collection off, wherever that thread stood: it bumps series that
+    exist and never registers one (a registry reset leaves the
+    collections unbooked until the next `install_runtime_hooks` or
+    compile stage binds the series again)."""
+    if phase == "start":
+        _gc_t0[0] = time.perf_counter_ns()
+        return
+    t1, t0 = time.perf_counter_ns(), _gc_t0[0]
+    series = _runtime[0]
+    if not t0 or _runtime[1] != _metrics.REGISTRY._epoch:
+        return  # installed between a start and its stop, or orphaned
+    _gc_t0[0] = 0
+    series["gc"][str(info["generation"])].inc((t1 - t0) / 1e9)
+    series["gc"]["any"].inc((t1 - t0) / 1e9)
+
+
+def _on_xla_enter(event, _value, **_kw):
+    """jax.monitoring scalar listener: jax records a stage's start so.
+    Traces nest (every jitted callee traced inside a trace reports its
+    own), so each open one keeps the seconds of those inside it."""
+    if event == _XLA_TRACE:
+        opened = getattr(_tls, "xla_open", None)
+        if opened is None:
+            opened = _tls.xla_open = []
+        opened.append(0.0)
+
+
+def _on_xla_stage(event, duration, fun_name=None, **_kw):
+    """jax.monitoring duration listener: a stage just ended on this
+    thread."""
+    stage = _XLA_STAGES.get(event)
+    if stage is None:
+        return
+    t1 = time.perf_counter_ns()
+    own, nested = duration, False
+    if stage == "trace":
+        opened = getattr(_tls, "xla_open", None)
+        inside = opened.pop() if opened else 0.0
+        own = max(duration - inside, 0.0)
+        if opened:
+            opened[-1] += duration
+            nested = True
+    names = [sp.name for sp in getattr(_tls, "stack", None) or ()]
+    under = ("compile" if "compile" in names
+             else "dispatch" if "dispatch" in names
+             else "other" if names else "none")
+    _runtime_series()["xla"][stage, under].inc(own)
+    if not nested or duration * 1e9 >= RING_MIN_NS:
+        child_span("xla." + stage, "host", t1 - int(duration * 1e9), t1,
+                   note=fun_name)
+
+
+def install_runtime_hooks():
+    """Book what the runtime does beneath the program's spans: one
+    callback on `gc.callbacks`, and two `jax.monitoring` listeners where
+    jax is imported (this module never imports it first).  Idempotent;
+    called where `fluid.executor` is imported, which every lane does
+    before it builds, compiles or runs anything.  No flag: the hooks are
+    on like the spans are, and `reset()` leaves them on.
+
+    Collections: ``pt_host_gc_seconds_total{generation}`` for 0, 1, 2
+    and ``any``, from a collection's ``start`` to its ``stop``.  They are
+    counted and not put in the ring: a ``gc.gen<N>`` child would be the
+    innermost span wherever it fell, and the benchmark's three idle
+    shares (`benchmark/readers/host_gap.py`) are tested to sum to 100
+    without it (PERF.md, open questions).
+
+    Compile stages: ``xla.trace`` / ``xla.lower`` / ``xla.backend_compile``
+    / ``xla.cache_read`` spans on lane ``host`` (end = the callback's
+    clock reading, start = end - jax's duration, note = jax's
+    ``fun_name``; a trace nested in another enters the ring from
+    `RING_MIN_NS` up) and ``pt_xla_stage_seconds_total{stage,under}``.
+    A cache read lies inside its backend compile and is counted in both.
+    ``under`` is ``compile`` where a `compile` span is open on the thread
+    (a signature's first run: its `dispatch` phase lies inside), else
+    ``dispatch`` where a `dispatch` is (the compile nobody asked for: a
+    jitted call that retraces), else ``other`` under any other span (a
+    `jnp` call on a scheduler's path), else ``none`` (the caller's own
+    jax)."""
+    with _hooks_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        jx = sys.modules.get("jax")
+        if not _xla_listening[0] and jx is not None:
+            jx.monitoring.register_scalar_listener(_on_xla_enter)
+            jx.monitoring.register_event_duration_secs_listener(
+                _on_xla_stage)
+            _xla_listening[0] = True
+    _runtime_series()
+
+
+def _beneath(t0_ns, t1_ns):
+    """``[{"name", "ms"[, "note"]}]`` of every ``xla.*`` span that ended
+    inside the interval, on any thread, oldest first.  The ring is in
+    order of the spans' ends, so it is read from its tail."""
+    out = []
+    for name, _lane, s0, s1, _id, _parent, _number, note in reversed(spans()):
+        if s1 < t0_ns - 1_000_000_000:  # threads append a little out of order
+            break
+        if t0_ns <= s1 <= t1_ns and name.startswith("xla."):
+            ent = {"name": name, "ms": round((s1 - s0) / 1e6, 3)}
+            if note is not None:
+                ent["note"] = note
+            out.append(ent)
+    return out[::-1]
 
 
 # ---------------------------------------------------------------------------
@@ -813,6 +1016,13 @@ def note_step(lane, seconds=None, first_run=False):
         rec["prefetch_queue_depth"] = qd
     if slow is not None:
         rec["slow_step"] = slow
+        # what the runtime did beneath it: the compile stages that ended
+        # inside the step, whatever thread ran them
+        end = time.perf_counter_ns()
+        start = end - int(seconds * 1e9)
+        if t0 is not None:
+            start = min(start, int(t0 * 1e9))
+        rec["beneath"] = _beneath(start, end)
     _flight.record(rec)
     if slow is not None:
         _flight.maybe_auto_dump(
@@ -1044,7 +1254,8 @@ def ensure_profilez_page():
 
 
 def reset():
-    """Drop all attribution state (tests)."""
+    """Drop all attribution state (tests).  The runtime hooks stay
+    installed."""
     global _flight
     with _lock:
         _signatures.clear()

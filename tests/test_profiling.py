@@ -107,8 +107,10 @@ def test_recorder_flag_off_records_phases_and_never_blocks(
     keys = set(obs.REGISTRY.get("pt_step_phase_seconds")
                ._snapshot()["samples"])
     assert {("dispatch", "dp"), ("device_wait", "dp")} <= keys
-    assert [sp[0] for sp in profiling.spans()] == ["dispatch",
-                                                   "device_wait"]
+    # (lane ``host`` holds what the runtime did beneath them: the
+    # `zeros` above compiles)
+    assert [sp[0] for sp in profiling.spans()
+            if sp[1] == "dp"] == ["dispatch", "device_wait"]
     rec = profiling.flight_recorder().snapshot()[-1]
     assert rec["label"] == "sig-b"
     assert set(rec["phases"]) == set(rec["phase_starts"]) == {

@@ -290,7 +290,8 @@ def test_data_parallel_step_is_named():
         runner.run(exe, feed, [loss.name], scope)
         (cb,) = runner._cache.values()
         assert "module @jit_train_step" in cb.lower(scope, feed).as_text()
-    names = [s[NAME] for s in profiling.spans()]
+    # (the lowering above traces once more: `xla.*` spans of its own)
+    names = [s[NAME] for s in profiling.spans() if s[LANE] != "host"]
     assert names[0] == "lookup" and "compile" in names
     assert names[-1] == "fetch_wait"
 

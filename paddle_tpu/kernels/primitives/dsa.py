@@ -28,6 +28,29 @@ attention kernel walks the sequence's pages like paged.py and the mask
 drops the rows that were not selected; at contexts far past 33k a row
 gather would win and this file is where it would go.
 
+A grid step of the attention (PR 37): the step's G pages are
+concatenated on the key axis (``paged._step_pages``, as mla.py does) and
+a tile of tq queries x all heads makes ONE score product
+``[tq * heads, W] x [W, G * page]``, ONE application of the visibility
+rule and of the ``selected`` block ``[tq, G * page]``, ONE online-softmax
+update and ONE value product ``[tq * heads, G * page] x [G * page, C]``
+into the float32 accumulator; a step whose first key lies past the
+tile's last query is skipped, a partly dead one is made right by the
+mask.  ``_attention_tile`` picks (tq, G) from the shapes: the largest
+tile of 8, 16, 32 .. queries within 1024 rows, then the most pages that
+divide the selection's within 1024 keys and a 2-MB float32 score tile:
+(16, 4) for GLM-5's chunk (T = 512, 64 heads, page 128), (1, 8) for its
+decode row.  On a v5e, a call at the chunk's shape and 4k / 9k / 16k /
+33k of context (ms; the two products alone need 1.6 / 3.5 / 6.3 / 12.6):
+one update a page, four a step (the parent) 8.78 / 13.66 / 20.49 /
+36.37; one a step at (8, 4) 3.44 / 5.70 / 8.86 / 16.22, (8, 8) 3.27 /
+5.41 / 8.39 / 15.32, (16, 4) 2.78 / 4.92 / 7.91 / 14.88; (32, 4) 2.54 /
+4.64 / 7.54 / 14.29 with the scoped VMEM raised to hold it.  The decode
+row over 16 slots, 5 live: 0.78 -> 0.46-0.47 (PERF.md section 6, PR 37).
+The choice is booked at trace time on
+``pt_paged_attention_form_total{primitive="sparse_mla_attention",
+form="chunk_tq<tq>" | "row_tq1", pages_per_step}``.
+
 Shapes (B sequences, T queries each: T = 1 in a decode step with B the
 pool's slots; B = 1 in a prefill chunk with T the chunk):
   q_idx        [B, T, Hi, Di]   indexer queries (RoPE applied)
@@ -41,7 +64,8 @@ pool's slots; B = 1 in a prefill chunk with T the chunk):
   scores       [B, T, Lp] float32, Lp >= max_pages * page_size (the
                page table is padded to a whole number of grid steps);
                positions a query may not see read -inf
-  selected     [B, T, Lp] float32 additive mask: 0 on the k positions
+  selected     [B, T, Lp] float32 additive mask (Lp a whole number of
+               pages, at least the page table's): 0 on the k positions
                with the largest scores, -1e9 elsewhere; where a query
                sees fewer than k positions the rest of the k are
                positions it may not see, which the attention masks by
@@ -61,7 +85,8 @@ import jax.numpy as jnp
 
 from . import contract
 from .contract import Block, Vmem
-from .paged import NEG_INF, _online_softmax_step
+from .paged import NEG_INF, _book_form, _init_state, _mxu, \
+    _online_softmax_step, _p_dtype, _step_pages
 
 MASKED = float("-inf")
 
@@ -384,6 +409,18 @@ def sparse_mla_attention_reference(q_lat, q_rope, latent_pages, page_table,
                       rows[..., :c], preferred_element_type=jnp.float32)
 
 
+def _mask_rows(s, mask, tq, heads):
+    """``s`` [tq * heads, keys] plus the [tq, keys] additive mask of its
+    queries, a query's row for each of its heads.  The reshape is free
+    (64 heads are whole sublane tiles); a broadcast of the mask or a
+    slice a query read the same on the chip (PERF.md section 6, PR 37)."""
+    if tq == 1:
+        return s + mask
+    keys = s.shape[-1]
+    return (s.reshape(tq, heads, keys)
+            + mask[:, None, :]).reshape(tq * heads, keys)
+
+
 def _sparse_mla_kernel(pt_ref, qs_ref, q_ref, sel_ref, *refs, page, tq,
                        heads, c, n_sub, n_steps, sm_scale):
     from jax.experimental import pallas as pl
@@ -391,39 +428,32 @@ def _sparse_mla_kernel(pt_ref, qs_ref, q_ref, sel_ref, *refs, page, tq,
     k_refs, o_ref = refs[:n_sub], refs[n_sub]
     acc_ref, m_ref, l_ref = refs[n_sub + 1:]
     bi, qi, pi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    rows = tq * heads
+    rows, keys = tq * heads, n_sub * page
 
     @pl.when(pi == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     first_q = qs_ref[bi] + qi * tq
-    q2 = q_ref[0].reshape(rows, q_ref.shape[-1])
-    for j in range(n_sub):
-        base = (pi * n_sub + j) * page
+    base = pi * keys
 
-        @pl.when(base <= first_q + tq - 1)
-        def _live(j=j, base=base):
-            rows_j = k_refs[j][0]                          # [page, W]
-            s = jax.lax.dot_general(
-                q2, rows_j, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            kpos = base + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, page), 1)
-            qpos = first_q + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, page), 0)
-            mask = jnp.where(kpos <= qpos,
-                             sel_ref[0, :, j * page:(j + 1) * page],
-                             NEG_INF)                      # [tq, page]
-            s = (s.reshape(tq, heads, page)
-                 + mask[:, None, :]).reshape(rows, page)
-            # a masked score reads -1e9 exactly (its own value is lost in
-            # the rounding), as in paged.py
-            s = jnp.maximum(s, NEG_INF)
-            _online_softmax_step(s, rows_j[:, :c], acc_ref, m_ref, l_ref,
-                                 p_dtype=rows_j.dtype)
+    # live iff the tile's last query sees the step's first key; a partly
+    # dead step is made right by the mask
+    @pl.when(base <= first_q + tq - 1)
+    def _live():
+        kv = _mxu(_step_pages(k_refs))                     # [keys, W]
+        s = jax.lax.dot_general(
+            q_ref[0].reshape(rows, q_ref.shape[-1]), kv,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        kpos = base + jax.lax.broadcasted_iota(jnp.int32, (tq, keys), 1)
+        qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, (tq, keys), 0)
+        mask = jnp.where(kpos <= qpos, sel_ref[0], NEG_INF)   # [tq, keys]
+        # a masked score reads -1e9 exactly (its own value is lost in the
+        # rounding), as in paged.py
+        s = jnp.maximum(_mask_rows(s, mask, tq, heads), NEG_INF)
+        _online_softmax_step(s, kv[:, :c], acc_ref, m_ref, l_ref,
+                             p_dtype=_p_dtype(kv.dtype))
 
     @pl.when(pi == n_steps - 1)
     def _finish():
@@ -432,23 +462,52 @@ def _sparse_mla_kernel(pt_ref, qs_ref, q_ref, sel_ref, *refs, page, tq,
         o_ref[0] = (acc_ref[...] / l_safe[:, :1]).reshape(tq, heads, c)
 
 
+# What a grid step of the attention may hold: the rows of its query tile
+# (queries x heads), the keys it scores at once, and the float32 bytes of
+# the [rows, keys] score tile; the readings behind them are in the
+# module's docstring.  2048 rows read 6% less again but want 22 MB of
+# scoped VMEM where Mosaic's default gives 16; a decode row (64 rows) is
+# flat from 768 to 1408 keys a step and loses at 3072.
+_ATTN_ROWS_PER_STEP = 1024
+_ATTN_KEYS_PER_STEP = 1024
+_ATTN_SCORE_BYTES = 2 << 20
+
+
+def _attention_tile(t, heads, page, sel_pages):
+    """(query tile, pages a step) of the attention launch, from the
+    shapes: the largest tile of 8, 16, 32 .. queries that divides T
+    within the rows a step may hold (T itself where 8 does not divide
+    it: a decode row), then the most pages that divide the selection's
+    within the keys and the score bytes.  GLM-5's chunk (T = 512, 64
+    heads, page 128) gets (16, 4), its decode row (1, 8)."""
+    tq = t
+    if t % 8 == 0:
+        tq = 8
+        while (t % (2 * tq) == 0
+               and 2 * tq * heads <= _ATTN_ROWS_PER_STEP):
+            tq *= 2
+    keys = min(_ATTN_KEYS_PER_STEP, _ATTN_SCORE_BYTES // (4 * tq * heads))
+    return tq, max(d for d in range(1, sel_pages + 1)
+                   if sel_pages % d == 0 and (d == 1 or d * page <= keys))
+
+
 def _pallas_sparse_mla(q_lat, q_rope, latent_pages, page_table, selected,
                        q_start, sm_scale, interpret):
     b, t, heads, c = q_lat.shape
     page, width = latent_pages.shape[1], latent_pages.shape[2]
-    # half the indexer's pages a step: a latent row is 4.5 times an
-    # indexer key, and 8 query rows of 64 heads already fill the MXU
-    n_sub = PAGES_PER_STEP // 2
-    steps, odd = divmod(selected.shape[-1], n_sub * page)
-    if odd or steps * n_sub < page_table.shape[1]:
+    sel_pages, odd = divmod(selected.shape[-1], page)
+    if odd or sel_pages < page_table.shape[1]:
         raise ValueError(
             f"sparse_mla_attention: the selection covers "
             f"{selected.shape[-1]} positions for a page table of "
             f"{page_table.shape[1]} pages of {page}: take it from "
             f"dsa_topk_select of dsa_indexer_scores over the same page "
             f"table")
-    page_table = _padded_table(page_table, steps * n_sub)
-    tq = 8 if t % 8 == 0 else t
+    tq, n_sub = _attention_tile(t, heads, page, sel_pages)
+    steps = sel_pages // n_sub
+    _book_form("sparse_mla_attention",
+               f"{'row' if t == 1 else 'chunk'}_tq{tq}", n_sub)
+    page_table = _padded_table(page_table, sel_pages)
     q = _padded_queries(q_lat, q_rope, width).astype(latent_pages.dtype)
 
     spec = contract.make_spec(

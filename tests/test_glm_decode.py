@@ -524,9 +524,49 @@ def test_indexer_scores_pallas_against_reference(t):
     assert seen[0, 0].sum() == int(a["q_start"][0]) + 1
 
 
-@pytest.mark.parametrize("t", [1, 8])
-def test_sparse_attention_pallas_against_reference(t):
-    a, length = _dsa_case(t, seed=1)
+def _attention_forms(since=None):
+    """{(form, pages a step): launches booked} of the attention, less
+    those of an earlier reading."""
+    fam = obs.snapshot().get("pt_paged_attention_form_total") or {}
+    now = {k[1:]: v for k, v in fam.get("samples", {}).items()
+           if k[0] == "sparse_mla_attention"}
+    return {k: v - (since or {}).get(k, 0) for k, v in now.items()
+            if v != (since or {}).get(k, 0)}
+
+
+# t, pages a row, the two rows' q_start, pages a step (None: what the
+# shapes give, the whole table at this size; else the step's keys are
+# held to that many pages), the pool's dtype, the tile
+_ATTENTION_CASES = {
+    "t1": (1, 6, (23, 3), None, jnp.float32, "row_tq1"),
+    "t8": (8, 6, (16, 3), None, jnp.float32, "chunk_tq8"),
+    # a tile of more than 8 queries; steps of 8 keys, so that several
+    # accumulate, and row 1's last live step (keys 16-23 for queries
+    # 3-18) is partly dead
+    "t16_several_steps": (16, 12, (32, 3), 2, jnp.float32, "chunk_tq16"),
+    # row 1's first query sees a single key
+    "t32_single_key": (32, 12, (16, 0), 4, jnp.float32, "chunk_tq32"),
+    "t8_single_key": (8, 6, (0, 0), 2, jnp.float32, "chunk_tq8"),
+    # the last live step of both rows ends past the last query
+    "t8_partly_dead_step": (8, 12, (21, 2), 2, jnp.float32, "chunk_tq8"),
+    "t1_several_steps": (1, 12, (41, 9), 2, jnp.float32, "row_tq1"),
+    "t1_bf16": (1, 12, (47, 3), 2, jnp.bfloat16, "row_tq1"),
+    "t16_bf16": (16, 12, (32, 3), 2, jnp.bfloat16, "chunk_tq16"),
+}
+
+
+@pytest.mark.parametrize("case", list(_ATTENTION_CASES))
+def test_sparse_attention_pallas_against_reference(case, monkeypatch):
+    t, maxp, starts, g, dtype, form = _ATTENTION_CASES[case]
+    a, length = _dsa_case(t, seed=1, maxp=maxp)
+    a["q_start"] = jnp.asarray(starts, jnp.int32)
+    a["latent_pages"] = a["latent_pages"].astype(dtype)
+    # a bfloat16 pool rounds where the XLA form rounds (test_mla_kernels)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    if g is not None:
+        from paddle_tpu.kernels.primitives import dsa
+
+        monkeypatch.setattr(dsa, "_ATTN_KEYS_PER_STEP", g * 4)
     scores = prims.dsa_indexer_scores(
         a["q_idx"], a["w_idx"], a["index_pages"], a["table"], a["q_start"],
         force="pallas")
@@ -535,11 +575,14 @@ def test_sparse_attention_pallas_against_reference(t):
     want = prims.sparse_mla_attention(
         a["q_lat"], a["q_rope"], *tail, selected[..., :length],
         a["q_start"], sm_scale=0.3, force="reference")
+    before = _attention_forms()
     got = prims.sparse_mla_attention(
         a["q_lat"], a["q_rope"], *tail, selected, a["q_start"],
         sm_scale=0.3, force="pallas")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-    # only the selected rows count: zero every other row of the cache
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
+    steps = selected.shape[-1] // 4 if g is None else g
+    assert _attention_forms(since=before) == {(form, str(steps)): 1}
+    # only the selected rows count: overwrite every other row of the cache
     keep = np.zeros(a["latent_pages"].shape[:2], bool)
     sel = np.asarray(selected)[..., :length] == 0.0
     table = np.asarray(a["table"])
@@ -550,7 +593,27 @@ def test_sparse_attention_pallas_against_reference(t):
     again = prims.sparse_mla_attention(
         a["q_lat"], a["q_rope"], zeroed, a["table"], selected, a["q_start"],
         sm_scale=0.3, force="pallas")
-    np.testing.assert_allclose(np.asarray(again), np.asarray(got), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(again), np.asarray(got), atol=tol)
+
+
+@pytest.mark.parametrize("b,t,form,pages", [(16, 1, "row_tq1", 8),
+                                            (1, 512, "chunk_tq16", 4)])
+def test_attention_books_the_tile_it_chose_at_glm5_widths(b, t, form,
+                                                          pages):
+    """The decode step's and the prefill chunk's launch at the
+    benchmark's shapes (64 heads, rows stored 640 wide in bfloat16, page
+    128, a selection 264 pages wide): the query tile and the pages a step
+    come from the shapes alone and are booked at trace time."""
+    shapes = [((b, t, 64, 512), jnp.float32), ((b, t, 64, 64), jnp.float32),
+              ((4129, 128, 640), jnp.bfloat16), ((b, 258), jnp.int32),
+              ((b, t, 264 * 128), jnp.float32), ((b,), jnp.int32)]
+    before = _attention_forms()
+    out = jax.eval_shape(
+        lambda *a: prims.sparse_mla_attention(*a, sm_scale=1.0 / 16,
+                                              force="pallas"),
+        *[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+    assert out.shape == (b, t, 64, 512) and out.dtype == jnp.float32
+    assert _attention_forms(since=before) == {(form, str(pages)): 1}
 
 
 def _topk_oracle(scores, k):

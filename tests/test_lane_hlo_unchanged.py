@@ -9,9 +9,11 @@ traced.
 
 The digests below are of `lowered.as_text()` at commit 67a584c (PR 30's
 tree), made by this file's `digests()` there, but for `glm.pallas.*`,
-which PR 32 moved by design: their text holds the interpreted grouped
-product, whose grid now ends at the live visits (a traced extent); the
-ten others are the proof that nothing else moved.  The `trinity.*` four
+which PR 32 and PR 37 moved by design: their text holds the interpreted
+grouped product, whose grid ends at the live visits (a traced extent,
+PR 32), and the interpreted `sparse_mla_attention`, which makes one
+score / softmax / value update a grid step over all the step's pages
+(PR 37); the ten others are the proof that nothing else moved.  The `trinity.*` four
 are of commit 3ff1d2d (PR 32's tree), made before PR 33 gave a lane its
 optional encoder: a lane that declares none builds what it built.  After a deliberate change
 to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
@@ -39,8 +41,8 @@ GOLDEN = {
     "gpt.float32.pallas.decode": "931f17d5b326722c8c62cc0222397248c73d072475d72e437d830427d102cfd8",
     "gpt.int8.pallas.prefill": "6c60c7216eb5c3bdf1ddf181932dce89b726895a1a580d4007cfcf4796f59142",
     "gpt.int8.pallas.decode": "5b1236c43d90c101518a07f823c0b878bcd2344b3f6cc813df068e8a3a84ae6c",
-    "glm.pallas.prefill": "82d1a4b860f5534399f27243c4d074eda21b97c8e0631a0d11a05962706af923",
-    "glm.pallas.decode": "865787bd0491c7c45bd9fe403f0537cf4e60e57544253b2d09018178b6a58c15",
+    "glm.pallas.prefill": "d2a0f7d7b30df9bdf2511c7b4f2244290a4776f329925830d162d7a55b64c807",
+    "glm.pallas.decode": "578e2a3223d4468c4e64b81fbf6adce62130beace5189ff432f7ae0a23dbd310",
     "trinity.None.prefill": "6a0acaf7ee2c08877896d839f89c39e243d3bc10d4e001c30b769978814375b6",
     "trinity.None.decode": "ce29f09c2160a3c759dab784c8df9ca47db24d0ccc773a70bb2dfbefbf9175fb",
     "trinity.pallas.prefill": "cbb052cc583019c889beb82edc4bed3f5e94c17bf1a7dde125e6adcec01b1510",

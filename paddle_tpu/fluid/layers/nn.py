@@ -23,6 +23,7 @@ __all__ = [
     "sparse_mla_attention", "moe_ffn_held", "rope_half", "sigmoid_gate",
     "paged_mla_attention", "mla_chunk_attention", "bicubic_resize_table",
     "rope_2d_interleaved", "vit_attention", "select_embedding_rows",
+    "short_conv", "gdn_inputs", "gated_delta_rule", "gated_rms_norm",
     "conv2d", "conv3d", "conv2d_transpose", "pool2d",
     "batch_norm", "layer_norm", "group_norm", "instance_norm", "dropout",
     "softmax", "log_softmax", "cross_entropy", "softmax_with_cross_entropy",
@@ -1736,6 +1737,102 @@ def select_embedding_rows(emb, rows, idx, name=None):
     return _out_f32(LayerHelper("select_embedding_rows", name=name),
                     "select_embedding_rows",
                     {"Emb": [emb], "Rows": [rows], "Idx": [idx]})
+
+
+# ---------------------------------------------------------------------------
+# a linear-attention (gated delta rule) layer over per-sequence state
+# (ops/gdn_ops.py; models/olmo_hybrid.py).  Inference-only; float32.
+# ---------------------------------------------------------------------------
+
+
+def short_conv(x, kernel, tail, block, q_start=None, last_idx=None,
+               param_attr=None, name=None):
+    """Depthwise causal convolution of ``kernel`` taps over time, then
+    SiLU, of x [B, T, ch]; its last ``kernel`` - 1 pre-activation inputs
+    are carried in the state var ``tail`` [blocks, (kernel - 1) * ch],
+    updated in place.  With ``q_start`` and ``last_idx`` ([1] each) x is
+    one sequence's chunk and ``block`` [1] its block (read as zeros where
+    ``q_start`` is 0, written up to position ``last_idx``); without them
+    x is one token a slot and ``block`` [B] each slot's block."""
+    helper = LayerHelper("short_conv", name=name)
+    w = helper.create_parameter(
+        param_attr, shape=[int(kernel), x.shape[-1]], dtype="float32",
+        default_initializer=Normal(0.0, 0.3))
+    out = helper.create_variable_for_type_inference("float32")
+    inputs = {"X": [x], "W": [w], "Tail": [tail], "Block": [block]}
+    op = "short_conv_step"
+    if q_start is not None:
+        op = "short_conv_chunk"
+        inputs.update({"QStart": [q_start], "LastIdx": [last_idx]})
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "TailOut": [tail]})
+    return out
+
+
+def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
+               epsilon=1e-6, row_valid=None, a_log_attr=None,
+               dt_bias_attr=None, name=None):
+    """The gated delta rule's operands from the convolved projections
+    qkv [B, T, 2 H d_k + H d_v] and the gate projections a, b [B, T, H]:
+    (q [B, T, H, d_k] L2-normalised and scaled by d_k^-1/2, k
+    L2-normalised, v [B, T, H, d_v], g = -exp(A_log) softplus(a +
+    dt_bias), beta = ``beta_scale`` sigmoid(b)).  ``row_valid`` [T]: rows
+    marked 0 get beta = 0 and g = 0."""
+    helper = LayerHelper("gdn_inputs", name=name)
+    a_log = helper.create_parameter(a_log_attr, shape=[int(heads)],
+                                    dtype="float32",
+                                    default_initializer=Constant(0.0))
+    dt_bias = helper.create_parameter(dt_bias_attr, shape=[int(heads)],
+                                      dtype="float32",
+                                      default_initializer=Constant(0.0))
+    outs = [helper.create_variable_for_type_inference("float32")
+            for _ in range(5)]
+    inputs = {"QKV": [qkv], "A": [a], "B": [b], "ALog": [a_log],
+              "DtBias": [dt_bias]}
+    if row_valid is not None:
+        inputs["RowValid"] = [row_valid]
+    helper.append_op(
+        "gdn_inputs", inputs=inputs,
+        outputs=dict(zip(("Q", "K", "V", "G", "Beta"),
+                         ([o] for o in outs))),
+        attrs={"heads": int(heads), "key_dim": int(key_dim),
+               "value_dim": int(value_dim), "beta_scale": float(beta_scale),
+               "epsilon": float(epsilon)})
+    return outs
+
+
+def gated_delta_rule(q, k, v, g, beta, state, block, q_start=None,
+                     force=None, name=None):
+    """The gated delta rule over the per-sequence state var ``state``
+    [blocks, d_k, H * d_v] (kernels/primitives/gdn.py), updated in place
+    -> [B, T, H, d_v] float32.  With ``q_start`` [1] the operands are one
+    sequence's chunk [1, C, H, .] and ``block`` [1] its block (read as
+    zeros where ``q_start`` is 0); without it they are one token a slot
+    [B, 1, H, .] and ``block`` [B] each slot's block."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    inputs = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+              "State": [state], "Block": [block]}
+    op = "gated_delta_step"
+    if q_start is not None:
+        op = "gated_delta_chunk"
+        inputs["QStart"] = [q_start]
+    helper.append_op(op, inputs=inputs,
+                     outputs={"Out": [out], "StateOut": [state]},
+                     attrs={} if force is None else {"force": force})
+    return out
+
+
+def gated_rms_norm(x, gate, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over each head's entries of x [B, T, H, d] (one gain [d])
+    times silu(gate [B, T, H * d]) -> [B, T, H * d]."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = helper.create_parameter(param_attr, shape=[x.shape[-1]],
+                                    dtype="float32",
+                                    default_initializer=Constant(1.0))
+    return _out_f32(helper, "gated_rms_norm",
+                    {"X": [x], "Gate": [gate], "Scale": [scale]},
+                    {"epsilon": float(epsilon)})
 
 
 def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,

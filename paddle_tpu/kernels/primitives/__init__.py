@@ -14,6 +14,8 @@ serving lane lowers through:
   mla        dense latent attention over a paged latent cache: latent
              space for the decode step, head space for the prefill chunk
   vit        bidirectional attention inside one image (a vision tower)
+  gdn        the gated delta rule over a per-sequence state: a chunked
+             form for the prefill chunk, an in-place step for decode
 
 Raw ``pl.pallas_call`` / ``pltpu`` outside this package is a lint
 error (tools/lint_kernels.py) unless marked ``# kernel: allow``.
@@ -41,6 +43,10 @@ from .dsa import (  # noqa: F401
     dsa_indexer_scores, dsa_indexer_scores_reference, dsa_topk_select,
     dsa_topk_select_reference, sparse_mla_attention,
     sparse_mla_attention_reference,
+)
+from .gdn import (  # noqa: F401
+    gated_delta_chunk, gated_delta_chunk_reference, gated_delta_step,
+    gated_delta_step_reference,
 )
 from .grouped import (  # noqa: F401
     grouped_matmul, grouped_matmul_reference,
@@ -76,4 +82,6 @@ __all__ = [
     "paged_mla_attention", "paged_mla_attention_reference",
     "mla_chunk_attention", "mla_chunk_attention_reference",
     "vit_attention", "vit_attention_reference",
+    "gated_delta_chunk", "gated_delta_chunk_reference",
+    "gated_delta_step", "gated_delta_step_reference",
 ]

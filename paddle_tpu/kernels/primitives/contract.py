@@ -58,19 +58,25 @@ DmaSem = namedtuple("DmaSem", ("shape",))
 # One primitive launch as data.  `out_shape` entries are (shape, dtype)
 # pairs; `in_specs`/`out_specs` are Block tuples (one out entry per
 # out_shape entry).  A single-element out list returns a single array.
+# ``input_output_aliases``: {operand index (scalar-prefetch operands
+# counted): output index} — the output IS that operand's buffer, written
+# where it lies (a state tensor a kernel updates in place).
 KernelSpec = namedtuple(
     "KernelSpec",
     ("name", "grid", "in_specs", "out_specs", "out_shape", "scratch",
-     "num_scalar_prefetch", "interpret"),
+     "num_scalar_prefetch", "interpret", "input_output_aliases"),
+    defaults=((),),
 )
 
 
 def make_spec(name, grid, in_specs, out_specs, out_shape, scratch=(),
-              num_scalar_prefetch=0, interpret=False):
+              num_scalar_prefetch=0, interpret=False,
+              input_output_aliases=None):
     """Build a :class:`KernelSpec` (keyword-friendly constructor)."""
     return KernelSpec(name, tuple(grid), tuple(in_specs),
                       tuple(out_specs), tuple(out_shape), tuple(scratch),
-                      int(num_scalar_prefetch), bool(interpret))
+                      int(num_scalar_prefetch), bool(interpret),
+                      tuple(sorted((input_output_aliases or {}).items())))
 
 
 def primitive_call(kernel, spec, *operands):
@@ -101,6 +107,7 @@ def primitive_call(kernel, spec, *operands):
 
     scratch = [scratch_shape(v) for v in spec.scratch]
     single = len(out_specs) == 1
+    aliases = dict(spec.input_output_aliases)
 
     if spec.num_scalar_prefetch:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -115,6 +122,7 @@ def primitive_call(kernel, spec, *operands):
             out_shape=out_shape[0] if single else out_shape,
             interpret=spec.interpret,
             name=spec.name,
+            input_output_aliases=aliases,
         )(*operands)
     return pl.pallas_call(                             # kernel: allow
         kernel,
@@ -125,6 +133,7 @@ def primitive_call(kernel, spec, *operands):
         scratch_shapes=scratch,
         interpret=spec.interpret,
         name=spec.name,
+        input_output_aliases=aliases,
     )(*operands)
 
 

@@ -536,6 +536,18 @@ def _paged_kv_head_kernel(page_table_ref, q_start_ref, q_ref, *refs, n_sub,
 _QUERY_ROWS_PER_STEP = 1536
 
 
+# most bytes the per-head chunk body's whole-chunk blocks may take: its
+# q and o blocks (double-buffered) and its accumulator hold every head's
+# queries at once
+_PER_HEAD_VMEM_BYTES = 8 << 20
+
+
+def _per_head_block_bytes(q):
+    _, n, t, d = q.shape
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    return n * tp * (4 * d * q.dtype.itemsize + 4 * d + 2 * 4 * 128)
+
+
 def _query_tile(tp, heads_per_kv):
     """Queries a tile: the whole (padded) block where its group's rows
     fit, else the largest sublane-multiple divisor of it that does."""
@@ -705,6 +717,13 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
         return _heads_batched_call(name, q, pools, page_table, q_start,
                                    scale, interpret, g, heads_per_kv,
                                    window)
+    if (heads_per_kv is None and heads_batched and d % 128 == 0
+            and _per_head_block_bytes(q) > _PER_HEAD_VMEM_BYTES):
+        # a chunk whose every head's queries do not fit VMEM at once (30
+        # heads x 512 queries x 128: 39 MB) takes the K/V-head body with
+        # one query head a K/V head: a grid axis a head, the queries in
+        # tiles.  What fits keeps the launch it had
+        heads_per_kv = 1
     if heads_per_kv is not None:
         _book_form(name, "kv_head", g)
         return _kv_head_call(name, q, pools, page_table, q_start, scale,

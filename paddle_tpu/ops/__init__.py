@@ -32,5 +32,6 @@ from . import decode_ops  # noqa: F401
 from . import mla_ops  # noqa: F401
 from . import gqa_ops  # noqa: F401
 from . import vision_ops  # noqa: F401
+from . import gdn_ops  # noqa: F401
 from . import compat_ops  # noqa: F401
 from . import interop_tail_ops  # noqa: F401

@@ -31,6 +31,20 @@ held its position has run.  An evicted request replays from token 0 and
 its images are encoded again.  One executable an image shape, every
 declared shape compiled by ``warmup()``.
 
+A lane may declare state a SEQUENCE owns in some layers (serving/lane.py
+``SeqState``: a linear-attention layer's recurrent state;
+models/olmo_hybrid.py).  The pool then has the kind ``state`` beside its
+page kinds: one block a sequence from ``open_seq`` to ``free_seq``.  A
+prefilling sequence has a seq_id and no slot, so the programs find the
+state by BLOCK INDEX, one feed more in each executable
+(``pf_state_block`` [1], ``dec_state_block`` [slots]; inactive slots and
+warm-up name the trash block); the chunk whose ``pf_qstart`` is 0 reads
+the block as zeros inside the program, so a block goes from one sequence
+to the next without a host write, the state is carried from chunk to
+chunk and from the last chunk into the decode steps, and an eviction
+replays from token 0 as it always did.  A pool out of state blocks
+raises what a pool out of pages raises, and the scheduler evicts.
+
 Execution model — exactly TWO compiled signatures in steady state (and
 one more an image shape where the lane declares an encoder):
 
@@ -241,7 +255,8 @@ def _m_kind_pages_in_use():
     return obs.gauge(
         "pt_kv_pages_in_use",
         "KV pool pages currently allocated to live sequences, by cache "
-        "kind (full / window<W>)", labels=("engine", "kind"))
+        "kind (full / window<W>; state: per-sequence state blocks)",
+        labels=("engine", "kind"))
 
 
 def _m_pages_alloc():
@@ -269,7 +284,9 @@ def _m_cache_bytes():
     return obs.gauge(
         "pt_decode_cache_bytes",
         "Device bytes the pages in use hold, by declared cache row "
-        "tensor (k / v; latent / index), over every layer",
+        "tensor (k / v; latent / index), over every layer; for a lane "
+        "with per-sequence state also the bytes the blocks in use hold, "
+        "by declared state tensor",
         labels=("engine", "row"))
 
 
@@ -390,14 +407,18 @@ class DecodeEngine:
     window layers (``lane.layer_windows``) gets a pool tensor size a
     cache kind: ``num_pages`` is the ``full`` kind's, a window kind has
     ``pool_slots x lane.window_pages_per_seq`` pages (+1 trash) and never
-    more than ``num_pages``."""
+    more than ``num_pages``.  A lane that declares per-sequence state
+    (``lane.seq_state``) gets ``state_blocks`` blocks of every state
+    tensor, the trash block included: by default one a slot and one for
+    the sequence that prefills (``pool_slots + 2``) — shrink it to
+    exercise eviction."""
 
     def __init__(self, cfg, *, scope=None, place=None, pool_slots=4,
                  page_size=16, prefill_chunk=None, max_len=None,
                  num_pages=None, max_queue=None, pool_dtype=None,
                  attn_force=None, name="decode", auto_start=True,
                  tenant_quota=None, drain_on_sigterm=True,
-                 int8_weights=False):
+                 int8_weights=False, state_blocks=None):
         from paddle_tpu import fluid
         from paddle_tpu.fluid import flags as _flags
         from paddle_tpu.fluid.executor import global_scope
@@ -444,13 +465,23 @@ class DecodeEngine:
                               if max_queue is None else max_queue)
         rows = lane.cache_rows(pool_dtype)
         windows = lane.layer_windows
+        state_kw = {}
+        if lane.seq_state:
+            if state_blocks is None:
+                state_blocks = self.pool_slots + 2
+            state_kw = {"seq_state": lane.seq_state,
+                        "state_layers": lane.state_layers,
+                        "state_blocks": int(state_blocks)}
         self.pool = KVPool(
             lane.num_layers, rows, num_pages, page_size, max_pages,
             layer_windows=windows, window_pages={
                 _lane.kind_name(w): self.pool_slots
                 * _lane.window_pages_per_seq(w, prefill_chunk, page_size) + 1
-                for w in set(windows or ()) if w is not None})
+                for w in set(windows or ()) if w is not None}, **state_kw)
         self.pool.install(self.scope)
+        # the builders of a lane with state size its tensors by this
+        build_kw = ({"state_blocks": self.pool.state_blocks}
+                    if lane.seq_state else {})
         if windows is not None:  # the builders size each layer by its kind
             num_pages = self.pool.pages_by_kind()
         if pool_dtype == "int8":
@@ -490,13 +521,14 @@ class DecodeEngine:
                 fluid.unique_name.guard():
             self._dec_feeds, dec_tok, _ = lane.build_decode_step(
                 self.pool_slots, num_pages, page_size, max_pages,
-                pool_dtype=pool_dtype, attn_force=attn_force)
+                pool_dtype=pool_dtype, attn_force=attn_force, **build_kw)
         pf_prog, pf_start = fluid.Program(), fluid.Program()
         with fluid.program_guard(pf_prog, pf_start), \
                 fluid.unique_name.guard():
             self._pf_feeds, pf_tok, _ = lane.build_prefill_chunk(
                 prefill_chunk, num_pages, page_size, max_pages,
-                pool_dtype=pool_dtype, attn_force=attn_force, **chunk_kw)
+                pool_dtype=pool_dtype, attn_force=attn_force, **chunk_kw,
+                **build_kw)
         self._dec_prog, self._dec_fetch = dec_prog, dec_tok.name
         self._pf_prog, self._pf_fetch = pf_prog, pf_tok.name
         # what a device trace calls the two executables:
@@ -597,7 +629,10 @@ class DecodeEngine:
         self._cache_bytes = {
             row: _m_cache_bytes().labels(engine=e, row=row.name)
             for row in self.pool.rows}
-        kinds = self.pool.kinds
+        self._state_bytes = {
+            st: _m_cache_bytes().labels(engine=e, row=st.name)
+            for st in self.pool.seq_state}
+        kinds = list(self.pool.kind_stats())  # the state kind with them
         self._kind_pages = {
             k: _m_kind_pages_in_use().labels(engine=e, kind=k)
             for k in kinds}
@@ -1034,6 +1069,8 @@ class DecodeEngine:
         in_use = {k: st["pages_in_use"] for k, st in kinds.items()}
         for row, gauge in self._cache_bytes.items():
             gauge.set(self.pool.row_bytes(row, in_use))
+        for st, gauge in self._state_bytes.items():
+            gauge.set(self.pool.state_bytes(st, in_use[_lane.STATE]))
         for k, st in kinds.items():
             booked = self._kind_booked[k]
             self._kind_pages[k].set(st["pages_in_use"])
@@ -1074,6 +1111,16 @@ class DecodeEngine:
             return True
         return False
 
+    def _open_seq(self, req):
+        """Open the request's sequence in the pool; a pool out of state
+        blocks evicts as a pool out of pages does."""
+        while True:
+            try:
+                return self.pool.open_seq(req.seq_id)
+            except PoolExhaustedError:
+                if not self._evict_one(protect=req):
+                    raise  # fewer state blocks than one sequence needs
+
     def _ensure_pages(self, req, n_tokens):
         while True:
             try:
@@ -1097,7 +1144,7 @@ class DecodeEngine:
             if req.seq_id is None:
                 req.seq_id = self._next_seq
                 self._next_seq += 1
-                self.pool.open_seq(req.seq_id)
+                self._open_seq(req)
                 self._live_order.append(req)
             tokens = req.tokens_to_write()
             total = len(tokens)
@@ -1318,6 +1365,9 @@ class DecodeEngine:
                 write_pages[kind].astype(np.int32)
         feed["pf_qstart"] = np.asarray([pos0], np.int32)
         feed["pf_last_idx"] = np.asarray([max(valid - 1, 0)], np.int64)
+        if self.lane.seq_state:
+            feed[_lane.STATE_FEEDS["prefill"]] = np.asarray(
+                [self.pool.state_block(seq_id)], np.int32)
         if self._enc is not None:
             feed[self._enc.index_feed] = (
                 np.full((1, c), -1, np.int32) if row_idx is None
@@ -1434,6 +1484,11 @@ class DecodeEngine:
                 table.astype(np.int32)
             feed[_lane.kind_feed("dec_write_page", kind)] = wpage
         feed["dec_write_off"] = woff
+        if self.lane.seq_state:
+            blocks = np.full(ps, TRASH_PAGE, np.int32)
+            for i, req in active:
+                blocks[i] = self.pool.state_block(req.seq_id)
+            feed[_lane.STATE_FEEDS["decode"]] = blocks
         return feed
 
     def _run_decode_feed(self, active, warm=False):

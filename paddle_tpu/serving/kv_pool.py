@@ -29,6 +29,16 @@ see a key of it) while the request lives; its table entry becomes the
 trash page.  A page index stays logical: a table is as wide for a
 window kind as for ``full``, with trash below the window.
 
+**Per-sequence state.**  A lane may also declare state a SEQUENCE owns
+in some layers (serving/lane.py ``SeqState``: a linear-attention layer's
+recurrent state).  That is the kind ``state``: its "pages" are whole
+blocks, a sequence holds exactly ONE whatever its length, handed out by
+``open_seq`` (which raises what ``ensure_capacity`` raises when none is
+free: the scheduler evicts) and given back by ``free_seq``.  Its tensors
+are ``[blocks, *shape]`` a state layer, block 0 the trash block; it has
+no page table (``kinds`` lists the page kinds only) and is counted by
+the same ``kind_stats``.
+
 Page 0 of every kind is the TRASH page: never allocated, the write
 target of inactive decode slots and padded prefill tails, and what a
 released entry of a window kind's table points at.  Readers can't
@@ -52,7 +62,8 @@ import collections
 import numpy as np
 
 from .errors import PoolExhaustedError
-from .lane import POOL_PREFIX, kind_name, kinds_of, pool_var_names
+from .lane import (POOL_PREFIX, STATE, kind_name, kinds_of, pool_var_names,
+                   state_var_names)
 
 __all__ = ["KVPool", "PoolExhaustedError"]
 
@@ -82,6 +93,15 @@ class _Kind:
     def in_use(self):
         return (self.num_pages - 1) - len(self.free)
 
+    def take(self):
+        """Hand out the free list's last page (the one freed last)."""
+        page = self.free.pop()
+        if page in self.ever_used:
+            self.reused_allocs += 1
+        self.ever_used.add(page)
+        self.alloc_total += 1
+        return page
+
     def give_back(self, pages, why):
         for p in reversed(pages):
             self.free.append(p)
@@ -102,11 +122,17 @@ class KVPool:
     or W) declares the layers' cache kinds; ``num_pages`` is then the
     ``full`` kind's, and a window kind gets ``window_pages[kind]``
     pages (trash included; never more than ``num_pages``, and
-    ``num_pages`` where none is given)."""
+    ``num_pages`` where none is given).
+
+    ``seq_state`` / ``state_layers`` (``lane.DecodeLane``'s) declare the
+    per-sequence state tensors and the layers that own them;
+    ``state_blocks`` is how many blocks each has, the trash block
+    included."""
 
     def __init__(self, num_layers, rows, num_pages, page_size,
                  max_pages_per_seq, prefix=None, layer_windows=None,
-                 window_pages=None):
+                 window_pages=None, seq_state=(), state_layers=(),
+                 state_blocks=0):
         if num_pages - 1 < max_pages_per_seq:
             raise ValueError(
                 f"KV pool of {num_pages} pages (1 reserved for trash) "
@@ -132,9 +158,32 @@ class KVPool:
             self._kinds[name] = _Kind(name, w, pages,
                                       self.layer_kinds.count(name))
         self.kinds = list(self._kinds)
+        self.seq_state = list(seq_state)
+        self.state_layers = list(state_layers)
+        self.state_var_names = state_var_names(
+            self.seq_state, self.state_layers, self.prefix)
+        self._state = None
+        if self.seq_state:
+            if int(state_blocks) < 2:
+                raise ValueError(
+                    f"KV pool with per-sequence state needs at least 2 "
+                    f"state blocks (1 is the trash block), got "
+                    f"{state_blocks}")
+            self._state = _Kind(STATE, None, state_blocks,
+                                len(self.state_layers))
+        # the page kinds and, where declared, the state kind, by name
+        self._every = dict(self._kinds)
+        if self._state is not None:
+            self._every[STATE] = self._state
 
     def _kind(self, kind=None):
         return self._kinds[self.kinds[0] if kind is None else kind]
+
+    @property
+    def state_blocks(self):
+        """Blocks of each state tensor, the trash block included (0: no
+        state declared)."""
+        return 0 if self._state is None else self._state.num_pages
 
     def pages_by_kind(self):
         """{kind: pages of its tensors, trash included}."""
@@ -159,14 +208,20 @@ class KVPool:
         gigabytes, and host zeros would cross to the chip."""
         import jax.numpy as jnp
 
+        def zeros(name, shape, dtype):
+            cur = scope.get(name)
+            if (cur is None or tuple(np.shape(cur)) != shape
+                    or str(getattr(cur, "dtype", "")) != dtype):
+                scope.set(name, jnp.zeros(shape, dtype=dtype))
+
         for names, kind in zip(self.var_names, self.layer_kinds):
             for name, row in zip(names, self.rows):
-                shape = (self._kinds[kind].num_pages, self.page_size,
-                         row.width)
-                cur = scope.get(name)
-                if (cur is None or tuple(np.shape(cur)) != shape
-                        or str(getattr(cur, "dtype", "")) != row.dtype):
-                    scope.set(name, jnp.zeros(shape, dtype=row.dtype))
+                zeros(name, (self._kinds[kind].num_pages, self.page_size,
+                             row.width), row.dtype)
+        for names in self.state_var_names:
+            for name, st in zip(names, self.seq_state):
+                zeros(name, (self.state_blocks, *map(int, st.shape)),
+                      st.dtype)
 
     # -- modeled bytes ------------------------------------------------------
 
@@ -184,20 +239,53 @@ class KVPool:
                 * sum(pages[k.name] * k.layers
                       for k in self._kinds.values()))
 
+    def state_bytes(self, st, blocks=None):
+        """Device bytes of one declared state tensor over every state
+        layer: resident (``blocks`` None, the trash block included), or
+        of ``blocks`` blocks."""
+        import jax.numpy as jnp
+
+        if blocks is None:
+            blocks = self.state_blocks
+        return (int(np.prod(st.shape)) * jnp.dtype(st.dtype).itemsize
+                * blocks * len(self.state_layers))
+
     def modeled_bytes(self):
         """Device bytes of the resident pool: every declared row tensor
         of every layer (for the dual-int8 rows that is kernels/
-        primitives/int8.py's ``dual_int8_bytes``)."""
-        return sum(self.row_bytes(row) for row in self.rows)
+        primitives/int8.py's ``dual_int8_bytes``) and every declared
+        state tensor of every state layer."""
+        return (sum(self.row_bytes(row) for row in self.rows)
+                + sum(self.state_bytes(st) for st in self.seq_state))
 
     # -- allocation ---------------------------------------------------------
 
     def open_seq(self, seq_id):
+        """Open `seq_id`: empty page lists and, where the lane declares
+        per-sequence state, its ONE state block — raises
+        PoolExhaustedError (nothing opened) when none is free; the
+        caller evicts and retries."""
         if seq_id in self._kind().tables:
             raise ValueError(f"sequence {seq_id!r} already open")
+        st = self._state
+        if st is not None:
+            if not st.free:
+                raise PoolExhaustedError(
+                    f"KV pool out of blocks of kind {st.name!r}: sequence "
+                    f"{seq_id!r} needs 1 but 0 of {st.num_pages - 1} "
+                    f"allocatable blocks are free — evict a sequence or "
+                    f"grow state_blocks")
+            st.tables[seq_id] = [st.take()]
         for k in self._kinds.values():
             k.tables[seq_id] = []
             k.first_live[seq_id] = 0
+
+    def state_block(self, seq_id=None):
+        """The state block `seq_id` holds (the trash block for None: an
+        inactive slot, a warm-up chunk)."""
+        if seq_id is None or self._state is None:
+            return TRASH_PAGE
+        return self._state.tables[seq_id][0]
 
     def ensure_capacity(self, seq_id, n_tokens):
         """Grow `seq_id`'s page table, of every kind, to cover `n_tokens`
@@ -225,12 +313,7 @@ class KVPool:
                         f"{need - len(table)} more (of {need}) but 0 of "
                         f"{k.num_pages - 1} allocatable pages are free "
                         f"— evict a sequence or grow the pool")
-                page = k.free.pop()
-                if page in k.ever_used:
-                    k.reused_allocs += 1
-                k.ever_used.add(page)
-                k.alloc_total += 1
-                table.append(page)
+                table.append(k.take())
         return self._kind().tables[seq_id]
 
     def release(self, seq_id, length):
@@ -258,7 +341,7 @@ class KVPool:
         lists (LIFO).  ``why``: ``end`` (the request finished) or
         ``evict``."""
         n = 0
-        for k in self._kinds.values():
+        for k in self._every.values():
             pages = [p for p in k.tables.pop(seq_id, [])
                      if p != TRASH_PAGE]
             k.first_live.pop(seq_id, None)
@@ -278,7 +361,7 @@ class KVPool:
         """Pages allocated to live sequences: of ``kind``, or of every
         kind together."""
         if kind is not None:
-            return self._kinds[kind].in_use()
+            return self._every[kind].in_use()
         return sum(k.in_use() for k in self._kinds.values())
 
     def padded_table(self, seq_id=None, kind=None):
@@ -294,12 +377,13 @@ class KVPool:
 
     def kind_stats(self):
         """Per kind: pages, pages in use, and the running totals of pages
-        allocated and of pages freed by reason."""
+        allocated and of pages freed by reason.  The ``state`` kind,
+        where declared, counts its blocks under the same keys."""
         return {k.name: {"pages_total": k.num_pages - 1,
                          "pages_in_use": k.in_use(),
                          "alloc_total": k.alloc_total,
                          "freed": dict(k.freed)}
-                for k in self._kinds.values()}
+                for k in self._every.values()}
 
     def stats(self):
         return {

@@ -16,6 +16,21 @@ A model declares
   keeps ONE page list a kind a sequence, so every layer of a kind reads
   through the same page table; a model that declares nothing has the one
   kind ``full`` and the feeds it always had;
+- optionally the **state a sequence owns** in some layers
+  (``seq_state``, ``state_layers``): named tensors of any shape and dtype
+  (``SeqState``) that every token of the sequence overwrites, not rows a
+  token leaves: a linear-attention layer's recurrent state, a short
+  convolution's last inputs.  The pool allocates every state tensor
+  ``[blocks, *shape]`` for each of ``state_layers`` and gives a sequence
+  exactly ONE block, whatever its length, from the time it is opened
+  until it ends or is evicted (the cache kind ``state``, beside the page
+  kinds).  The programs find a sequence's block by its index, one feed
+  more in each executable (``dec_state_block`` [slots],
+  ``pf_state_block`` [1]; inactive slots and warm-up name the trash
+  block 0), and read it as zeros in a sequence's first chunk
+  (``pf_qstart == 0``): nothing clears a block on the host, and an
+  eviction replays from token 0.  A lane that declares no state builds,
+  feeds and compiles exactly what it did;
 - the **two program builders** of the lane's two fixed-shape
   executables, a decode step over the pool's slots and a prefill chunk
   of one sequence, both against the model's own parameter names and the
@@ -43,7 +58,9 @@ from __future__ import annotations
 
 import collections
 
-__all__ = ["CacheRow", "DecodeLane", "DeviceCounter", "POOL_PREFIX",
+__all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
+           "state_var_names", "declare_state_vars",
+           "DecodeLane", "DeviceCounter", "POOL_PREFIX",
            "ImageEncoder", "PreparedImage", "ROW_STAGING",
            "declare_row_staging",
            "FULL", "kind_name", "kind_feed", "kinds_of",
@@ -54,6 +71,9 @@ POOL_PREFIX = "@KVPOOL@"
 
 # one row tensor of the cache: ``width`` values of ``dtype`` a token a layer
 CacheRow = collections.namedtuple("CacheRow", ("name", "width", "dtype"))
+# one tensor of the state a sequence owns in a layer: ``shape`` values of
+# ``dtype`` a sequence (a block), overwritten by every token
+SeqState = collections.namedtuple("SeqState", ("name", "shape", "dtype"))
 # an int32 vector of ``length`` counts that the lane's programs add to in
 # place, under the persistable var ``name``
 DeviceCounter = collections.namedtuple("DeviceCounter", ("name", "length"))
@@ -69,6 +89,11 @@ ROW_STAGING = "@IMGROWS@"
 
 
 FULL = "full"
+# the cache kind of per-sequence state: one block a sequence
+STATE = "state"
+# the feed that names each slot's state block (decode step) and the
+# prefilling sequence's (prefill chunk)
+STATE_FEEDS = {"decode": "dec_state_block", "prefill": "pf_state_block"}
 
 
 def kind_name(window):
@@ -156,6 +181,29 @@ def declare_pool_vars(rows, num_layers, num_pages, page_size,
             pool_var_names(rows, num_layers, prefix))]
 
 
+def state_var_names(states, state_layers, prefix=POOL_PREFIX):
+    """Per state layer (the model's own layer numbers), the pool var
+    name of each declared state tensor, in order."""
+    return [tuple(f"{prefix}{st.name}_l{int(layer)}" for st in states)
+            for layer in state_layers]
+
+
+def declare_state_vars(states, state_layers, num_blocks,
+                       prefix=POOL_PREFIX):
+    """The pool's state vars in the program being built: per state layer
+    one ``[num_blocks, *shape]`` persistable var a declared state tensor
+    (block 0 the trash block).  Returns {layer: (var, ...)}."""
+    from paddle_tpu import fluid
+
+    block = fluid.default_main_program().global_block()
+    return {int(layer): tuple(block.create_var(
+        name=name, shape=[int(num_blocks), *map(int, st.shape)],
+        dtype=st.dtype, persistable=True)
+        for name, st in zip(names, states))
+        for layer, names in zip(
+            state_layers, state_var_names(states, state_layers, prefix))}
+
+
 def declare_row_staging(rows, width, name=ROW_STAGING):
     """The engine's staged image rows in the program being built:
     persistable float32 ``[rows, 1, width]``, a page of one row each, so
@@ -214,7 +262,13 @@ class DecodeLane:
 
     ``cache_rows(pool_dtype)`` -> [CacheRow]: what a token leaves in each
     of ``num_layers`` layers at that storage dtype (raise for a dtype the
-    model has no kernels for).
+    model has no kernels for).  ``num_layers`` counts the layers that
+    leave cache rows: a model whose other layers hold per-sequence state
+    instead numbers its cache layers 0 .. ``num_layers`` - 1 itself.
+    ``seq_state``: ``[SeqState]`` the state a sequence owns in each of
+    ``state_layers`` (the model's own layer numbers); the builders are
+    then handed ``state_blocks=`` (the blocks of every state tensor,
+    trash included) and take the state-block feed (``STATE_FEEDS``).
     ``build_decode_step(pool_slots, num_pages, page_size, max_pages,
     pool_dtype=, attn_force=)`` and ``build_prefill_chunk(chunk_len,
     num_pages, page_size, max_pages, pool_dtype=, attn_force=)`` build
@@ -244,7 +298,8 @@ class DecodeLane:
                  build_decode_step, build_prefill_chunk,
                  pool_dtype="float32", prefill_chunk=None,
                  device_counters=(), book_counters=None,
-                 layer_windows=None, encoder=None):
+                 layer_windows=None, encoder=None, seq_state=(),
+                 state_layers=()):
         self.num_layers = int(num_layers)
         self.max_position = int(max_position)
         self.cache_rows = cache_rows
@@ -255,6 +310,12 @@ class DecodeLane:
         self.device_counters = list(device_counters)
         self.book_counters = book_counters
         self.encoder = encoder
+        self.seq_state = list(seq_state)
+        self.state_layers = [int(n) for n in state_layers]
+        if bool(self.seq_state) != bool(self.state_layers):
+            raise ValueError(
+                "DecodeLane: seq_state and state_layers go together (the "
+                "state tensors, and the layers that own them)")
         self.layer_windows = (None if layer_windows is None
                               else list(layer_windows))
         if (self.layer_windows is not None

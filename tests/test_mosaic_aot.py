@@ -658,3 +658,127 @@ def test_kimi_vl_decode_engine_executables(chip):
             assert row[0] in _aliased_parameters(hlo)
     finally:
         engine.close()
+
+
+# ---------------------------------------------------------------------------
+# Olmo-Hybrid through the decode lane (benchmark/configs/
+# olmo-hybrid-7b-pp4.json): the two delta-rule kernels at the published
+# widths, and the engine's two executables over K/V pages AND per-sequence
+# state blocks
+# ---------------------------------------------------------------------------
+
+_OLMO_PAGES, _OLMO_BLOCKS = 16 * 98 + 1, 18
+_OLMO_STATE = ((_OLMO_BLOCKS, 96, 30 * 192), jnp.float32)
+
+
+def _state_copies(hlo, shape):
+    """Lines of the compiled module that copy or transpose a whole state
+    tensor of ``shape`` (``18,96,5760``)."""
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if re.match(rf"\s*(?:ROOT )?%\S+ = \w+\[{shape}\]\S* "
+                        r"(copy|copy-start|transpose)\(", line)]
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_gated_delta_kernels_at_olmo_widths(chip, form):
+    """30 heads of 96 keys x 192 values over the float32 state tensor
+    [18, 96, 5760]: the step over 16 slots (the tensor aliased, rewritten
+    where it lies) and the chunk over 512 tokens (one block sliced out,
+    solved in sub-chunks of 64, written back).  Neither copies the
+    tensor."""
+    f32 = jnp.float32
+    n = 16 if form == "step" else 512
+    rows = [((n, 30, 96), f32), ((n, 30, 96), f32), ((n, 30, 192), f32),
+            ((n, 30), f32), ((n, 30), f32), _OLMO_STATE]
+    if form == "step":
+        fn = prims.gated_delta_step
+        rows.append(((16,), jnp.int32))
+    else:
+        def fn(q, k, v, g, beta, state, block, fresh):
+            return prims.gated_delta_chunk(q, k, v, g, beta, state,
+                                           block[0], fresh[0])
+        rows += [((1,), jnp.int32), ((1,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in rows]
+    with lowering_for("tpu"):
+        hlo = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile() \
+            .as_text()
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(rf"%gated_delta_{form}[.\d]* = ", hlo)) == 1
+    assert _state_copies(hlo, "18,96,5760") == []
+    assert 5 in _aliased_parameters(hlo)
+
+
+def test_olmo_hybrid_decode_engine_executables(chip):
+    """The prefill chunk and the decode step of Olmo-Hybrid at the
+    benchmark's widths, pool and slots (one period of its two: linear,
+    linear, linear, full): a delta-rule call a linear layer under the
+    names the benchmark's two patterns read, ONE plain paged call whose
+    page table is its first operand (what ``paged_attn_roofline.serve``
+    finds, in the decode step and not in the chunk, and finds nothing
+    else by), and both kinds of cache — K/V pages and per-sequence state
+    blocks — donated, row-major and UNCOPIED."""
+    import json
+    import os
+
+    import ml_dtypes
+
+    from paddle_tpu.models import olmo_hybrid
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-pp4.json")) as f:
+        config = json.load(f)
+    args = dict(config["builder"]["config_args"], num_hidden_layers=4,
+                layer_types=config["layer_types"][:4])
+    cfg = olmo_hybrid.OlmoHybridConfig(**args)
+    lm, lm_start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
+        olmo_hybrid.build_olmo_hybrid_lm(cfg)
+    scope = fluid.Scope()
+    for p in lm.global_block().all_parameters():
+        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                 else np.dtype(p.dtype))
+        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                          tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-olmo",
+        auto_start=False)
+    assert engine.prefill_chunk == 512
+    assert engine.pool.num_pages == _OLMO_PAGES
+    assert engine.pool.state_blocks == _OLMO_BLOCKS
+    gdn = {form: re.compile(harness_json(root, f"gdn_{metric}.serve")
+                            ["pattern"])
+           for form, metric in (("chunk", "chunk_mxu_share"),
+                                ("step", "step_roofline"))}
+    seen_by_roofline = []
+    try:
+        with lowering_for("tpu"):
+            for form, lowered in zip(("chunk", "step"),
+                                     engine.lower(sharding=chip)):
+                compiled = lowered.compile()
+                hlo = compiled.as_text()
+                lines = [line.strip()
+                         for line in _long_hlo(compiled).splitlines()]
+                seen_by_roofline.append(sum(
+                    bool(_roofline_pattern().search(x)) for x in lines))
+                assert sum(bool(gdn[form].search(x)) for x in lines) == 3
+                other = "step" if form == "chunk" else "chunk"
+                assert sum(bool(gdn[other].search(x)) for x in lines) == 0
+                assert _mosaic_calls(hlo) == 3 + 1
+                assert _pool_copies(hlo, _OLMO_PAGES, 128) == []
+                kv = _pool_parameters(hlo, f"{_OLMO_PAGES},128,3840")
+                assert len(kv) == 2                        # K and V
+                state = _pool_parameters(hlo, "18,96,5760")
+                tails = _pool_parameters(hlo, "18,34560")
+                assert len(state) == len(tails) == 3       # a linear layer
+                assert _state_copies(hlo, "18,96,5760") == []
+                assert _state_copies(hlo, "18,34560") == []
+                assert [lay for _, lay in kv + state
+                        if not lay.startswith("{2,1,0")] == []
+                assert {num for num, _ in kv + state + tails} <= \
+                    _aliased_parameters(hlo)
+        assert seen_by_roofline == [0, 1]
+    finally:
+        engine.close()

@@ -46,13 +46,22 @@ __all__ = ["grouped_matmul", "grouped_matmul_reference"]
 # its 2 MiB weight block takes to arrive, so a step is bound by the
 # block's bytes and the padding rows ride for free
 ROW_TILE = 128
-# (tk, tn): a call's bytes do not depend on the block, and neither does
-# its time: at both expert cells' widths (3072 x 3072; 6144 x 2048 up and
-# 2048 x 6144 down), decode rows and a chunk's, blocks of 1 to 4.5 MiB
-# read within 5% of each other with live visits streaming at ~750 GB/s
-# (PERF.md, PR 32; PR 27 read "within 10%" under the dead visits' traffic)
+# (tk, tn), from the shape and the weights' item size.  A grid step costs
+# ~0.3 us before it moves a byte, which is what a 256-KiB block takes to
+# arrive at 819 GB/s: under a megabyte a step streams at about half of
+# the bandwidth (Kimi-VL's 1408 = 11 x 128 had no divisor here but 128:
+# 54% of its bytes' roofline), and blocks of 1 to 4.5 MiB read within 5%
+# of each other at ~750 GB/s (3072 x 3072; 6144 x 2048 and 2048 x 6144:
+# PERF.md, PR 32).  So a side's first divisor below stands wherever the
+# block it gives is a megabyte, and elsewhere the block is the largest
+# that fits: each side a multiple of 128 that divides it or the side
+# whole, which a block's last dimensions may always be
 _K_TILES = (1024, 512, 256, 128)
 _N_TILES = (1024, 512, 256, 128)
+_MIN_BLOCK_BYTES = 1 << 20
+# Mosaic's default scoped limit is 16 MiB and the launch passes none of
+# its own: the rest is room for what the compiler keeps beside the blocks
+_VMEM_BUDGET = 12 << 20
 
 
 def _tile(size, choices):
@@ -60,6 +69,29 @@ def _tile(size, choices):
         if size % c == 0:
             return c
     return size
+
+
+def _sides(size):
+    return [t for t in range(128, size, 128) if size % t == 0] + [size]
+
+
+def _vmem_bytes(tk, tn, itemsize):
+    """Two buffers each of the weight block, the [tm, tk] row block and
+    the [tm, tn] float32 output block, and the accumulator."""
+    return (2 * tk * tn * itemsize + 2 * ROW_TILE * tk * itemsize
+            + 3 * ROW_TILE * tn * 4)
+
+
+def _weight_block(k, n, itemsize):
+    tk, tn = _tile(k, _K_TILES), _tile(n, _N_TILES)
+    if tk * tn * itemsize >= _MIN_BLOCK_BYTES:
+        return tk, tn
+    fits = [(a, b) for a in _sides(k) for b in _sides(n)
+            if _vmem_bytes(a, b, itemsize) <= _VMEM_BUDGET]
+    # of two blocks as large, the one with fewer passes over the
+    # accumulator; a matrix no block of which fits fails in Mosaic as it
+    # did
+    return max(fits, key=lambda s: (s[0] * s[1], s[0]), default=(tk, tn))
 
 
 def _zero_rows_of_no_group(out, group_sizes):
@@ -110,7 +142,7 @@ def _pallas_gmm(lhs, rhs, group_sizes, interpret):
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    tk, tn = _tile(k, _K_TILES), _tile(n, _N_TILES)
+    tk, tn = _weight_block(k, n, jnp.dtype(rhs.dtype).itemsize)
     (offsets, group_ids, tile_ids), visits = make_group_metadata(
         group_sizes=group_sizes.astype(jnp.int32), m=m + pad, tm=tm,
         start_group=jnp.int32(0), num_nonzero_groups=groups,

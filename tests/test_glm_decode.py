@@ -19,6 +19,7 @@ import pytest
 from paddle_tpu import fluid, serving
 from paddle_tpu import observability as obs
 from paddle_tpu.kernels import primitives as prims
+from paddle_tpu.kernels.primitives import grouped
 from paddle_tpu.models import glm
 from paddle_tpu.serving.kv_pool import KVPool
 from paddle_tpu.serving.lane import CacheRow, lane_padded
@@ -656,15 +657,22 @@ def test_topk_select_is_exact(case, force):
 # one block a side (k = 16, n = 24), then three 128-tiles a side under two
 # row tiles, where a grid step no group owns would move a block index:
 # no row at all, empty groups at the front, in the middle and at the end,
-# a group across the row tiles' edge, and both row tiles full
+# a group across the row tiles' edge, and both row tiles full; then a width
+# with no divisor but 128 (11 x 128) on either side, which the block holds
+# whole
 @pytest.mark.parametrize("sizes,k,n", [
     ([3, 0, 10, 5, 2], 16, 24), ([0, 0, 0, 0, 0], 16, 24),
     ([40, 0, 0, 0, 0], 16, 24), ([1, 1, 1, 1, 130], 16, 24),
     ([0, 0, 0, 0, 0], 384, 384), ([0, 0, 40, 30, 0], 384, 384),
     ([50, 0, 0, 60, 0], 384, 384), ([100, 0, 56, 0, 90], 384, 384),
     ([0, 120, 16, 0, 1], 384, 384), ([1, 0, 0, 0, 0], 384, 384),
-    ([0, 0, 0, 0, 256], 384, 384), ([128, 0, 0, 128, 0], 384, 384)])
-def test_grouped_matmul_pallas_against_oracle(sizes, k, n):
+    ([0, 0, 0, 0, 256], 384, 384), ([128, 0, 0, 128, 0], 384, 384),
+    ([70, 0, 130, 56], 1408, 256), ([70, 0, 130, 56], 256, 1408)])
+def test_grouped_matmul_pallas_against_oracle(monkeypatch, sizes, k, n):
+    if k == 384:
+        # a matrix this small is one block: hold it to 128 a side
+        monkeypatch.setattr(grouped, "_weight_block",
+                            lambda k, n, itemsize: (128, 128))
     rng = np.random.RandomState(2)
     m = max(40, sum(sizes) + 7) if k == 16 else 256
     lhs = rng.randn(m, k).astype(np.float32)
@@ -678,5 +686,7 @@ def test_grouped_matmul_pallas_against_oracle(sizes, k, n):
         got = prims.grouped_matmul(jnp.asarray(lhs), jnp.asarray(rhs),
                                    jnp.asarray(sizes, jnp.int32),
                                    force=force)
-        np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
+        # float32 sums in another order: the room grows with their length
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   atol=1e-4 * max(1, k // 256))
         assert (np.asarray(got)[sum(sizes):] == 0.0).all()

@@ -21,8 +21,8 @@ from paddle_tpu.kernels import primitives as prims
 from paddle_tpu.kernels.primitives import grouped
 
 # (rows, K, N, held experts): a decode step's and a chunk's pick rows of
-# trinity-large-ep8 (64 picks pad to one row tile) and glm-5-ep16 (up and
-# down projections), benchmark/configs/
+# trinity-large-ep8 (64 picks pad to one row tile), glm-5-ep16 and
+# kimi-vl-a3b-ep1 (up and down projections), benchmark/configs/
 SHAPES = {
     "trinity-decode": (64, 3072, 3072, 32),
     "trinity-chunk": (2048, 3072, 3072, 32),
@@ -30,7 +30,16 @@ SHAPES = {
     "glm-decode-down": (128, 2048, 6144, 16),
     "glm-chunk-up": (4096, 6144, 2048, 16),
     "glm-chunk-down": (4096, 2048, 6144, 16),
+    "kimi-decode-up": (96, 2048, 1408, 64),
+    "kimi-decode-down": (96, 1408, 2048, 64),
+    "kimi-chunk-up": (3072, 2048, 1408, 64),
+    "kimi-chunk-down": (3072, 1408, 2048, 64),
 }
+# the widths whose first divisor is 1024 on both sides: their launches,
+# and so their compiled kernels, are what they were before the block was
+# sized from the shape
+DIVIDE_BY_1024 = [s for s, (_, k, n, _) in SHAPES.items()
+                  if k % 1024 == 0 and n % 1024 == 0]
 
 
 def _sizes(kind, rows, groups):
@@ -99,7 +108,28 @@ def test_a_visit_no_group_owns_fetches_nothing(monkeypatch, shape, kind):
     assert live == want
     # an all-empty call keeps one (dead) visit: a grid has no empty axis
     assert visits == max(live, 1)
-    assert tiles_k > 1 and tiles_n > 1
-    assert fetches["rhs"] == max(live, 1) * tiles_k * tiles_n
-    assert fetches["lhs"] <= fetches["rhs"]
+    _, tk, tn = spec.in_specs[1].shape
+    assert tk * tn * 2 >= 1 << 20
+    # what the launch holds in VMEM, under Mosaic's default scoped limit
+    # (the contract passes none): two buffers of every block (bfloat16 in,
+    # float32 out) and the scratch
+    blocks = [(b.shape, 2) for b in spec.in_specs] + [
+        (b.shape, 4) for b in spec.out_specs]
+    held = sum(2 * np.prod(shape) * size for shape, size in blocks) + sum(
+        np.prod(v.shape) * np.dtype(v.dtype).itemsize for v in spec.scratch)
+    assert held < 16 << 20
+    if shape in DIVIDE_BY_1024:
+        assert (tk, tn) == (1024, 1024)
+        assert tiles_k > 1 and tiles_n > 1
+    if tiles_k > 1:
+        assert fetches["rhs"] == max(live, 1) * tiles_k * tiles_n
+        assert fetches["lhs"] <= fetches["rhs"]
+    else:
+        # the block holds K whole: its index does not move between two
+        # visits of one group, so an expert whose rows straddle a row
+        # tile is read once a tile of N, not once a visit
+        runs = max(int(np.count_nonzero(sizes)), 1)
+        assert fetches["rhs"] == runs * tiles_n
+        if kind == "dense" and rows > tm:
+            assert live > runs
     assert fetches["out"] <= max(live, 1) * tiles_n

@@ -362,11 +362,15 @@ def test_dsa_kernels_at_glm5_widths(chip, b, t):
 
 @pytest.mark.parametrize("groups,rows,k,n", [
     (16, 128, 6144, 2048), (16, 4096, 6144, 2048), (16, 128, 2048, 6144),
-    (16, 4096, 2048, 6144), (32, 128, 3072, 3072), (32, 2048, 3072, 3072)])
+    (16, 4096, 2048, 6144), (32, 128, 3072, 3072), (32, 2048, 3072, 3072),
+    (64, 128, 2048, 1408), (64, 3072, 2048, 1408), (64, 128, 1408, 2048),
+    (64, 3072, 1408, 2048)])
 def test_grouped_matmul_at_glm5_widths(chip, groups, rows, k, n):
     """The expert layer's product over GLM-5's 16 held experts (a decode
-    step's 128 pick rows and a chunk's 4096) and Trinity's 32 (128 and
-    2048), the grid's visit extent a traced number."""
+    step's 128 pick rows and a chunk's 4096), Trinity's 32 (128 and
+    2048) and Kimi-VL's 64 (128 and 3072; 1408 wide, so a block holds
+    that side whole: 2.75 MiB and ~8 MiB of VMEM), the grid's visit
+    extent a traced number."""
     hlo = _compile(lambda x, w, g: prims.grouped_matmul(x, w, g), chip,
                    ((rows, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16),
                    ((groups,), jnp.int32))

@@ -1423,7 +1423,8 @@ def moe_ffn(x, num_experts, d_ff, top_k=2, act="gelu", param_attr=None,
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start,
-                    sm_scale=None, force=None, name=None, window=None):
+                    sm_scale=None, force=None, name=None, window=None,
+                    sinks=None):
     """Attention of q [B, n_heads, T, d] against pool K/V
     [num_pages, page_size, n_kv_heads*d] (n_kv_heads = n_heads, or a
     divisor of it: grouped-query heads) read THROUGH a per-sequence page
@@ -1431,7 +1432,9 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start,
     kernels/paged_attention.py — Pallas on TPU, lax gather reference on
     CPU).  Query i of row b attends global key positions
     j <= q_start[b] + i and, with ``window``, j > q_start[b] + i -
-    window."""
+    window.  The V pool may hold its heads at another width d_v (the
+    output is [B, n_heads, T, d_v]); ``sinks`` [n_heads] float32 joins
+    each head's softmax as one more column, which carries no value."""
     helper = LayerHelper("paged_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     attrs = {}
@@ -1441,11 +1444,11 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start,
         attrs["sm_scale"] = float(sm_scale)
     if force is not None:
         attrs["force"] = force
-    helper.append_op("paged_attention",
-                     inputs={"Q": [q], "KPages": [k_pages],
-                             "VPages": [v_pages],
-                             "PageTable": [page_table],
-                             "QStart": [q_start]},
+    inputs = {"Q": [q], "KPages": [k_pages], "VPages": [v_pages],
+              "PageTable": [page_table], "QStart": [q_start]}
+    if sinks is not None:
+        inputs["Sinks"] = [sinks]
+    helper.append_op("paged_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out
 
@@ -1611,11 +1614,15 @@ def rope_interleaved(x, pos, theta, rotary_dim, name=None):
                     {"theta": float(theta), "rotary_dim": int(rotary_dim)})
 
 
-def rope_half(x, pos, theta, name=None):
+def rope_half(x, pos, theta, rotary_dim=None, name=None):
     """Rotary embedding in the ``rotate_half`` form over the whole last
-    dimension of x [B, T, H, d]; pos [B, T] (ops/gqa_ops.py)."""
+    dimension of x [B, T, H, d], or over its first ``rotary_dim``
+    entries alone; pos [B, T] (ops/gqa_ops.py)."""
+    attrs = {"theta": float(theta)}
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
     return _out_f32(LayerHelper("rope_half", name=name), "rope_half",
-                    {"X": [x], "Pos": [pos]}, {"theta": float(theta)})
+                    {"X": [x], "Pos": [pos]}, attrs)
 
 
 def sigmoid_gate(x, gate, name=None):

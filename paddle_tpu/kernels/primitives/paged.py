@@ -62,12 +62,33 @@ which kind it serves (``paged_attention``, ``.._grouped``,
 and form counters.  Plain multi-head attention over the whole context
 (g = 1, no window) traces exactly what it did.
 
+**Value heads narrower than key heads, and a sink** (PR 41).  The V
+pool may hold its heads at another width than the K pool
+(``n_kv x d_v`` lanes beside ``n_kv x d``; the output is d_v wide), and
+``sinks`` [n_heads] float32 joins every query's softmax as one more
+column that carries no value: ``p_t = exp(s_t - m) / (sum_u exp(s_u -
+m) + exp(b_h - m))`` with ``m`` the largest of the scores and ``b_h``.
+Either takes the asymmetric form of the grouped bodies
+(``.._asym``, ``.._sink`` in the kernel's name).  The decode row reads
+its pages as the grouped row does; its queries arrive laid out on their
+K/V head's lanes already, so no lane of the row is cut at a head's
+edge.  The chunk reads the K pool in LANE BLOCKS of whole 128-lane
+tiles — two heads of 192 as one block of 384 — and scores each head of
+the block against the aligned tiles that cover it (lanes 0..255 for the
+first, 128..383 for the second, the query zero outside its own lanes:
+the products are those of a 192-wide head, at the two MXU passes a
+192-wide contraction takes anyway), the V pool in blocks of the same
+heads.  The sink is the state the online softmax STARTS from (m = b_h,
+l = 1), so a step scores nothing more.  The pool is read as stored:
+nothing pads a head to 256 lanes.
+
 Shapes:
   q           [B, n_heads, T, d]   T = 1 (decode step) or the prefill
                                    chunk length
   k/v_pages   [num_pages, page_size, n_kv_heads * d] — heads side by
               side in the lane dimension (head h is lanes h*d..(h+1)*d);
-              n_kv_heads divides n_heads.
+              n_kv_heads divides n_heads.  v_pages may be
+              [.., n_kv_heads * d_v] with d_v != d.
               The pool is stored, written and read in THIS shape and
               no other: its default TPU layout is row-major with
               (8, 128) tiles of (page_size, n_heads*d), which is what
@@ -107,31 +128,31 @@ __all__ = ["paged_attention", "paged_attention_reference",
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
-                              sm_scale=None, window=None):
+                              sm_scale=None, window=None, sinks=None):
     """Materializing XLA implementation: CPU fallback + numerics oracle.
 
     Mirrors the composed attention path's op spelling (matmul — scale —
     -1e9 mask — jax.nn.softmax — matmul) so greedy decode through the
     pool is comparable with the whole-sequence program token for
-    token.  Grouped-query pools and ``window`` as ``paged_attention``."""
+    token.  Grouped-query pools, ``window``, V heads of another width
+    and ``sinks`` as ``paged_attention``."""
     b, n, t, d = q.shape
-    n_kv = _check_pool_shapes("paged_attention", q, grouped=True,
-                              k_pages=k_pages, v_pages=v_pages)
+    n_kv, d_v = _kv_heads("paged_attention", q, k_pages, v_pages)
     page_size = k_pages.shape[1]
     max_pages = page_table.shape[1]
     l_max = max_pages * page_size
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
 
-    def gathered(pages):
+    def gathered(pages, width):
         # the heads come apart on the gathered pages, never on the pool
-        g = pages[page_table]                      # [B, MAXP, PGS, n_kv*d]
-        g = g.reshape(b, l_max, n_kv, d)
-        g = jnp.transpose(g, (0, 2, 1, 3))         # [B, n_kv, L, d]
+        g = pages[page_table]                      # [B, MAXP, PGS, n_kv*w]
+        g = g.reshape(b, l_max, n_kv, width)
+        g = jnp.transpose(g, (0, 2, 1, 3))         # [B, n_kv, L, w]
         # query head j reads K/V head j // (n / n_kv)
         return g if n_kv == n else jnp.repeat(g, n // n_kv, axis=1)
 
-    k = gathered(k_pages)
-    v = gathered(v_pages)
+    k = gathered(k_pages, d)
+    v = gathered(v_pages, d_v)
     s = jnp.matmul(q.astype(jnp.float32),
                    jnp.swapaxes(k.astype(jnp.float32), -1, -2)) * scale
     kpos = jax.lax.broadcasted_iota(jnp.int32, (b, n, t, l_max), 3)
@@ -141,7 +162,13 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
     if window is not None:
         visible &= kpos > qpos - int(window)
     s = jnp.where(visible, s, jnp.asarray(NEG_INF, s.dtype))
-    p = jax.nn.softmax(s, axis=-1)
+    if sinks is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:   # one more column a head, which carries no value
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32)[None, :, None, None], (b, n, t, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, sink], axis=-1),
+                           axis=-1)[..., :l_max]
     return jnp.matmul(p, v.astype(jnp.float32)).astype(q.dtype)
 
 
@@ -243,6 +270,23 @@ def _check_pool_shapes(op, q, scales=(), grouped=False, **pools):
     return n_kv
 
 
+def _kv_heads(op, q, k_pages, v_pages):
+    """(K/V heads, width of a V head) of the grouped pools: the V pool
+    may hold its heads at another width than the K pool (and q)."""
+    n, d = q.shape[1], q.shape[3]
+    if (k_pages.ndim != 3 or v_pages.ndim != 3
+            or k_pages.shape[2] == v_pages.shape[2]):
+        return _check_pool_shapes(op, q, grouped=True, k_pages=k_pages,
+                                  v_pages=v_pages), d
+    n_kv = _check_pool_shapes(op, q, grouped=True, k_pages=k_pages)
+    if v_pages.shape[2] % n_kv or k_pages.shape[:2] != v_pages.shape[:2]:
+        raise ValueError(
+            f"{op}: K pool {tuple(k_pages.shape)} holds {n_kv} heads of "
+            f"{d}, but V pool {tuple(v_pages.shape)} is not as many "
+            f"pages of as many whole heads")
+    return n_kv, v_pages.shape[2] // n_kv
+
+
 def _online_softmax_step(s, v, acc_ref, m_ref, l_ref, p_dtype=None):
     """One kv-block update of the running (max, sum, acc) state — the
     shared online-softmax spelling of every attention primitive.
@@ -274,6 +318,15 @@ def _init_state(acc_ref, m_ref, l_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _init_sink(sink, m_ref, l_ref):
+    """The state a softmax with a sink starts from: the sink logit
+    ``sink`` (the state's shape) is a column already summed, so m = b_h
+    and l = exp(b_h - m) = 1.  A sink of -inf leaves the plain start."""
+    m = jnp.maximum(sink, NEG_INF)
+    m_ref[...] = m
+    l_ref[...] = jnp.exp(sink - m)
 
 
 def _paged_body(page_table_ref, q_start_ref, q_ref, o_ref, acc_ref, m_ref,
@@ -344,7 +397,8 @@ def _p_dtype(pool_dtype):
 def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
                         o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref,
                         l_ref, *, d, n_sub, page_size, n_rows, sm_scale,
-                        heads_per_kv=None, window=None):
+                        heads_per_kv=None, window=None, d_v=None,
+                        sink_ref=None):
     """The heads-batched body of a decode row (T == 1), one grid step a
     row: q_ref is the row [1, n*d] of all heads' queries, the state
     [rows, ...] holds head h in row h (rows = n rounded up to a sublane
@@ -358,13 +412,21 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
     The grouped form (``heads_per_kv`` = g, not None): q_ref and o_ref
     are [rows, d], query head h a row of its own on the lanes of K/V
     head h // g.  With ``window`` a row's groups count from the first
-    page its window reaches, and keys below the window are masked."""
+    page its window reaches, and keys below the window are masked.
+
+    The asymmetric form (``d_v`` not None): the V pool's heads are d_v
+    wide, so the state is [rows, n_kv*d_v] and o_ref [rows, d_v]; q_ref
+    is [rows, n_kv*d], each query laid on its K/V head's lanes by the
+    launch (zeros elsewhere), in the pool's dtype.  ``sink_ref``
+    [rows, 128] float32 (every lane the row's sink logit) is the state
+    the softmax starts from: m = b_h, l = exp(b_h - m) = 1."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bi = pl.program_id(0)
     rows, width = acc_ref.shape
     keys = n_sub * page_size
+    dv = d if d_v is None else d_v
 
     def first_page(row):
         """The first page ``row``'s window reaches."""
@@ -416,22 +478,28 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
         first_key = first_page(bi) * page_size
     first_slot = slot_ref[0]
     _init_state(acc_ref, m_ref, l_ref)
+    if sink_ref is not None:
+        _init_sink(sink_ref[...], m_ref, l_ref)
 
-    # [rows, n*d] bool: the d lanes of row h's own head
-    first_lane = d * jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
-    if heads_per_kv is not None:    # row h on the lanes of head h // g
-        first_lane = d * jax.lax.div(jax.lax.broadcasted_iota(
-            jnp.int32, (rows, width), 0), heads_per_kv)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
-    own_lanes = (lane >= first_lane) & (lane < first_lane + d)
-    if heads_per_kv is None:
-        q = jnp.where(own_lanes, jnp.broadcast_to(
-            q_ref[0].astype(jnp.float32), (rows, width)), 0.0)
+    if d_v is not None:
+        q = q_ref[0]
     else:
-        q = jnp.where(own_lanes, jnp.tile(
-            q_ref[0].astype(jnp.float32), (1, width // d)), 0.0)
-    if k_buf.dtype == jnp.bfloat16:
-        q = q.astype(jnp.bfloat16)
+        # [rows, n*d] bool: the d lanes of row h's own head
+        first_lane = d * jax.lax.broadcasted_iota(
+            jnp.int32, (rows, width), 0)
+        if heads_per_kv is not None:  # row h on the lanes of head h // g
+            first_lane = d * jax.lax.div(jax.lax.broadcasted_iota(
+                jnp.int32, (rows, width), 0), heads_per_kv)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        own_lanes = (lane >= first_lane) & (lane < first_lane + d)
+        if heads_per_kv is None:
+            q = jnp.where(own_lanes, jnp.broadcast_to(
+                q_ref[0].astype(jnp.float32), (rows, width)), 0.0)
+        else:
+            q = jnp.where(own_lanes, jnp.tile(
+                q_ref[0].astype(jnp.float32), (1, width // d)), 0.0)
+        if k_buf.dtype == jnp.bfloat16:
+            q = q.astype(jnp.bfloat16)
 
     def score_group(g, carry):
         slot = jax.lax.rem(first_slot + g, 2)
@@ -472,12 +540,19 @@ def _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, k_hbm, v_hbm,
     else:   # row h's d lanes, from under its K/V head
         normed = acc_ref[...] / l_safe[:, :1]
         kv_head = jax.lax.div(jax.lax.broadcasted_iota(
-            jnp.int32, (rows, d), 0), heads_per_kv)
-        out = jnp.zeros((rows, d), jnp.float32)
-        for j in range(width // d):
+            jnp.int32, (rows, dv), 0), heads_per_kv)
+        out = jnp.zeros((rows, dv), jnp.float32)
+        for j in range(width // dv):
             out = out + jnp.where(kv_head == j,
-                                  normed[:, j * d:(j + 1) * d], 0.0)
+                                  normed[:, j * dv:(j + 1) * dv], 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _paged_heads_sink_kernel(page_table_ref, q_start_ref, q_ref, sink_ref,
+                             *refs, **kw):
+    """``_paged_heads_kernel`` with the sinks as one operand more."""
+    _paged_heads_kernel(page_table_ref, q_start_ref, q_ref, *refs,
+                        sink_ref=sink_ref, **kw)
 
 
 def _first_step(start, window, keys):
@@ -592,49 +667,72 @@ def _book_form(primitive, form, pages_per_step):
 
 
 def _heads_batched_call(name, q, pools, page_table, q_start, scale,
-                        interpret, g, heads_per_kv=None, window=None):
+                        interpret, g, heads_per_kv=None, window=None,
+                        d_v=None, sinks=None):
     """A decode row's launch (T == 1): grid (B,), the pools unblocked.
     ``heads_per_kv`` (not None) is the grouped form: q and the output
-    ride [rows, d], a query head a row."""
+    ride [rows, d], a query head a row.  ``d_v`` (not None) is the
+    asymmetric form: q rides [rows, n_kv*d], laid on its K/V head's
+    lanes here, the output [rows, d_v]; ``sinks`` [n] one operand
+    more."""
     b, n, _, d = q.shape
     page_size = pools[0].shape[1]
-    width = pools[0].shape[2]
     rows = -(-n // _SUBLANES) * _SUBLANES
 
     def row_map(bi, pt, qs):
         return (bi, 0, 0)
 
+    kernel, extra, extra_specs = _paged_heads_kernel, (), []
     if heads_per_kv is None:
         q_block, q_rows = (1, 1, n * d), q.reshape(b, 1, n * d)
+        o_block = q_block
         kw = {}
     else:
-        q_block = (1, rows, d)
+        q_block = o_block = (1, rows, d)
         q_rows = jnp.pad(q.reshape(b, n, d), ((0, 0), (0, rows - n), (0, 0)))
         kw = {"heads_per_kv": heads_per_kv, "window": window}
+    if d_v is not None:
+        n_kv = n // heads_per_kv
+        # row h on the lanes of K/V head h // g, zeros elsewhere
+        own = (jnp.arange(rows)[:, None] // heads_per_kv
+               == jnp.arange(n_kv)[None, :])
+        q_rows = jnp.where(own[None, :, :, None], q_rows[:, :, None, :],
+                           0.0).reshape(b, rows, n_kv * d)
+        if pools[0].dtype == jnp.bfloat16:
+            q_rows = q_rows.astype(jnp.bfloat16)
+        q_block, o_block = (1, rows, n_kv * d), (1, rows, d_v)
+        kw["d_v"] = d_v
+    if sinks is not None:
+        kernel = _paged_heads_sink_kernel
+        extra = (jnp.broadcast_to(jnp.pad(
+            sinks.astype(jnp.float32), (0, rows - n),
+            constant_values=NEG_INF)[:, None], (rows, 128)),)
+        extra_specs = [Block((rows, 128), lambda bi, pt, qs: (0, 0))]
     spec = contract.make_spec(
         name,
         grid=(b,),
-        in_specs=[Block(q_block, row_map)]
+        in_specs=[Block(q_block, row_map)] + extra_specs
         + [Block(None, None) for _ in pools],
-        out_specs=[Block(q_block, row_map)],
-        out_shape=[((b,) + q_block[1:], q.dtype)],
-        scratch=[Vmem((2, g * page_size, width), x.dtype) for x in pools]
+        out_specs=[Block(o_block, row_map)],
+        out_shape=[((b,) + o_block[1:], q.dtype)],
+        scratch=[Vmem((2, g * page_size, x.shape[2]), x.dtype)
+                 for x in pools]
         + [DmaSem((2, len(pools))), Smem((1,), jnp.int32),
-           Vmem((rows, width), jnp.float32),
+           Vmem((rows, pools[-1].shape[2]), jnp.float32),
            Vmem((rows, 128), jnp.float32),
            Vmem((rows, 128), jnp.float32)],
         num_scalar_prefetch=2,
         interpret=interpret,
     )
     out = contract.primitive_call(
-        functools.partial(_paged_heads_kernel, d=d, n_sub=g,
+        functools.partial(kernel, d=d, n_sub=g,
                           page_size=page_size, n_rows=b, sm_scale=scale,
                           **kw),
         spec, page_table.astype(jnp.int32), q_start.astype(jnp.int32),
-        q_rows, *pools)                     # T == 1: the same bytes
+        q_rows, *extra, *pools)             # T == 1: the same bytes
     if heads_per_kv is not None:
         out = out[:, :n]
-    return out.reshape(b, n, 1, d)
+    return out.reshape(b, n, 1, d if d_v is None else d_v)
 
 
 def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret, g,
@@ -697,6 +795,191 @@ def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret, g,
     out = out.reshape(b, n_kv, tiles, heads_per_kv, tq, d).transpose(
         0, 1, 3, 2, 4, 5).reshape(b, n, tp, d)
     return out[:, :, :t, :]
+
+
+def _lane_block_heads(d, d_v):
+    """How the asymmetric chunk body reads K/V heads of width ``d`` /
+    ``d_v`` from pools stored with the heads side by side: P heads make
+    one LANE BLOCK of whole 128-lane tiles (two heads of 192: 384
+    lanes), and head h of a block is scored against the aligned tiles
+    that cover it.  Returns (P, query width, [(first K lane of the
+    tiles, their width, where the head starts inside them, first V
+    lane)] a head)."""
+    p = 128 // int(np.gcd(d, 128))
+    heads = []
+    for h in range(p):
+        first = h * d // 128 * 128
+        inner = h * d - first
+        heads.append((first, -(-(inner + d) // 128) * 128, inner, h * d_v))
+    return p, max(w for _, w, _, _ in heads), heads
+
+
+def _paged_lane_block_kernel(page_table_ref, q_start_ref, q_ref, *refs,
+                             n_sub, keys, tq, heads_per_kv, heads, d_v,
+                             n_steps, window, sm_scale, sink):
+    """The asymmetric body of a chunk (T > 1): one grid step is one lane
+    block of K/V heads (``heads``, ``_lane_block_heads``), one tile of
+    ``tq`` queries and one step of ``keys`` keys.  q_ref
+    [P, g*tq, width]: row r*tq + i of head h is query i of the r-th
+    query head of the block's h-th K/V head, laid where the head lies
+    inside its tiles, zeros elsewhere; o_ref [P, g*tq, d_v].  With
+    ``sink`` the first operand behind q is [P, g8, 128] float32: row r
+    of head h holds that query head's sink logit."""
+    from jax.experimental import pallas as pl
+
+    if sink:
+        sink_ref, refs = refs[0], refs[1:]
+    k_refs, v_refs = refs[:n_sub], refs[n_sub:2 * n_sub]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * n_sub:]
+    bi, qi, pi = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    rows = heads_per_kv * tq
+
+    @pl.when(pi == 0)
+    def _init():
+        _init_state(acc_ref, m_ref, l_ref)
+        if sink:
+            for h in range(len(heads)):
+                for r in range(heads_per_kv):
+                    at = pl.ds(r * tq, tq)
+                    _init_sink(jnp.broadcast_to(
+                        sink_ref[0, h, r:r + 1, :], (tq, 128)),
+                        m_ref.at[h, at], l_ref.at[h, at])
+
+    start = q_start_ref[bi] + qi * tq      # the tile's first query
+    step = pi if window is None else _first_step(start, window, keys) + pi
+
+    @pl.when(step * keys <= start + tq - 1)
+    def _step():
+        kpos = step * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        qpos = start + jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 0), tq)
+        visible = kpos <= qpos
+        if window is not None:
+            visible &= kpos > qpos - window
+        k, v = _mxu(_step_pages(k_refs)), _mxu(_step_pages(v_refs))
+        for h, (first, width, _, v_first) in enumerate(heads):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, 0, h, :, :width].astype(k.dtype),
+                k[:, first:first + width], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [rows, keys]
+            s = jnp.where(visible, s * sm_scale, NEG_INF)
+            _online_softmax_step(s, v[:, v_first:v_first + d_v],
+                                 acc_ref.at[h], m_ref.at[h], l_ref.at[h],
+                                 p_dtype=_p_dtype(v.dtype))
+
+    @pl.when(pi == n_steps - 1)
+    def _finish():
+        for h in range(len(heads)):
+            l = l_ref[h]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, 0, 0, h] = (acc_ref[h] / l_safe[:, :1]).astype(
+                o_ref.dtype)
+
+
+def _lane_block_call(name, q, pools, page_table, q_start, scale, interpret,
+                     g, heads_per_kv, window, d_v, sinks):
+    """The asymmetric chunk's launch (T > 1): grid (B, lane blocks of
+    K/V heads, query tiles, steps), ``_kv_head_call``'s with a lane
+    block of P heads where that has one head."""
+    b, n, t, d = q.shape
+    page_size = pools[0].shape[1]
+    n_kv = n // heads_per_kv
+    p, q_width, heads = _lane_block_heads(d, d_v)
+    if n_kv % p or d_v % 128:
+        raise ValueError(
+            f"{name}: the Pallas form reads {p} K heads of {d} as one "
+            f"lane block and V heads in whole 128-lane tiles; {n_kv} K/V "
+            f"heads with V heads of {d_v} fit neither (the XLA reference "
+            f"form takes any widths)")
+    blocks = n_kv // p
+    max_pages = page_table.shape[1]
+    keys = g * page_size
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    tq = _query_tile(tp, heads_per_kv * p)
+    tiles = tp // tq
+    steps = -(-max_pages // g)
+    if window is not None:  # a window and a tile span so many steps
+        steps = min(steps, -(-(int(window) + tq - 1) // keys) + 1)
+    pad = (-(-max_pages // g) + steps) * g - max_pages
+    page_table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
+    if tp != t:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
+    if pools[0].dtype == jnp.bfloat16:
+        q = q.astype(jnp.bfloat16)
+    # [B, blocks, tiles, P, g*tq, d]: row r*tq + i of head h of a block
+    # is query i of that K/V head's r-th query head ...
+    rows = heads_per_kv * tq
+    q = q.reshape(b, blocks, p, heads_per_kv, tiles, tq, d).transpose(
+        0, 1, 4, 2, 3, 5, 6).reshape(b, blocks, tiles, p, rows, d)
+    # ... laid where the head lies inside the tiles it is scored against
+    q = jnp.stack([jnp.pad(q[:, :, :, h], ((0, 0),) * 4 + (
+        (inner, q_width - inner - d),))
+        for h, (_, _, inner, _) in enumerate(heads)], axis=3)
+
+    def q_map(bi, hj, qi, pi, pt, qs):
+        return (bi, hj, qi, 0, 0, 0)
+
+    def kv_map(j):
+        def index(bi, hj, qi, pi, pt, qs):
+            step = pi if window is None else _first_step(
+                qs[bi] + qi * tq, window, keys) + pi
+            return (pt[bi, step * g + j], 0, hj)
+        return index
+
+    extra, extra_specs = (), []
+    if sinks is not None:
+        g8 = -(-heads_per_kv // _SUBLANES) * _SUBLANES
+        rows_of = jnp.pad(
+            sinks.astype(jnp.float32).reshape(blocks, p, heads_per_kv),
+            ((0, 0), (0, 0), (0, g8 - heads_per_kv)),
+            constant_values=NEG_INF)
+        extra = (jnp.broadcast_to(rows_of[..., None],
+                                  (blocks, p, g8, 128)),)
+        extra_specs = [Block((1, p, g8, 128),
+                             lambda bi, hj, qi, pi, pt, qs: (hj, 0, 0, 0))]
+    spec = contract.make_spec(
+        name,
+        grid=(b, blocks, tiles, steps),
+        in_specs=[Block((1, 1, 1, p, rows, q_width), q_map)] + extra_specs
+        + [Block((1, page_size, p * w), kv_map(j))
+           for w in (d, d_v) for j in range(g)],
+        out_specs=[Block((1, 1, 1, p, rows, d_v), q_map)],
+        out_shape=[((b, blocks, tiles, p, rows, d_v), q.dtype)],
+        scratch=[Vmem((p, rows, d_v), jnp.float32),
+                 Vmem((p, rows, 128), jnp.float32),
+                 Vmem((p, rows, 128), jnp.float32)],
+        num_scalar_prefetch=2,
+        interpret=interpret,
+    )
+    out = contract.primitive_call(
+        functools.partial(_paged_lane_block_kernel, n_sub=g, keys=keys,
+                          tq=tq, heads_per_kv=heads_per_kv,
+                          heads=tuple(heads), d_v=d_v, n_steps=steps,
+                          window=window, sm_scale=scale,
+                          sink=sinks is not None),
+        spec, page_table, q_start.astype(jnp.int32), q, *extra,
+        *[x for x in pools for _ in range(g)])
+    out = out.reshape(b, blocks, tiles, p, heads_per_kv, tq, d_v).transpose(
+        0, 1, 3, 4, 2, 5, 6).reshape(b, n, tp, d_v)
+    return out[:, :, :t, :]
+
+
+def _pallas_paged_asym(q, k_pages, v_pages, page_table, q_start, scale,
+                       interpret, name, heads_per_kv, window, d_v, sinks):
+    """The asymmetric form's launches: a decode row through the
+    heads-batched body, a chunk through the lane-block body."""
+    pools = (k_pages, v_pages)
+    g = _pages_per_step(name, q, k_pages.shape[1], page_table.shape[1],
+                        pools)
+    if q.shape[2] == 1:
+        _book_form(name, "heads_batched", g)
+        return _heads_batched_call(name, q, pools, page_table, q_start,
+                                   scale, interpret, g, heads_per_kv,
+                                   window, d_v, sinks)
+    _book_form(name, "kv_head", g)
+    return _lane_block_call(name, q, pools, page_table, q_start, scale,
+                            interpret, g, heads_per_kv, window, d_v, sinks)
 
 
 def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
@@ -783,35 +1066,46 @@ def _pallas_paged(q, k_pages, v_pages, page_table, q_start, scale,
                        window=window)
 
 
-def kernel_name(heads_per_kv, window):
+def kernel_name(heads_per_kv, window, asym=False, sink=False):
     """What the kernel that serves this kind of layer is called, in the
     HLO and on the dispatch and form counters."""
     return ("paged_attention" + ("_grouped" if heads_per_kv > 1 else "")
-            + ("" if window is None else "_window"))
+            + ("" if window is None else "_window")
+            + ("_asym" if asym else "") + ("_sink" if sink else ""))
 
 
 def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
-                    sm_scale=None, force=None, window=None):
+                    sm_scale=None, force=None, window=None, sinks=None):
     """Attention of q [B, n, T, d] against pool K/V read through
     `page_table` [B, max_pages]; query i of row b attends global key
     positions j <= q_start[b] + i and, with a static ``window``,
     j > q_start[b] + i - window.  The pools hold n or any divisor of n
-    K/V heads (grouped-query attention).
+    K/V heads (grouped-query attention); the V pool's heads may be of
+    another width d_v than the K pool's (the output is then d_v wide).
+    ``sinks`` [n] float32: one logit a query head that joins its softmax
+    as a column without a value.
 
     force: None → Pallas on TPU, XLA reference elsewhere; "pallas" →
     Pallas (interpret mode off-TPU, for tests); "reference" → XLA."""
     n, d = q.shape[1], q.shape[-1]
     scale = float(sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
-    n_kv = _check_pool_shapes("paged_attention", q, grouped=True,
-                              k_pages=k_pages, v_pages=v_pages)
-    if k_pages.dtype != v_pages.dtype or k_pages.shape != v_pages.shape:
+    n_kv, d_v = _kv_heads("paged_attention", q, k_pages, v_pages)
+    if k_pages.dtype != v_pages.dtype or (
+            d_v == d and k_pages.shape != v_pages.shape):
         raise ValueError(
             f"paged_attention: K pool {k_pages.dtype}{k_pages.shape} != V "
             f"pool {v_pages.dtype}{v_pages.shape} — the pool must be one "
             f"dtype and one shape")
+    if sinks is not None and sinks.shape != (n,):
+        raise ValueError(f"paged_attention: sinks {sinks.shape} is not one "
+                         f"logit for each of {n} query heads")
     window = None if window is None else int(window)
-    name = kernel_name(n // n_kv, window)
+    name = kernel_name(n // n_kv, window, d_v != d, sinks is not None)
     mode, interpret = contract.resolve_mode(name, force)
+    if mode == "pallas" and (d_v != d or sinks is not None):
+        return _pallas_paged_asym(q, k_pages, v_pages, page_table, q_start,
+                                  scale, interpret, name, n // n_kv, window,
+                                  d_v, sinks)
     if mode == "pallas":
         # plain multi-head attention over the whole context keeps the
         # launch it had; anything else takes the grouped form
@@ -820,7 +1114,8 @@ def paged_attention(q, k_pages, v_pages, page_table, q_start, *,
                              scale, interpret, name,
                              None if plain else n // n_kv, window)
     return paged_attention_reference(q, k_pages, v_pages, page_table,
-                                     q_start, sm_scale=scale, window=window)
+                                     q_start, sm_scale=scale, window=window,
+                                     sinks=sinks)
 
 
 # ---------------------------------------------------------------------------

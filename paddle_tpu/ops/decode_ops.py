@@ -93,20 +93,21 @@ def _kv_cache_write_pages(ctx, pages, new, page_idx, attrs):
 
 
 @simple_op("paged_attention",
-           ["Q", "KPages", "VPages", "PageTable", "QStart"], ["Out"],
-           grad=None)
-def _paged_attention(ctx, q, k_pages, v_pages, page_table, q_start,
+           ["Q", "KPages", "VPages", "PageTable", "QStart", "Sinks"],
+           ["Out"], optional=("Sinks",), grad=None)
+def _paged_attention(ctx, q, k_pages, v_pages, page_table, q_start, sinks,
                      attrs):
     """Attention of q [B, n, T, d] against the pool through the page
     table — kernels/primitives/paged.py (Pallas on TPU, lax gather
     reference on CPU; attrs["force"] pins an implementation,
-    attrs["window"] bounds the keys from below)."""
+    attrs["window"] bounds the keys from below; ``Sinks`` [n] joins
+    each head's softmax as a column without a value)."""
     from paddle_tpu.kernels import primitives as _prims
 
     return _prims.paged_attention(
         q, k_pages, v_pages, page_table, q_start,
         sm_scale=attrs.get("sm_scale"), force=attrs.get("force"),
-        window=attrs.get("window"))
+        window=attrs.get("window"), sinks=sinks)
 
 
 # ---------------------------------------------------------------------------

@@ -259,6 +259,16 @@ def _m_kind_pages_in_use():
         labels=("engine", "kind"))
 
 
+def _m_kind_bytes_in_use():
+    from paddle_tpu import observability as obs
+
+    return obs.gauge(
+        "pt_kv_bytes_in_use",
+        "Device bytes the pages (state: blocks) in use hold, by cache "
+        "kind, each kind at its own rows' widths over its own layers",
+        labels=("engine", "kind"))
+
+
 def _m_pages_alloc():
     from paddle_tpu import observability as obs
 
@@ -626,15 +636,21 @@ class DecodeEngine:
         self._queue_wait = _m_queue_wait().labels(engine=e)
         self._ttft = _m_ttft().labels(engine=e)
         self._token_gap = _m_token_gap().labels(engine=e)
+        # a gauge a row NAME: rows declared by kind (lane.py) may carry
+        # one name at a width a kind, and their bytes add up
         self._cache_bytes = {
-            row: _m_cache_bytes().labels(engine=e, row=row.name)
-            for row in self.pool.rows}
+            name: (_m_cache_bytes().labels(engine=e, row=name),
+                   [r for r in self.pool.rows if r.name == name])
+            for name in dict.fromkeys(r.name for r in self.pool.rows)}
         self._state_bytes = {
             st: _m_cache_bytes().labels(engine=e, row=st.name)
             for st in self.pool.seq_state}
         kinds = list(self.pool.kind_stats())  # the state kind with them
         self._kind_pages = {
             k: _m_kind_pages_in_use().labels(engine=e, kind=k)
+            for k in kinds}
+        self._kind_bytes = {
+            k: _m_kind_bytes_in_use().labels(engine=e, kind=k)
             for k in kinds}
         self._kind_alloc = {
             k: _m_pages_alloc().labels(engine=e, kind=k) for k in kinds}
@@ -1067,13 +1083,15 @@ class DecodeEngine:
         gained since the last booking onto the page counters."""
         kinds = self.pool.kind_stats()
         in_use = {k: st["pages_in_use"] for k, st in kinds.items()}
-        for row, gauge in self._cache_bytes.items():
-            gauge.set(self.pool.row_bytes(row, in_use))
+        for gauge, rows in self._cache_bytes.values():
+            gauge.set(sum(self.pool.row_bytes(row, in_use) for row in rows))
         for st, gauge in self._state_bytes.items():
             gauge.set(self.pool.state_bytes(st, in_use[_lane.STATE]))
         for k, st in kinds.items():
             booked = self._kind_booked[k]
             self._kind_pages[k].set(st["pages_in_use"])
+            self._kind_bytes[k].set(
+                self.pool.kind_bytes(k, st["pages_in_use"]))
             self._kind_alloc[k].inc(st["alloc_total"]
                                     - booked["alloc_total"])
             for why, n in st["freed"].items():

@@ -19,7 +19,10 @@ layer of a kind lives under that kind's page table), its own free list
 a kind, and sizes a kind's tensors by that kind's worst case: every
 slot at full length for ``full``, ``lane.window_pages_per_seq`` pages a
 slot for a window kind.  A model that declares nothing has the one kind
-``full`` and this allocator does what it always did.
+``full`` and this allocator does what it always did.  A lane may
+declare its rows BY KIND (``{kind: [CacheRow]}``: a window layer that
+leaves wider rows than a full one): a layer's tensors then have its
+kind's rows, and a page of a kind counts its own rows' bytes.
 
 **When a window page is freed.**  ``release(seq_id, length)`` — called
 by the scheduler after a prefill chunk or a decode step has moved the
@@ -63,7 +66,7 @@ import numpy as np
 
 from .errors import PoolExhaustedError
 from .lane import (POOL_PREFIX, STATE, kind_name, kinds_of, pool_var_names,
-                   state_var_names)
+                   rows_of_layers, state_var_names)
 
 __all__ = ["KVPool", "PoolExhaustedError"]
 
@@ -112,7 +115,8 @@ class KVPool:
     """Host-side page allocator + the device-resident pool vars.
 
     ``rows`` is the model's declaration of what a token leaves in each
-    layer (``lane.CacheRow`` name, width, dtype); ``num_pages`` INCLUDES
+    layer (``lane.CacheRow`` name, width, dtype), one list for every
+    layer or ``{kind: [CacheRow]}``; ``num_pages`` INCLUDES
     the trash page, so ``num_pages - 1`` pages are allocatable; a single
     sequence needs up to ``max_pages_per_seq`` of them (the constructor
     enforces one sequence always fits — otherwise eviction could never
@@ -139,16 +143,20 @@ class KVPool:
                 f"cannot hold one full sequence of {max_pages_per_seq} "
                 f"pages — raise num_pages or lower max_len")
         self.num_layers = int(num_layers)
-        self.rows = list(rows)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.max_pages_per_seq = int(max_pages_per_seq)
         self.prefix = POOL_PREFIX if prefix is None else prefix
-        self.var_names = pool_var_names(self.rows, self.num_layers,
-                                        self.prefix)
         windows = ([None] * self.num_layers if layer_windows is None
                    else list(layer_windows))
         self.layer_kinds = [kind_name(w) for w in windows]
+        # per layer, the rows it leaves (its kind's, where the lane
+        # declares them by kind); ``rows``: every distinct row, once
+        self.layer_rows = rows_of_layers(rows, self.num_layers, windows)
+        self.rows = list(dict.fromkeys(
+            row for layer in self.layer_rows for row in layer))
+        self.var_names = pool_var_names(rows, self.num_layers, self.prefix,
+                                        windows)
         self._kinds = {}
         for w in kinds_of(windows):
             name = kind_name(w)
@@ -214,8 +222,9 @@ class KVPool:
                     or str(getattr(cur, "dtype", "")) != dtype):
                 scope.set(name, jnp.zeros(shape, dtype=dtype))
 
-        for names, kind in zip(self.var_names, self.layer_kinds):
-            for name, row in zip(names, self.rows):
+        for names, kind, rows in zip(self.var_names, self.layer_kinds,
+                                     self.layer_rows):
+            for name, row in zip(names, rows):
                 zeros(name, (self._kinds[kind].num_pages, self.page_size,
                              row.width), row.dtype)
         for names in self.state_var_names:
@@ -225,19 +234,36 @@ class KVPool:
 
     # -- modeled bytes ------------------------------------------------------
 
-    def row_bytes(self, row, pages=None):
-        """Device bytes of one declared row tensor over every layer:
-        resident (``pages`` None), or of ``pages`` pages — a number (of
-        every layer) or ``{kind: pages}``."""
+    def _page_bytes(self, row):
+        """Bytes one page of one layer holds of ``row``."""
         import jax.numpy as jnp  # its dtypes know bfloat16
 
+        return self.page_size * row.width * jnp.dtype(row.dtype).itemsize
+
+    def row_bytes(self, row, pages=None):
+        """Device bytes of one declared row tensor over every layer that
+        leaves it: resident (``pages`` None), or of ``pages`` pages — a
+        number (of every layer) or ``{kind: pages}``."""
         if pages is None:
             pages = self.pages_by_kind()
         elif not isinstance(pages, dict):
             pages = dict.fromkeys(self.kinds, pages)
-        return (self.page_size * row.width * jnp.dtype(row.dtype).itemsize
-                * sum(pages[k.name] * k.layers
-                      for k in self._kinds.values()))
+        return self._page_bytes(row) * sum(
+            pages[kind] for kind, rows in zip(self.layer_kinds,
+                                              self.layer_rows) if row in rows)
+
+    def kind_bytes(self, kind, pages=None):
+        """Device bytes of cache kind ``kind`` over its layers, each at
+        its own rows: resident (``pages`` None), or of ``pages`` pages
+        (blocks, for the ``state`` kind)."""
+        if kind == STATE:
+            return sum(self.state_bytes(st, pages) for st in self.seq_state)
+        if pages is None:
+            pages = self._kinds[kind].num_pages
+        return pages * sum(
+            self._page_bytes(row) for k, rows in zip(self.layer_kinds,
+                                                     self.layer_rows)
+            if k == kind for row in rows)
 
     def state_bytes(self, st, blocks=None):
         """Device bytes of one declared state tensor over every state

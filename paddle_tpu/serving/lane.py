@@ -8,7 +8,11 @@ A model declares
   tensors of any width and dtype (``CacheRow``).  Dense multi-head
   attention leaves a K and a V row (``kv_rows``); latent attention with
   a learned indexer leaves a latent row and an indexer key.  The pool
-  allocates every row tensor ``[num_pages, page_size, width]``;
+  allocates every row tensor ``[num_pages, page_size, width]``.  A lane
+  whose layers of different kinds leave rows of different widths (a
+  window layer with more K/V heads than a full one) declares its rows BY
+  KIND, ``{kind: [CacheRow]}``: pool tensors, the bytes of a page and
+  the modeled bytes then follow each layer's kind, as the pages do;
 - optionally the **kind** of cache each layer leaves (``layer_windows``):
   ``full`` (a token's rows stay until its request ends) or ``window`` with
   its W (a layer that attends the last W tokens: rows wholly below a
@@ -64,7 +68,7 @@ __all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
            "ImageEncoder", "PreparedImage", "ROW_STAGING",
            "declare_row_staging",
            "FULL", "kind_name", "kind_feed", "kinds_of",
-           "window_pages_per_seq",
+           "window_pages_per_seq", "rows_of_layers",
            "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars"]
 
 POOL_PREFIX = "@KVPOOL@"
@@ -149,10 +153,28 @@ def kv_rows(num_heads, head_dim, dtype="float32"):
     return [CacheRow("k", width, dtype), CacheRow("v", width, dtype)]
 
 
-def pool_var_names(rows, num_layers, prefix=POOL_PREFIX):
-    """Per layer, the pool var name of each declared row, in order."""
-    return [tuple(f"{prefix}{row.name}_l{i}" for row in rows)
-            for i in range(int(num_layers))]
+def rows_of_layers(rows, num_layers, layer_windows=None):
+    """Per layer, the rows a token leaves there: ``rows`` itself for a
+    lane that declares one list, its kind's list for a lane that
+    declares ``{kind: [CacheRow]}``."""
+    if not isinstance(rows, dict):
+        return [list(rows)] * int(num_layers)
+    windows = ([None] * int(num_layers) if layer_windows is None
+               else layer_windows)
+    kinds = [kind_name(w) for w in windows]
+    if set(kinds) != set(rows):
+        raise ValueError(
+            f"cache rows declared for kinds {sorted(rows)}, but the "
+            f"layers are of kinds {sorted(set(kinds))}")
+    return [list(rows[k]) for k in kinds]
+
+
+def pool_var_names(rows, num_layers, prefix=POOL_PREFIX, layer_windows=None):
+    """Per layer, the pool var name of each row declared for it, in
+    order."""
+    return [tuple(f"{prefix}{row.name}_l{i}" for row in layer_rows)
+            for i, layer_rows in enumerate(
+                rows_of_layers(rows, num_layers, layer_windows))]
 
 
 def declare_pool_vars(rows, num_layers, num_pages, page_size,
@@ -163,7 +185,8 @@ def declare_pool_vars(rows, num_layers, num_pages, page_size,
     (and the kernels read it as stored), so no executable copies a pool
     tensor (PERF.md finding 4).  With ``layer_windows`` (a lane of more
     than one cache kind) ``num_pages`` is ``{kind: pages}`` and layer i's
-    vars have its kind's."""
+    vars have its kind's; ``rows`` declared by kind give layer i its
+    kind's rows too."""
     from paddle_tpu import fluid
 
     block = fluid.default_main_program().global_block()
@@ -176,9 +199,10 @@ def declare_pool_vars(rows, num_layers, num_pages, page_size,
     return [tuple(block.create_var(
         name=name, shape=[pages(layer), int(page_size), row.width],
         dtype=row.dtype, persistable=True)
-        for name, row in zip(names, rows))
-        for layer, names in enumerate(
-            pool_var_names(rows, num_layers, prefix))]
+        for name, row in zip(names, layer_rows))
+        for layer, (names, layer_rows) in enumerate(zip(
+            pool_var_names(rows, num_layers, prefix, layer_windows),
+            rows_of_layers(rows, num_layers, layer_windows)))]
 
 
 def state_var_names(states, state_layers, prefix=POOL_PREFIX):
@@ -262,7 +286,9 @@ class DecodeLane:
 
     ``cache_rows(pool_dtype)`` -> [CacheRow]: what a token leaves in each
     of ``num_layers`` layers at that storage dtype (raise for a dtype the
-    model has no kernels for).  ``num_layers`` counts the layers that
+    model has no kernels for); or ``{kind: [CacheRow]}`` where the layers
+    of each cache kind (``layer_windows``) leave rows of their own.
+    ``num_layers`` counts the layers that
     leave cache rows: a model whose other layers hold per-sequence state
     instead numbers its cache layers 0 .. ``num_layers`` - 1 itself.
     ``seq_state``: ``[SeqState]`` the state a sequence owns in each of

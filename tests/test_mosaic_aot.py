@@ -786,3 +786,130 @@ def test_olmo_hybrid_decode_engine_executables(chip):
         assert seen_by_roofline == [0, 1]
     finally:
         engine.close()
+
+
+# ---------------------------------------------------------------------------
+# MiMo-V2.5 through the decode lane (benchmark/configs/mimo-v2.5-ep16.json):
+# the asymmetric paged kernels (K heads of 192 beside V heads of 128, the
+# window layers' sink) at the published widths, and the engine's two
+# executables over a pool whose ROWS go by cache kind
+# ---------------------------------------------------------------------------
+
+_MIMO_PAGES = {"full": 16 * 272 + 1, "window128": 16 * 6 + 1}
+
+
+@pytest.mark.parametrize("window", [None, 128])
+@pytest.mark.parametrize("b,t", [(16, 1), (1, 512)])
+def test_paged_attention_asym_and_sink_at_mimo_widths(chip, b, t, window):
+    """64 query heads of 192 on 4 (full) or 8 (window) K/V heads, V heads
+    of 128, over bf16 pools [pages, 128, 768 | 1536] and [.., 512 | 1024]
+    as stored, at a 34k-token page table, as a decode step and as a
+    512-token chunk see them; the window layers carry a sink; the
+    kernel's name says which form it is."""
+    n_kv = 4 if window is None else 8
+    pages = _MIMO_PAGES["full" if window is None else "window128"]
+    shapes = [((b, 64, t, 192), jnp.float32),
+              ((pages, 128, n_kv * 192), jnp.bfloat16),
+              ((pages, 128, n_kv * 128), jnp.bfloat16),
+              ((b, 272), jnp.int32), ((b,), jnp.int32)]
+    if window is None:
+        def fn(q, k, v, pt, qs):
+            return prims.paged_attention(q, k, v, pt, qs)
+        name = "paged_attention_grouped_asym"
+    else:
+        shapes.append(((64,), jnp.float32))
+
+        def fn(q, k, v, pt, qs, sinks):
+            return prims.paged_attention(q, k, v, pt, qs, window=window,
+                                         sinks=sinks)
+        name = "paged_attention_grouped_window_asym_sink"
+    hlo = _compile(fn, chip, *shapes)
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == 1
+    assert _pool_copies(hlo, pages, 128) == []
+
+
+def test_mimo_decode_engine_executables(chip):
+    """The prefill chunk and the decode step of MiMo-V2.5 at the
+    benchmark's widths, pool and slots (three of its seven layers: the
+    dense full one, a window and a full expert layer... cut to the dense
+    full layer, a window expert layer and the full expert layer): a
+    paged call a layer, named by its form — which is what the benchmark's
+    three attention patterns read —, three grouped products an expert
+    layer, each kind's pool tensors at that kind's pages AND widths,
+    donated, row-major and UNCOPIED."""
+    import json
+    import os
+
+    import ml_dtypes
+
+    from paddle_tpu.models import mimo
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        config = json.load(f)
+    args = dict(config["builder"]["config_args"], num_hidden_layers=3,
+                hybrid_layer_pattern=[0, 1, 0], moe_layer_freq=[0, 1, 1])
+    cfg = mimo.MiMoConfig(**args)
+    lm, lm_start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
+        mimo.build_mimo_lm(cfg)
+    scope = fluid.Scope()
+    for p in lm.global_block().all_parameters():
+        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                 else np.dtype(p.dtype))
+        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                          tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-mimo",
+        auto_start=False)
+    assert engine.prefill_chunk == 512
+    assert engine.pool.pages_by_kind() == _MIMO_PAGES
+    # the issue's bytes, at the configuration's seven layers
+    per_page = 128 * 2
+    assert engine.pool.kind_bytes("full") == 2 * 4353 * per_page * 1280
+    assert engine.pool.kind_bytes("window128") == 97 * per_page * 2560
+    patterns = {
+        name: re.compile(harness_json(root, name + ".serve")["pattern"])
+        for name in ("sink_window_attn_roofline", "asym_full_attn_roofline",
+                     "asym_attn_chunk_mxu_share", "full_attn_roofline",
+                     "window_attn_roofline")}
+    try:
+        with lowering_for("tpu"):
+            for which, lowered in zip(("chunk", "step"),
+                                      engine.lower(sharding=chip)):
+                compiled = lowered.compile()
+                hlo = compiled.as_text()
+                lines = [line.strip()
+                         for line in _long_hlo(compiled).splitlines()]
+
+                def seen(name):
+                    return sum(bool(patterns[name].search(x))
+                               for x in lines)
+
+                assert seen("asym_full_attn_roofline") == 2
+                assert seen("sink_window_attn_roofline") == 1
+                assert seen("asym_attn_chunk_mxu_share") == 3
+                # Trinity's patterns find none of this model's calls
+                assert seen("full_attn_roofline") == 0
+                assert seen("window_attn_roofline") == 0
+                assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
+                assert _mosaic_calls(hlo) == 3 + 6, which
+                pools = []
+                for kind, layers, heads in (("full", 2, 4),
+                                            ("window128", 1, 8)):
+                    pages = _MIMO_PAGES[kind]
+                    assert _pool_copies(hlo, pages, 128) == []
+                    for width in (heads * 192, heads * 128):    # K, V
+                        params = _pool_parameters(
+                            hlo, f"{pages},128,{width}")
+                        assert len(params) == layers
+                        assert [lay for _, lay in params
+                                if not lay.startswith("{2,1,0")] == []
+                        pools += params
+                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
+    finally:
+        engine.close()

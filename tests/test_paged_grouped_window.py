@@ -171,9 +171,19 @@ def test_window_alone_and_a_bfloat16_pool(pages_per_step, t):
 def test_pools_of_unequal_heads_are_refused():
     q = _rand((1, 6, 1, 8), 0)
     k, v = _rand((5, PG, 16), 1), _rand((5, PG, 24), 2)
+    table, start = np.zeros((1, 2), np.int32), np.zeros(1, np.int32)
+    # since PR 41 as many V heads of ANOTHER width are a pool (two heads
+    # of 12 beside two K heads of 8: the output is 12 wide) ...
+    assert prims.paged_attention(q, k, v, table, start).shape == (1, 6, 1, 12)
+    # ... but not V lanes that are no whole heads, another page count, or
+    # another dtype
+    with pytest.raises(ValueError, match="whole heads"):
+        prims.paged_attention(q, k, v[:, :, :23], table, start)
+    with pytest.raises(ValueError, match="whole heads"):
+        prims.paged_attention(q, k, v[:4], table, start)
     with pytest.raises(ValueError, match="one dtype and one shape"):
-        prims.paged_attention(q, k, v, np.zeros((1, 2), np.int32),
-                              np.zeros(1, np.int32))
+        prims.paged_attention(q, k, _rand((5, PG, 16), 2).astype(
+            jnp.bfloat16), table, start)
     with pytest.raises(ValueError, match=r"has shape \(5, 4, 20\)"):
         bad = _rand((5, PG, 20), 3)     # 20 lanes: no whole heads of 8
         prims.paged_attention(q, bad, bad, np.zeros((1, 2), np.int32),

@@ -1572,14 +1572,19 @@ def _out_f32(helper, op, inputs, attrs=None):
     return out
 
 
-def weight_matmul(x, size, param_attr=None, dtype="float32", name=None):
+def weight_matmul(x, size, param_attr=None, dtype="float32", name=None,
+                  head_dim=None):
     """x [.., K] @ W [K, size], W stored in ``dtype`` and the product
-    taken in it with float32 accumulation (no bias)."""
+    taken in it with float32 accumulation (no bias).  ``head_dim``: the
+    width of the heads the caller reshapes the product into, if it does
+    (the op pins the product's layout where they are no whole lane
+    tiles: ops/mla_ops.py ``_pin_product``)."""
     helper = LayerHelper("weight_matmul", name=name)
     w = helper.create_parameter(param_attr, shape=[x.shape[-1], size],
                                 dtype=dtype,
                                 default_initializer=Normal(0.0, 0.02))
-    return _out_f32(helper, "weight_matmul", {"X": [x], "W": [w]})
+    return _out_f32(helper, "weight_matmul", {"X": [x], "W": [w]},
+                    {"head_dim": int(head_dim)} if head_dim else None)
 
 
 def headwise_matmul(x, size, param_attr=None, dtype="float32", name=None):

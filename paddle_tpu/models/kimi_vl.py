@@ -222,11 +222,12 @@ class KimiVLConfig:
 # ---------------------------------------------------------------------------
 
 
-def _linear_b(x, size, name, cfg):
+def _linear_b(x, size, name, cfg, head_dim=None):
     """x W + b: W in ``cfg.dtype``, the bias float32."""
     bias = layers.create_parameter(
         [size], "float32", attr=_attr(name + ".b_0", cfg, Constant(0.0)))
-    return layers.elementwise_add(_linear(x, size, name, cfg), bias)
+    return layers.elementwise_add(_linear(x, size, name, cfg, head_dim),
+                                  bias)
 
 
 def _attention(x, pos, page_table, q_start, pool, write, shape, cfg, name,
@@ -241,7 +242,8 @@ def _attention(x, pos, page_table, q_start, pool, write, shape, cfg, name,
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     (latent_pool,) = pool
     xa = _rms(x, name + "_attn_norm", cfg)
-    q = L.reshape(_linear(xa, heads * (nope + rope), name + "_q", cfg),
+    q = L.reshape(_linear(xa, heads * (nope + rope), name + "_q", cfg,
+                          nope + rope),
                   shape=[b, t, heads, nope + rope])
     q_nope, q_rope = L.split(q, [nope, rope], dim=-1)
     q_rope = _rope(q_rope, pos, cfg)
@@ -488,7 +490,7 @@ def _tower(patches, pos, grid, cfg, attn_force):
     for layer in range(cfg.vt_num_hidden_layers):
         name = f"kimi_vit_layer_{layer}"
         qkv = L.reshape(_linear_b(_ln(z, name + "_ln0", cfg), 3 * width,
-                                  name + "_qkv", cfg),
+                                  name + "_qkv", cfg, d),
                         shape=[n, 3 * heads, d])
         q, k, v = L.split(qkv, 3, dim=1)
         o = L.vit_attention(

@@ -229,10 +229,10 @@ def _attention(u, pos, page_table, q_start, pools, write, shape, window, cfg,
     d, dv = cfg.head_dim, cfg.v_head_dim
     theta = cfg.rope_theta if window is None else cfg.swa_rope_theta
     k_pool, v_pool = pools
-    q = L.rope_half(L.reshape(_linear(u, hq * d, name + "_q", cfg),
+    q = L.rope_half(L.reshape(_linear(u, hq * d, name + "_q", cfg, d),
                               shape=[b, t, hq, d]), pos, theta=theta,
                     rotary_dim=cfg.rotary_dim)
-    k = L.rope_half(L.reshape(_linear(u, hkv * d, name + "_k", cfg),
+    k = L.rope_half(L.reshape(_linear(u, hkv * d, name + "_k", cfg, d),
                               shape=[b, t, hkv, d]), pos, theta=theta,
                     rotary_dim=cfg.rotary_dim)
     v = L.scale(_linear(u, hkv * dv, name + "_v", cfg),

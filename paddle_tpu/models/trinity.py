@@ -173,9 +173,9 @@ def _attr(name, cfg):
                      initializer=Normal(0.0, cfg.initializer_range))
 
 
-def _linear(x, size, name, cfg):
+def _linear(x, size, name, cfg, head_dim=None):
     return layers.weight_matmul(x, size, param_attr=_attr(name + ".w_0", cfg),
-                                dtype=cfg.dtype)
+                                dtype=cfg.dtype, head_dim=head_dim)
 
 
 def _rms(x, name, cfg):

@@ -25,8 +25,11 @@ the cache's) dtype where it multiplies them.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from paddle_tpu.fluid.registry import simple_op
 
@@ -35,10 +38,45 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
+def _pin_product(out, w, head_dim):
+    """Constrain ``out = x @ w`` to the row-major layout where the caller
+    splits it into heads of ``head_dim`` that are no whole 128-lane tiles
+    and the weight is at least as large as the product, and book the
+    choice.  There XLA:TPU's layout assignment gives the dot the layout
+    the reshape wants and pays with a copy of the WEIGHT, an entry
+    parameter, through HBM in every run (MiMo-V2.5's 100.7-MB ``W_q``,
+    64 heads of 192, in every layer of a prefill chunk); told the
+    product's layout it relays the product after the dot.  The constraint
+    sits on the product because one on the weight changes nothing (a
+    parameter arrives row-major already: the copy is what XLA inserts
+    behind it).  Every other product is XLA's: a pin there buys nothing
+    (the weight copies left are XLA's own fetches into fast memory) and
+    can cost a relay of the product (docs/KERNELS.md "The layout of
+    weight_matmul's product")."""
+    from paddle_tpu.observability import metrics as obs
+
+    pinned = bool(head_dim and head_dim % 128
+                  and w.size * w.dtype.itemsize
+                  >= out.size * out.dtype.itemsize)
+    obs.counter(
+        "pt_weight_matmul_layout_total",
+        "Trace-time choices of weight_matmul: products of this many rows "
+        "whose layout was pinned row-major (heads that are no whole lane "
+        "tiles under a weight as large) or left to XLA",
+        labels=("rows", "pinned"),
+    ).labels(rows=str(math.prod(out.shape[:-1])),
+             pinned=str(pinned).lower()).inc()
+    if not pinned:
+        return out
+    return with_layout_constraint(
+        out, Layout(major_to_minor=tuple(range(out.ndim))))
+
+
 @simple_op("weight_matmul", ["X", "W"], ["Out"], grad=None)
 def _weight_matmul(ctx, x, w, attrs):
-    return jnp.dot(x.astype(w.dtype), w,
-                   preferred_element_type=jnp.float32)
+    return _pin_product(
+        jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32), w,
+        attrs.get("head_dim"))
 
 
 @simple_op("headwise_matmul", ["X", "W"], ["Out"], grad=None)

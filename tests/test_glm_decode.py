@@ -366,6 +366,84 @@ def test_small_ops_against_the_references_functions(op):
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
+def _product_layouts(since=None):
+    """{(rows, pinned): products booked} of ``weight_matmul``, less those
+    of an earlier reading."""
+    fam = obs.snapshot().get("pt_weight_matmul_layout_total") or {}
+    return {k: v - (since or {}).get(k, 0)
+            for k, v in fam.get("samples", {}).items()
+            if v != (since or {}).get(k, 0)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("lead,head_dim,pinned", [
+    ((16, 1), None, "false"),       # a step
+    ((16, 1), 192, "true"),         # a step split into heads of 192
+    ((1, 512), 192, "true")])       # a chunk split into heads of 192
+def test_weight_matmul_pins_its_product_and_stays_bit_equal(
+        lead, head_dim, pinned, dtype):
+    """A product split into heads that are no whole lane tiles carries
+    the layout constraint, at a step's and at a chunk's rows, and none
+    computes anything else than the plain dot in the weight's dtype with
+    float32 accumulation: equal to the last bit."""
+    rng = np.random.RandomState(7)
+    x = rng.randn(*lead, 1024).astype(np.float32)
+    w = jnp.asarray(rng.randn(1024, 2 * 192).astype(np.float32) * 0.02,
+                    dtype=dtype)
+    before = _product_layouts()
+    got, _ = _run_layer(
+        lambda v: fluid.layers.weight_matmul(
+            v["x"], 2 * 192, param_attr="w", dtype=dtype, head_dim=head_dim),
+        {"x": x}, {"w": w})
+    # booked once a trace of the op, and the executor traces more than once
+    assert set(_product_layouts(before)) == {
+        (str(lead[0] * lead[1]), pinned)}
+    want = jnp.dot(jnp.asarray(x).astype(w.dtype), w,
+                   preferred_element_type=jnp.float32)
+    assert got.dtype == np.float32
+    assert (got == np.asarray(want)).all()
+
+
+# the served widths (benchmark/configs/*.json): lead, K, N, the heads the
+# caller splits the product into, pinned
+_SERVED_PRODUCTS = {
+    "mimo_q_step": ((16, 1), 4096, 12288, 192, True),
+    "mimo_q_chunk": ((1, 512), 4096, 12288, 192, True),
+    "mimo_k_chunk": ((1, 512), 4096, 768, 192, True),
+    "mimo_v_chunk": ((1, 512), 4096, 512, None, False),
+    "mimo_o_step": ((16, 1), 8192, 4096, None, False),
+    "kimi_q_step": ((16, 1), 2048, 3072, 192, True),
+    "kimi_q_chunk": ((1, 512), 2048, 3072, 192, True),
+    # heads of 72, but 4096 patches: the product is seven times the weight
+    "kimi_tower_qkv": ((4096,), 1152, 3456, 72, False),
+    # whole tiles, were they stated
+    "trinity_q_chunk": ((1, 512), 3072, 6144, 128, False),
+    "trinity_o_step": ((16, 1), 6144, 3072, None, False),
+    "glm_q_up_step": ((16, 1), 2048, 16384, None, False),
+    "glm_q_up_chunk": ((1, 512), 2048, 16384, 256, False),
+    "olmo_values_step": ((16, 1), 3840, 5760, None, False),
+    "olmo_values_chunk": ((1, 512), 3840, 5760, None, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_SERVED_PRODUCTS))
+def test_weight_matmul_books_what_the_shapes_choose(case):
+    """The rule reads nothing but shapes: heads that are no whole lane
+    tiles, stated by the caller, under a weight as large as the product."""
+    from paddle_tpu.ops.mla_ops import _weight_matmul
+
+    lead, k, n, head_dim, pinned = _SERVED_PRODUCTS[case]
+    before = _product_layouts()
+    out = jax.eval_shape(
+        lambda x, w: _weight_matmul(
+            None, x, w, {"head_dim": head_dim} if head_dim else {}),
+        jax.ShapeDtypeStruct(lead + (k,), jnp.float32),
+        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+    assert out.shape == lead + (n,) and out.dtype == jnp.float32
+    assert _product_layouts(before) == {
+        (str(np.prod(lead)), str(pinned).lower()): 1}
+
+
 # ---------------------------------------------------------------------------
 # the pool: two kinds of row tensor under one page table
 # ---------------------------------------------------------------------------

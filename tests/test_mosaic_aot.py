@@ -11,8 +11,13 @@ chip_smoke.py runs (BERT-base / GPT-base heads and widths; whole
 programs are cut in depth only, to keep the compile in seconds).
 """
 
+import functools
+import json
+import os
 import re
+import types
 
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -32,10 +37,15 @@ from paddle_tpu.parallel.data_parallel import DataParallelRunner
 HEADS, HEAD_DIM = 12, 64  # BERT-base and GPT-base
 
 
-@pytest.fixture(scope="module")
-def topo():
+@functools.lru_cache(maxsize=None)
+def _topo():
     return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    return _topo()
 
 
 @pytest.fixture(scope="module")
@@ -237,13 +247,8 @@ def _long_hlo(compiled):
 def _roofline_pattern():
     """The regular expression ``paged_attn_roofline.serve`` finds the
     decode step's paged-attention calls by, read from the benchmark."""
-    import json
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "layer_metrics",
-                           "paged_attn_roofline.serve.json")) as f:
-        return re.compile(json.load(f)["pattern"])
+    return re.compile(
+        harness_json(_ROOT, "paged_attn_roofline.serve")["pattern"])
 
 
 # (hidden, heads, layers, slots, max_len): GPT-base as chip_smoke.py
@@ -322,6 +327,129 @@ def test_decode_engine_executables(chip, size, pool_dtype):
 
 
 # ---------------------------------------------------------------------------
+# The benchmark's five bf16 serve configurations through the decode lane:
+# each one's executables are compiled ONCE a module, at the benchmark's
+# widths, pool and slots and a cut in depth, for that configuration's own
+# test below and for the audit of copies at the end of this file
+# ---------------------------------------------------------------------------
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _config_args(config):
+    return config["builder"]["config_args"]
+
+
+def _glm_cut(config):
+    # two of its five layers: the dense one and an expert one
+    cfg = glm.GLMConfig(**dict(_config_args(config),
+                               num_hidden_layers=2))
+    return cfg, [lambda: glm.build_glm_lm(cfg)]
+
+
+def _trinity_cut(config):
+    from paddle_tpu.models import trinity
+
+    # three of its five layers: the dense sliding one, a full and a
+    # sliding expert layer
+    cfg = trinity.TrinityConfig(**dict(
+        _config_args(config), num_hidden_layers=3,
+        layer_types=["sliding_attention", "full_attention",
+                     "sliding_attention"]))
+    return cfg, [lambda: trinity.build_trinity_lm(cfg)]
+
+
+def _kimi_cut(config):
+    from paddle_tpu.models import kimi_vl
+
+    # two of its six decoder layers, the dense one and an expert one, and
+    # two of the tower's six blocks
+    cfg = kimi_vl.KimiVLConfig(**dict(
+        _config_args(config), num_hidden_layers=2, vt_num_hidden_layers=2))
+    return cfg, [lambda: kimi_vl.build_kimi_vl_lm(cfg),
+                 lambda: kimi_vl.build_kimi_vl_vision_encoder(
+                     cfg, 4, 4, 8)[1]]
+
+
+def _olmo_cut(config):
+    from paddle_tpu.models import olmo_hybrid
+
+    # one period of its two: linear, linear, linear, full
+    cfg = olmo_hybrid.OlmoHybridConfig(**dict(
+        _config_args(config), num_hidden_layers=4,
+        layer_types=config["layer_types"][:4]))
+    return cfg, [lambda: olmo_hybrid.build_olmo_hybrid_lm(cfg)]
+
+
+def _mimo_cut(config):
+    from paddle_tpu.models import mimo
+
+    # three of its seven layers: the dense full layer, a window expert
+    # layer and the full expert layer
+    cfg = mimo.MiMoConfig(**dict(
+        _config_args(config), num_hidden_layers=3,
+        hybrid_layer_pattern=[0, 1, 0], moe_layer_freq=[0, 1, 1]))
+    return cfg, [lambda: mimo.build_mimo_lm(cfg)]
+
+
+_CUTS = {"glm-5-ep16": _glm_cut, "trinity-large-ep8": _trinity_cut,
+         "kimi-vl-a3b-ep1": _kimi_cut, "olmo-hybrid-7b-pp4": _olmo_cut,
+         "mimo-v2.5-ep16": _mimo_cut}
+
+
+@functools.lru_cache(maxsize=None)
+def _served(name):
+    """``benchmark/configs/<name>.json`` at its cut, compiled for one v5e
+    chip: what the engine said of itself (``prefill_chunk``, ``pool``,
+    ``image_rows``; it is closed again) and ``exes``, {label: (the
+    compiled HLO, the lines of its long form)} under the labels chunk,
+    step and, an image shape each, tower0.."""
+    with open(os.path.join(_ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    cfg, builds = _CUTS[name](config)
+    programs = []
+    for build in builds:
+        prog, start = fluid.Program(), fluid.Program()
+        with fluid.program_guard(prog, start), fluid.unique_name.guard():
+            built = build()
+        programs.append(prog)
+        if isinstance(built, fluid.Program):    # an encoder's second program
+            programs.append(built)
+    scope = fluid.Scope()
+    for prog in programs:
+        for p in prog.global_block().all_parameters():
+            dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                     else np.dtype(p.dtype))
+            # shapes are all a lowering reads: no 1.5 B parameters on the
+            # host
+            scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
+                                              tuple(p.shape)))
+    e = config["engine"]
+    engine = serving.DecodeEngine(
+        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
+        page_size=e["page_size"], max_len=e["max_len"], name="aot-" + name,
+        auto_start=False)
+    exes = {}
+    try:
+        with lowering_for("tpu"):
+            lowered = engine.lower(
+                sharding=SingleDeviceSharding(_topo().devices[0]))
+            labels = ["chunk", "step"] + [
+                f"tower{i}" for i in range(len(lowered) - 2)]
+            for label, low in zip(labels, lowered):
+                compiled = low.compile()
+                exes[label] = (compiled.as_text(), [
+                    line.strip()
+                    for line in _long_hlo(compiled).splitlines()])
+        return types.SimpleNamespace(
+            prefill_chunk=engine.prefill_chunk, pool=engine.pool,
+            image_rows=engine.stats().get("image_rows"), exes=exes)
+    finally:
+        engine.close()
+
+
+# ---------------------------------------------------------------------------
 # GLM-5 through the decode lane (benchmark/configs/glm-5-ep16.json): the
 # three sparse-attention operations and the grouped product at the
 # published widths, and the engine's two executables
@@ -377,7 +505,7 @@ def test_grouped_matmul_at_glm5_widths(chip, groups, rows, k, n):
     assert _mosaic_calls(hlo) == 1 and "%grouped_matmul" in hlo
 
 
-def test_glm_decode_engine_executables(chip):
+def test_glm_decode_engine_executables():
     """The prefill chunk and the decode step of GLM-5 at the benchmark's
     widths, pool and slots (two of its five layers: the dense one and an
     expert one): per layer one indexer, one selection and one attention
@@ -388,54 +516,24 @@ def test_glm_decode_engine_executables(chip):
     or transpose of a whole pool tensor is left.  With the latent row
     stored 576 wide the decode step compiled to ten whole-pool copies
     and 6.1 GB of temporaries (serving/lane.py lane_padded)."""
-    import json
-    import os
-
-    import ml_dtypes
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "glm-5-ep16.json")) as f:
-        config = json.load(f)
-    args = dict(config["builder"]["config_args"], num_hidden_layers=2)
-    cfg = glm.GLMConfig(**args)
-    lm, lm_start = fluid.Program(), fluid.Program()
-    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
-        glm.build_glm_lm(cfg)
-    scope = fluid.Scope()
-    for p in lm.global_block().all_parameters():
-        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                 else np.dtype(p.dtype))
-        # shapes are all a lowering reads: no 1.5 B parameters on the host
-        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
-                                          tuple(p.shape)))
-    e = config["engine"]
-    engine = serving.DecodeEngine(
-        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
-        page_size=e["page_size"], max_len=e["max_len"], name="aot-glm",
-        auto_start=False)
-    assert engine.prefill_chunk == 512
-    assert engine.pool.num_pages == _GLM_PAGES
-    try:
-        with lowering_for("tpu"):
-            for lowered in engine.lower(sharding=chip):
-                hlo = lowered.compile().as_text()
-                assert hlo.count("%dsa_indexer_scores") >= 2
-                assert hlo.count("%sparse_mla_attention") >= 2
-                assert hlo.count("%dsa_topk_select") >= 2
-                assert _mosaic_calls(hlo) == 2 * 3 + 3
-                assert _pool_copies(hlo, _GLM_PAGES, _GLM_PAGE) == []
-                pools = []
-                for width in (640, 128):
-                    params = _pool_parameters(
-                        hlo, f"{_GLM_PAGES},{_GLM_PAGE},{width}")
-                    assert len(params) == 2        # one a layer
-                    assert [lay for _, lay in params
-                            if not lay.startswith("{2,1,0")] == []
-                    pools += params
-                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
-    finally:
-        engine.close()
+    served = _served("glm-5-ep16")
+    assert served.prefill_chunk == 512
+    assert served.pool.num_pages == _GLM_PAGES
+    for hlo, _ in served.exes.values():
+        assert hlo.count("%dsa_indexer_scores") >= 2
+        assert hlo.count("%sparse_mla_attention") >= 2
+        assert hlo.count("%dsa_topk_select") >= 2
+        assert _mosaic_calls(hlo) == 2 * 3 + 3
+        assert _pool_copies(hlo, _GLM_PAGES, _GLM_PAGE) == []
+        pools = []
+        for width in (640, 128):
+            params = _pool_parameters(
+                hlo, f"{_GLM_PAGES},{_GLM_PAGE},{width}")
+            assert len(params) == 2        # one a layer
+            assert [lay for _, lay in params
+                    if not lay.startswith("{2,1,0")] == []
+            pools += params
+        assert {num for num, _ in pools} <= _aliased_parameters(hlo)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +568,7 @@ def test_paged_attention_grouped_and_window_at_trinity_widths(chip, b, t,
     assert _pool_copies(hlo, pages, 128) == []
 
 
-def test_trinity_decode_engine_executables(chip):
+def test_trinity_decode_engine_executables():
     """The prefill chunk and the decode step of Trinity at the
     benchmark's widths, pool and slots (three of its five layers: the
     dense sliding one, a full and a sliding expert layer): a paged call
@@ -478,72 +576,31 @@ def test_trinity_decode_engine_executables(chip):
     roofline patterns read —, three grouped products an expert layer,
     each kind's pool tensors at that kind's size, donated, row-major and
     UNCOPIED."""
-    import json
-    import os
-
-    import ml_dtypes
-
-    from paddle_tpu.models import trinity
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "trinity-large-ep8.json")) as f:
-        config = json.load(f)
-    args = dict(config["builder"]["config_args"], num_hidden_layers=3,
-                layer_types=["sliding_attention", "full_attention",
-                             "sliding_attention"])
-    cfg = trinity.TrinityConfig(**args)
-    lm, lm_start = fluid.Program(), fluid.Program()
-    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
-        trinity.build_trinity_lm(cfg)
-    scope = fluid.Scope()
-    for p in lm.global_block().all_parameters():
-        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                 else np.dtype(p.dtype))
-        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
-                                          tuple(p.shape)))
-    e = config["engine"]
-    engine = serving.DecodeEngine(
-        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
-        page_size=e["page_size"], max_len=e["max_len"], name="aot-trinity",
-        auto_start=False)
-    assert engine.prefill_chunk == 512
-    assert engine.pool.pages_by_kind() == _TRI_PAGES
+    served = _served("trinity-large-ep8")
+    assert served.prefill_chunk == 512
+    assert served.pool.pages_by_kind() == _TRI_PAGES
     patterns = {
-        kind: re.compile(harness_json(root, f"{kind}_attn_roofline.serve")
+        kind: re.compile(harness_json(_ROOT, f"{kind}_attn_roofline.serve")
                          ["pattern"])
         for kind in ("full", "window")}
-    try:
-        with lowering_for("tpu"):
-            for lowered in engine.lower(sharding=chip):
-                compiled = lowered.compile()
-                hlo = compiled.as_text()
-                lines = [line.strip()
-                         for line in _long_hlo(compiled).splitlines()]
-                assert sum(bool(patterns["full"].search(x))
-                           for x in lines) == 1
-                assert sum(bool(patterns["window"].search(x))
-                           for x in lines) == 2
-                assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
-                assert _mosaic_calls(hlo) == 3 + 6
-                pools = []
-                for kind, layers in (("full", 1), ("window4096", 2)):
-                    pages = _TRI_PAGES[kind]
-                    assert _pool_copies(hlo, pages, 128) == []
-                    params = _pool_parameters(hlo, f"{pages},128,1024")
-                    assert len(params) == 2 * layers       # K and V
-                    assert [lay for _, lay in params
-                            if not lay.startswith("{2,1,0")] == []
-                    pools += params
-                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
-    finally:
-        engine.close()
+    for hlo, lines in served.exes.values():
+        assert sum(bool(patterns["full"].search(x)) for x in lines) == 1
+        assert sum(bool(patterns["window"].search(x)) for x in lines) == 2
+        assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
+        assert _mosaic_calls(hlo) == 3 + 6
+        pools = []
+        for kind, layers in (("full", 1), ("window4096", 2)):
+            pages = _TRI_PAGES[kind]
+            assert _pool_copies(hlo, pages, 128) == []
+            params = _pool_parameters(hlo, f"{pages},128,1024")
+            assert len(params) == 2 * layers       # K and V
+            assert [lay for _, lay in params
+                    if not lay.startswith("{2,1,0")] == []
+            pools += params
+        assert {num for num, _ in pools} <= _aliased_parameters(hlo)
 
 
 def harness_json(root, metric):
-    import json
-    import os
-
     with open(os.path.join(root, "benchmark", "layer_metrics",
                            metric + ".json")) as f:
         return json.load(f)
@@ -586,7 +643,7 @@ def test_vit_attention_at_the_tower_shapes(chip, patches):
     assert _mosaic_calls(hlo) == 1 and "%vit_attention" in hlo
 
 
-def test_kimi_vl_decode_engine_executables(chip):
+def test_kimi_vl_decode_engine_executables():
     """The prefill chunk, the decode step and the three encoders of
     Kimi-VL at the benchmark's widths, pool, slots and image shapes (two
     of its six decoder layers, the dense one and an expert one, and two
@@ -595,73 +652,33 @@ def test_kimi_vl_decode_engine_executables(chip):
     products an expert layer, one attention call a tower block; the
     latent pool and the row staging go through every executable that
     writes them UNCOPIED and donated."""
-    import json
-    import os
-
-    import ml_dtypes
-
-    from paddle_tpu.models import kimi_vl
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "kimi-vl-a3b-ep1.json")) as f:
-        config = json.load(f)
-    args = dict(config["builder"]["config_args"], num_hidden_layers=2,
-                vt_num_hidden_layers=2)
-    cfg = kimi_vl.KimiVLConfig(**args)
-    programs = []
-    for build in (lambda: kimi_vl.build_kimi_vl_lm(cfg),
-                  lambda: programs.append(
-                      kimi_vl.build_kimi_vl_vision_encoder(cfg, 4, 4, 8)[1])):
-        prog, start = fluid.Program(), fluid.Program()
-        with fluid.program_guard(prog, start), fluid.unique_name.guard():
-            build()
-        programs.append(prog)
-    scope = fluid.Scope()
-    for prog in programs:
-        for p in prog.global_block().all_parameters():
-            dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                     else np.dtype(p.dtype))
-            # shapes are all a lowering reads
-            scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
-                                              tuple(p.shape)))
-    e = config["engine"]
-    engine = serving.DecodeEngine(
-        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
-        page_size=e["page_size"], max_len=e["max_len"], name="aot-kimi",
-        auto_start=False)
-    assert engine.prefill_chunk == 512
-    assert engine.pool.num_pages == _KIMI_PAGES
-    assert engine.stats()["image_rows"]["staging_rows"] == 1536 + 512
+    served = _served("kimi-vl-a3b-ep1")
+    assert served.prefill_chunk == 512
+    assert served.pool.num_pages == _KIMI_PAGES
+    assert served.image_rows["staging_rows"] == 1536 + 512
     staging = "2048,1,2048"
-    try:
-        with lowering_for("tpu"):
-            chunk, step, *encoders = [
-                low.compile().as_text()
-                for low in engine.lower(sharding=chip)]
-        assert len(encoders) == 3
-        assert chunk.count("%mla_chunk_attention") >= 2
-        assert "%paged_mla_attention" not in chunk
-        assert step.count("%paged_mla_attention") >= 2
-        assert "%mla_chunk_attention" not in step
-        for hlo in (chunk, step):
-            assert _mosaic_calls(hlo) == 2 + 3
-            assert _pool_copies(hlo, _KIMI_PAGES, _KIMI_PAGE) == []
-            params = _pool_parameters(
-                hlo, f"{_KIMI_PAGES},{_KIMI_PAGE},640")
-            assert len(params) == 2                # one a layer
-            assert [lay for _, lay in params
-                    if not lay.startswith("{2,1,0")] == []
-            assert {num for num, _ in params} <= _aliased_parameters(hlo)
-        # the chunk reads the staged rows, an encoder writes them in place
-        assert len(_pool_parameters(chunk, staging)) == 1
-        assert _pool_parameters(step, staging) == []
-        for hlo in encoders:
-            assert _mosaic_calls(hlo) == 2 and "%vit_attention" in hlo
-            (row,) = _pool_parameters(hlo, staging)
-            assert row[0] in _aliased_parameters(hlo)
-    finally:
-        engine.close()
+    chunk, step, *encoders = [hlo for hlo, _ in served.exes.values()]
+    assert len(encoders) == 3
+    assert chunk.count("%mla_chunk_attention") >= 2
+    assert "%paged_mla_attention" not in chunk
+    assert step.count("%paged_mla_attention") >= 2
+    assert "%mla_chunk_attention" not in step
+    for hlo in (chunk, step):
+        assert _mosaic_calls(hlo) == 2 + 3
+        assert _pool_copies(hlo, _KIMI_PAGES, _KIMI_PAGE) == []
+        params = _pool_parameters(
+            hlo, f"{_KIMI_PAGES},{_KIMI_PAGE},640")
+        assert len(params) == 2                # one a layer
+        assert [lay for _, lay in params
+                if not lay.startswith("{2,1,0")] == []
+        assert {num for num, _ in params} <= _aliased_parameters(hlo)
+    # the chunk reads the staged rows, an encoder writes them in place
+    assert len(_pool_parameters(chunk, staging)) == 1
+    assert _pool_parameters(step, staging) == []
+    for hlo in encoders:
+        assert _mosaic_calls(hlo) == 2 and "%vit_attention" in hlo
+        (row,) = _pool_parameters(hlo, staging)
+        assert row[0] in _aliased_parameters(hlo)
 
 
 # ---------------------------------------------------------------------------
@@ -712,7 +729,7 @@ def test_gated_delta_kernels_at_olmo_widths(chip, form):
     assert 5 in _aliased_parameters(hlo)
 
 
-def test_olmo_hybrid_decode_engine_executables(chip):
+def test_olmo_hybrid_decode_engine_executables():
     """The prefill chunk and the decode step of Olmo-Hybrid at the
     benchmark's widths, pool and slots (one period of its two: linear,
     linear, linear, full): a delta-rule call a linear layer under the
@@ -721,71 +738,36 @@ def test_olmo_hybrid_decode_engine_executables(chip):
     finds, in the decode step and not in the chunk, and finds nothing
     else by), and both kinds of cache — K/V pages and per-sequence state
     blocks — donated, row-major and UNCOPIED."""
-    import json
-    import os
-
-    import ml_dtypes
-
-    from paddle_tpu.models import olmo_hybrid
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "olmo-hybrid-7b-pp4.json")) as f:
-        config = json.load(f)
-    args = dict(config["builder"]["config_args"], num_hidden_layers=4,
-                layer_types=config["layer_types"][:4])
-    cfg = olmo_hybrid.OlmoHybridConfig(**args)
-    lm, lm_start = fluid.Program(), fluid.Program()
-    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
-        olmo_hybrid.build_olmo_hybrid_lm(cfg)
-    scope = fluid.Scope()
-    for p in lm.global_block().all_parameters():
-        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                 else np.dtype(p.dtype))
-        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
-                                          tuple(p.shape)))
-    e = config["engine"]
-    engine = serving.DecodeEngine(
-        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
-        page_size=e["page_size"], max_len=e["max_len"], name="aot-olmo",
-        auto_start=False)
-    assert engine.prefill_chunk == 512
-    assert engine.pool.num_pages == _OLMO_PAGES
-    assert engine.pool.state_blocks == _OLMO_BLOCKS
-    gdn = {form: re.compile(harness_json(root, f"gdn_{metric}.serve")
+    served = _served("olmo-hybrid-7b-pp4")
+    assert served.prefill_chunk == 512
+    assert served.pool.num_pages == _OLMO_PAGES
+    assert served.pool.state_blocks == _OLMO_BLOCKS
+    gdn = {form: re.compile(harness_json(_ROOT, f"gdn_{metric}.serve")
                             ["pattern"])
            for form, metric in (("chunk", "chunk_mxu_share"),
                                 ("step", "step_roofline"))}
     seen_by_roofline = []
-    try:
-        with lowering_for("tpu"):
-            for form, lowered in zip(("chunk", "step"),
-                                     engine.lower(sharding=chip)):
-                compiled = lowered.compile()
-                hlo = compiled.as_text()
-                lines = [line.strip()
-                         for line in _long_hlo(compiled).splitlines()]
-                seen_by_roofline.append(sum(
-                    bool(_roofline_pattern().search(x)) for x in lines))
-                assert sum(bool(gdn[form].search(x)) for x in lines) == 3
-                other = "step" if form == "chunk" else "chunk"
-                assert sum(bool(gdn[other].search(x)) for x in lines) == 0
-                assert _mosaic_calls(hlo) == 3 + 1
-                assert _pool_copies(hlo, _OLMO_PAGES, 128) == []
-                kv = _pool_parameters(hlo, f"{_OLMO_PAGES},128,3840")
-                assert len(kv) == 2                        # K and V
-                state = _pool_parameters(hlo, "18,96,5760")
-                tails = _pool_parameters(hlo, "18,34560")
-                assert len(state) == len(tails) == 3       # a linear layer
-                assert _state_copies(hlo, "18,96,5760") == []
-                assert _state_copies(hlo, "18,34560") == []
-                assert [lay for _, lay in kv + state
-                        if not lay.startswith("{2,1,0")] == []
-                assert {num for num, _ in kv + state + tails} <= \
-                    _aliased_parameters(hlo)
-        assert seen_by_roofline == [0, 1]
-    finally:
-        engine.close()
+    for form in ("chunk", "step"):
+        hlo, lines = served.exes[form]
+        seen_by_roofline.append(sum(
+            bool(_roofline_pattern().search(x)) for x in lines))
+        assert sum(bool(gdn[form].search(x)) for x in lines) == 3
+        other = "step" if form == "chunk" else "chunk"
+        assert sum(bool(gdn[other].search(x)) for x in lines) == 0
+        assert _mosaic_calls(hlo) == 3 + 1
+        assert _pool_copies(hlo, _OLMO_PAGES, 128) == []
+        kv = _pool_parameters(hlo, f"{_OLMO_PAGES},128,3840")
+        assert len(kv) == 2                        # K and V
+        state = _pool_parameters(hlo, "18,96,5760")
+        tails = _pool_parameters(hlo, "18,34560")
+        assert len(state) == len(tails) == 3       # a linear layer
+        assert _state_copies(hlo, "18,96,5760") == []
+        assert _state_copies(hlo, "18,34560") == []
+        assert [lay for _, lay in kv + state
+                if not lay.startswith("{2,1,0")] == []
+        assert {num for num, _ in kv + state + tails} <= \
+            _aliased_parameters(hlo)
+    assert seen_by_roofline == [0, 1]
 
 
 # ---------------------------------------------------------------------------
@@ -829,7 +811,7 @@ def test_paged_attention_asym_and_sink_at_mimo_widths(chip, b, t, window):
     assert _pool_copies(hlo, pages, 128) == []
 
 
-def test_mimo_decode_engine_executables(chip):
+def test_mimo_decode_engine_executables():
     """The prefill chunk and the decode step of MiMo-V2.5 at the
     benchmark's widths, pool and slots (three of its seven layers: the
     dense full one, a window and a full expert layer... cut to the dense
@@ -838,78 +820,117 @@ def test_mimo_decode_engine_executables(chip):
     three attention patterns read —, three grouped products an expert
     layer, each kind's pool tensors at that kind's pages AND widths,
     donated, row-major and UNCOPIED."""
-    import json
-    import os
-
-    import ml_dtypes
-
-    from paddle_tpu.models import mimo
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "mimo-v2.5-ep16.json")) as f:
-        config = json.load(f)
-    args = dict(config["builder"]["config_args"], num_hidden_layers=3,
-                hybrid_layer_pattern=[0, 1, 0], moe_layer_freq=[0, 1, 1])
-    cfg = mimo.MiMoConfig(**args)
-    lm, lm_start = fluid.Program(), fluid.Program()
-    with fluid.program_guard(lm, lm_start), fluid.unique_name.guard():
-        mimo.build_mimo_lm(cfg)
-    scope = fluid.Scope()
-    for p in lm.global_block().all_parameters():
-        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                 else np.dtype(p.dtype))
-        scope.set(p.name, np.broadcast_to(np.zeros((), dtype),
-                                          tuple(p.shape)))
-    e = config["engine"]
-    engine = serving.DecodeEngine(
-        cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=e["pool_slots"],
-        page_size=e["page_size"], max_len=e["max_len"], name="aot-mimo",
-        auto_start=False)
-    assert engine.prefill_chunk == 512
-    assert engine.pool.pages_by_kind() == _MIMO_PAGES
+    served = _served("mimo-v2.5-ep16")
+    assert served.prefill_chunk == 512
+    assert served.pool.pages_by_kind() == _MIMO_PAGES
     # the issue's bytes, at the configuration's seven layers
     per_page = 128 * 2
-    assert engine.pool.kind_bytes("full") == 2 * 4353 * per_page * 1280
-    assert engine.pool.kind_bytes("window128") == 97 * per_page * 2560
+    assert served.pool.kind_bytes("full") == 2 * 4353 * per_page * 1280
+    assert served.pool.kind_bytes("window128") == 97 * per_page * 2560
     patterns = {
-        name: re.compile(harness_json(root, name + ".serve")["pattern"])
+        name: re.compile(harness_json(_ROOT, name + ".serve")["pattern"])
         for name in ("sink_window_attn_roofline", "asym_full_attn_roofline",
                      "asym_attn_chunk_mxu_share", "full_attn_roofline",
                      "window_attn_roofline")}
-    try:
-        with lowering_for("tpu"):
-            for which, lowered in zip(("chunk", "step"),
-                                      engine.lower(sharding=chip)):
-                compiled = lowered.compile()
-                hlo = compiled.as_text()
-                lines = [line.strip()
-                         for line in _long_hlo(compiled).splitlines()]
+    for which, (hlo, lines) in served.exes.items():
 
-                def seen(name):
-                    return sum(bool(patterns[name].search(x))
-                               for x in lines)
+        def seen(name):
+            return sum(bool(patterns[name].search(x)) for x in lines)
 
-                assert seen("asym_full_attn_roofline") == 2
-                assert seen("sink_window_attn_roofline") == 1
-                assert seen("asym_attn_chunk_mxu_share") == 3
-                # Trinity's patterns find none of this model's calls
-                assert seen("full_attn_roofline") == 0
-                assert seen("window_attn_roofline") == 0
-                assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
-                assert _mosaic_calls(hlo) == 3 + 6, which
-                pools = []
-                for kind, layers, heads in (("full", 2, 4),
-                                            ("window128", 1, 8)):
-                    pages = _MIMO_PAGES[kind]
-                    assert _pool_copies(hlo, pages, 128) == []
-                    for width in (heads * 192, heads * 128):    # K, V
-                        params = _pool_parameters(
-                            hlo, f"{pages},128,{width}")
-                        assert len(params) == layers
-                        assert [lay for _, lay in params
-                                if not lay.startswith("{2,1,0")] == []
-                        pools += params
-                assert {num for num, _ in pools} <= _aliased_parameters(hlo)
-    finally:
-        engine.close()
+        assert seen("asym_full_attn_roofline") == 2
+        assert seen("sink_window_attn_roofline") == 1
+        assert seen("asym_attn_chunk_mxu_share") == 3
+        # Trinity's patterns find none of this model's calls
+        assert seen("full_attn_roofline") == 0
+        assert seen("window_attn_roofline") == 0
+        assert len(re.findall(r"%grouped_matmul[.\d]* = ", hlo)) == 6
+        assert _mosaic_calls(hlo) == 3 + 6, which
+        pools = []
+        for kind, layers, heads in (("full", 2, 4), ("window128", 1, 8)):
+            pages = _MIMO_PAGES[kind]
+            assert _pool_copies(hlo, pages, 128) == []
+            for width in (heads * 192, heads * 128):    # K, V
+                params = _pool_parameters(hlo, f"{pages},128,{width}")
+                assert len(params) == layers
+                assert [lay for _, lay in params
+                        if not lay.startswith("{2,1,0")] == []
+                pools += params
+        assert {num for num, _ in pools} <= _aliased_parameters(hlo)
+
+
+# ---------------------------------------------------------------------------
+# What the compiled executables COPY (PR 42).  Where a product is reshaped
+# into heads that are no whole lane tiles, XLA:TPU's layout assignment
+# pays with a copy of the read-only WEIGHT through HBM in every run
+# (MiMo's 100.7-MB W_q in every layer of a chunk and in one of a step);
+# `weight_matmul` pins its product's layout where the caller states such
+# heads under a weight as large (ops/mla_ops.py _pin_product).  A copy
+# whose result lies in fast memory (`S(1)` in its layout) is NOT such a
+# pass: it is XLA's fetch of the weight, its one read from HBM, and
+# taking those away cost Olmo-Hybrid's step 3.4% on the chip (PERF.md
+# section 6, PR 42): every executable the rule does not pin compiles
+# what it compiled
+# ---------------------------------------------------------------------------
+
+
+def _copies(hlo):
+    """(dims, bytes, whether into fast memory) of every ``copy`` of the
+    compiled module, those inside fusions too."""
+    from paddle_tpu.observability.profiling import _shape_bytes
+
+    return [(tuple(int(d) for d in dims.split(",") if d),
+             _shape_bytes(f"{dtype}[{dims}]"), "S(1)" in layout)
+            for dtype, dims, layout in re.findall(
+                r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\](\S*) copy\(", hlo,
+                re.M)]
+
+
+def _matrix_parameters(hlo):
+    """Shapes of the entry's 2-D parameters (the weights), and their
+    transposes."""
+    entry = hlo[hlo.index("ENTRY "):]
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= \w+\[(\d+,\d+)\]\S* parameter\(",
+                                     entry)}
+    return shapes | {s[::-1] for s in shapes}
+
+
+# {(config, executable): (MB of copies at the parent 564dea6, MB now)}, at
+# this file's cuts, fetches into fast memory included (of MiMo's step
+# 227.8 MB were such fetches and 101.4 one W_q through HBM; of its chunk
+# 136.6 and 321.7)
+_COPY_MB = {
+    ("mimo-v2.5-ep16", "chunk"): (458.2, 131.1),
+    ("mimo-v2.5-ep16", "step"): (329.2, 0.8),
+    ("trinity-large-ep8", "chunk"): (289.4, 289.4),
+    ("trinity-large-ep8", "step"): (134.7, 134.7),
+    ("glm-5-ep16", "chunk"): (359.9, 359.9),
+    ("glm-5-ep16", "step"): (179.1, 179.1),
+    ("olmo-hybrid-7b-pp4", "chunk"): (213.1, 213.1),
+    ("olmo-hybrid-7b-pp4", "step"): (8.5, 8.5),
+    ("kimi-vl-a3b-ep1", "chunk"): (54.1, 33.1),
+    ("kimi-vl-a3b-ep1", "step"): (26.7, 1.7),
+    ("kimi-vl-a3b-ep1", "tower0"): (177.2, 177.2),
+    ("kimi-vl-a3b-ep1", "tower1"): (257.4, 257.4),
+    ("kimi-vl-a3b-ep1", "tower2"): (85.2, 85.2),
+}
+_PINNED_CHUNKS = {("mimo-v2.5-ep16", "chunk"), ("kimi-vl-a3b-ep1", "chunk")}
+
+
+@pytest.mark.parametrize("name,exe", list(_COPY_MB))
+def test_served_executables_copy_no_more_than_they_did(name, exe):
+    """(a) No decode step, and no chunk whose products the rule pins,
+    copies a tensor of a weight's shape (or its transpose) of 1 MiB or
+    more THROUGH HBM; (b) no executable, the tower's three included,
+    copies more bytes than it did at the parent (half a MB of room), nor
+    more than this table says it copies now."""
+    parent, now = _COPY_MB[(name, exe)]
+    assert now <= parent
+    hlo, _ = _served(name).exes[exe]
+    copies = _copies(hlo)
+    assert sum(n for _, n, _ in copies) / 1e6 <= now + 0.5
+    if exe == "step" or (name, exe) in _PINNED_CHUNKS:
+        weights = _matrix_parameters(hlo)
+        assert len(weights) >= 8
+        assert [dims for dims, n, fast in copies
+                if dims in weights and n >= 2 ** 20 and not fast] == []

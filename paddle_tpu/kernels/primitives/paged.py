@@ -50,9 +50,19 @@ two bodies — the decode row still copies its live pages itself, now
 starting at the first page its window reaches, with the g query heads of
 a K/V head as g rows on that head's lanes; the chunk gets a grid axis a
 K/V head (a (page, d) lane block of the pool as stored, so d must be a
-multiple of 128 on the chip) and scores the group's g x T query rows at
-once, over the steps between its first query's window and its last
-query, and no others.  Pages below the bound are neither fetched nor
+multiple of 128 on the chip) and scores the group's g x tq query rows of
+a tile at once, over the steps between its first query's window and its
+last query, and no others.  **The chunk's grid step** (PR 43): the query
+tile and the keys a step are chosen TOGETHER from the shapes
+(``_chunk_geometry``: as many of the group's rows as one score product
+takes, a tile no longer than half a window, then as many whole pages as
+the float32 score tiles and the layer's span allow — 256 queries x 1024
+keys for Trinity's 6 heads, 512 x 1024 for one head a K/V head, 64 x
+1024 for MiMo's 16 heads and 64 x 256 under its window of 128), the
+grid's step axis ends at the last step the call's chunk reaches (a
+traced extent), and a step that every query of the tile sees whole is
+scored without a mask.  The decode row's G is not the chunk's: it stays
+what ``_pages_per_step`` gives.  Pages below the bound are neither fetched nor
 scored: **a table entry wholly below a row's window may be anything**
 (the allocator points it at the trash page once the page is given
 back, serving/kv_pool.py); what the kernel assumes of the trash page
@@ -227,20 +237,24 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, q_start,
 #       starting at the page the row's window reaches.  T > 1
 #       (_paged_kv_head_kernel): grid (B, n_kv, query tiles, steps), a
 #       K/V head's (page, d) lane block a page, the group's g x tq query
-#       rows stacked into one [g*tq, d] operand, so that a chunk of 512
-#       queries of 6 heads meets a K page as a 3072-row product; the
-#       steps start at the tile's first query's window and the grid has
-#       only as many as a window and a tile span.  A bf16 pool feeds
-#       the MXU in bf16 (float32 accumulation, float32 softmax state).
+#       rows stacked into one [g*tq, d] operand, so that a tile of 256
+#       queries of 6 heads meets 1024 keys as a [1536, 128] x [128, 1024]
+#       product (_chunk_geometry, PR 43); the steps start at the tile's
+#       first query's window, the grid has only as many as a window and
+#       a tile span and ends at the last one the chunk reaches.  A bf16
+#       pool feeds the MXU in bf16 (float32 accumulation, float32
+#       softmax state).
 # ---------------------------------------------------------------------------
 
 _SUBLANES = 8
-# keys scored at once, and the VMEM the two slots (or the double-buffered
-# blocks) of one pool form may take: G = pages_per_step is the most pages
-# inside both — 8 at page 32.  On the chip G = 4 / 8 / 16 read 123 / 118
-# / 113 us a decode call at GPT-2-large's mixed contexts and 300 / 270 /
-# 259 at 1024 tokens (PERF.md section 6, PR 30): flat, so the smaller
-# VMEM footprint wins
+# keys scored at once by a DECODE ROW and by the per-head chunk body, and
+# the VMEM the two slots (or the double-buffered blocks) of one pool form
+# may take: G = pages_per_step is the most pages inside both — 8 at page
+# 32.  It is the decode row's measurement: on the chip G = 4 / 8 / 16
+# read 123 / 118 / 113 us a decode call at GPT-2-large's mixed contexts
+# and 300 / 270 / 259 at 1024 tokens (PERF.md section 6, PR 30): flat, so
+# the smaller VMEM footprint wins.  No chunk was in it: the grouped and
+# lane-block chunk bodies take their step from ``_chunk_geometry``
 _KEYS_PER_STEP = 256
 _BLOCK_VMEM_BYTES = 8 << 20
 
@@ -561,14 +575,46 @@ def _first_step(start, window, keys):
     return jax.lax.div(jnp.maximum(start - (window - 1), 0), keys)
 
 
-def _paged_kv_head_kernel(page_table_ref, q_start_ref, q_ref, *refs, n_sub,
-                          keys, tq, heads_per_kv, n_steps, window,
-                          sm_scale):
+def _chunk_step(q_start_ref, bi, qi, pi, *, keys, tq, window):
+    """Where one grid step of a chunk body stands: (the position of its
+    query tile's first query, the step's first key, whether a query of
+    the tile sees a key of the step, whether EVERY query of the tile
+    sees every key of it).  The tile's steps count from the one its
+    first query's window reaches."""
+    start = q_start_ref[bi] + qi * tq
+    step = pi if window is None else _first_step(start, window, keys) + pi
+    first_key = step * keys
+    live = first_key <= start + tq - 1
+    # not on the causal edge: the step's last key is the first query's
+    # own or older; not on the window's lower edge: its first key is
+    # inside the last query's window
+    whole = first_key + keys - 1 <= start
+    if window is not None:
+        whole &= first_key > start + tq - 1 - window
+    return start, first_key, live, whole
+
+
+def _chunk_mask(start, first_key, rows, keys, tq, window):
+    """[rows, keys] bool: the keys of a step that row r*tq + i, query i
+    of a tile that starts at position ``start``, sees."""
+    kpos = first_key + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+    qpos = start + jax.lax.rem(jax.lax.broadcasted_iota(
+        jnp.int32, (rows, keys), 0), tq)
+    visible = kpos <= qpos
+    if window is not None:
+        visible &= kpos > qpos - window
+    return visible
+
+
+def _paged_kv_head_kernel(page_table_ref, q_start_ref, run_ref, q_ref, *refs,
+                          n_sub, keys, tq, heads_per_kv, window, sm_scale):
     """The grouped body of a chunk (T > 1): one grid step is one K/V
     head (its d lanes of ``n_sub`` pages), one tile of ``tq`` queries
     and one step of ``keys`` keys.  q_ref / o_ref [g*tq, d]: row
-    r*tq + i is query i of the group's r-th head.  The tile's steps
-    count from the one its first query's window reaches."""
+    r*tq + i is query i of the group's r-th head.  A step that every
+    query of the tile sees whole is scored without a mask; only the
+    steps on the causal and the window's edges are masked.  The grid's
+    step axis ends at ``run_ref[0]`` steps."""
     from jax.experimental import pallas as pl
 
     k_refs, v_refs = refs[:n_sub], refs[n_sub:2 * n_sub]
@@ -577,38 +623,72 @@ def _paged_kv_head_kernel(page_table_ref, q_start_ref, q_ref, *refs, n_sub,
     rows = heads_per_kv * tq
 
     pl.when(pi == 0)(lambda: _init_state(acc_ref, m_ref, l_ref))
-    start = q_start_ref[bi] + qi * tq      # the tile's first query
-    step = pi if window is None else _first_step(start, window, keys) + pi
+    start, first_key, live, whole = _chunk_step(
+        q_start_ref, bi, qi, pi, keys=keys, tq=tq, window=window)
 
-    @pl.when(step * keys <= start + tq - 1)
-    def _step():
-        kpos = step * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
-        qpos = start + jax.lax.rem(jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 0), tq)
-        visible = kpos <= qpos
-        if window is not None:
-            visible &= kpos > qpos - window
+    def score(masked):
         k, v = _mxu(_step_pages(k_refs)), _mxu(_step_pages(v_refs))
         s = jax.lax.dot_general(
             q_ref[0, 0, 0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [rows, keys]
-        s = jnp.where(visible, s * sm_scale, NEG_INF)
+        s = s * sm_scale
+        if masked:
+            s = jnp.where(_chunk_mask(start, first_key, rows, keys, tq,
+                                      window), s, NEG_INF)
         _online_softmax_step(s, v, acc_ref, m_ref, l_ref,
                              p_dtype=_p_dtype(v.dtype))
 
-    @pl.when(pi == n_steps - 1)
+    pl.when(live & whole)(lambda: score(False))
+    pl.when(live & jnp.logical_not(whole))(lambda: score(True))
+
+    @pl.when(pi == run_ref[0] - 1)
     def _finish():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
 
 
-# query rows (heads of a group x queries of a tile) one grid step of the
-# grouped chunk body scores: its [rows, keys] float32 scores and
-# probabilities, the accumulator and the double-buffered q and o blocks
-# stay inside ~10 MB of VMEM at d = 128
-_QUERY_ROWS_PER_STEP = 1536
+# What a grid step of a chunk body (T > 1: the grouped and the lane-block
+# launch) may hold (PR 43): the rows of one score product (the heads of
+# a group x the queries of a tile), the keys it scores at once, and the
+# float32 bytes of the [rows, keys] score tiles of the K/V heads a step
+# scores (one; a lane block's P).  Every geometry they admit at the
+# served widths compiles under Mosaic's default 16 MiB of scoped VMEM
+# (tests/test_mosaic_aot.py); the readings behind them are in
+# docs/KERNELS.md ("The chunk's grid step"): on a v5e more rows at equal
+# keys read faster up to 1536 (Trinity's 6 heads x 256), more keys than
+# 1024 read no faster, and 1024 rows x 1536 keys of a lane block of two
+# heads no longer fit
+_CHUNK_ROWS_PER_STEP = 1536
+_CHUNK_KEYS_PER_STEP = 1024
+_CHUNK_SCORE_BYTES = 8 << 20
+
+
+def _chunk_geometry(tp, heads_per_kv, kv_heads, page_size, max_pages,
+                    window):
+    """(query tile, pages a step) of a chunk's launch, chosen together
+    from the shapes.  The tile: the largest sublane-multiple divisor of
+    the padded chunk ``tp`` whose group's rows fit one score product (as
+    few tiles as may be stream K and V again) and that is no longer than
+    half a window (a tile scores window + tq keys a query, of which each
+    query sees window).  The step: the most whole pages within the keys
+    a step may score, the score tiles' bytes of the ``kv_heads`` heads
+    it scores, and what the layer can use: the table, or what a window
+    and a tile span."""
+    tq = _SUBLANES
+    for tiles in range(1, tp // _SUBLANES + 1):
+        tile = tp // tiles
+        if (tp % tiles == 0 and tile % _SUBLANES == 0
+                and heads_per_kv * tile <= _CHUNK_ROWS_PER_STEP
+                and (window is None or 2 * tile <= window)):
+            tq = tile
+            break
+    keys = min(_CHUNK_KEYS_PER_STEP,
+               _CHUNK_SCORE_BYTES // (4 * kv_heads * heads_per_kv * tq))
+    usable = max_pages
+    if window is not None:
+        usable = min(usable, -(-(int(window) + tq) // page_size))
+    return tq, max(1, min(keys // page_size, usable))
 
 
 # most bytes the per-head chunk body's whole-chunk blocks may take: its
@@ -621,17 +701,6 @@ def _per_head_block_bytes(q):
     _, n, t, d = q.shape
     tp = -(-t // _SUBLANES) * _SUBLANES
     return n * tp * (4 * d * q.dtype.itemsize + 4 * d + 2 * 4 * 128)
-
-
-def _query_tile(tp, heads_per_kv):
-    """Queries a tile: the whole (padded) block where its group's rows
-    fit, else the largest sublane-multiple divisor of it that does."""
-    for tiles in range(1, tp // _SUBLANES + 1):
-        tq = tp // tiles
-        if (tp % tiles == 0 and tq % _SUBLANES == 0
-                and heads_per_kv * tq <= _QUERY_ROWS_PER_STEP):
-            return tq
-    return _SUBLANES
 
 
 def _pages_per_step(name, q, page_size, max_pages, pools):
@@ -660,7 +729,9 @@ def _book_form(primitive, form, pages_per_step):
         "Trace-time choices of the paged-attention Pallas kernel's body "
         "(heads_batched = all heads in one product, a decode row; "
         "per_head = heads as lane slices, a prefill chunk or the int8 "
-        "pool) and the pages one grid step reads",
+        "pool; kv_head_tq<tq> = a grid axis a K/V head or lane block, a "
+        "grouped chunk in tiles of tq queries) and the pages one grid "
+        "step reads",
         labels=("primitive", "form", "pages_per_step"),
     ).labels(primitive=primitive, form=form,
              pages_per_step=str(pages_per_step)).inc()
@@ -735,25 +806,56 @@ def _heads_batched_call(name, q, pools, page_table, q_start, scale,
     return out.reshape(b, n, 1, d if d_v is None else d_v)
 
 
-def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret, g,
+def _chunk_grid(name, t, heads_per_kv, kv_heads, page_table, q_start,
+                page_size, window):
+    """The query and step axes of a chunk's launch: (the chunk padded to
+    sublanes, the query tile, the pages and the keys a step, the steps
+    THIS call runs, the page table padded with the trash page, q_start
+    as int32), the geometry booked as ``form="kv_head_tq<tq>"``.  The grid's step axis ends at the last
+    step a row's chunk reaches (a traced extent [1], as grouped.py's
+    visits): a step past every row's last query moves nothing, and costs
+    its block specs' bookkeeping all the same (Trinity's full layer at
+    4k of context under a 33k table, 256 queries x 512 keys a step:
+    1.17 -> 0.76 ms a call on a v5e, docs/KERNELS.md)."""
+    max_pages = page_table.shape[1]
+    tp = -(-t // _SUBLANES) * _SUBLANES
+    tq, g = _chunk_geometry(tp, heads_per_kv, kv_heads, page_size,
+                            max_pages, window)
+    _book_form(name, f"kv_head_tq{tq}", g)
+    keys = g * page_size
+    steps = -(-max_pages // g)
+    if window is not None:  # a window and a tile span so many steps
+        steps = min(steps, -(-(int(window) + tq - 1) // keys) + 1)
+    q_start = q_start.astype(jnp.int32)
+    run = jnp.clip((jnp.max(q_start) + tp - 1) // keys + 1, 1, steps)
+    # whole steps, and the steps a window's first may run past the
+    # table: pad with the trash page
+    pad = (-(-max_pages // g) + steps) * g - max_pages
+    page_table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
+    return tp, tq, g, keys, run.reshape(1), page_table, q_start
+
+
+def _chunk_kv_map(j, g, keys, tq, window):
+    """The index map of a chunk step's j-th page: lane block ``hj`` of
+    the page the table names, the tile's steps counted from the one its
+    first query's window reaches."""
+    def index(bi, hj, qi, pi, pt, qs, run):
+        step = pi if window is None else _first_step(
+            qs[bi] + qi * tq, window, keys) + pi
+        return (pt[bi, step * g + j], 0, hj)
+    return index
+
+
+def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret,
                   heads_per_kv, window):
     """The grouped chunk's launch (T > 1): grid (B, n_kv, query tiles,
     steps), a K/V head's lane block a page."""
     b, n, t, d = q.shape
     page_size = pools[0].shape[1]
     n_kv = n // heads_per_kv
-    max_pages = page_table.shape[1]
-    keys = g * page_size
-    tp = -(-t // _SUBLANES) * _SUBLANES
-    tq = _query_tile(tp, heads_per_kv)
+    tp, tq, g, keys, run, page_table, q_start = _chunk_grid(
+        name, t, heads_per_kv, 1, page_table, q_start, page_size, window)
     tiles = tp // tq
-    steps = -(-max_pages // g)
-    if window is not None:  # a window and a tile span so many steps
-        steps = min(steps, -(-(int(window) + tq - 1) // keys) + 1)
-    # whole steps, and the steps a window's first may run past the
-    # table: pad with the trash page
-    pad = (-(-max_pages // g) + steps) * g - max_pages
-    page_table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
     if tp != t:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
     # [B, n_kv, tiles, g*tq, d]: row r*tq + i of a tile is query i of
@@ -762,35 +864,28 @@ def _kv_head_call(name, q, pools, page_table, q_start, scale, interpret, g,
     q = q.reshape(b, n_kv, heads_per_kv, tiles, tq, d).transpose(
         0, 1, 3, 2, 4, 5).reshape(b, n_kv, tiles, rows, d)
 
-    def q_map(bi, hj, qi, pi, pt, qs):
+    def q_map(bi, hj, qi, pi, pt, qs, run):
         return (bi, hj, qi, 0, 0)
-
-    def kv_map(j):
-        def index(bi, hj, qi, pi, pt, qs):
-            step = pi if window is None else _first_step(
-                qs[bi] + qi * tq, window, keys) + pi
-            return (pt[bi, step * g + j], 0, hj)
-        return index
 
     spec = contract.make_spec(
         name,
-        grid=(b, n_kv, tiles, steps),
+        grid=(b, n_kv, tiles, run[0]),
         in_specs=[Block((1, 1, 1, rows, d), q_map)]
-        + [Block((1, page_size, d), kv_map(j))
+        + [Block((1, page_size, d), _chunk_kv_map(j, g, keys, tq, window))
            for _ in pools for j in range(g)],
         out_specs=[Block((1, 1, 1, rows, d), q_map)],
         out_shape=[((b, n_kv, tiles, rows, d), q.dtype)],
         scratch=[Vmem((rows, d), jnp.float32),
                  Vmem((rows, 128), jnp.float32),
                  Vmem((rows, 128), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         interpret=interpret,
     )
     out = contract.primitive_call(
         functools.partial(_paged_kv_head_kernel, n_sub=g, keys=keys, tq=tq,
-                          heads_per_kv=heads_per_kv, n_steps=steps,
-                          window=window, sm_scale=scale),
-        spec, page_table, q_start.astype(jnp.int32), q,
+                          heads_per_kv=heads_per_kv, window=window,
+                          sm_scale=scale),
+        spec, page_table, q_start, run, q,
         *[x for x in pools for _ in range(g)])
     out = out.reshape(b, n_kv, tiles, heads_per_kv, tq, d).transpose(
         0, 1, 3, 2, 4, 5).reshape(b, n, tp, d)
@@ -814,9 +909,9 @@ def _lane_block_heads(d, d_v):
     return p, max(w for _, w, _, _ in heads), heads
 
 
-def _paged_lane_block_kernel(page_table_ref, q_start_ref, q_ref, *refs,
-                             n_sub, keys, tq, heads_per_kv, heads, d_v,
-                             n_steps, window, sm_scale, sink):
+def _paged_lane_block_kernel(page_table_ref, q_start_ref, run_ref, q_ref,
+                             *refs, n_sub, keys, tq, heads_per_kv, heads,
+                             d_v, window, sm_scale, sink):
     """The asymmetric body of a chunk (T > 1): one grid step is one lane
     block of K/V heads (``heads``, ``_lane_block_heads``), one tile of
     ``tq`` queries and one step of ``keys`` keys.  q_ref
@@ -845,30 +940,29 @@ def _paged_lane_block_kernel(page_table_ref, q_start_ref, q_ref, *refs,
                         sink_ref[0, h, r:r + 1, :], (tq, 128)),
                         m_ref.at[h, at], l_ref.at[h, at])
 
-    start = q_start_ref[bi] + qi * tq      # the tile's first query
-    step = pi if window is None else _first_step(start, window, keys) + pi
+    start, first_key, live, whole = _chunk_step(
+        q_start_ref, bi, qi, pi, keys=keys, tq=tq, window=window)
 
-    @pl.when(step * keys <= start + tq - 1)
-    def _step():
-        kpos = step * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
-        qpos = start + jax.lax.rem(jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 0), tq)
-        visible = kpos <= qpos
-        if window is not None:
-            visible &= kpos > qpos - window
+    def score(masked):
         k, v = _mxu(_step_pages(k_refs)), _mxu(_step_pages(v_refs))
+        if masked:
+            visible = _chunk_mask(start, first_key, rows, keys, tq, window)
         for h, (first, width, _, v_first) in enumerate(heads):
             s = jax.lax.dot_general(
                 q_ref[0, 0, 0, h, :, :width].astype(k.dtype),
                 k[:, first:first + width], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)        # [rows, keys]
-            s = jnp.where(visible, s * sm_scale, NEG_INF)
+            s = s * sm_scale
+            if masked:
+                s = jnp.where(visible, s, NEG_INF)
             _online_softmax_step(s, v[:, v_first:v_first + d_v],
                                  acc_ref.at[h], m_ref.at[h], l_ref.at[h],
                                  p_dtype=_p_dtype(v.dtype))
 
-    @pl.when(pi == n_steps - 1)
+    pl.when(live & whole)(lambda: score(False))
+    pl.when(live & jnp.logical_not(whole))(lambda: score(True))
+
+    @pl.when(pi == run_ref[0] - 1)
     def _finish():
         for h in range(len(heads)):
             l = l_ref[h]
@@ -878,7 +972,7 @@ def _paged_lane_block_kernel(page_table_ref, q_start_ref, q_ref, *refs,
 
 
 def _lane_block_call(name, q, pools, page_table, q_start, scale, interpret,
-                     g, heads_per_kv, window, d_v, sinks):
+                     heads_per_kv, window, d_v, sinks):
     """The asymmetric chunk's launch (T > 1): grid (B, lane blocks of
     K/V heads, query tiles, steps), ``_kv_head_call``'s with a lane
     block of P heads where that has one head."""
@@ -893,16 +987,9 @@ def _lane_block_call(name, q, pools, page_table, q_start, scale, interpret,
             f"heads with V heads of {d_v} fit neither (the XLA reference "
             f"form takes any widths)")
     blocks = n_kv // p
-    max_pages = page_table.shape[1]
-    keys = g * page_size
-    tp = -(-t // _SUBLANES) * _SUBLANES
-    tq = _query_tile(tp, heads_per_kv * p)
+    tp, tq, g, keys, run, page_table, q_start = _chunk_grid(
+        name, t, heads_per_kv, p, page_table, q_start, page_size, window)
     tiles = tp // tq
-    steps = -(-max_pages // g)
-    if window is not None:  # a window and a tile span so many steps
-        steps = min(steps, -(-(int(window) + tq - 1) // keys) + 1)
-    pad = (-(-max_pages // g) + steps) * g - max_pages
-    page_table = jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, pad)))
     if tp != t:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
     if pools[0].dtype == jnp.bfloat16:
@@ -917,15 +1004,8 @@ def _lane_block_call(name, q, pools, page_table, q_start, scale, interpret,
         (inner, q_width - inner - d),))
         for h, (_, _, inner, _) in enumerate(heads)], axis=3)
 
-    def q_map(bi, hj, qi, pi, pt, qs):
+    def q_map(bi, hj, qi, pi, pt, qs, run):
         return (bi, hj, qi, 0, 0, 0)
-
-    def kv_map(j):
-        def index(bi, hj, qi, pi, pt, qs):
-            step = pi if window is None else _first_step(
-                qs[bi] + qi * tq, window, keys) + pi
-            return (pt[bi, step * g + j], 0, hj)
-        return index
 
     extra, extra_specs = (), []
     if sinks is not None:
@@ -937,28 +1017,29 @@ def _lane_block_call(name, q, pools, page_table, q_start, scale, interpret,
         extra = (jnp.broadcast_to(rows_of[..., None],
                                   (blocks, p, g8, 128)),)
         extra_specs = [Block((1, p, g8, 128),
-                             lambda bi, hj, qi, pi, pt, qs: (hj, 0, 0, 0))]
+                             lambda bi, hj, qi, pi, pt, qs, run: (
+                                 hj, 0, 0, 0))]
     spec = contract.make_spec(
         name,
-        grid=(b, blocks, tiles, steps),
+        grid=(b, blocks, tiles, run[0]),
         in_specs=[Block((1, 1, 1, p, rows, q_width), q_map)] + extra_specs
-        + [Block((1, page_size, p * w), kv_map(j))
+        + [Block((1, page_size, p * w),
+                 _chunk_kv_map(j, g, keys, tq, window))
            for w in (d, d_v) for j in range(g)],
         out_specs=[Block((1, 1, 1, p, rows, d_v), q_map)],
         out_shape=[((b, blocks, tiles, p, rows, d_v), q.dtype)],
         scratch=[Vmem((p, rows, d_v), jnp.float32),
                  Vmem((p, rows, 128), jnp.float32),
                  Vmem((p, rows, 128), jnp.float32)],
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         interpret=interpret,
     )
     out = contract.primitive_call(
         functools.partial(_paged_lane_block_kernel, n_sub=g, keys=keys,
                           tq=tq, heads_per_kv=heads_per_kv,
-                          heads=tuple(heads), d_v=d_v, n_steps=steps,
-                          window=window, sm_scale=scale,
-                          sink=sinks is not None),
-        spec, page_table, q_start.astype(jnp.int32), q, *extra,
+                          heads=tuple(heads), d_v=d_v, window=window,
+                          sm_scale=scale, sink=sinks is not None),
+        spec, page_table, q_start, run, q, *extra,
         *[x for x in pools for _ in range(g)])
     out = out.reshape(b, blocks, tiles, p, heads_per_kv, tq, d_v).transpose(
         0, 1, 3, 4, 2, 5, 6).reshape(b, n, tp, d_v)
@@ -970,16 +1051,15 @@ def _pallas_paged_asym(q, k_pages, v_pages, page_table, q_start, scale,
     """The asymmetric form's launches: a decode row through the
     heads-batched body, a chunk through the lane-block body."""
     pools = (k_pages, v_pages)
-    g = _pages_per_step(name, q, k_pages.shape[1], page_table.shape[1],
-                        pools)
     if q.shape[2] == 1:
+        g = _pages_per_step(name, q, k_pages.shape[1], page_table.shape[1],
+                            pools)
         _book_form(name, "heads_batched", g)
         return _heads_batched_call(name, q, pools, page_table, q_start,
                                    scale, interpret, g, heads_per_kv,
                                    window, d_v, sinks)
-    _book_form(name, "kv_head", g)
     return _lane_block_call(name, q, pools, page_table, q_start, scale,
-                            interpret, g, heads_per_kv, window, d_v, sinks)
+                            interpret, heads_per_kv, window, d_v, sinks)
 
 
 def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
@@ -1008,9 +1088,8 @@ def _paged_call(kernel, name, q, pools, page_table, q_start, scale,
         # tiles.  What fits keeps the launch it had
         heads_per_kv = 1
     if heads_per_kv is not None:
-        _book_form(name, "kv_head", g)
         return _kv_head_call(name, q, pools, page_table, q_start, scale,
-                             interpret, g, heads_per_kv, window)
+                             interpret, heads_per_kv, window)
     _book_form(name, "per_head", g)
     steps = -(-max_pages // g)
     page_table = page_table.astype(jnp.int32)

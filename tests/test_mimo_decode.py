@@ -477,8 +477,50 @@ def test_the_pallas_forms_match_the_reference_at_the_published_head_widths(
                                rtol=1e-4)
     name = paged.kernel_name(group, window, True, sink)
     forms = obs.snapshot()["pt_paged_attention_form_total"]["samples"]
-    assert any(k[0] == name and k[1] == ("heads_batched" if t == 1
-                                         else "kv_head") for k in forms)
+    assert any(k[0] == name and k[1].startswith(
+        "heads_batched" if t == 1 else "kv_head_tq") for k in forms)
+
+
+# (queries a tile, pages a step) of the lane-block body's grid step (PR
+# 43): one page; tq < t under several pages; a step longer than the
+# window and as long as the table
+@pytest.mark.parametrize("sink", [False, True])
+@pytest.mark.parametrize("window", [None, 16, 64])
+@pytest.mark.parametrize("group", [8, 16])
+@pytest.mark.parametrize("geometry", [(8, 1), (16, 2), (32, 4), (64, 12)])
+def test_the_lane_block_chunk_at_every_geometry(monkeypatch, geometry, group,
+                                                window, sink):
+    """A 64-token chunk over pages of 16 from 0 (its first query sees
+    one key: with a sink, two columns) and from 70 cached tokens (the
+    last step partly dead), two K heads of 192 a lane block.  A tile is
+    at most half a window long."""
+    tq, pages = geometry
+    monkeypatch.setattr(paged, "_CHUNK_ROWS_PER_STEP", group * tq)
+    monkeypatch.setattr(paged, "_CHUNK_KEYS_PER_STEP", pages * 16)
+    while window is not None and tq > 8 and 2 * tq > window:
+        tq //= 2
+    usable = 12 if window is None else min(12, -(-(window + tq) // 16))
+    assert paged._chunk_geometry(64, group, 2, 16, 12, window) == (
+        tq, min(pages, usable))
+    *args, sinks = _paged_case(2, 2 * group, 2, 64, 192, 128, 16, 12,
+                               [0, 70], seed=tq)
+    kw = {"window": window, "sinks": sinks if sink else None}
+    want = paged.paged_attention(*args, force="reference", **kw)
+    got = paged.paged_attention(*args, force="pallas", **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5,
+                               rtol=1e-4)
+    name = paged.kernel_name(group, window, True, sink)
+    forms = obs.snapshot()["pt_paged_attention_form_total"]["samples"]
+    assert forms[(name, f"kv_head_tq{tq}", str(min(pages, usable)))] >= 1
+    # the pools as the configuration stores them: bfloat16 operands,
+    # float32 scores and sums
+    low = [x.astype(jnp.bfloat16) if i in (1, 2) else x
+           for i, x in enumerate(args)]
+    got = paged.paged_attention(*low, force="pallas", **kw)
+    want = paged.paged_attention(*low, force="reference", **kw)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=3e-2,
+                               rtol=3e-2)
 
 
 @pytest.mark.parametrize("force", ["reference", "pallas"])

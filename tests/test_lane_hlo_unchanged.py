@@ -20,7 +20,12 @@ for `trinity.pallas.prefill`, which PR 43 moved by design: its text holds
 the interpreted grouped chunk body, whose query tile and keys a step now
 come from the shapes together and whose step axis ends at the last step
 the chunk reaches (`trinity.pallas.decode` stands: the decode row did
-not move).  After a deliberate change
+not move).  The `kimi_vl.*` six (its third executable is the image
+encoder of its one declared shape), the `olmo_hybrid.*` four and the
+`mimo.*` four are of commit e98f100 (PR 43's tree), made before PR 44
+moved the lanes' program scaffold out of the model files into
+`serving/lane.py`: all thirty are the proof that the move changed no
+executable.  After a deliberate change
 to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
@@ -33,7 +38,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu import fluid, serving
-from paddle_tpu.models import glm, gpt, trinity
+from paddle_tpu.models import glm, gpt, kimi_vl, mimo, olmo_hybrid, trinity
 
 GOLDEN = {
     "gpt.float32.None.prefill": "e4de139bba244c34c30e3378cea1230b90f392d45e9ccf67a0d043c67755d999",
@@ -51,22 +56,43 @@ GOLDEN = {
     "trinity.None.prefill": "6a0acaf7ee2c08877896d839f89c39e243d3bc10d4e001c30b769978814375b6",
     "trinity.None.decode": "ce29f09c2160a3c759dab784c8df9ca47db24d0ccc773a70bb2dfbefbf9175fb",
     "trinity.pallas.prefill": "f4a43df07a6f72f3aee87bad50ed93c0158aeb46c08ee96774dfe571d37cf65e",
-    "trinity.pallas.decode": "901a6a9d530abca4c35470742261f788f866b9a119c3b2f2da2f720e07e03861"
+    "trinity.pallas.decode": "901a6a9d530abca4c35470742261f788f866b9a119c3b2f2da2f720e07e03861",
+    "kimi_vl.None.prefill": "3ab5440d0790ab5dbd49c3ce241c53186efc8adcd1a8b45b9db7f450c54a1a9b",
+    "kimi_vl.None.decode": "6bd8524d832d625f9037c5c262c620436d68837a5cb31cebf32aa58982da8042",
+    "kimi_vl.None.encoder": "6b20bcce5d7cebedc5d6ae00b8dd48f61001971a6e3ba52c451e2397133014b4",
+    "kimi_vl.pallas.prefill": "b00e232159c740b15f90228784930c5925f0c4c1cff80abe9e5e4c2edf21f589",
+    "kimi_vl.pallas.decode": "e7a624f22e7d2c22b49ac339a5451104796cf8e436ce423d597e811b4f336a03",
+    "kimi_vl.pallas.encoder": "45645d79bbe5ee2fb023ea669853f92f3f037814b83388a0422c786d8f168d92",
+    "olmo_hybrid.None.prefill": "00de766abf0286e0f1a461d822489f2f3a3f370fb2e08b8e61660724f77a7dca",
+    "olmo_hybrid.None.decode": "e2110da57faecdbf733e9abe407fe5031f6eaff67363957ebe44be4bd6e2c3d2",
+    "olmo_hybrid.pallas.prefill": "6b6526dda50c081d66b7c5d86d551a603aef9a9c005d020d8940da97948e47df",
+    "olmo_hybrid.pallas.decode": "50690e7dce8990a9e437fd7c4c7e322c8e28e5f106a29f16cbc760bb1b6d2f42",
+    "mimo.None.prefill": "3c2ece044c67fda4e36e33b3ae2b728bb1251c295704a6f781a555a290e90e71",
+    "mimo.None.decode": "aa38eae686b3abd65a3cbbcc60fef6e4b5ce9004cefb869562b8a65f09bb7a4a",
+    "mimo.pallas.prefill": "948323c8f183e13c9742b70dd73a21b84939d27192a01347bb70d8776ed30d46",
+    "mimo.pallas.decode": "de58ea6e91449e77cdc780da162099b5ebee6d66b73e5ff677966152b2d1c245"
 }
 
 
-MODELS = ("gpt", "glm", "trinity")
+MODELS = ("gpt", "glm", "trinity", "kimi_vl", "olmo_hybrid", "mimo")
 
 
-def _zero_scope(build):
-    lm, start = fluid.Program(), fluid.Program()
-    with fluid.program_guard(lm, start), fluid.unique_name.guard():
-        build()
+def _zero_scope(*builds):
+    """Zeros under every parameter of the programs ``builds`` build (a
+    builder that returns ``(feeds, prepare program)``, the image
+    encoder's, gives that program's too)."""
     scope = fluid.Scope()
-    for p in lm.global_block().all_parameters():
-        dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
-                 else np.dtype(p.dtype))
-        scope.set(p.name, np.zeros(tuple(p.shape), dtype))
+    for build in builds:
+        lm, start = fluid.Program(), fluid.Program()
+        with fluid.program_guard(lm, start), fluid.unique_name.guard():
+            built = build()
+        programs = [lm] + [b for b in (built if isinstance(built, tuple)
+                                       else ()) if isinstance(b, fluid.Program)]
+        for p in (p for prog in programs
+                  for p in prog.global_block().all_parameters()):
+            dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
+                     else np.dtype(p.dtype))
+            scope.set(p.name, np.zeros(tuple(p.shape), dtype))
     return scope
 
 
@@ -75,26 +101,45 @@ def _lowered(cfg, scope, force, **kw):
         cfg, scope=scope, place=fluid.CPUPlace(), pool_slots=3, page_size=4,
         max_len=32, attn_force=force, auto_start=False, name="hlo", **kw)
     try:
-        return dict(zip(("prefill", "decode"),
+        return dict(zip(("prefill", "decode", "encoder"),
                         (low.as_text() for low in eng.lower())))
     finally:
         eng.close()
 
 
-def texts(model, force):
-    """{case: HLO text} of one model's two executables."""
-    force = None if force == "None" else force
+def _later_model(model):
+    """(tiny config, the builders that name every parameter) of a model
+    with one whole-sequence builder; kimi_vl's one declared image shape
+    makes the engine's third executable."""
     if model == "glm":
         cfg = glm.GLMConfig.tiny()
-        low = _lowered(cfg, _zero_scope(lambda: glm.build_glm_lm(cfg)),
-                       force, prefill_chunk=8)
-        return {f"glm.{force}.{which}": t for which, t in low.items()}
+        return cfg, [lambda: glm.build_glm_lm(cfg)]
     if model == "trinity":
         cfg = trinity.TrinityConfig.tiny()
-        low = _lowered(
-            cfg, _zero_scope(lambda: trinity.build_trinity_lm(cfg)), force,
-            prefill_chunk=8)
-        return {f"trinity.{force}.{which}": t for which, t in low.items()}
+        return cfg, [lambda: trinity.build_trinity_lm(cfg)]
+    if model == "kimi_vl":
+        cfg = kimi_vl.KimiVLConfig.tiny(image_grids=((4, 4),))
+        return cfg, [lambda: kimi_vl.build_kimi_vl_lm(cfg),
+                     lambda: kimi_vl.build_kimi_vl_vision_encoder(
+                         cfg, 4, 4, 16)]
+    if model == "olmo_hybrid":
+        cfg = olmo_hybrid.OlmoHybridConfig.tiny()
+        return cfg, [lambda: olmo_hybrid.build_olmo_hybrid_lm(cfg)]
+    # K heads of 192 beside V heads of 128, the published widths: the
+    # asymmetric Pallas forms read whole 128-lane tiles and take no other
+    cfg = mimo.MiMoConfig.tiny(
+        head_dim=192, v_head_dim=128, num_hidden_layers=3,
+        hybrid_layer_pattern=[0, 1, 1], moe_layer_freq=[0, 1, 1])
+    return cfg, [lambda: mimo.build_mimo_lm(cfg)]
+
+
+def texts(model, force):
+    """{case: HLO text} of one model's executables."""
+    force = None if force == "None" else force
+    if model != "gpt":
+        cfg, builds = _later_model(model)
+        low = _lowered(cfg, _zero_scope(*builds), force, prefill_chunk=8)
+        return {f"{model}.{force}.{which}": t for which, t in low.items()}
     cfg = gpt.GPTConfig.tiny()
     out = {}
     for pool_dtype in ("float32", "int8"):

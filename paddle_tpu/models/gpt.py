@@ -46,19 +46,18 @@ class GPTConfig:
 
     def decode_lane(self):
         """This model's decode-lane declaration (serving/lane.py): a K
-        and a V row a token a layer, and the two paged programs below."""
+        and a V row a token a layer, and the paged decoder below."""
         import functools
 
         from paddle_tpu.serving import lane
 
-        return lane.DecodeLane(
+        return lane.scaffold(
+            functools.partial(_paged_decoder, cfg=self),
+            functools.partial(_paged_head, cfg=self),
             num_layers=self.num_layers, max_position=self.max_position,
             cache_rows=functools.partial(
                 lane.kv_rows, self.num_heads,
-                self.hidden_size // self.num_heads),
-            build_decode_step=functools.partial(build_gpt_decode_step, self),
-            build_prefill_chunk=functools.partial(build_gpt_prefill_chunk,
-                                                  self))
+                self.hidden_size // self.num_heads))
 
 
 def _fc(x, size, name, act=None, init_std=0.02, nfd=2):
@@ -587,68 +586,35 @@ def make_fake_lm_batch(cfg: GPTConfig, batch, seq_len, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# Paged decode lane (serving/decode.py): fixed-shape prefill-chunk and
-# decode-step programs over a paged KV pool (serving/kv_pool.py).  The
-# pool vars are PERSISTABLE program vars — the executor donates their
+# Paged decode lane (serving/decode.py): the decoder and the head that
+# serving/lane.py builds the fixed-shape prefill-chunk and decode-step
+# programs around, over a paged KV pool (serving/kv_pool.py).  The pool
+# vars are PERSISTABLE program vars — the executor donates their
 # buffers, so the pool updates in place across steps, never copied.
 # ---------------------------------------------------------------------------
 
-def _declare_pool_vars(cfg: GPTConfig, num_pages, page_size, dtype,
-                       prefix=None):
-    """Per layer ``(K, V)`` pool vars — for the int8 pool
-    ``((k_hi, k_lo, k_scale), (v_hi, v_lo, v_scale))`` — declared from
-    the model's cache rows (serving/lane.py: K and V, the heads side by
-    side, so that neither executable copies the pool)."""
-    from paddle_tpu.serving import lane
+def _paged_decoder(frame, cfg: GPTConfig):
+    """Embeddings and every pre-LN block over the frame's tokens
+    (serving/lane.py ``Frame``) -> hidden [B, T, H] before the final LN:
+    each layer writes the tokens' K and V rows, the heads side by side
+    (neither executable copies the pool), and attends the prefix through
+    the page table.  The dual-int8 pool's six tensors a layer are K's and
+    V's (hi, lo, scale); its write quantises ONCE, at append."""
+    from paddle_tpu.serving.lane import FULL
 
-    rows = lane.kv_rows(cfg.num_heads, cfg.hidden_size // cfg.num_heads,
-                        dtype)
-    layers_ = lane.declare_pool_vars(rows, cfg.num_layers, num_pages,
-                                     page_size, prefix or lane.POOL_PREFIX)
-    if dtype == "int8":
-        return [(v[:3], v[3:]) for v in layers_]
-    return layers_
-
-
-def build_gpt_decode_step(cfg: GPTConfig, pool_slots, num_pages,
-                          page_size, max_pages, pool_dtype="float32",
-                          pool_prefix=None, attn_force=None):
-    """ONE token-level decode step over the paged KV pool — the single
-    fixed-shape executable the continuous-batching scheduler dispatches
-    every step (zero steady-state recompiles: every feed shape below is
-    static in `pool_slots`/`max_pages`).
-
-    Per slot s: embed dec_tok[s] at position dec_pos[s], write each
-    layer's new K/V at (dec_write_page[s], dec_write_off[s]), attend the
-    slot's pool prefix through dec_page_table[s], and emit the greedy
-    next token (log_softmax → argmax — the same op chain the
-    whole-sequence lane scores beams with, so greedy decode is
-    comparable token for token).  Inactive slots carry page-table zeros
-    (the pool's trash page) and position 0; their outputs are garbage
-    the scheduler ignores.
-
-    Returns (feed_names, next_tok [pool_slots] int64, logprobs
-    [pool_slots, vocab])."""
     L = layers
     h, n = cfg.hidden_size, cfg.num_heads
     d = h // n
-    ps = int(pool_slots)
+    table, write = frame.tables[FULL], frame.writes[FULL]
+    chunk = frame.last_idx is not None
 
-    tok = fluid.data("dec_tok", [ps, 1], False, dtype="int64")
-    pos = fluid.data("dec_pos", [ps, 1], False, dtype="int64")
-    page_table = fluid.data("dec_page_table", [ps, int(max_pages)],
-                            False, dtype="int32")
-    write_page = fluid.data("dec_write_page", [ps], False, dtype="int32")
-    write_off = fluid.data("dec_write_off", [ps], False, dtype="int32")
-    pool = _declare_pool_vars(cfg, num_pages, page_size, pool_dtype,
-                              pool_prefix)
-    q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")  # [PS]
-
-    emb = L.embedding(tok, size=[cfg.vocab_size, cfg.hidden_size],
+    emb = L.embedding(frame.tok, size=[cfg.vocab_size, cfg.hidden_size],
                       param_attr=ParamAttr(name="gpt_word_embedding"))
-    pemb = L.embedding(pos, size=[cfg.max_position, cfg.hidden_size],
+    pemb = L.embedding(frame.pos, size=[cfg.max_position, cfg.hidden_size],
                        param_attr=ParamAttr(name="gpt_pos_embedding"))
-    x = L.reshape(L.elementwise_add(emb, pemb), shape=[-1, 1, h])
+    x = L.elementwise_add(emb, pemb)
+    if frame.shape[1] == 1:  # lookup_table squeezes trailing [*, 1] ids
+        x = L.reshape(x, shape=[-1, 1, h])
 
     for li in range(cfg.num_layers):
         name = f"decoder_layer_{li}"
@@ -660,142 +626,37 @@ def build_gpt_decode_step(cfg: GPTConfig, pool_slots, num_pages,
         v = _fc(xa, h, name + "_att_value_fc",
                 init_std=cfg.initializer_range)
         q_h = L.transpose(L.reshape(q, shape=[0, 0, n, d]),
-                          perm=[0, 2, 1, 3])               # [PS, n, 1, d]
-        if pool_dtype == "int8":
-            (k_hi, k_lo, k_sc), (v_hi, v_lo, v_sc) = pool[li]
-            L.kv_cache_write_quant(k_hi, k_lo, k_sc,
-                                   L.reshape(k, shape=[-1, n, d]),
-                                   write_page, write_off)
-            L.kv_cache_write_quant(v_hi, v_lo, v_sc,
-                                   L.reshape(v, shape=[-1, n, d]),
-                                   write_page, write_off)
+                          perm=[0, 2, 1, 3])               # [B, n, T, d]
+        pools = frame.pools[li]
+        int8 = len(pools) == 6
+        if int8:
+            pools = (pools[:3], pools[3:])
+        for pool, rows in zip(pools, (k, v)):
+            rows = L.reshape(rows, shape=[-1, n, d])
+            if chunk and not int8:
+                # the KVSink dtype-stamping contract: a bf16-AMP prefill
+                # cannot silently hand bf16 arrays to an fp32 pool
+                rows = L.cast(rows, pool.dtype)
+            write(pool, rows)
+        if int8:
             ctx = L.paged_attention_quant(
-                q_h, k_hi, k_lo, k_sc, v_hi, v_lo, v_sc, page_table,
-                q_start, sm_scale=float(d) ** -0.5, force=attn_force)
+                q_h, *pools[0], *pools[1], table, frame.q_start,
+                sm_scale=float(d) ** -0.5, force=frame.attn_force)
         else:
-            k_pool, v_pool = pool[li]
-            L.kv_cache_write(k_pool, L.reshape(k, shape=[-1, n, d]),
-                             write_page, write_off)
-            L.kv_cache_write(v_pool, L.reshape(v, shape=[-1, n, d]),
-                             write_page, write_off)
-            ctx = L.paged_attention(q_h, k_pool, v_pool, page_table,
-                                    q_start, sm_scale=float(d) ** -0.5,
-                                    force=attn_force)
+            ctx = L.paged_attention(q_h, *pools, table, frame.q_start,
+                                    sm_scale=float(d) ** -0.5,
+                                    force=frame.attn_force)
         ctx = L.reshape(L.transpose(ctx, perm=[0, 2, 1, 3]),
                         shape=[0, 0, h])
         attn = _fc(ctx, h, name + "_att_output_fc",
                    init_std=cfg.initializer_range)
         x = _ffn_block(L.elementwise_add(x, attn), cfg, name)
-
-    logits = _lm_logits(_ln(x, "gpt_final_ln"), cfg)       # [PS, V]
-    logp = L.log_softmax(logits)
-    next_tok = L.argmax(logp, axis=-1)                     # [PS] int64
-    feeds = ["dec_tok", "dec_pos", "dec_page_table", "dec_write_page",
-             "dec_write_off"]
-    return feeds, next_tok, logp
+    return x
 
 
-def build_gpt_prefill_chunk(cfg: GPTConfig, chunk_len, num_pages,
-                            page_size, max_pages, pool_dtype="float32",
-                            pool_prefix=None, attn_force=None):
-    """One prefill CHUNK of a single sequence through the paged pool —
-    the phase-split half of the decode lane: long prompts stream
-    through this fixed-shape executable `ceil(P/chunk_len)` times
-    (never stalling the decode step for a whole-prompt pass), each call
-    writing the chunk's K/V into whole pool pages and attending the
-    previously-written prefix through the page table.
-
-    `chunk_len` must be a multiple of `page_size` (chunks cover whole
-    pages; the write is a clean page scatter).  The K/V captured here
-    is cast to `pool_dtype` via the same stamping contract as
-    KVSink(dtype=...) — a bf16-AMP prefill cannot silently hand bf16
-    arrays to an fp32 pool.
-
-    Feeds: pf_tok/pf_pos [1, C] int64 (positions clamped host-side for
-    the padded tail), pf_page_table [1, max_pages] int32,
-    pf_write_pages [C/page_size] int32 (trash page 0 past the valid
-    tail), pf_qstart [1] int32 (tokens already in the pool),
-    pf_last_idx [1] int64 (index of the last VALID token in this chunk
-    — only the final chunk's next-token output is consumed).
-
-    Returns (feed_names, next_tok [1] int64, logprobs [1, vocab])."""
-    L = layers
-    h, n = cfg.hidden_size, cfg.num_heads
-    d = h // n
-    c = int(chunk_len)
-    if c % int(page_size):
-        raise ValueError(
-            f"prefill chunk_len {c} must be a multiple of page_size "
-            f"{page_size} (chunks write whole pages)")
-
-    tok = fluid.data("pf_tok", [1, c], False, dtype="int64")
-    pos = fluid.data("pf_pos", [1, c], False, dtype="int64")
-    page_table = fluid.data("pf_page_table", [1, int(max_pages)], False,
-                            dtype="int32")
-    write_pages = fluid.data("pf_write_pages", [c // int(page_size)],
-                             False, dtype="int32")
-    q_start = fluid.data("pf_qstart", [1], False, dtype="int32")
-    last_idx = fluid.data("pf_last_idx", [1], False, dtype="int64")
-    pool = _declare_pool_vars(cfg, num_pages, page_size, pool_dtype,
-                              pool_prefix)
-
-    emb = L.embedding(tok, size=[cfg.vocab_size, cfg.hidden_size],
-                      param_attr=ParamAttr(name="gpt_word_embedding"))
-    pemb = L.embedding(pos, size=[cfg.max_position, cfg.hidden_size],
-                       param_attr=ParamAttr(name="gpt_pos_embedding"))
-    x = L.elementwise_add(emb, pemb)                       # [1, C, H]
-
-    sink_dtype = pool_dtype  # the KVSink dtype-stamping contract
-    for li in range(cfg.num_layers):
-        name = f"decoder_layer_{li}"
-        xa = _ln(x, name + "_ln_attn")
-        q = _fc(xa, h, name + "_att_query_fc",
-                init_std=cfg.initializer_range)
-        k = _fc(xa, h, name + "_att_key_fc",
-                init_std=cfg.initializer_range)
-        v = _fc(xa, h, name + "_att_value_fc",
-                init_std=cfg.initializer_range)
-        q_h = L.transpose(L.reshape(q, shape=[0, 0, n, d]),
-                          perm=[0, 2, 1, 3])               # [1, n, C, d]
-        if pool_dtype == "int8":
-            # no sink cast: the quant write op owns the fp32→dual-int8
-            # conversion (quantize happens ONCE at append)
-            (k_hi, k_lo, k_sc), (v_hi, v_lo, v_sc) = pool[li]
-            L.kv_cache_write_pages_quant(
-                k_hi, k_lo, k_sc, L.reshape(k, shape=[-1, n, d]),
-                write_pages)
-            L.kv_cache_write_pages_quant(
-                v_hi, v_lo, v_sc, L.reshape(v, shape=[-1, n, d]),
-                write_pages)
-            ctx = L.paged_attention_quant(
-                q_h, k_hi, k_lo, k_sc, v_hi, v_lo, v_sc, page_table,
-                q_start, sm_scale=float(d) ** -0.5, force=attn_force)
-        else:
-            k_pool, v_pool = pool[li]
-            L.kv_cache_write_pages(
-                k_pool, L.cast(L.reshape(k, shape=[-1, n, d]),
-                               sink_dtype),
-                write_pages)
-            L.kv_cache_write_pages(
-                v_pool, L.cast(L.reshape(v, shape=[-1, n, d]),
-                               sink_dtype),
-                write_pages)
-            ctx = L.paged_attention(q_h, k_pool, v_pool, page_table,
-                                    q_start, sm_scale=float(d) ** -0.5,
-                                    force=attn_force)
-        ctx = L.reshape(L.transpose(ctx, perm=[0, 2, 1, 3]),
-                        shape=[0, 0, h])
-        attn = _fc(ctx, h, name + "_att_output_fc",
-                   init_std=cfg.initializer_range)
-        x = _ffn_block(L.elementwise_add(x, attn), cfg, name)
-
-    # logits of the last VALID chunk position (exact row copy — the
-    # final chunk's output seeds the decode loop's first token)
-    flat = L.reshape(x, shape=[-1, h])                     # [C, H]
-    h_last = L.reshape(L.gather(flat, last_idx), shape=[-1, 1, h])
-    logits = _lm_logits(_ln(h_last, "gpt_final_ln"), cfg)  # [1, V]
-    logp = L.log_softmax(logits)
-    next_tok = L.argmax(logp, axis=-1)                     # [1] int64
-    feeds = ["pf_tok", "pf_pos", "pf_page_table", "pf_write_pages",
-             "pf_qstart", "pf_last_idx"]
-    return feeds, next_tok, logp
+def _paged_head(h, cfg: GPTConfig):
+    """h [N, 1, H] -> (greedy next token [N] int64, logprobs [N, V]):
+    log_softmax -> argmax, the op chain the whole-sequence lane scores
+    beams with, so greedy decode is comparable token for token."""
+    logp = layers.log_softmax(_lm_logits(_ln(h, "gpt_final_ln"), cfg))
+    return layers.argmax(logp, axis=-1), logp

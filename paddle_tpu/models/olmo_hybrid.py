@@ -33,11 +33,10 @@ SEQUENCE owns (the kind ``state``, one block a sequence): the rule's
 state ``s`` [d_k, H d_v] float32 and the convolution's last K - 1
 pre-activation inputs ``conv`` [(K - 1) (2 H d_k + H d_v)] float32.
 
-Three builders on the same parameter names: ``build_olmo_hybrid_lm`` (a
-whole sequence, caches and state program-local),
-``build_olmo_hybrid_decode_step`` and ``build_olmo_hybrid_prefill_chunk``
-(the decode lane's two executables; ``OlmoHybridConfig.decode_lane()``
-hands them to ``serving.DecodeEngine``).  Matrices are stored in
+``OlmoHybridConfig.decode_lane()`` hands ``_decoder`` and the head to
+serving/lane.py, which builds the decode lane's two executables around
+them, and ``build_olmo_hybrid_lm`` a whole sequence on the same
+parameter names, caches and state program-local.  Matrices are stored in
 ``cfg.dtype`` (bfloat16 in the serving lane) and multiplied in it with
 float32 accumulation; norm gains, the convolution's taps, ``A_log``,
 ``dt_bias``, the state and activations between ops are float32; K/V
@@ -48,10 +47,11 @@ from __future__ import annotations
 
 import functools
 
-from paddle_tpu import fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import Constant, Normal
 from paddle_tpu.fluid.param_attr import ParamAttr
+
+from .decode_blocks import _attr, _linear, _next_token, _rms, _swiglu_ffn
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -157,14 +157,12 @@ class OlmoHybridConfig:
         """This model's decode-lane declaration (serving/lane.py)."""
         from paddle_tpu.serving import lane
 
-        return lane.DecodeLane(
+        return lane.scaffold(
+            functools.partial(_decoder, cfg=self),
+            functools.partial(_next_token, cfg=self, prefix="olmo"),
             num_layers=len(self.full_layers),
             max_position=self.max_position_embeddings,
             cache_rows=self.cache_rows,
-            build_decode_step=functools.partial(
-                build_olmo_hybrid_decode_step, self),
-            build_prefill_chunk=functools.partial(
-                build_olmo_hybrid_prefill_chunk, self),
             pool_dtype=self.dtype, prefill_chunk=self.prefill_chunk,
             seq_state=self.seq_state(), state_layers=self.linear_layers)
 
@@ -172,30 +170,6 @@ class OlmoHybridConfig:
 # ---------------------------------------------------------------------------
 # layer pieces
 # ---------------------------------------------------------------------------
-
-
-def _attr(name, cfg):
-    return ParamAttr(name=name,
-                     initializer=Normal(0.0, cfg.initializer_range))
-
-
-def _linear(x, size, name, cfg):
-    return layers.weight_matmul(x, size, param_attr=_attr(name + ".w_0", cfg),
-                                dtype=cfg.dtype)
-
-
-def _rms(x, name, cfg):
-    return layers.rms_norm(
-        x, epsilon=cfg.rms_norm_eps,
-        param_attr=ParamAttr(name=name + ".scale",
-                             initializer=Constant(1.0)))
-
-
-def _swiglu_ffn(x, name, cfg):
-    hidden = layers.swiglu(
-        _linear(x, cfg.intermediate_size, name + "_gate", cfg),
-        _linear(x, cfg.intermediate_size, name + "_up", cfg))
-    return _linear(hidden, cfg.hidden_size, name + "_down", cfg)
 
 
 def _linear_attention(x, state, block, q_start, last_idx, row_valid, cfg,
@@ -259,183 +233,48 @@ def _full_attention(x, pos, page_table, q_start, pools, write, shape, cfg,
                 name + "_post_attn_norm", cfg)
 
 
-def _decoder(tok, pos, page_table, q_start, pools, write, states, block,
-             shape, cfg, attn_force=None, chunk=None):
-    """Embedding and every block over tok/pos [B, T] -> hidden [B, T, D]
-    (before the final norm).  ``pools``: per full-attention layer, in
-    order, its (K, V) pool vars; ``states``: {layer: (s, conv)} of the
-    linear-attention layers; ``chunk``: (last_idx, row_valid) of a
-    prefill chunk, None in a decode step."""
+def _decoder(frame, cfg):
+    """Embedding and every block over the frame's tokens (serving/lane.py
+    ``Frame``) -> hidden [B, T, D] (before the final norm).  The frame's
+    ``pools`` hold the full-attention layers' (K, V) in order, its
+    ``states`` {layer: (s, conv)} of the linear-attention layers, which
+    read the chunk's ``q_start`` / ``last_idx`` / ``row_valid`` and in a
+    decode step none of them."""
+    from paddle_tpu.serving import lane
+
     L = layers
-    b, t = shape
-    emb = L.embedding(tok, size=[cfg.vocab_size, cfg.hidden_size],
+    b, t = frame.shape
+    emb = L.embedding(frame.tok, size=[cfg.vocab_size, cfg.hidden_size],
                       param_attr=_attr("olmo_embed.w_0", cfg),
                       dtype=cfg.dtype)
     x = L.cast(L.reshape(emb, shape=[b, t, cfg.hidden_size]), "float32")
-    last_idx, row_valid = chunk if chunk is not None else (None, None)
-    full = iter(pools)
+    chunk = ((frame.q_start, frame.last_idx, frame.row_valid)
+             if frame.last_idx is not None else (None, None, None))
+    full = iter(frame.pools)
     for layer, kind in enumerate(cfg.layer_types):
         name = f"olmo_layer_{layer}"
         if kind == LINEAR:
-            mixed = _linear_attention(
-                x, states[layer], block,
-                q_start if chunk is not None else None, last_idx, row_valid,
-                cfg, name, attn_force)
+            mixed = _linear_attention(x, frame.states[layer],
+                                      frame.state_block, *chunk, cfg, name,
+                                      frame.attn_force)
         else:
-            mixed = _full_attention(x, pos, page_table, q_start, next(full),
-                                    write, shape, cfg, name, attn_force)
+            mixed = _full_attention(
+                x, frame.pos, frame.tables[lane.FULL], frame.q_start, next(full),
+                frame.writes[lane.FULL], frame.shape, cfg, name, frame.attn_force)
         x = L.elementwise_add(x, mixed)
-        ffn = _swiglu_ffn(x, name + "_ffn", cfg)
+        ffn = _swiglu_ffn(x, cfg.intermediate_size, name + "_ffn", cfg)
         x = L.elementwise_add(x, _rms(ffn, name + "_post_ff_norm", cfg))
     return x
-
-
-def _next_token(h, cfg):
-    """h [N, 1, D] -> (greedy next token [N] int64, logprobs [N, V])."""
-    L = layers
-    logits = L.reshape(_linear(_rms(h, "olmo_final_norm", cfg),
-                               cfg.vocab_size, "olmo_head", cfg),
-                       shape=[-1, cfg.vocab_size])
-    logp = L.log_softmax(logits)
-    return L.argmax(logp, axis=-1), logp
-
-
-def _declare(cfg, num_pages, page_size, pool_dtype, state_blocks):
-    from paddle_tpu.serving import lane
-
-    pools = lane.declare_pool_vars(
-        cfg.cache_rows(pool_dtype), len(cfg.full_layers), num_pages,
-        page_size)
-    states = lane.declare_state_vars(cfg.seq_state(), cfg.linear_layers,
-                                     state_blocks)
-    return pools, states
-
-
-# ---------------------------------------------------------------------------
-# the three builders
-# ---------------------------------------------------------------------------
-
-
-def build_olmo_hybrid_decode_step(cfg: OlmoHybridConfig, pool_slots,
-                                  num_pages, page_size, max_pages,
-                                  pool_dtype=None, attn_force=None,
-                                  state_blocks=None):
-    """ONE token-level decode step over the paged K/V caches and the
-    per-sequence state: the feeds, the output and the slot semantics of
-    models/gpt.py build_gpt_decode_step, and ``dec_state_block`` [slots]
-    each slot's state block (the trash block 0 for an inactive slot)."""
-    from paddle_tpu.serving import lane
-
-    L = layers
-    ps = int(pool_slots)
-    tok = fluid.data("dec_tok", [ps, 1], False, dtype="int64")
-    pos = fluid.data("dec_pos", [ps, 1], False, dtype="int64")
-    table = fluid.data("dec_page_table", [ps, int(max_pages)], False,
-                       dtype="int32")
-    write_page = fluid.data("dec_write_page", [ps], False, dtype="int32")
-    write_off = fluid.data("dec_write_off", [ps], False, dtype="int32")
-    block = fluid.data(lane.STATE_FEEDS["decode"], [ps], False,
-                       dtype="int32")
-    feeds = ["dec_tok", "dec_pos", "dec_page_table", "dec_write_page",
-             "dec_write_off", lane.STATE_FEEDS["decode"]]
-    pools, states = _declare(cfg, num_pages, page_size, pool_dtype,
-                             state_blocks or ps + 2)
-    q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")
-
-    def write(pool, rows):                                 # rows [PS, 1, w]
-        L.kv_cache_write(pool, rows, write_page, write_off)
-
-    x = _decoder(tok, pos, table, q_start, pools, write, states, block,
-                 (ps, 1), cfg, attn_force)
-    next_tok, logp = _next_token(x, cfg)
-    return feeds, next_tok, logp
-
-
-def _chunk(cfg, c, table, write_pages, q_start, last_idx, pools, states,
-           block, attn_force):
-    """One sequence's chunk of ``c`` tokens through the blocks; returns
-    the hidden state of every position [1, C, D]."""
-    L = layers
-    tok = fluid.data("pf_tok", [1, c], False, dtype="int64")
-    pos = fluid.data("pf_pos", [1, c], False, dtype="int64")
-
-    def write(pool, rows):                                 # rows [1, C, w]
-        L.kv_cache_write_pages(pool, L.reshape(rows, shape=[c, 1, -1]),
-                               write_pages)
-
-    row_valid = L.cast(L.less_equal(L.range(0, c, 1, "int64"), last_idx),
-                       "int32")
-    return _decoder(tok, pos, table, q_start, pools, write, states, block,
-                    (1, c), cfg, attn_force, chunk=(last_idx, row_valid))
-
-
-def build_olmo_hybrid_prefill_chunk(cfg: OlmoHybridConfig, chunk_len,
-                                    num_pages, page_size, max_pages,
-                                    pool_dtype=None, attn_force=None,
-                                    state_blocks=None):
-    """One prefill CHUNK of a single sequence: the feeds, the output and
-    the page-write semantics of models/gpt.py build_gpt_prefill_chunk,
-    and ``pf_state_block`` [1] the sequence's state block, read as zeros
-    where ``pf_qstart`` is 0 and carried to the next chunk (rows past
-    ``pf_last_idx`` leave it alone)."""
-    from paddle_tpu.serving import lane
-
-    L = layers
-    c = int(chunk_len)
-    if c % int(page_size):
-        raise ValueError(
-            f"prefill chunk_len {c} must be a multiple of page_size "
-            f"{page_size} (chunks write whole pages)")
-    table = fluid.data("pf_page_table", [1, int(max_pages)], False,
-                       dtype="int32")
-    write_pages = fluid.data("pf_write_pages", [c // int(page_size)], False,
-                             dtype="int32")
-    q_start = fluid.data("pf_qstart", [1], False, dtype="int32")
-    last_idx = fluid.data("pf_last_idx", [1], False, dtype="int64")
-    block = fluid.data(lane.STATE_FEEDS["prefill"], [1], False,
-                       dtype="int32")
-    feeds = ["pf_tok", "pf_pos", "pf_page_table", "pf_write_pages",
-             "pf_qstart", "pf_last_idx", lane.STATE_FEEDS["prefill"]]
-    pools, states = _declare(cfg, num_pages, page_size, pool_dtype,
-                             state_blocks or 2)
-    x = _chunk(cfg, c, table, write_pages, q_start, last_idx, pools, states,
-               block, attn_force)
-    flat = L.reshape(x, shape=[-1, cfg.hidden_size])
-    h_last = L.reshape(L.gather(flat, last_idx),
-                       shape=[-1, 1, cfg.hidden_size])
-    next_tok, logp = _next_token(h_last, cfg)
-    return feeds, next_tok, logp
 
 
 def build_olmo_hybrid_lm(cfg: OlmoHybridConfig = None, is_test=True,
                          seq_len=None, page_size=None, attn_force=None):
     """A whole sequence in one pass: logprobs [S, V] of every position of
-    ``pf_tok`` [1, S].  The same blocks as the decode lane's chunk over
-    caches and state that live and die inside the program (the identity
-    page table; state block 1 of 2, read as zeros).  Inference only
-    (``is_test`` is accepted for the zoo's calling convention)."""
+    ``pf_tok`` [1, S] (serving/lane.py ``build_whole_sequence``: the
+    decode lane's blocks over caches and state that live and die inside
+    the program).  Inference only (``is_test`` is accepted for the zoo's
+    calling convention)."""
     del is_test
-    L = layers
     cfg = cfg or OlmoHybridConfig()
-    c = int(seq_len or cfg.prefill_chunk or 128)
-    page = int(page_size or min(c, 128))
-    if c % page:
-        raise ValueError(f"seq_len {c} must be a multiple of page {page}")
-    n = c // page
-    page_table = L.reshape(L.cast(L.range(1, n + 1, 1, "int64"), "int32"),
-                           shape=[1, n])
-    q_start = L.fill_constant(shape=[1], value=0, dtype="int32")
-    last_idx = L.fill_constant(shape=[1], value=c - 1, dtype="int64")
-    block = L.fill_constant(shape=[1], value=1, dtype="int32")
-    pools = [tuple(L.fill_constant(shape=[n + 1, page, row.width], value=0.0,
-                                   dtype=row.dtype)
-                   for row in cfg.cache_rows())
-             for _ in cfg.full_layers]
-    states = {layer: tuple(L.fill_constant(shape=[2, *st.shape], value=0.0,
-                                           dtype=st.dtype)
-                           for st in cfg.seq_state())
-              for layer in cfg.linear_layers}
-    x = _chunk(cfg, c, page_table, L.reshape(page_table, shape=[n]), q_start,
-               last_idx, pools, states, block, attn_force)
-    _, logp = _next_token(L.reshape(x, shape=[c, 1, cfg.hidden_size]), cfg)
-    return logp
+    return cfg.decode_lane().build_whole_sequence(
+        seq_len or cfg.prefill_chunk or 128, page_size, attn_force)

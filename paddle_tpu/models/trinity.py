@@ -30,10 +30,10 @@ process holds a share.
            shared SwiGLU expert; experts ``moe_intermediate_size`` wide.
   head     final RMSNorm, untied lm_head.
 
-Three builders on the same parameter names: ``build_trinity_lm`` (a
-whole sequence, its caches program-local), ``build_trinity_decode_step``
-and ``build_trinity_prefill_chunk`` (the decode lane's two executables;
-``TrinityConfig.decode_lane()`` hands them to ``serving.DecodeEngine``).
+``TrinityConfig.decode_lane()`` hands ``_decoder`` and the head to
+serving/lane.py, which builds the decode lane's two executables around
+them, and ``build_trinity_lm`` a whole sequence on the same parameter
+names, its caches program-local.
 Matrices are stored in ``cfg.dtype`` (bfloat16 in the serving lane) and
 multiplied in it with float32 accumulation; norm scales, the router's
 bias, its product and activations between ops are float32; cache rows
@@ -44,12 +44,10 @@ from __future__ import annotations
 
 import functools
 
-from paddle_tpu import fluid
 from paddle_tpu.fluid import layers
-from paddle_tpu.fluid.initializer import Constant, Normal
-from paddle_tpu.fluid.param_attr import ParamAttr
 
 from . import moe_stats
+from .decode_blocks import _attr, _linear, _next_token, _rms, _swiglu_ffn
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -148,14 +146,12 @@ class TrinityConfig:
         """This model's decode-lane declaration (serving/lane.py)."""
         from paddle_tpu.serving import lane
 
-        return lane.DecodeLane(
+        return lane.scaffold(
+            functools.partial(_decoder, cfg=self),
+            functools.partial(_next_token, cfg=self, prefix="trinity"),
             num_layers=self.num_hidden_layers,
             max_position=self.max_position_embeddings,
             cache_rows=self.cache_rows,
-            build_decode_step=functools.partial(build_trinity_decode_step,
-                                                self),
-            build_prefill_chunk=functools.partial(
-                build_trinity_prefill_chunk, self),
             pool_dtype=self.dtype, prefill_chunk=self.prefill_chunk,
             device_counters=moe_stats.expert_stats_counters(self),
             book_counters=functools.partial(moe_stats.book_expert_stats,
@@ -166,29 +162,6 @@ class TrinityConfig:
 # ---------------------------------------------------------------------------
 # layer pieces
 # ---------------------------------------------------------------------------
-
-
-def _attr(name, cfg):
-    return ParamAttr(name=name,
-                     initializer=Normal(0.0, cfg.initializer_range))
-
-
-def _linear(x, size, name, cfg, head_dim=None):
-    return layers.weight_matmul(x, size, param_attr=_attr(name + ".w_0", cfg),
-                                dtype=cfg.dtype, head_dim=head_dim)
-
-
-def _rms(x, name, cfg):
-    return layers.rms_norm(
-        x, epsilon=cfg.rms_norm_eps,
-        param_attr=ParamAttr(name=name + ".scale",
-                             initializer=Constant(1.0)))
-
-
-def _swiglu_ffn(x, width, name, cfg):
-    hidden = layers.swiglu(_linear(x, width, name + "_gate", cfg),
-                           _linear(x, width, name + "_up", cfg))
-    return _linear(hidden, cfg.hidden_size, name + "_down", cfg)
 
 
 def _attention(x, pos, page_table, q_start, pools, write, shape, window, cfg,
@@ -235,17 +208,15 @@ def _ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
     return layers.elementwise_add(routed, shared)
 
 
-def _decoder(tok, pos, tables, q_start, pools, writes, row_valid, shape, cfg,
-             attn_force=None, counted_as=None):
-    """Embedding and every block over tok/pos [B, T] -> hidden [B, T, D]
-    (before the final norm).  ``tables`` / ``writes``: per cache kind
-    (serving/lane.py ``kind_name``), the page table and the function
-    that writes a token's rows into a pool of that kind."""
+def _decoder(frame, cfg):
+    """Embedding and every block over the frame's tokens (serving/lane.py
+    ``Frame``) -> hidden [B, T, D] (before the final norm); a layer
+    reads the page table and the writer of its cache kind."""
     from paddle_tpu.serving import lane
 
     L = layers
-    b, t = shape
-    emb = L.embedding(tok, size=[cfg.vocab_size, cfg.hidden_size],
+    b, t = frame.shape
+    emb = L.embedding(frame.tok, size=[cfg.vocab_size, cfg.hidden_size],
                       param_attr=_attr("trinity_embed.w_0", cfg),
                       dtype=cfg.dtype)
     x = L.cast(L.reshape(emb, shape=[b, t, cfg.hidden_size]), "float32")
@@ -254,171 +225,25 @@ def _decoder(tok, pos, tables, q_start, pools, writes, row_valid, shape, cfg,
     for layer, window in enumerate(cfg.layer_windows):
         name = f"trinity_layer_{layer}"
         kind = lane.kind_name(window)
-        attn = _attention(x, pos, tables[kind], q_start, pools[layer],
-                          writes[kind], shape, window, cfg, name, attn_force)
+        attn = _attention(x, frame.pos, frame.tables[kind], frame.q_start,
+                          frame.pools[layer], frame.writes[kind], frame.shape,
+                          window, cfg, name, frame.attn_force)
         x = L.elementwise_add(x, _rms(attn, name + "_post_attn_norm", cfg))
-        ffn = _ffn(_rms(x, name + "_pre_mlp_norm", cfg), layer, row_valid,
-                   counted_as, cfg, name, attn_force)
+        ffn = _ffn(_rms(x, name + "_pre_mlp_norm", cfg), layer,
+                   frame.row_valid, frame.counted_as, cfg, name,
+                   frame.attn_force)
         x = L.elementwise_add(x, _rms(ffn, name + "_post_mlp_norm", cfg))
     return x
-
-
-def _next_token(h, cfg):
-    """h [N, 1, D] -> (greedy next token [N] int64, logprobs [N, V])."""
-    L = layers
-    logits = L.reshape(_linear(_rms(h, "trinity_final_norm", cfg),
-                               cfg.vocab_size, "trinity_head", cfg),
-                       shape=[-1, cfg.vocab_size])
-    logp = L.log_softmax(logits)
-    return L.argmax(logp, axis=-1), logp
-
-
-def _kinds(cfg):
-    """The cache kinds of this model's layers, ``full`` first, as the
-    pool orders them."""
-    from paddle_tpu.serving import lane
-
-    return [lane.kind_name(w) for w in lane.kinds_of(cfg.layer_windows)]
-
-
-def _declare_pools(cfg, num_pages, page_size, pool_dtype):
-    from paddle_tpu.serving import lane
-
-    return lane.declare_pool_vars(
-        cfg.cache_rows(pool_dtype), cfg.num_hidden_layers, num_pages,
-        page_size, layer_windows=cfg.layer_windows)
-
-
-# ---------------------------------------------------------------------------
-# the three builders
-# ---------------------------------------------------------------------------
-
-
-def build_trinity_decode_step(cfg: TrinityConfig, pool_slots, num_pages,
-                              page_size, max_pages, pool_dtype=None,
-                              attn_force=None):
-    """ONE token-level decode step over the paged K/V caches: the feeds,
-    the output and the slot semantics of models/gpt.py
-    build_gpt_decode_step, with one page table and one write page a
-    cache kind (``num_pages`` is ``{kind: pages}``)."""
-    from paddle_tpu.serving import lane
-
-    L = layers
-    ps = int(pool_slots)
-    tok = fluid.data("dec_tok", [ps, 1], False, dtype="int64")
-    pos = fluid.data("dec_pos", [ps, 1], False, dtype="int64")
-    feeds = ["dec_tok", "dec_pos"]
-    tables, write_page = {}, {}
-    for kind in _kinds(cfg):
-        names = [lane.kind_feed(f, kind)
-                 for f in ("dec_page_table", "dec_write_page")]
-        tables[kind] = fluid.data(names[0], [ps, int(max_pages)], False,
-                                  dtype="int32")
-        write_page[kind] = fluid.data(names[1], [ps], False, dtype="int32")
-        feeds += names
-    write_off = fluid.data("dec_write_off", [ps], False, dtype="int32")
-    feeds.append("dec_write_off")
-    pools = _declare_pools(cfg, num_pages, page_size, pool_dtype)
-    q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")
-
-    def writer(kind):
-        def write(pool, rows):                             # rows [PS, 1, w]
-            L.kv_cache_write(pool, rows, write_page[kind], write_off)
-        return write
-
-    x = _decoder(tok, pos, tables, q_start, pools,
-                 {kind: writer(kind) for kind in tables},
-                 write_page[_kinds(cfg)[0]], (ps, 1), cfg, attn_force,
-                 counted_as="decode")
-    next_tok, logp = _next_token(x, cfg)
-    return feeds, next_tok, logp
-
-
-def _chunk(cfg, c, tables, write_pages, q_start, last_idx, pools, attn_force,
-           counted_as="prefill"):
-    """One sequence's chunk of ``c`` tokens through the blocks; returns
-    the hidden state of every position [1, C, D]."""
-    L = layers
-    tok = fluid.data("pf_tok", [1, c], False, dtype="int64")
-    pos = fluid.data("pf_pos", [1, c], False, dtype="int64")
-
-    def writer(kind):
-        def write(pool, rows):                             # rows [1, C, w]
-            L.kv_cache_write_pages(
-                pool, L.reshape(rows, shape=[c, 1, -1]), write_pages[kind])
-        return write
-
-    row_valid = L.cast(L.less_equal(L.range(0, c, 1, "int64"), last_idx),
-                       "int32")
-    return _decoder(tok, pos, tables, q_start, pools,
-                    {kind: writer(kind) for kind in tables}, row_valid,
-                    (1, c), cfg, attn_force, counted_as)
-
-
-def build_trinity_prefill_chunk(cfg: TrinityConfig, chunk_len, num_pages,
-                                page_size, max_pages, pool_dtype=None,
-                                attn_force=None):
-    """One prefill CHUNK of a single sequence through the paged caches:
-    the feeds, the output and the page-write semantics of models/gpt.py
-    build_gpt_prefill_chunk, with one page table and one set of write
-    pages a cache kind."""
-    from paddle_tpu.serving import lane
-
-    L = layers
-    c = int(chunk_len)
-    if c % int(page_size):
-        raise ValueError(
-            f"prefill chunk_len {c} must be a multiple of page_size "
-            f"{page_size} (chunks write whole pages)")
-    feeds = ["pf_tok", "pf_pos"]
-    tables, write_pages = {}, {}
-    for kind in _kinds(cfg):
-        names = [lane.kind_feed(f, kind)
-                 for f in ("pf_page_table", "pf_write_pages")]
-        tables[kind] = fluid.data(names[0], [1, int(max_pages)], False,
-                                  dtype="int32")
-        write_pages[kind] = fluid.data(names[1], [c // int(page_size)],
-                                       False, dtype="int32")
-        feeds += names
-    q_start = fluid.data("pf_qstart", [1], False, dtype="int32")
-    last_idx = fluid.data("pf_last_idx", [1], False, dtype="int64")
-    feeds += ["pf_qstart", "pf_last_idx"]
-    pools = _declare_pools(cfg, num_pages, page_size, pool_dtype)
-    x = _chunk(cfg, c, tables, write_pages, q_start, last_idx, pools,
-               attn_force)
-    flat = L.reshape(x, shape=[-1, cfg.hidden_size])
-    h_last = L.reshape(L.gather(flat, last_idx),
-                       shape=[-1, 1, cfg.hidden_size])
-    next_tok, logp = _next_token(h_last, cfg)
-    return feeds, next_tok, logp
 
 
 def build_trinity_lm(cfg: TrinityConfig = None, is_test=True, seq_len=None,
                      page_size=None, attn_force=None):
     """A whole sequence in one pass: logprobs [S, V] of every position of
-    ``pf_tok`` [1, S].  The same blocks as the decode lane's chunk over
-    caches that live and die inside the program (every kind under the
-    identity page table: nothing is given back).  Inference only
-    (``is_test`` is accepted for the zoo's calling convention)."""
+    ``pf_tok`` [1, S] (serving/lane.py ``build_whole_sequence``: the
+    decode lane's blocks over caches that live and die inside the
+    program).  Inference only (``is_test`` is accepted for the zoo's
+    calling convention)."""
     del is_test
-    L = layers
     cfg = cfg or TrinityConfig()
-    c = int(seq_len or cfg.prefill_chunk or 128)
-    page = int(page_size or min(c, 128))
-    if c % page:
-        raise ValueError(f"seq_len {c} must be a multiple of page {page}")
-    n = c // page
-    page_table = L.reshape(L.cast(L.range(1, n + 1, 1, "int64"), "int32"),
-                           shape=[1, n])
-    q_start = L.fill_constant(shape=[1], value=0, dtype="int32")
-    last_idx = L.fill_constant(shape=[1], value=c - 1, dtype="int64")
-    pools = [tuple(L.fill_constant(shape=[n + 1, page, row.width], value=0.0,
-                                   dtype=row.dtype)
-                   for row in cfg.cache_rows())
-             for _ in range(cfg.num_hidden_layers)]
-    kinds = _kinds(cfg)
-    x = _chunk(cfg, c, dict.fromkeys(kinds, page_table),
-               dict.fromkeys(kinds, L.reshape(page_table, shape=[n])),
-               q_start, last_idx, pools, attn_force, counted_as=None)
-    _, logp = _next_token(L.reshape(x, shape=[c, 1, cfg.hidden_size]), cfg)
-    return logp
+    return cfg.decode_lane().build_whole_sequence(
+        seq_len or cfg.prefill_chunk or 128, page_size, attn_force)

@@ -1366,31 +1366,29 @@ class DecodeEngine:
 
     def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid,
                       row_idx=None):
-        """One chunk's feed: a page table and the chunk's write pages a
-        cache kind (``lane.kind_feed``: the ``full`` kind under the plain
-        names); for a lane with an image encoder also the staged row a
-        position (-1 throughout for a chunk of tokens)."""
+        """One chunk's feed (``lane.prefill_feed`` names it): a page
+        table and the chunk's write pages a cache kind; for a lane with
+        an image encoder also the staged row a position (-1 throughout
+        for a chunk of tokens)."""
         c = self.prefill_chunk
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
         pos = np.minimum(pos0 + np.arange(c, dtype=np.int64),
                          self.lane.max_position - 1)[None, :]
-        feed = {"pf_tok": tok, "pf_pos": pos}
-        for kind in self.pool.kinds:
-            feed[_lane.kind_feed("pf_page_table", kind)] = \
-                self.pool.padded_table(seq_id, kind)[None, :].astype(np.int32)
-            feed[_lane.kind_feed("pf_write_pages", kind)] = \
-                write_pages[kind].astype(np.int32)
-        feed["pf_qstart"] = np.asarray([pos0], np.int32)
-        feed["pf_last_idx"] = np.asarray([max(valid - 1, 0)], np.int64)
-        if self.lane.seq_state:
-            feed[_lane.STATE_FEEDS["prefill"]] = np.asarray(
-                [self.pool.state_block(seq_id)], np.int32)
+        kinds = self.pool.kinds
+        block = (np.asarray([self.pool.state_block(seq_id)], np.int32)
+                 if self.lane.seq_state else None)
         if self._enc is not None:
-            feed[self._enc.index_feed] = (
-                np.full((1, c), -1, np.int32) if row_idx is None
-                else row_idx)
-        return feed
+            row_idx = (self._enc.index_feed,
+                       np.full((1, c), -1, np.int32) if row_idx is None
+                       else row_idx)
+        return _lane.prefill_feed(
+            tok, pos,
+            {k: self.pool.padded_table(seq_id, k)[None, :].astype(np.int32)
+             for k in kinds},
+            {k: write_pages[k].astype(np.int32) for k in kinds},
+            np.asarray([pos0], np.int32),
+            np.asarray([max(valid - 1, 0)], np.int64), block, row_idx)
 
     def _run_prefill_feed(self, tokens, pos0, seq_id, write_pages,
                           valid, warm=False, row_idx=None):
@@ -1480,8 +1478,8 @@ class DecodeEngine:
                              if self._slots[i] is req])
 
     def _decode_feed(self, active):
-        """One step's feed: a page table and the slots' write pages a
-        cache kind (``lane.kind_feed``)."""
+        """One step's feed (``lane.decode_feed`` names it): a page table
+        and the slots' write pages a cache kind."""
         ps, pgs = self.pool_slots, self.pool.page_size
         tok = np.zeros((ps, 1), np.int64)
         pos = np.zeros((ps, 1), np.int64)
@@ -1491,23 +1489,20 @@ class DecodeEngine:
             tok[i, 0] = req.generated[-1]
             pos[i, 0] = p
             woff[i] = p % pgs
-        feed = {"dec_tok": tok, "dec_pos": pos}
+        tables, wpages = {}, {}
         for kind in self.pool.kinds:
             table = np.tile(self.pool.padded_table(None, kind), (ps, 1))
             wpage = np.zeros(ps, np.int32)
             for i, req in active:
                 table[i] = self.pool.padded_table(req.seq_id, kind)
                 wpage[i] = table[i][pos[i, 0] // pgs]
-            feed[_lane.kind_feed("dec_page_table", kind)] = \
-                table.astype(np.int32)
-            feed[_lane.kind_feed("dec_write_page", kind)] = wpage
-        feed["dec_write_off"] = woff
+            tables[kind], wpages[kind] = table.astype(np.int32), wpage
+        blocks = None
         if self.lane.seq_state:
             blocks = np.full(ps, TRASH_PAGE, np.int32)
             for i, req in active:
                 blocks[i] = self.pool.state_block(req.seq_id)
-            feed[_lane.STATE_FEEDS["decode"]] = blocks
-        return feed
+        return _lane.decode_feed(tok, pos, tables, wpages, woff, blocks)
 
     def _run_decode_feed(self, active, warm=False):
         with _profiling.span("decode.feed_build", "decode"):

@@ -35,10 +35,13 @@ A model declares
   (``pf_qstart == 0``): nothing clears a block on the host, and an
   eviction replays from token 0.  A lane that declares no state builds,
   feeds and compiles exactly what it did;
-- the **two program builders** of the lane's two fixed-shape
-  executables, a decode step over the pool's slots and a prefill chunk
-  of one sequence, both against the model's own parameter names and the
-  engine's feed names (``dec_*`` / ``pf_*``, models/gpt.py);
+- its **decoder and its head** (``scaffold``): ``decoder(frame)`` runs
+  the embedding and every block over the tokens of a ``Frame`` and
+  returns the hidden state ``[B, T, D]``, ``head(h)`` turns rows of it
+  into ``(greedy next token, logprobs)``, both against the model's own
+  parameter names.  The lane's two fixed-shape executables, a decode
+  step over the pool's slots and a prefill chunk of one sequence, are
+  built around them HERE (below);
 - optionally an **image encoder** (``ImageEncoder``): a third builder,
   one executable an image shape, whose output rows stand at the prompt
   positions that hold the model's placeholder id.  A request may then
@@ -56,11 +59,29 @@ A model declares
 The model's config class returns the declaration from ``decode_lane()``;
 the engine asks for nothing else, so ``serving/decode.py`` imports no
 model module.
+
+What a model file holds, what the lane builds.  A model file holds its
+config class with ``decode_lane()``, its attention (linear-attention,
+tower) block, its feed-forward block, its ``_decoder(frame, cfg)`` and
+its head (``models/decode_blocks.py`` has the parts the decoders share).
+Everything that is the ENGINE's is here: the names, shapes and order of
+the feeds (``decode_feed`` / ``prefill_feed`` fill what
+``build_decode_step`` / ``build_prefill_chunk`` declare, and no other
+module spells a feed's name), the pool, state and staging vars, the page
+writers of each cache kind, ``q_start``, ``row_valid``, the chunk's
+last-valid-row gather, and the whole-sequence form
+(``build_whole_sequence``: identity page table, caches and state that
+live and die inside the program) that a model's ``build_<model>_lm`` is
+a few lines over.  A lane of one cache kind and a lane of several go
+through the same code: one kind is the dict of one.  A new serve
+configuration touches its model file, its kernel
+(``kernels/primitives``) and its declaration, and nothing here.
 """
 
 from __future__ import annotations
 
 import collections
+import functools
 
 __all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
            "state_var_names", "declare_state_vars",
@@ -69,7 +90,10 @@ __all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
            "declare_row_staging",
            "FULL", "kind_name", "kind_feed", "kinds_of",
            "window_pages_per_seq", "rows_of_layers",
-           "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars"]
+           "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars",
+           "Frame", "scaffold", "decode_feed", "prefill_feed",
+           "build_decode_step", "build_prefill_chunk",
+           "build_whole_sequence"]
 
 POOL_PREFIX = "@KVPOOL@"
 
@@ -299,7 +323,10 @@ class DecodeLane:
     pool_dtype=, attn_force=)`` and ``build_prefill_chunk(chunk_len,
     num_pages, page_size, max_pages, pool_dtype=, attn_force=)`` build
     into the default main program and return ``(feed_names, next_tok,
-    logprobs)``.
+    logprobs)``: this module's builders of those names bound to the
+    model's decoder and head, which is what ``scaffold`` returns a
+    declaration with (and with ``build_whole_sequence``, None where the
+    builders are a model's own).
     ``pool_dtype`` / ``prefill_chunk``: the model's defaults where the
     engine is given none.
     ``layer_windows``: per layer, None (kind ``full``) or the W of a layer
@@ -331,6 +358,7 @@ class DecodeLane:
         self.cache_rows = cache_rows
         self.build_decode_step = build_decode_step
         self.build_prefill_chunk = build_prefill_chunk
+        self.build_whole_sequence = None
         self.pool_dtype = pool_dtype
         self.prefill_chunk = prefill_chunk
         self.device_counters = list(device_counters)
@@ -352,3 +380,312 @@ class DecodeLane:
         if self.device_counters and book_counters is None:
             raise ValueError("DecodeLane: device_counters without "
                              "book_counters would never be read")
+
+
+# ---------------------------------------------------------------------------
+# The feed contract and the programs' frame: what the engine feeds, by
+# name, shape and order, and the part of the lane's programs that is the
+# same for every model.  No other module spells a feed's name.
+# ---------------------------------------------------------------------------
+
+_DEC_TOK, _DEC_POS, _DEC_WRITE_OFF = "dec_tok", "dec_pos", "dec_write_off"
+_DEC_TABLE, _DEC_WRITE_PAGE = "dec_page_table", "dec_write_page"
+_PF_TOK, _PF_POS, _PF_QSTART = "pf_tok", "pf_pos", "pf_qstart"
+_PF_TABLE, _PF_WRITE_PAGES = "pf_page_table", "pf_write_pages"
+_PF_LAST_IDX = "pf_last_idx"
+
+
+def decode_feed(tok, pos, tables, write_page, write_off, state_block=None):
+    """One decode step's feed, in the order ``build_decode_step``
+    declares it: ``tok`` / ``pos`` [slots, 1] int64; per cache kind, in
+    the pool's order, ``tables`` {kind: [slots, max_pages] int32} and
+    ``write_page`` {kind: [slots] int32}; ``write_off`` [slots] int32;
+    for a lane with per-sequence state ``state_block`` [slots] int32."""
+    feed = {_DEC_TOK: tok, _DEC_POS: pos}
+    for kind, table in tables.items():
+        feed[kind_feed(_DEC_TABLE, kind)] = table
+        feed[kind_feed(_DEC_WRITE_PAGE, kind)] = write_page[kind]
+    feed[_DEC_WRITE_OFF] = write_off
+    if state_block is not None:
+        feed[STATE_FEEDS["decode"]] = state_block
+    return feed
+
+
+def prefill_feed(tok, pos, tables, write_pages, q_start, last_idx,
+                 state_block=None, row_idx=None):
+    """One prefill chunk's feed, in the order ``build_prefill_chunk``
+    declares it: ``tok`` / ``pos`` [1, C] int64; per cache kind
+    ``tables`` {kind: [1, max_pages] int32} and ``write_pages`` {kind:
+    [C / page_size] int32}; ``q_start`` [1] int32 (tokens already in the
+    pool); ``last_idx`` [1] int64 (the chunk's last valid row); for a
+    lane with per-sequence state ``state_block`` [1] int32; for a lane
+    with an image encoder ``row_idx`` = (the encoder's index feed,
+    [1, C] int32)."""
+    feed = {_PF_TOK: tok, _PF_POS: pos}
+    for kind, table in tables.items():
+        feed[kind_feed(_PF_TABLE, kind)] = table
+        feed[kind_feed(_PF_WRITE_PAGES, kind)] = write_pages[kind]
+    feed[_PF_QSTART] = q_start
+    feed[_PF_LAST_IDX] = last_idx
+    if state_block is not None:
+        feed[STATE_FEEDS["prefill"]] = state_block
+    if row_idx is not None:
+        feed[row_idx[0]] = row_idx[1]
+    return feed
+
+
+class Frame(collections.namedtuple("Frame", (
+        "tok", "pos", "shape", "tables", "q_start", "pools", "writes",
+        "row_valid", "last_idx", "states", "state_block", "image_rows",
+        "counted_as", "attn_force"))):
+    """What a model's ``decoder(frame)`` is handed: one executable's
+    tokens and everything of the engine's its blocks read and write.
+
+    ``tok`` / ``pos`` [B, T] int64 and ``shape`` = (B, T): (slots, 1) in
+    a decode step, (1, C) in a chunk.  ``tables`` {kind: page table
+    [B, max_pages]} and ``writes`` {kind: ``write(pool, rows)``, which
+    writes the tokens' rows [B, T, w] into a pool var of that kind} by
+    cache kind (``kind_name``; a lane that declares no windows has the
+    one kind ``FULL``); ``q_start`` [B] int32, the tokens ahead of the
+    first row; ``pools``: per cache layer its pool vars in the order of
+    its declared rows.  ``row_valid`` int32, nonzero where a row is a
+    token: [slots] in a decode step (the slot's write page; an inactive
+    slot writes the trash page 0), [C] in a chunk (the rows up to
+    ``last_idx`` [1] int64, which is None in a decode step).  ``states``
+    {layer: its state vars} and ``state_block`` [B] int32 for a lane
+    with per-sequence state, else None.  ``image_rows``: None, or (the
+    row staging var, the index a position [1, C]) in the chunk of a lane
+    with an image encoder.  ``counted_as``: ``"decode"`` / ``"prefill"``,
+    the executable device counters book under, None in the
+    whole-sequence form, which books nothing.  ``attn_force``: the
+    kernels' ``force``."""
+    __slots__ = ()
+
+
+def _page_writer(chunk_len, *where):
+    """``write(pool, rows)`` of one cache kind: the tokens' rows into the
+    pool at ``where`` = (page [B], offset [B]) in a decode step
+    (``chunk_len`` None), (pages [C / page_size],) in a chunk, whose rows
+    [1, C, w] are first laid a token a row.  A pool that is a (hi, lo,
+    scale) triple is the dual-int8 pool (``kv_rows``): its write
+    quantises."""
+    from paddle_tpu.fluid import layers as L
+
+    def write(pool, rows):
+        if chunk_len is not None and rows.shape[0] != chunk_len:
+            rows = L.reshape(rows, shape=[chunk_len, 1, -1])
+        if isinstance(pool, tuple):
+            op = (L.kv_cache_write_quant if chunk_len is None
+                  else L.kv_cache_write_pages_quant)
+            op(*pool, rows, *where)
+        else:
+            op = (L.kv_cache_write if chunk_len is None
+                  else L.kv_cache_write_pages)
+            op(pool, rows, *where)
+    return write
+
+
+def _kinds(decl):
+    """The cache kinds of a lane's layers, ``full`` first, as the pool
+    orders them."""
+    return [kind_name(w) for w in kinds_of(decl.layer_windows or [None])]
+
+
+def _declare(decl, num_pages, page_size, pool_dtype, state_blocks):
+    """(pool vars a cache layer, {layer: state vars} or None)."""
+    pools = declare_pool_vars(
+        decl.cache_rows(pool_dtype or decl.pool_dtype), decl.num_layers,
+        num_pages, page_size, layer_windows=decl.layer_windows)
+    states = (declare_state_vars(decl.seq_state, decl.state_layers,
+                                 state_blocks) if decl.seq_state else None)
+    return pools, states
+
+
+def build_decode_step(decl, decoder, head, pool_slots, num_pages, page_size,
+                      max_pages, pool_dtype=None, attn_force=None,
+                      state_blocks=None):
+    """ONE token-level decode step over the paged caches, the single
+    fixed-shape executable the scheduler dispatches every step (every
+    feed shape is static in ``pool_slots`` / ``max_pages``: no
+    steady-state recompile).  Per slot s: the token ``dec_tok[s]`` at
+    position ``dec_pos[s]`` goes through ``decoder``, which writes each
+    layer's rows at (``dec_write_page[s]``, ``dec_write_off[s]``) and
+    attends the slot's prefix through ``dec_page_table[s]`` (one table
+    and one write page a cache kind, ``kind_feed``; ``num_pages`` is then
+    ``{kind: pages}``), and ``head`` emits the greedy next token.
+    Inactive slots carry page-table zeros (the trash page), position 0
+    and, in a lane with state, the trash block; their outputs are
+    garbage the scheduler ignores.  Returns ``(feed names, next_tok
+    [pool_slots] int64, logprobs [pool_slots, vocab])``."""
+    from paddle_tpu import fluid
+    from paddle_tpu.fluid import layers as L
+
+    ps = int(pool_slots)
+    tok = fluid.data(_DEC_TOK, [ps, 1], False, dtype="int64")
+    pos = fluid.data(_DEC_POS, [ps, 1], False, dtype="int64")
+    tables, write_page = {}, {}
+    for kind in _kinds(decl):
+        tables[kind] = fluid.data(kind_feed(_DEC_TABLE, kind),
+                                  [ps, int(max_pages)], False, dtype="int32")
+        write_page[kind] = fluid.data(kind_feed(_DEC_WRITE_PAGE, kind), [ps],
+                                      False, dtype="int32")
+    write_off = fluid.data(_DEC_WRITE_OFF, [ps], False, dtype="int32")
+    block = (fluid.data(STATE_FEEDS["decode"], [ps], False, dtype="int32")
+             if decl.seq_state else None)
+    pools, states = _declare(decl, num_pages, page_size, pool_dtype,
+                             state_blocks or ps + 2)
+    q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")
+    x = decoder(Frame(
+        tok=tok, pos=pos, shape=(ps, 1), tables=tables, q_start=q_start,
+        pools=pools, writes={kind: _page_writer(None, page, write_off)
+                             for kind, page in write_page.items()},
+        row_valid=next(iter(write_page.values())), last_idx=None,
+        states=states, state_block=block, image_rows=None,
+        counted_as="decode", attn_force=attn_force))
+    # the names as ``decode_feed`` orders them: the one place that does
+    return (list(decode_feed(tok, pos, tables, write_page, write_off, block)),
+            *head(x))
+
+
+def _chunk_tokens(c):
+    from paddle_tpu import fluid
+
+    return (fluid.data(_PF_TOK, [1, c], False, dtype="int64"),
+            fluid.data(_PF_POS, [1, c], False, dtype="int64"))
+
+
+def _chunk(decoder, tok, pos, tables, write_pages, q_start, last_idx, pools,
+           states, block, image_rows, counted_as, attn_force):
+    """One sequence's chunk of tokens through ``decoder``: the hidden
+    state of every row, [1, C, D]."""
+    from paddle_tpu.fluid import layers as L
+
+    c = int(tok.shape[1])
+    row_valid = L.cast(L.less_equal(L.range(0, c, 1, "int64"), last_idx),
+                       "int32")
+    return decoder(Frame(
+        tok=tok, pos=pos, shape=(1, c), tables=tables, q_start=q_start,
+        pools=pools, writes={kind: _page_writer(c, pages)
+                             for kind, pages in write_pages.items()},
+        row_valid=row_valid, last_idx=last_idx, states=states,
+        state_block=block, image_rows=image_rows, counted_as=counted_as,
+        attn_force=attn_force))
+
+
+def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
+                        max_pages, pool_dtype=None, attn_force=None,
+                        state_blocks=None, image_rows=None):
+    """One prefill CHUNK of a single sequence through the paged caches:
+    long prompts stream through this fixed-shape executable
+    ``ceil(P / chunk_len)`` times, each call writing the chunk's rows
+    into whole pool pages (``chunk_len`` is a multiple of ``page_size``)
+    and attending what was written before through the page table.
+
+    Feeds (``prefill_feed``): ``pf_tok`` / ``pf_pos`` [1, C] int64
+    (positions clamped host-side for the padded tail); a cache kind
+    ``pf_page_table`` [1, max_pages] int32 and ``pf_write_pages``
+    [C / page_size] int32 (the trash page 0 past the valid tail);
+    ``pf_qstart`` [1] int32, the tokens already in the pool;
+    ``pf_last_idx`` [1] int64, the last VALID row of the chunk (only the
+    final chunk's next token is consumed); in a lane with state
+    ``pf_state_block`` [1] int32, read as zeros where ``pf_qstart`` is 0
+    and carried to the next chunk (rows past ``pf_last_idx`` leave it
+    alone); in a lane with an image encoder its index feed [1, C] int32
+    (``image_rows``: the rows of the engine's staging var), -1 where the
+    position is a token: a chunk of tokens feeds -1 throughout, one
+    executable.  Returns ``(feed names, next_tok [1] int64, logprobs
+    [1, vocab])``."""
+    from paddle_tpu import fluid
+    from paddle_tpu.fluid import layers as L
+
+    c = int(chunk_len)
+    if c % int(page_size):
+        raise ValueError(
+            f"prefill chunk_len {c} must be a multiple of page_size "
+            f"{page_size} (chunks write whole pages)")
+    tok, pos = _chunk_tokens(c)
+    tables, write_pages = {}, {}
+    for kind in _kinds(decl):
+        tables[kind] = fluid.data(kind_feed(_PF_TABLE, kind),
+                                  [1, int(max_pages)], False, dtype="int32")
+        write_pages[kind] = fluid.data(kind_feed(_PF_WRITE_PAGES, kind),
+                                       [c // int(page_size)], False,
+                                       dtype="int32")
+    q_start = fluid.data(_PF_QSTART, [1], False, dtype="int32")
+    last_idx = fluid.data(_PF_LAST_IDX, [1], False, dtype="int64")
+    block = (fluid.data(STATE_FEEDS["prefill"], [1], False, dtype="int32")
+             if decl.seq_state else None)
+    pools, states = _declare(decl, num_pages, page_size, pool_dtype,
+                             state_blocks or 2)
+    staged = row_idx = None
+    if image_rows is not None:
+        enc = decl.encoder
+        row_idx = (enc.index_feed,
+                   fluid.data(enc.index_feed, [1, c], False, dtype="int32"))
+        staged = (declare_row_staging(image_rows, enc.row_width), row_idx[1])
+    x = _chunk(decoder, tok, pos, tables, write_pages, q_start, last_idx,
+               pools, states, block, staged, "prefill", attn_force)
+    width = int(x.shape[-1])
+    # an exact copy of the last valid row: the final chunk's output seeds
+    # the decode loop's first token
+    h_last = L.reshape(L.gather(L.reshape(x, shape=[-1, width]), last_idx),
+                       shape=[-1, 1, width])
+    return (list(prefill_feed(tok, pos, tables, write_pages, q_start,
+                              last_idx, block, row_idx)), *head(h_last))
+
+
+def build_whole_sequence(decl, decoder, head, seq_len, page_size=None,
+                         attn_force=None):
+    """A whole sequence in one pass: logprobs [S, V] after every
+    position of ``pf_tok`` [1, S] (fed with ``pf_pos``).  The chunk's
+    blocks over caches and state that live and die inside the program:
+    every cache kind under the identity page table (nothing is given
+    back), state block 1 of 2, read as zeros.  Books no device counter
+    and takes no image."""
+    from paddle_tpu.fluid import layers as L
+
+    c = int(seq_len)
+    page = int(page_size or min(c, 128))
+    if c % page:
+        raise ValueError(f"seq_len {c} must be a multiple of page {page}")
+    n = c // page
+    tok, pos = _chunk_tokens(c)
+    page_table = L.reshape(L.cast(L.range(1, n + 1, 1, "int64"), "int32"),
+                           shape=[1, n])
+    q_start = L.fill_constant(shape=[1], value=0, dtype="int32")
+    last_idx = L.fill_constant(shape=[1], value=c - 1, dtype="int64")
+    block = states = None
+    if decl.seq_state:
+        block = L.fill_constant(shape=[1], value=1, dtype="int32")
+    pools = [tuple(L.fill_constant(shape=[n + 1, page, row.width], value=0.0,
+                                   dtype=row.dtype) for row in rows)
+             for rows in rows_of_layers(decl.cache_rows(decl.pool_dtype),
+                                        decl.num_layers, decl.layer_windows)]
+    if decl.seq_state:
+        states = {layer: tuple(L.fill_constant(
+            shape=[2, *st.shape], value=0.0, dtype=st.dtype)
+            for st in decl.seq_state) for layer in decl.state_layers}
+    kinds = _kinds(decl)
+    x = _chunk(decoder, tok, pos, dict.fromkeys(kinds, page_table),
+               dict.fromkeys(kinds, L.reshape(page_table, shape=[n])),
+               q_start, last_idx, pools, states, block, None, None,
+               attn_force)
+    return head(L.reshape(x, shape=[c, 1, int(x.shape[-1])]))[1]
+
+
+def scaffold(decoder, head, **declaration):
+    """A model's ``DecodeLane`` (``declaration``: its keywords but the
+    two builders) whose builders are this module's frames around the
+    model's ``decoder(frame)`` -> hidden [B, T, D] and ``head(h [N, 1,
+    D])`` -> (next token [N] int64, logprobs [N, V]); its
+    ``build_whole_sequence(seq_len, page_size=, attn_force=)`` builds
+    the whole-sequence form around the same two."""
+    decl = DecodeLane(build_decode_step=None, build_prefill_chunk=None,
+                      **declaration)
+    decl.build_decode_step = functools.partial(build_decode_step, decl,
+                                               decoder, head)
+    decl.build_prefill_chunk = functools.partial(build_prefill_chunk, decl,
+                                                 decoder, head)
+    decl.build_whole_sequence = functools.partial(build_whole_sequence, decl,
+                                                  decoder, head)
+    return decl

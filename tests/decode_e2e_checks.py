@@ -278,22 +278,22 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
     page_size, max_pages, num_pages, steps = 4, 8, 9, 20
 
     progs = {}
-    for dtype, prefix in (("float32", "@KVF@"), ("int8", "@KVQ@")):
+    for dtype in ("float32", "int8"):
         main, start = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, start), fluid.unique_name.guard():
-            _, _, logp = gpt.build_gpt_decode_step(
-                cfg, pool_slots=1, num_pages=num_pages,
-                page_size=page_size, max_pages=max_pages,
-                pool_dtype=dtype, pool_prefix=prefix)
+            _, _, logp = cfg.decode_lane().build_decode_step(
+                pool_slots=1, num_pages=num_pages, page_size=page_size,
+                max_pages=max_pages, pool_dtype=dtype)
         progs[dtype] = (main, logp.name)
 
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
-        # both pools in the one shape every pool var has (KVPool.install
-        # is the one place that knows heads and their width apart)
-        for dtype, prefix in (("float32", "@KVF@"), ("int8", "@KVQ@")):
+        # both pools in the one scope, each in the one shape every pool
+        # var has: the int8 rows' vars are named apart from the float32
+        # rows' (lane.kv_rows)
+        for dtype in ("float32", "int8"):
             KVPool(cfg.num_layers, kv_rows(n, d, dtype), num_pages,
-                   page_size, max_pages, prefix=prefix).install(scope)
+                   page_size, max_pages).install(scope)
 
         toks = np.random.RandomState(0).randint(
             1, cfg.vocab_size, steps)

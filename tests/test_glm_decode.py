@@ -102,11 +102,12 @@ def test_prefill_then_decode_matches_the_reference(weights, force):
     for counter in cfg.decode_lane().device_counters:
         scope.set(counter.name, jnp.zeros((counter.length,), jnp.int32))
     progs = {}
+    decl = cfg.decode_lane()
     for kind, build in (
-            ("pf", lambda: glm.build_glm_prefill_chunk(
-                cfg, CHUNK, num_pages, PAGE, MAX_PAGES, attn_force=force)),
-            ("dec", lambda: glm.build_glm_decode_step(
-                cfg, 1, num_pages, PAGE, MAX_PAGES, attn_force=force))):
+            ("pf", lambda: decl.build_prefill_chunk(
+                CHUNK, num_pages, PAGE, MAX_PAGES, attn_force=force)),
+            ("dec", lambda: decl.build_decode_step(
+                1, num_pages, PAGE, MAX_PAGES, attn_force=force))):
         main, start = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, start), fluid.unique_name.guard():
             _, _, logp = build()

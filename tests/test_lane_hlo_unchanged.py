@@ -30,8 +30,11 @@ to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
 
+import ast
 import hashlib
 import json
+import pathlib
+import re
 
 import ml_dtypes
 import numpy as np
@@ -181,6 +184,26 @@ def test_one_kind_lanes_feed_the_names_they_fed():
             "pf_qstart", "pf_last_idx"]
     finally:
         eng.close()
+
+
+def test_the_feed_contract_has_one_owner_and_model_files_no_private_siblings():
+    """The engine's feed names are spelled in serving/lane.py and nowhere
+    else in the package, and a model file imports no underscore name
+    from another model file (models/decode_blocks.py holds the parts the
+    decoders share)."""
+    package = pathlib.Path(fluid.__file__).parent.parent
+    spelled = [str(path.relative_to(package))
+               for path in sorted(package.rglob("*.py"))
+               if re.search(r'"(dec|pf)_[a-z_]+"', path.read_text())]
+    assert spelled == ["serving/lane.py"]
+    borrowed = [
+        (path.name, node.module, alias.name)
+        for path in sorted((package / "models").glob("*.py"))
+        for node in ast.walk(ast.parse(path.read_text()))
+        if isinstance(node, ast.ImportFrom) and node.level == 1
+        and node.module != "decode_blocks"
+        for alias in node.names if alias.name.startswith("_")]
+    assert not borrowed
 
 
 if __name__ == "__main__":

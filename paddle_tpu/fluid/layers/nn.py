@@ -1788,13 +1788,21 @@ def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
     qkv [B, T, 2 H d_k + H d_v] and the gate projections a, b [B, T, H]:
     (q [B, T, H, d_k] L2-normalised and scaled by d_k^-1/2, k
     L2-normalised, v [B, T, H, d_v], g = -exp(A_log) softplus(a +
-    dt_bias), beta = ``beta_scale`` sigmoid(b)).  ``row_valid`` [T]: rows
-    marked 0 get beta = 0 and g = 0."""
+    dt_bias), beta = ``beta_scale`` sigmoid(b)).  With a [B, T, H d_k]
+    the decay is one number a key channel: dt_bias is [H d_k] (A_log
+    stays [H]) and g [B, T, H, d_k].  ``row_valid`` [T]: rows marked 0
+    get beta = 0 and g = 0."""
     helper = LayerHelper("gdn_inputs", name=name)
+    if int(a.shape[-1]) not in (int(heads), int(heads) * int(key_dim)):
+        raise ValueError(
+            f"gdn_inputs: the gate projection is {a.shape[-1]} wide, wanted "
+            f"{heads} (a decay a head) or {heads * key_dim} (a decay a key "
+            f"channel)")
     a_log = helper.create_parameter(a_log_attr, shape=[int(heads)],
                                     dtype="float32",
                                     default_initializer=Constant(0.0))
-    dt_bias = helper.create_parameter(dt_bias_attr, shape=[int(heads)],
+    dt_bias = helper.create_parameter(dt_bias_attr,
+                                      shape=[int(a.shape[-1])],
                                       dtype="float32",
                                       default_initializer=Constant(0.0))
     outs = [helper.create_variable_for_type_inference("float32")
@@ -1816,7 +1824,8 @@ def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
 def gated_delta_rule(q, k, v, g, beta, state, block, q_start=None,
                      force=None, name=None):
     """The gated delta rule over the per-sequence state var ``state``
-    [blocks, d_k, H * d_v] (kernels/primitives/gdn.py), updated in place
+    [blocks, d_k, H * d_v] (kernels/primitives/gdn.py; kda.py where g
+    is [B, T, H, d_k], a decay a key channel), updated in place
     -> [B, T, H, d_v] float32.  With ``q_start`` [1] the operands are one
     sequence's chunk [1, C, H, .] and ``block`` [1] its block (read as
     zeros where ``q_start`` is 0); without it they are one token a slot
@@ -1835,16 +1844,21 @@ def gated_delta_rule(q, k, v, g, beta, state, block, q_start=None,
     return out
 
 
-def gated_rms_norm(x, gate, epsilon=1e-6, param_attr=None, name=None):
+def gated_rms_norm(x, gate, epsilon=1e-6, param_attr=None,
+                   activation="silu", name=None):
     """RMSNorm over each head's entries of x [B, T, H, d] (one gain [d])
-    times silu(gate [B, T, H * d]) -> [B, T, H * d]."""
+    times ``activation``(gate [B, T, H * d]), ``silu`` or ``sigmoid``
+    -> [B, T, H * d]."""
+    if activation not in ("silu", "sigmoid"):
+        raise ValueError(f"gated_rms_norm: activation {activation!r}, "
+                         f"wanted 'silu' or 'sigmoid'")
     helper = LayerHelper("gated_rms_norm", name=name)
     scale = helper.create_parameter(param_attr, shape=[x.shape[-1]],
                                     dtype="float32",
                                     default_initializer=Constant(1.0))
     return _out_f32(helper, "gated_rms_norm",
                     {"X": [x], "Gate": [gate], "Scale": [scale]},
-                    {"epsilon": float(epsilon)})
+                    {"epsilon": float(epsilon), "activation": activation})
 
 
 def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,

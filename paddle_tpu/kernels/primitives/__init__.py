@@ -16,6 +16,8 @@ serving lane lowers through:
   vit        bidirectional attention inside one image (a vision tower)
   gdn        the gated delta rule over a per-sequence state: a chunked
              form for the prefill chunk, an in-place step for decode
+  kda        the same rule with a decay a key channel (Kimi Delta
+             Attention), over gdn's state layout: its chunk and step
 
 Raw ``pl.pallas_call`` / ``pltpu`` outside this package is a lint
 error (tools/lint_kernels.py) unless marked ``# kernel: allow``.
@@ -48,6 +50,7 @@ from .gdn import (  # noqa: F401
     gated_delta_chunk, gated_delta_chunk_reference, gated_delta_step,
     gated_delta_step_reference,
 )
+from .kda import kda_chunk, kda_step  # noqa: F401
 from .grouped import (  # noqa: F401
     grouped_matmul, grouped_matmul_reference,
 )
@@ -84,4 +87,5 @@ __all__ = [
     "vit_attention", "vit_attention_reference",
     "gated_delta_chunk", "gated_delta_chunk_reference",
     "gated_delta_step", "gated_delta_step_reference",
+    "kda_chunk", "kda_step",
 ]

@@ -14,6 +14,14 @@ the log of the decay (<= 0) and ``beta`` in (0, 2); a position with
 tail).  Everything here is float32: the state is stored and updated in
 float32 and every product is taken at full precision.
 
+``g`` has one of two shapes.  One number a HEAD, ``[.., H]``: the rule
+above, and the two kernels of this file.  One number a KEY CHANNEL,
+``[.., H, d_k]`` (Kimi Delta Attention): ``alpha`` is then
+``Diag(alpha_t)`` on the state's d_k rows, ``S' = Diag(alpha_t)
+S_{t-1}``, and the kernels are kda.py's (``kda_chunk``, ``kda_step``),
+which share this file's layout, triangular inverse and reference forms:
+the XLA reference forms below take either shape.
+
 Two forms of the same numbers:
 
   gated_delta_chunk   one sequence's C tokens, all heads (a prefill
@@ -122,8 +130,10 @@ def _book_form(primitive, form):
 
 def _rule(s, q, k, v, g, beta):
     """One token of every head: s [H, d_k, d_v], q, k [H, d_k], v
-    [H, d_v], g, beta [H] -> (new s, o [H, d_v])."""
-    s = jnp.exp(g)[:, None, None] * s
+    [H, d_v], beta [H], g [H] (a decay a head) or [H, d_k] (a decay a
+    key channel, on the state's rows) -> (new s, o [H, d_v])."""
+    alpha = jnp.exp(g)
+    s = (alpha[:, None, None] if g.ndim == 1 else alpha[:, :, None]) * s
     r = jnp.einsum("hkv,hk->hv", s, k, precision=_HIGHEST)
     u = beta[:, None] * (v - r)
     s = s + k[:, :, None] * u[:, None, :]
@@ -136,7 +146,8 @@ def _f32(*xs):
 
 def gated_delta_chunk_reference(q, k, v, g, beta, state, block, fresh):
     """The recurrence over one sequence's tokens: q, k [C, H, d_k], v
-    [C, H, d_v], g, beta [C, H]; ``state`` [blocks, d_k, H * d_v],
+    [C, H, d_v], beta [C, H], g [C, H] or [C, H, d_k]; ``state``
+    [blocks, d_k, H * d_v],
     ``block`` the sequence's block (a scalar), ``fresh`` (a scalar bool)
     reads the block as zeros.  -> (o [C, H, d_v], the state tensor with
     the block written)."""
@@ -148,10 +159,10 @@ def gated_delta_chunk_reference(q, k, v, g, beta, state, block, fresh):
 
 
 def gated_delta_step_reference(q, k, v, g, beta, state, blocks):
-    """One token a slot: q, k [B, H, d_k], v [B, H, d_v], g, beta [B, H],
-    ``blocks`` [B] each slot's state block.  -> (o [B, H, d_v], the state
-    tensor with the slots' blocks written; slots that share the trash
-    block write it in turn)."""
+    """One token a slot: q, k [B, H, d_k], v [B, H, d_v], beta [B, H], g
+    [B, H] or [B, H, d_k], ``blocks`` [B] each slot's state block.  -> (o
+    [B, H, d_v], the state tensor with the slots' blocks written; slots
+    that share the trash block write it in turn)."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
     heads = q.shape[1]
     s0 = jax.vmap(lambda b: _heads_first(b, heads))(state[blocks])
@@ -277,6 +288,31 @@ def _pallas_chunk(q, k, v, g, beta, s0, sub, interpret):
     return o.transpose(1, 0, 2), s
 
 
+def _run_chunk(op, pallas_chunk, form, q, k, v, g, beta, state, block, fresh,
+               force):
+    """What the chunk forms of the rule share (this file's and kda.py's):
+    the dispatch, the tail padded to whole sub-chunks, the sequence's
+    block read (as zeros where ``fresh``) and written back.
+    ``pallas_chunk(q, k, v, g, beta, s0, sub, interpret)`` -> (o, s);
+    ``form(sub)`` names the choice on ``pt_gated_delta_form_total``."""
+    _check(op, q, k, v, state)
+    mode, interpret = contract.resolve_mode(op, force)
+    if mode != "pallas":
+        return gated_delta_chunk_reference(q, k, v, g, beta, state, block,
+                                           fresh)
+    q, k, v, g, beta = _f32(q, k, v, g, beta)
+    c, heads = q.shape[0], q.shape[1]
+    sub = SUB if c >= SUB else BASE
+    pad = -c % sub
+    if pad:  # beta = 0, g = 0: the tail leaves the state alone
+        q, k, v, g, beta = (jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+                            for x in (q, k, v, g, beta))
+    _book_form(op, form(sub))
+    s0 = jnp.where(fresh, 0.0, _heads_first(state[block], heads))
+    o, s = pallas_chunk(q, k, v, g, beta, s0, sub, interpret)
+    return o[:c], state.at[block].set(_stored(s))
+
+
 def gated_delta_chunk(q, k, v, g, beta, state, block, fresh, *, force=None):
     """The gated delta rule over one sequence's C tokens, all heads: q, k
     [C, H, d_k], v [C, H, d_v], g, beta [C, H] -> (o [C, H, d_v] float32,
@@ -288,22 +324,9 @@ def gated_delta_chunk(q, k, v, g, beta, state, block, fresh, *, force=None):
 
     force: None -> Pallas on TPU, XLA reference elsewhere; "pallas" ->
     Pallas (interpret mode off-TPU); "reference" -> XLA."""
-    _check("gated_delta_chunk", q, k, v, state)
-    mode, interpret = contract.resolve_mode("gated_delta_chunk", force)
-    if mode != "pallas":
-        return gated_delta_chunk_reference(q, k, v, g, beta, state, block,
-                                           fresh)
-    q, k, v, g, beta = _f32(q, k, v, g, beta)
-    c, heads = q.shape[0], q.shape[1]
-    sub = SUB if c >= SUB else BASE
-    pad = -c % sub
-    if pad:  # beta = 0, g = 0: the tail leaves the state alone
-        q, k, v, g, beta = (jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-                            for x in (q, k, v, g, beta))
-    _book_form("gated_delta_chunk", f"sub{sub}")
-    s0 = jnp.where(fresh, 0.0, _heads_first(state[block], heads))
-    o, s = _pallas_chunk(q, k, v, g, beta, s0, sub, interpret)
-    return o[:c], state.at[block].set(_stored(s))
+    return _run_chunk("gated_delta_chunk", _pallas_chunk,
+                      lambda sub: f"sub{sub}", q, k, v, g, beta, state, block,
+                      fresh, force)
 
 
 # ---------------------------------------------------------------------------
@@ -321,11 +344,10 @@ def _heads_per_tile(heads, dk, dv):
     return max(fits) if fits else heads
 
 
-def _step_kernel(blk_ref, q_ref, k_ref, kt_ref, v_ref, a_ref, b_ref, e_ref,
-                 s_ref, o_ref, so_ref):
-    del blk_ref  # read by the index maps
-    pick = e_ref[...]                                  # [hp, L] 0 / 1
-    s = a_ref[0] * s_ref[0]                            # [d_k, L]
+def _step_update(s, pick, q_ref, k_ref, kt_ref, v_ref, b_ref, o_ref, so_ref):
+    """The token's update of one (slot, tile of heads) from its DECAYED
+    state s [d_k, L]; ``pick`` [hp, L] 0 / 1 says which lanes are which
+    head's."""
     r = jnp.sum(_mm(k_ref[0, 0], s) * pick, axis=0, keepdims=True)
     u = b_ref[0] * (v_ref[0] - r)                      # [1, L]
     s = s + _mm(kt_ref[0, 0], pick) * u
@@ -333,16 +355,34 @@ def _step_kernel(blk_ref, q_ref, k_ref, kt_ref, v_ref, a_ref, b_ref, e_ref,
     so_ref[0] = s
 
 
-def _pallas_step(q, k, v, g, beta, state, blocks, interpret):
+def _step_kernel(blk_ref, q_ref, k_ref, kt_ref, v_ref, a_ref, b_ref, e_ref,
+                 s_ref, o_ref, so_ref):
+    del blk_ref  # read by the index maps
+    pick = e_ref[...]
+    _step_update(a_ref[0] * s_ref[0], pick, q_ref, k_ref, kt_ref, v_ref,
+                 b_ref, o_ref, so_ref)
+
+
+def _launch_step(op, kernel, decay, q, k, v, beta, state, blocks, interpret):
+    """What the step forms of the rule share (this file's and kda.py's):
+    heads by lane tile, the 0/1 pick matrix, the slot's block found
+    through the scalar-prefetched index and rewritten where it lies.
+    ``decay(by_tile, lanes_of, head_rows, lane_row)`` -> (the decay's
+    operand, its block): the one operand the two kernels take in
+    different shapes, the sixth of ``kernel``."""
     b, heads, dk = q.shape
     dv = v.shape[-1]
     hg = _heads_per_tile(heads, dk, dv)
-    _book_form("gated_delta_step", f"heads{hg}")
+    _book_form(op, f"heads{hg}")
     tiles, lanes = heads // hg, hg * dv
     hp = -(-hg // 8) * 8
     pad = ((0, 0), (0, 0), (0, hp - hg), (0, 0))
-    qx = jnp.pad(q.reshape(b, tiles, hg, dk), pad)
-    kx = jnp.pad(k.reshape(b, tiles, hg, dk), pad)
+
+    def by_tile(x):                          # [B, H, dk] -> [B, tiles, hp, dk]
+        return jnp.pad(x.reshape(b, tiles, hg, dk), pad)
+
+    qx = by_tile(q)
+    kx = by_tile(k)
 
     def lanes_of(x):                                   # [B, H] -> [B, 1, H dv]
         return jnp.repeat(x, dv, axis=-1)[:, None, :]
@@ -355,11 +395,15 @@ def _pallas_step(q, k, v, g, beta, state, blocks, interpret):
 
     lane_row = Block((1, 1, lanes), lambda i, t, blk: (i, 0, t))
     block = Block((1, dk, lanes), lambda i, t, blk: (blk[i], 0, t))
+    operands = [blocks.astype(jnp.int32), qx, kx, kx.transpose(0, 1, 3, 2),
+                v.reshape(b, 1, heads * dv)]
+    decay_operand, decay_block = decay(by_tile, lanes_of, head_rows, lane_row)
+    operands += [decay_operand, lanes_of(beta), pick, state]
     spec = contract.make_spec(
-        "gated_delta_step",
+        op,
         grid=(b, tiles),
         in_specs=[head_rows(hp, dk), head_rows(hp, dk), head_rows(dk, hp),
-                  lane_row, lane_row, lane_row,
+                  lane_row, decay_block, lane_row,
                   Block((hp, lanes), lambda i, t, blk: (0, 0)), block],
         out_specs=[lane_row, block],
         out_shape=[((b, 1, heads * dv), jnp.float32),
@@ -370,11 +414,16 @@ def _pallas_step(q, k, v, g, beta, state, blocks, interpret):
         input_output_aliases={8: 1},
         interpret=interpret,
     )
-    o, state = contract.primitive_call(
-        _step_kernel, spec, blocks.astype(jnp.int32), qx, kx,
-        kx.transpose(0, 1, 3, 2), v.reshape(b, 1, heads * dv),
-        lanes_of(jnp.exp(g)), lanes_of(beta), pick, state)
+    o, state = contract.primitive_call(kernel, spec, *operands)
     return o.reshape(b, heads, dv), state
+
+
+def _pallas_step(q, k, v, g, beta, state, blocks, interpret):
+    def decay(by_tile, lanes_of, head_rows, lane_row):
+        return lanes_of(jnp.exp(g)), lane_row          # alpha a head's lanes
+
+    return _launch_step("gated_delta_step", _step_kernel, decay, q, k, v,
+                        beta, state, blocks, interpret)
 
 
 def gated_delta_step(q, k, v, g, beta, state, blocks, *, force=None):

@@ -60,8 +60,8 @@ from paddle_tpu.fluid.initializer import Constant
 from paddle_tpu.fluid.param_attr import ParamAttr
 
 from . import moe_stats
-from .decode_blocks import (_attr, _linear, _next_token, _rms, _rope,
-                            _swiglu_ffn)
+from .decode_blocks import (_attr, _linear, _next_token, expert_ffn,
+                            latent_attention)
 
 
 class KimiVLConfig:
@@ -229,72 +229,6 @@ def _linear_b(x, size, name, cfg, head_dim=None):
                                   bias)
 
 
-def _attention(x, pos, page_table, q_start, pool, write, shape, cfg, name,
-               attn_force):
-    """Latent attention over every visible row; writes the token's cache
-    row first (a query sees its own position).  A decode step (T = 1)
-    takes the latent-space form, a chunk the head-space form: the same
-    parameters either way."""
-    L = layers
-    b, t = shape
-    heads = cfg.num_attention_heads
-    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    (latent_pool,) = pool
-    xa = _rms(x, name + "_attn_norm", cfg)
-    q = L.reshape(_linear(xa, heads * (nope + rope), name + "_q", cfg,
-                          nope + rope),
-                  shape=[b, t, heads, nope + rope])
-    q_nope, q_rope = L.split(q, [nope, rope], dim=-1)
-    q_rope = _rope(q_rope, pos, cfg)
-    c_kv, k_rope = L.split(
-        _linear(xa, cfg.kv_lora_rank + rope, name + "_kv_a", cfg),
-        [cfg.kv_lora_rank, rope], dim=-1)
-    latent = [_rms(c_kv, name + "_kv_a_norm", cfg), _rope(k_rope, pos, cfg)]
-    pad = latent_pool.shape[2] - cfg.kv_lora_rank - rope
-    if pad:  # the row is stored at whole lane tiles (lane.lane_padded)
-        latent.append(L.fill_constant(shape=[b, t, pad], value=0.0,
-                                      dtype="float32"))
-    write(latent_pool, L.cast(L.concat(latent, axis=2), latent_pool.dtype))
-
-    scale = float(nope + rope) ** -0.5
-    k_attr = _attr(name + "_kv_b_k.w_0", cfg)
-    v_attr = _attr(name + "_kv_b_v.w_0", cfg)
-    if t == 1:
-        q_lat = L.headwise_matmul(q_nope, cfg.kv_lora_rank,
-                                  param_attr=k_attr, dtype=cfg.dtype)
-        o_lat = L.paged_mla_attention(q_lat, q_rope, latent_pool,
-                                      page_table, q_start, sm_scale=scale,
-                                      force=attn_force)
-        o = L.headwise_matmul(o_lat, cfg.v_head_dim, param_attr=v_attr,
-                              dtype=cfg.dtype)
-    else:
-        o = L.mla_chunk_attention(
-            q_nope, q_rope, latent_pool, page_table, q_start,
-            cfg.kv_lora_rank, cfg.v_head_dim, sm_scale=scale, k_attr=k_attr,
-            v_attr=v_attr, dtype=cfg.dtype, force=attn_force)
-    return _linear(L.reshape(o, shape=[b, t, heads * cfg.v_head_dim]),
-                   cfg.hidden_size, name + "_o", cfg)
-
-
-def _ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
-    xf = _rms(x, name + "_ffn_norm", cfg)
-    if layer < cfg.first_k_dense_replace:
-        return _swiglu_ffn(xf, cfg.intermediate_size, name + "_ffn", cfg)
-    stats = (moe_stats.expert_stats_var(cfg, layer, counted_as)
-             if counted_as else None)
-    routed = layers.moe_ffn_held(
-        xf, cfg.n_routed_experts, cfg.held_experts,
-        cfg.moe_intermediate_size, cfg.num_experts_per_tok,
-        first_expert=cfg.first_expert,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        norm_topk_prob=cfg.norm_topk_prob, row_valid=row_valid, stats=stats,
-        dtype=cfg.dtype, force=attn_force, name=name + "_moe")
-    shared = _swiglu_ffn(
-        xf, cfg.n_shared_experts * cfg.moe_intermediate_size,
-        name + "_shared", cfg)
-    return layers.elementwise_add(routed, shared)
-
-
 def _decoder(frame, cfg):
     """Embedding (image rows where the frame's ``image_rows`` = (staged
     rows, index a position) says so) and every block over the frame's
@@ -312,13 +246,13 @@ def _decoder(frame, cfg):
         x = L.select_embedding_rows(x, *frame.image_rows)
     for layer in range(cfg.num_hidden_layers):
         name = f"kimi_layer_{layer}"
-        x = L.elementwise_add(x, _attention(
+        x = L.elementwise_add(x, latent_attention(
             x, frame.pos, frame.tables[FULL], frame.q_start,
             frame.pools[layer], frame.writes[FULL], frame.shape, cfg, name,
             frame.attn_force))
-        x = L.elementwise_add(x, _ffn(x, layer, frame.row_valid,
-                                      frame.counted_as, cfg, name,
-                                      frame.attn_force))
+        x = L.elementwise_add(x, expert_ffn(x, layer, frame.row_valid,
+                                            frame.counted_as, cfg, name,
+                                            frame.attn_force))
     return x
 
 

@@ -202,6 +202,7 @@ def _linear_attention(x, state, block, q_start, last_idx, row_valid, cfg,
                            force=attn_force)
     o = L.gated_rms_norm(
         o, _linear(u, h * dv, name + "_g", cfg), epsilon=cfg.rms_norm_eps,
+        activation="silu",
         param_attr=ParamAttr(name=name + "_o_norm.scale",
                              initializer=Constant(1.0)))
     return _linear(o, cfg.hidden_size, name + "_o", cfg)

@@ -1,5 +1,6 @@
 """Ops of a linear-attention (gated delta rule) layer served through the
-decode lane (models/olmo_hybrid.py), beside ops/mla_ops.py (whose
+decode lane (models/olmo_hybrid.py, models/kimi_linear.py), beside
+ops/mla_ops.py (whose
 ``weight_matmul``, ``rms_norm`` and ``swiglu`` it shares):
 
   short_conv_chunk   depthwise causal convolution over time, then SiLU,
@@ -9,12 +10,16 @@ decode lane (models/olmo_hybrid.py), beside ops/mla_ops.py (whose
                      block index
   gdn_inputs         the conv's output and the two gate projections as
                      the rule's operands: L2-normalised q (scaled) and k,
-                     v, g = log alpha, beta
-  gated_delta_chunk  the gated delta rule over a per-sequence state
-  gated_delta_step   (kernels/primitives/gdn.py): a prefill chunk's form
-                     and a decode step's, the state tensor updated in
-                     place
+                     v, g = log alpha, beta.  ``g`` is one number a
+                     head, [B, T, H], or, where the gate projection is
+                     H d_k wide, one a key channel, [B, T, H, d_k]
+  gated_delta_chunk  the gated delta rule over a per-sequence state: a
+  gated_delta_step   prefill chunk's form and a decode step's, the state
+                     tensor updated in place.  ``g`` [.., H] runs
+                     kernels/primitives/gdn.py's kernels, ``g`` [.., H,
+                     d_k] kda.py's
   gated_rms_norm     RMSNorm over each head's entries times silu(gate)
+                     or sigmoid(gate)
 
 All inference-only (grad=None), float32 in and out.  The state tensors
 are persistable vars of the pool (serving/kv_pool.py): ``*Out`` IS the
@@ -84,8 +89,10 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
     a, b [B, T, H] the two gate projections -> q [B, T, H, d_k] =
     l2norm(q') / sqrt(d_k), k = l2norm(k'), v [B, T, H, d_v], g = -exp(
     ALog) softplus(a + DtBias), beta = beta_scale sigmoid(b).  l2norm(x) =
-    x rsqrt(sum x^2 + eps).  Rows that ``row_valid`` [T] marks 0 get
-    beta = 0 and g = 0: the rule leaves the state alone there."""
+    x rsqrt(sum x^2 + eps).  With a [B, T, H d_k] and DtBias [H d_k] (a
+    decay a key channel; ALog stays [H]) g is [B, T, H, d_k].  Rows that
+    ``row_valid`` [T] marks 0 get beta = 0 and g = 0: the rule leaves
+    the state alone there."""
     heads, dk, dv = (int(attrs[k]) for k in ("heads", "key_dim",
                                              "value_dim"))
     eps = float(attrs["epsilon"])
@@ -99,11 +106,16 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
     q = l2norm(qkv[..., :heads * dk].reshape(*lead, heads, dk)) * dk ** -0.5
     k = l2norm(qkv[..., heads * dk:2 * heads * dk].reshape(*lead, heads, dk))
     v = qkv[..., 2 * heads * dk:].reshape(*lead, heads, dv)
-    g = -jnp.exp(_f32(a_log)) * jax.nn.softplus(_f32(a) + _f32(dt_bias))
+    if a.shape[-1] == heads:
+        g = -jnp.exp(_f32(a_log)) * jax.nn.softplus(_f32(a) + _f32(dt_bias))
+    else:                             # a decay a key channel
+        g = -jnp.exp(_f32(a_log))[:, None] * jax.nn.softplus(
+            (_f32(a) + _f32(dt_bias)).reshape(*lead, heads, dk))
     beta = float(attrs["beta_scale"]) * jax.nn.sigmoid(_f32(b))
     if row_valid is not None:
         live = (row_valid.reshape(1, -1, 1) > 0).astype(jnp.float32)
-        g, beta = g * live, beta * live
+        g = g * (live if g.ndim == 3 else live[..., None])
+        beta = beta * live
     return q, k, v, g, beta
 
 
@@ -112,10 +124,12 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
            ["Out", "StateOut"], grad=None, inplace={"StateOut": "State"})
 def _gated_delta_chunk(ctx, q, k, v, g, beta, state, block, q_start, attrs):
     """One sequence's chunk [1, C, H, .]; its state block ``block`` [1]
-    is read as zeros where ``q_start`` [1] is 0."""
+    is read as zeros where ``q_start`` [1] is 0.  g [1, C, H], or
+    [1, C, H, d_k] for a decay a key channel."""
     from paddle_tpu.kernels import primitives as _prims
 
-    out, state = _prims.gated_delta_chunk(
+    rule = _prims.gated_delta_chunk if g.ndim == 3 else _prims.kda_chunk
+    out, state = rule(
         q[0], k[0], v[0], g[0], beta[0], state,
         block.reshape(()).astype(jnp.int32), q_start.reshape(()) == 0,
         force=attrs.get("force"))
@@ -126,10 +140,12 @@ def _gated_delta_chunk(ctx, q, k, v, g, beta, state, block, q_start, attrs):
            ["Q", "K", "V", "G", "Beta", "State", "Block"],
            ["Out", "StateOut"], grad=None, inplace={"StateOut": "State"})
 def _gated_delta_step(ctx, q, k, v, g, beta, state, blocks, attrs):
-    """One token a slot [B, 1, H, .]; ``blocks`` [B]."""
+    """One token a slot [B, 1, H, .]; ``blocks`` [B].  g [B, 1, H], or
+    [B, 1, H, d_k] for a decay a key channel."""
     from paddle_tpu.kernels import primitives as _prims
 
-    out, state = _prims.gated_delta_step(
+    rule = _prims.gated_delta_step if g.ndim == 3 else _prims.kda_step
+    out, state = rule(
         q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, blocks,
         force=attrs.get("force"))
     return out[:, None], state
@@ -138,8 +154,11 @@ def _gated_delta_step(ctx, q, k, v, g, beta, state, blocks, attrs):
 @simple_op("gated_rms_norm", ["X", "Gate", "Scale"], ["Out"], grad=None)
 def _gated_rms_norm(ctx, x, gate, scale, attrs):
     """x [B, T, H, d] -> [B, T, H d]: RMSNorm over each head's d entries
-    with one gain [d], times silu(gate [B, T, H d])."""
+    with one gain [d], times ``activation``(gate [B, T, H d]): ``silu``
+    (the default) or ``sigmoid``."""
+    act = {"silu": _silu, "sigmoid": jax.nn.sigmoid}[
+        attrs.get("activation", "silu")]
     x = _f32(x)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + float(attrs["epsilon"])) * _f32(scale)
-    return y.reshape(gate.shape) * _silu(_f32(gate))
+    return y.reshape(gate.shape) * act(_f32(gate))

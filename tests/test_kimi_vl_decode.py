@@ -323,6 +323,7 @@ def test_every_expert_held_is_the_uncut_layer(weights):
     """8 held of 8: the expert layer is the reference's whole layer, no
     pick lost (the share that is the whole)."""
     from paddle_tpu.fluid import layers
+    from paddle_tpu.models import decode_blocks
 
     cfg = _cfg()
     rng = np.random.RandomState(2)
@@ -330,7 +331,7 @@ def test_every_expert_held_is_the_uncut_layer(weights):
     main, start = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, start), fluid.unique_name.guard():
         xin = fluid.data("x", [1, 8, 64], False, dtype="float32")
-        out = layers.elementwise_add(xin, kimi_vl._ffn(
+        out = layers.elementwise_add(xin, decode_blocks.expert_ffn(
             xin, 1, None, None, cfg, "kimi_layer_1", None))
     (got,) = fluid.Executor(fluid.CPUPlace()).run(
         main, feed={"x": x}, fetch_list=[out], scope=_scope_with(weights))

@@ -25,7 +25,11 @@ encoder of its one declared shape), the `olmo_hybrid.*` four and the
 `mimo.*` four are of commit e98f100 (PR 43's tree), made before PR 44
 moved the lanes' program scaffold out of the model files into
 `serving/lane.py`: all thirty are the proof that the move changed no
-executable.  After a deliberate change
+executable.  PR 46 moved kimi_vl.py's latent attention and expert
+layer to `models/decode_blocks.py` under public names (`kimi_vl.*`
+stand) and widened the delta-rule ops (`olmo_hybrid.*` stand); the
+`kimi_linear.*` four are PR 46's own lane (KDA layers beside a latent
+layer, 4 of 8 experts held), pinned as it was added.  After a deliberate change
 to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
@@ -41,7 +45,8 @@ import numpy as np
 import pytest
 
 from paddle_tpu import fluid, serving
-from paddle_tpu.models import glm, gpt, kimi_vl, mimo, olmo_hybrid, trinity
+from paddle_tpu.models import (glm, gpt, kimi_linear, kimi_vl, mimo,
+                               olmo_hybrid, trinity)
 
 GOLDEN = {
     "gpt.float32.None.prefill": "e4de139bba244c34c30e3378cea1230b90f392d45e9ccf67a0d043c67755d999",
@@ -73,11 +78,16 @@ GOLDEN = {
     "mimo.None.prefill": "3c2ece044c67fda4e36e33b3ae2b728bb1251c295704a6f781a555a290e90e71",
     "mimo.None.decode": "aa38eae686b3abd65a3cbbcc60fef6e4b5ce9004cefb869562b8a65f09bb7a4a",
     "mimo.pallas.prefill": "948323c8f183e13c9742b70dd73a21b84939d27192a01347bb70d8776ed30d46",
-    "mimo.pallas.decode": "de58ea6e91449e77cdc780da162099b5ebee6d66b73e5ff677966152b2d1c245"
+    "mimo.pallas.decode": "de58ea6e91449e77cdc780da162099b5ebee6d66b73e5ff677966152b2d1c245",
+    "kimi_linear.None.prefill": "1da69d78a406e226867b65aea9171145f7de7d372153abce0de31929fcefd685",
+    "kimi_linear.None.decode": "5267acacf672a7a037aae8b01cbed62f5b65afd7e4b72088f5b11c28cc74ed0d",
+    "kimi_linear.pallas.prefill": "bbcde65d990121b1f031f763f59a7cbf494b43021dafb5893d71c3ec67525e2b",
+    "kimi_linear.pallas.decode": "c32d82a3bd91aea4551e5c6496dffd1d9a61c90ddce978b94c401203afefe0da"
 }
 
 
-MODELS = ("gpt", "glm", "trinity", "kimi_vl", "olmo_hybrid", "mimo")
+MODELS = ("gpt", "glm", "trinity", "kimi_vl", "olmo_hybrid", "mimo",
+          "kimi_linear")
 
 
 def _zero_scope(*builds):
@@ -128,6 +138,10 @@ def _later_model(model):
     if model == "olmo_hybrid":
         cfg = olmo_hybrid.OlmoHybridConfig.tiny()
         return cfg, [lambda: olmo_hybrid.build_olmo_hybrid_lm(cfg)]
+    if model == "kimi_linear":
+        cfg = kimi_linear.KimiLinearConfig.tiny(held_experts=4,
+                                                first_expert=2)
+        return cfg, [lambda: kimi_linear.build_kimi_linear_lm(cfg)]
     # K heads of 192 beside V heads of 128, the published widths: the
     # asymmetric Pallas forms read whole 128-lane tiles and take no other
     cfg = mimo.MiMoConfig.tiny(

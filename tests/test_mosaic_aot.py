@@ -392,9 +392,21 @@ def _mimo_cut(config):
     return cfg, [lambda: mimo.build_mimo_lm(cfg)]
 
 
+def _kimi_linear_cut(config):
+    from paddle_tpu.models import kimi_linear
+
+    # one period of its two: KDA (the dense layer), KDA, KDA, latent
+    cfg = kimi_linear.KimiLinearConfig(**dict(
+        _config_args(config), num_hidden_layers=4,
+        linear_attn_config=dict(config["linear_attn_config"],
+                                kda_layers=[1, 2, 3], full_attn_layers=[4])))
+    return cfg, [lambda: kimi_linear.build_kimi_linear_lm(cfg)]
+
+
 _CUTS = {"glm-5-ep16": _glm_cut, "trinity-large-ep8": _trinity_cut,
          "kimi-vl-a3b-ep1": _kimi_cut, "olmo-hybrid-7b-pp4": _olmo_cut,
-         "mimo-v2.5-ep16": _mimo_cut}
+         "mimo-v2.5-ep16": _mimo_cut,
+         "kimi-linear-48b-ep4": _kimi_linear_cut}
 
 
 @functools.lru_cache(maxsize=None)
@@ -771,6 +783,117 @@ def test_olmo_hybrid_decode_engine_executables():
 
 
 # ---------------------------------------------------------------------------
+# Kimi-Linear through the decode lane (benchmark/configs/
+# kimi-linear-48b-ep4.json): the two KDA kernels and both latent forms at
+# the published widths and 32 SLOTS, and the engine's two executables over
+# latent pages AND per-sequence state blocks
+# ---------------------------------------------------------------------------
+
+_KLIN_PAGES, _KLIN_BLOCKS = 32 * 272 + 1, 34
+_KLIN_STATE = ((_KLIN_BLOCKS, 128, 32 * 128), jnp.float32)
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_kda_kernels_at_kimi_linear_widths(chip, form):
+    """32 heads of 128 keys x 128 values, a decay a key channel, over the
+    float32 state tensor [34, 128, 4096]: the step over 32 slots (the
+    tensor aliased, rewritten where it lies) and the chunk over 512
+    tokens (one block sliced out, solved in sub-chunks of 64 with blocks
+    of 8, written back).  Neither copies the tensor."""
+    f32 = jnp.float32
+    n = 32 if form == "step" else 512
+    rows = [((n, 32, 128), f32)] * 4 + [((n, 32), f32), _KLIN_STATE]
+    if form == "step":
+        fn = prims.kda_step
+        rows.append(((32,), jnp.int32))
+    else:
+        def fn(q, k, v, g, beta, state, block, fresh):
+            return prims.kda_chunk(q, k, v, g, beta, state, block[0],
+                                   fresh[0])
+        rows += [((1,), jnp.int32), ((1,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in rows]
+    with lowering_for("tpu"):
+        hlo = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile() \
+            .as_text()
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(rf"%kda_{form}[.\d]* = ", hlo)) == 1
+    assert "%gated_delta" not in hlo
+    assert _state_copies(hlo, "34,128,4096") == []
+    assert 5 in _aliased_parameters(hlo)
+
+
+def test_mla_kernels_at_kimi_linear_widths(chip):
+    """Both latent forms at 32 heads: the decode body over 32 slots x 32
+    query rows, the chunk's head-space form over 32 heads of 512
+    queries; the pool of 8705 pages is not copied."""
+    bf = jnp.bfloat16
+    pool = ((_KLIN_PAGES, 128, 640), bf)
+    hlo = _compile(
+        lambda ql, qr, pages, pt, qs: prims.paged_mla_attention(
+            ql, qr, pages, pt, qs, sm_scale=192 ** -0.5),
+        chip, ((32, 1, 32, 512), jnp.float32), ((32, 1, 32, 64), jnp.float32),
+        pool, ((32, 272), jnp.int32), ((32,), jnp.int32))
+    assert _mosaic_calls(hlo) == 1 and "%paged_mla_attention" in hlo
+    assert _pool_copies(hlo, _KLIN_PAGES, 128) == []
+    hlo = _compile(
+        lambda qn, qr, pages, pt, qs, uk, uv: prims.mla_chunk_attention(
+            qn, qr, pages, pt, qs, uk, uv, sm_scale=192 ** -0.5),
+        chip, ((1, 512, 32, 128), jnp.float32),
+        ((1, 512, 32, 64), jnp.float32), pool, ((1, 272), jnp.int32),
+        ((1,), jnp.int32), ((32, 128, 512), bf), ((32, 512, 128), bf))
+    assert _mosaic_calls(hlo) == 1 and "%mla_chunk_attention" in hlo
+    assert _pool_copies(hlo, _KLIN_PAGES, 128) == []
+
+
+def test_kimi_linear_decode_engine_executables():
+    """The prefill chunk and the decode step of Kimi-Linear at the
+    benchmark's widths, pool and 32 slots (one period of its two: KDA,
+    KDA, KDA, latent; the first layer dense, three expert layers): a KDA
+    call a KDA layer under the names the benchmark's two new patterns
+    read, one latent call (latent space in the step, head space in the
+    chunk) under the names ``mla_decode_roofline.serve`` and
+    ``mla_chunk_mxu_share.serve`` read, three grouped products an expert
+    layer, and both kinds of cache (latent pages and per-sequence state
+    blocks) donated, row-major and UNCOPIED."""
+    served = _served("kimi-linear-48b-ep4")
+    assert served.prefill_chunk == 512
+    assert served.pool.num_pages == _KLIN_PAGES
+    assert served.pool.state_blocks == _KLIN_BLOCKS
+    reads = {form: [re.compile(harness_json(_ROOT, metric)["pattern"])
+                    for metric in metrics]
+             for form, metrics in (
+                 ("chunk", ("kda_chunk_mxu_share.serve",
+                            "mla_chunk_mxu_share.serve")),
+                 ("step", ("kda_step_roofline.serve",
+                           "mla_decode_roofline.serve")))}
+    grouped = re.compile(harness_json(_ROOT, "moe_ffn_roofline.serve")
+                         ["pattern"])
+    for form in ("chunk", "step"):
+        hlo, lines = served.exes[form]
+        other = "step" if form == "chunk" else "chunk"
+        kda_mine, mla_mine = reads[form]
+        assert sum(bool(kda_mine.search(x)) for x in lines) == 3
+        assert sum(bool(mla_mine.search(x)) for x in lines) == 1
+        for pattern in reads[other]:
+            assert sum(bool(pattern.search(x)) for x in lines) == 0
+        assert sum(bool(grouped.search(x)) for x in lines) == 3 * 3
+        assert "%gated_delta" not in hlo
+        assert _mosaic_calls(hlo) == 3 + 1 + 9
+        assert _pool_copies(hlo, _KLIN_PAGES, 128) == []
+        latent = _pool_parameters(hlo, f"{_KLIN_PAGES},128,640")
+        state = _pool_parameters(hlo, "34,128,4096")
+        tails = _pool_parameters(hlo, "34,36864")
+        assert len(latent) == 1                    # ONE row tensor a layer
+        assert len(state) == len(tails) == 3       # a KDA layer
+        assert _state_copies(hlo, "34,128,4096") == []
+        assert _state_copies(hlo, "34,36864") == []
+        assert [lay for _, lay in latent + state
+                if not lay.startswith("{2,1,0")] == []
+        assert {num for num, _ in latent + state + tails} <= \
+            _aliased_parameters(hlo)
+
+
+# ---------------------------------------------------------------------------
 # MiMo-V2.5 through the decode lane (benchmark/configs/mimo-v2.5-ep16.json):
 # the asymmetric paged kernels (K heads of 192 beside V heads of 128, the
 # window layers' sink) at the published widths, and the engine's two
@@ -913,8 +1036,14 @@ _COPY_MB = {
     ("kimi-vl-a3b-ep1", "tower0"): (177.2, 177.2),
     ("kimi-vl-a3b-ep1", "tower1"): (257.4, 257.4),
     ("kimi-vl-a3b-ep1", "tower2"): (85.2, 85.2),
+    # new in PR 46 (no parent): what the cut's executables copy today,
+    # activations all (the chunk's [512, 32, 128] operands laid heads
+    # first for the KDA kernel)
+    ("kimi-linear-48b-ep4", "chunk"): (424.4, 424.4),
+    ("kimi-linear-48b-ep4", "step"): (23.2, 23.2),
 }
-_PINNED_CHUNKS = {("mimo-v2.5-ep16", "chunk"), ("kimi-vl-a3b-ep1", "chunk")}
+_PINNED_CHUNKS = {("mimo-v2.5-ep16", "chunk"), ("kimi-vl-a3b-ep1", "chunk"),
+                  ("kimi-linear-48b-ep4", "chunk")}
 
 
 @pytest.mark.parametrize("name,exe", list(_COPY_MB))

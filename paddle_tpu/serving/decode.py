@@ -36,7 +36,7 @@ A lane may declare state a SEQUENCE owns in some layers (serving/lane.py
 models/olmo_hybrid.py).  The pool then has the kind ``state`` beside its
 page kinds: one block a sequence from ``open_seq`` to ``free_seq``.  A
 prefilling sequence has a seq_id and no slot, so the programs find the
-state by BLOCK INDEX, one feed more in each executable
+state by BLOCK INDEX, one piece more in each executable's feed
 (``pf_state_block`` [1], ``dec_state_block`` [slots]; inactive slots and
 warm-up name the trash block); the chunk whose ``pf_qstart`` is 0 reads
 the block as zeros inside the program, so a block goes from one sequence
@@ -57,7 +57,13 @@ one more an image shape where the lane declares an encoder):
                   size), scatter-write + read against the page tables,
                   greedy argmax out.
 
-Every feed shape is static, so after the first prefill chunk and the
+Each of the two takes ONE feed, an int32 buffer of a static length that
+holds every scheduling table of the run (tokens, positions, page tables,
+write pages, offsets, state blocks, the staged-row index) and is sliced
+apart inside the program: one host transfer a run.  ``serving/lane.py``
+owns the layout (``decode_layout`` / ``prefill_layout``); ``_decode_feed``
+and ``_prefill_feed`` below build the tables and hand them to its
+fillers by role.  So after the first prefill chunk and the
 first decode step NOTHING recompiles (the benchmark's decode cells
 count compiles inside the window), and per-token latency is
 independent of prompt length (prefill cost is paid in the prefill
@@ -529,13 +535,13 @@ class DecodeEngine:
         dec_prog, dec_start = fluid.Program(), fluid.Program()
         with fluid.program_guard(dec_prog, dec_start), \
                 fluid.unique_name.guard():
-            self._dec_feeds, dec_tok, _ = lane.build_decode_step(
+            self._dec_layout, dec_tok, _ = lane.build_decode_step(
                 self.pool_slots, num_pages, page_size, max_pages,
                 pool_dtype=pool_dtype, attn_force=attn_force, **build_kw)
         pf_prog, pf_start = fluid.Program(), fluid.Program()
         with fluid.program_guard(pf_prog, pf_start), \
                 fluid.unique_name.guard():
-            self._pf_feeds, pf_tok, _ = lane.build_prefill_chunk(
+            self._pf_layout, pf_tok, _ = lane.build_prefill_chunk(
                 prefill_chunk, num_pages, page_size, max_pages,
                 pool_dtype=pool_dtype, attn_force=attn_force, **chunk_kw,
                 **build_kw)
@@ -1366,10 +1372,10 @@ class DecodeEngine:
 
     def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid,
                       row_idx=None):
-        """One chunk's feed (``lane.prefill_feed`` names it): a page
-        table and the chunk's write pages a cache kind; for a lane with
-        an image encoder also the staged row a position (-1 throughout
-        for a chunk of tokens)."""
+        """One chunk's feed (``lane.prefill_feed`` packs it into the one
+        buffer): a page table and the chunk's write pages a cache kind;
+        for a lane with an image encoder also the staged row a position
+        (-1 throughout for a chunk of tokens)."""
         c = self.prefill_chunk
         tok = np.zeros((1, c), np.int64)
         tok[0, :len(tokens)] = tokens
@@ -1478,8 +1484,8 @@ class DecodeEngine:
                              if self._slots[i] is req])
 
     def _decode_feed(self, active):
-        """One step's feed (``lane.decode_feed`` names it): a page table
-        and the slots' write pages a cache kind."""
+        """One step's feed (``lane.decode_feed`` packs it into the one
+        buffer): a page table and the slots' write pages a cache kind."""
         ps, pgs = self.pool_slots, self.pool.page_size
         tok = np.zeros((ps, 1), np.int64)
         pos = np.zeros((ps, 1), np.int64)

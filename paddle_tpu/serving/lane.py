@@ -19,7 +19,7 @@ A model declares
   sequence's window are given back while the request lives).  The pool
   keeps ONE page list a kind a sequence, so every layer of a kind reads
   through the same page table; a model that declares nothing has the one
-  kind ``full`` and the feeds it always had;
+  kind ``full`` and the feed pieces it always had;
 - optionally the **state a sequence owns** in some layers
   (``seq_state``, ``state_layers``): named tensors of any shape and dtype
   (``SeqState``) that every token of the sequence overwrites, not rows a
@@ -28,8 +28,8 @@ A model declares
   ``[blocks, *shape]`` for each of ``state_layers`` and gives a sequence
   exactly ONE block, whatever its length, from the time it is opened
   until it ends or is evicted (the cache kind ``state``, beside the page
-  kinds).  The programs find a sequence's block by its index, one feed
-  more in each executable (``dec_state_block`` [slots],
+  kinds).  The programs find a sequence's block by its index, one piece
+  more in each executable's feed (``dec_state_block`` [slots],
   ``pf_state_block`` [1]; inactive slots and warm-up name the trash
   block 0), and read it as zeros in a sequence's first chunk
   (``pf_qstart == 0``): nothing clears a block on the host, and an
@@ -50,8 +50,9 @@ A model declares
   the image's rows.  The rows never leave the device: an encoder run
   writes them into the engine's **row staging** var (``ROW_STAGING``, one
   for the engine, as large as the largest declared image and one chunk),
-  the chunk reads them from there by an index a position (the lane's one
-  feed more), and a place is written over once the chunk that held its
+  the chunk reads them from there by an index a position (one piece more
+  in the chunk's feed), and a place is written over once the chunk that
+  held its
   position has run.  An eviction replays from token 0 and encodes again.
   A lane that declares no encoder builds, feeds and compiles exactly
   what it did before lanes could.
@@ -64,10 +65,8 @@ What a model file holds, what the lane builds.  A model file holds its
 config class with ``decode_lane()``, its attention (linear-attention,
 tower) block, its feed-forward block, its ``_decoder(frame, cfg)`` and
 its head (``models/decode_blocks.py`` has the parts the decoders share).
-Everything that is the ENGINE's is here: the names, shapes and order of
-the feeds (``decode_feed`` / ``prefill_feed`` fill what
-``build_decode_step`` / ``build_prefill_chunk`` declare, and no other
-module spells a feed's name), the pool, state and staging vars, the page
+Everything that is the ENGINE's is here: the feed contract (below), the
+pool, state and staging vars, the page
 writers of each cache kind, ``q_start``, ``row_valid``, the chunk's
 last-valid-row gather, and the whole-sequence form
 (``build_whole_sequence``: identity page table, caches and state that
@@ -76,12 +75,37 @@ a few lines over.  A lane of one cache kind and a lane of several go
 through the same code: one kind is the dict of one.  A new serve
 configuration touches its model file, its kernel
 (``kernels/primitives``) and its declaration, and nothing here.
+
+The feed contract: ONE host transfer a program.  A decode step and a
+prefill chunk each declare one feed, ``dec_feed`` / ``pf_feed``: int32,
+1-D, of a length static in ``pool_slots``, ``max_pages``, the chunk
+length, the lane's cache kinds, ``seq_state`` and the encoder's index.
+Every scheduling table the scheduler builds afresh each turn (token ids,
+positions, a page table and the write pages a cache kind, offsets, state
+blocks, the staged-row index: all small integers) is a PIECE of it.
+The LAYOUT (``decode_layout`` / ``prefill_layout``: piece -> offset,
+shape, dtype) is the contract: the builder declares the one feed and
+slices, shapes and casts it apart into the variables ``decoder(frame)``
+and ``head`` are handed (``_declare_feed``), the filler (``decode_feed`` /
+``prefill_feed``) writes each piece into its slice of one ``numpy``
+buffer, and both are handed the same cached layout object, derived from
+the arguments the builder receives, so the two cannot drift.  A host
+array that rides a jitted call costs 0.13-0.16 ms of the call (PERF.md
+§6, PR 34), so five to eight feeds a program were 0.5-0.9 ms of every
+run that one buffer does not pay.  No other module spells a feed's or a
+piece's name; a test or a reader asks the layout
+(``FeedLayout.unpack``).  The image encoder's own program (patches:
+float, megabytes) and the whole-sequence form keep their feeds by name.
 """
 
 from __future__ import annotations
 
 import collections
 import functools
+import math
+import types
+
+import numpy as np
 
 __all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
            "state_var_names", "declare_state_vars",
@@ -91,7 +115,8 @@ __all__ = ["CacheRow", "SeqState", "STATE", "STATE_FEEDS",
            "FULL", "kind_name", "kind_feed", "kinds_of",
            "window_pages_per_seq", "rows_of_layers",
            "kv_rows", "lane_padded", "pool_var_names", "declare_pool_vars",
-           "Frame", "scaffold", "decode_feed", "prefill_feed",
+           "Frame", "scaffold", "Piece", "FeedLayout", "decode_layout",
+           "prefill_layout", "decode_feed", "prefill_feed",
            "build_decode_step", "build_prefill_chunk",
            "build_whole_sequence"]
 
@@ -119,8 +144,8 @@ ROW_STAGING = "@IMGROWS@"
 FULL = "full"
 # the cache kind of per-sequence state: one block a sequence
 STATE = "state"
-# the feed that names each slot's state block (decode step) and the
-# prefilling sequence's (prefill chunk)
+# the piece of the packed feed that names each slot's state block (decode
+# step) and the prefilling sequence's (prefill chunk)
 STATE_FEEDS = {"decode": "dec_state_block", "prefill": "pf_state_block"}
 
 
@@ -138,9 +163,9 @@ def kinds_of(layer_windows):
 
 
 def kind_feed(feed, kind):
-    """The feed that carries ``feed`` (a page table, a write page) for
-    cache kind ``kind``: the name itself for ``full``, as every one-kind
-    lane feeds it, ``<feed>@<kind>`` for a window kind."""
+    """The piece of the packed feed that carries ``feed`` (a page table,
+    a write page) for cache kind ``kind``: the name itself for ``full``,
+    ``<feed>@<kind>`` for a window kind."""
     return feed if kind == FULL else f"{feed}@{kind}"
 
 
@@ -283,10 +308,10 @@ class ImageEncoder:
     shape, of no more rows than the largest of these, compiles when its
     first image arrives.
     ``placeholder_id``: the prompt token whose positions image rows
-    fill, in order.  ``index_feed``: the prefill chunk's feed [1, C]
-    int32 that names, a position, the staged row standing there, or -1
-    for a token (the chunk builder is handed ``image_rows=`` the staging
-    var's rows and declares it)."""
+    fill, in order.  ``index_feed``: the piece [1, C] int32 of the
+    prefill chunk's feed that names, a position, the staged row standing
+    there, or -1 for a token (the chunk builder is handed ``image_rows=``
+    the staging var's rows and lays the piece out)."""
 
     def __init__(self, *, build, prepare, shapes, rows_of, row_width,
                  placeholder_id, index_feed="pf_row_idx",
@@ -318,12 +343,12 @@ class DecodeLane:
     ``seq_state``: ``[SeqState]`` the state a sequence owns in each of
     ``state_layers`` (the model's own layer numbers); the builders are
     then handed ``state_blocks=`` (the blocks of every state tensor,
-    trash included) and take the state-block feed (``STATE_FEEDS``).
+    trash included) and take the state-block piece (``STATE_FEEDS``).
     ``build_decode_step(pool_slots, num_pages, page_size, max_pages,
     pool_dtype=, attn_force=)`` and ``build_prefill_chunk(chunk_len,
     num_pages, page_size, max_pages, pool_dtype=, attn_force=)`` build
-    into the default main program and return ``(feed_names, next_tok,
-    logprobs)``: this module's builders of those names bound to the
+    into the default main program and return ``(the feed's layout,
+    next_tok, logprobs)``: this module's builders of those names bound to the
     model's decoder and head, which is what ``scaffold`` returns a
     declaration with (and with ``build_whole_sequence``, None where the
     builders are a model's own).
@@ -334,8 +359,8 @@ class DecodeLane:
     every layer is ``full``.  Declared, the pool keeps one page list a
     kind a sequence and sizes each kind's tensors by that kind's worst
     case; the builders are handed ``num_pages`` as ``{kind: pages}`` and
-    take one page table and one set of write pages a kind
-    (``kind_feed``: ``dec_page_table`` for ``full``,
+    lay out one page table and one set of write pages a kind in their
+    feed (``kind_feed``: ``dec_page_table`` for ``full``,
     ``dec_page_table@window<W>`` for a window kind).
     ``device_counters``: ``[DeviceCounter]`` the programs keep on the
     device: persistable int32 vectors they add to in place and no step
@@ -383,55 +408,178 @@ class DecodeLane:
 
 
 # ---------------------------------------------------------------------------
-# The feed contract and the programs' frame: what the engine feeds, by
-# name, shape and order, and the part of the lane's programs that is the
-# same for every model.  No other module spells a feed's name.
+# The feed contract and the programs' frame: what the engine feeds each
+# served program (ONE packed int32 buffer, and where each piece lies in
+# it), and the part of the lane's programs that is the same for every
+# model.  No other module spells a feed's or a piece's name.
 # ---------------------------------------------------------------------------
 
+_DEC_FEED, _PF_FEED = "dec_feed", "pf_feed"
 _DEC_TOK, _DEC_POS, _DEC_WRITE_OFF = "dec_tok", "dec_pos", "dec_write_off"
 _DEC_TABLE, _DEC_WRITE_PAGE = "dec_page_table", "dec_write_page"
 _PF_TOK, _PF_POS, _PF_QSTART = "pf_tok", "pf_pos", "pf_qstart"
 _PF_TABLE, _PF_WRITE_PAGES = "pf_page_table", "pf_write_pages"
 _PF_LAST_IDX = "pf_last_idx"
 
+_INT32 = np.iinfo(np.int32)
+
+# one piece of a packed feed: ``shape`` values from ``offset`` on, which
+# the program reads as ``dtype``
+Piece = collections.namedtuple("Piece", ("offset", "shape", "dtype"))
+
+
+class FeedLayout(collections.namedtuple("FeedLayout",
+                                        ("feed", "pieces", "size"))):
+    """A served program's one feed: ``feed`` is its name, int32
+    [``size``]; ``pieces`` {name: ``Piece``} says where each scheduling
+    table lies in it, in the order they are packed, and the shape and
+    dtype of the variable the program slices it into.  The builder
+    declares from it (``_declare_feed``), the filler packs by it (``pack``),
+    and both take it from the same cached call (``decode_layout`` /
+    ``prefill_layout``), so the two cannot drift."""
+    __slots__ = ()
+
+    def pack(self, values):
+        """``values`` {piece: integers of the piece's shape} as the
+        one-entry feed.  Every value must fit int32 (a token id, a
+        position, a page or block index, an offset or -1 all do): one
+        that does not raises with the piece's name."""
+        buf = np.empty(self.size, np.int32)
+        for name, (offset, shape, _) in self.pieces.items():
+            val = np.asarray(values[name])
+            if val.shape != shape:
+                raise ValueError(
+                    f"feed piece {name!r}: shape {val.shape}, the program "
+                    f"was built for {shape}")
+            if val.dtype != np.int32 and val.size and not (
+                    _INT32.min <= val.min() and val.max() <= _INT32.max):
+                raise OverflowError(
+                    f"feed piece {name!r} holds a value past int32 "
+                    f"(min {val.min()}, max {val.max()}): the packed feed "
+                    f"{self.feed!r} carries int32")
+            buf[offset:offset + val.size] = val.reshape(-1)
+        return {self.feed: buf}
+
+    def unpack(self, feed):
+        """{piece: its values} read back from a packed ``feed`` (what
+        ``pack`` returned), each in the shape and dtype of the program's
+        variable: what a test or a reader asks in place of a feed's
+        name."""
+        buf = feed[self.feed]
+        return {name: buf[offset:offset + math.prod(shape)]
+                .reshape(shape).astype(dtype)
+                for name, (offset, shape, dtype) in self.pieces.items()}
+
+
+def _layout(feed, pieces):
+    """``pieces`` [(name, shape, dtype)] laid end to end."""
+    laid, offset = {}, 0
+    for name, shape, dtype in pieces:
+        shape = tuple(int(n) for n in shape)
+        laid[name] = Piece(offset, shape, dtype)
+        offset += math.prod(shape)
+    # read-only: the cache hands every caller the same object
+    return FeedLayout(feed, types.MappingProxyType(laid), offset)
+
+
+@functools.lru_cache(maxsize=None)
+def decode_layout(kinds, pool_slots, max_pages, state):
+    """The layout of a decode step's feed ``dec_feed``: ``dec_tok`` /
+    ``dec_pos`` [slots, 1] int64; per cache kind of ``kinds`` (a tuple,
+    in the pool's order) ``dec_page_table`` [slots, max_pages] int32 and
+    ``dec_write_page`` [slots] int32 (``kind_feed``); ``dec_write_off``
+    [slots] int32; with ``state`` ``dec_state_block`` [slots] int32.
+    Cached: the builder and the filler are handed the same object."""
+    ps = int(pool_slots)
+    pieces = [(_DEC_TOK, (ps, 1), "int64"), (_DEC_POS, (ps, 1), "int64")]
+    for kind in kinds:
+        pieces += [(kind_feed(_DEC_TABLE, kind), (ps, max_pages), "int32"),
+                   (kind_feed(_DEC_WRITE_PAGE, kind), (ps,), "int32")]
+    pieces.append((_DEC_WRITE_OFF, (ps,), "int32"))
+    if state:
+        pieces.append((STATE_FEEDS["decode"], (ps,), "int32"))
+    return _layout(_DEC_FEED, pieces)
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_layout(kinds, chunk_len, chunk_pages, max_pages, state,
+                   index_feed=None):
+    """The layout of a prefill chunk's feed ``pf_feed``: ``pf_tok`` /
+    ``pf_pos`` [1, C] int64; per cache kind ``pf_page_table``
+    [1, max_pages] int32 and ``pf_write_pages`` [``chunk_pages``] int32;
+    ``pf_qstart`` [1] int32; ``pf_last_idx`` [1] int64; with ``state``
+    ``pf_state_block`` [1] int32; for a lane with an image encoder its
+    ``index_feed`` [1, C] int32.  Cached, as ``decode_layout``."""
+    c = int(chunk_len)
+    pieces = [(_PF_TOK, (1, c), "int64"), (_PF_POS, (1, c), "int64")]
+    for kind in kinds:
+        pieces += [(kind_feed(_PF_TABLE, kind), (1, max_pages), "int32"),
+                   (kind_feed(_PF_WRITE_PAGES, kind), (chunk_pages,),
+                    "int32")]
+    pieces += [(_PF_QSTART, (1,), "int32"), (_PF_LAST_IDX, (1,), "int64")]
+    if state:
+        pieces.append((STATE_FEEDS["prefill"], (1,), "int32"))
+    if index_feed is not None:
+        pieces.append((index_feed, (1, c), "int32"))
+    return _layout(_PF_FEED, pieces)
+
 
 def decode_feed(tok, pos, tables, write_page, write_off, state_block=None):
-    """One decode step's feed, in the order ``build_decode_step``
-    declares it: ``tok`` / ``pos`` [slots, 1] int64; per cache kind, in
-    the pool's order, ``tables`` {kind: [slots, max_pages] int32} and
-    ``write_page`` {kind: [slots] int32}; ``write_off`` [slots] int32;
-    for a lane with per-sequence state ``state_block`` [slots] int32."""
-    feed = {_DEC_TOK: tok, _DEC_POS: pos}
+    """One decode step's feed: the one entry ``dec_feed``, every piece
+    written into its slice of one int32 buffer as ``decode_layout``
+    lays them out.  ``tok`` / ``pos`` [slots, 1]; per cache kind, in the
+    pool's order, ``tables`` {kind: [slots, max_pages]} and
+    ``write_page`` {kind: [slots]}; ``write_off`` [slots]; for a lane
+    with per-sequence state ``state_block`` [slots]."""
+    layout = decode_layout(tuple(tables), *next(iter(tables.values())).shape,
+                           state_block is not None)
+    values = {_DEC_TOK: tok, _DEC_POS: pos, _DEC_WRITE_OFF: write_off,
+              STATE_FEEDS["decode"]: state_block}
     for kind, table in tables.items():
-        feed[kind_feed(_DEC_TABLE, kind)] = table
-        feed[kind_feed(_DEC_WRITE_PAGE, kind)] = write_page[kind]
-    feed[_DEC_WRITE_OFF] = write_off
-    if state_block is not None:
-        feed[STATE_FEEDS["decode"]] = state_block
-    return feed
+        values[kind_feed(_DEC_TABLE, kind)] = table
+        values[kind_feed(_DEC_WRITE_PAGE, kind)] = write_page[kind]
+    return layout.pack(values)
 
 
 def prefill_feed(tok, pos, tables, write_pages, q_start, last_idx,
                  state_block=None, row_idx=None):
-    """One prefill chunk's feed, in the order ``build_prefill_chunk``
-    declares it: ``tok`` / ``pos`` [1, C] int64; per cache kind
-    ``tables`` {kind: [1, max_pages] int32} and ``write_pages`` {kind:
-    [C / page_size] int32}; ``q_start`` [1] int32 (tokens already in the
-    pool); ``last_idx`` [1] int64 (the chunk's last valid row); for a
-    lane with per-sequence state ``state_block`` [1] int32; for a lane
-    with an image encoder ``row_idx`` = (the encoder's index feed,
-    [1, C] int32)."""
-    feed = {_PF_TOK: tok, _PF_POS: pos}
+    """One prefill chunk's feed: the one entry ``pf_feed``, packed as
+    ``prefill_layout`` lays it out.  ``tok`` / ``pos`` [1, C]; per cache
+    kind ``tables`` {kind: [1, max_pages]} and ``write_pages`` {kind:
+    [C / page_size]}; ``q_start`` [1] (tokens already in the pool);
+    ``last_idx`` [1] (the chunk's last valid row); for a lane with
+    per-sequence state ``state_block`` [1]; for a lane with an image
+    encoder ``row_idx`` = (the encoder's index feed, [1, C])."""
+    kind = next(iter(tables))
+    layout = prefill_layout(
+        tuple(tables), np.shape(tok)[1], len(write_pages[kind]),
+        tables[kind].shape[1], state_block is not None,
+        None if row_idx is None else row_idx[0])
+    values = {_PF_TOK: tok, _PF_POS: pos, _PF_QSTART: q_start,
+              _PF_LAST_IDX: last_idx, STATE_FEEDS["prefill"]: state_block}
     for kind, table in tables.items():
-        feed[kind_feed(_PF_TABLE, kind)] = table
-        feed[kind_feed(_PF_WRITE_PAGES, kind)] = write_pages[kind]
-    feed[_PF_QSTART] = q_start
-    feed[_PF_LAST_IDX] = last_idx
-    if state_block is not None:
-        feed[STATE_FEEDS["prefill"]] = state_block
+        values[kind_feed(_PF_TABLE, kind)] = table
+        values[kind_feed(_PF_WRITE_PAGES, kind)] = write_pages[kind]
     if row_idx is not None:
-        feed[row_idx[0]] = row_idx[1]
-    return feed
+        values[row_idx[0]] = row_idx[1]
+    return layout.pack(values)
+
+
+def _declare_feed(layout):
+    """The program's side of a packed feed: declares ``layout.feed`` and
+    returns {piece: the variable sliced, shaped and cast out of it}."""
+    from paddle_tpu import fluid
+    from paddle_tpu.fluid import layers as L
+
+    packed = fluid.data(layout.feed, [layout.size], False, dtype="int32")
+    out = {}
+    for name, (offset, shape, dtype) in layout.pieces.items():
+        var = L.slice(packed, axes=[0], starts=[offset],
+                      ends=[offset + math.prod(shape)])
+        if len(shape) != 1:
+            var = L.reshape(var, shape=list(shape))
+        out[name] = var if dtype == "int32" else L.cast(var, dtype)
+    return out
 
 
 class Frame(collections.namedtuple("Frame", (
@@ -491,6 +639,12 @@ def _kinds(decl):
     return [kind_name(w) for w in kinds_of(decl.layer_windows or [None])]
 
 
+def _by_kind(decl, fed, piece):
+    """{kind: the lane's ``piece`` of that cache kind} out of an unpacked
+    feed."""
+    return {kind: fed[kind_feed(piece, kind)] for kind in _kinds(decl)}
+
+
 def _declare(decl, num_pages, page_size, pool_dtype, state_blocks):
     """(pool vars a cache layer, {layer: state vars} or None)."""
     pools = declare_pool_vars(
@@ -505,33 +659,31 @@ def build_decode_step(decl, decoder, head, pool_slots, num_pages, page_size,
                       max_pages, pool_dtype=None, attn_force=None,
                       state_blocks=None):
     """ONE token-level decode step over the paged caches, the single
-    fixed-shape executable the scheduler dispatches every step (every
-    feed shape is static in ``pool_slots`` / ``max_pages``: no
-    steady-state recompile).  Per slot s: the token ``dec_tok[s]`` at
-    position ``dec_pos[s]`` goes through ``decoder``, which writes each
-    layer's rows at (``dec_write_page[s]``, ``dec_write_off[s]``) and
-    attends the slot's prefix through ``dec_page_table[s]`` (one table
-    and one write page a cache kind, ``kind_feed``; ``num_pages`` is then
-    ``{kind: pages}``), and ``head`` emits the greedy next token.
-    Inactive slots carry page-table zeros (the trash page), position 0
-    and, in a lane with state, the trash block; their outputs are
-    garbage the scheduler ignores.  Returns ``(feed names, next_tok
-    [pool_slots] int64, logprobs [pool_slots, vocab])``."""
-    from paddle_tpu import fluid
+    fixed-shape executable the scheduler dispatches every step.  Its ONE
+    feed is ``dec_feed``, int32 of a length static in ``pool_slots`` /
+    ``max_pages`` / the lane's cache kinds and state (no steady-state
+    recompile, one host transfer a run), which the program slices,
+    shapes and casts apart as ``decode_layout`` lays it out.  Per slot
+    s: the token ``dec_tok[s]`` at position ``dec_pos[s]`` goes through
+    ``decoder``, which writes each layer's rows at (``dec_write_page[s]``,
+    ``dec_write_off[s]``) and attends the slot's prefix through
+    ``dec_page_table[s]`` (one table and one write page a cache kind,
+    ``kind_feed``; ``num_pages`` is then ``{kind: pages}``), and ``head``
+    emits the greedy next token.  Inactive slots carry page-table zeros
+    (the trash page), position 0 and, in a lane with state, the trash
+    block; their outputs are garbage the scheduler ignores.  Returns
+    ``(the feed's layout, next_tok [pool_slots] int64, logprobs
+    [pool_slots, vocab])``."""
     from paddle_tpu.fluid import layers as L
 
     ps = int(pool_slots)
-    tok = fluid.data(_DEC_TOK, [ps, 1], False, dtype="int64")
-    pos = fluid.data(_DEC_POS, [ps, 1], False, dtype="int64")
-    tables, write_page = {}, {}
-    for kind in _kinds(decl):
-        tables[kind] = fluid.data(kind_feed(_DEC_TABLE, kind),
-                                  [ps, int(max_pages)], False, dtype="int32")
-        write_page[kind] = fluid.data(kind_feed(_DEC_WRITE_PAGE, kind), [ps],
-                                      False, dtype="int32")
-    write_off = fluid.data(_DEC_WRITE_OFF, [ps], False, dtype="int32")
-    block = (fluid.data(STATE_FEEDS["decode"], [ps], False, dtype="int32")
-             if decl.seq_state else None)
+    layout = decode_layout(tuple(_kinds(decl)), ps, int(max_pages),
+                           bool(decl.seq_state))
+    fed = _declare_feed(layout)
+    tok, pos, write_off = fed[_DEC_TOK], fed[_DEC_POS], fed[_DEC_WRITE_OFF]
+    tables = _by_kind(decl, fed, _DEC_TABLE)
+    write_page = _by_kind(decl, fed, _DEC_WRITE_PAGE)
+    block = fed.get(STATE_FEEDS["decode"])
     pools, states = _declare(decl, num_pages, page_size, pool_dtype,
                              state_blocks or ps + 2)
     q_start = L.cast(L.reshape(pos, shape=[-1]), "int32")
@@ -542,16 +694,7 @@ def build_decode_step(decl, decoder, head, pool_slots, num_pages, page_size,
         row_valid=next(iter(write_page.values())), last_idx=None,
         states=states, state_block=block, image_rows=None,
         counted_as="decode", attn_force=attn_force))
-    # the names as ``decode_feed`` orders them: the one place that does
-    return (list(decode_feed(tok, pos, tables, write_page, write_off, block)),
-            *head(x))
-
-
-def _chunk_tokens(c):
-    from paddle_tpu import fluid
-
-    return (fluid.data(_PF_TOK, [1, c], False, dtype="int64"),
-            fluid.data(_PF_POS, [1, c], False, dtype="int64"))
+    return (layout, *head(x))
 
 
 def _chunk(decoder, tok, pos, tables, write_pages, q_start, last_idx, pools,
@@ -581,7 +724,9 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
     into whole pool pages (``chunk_len`` is a multiple of ``page_size``)
     and attending what was written before through the page table.
 
-    Feeds (``prefill_feed``): ``pf_tok`` / ``pf_pos`` [1, C] int64
+    Its ONE feed is ``pf_feed``, int32 of a static length, sliced apart
+    inside the program as ``prefill_layout`` lays it out
+    (``prefill_feed`` packs it): ``pf_tok`` / ``pf_pos`` [1, C] int64
     (positions clamped host-side for the padded tail); a cache kind
     ``pf_page_table`` [1, max_pages] int32 and ``pf_write_pages``
     [C / page_size] int32 (the trash page 0 past the valid tail);
@@ -590,12 +735,11 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
     final chunk's next token is consumed); in a lane with state
     ``pf_state_block`` [1] int32, read as zeros where ``pf_qstart`` is 0
     and carried to the next chunk (rows past ``pf_last_idx`` leave it
-    alone); in a lane with an image encoder its index feed [1, C] int32
+    alone); in a lane with an image encoder its index piece [1, C] int32
     (``image_rows``: the rows of the engine's staging var), -1 where the
-    position is a token: a chunk of tokens feeds -1 throughout, one
-    executable.  Returns ``(feed names, next_tok [1] int64, logprobs
-    [1, vocab])``."""
-    from paddle_tpu import fluid
+    position is a token: a chunk of tokens carries -1 throughout, one
+    executable.  Returns ``(the feed's layout, next_tok [1] int64,
+    logprobs [1, vocab])``."""
     from paddle_tpu.fluid import layers as L
 
     c = int(chunk_len)
@@ -603,26 +747,22 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
         raise ValueError(
             f"prefill chunk_len {c} must be a multiple of page_size "
             f"{page_size} (chunks write whole pages)")
-    tok, pos = _chunk_tokens(c)
-    tables, write_pages = {}, {}
-    for kind in _kinds(decl):
-        tables[kind] = fluid.data(kind_feed(_PF_TABLE, kind),
-                                  [1, int(max_pages)], False, dtype="int32")
-        write_pages[kind] = fluid.data(kind_feed(_PF_WRITE_PAGES, kind),
-                                       [c // int(page_size)], False,
-                                       dtype="int32")
-    q_start = fluid.data(_PF_QSTART, [1], False, dtype="int32")
-    last_idx = fluid.data(_PF_LAST_IDX, [1], False, dtype="int64")
-    block = (fluid.data(STATE_FEEDS["prefill"], [1], False, dtype="int32")
-             if decl.seq_state else None)
+    enc = decl.encoder if image_rows is not None else None
+    layout = prefill_layout(
+        tuple(_kinds(decl)), c, c // int(page_size), int(max_pages),
+        bool(decl.seq_state), enc.index_feed if enc is not None else None)
+    fed = _declare_feed(layout)
+    tok, pos = fed[_PF_TOK], fed[_PF_POS]
+    q_start, last_idx = fed[_PF_QSTART], fed[_PF_LAST_IDX]
+    tables = _by_kind(decl, fed, _PF_TABLE)
+    write_pages = _by_kind(decl, fed, _PF_WRITE_PAGES)
+    block = fed.get(STATE_FEEDS["prefill"])
     pools, states = _declare(decl, num_pages, page_size, pool_dtype,
                              state_blocks or 2)
-    staged = row_idx = None
-    if image_rows is not None:
-        enc = decl.encoder
-        row_idx = (enc.index_feed,
-                   fluid.data(enc.index_feed, [1, c], False, dtype="int32"))
-        staged = (declare_row_staging(image_rows, enc.row_width), row_idx[1])
+    staged = None
+    if enc is not None:
+        staged = (declare_row_staging(image_rows, enc.row_width),
+                  fed[enc.index_feed])
     x = _chunk(decoder, tok, pos, tables, write_pages, q_start, last_idx,
                pools, states, block, staged, "prefill", attn_force)
     width = int(x.shape[-1])
@@ -630,8 +770,7 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
     # the decode loop's first token
     h_last = L.reshape(L.gather(L.reshape(x, shape=[-1, width]), last_idx),
                        shape=[-1, 1, width])
-    return (list(prefill_feed(tok, pos, tables, write_pages, q_start,
-                              last_idx, block, row_idx)), *head(h_last))
+    return (layout, *head(h_last))
 
 
 def build_whole_sequence(decl, decoder, head, seq_len, page_size=None,
@@ -641,7 +780,9 @@ def build_whole_sequence(decl, decoder, head, seq_len, page_size=None,
     blocks over caches and state that live and die inside the program:
     every cache kind under the identity page table (nothing is given
     back), state block 1 of 2, read as zeros.  Books no device counter
-    and takes no image."""
+    and takes no image.  No served program: its two feeds stay two, by
+    name (the references feed it so)."""
+    from paddle_tpu import fluid
     from paddle_tpu.fluid import layers as L
 
     c = int(seq_len)
@@ -649,7 +790,8 @@ def build_whole_sequence(decl, decoder, head, seq_len, page_size=None,
     if c % page:
         raise ValueError(f"seq_len {c} must be a multiple of page {page}")
     n = c // page
-    tok, pos = _chunk_tokens(c)
+    tok = fluid.data(_PF_TOK, [1, c], False, dtype="int64")
+    pos = fluid.data(_PF_POS, [1, c], False, dtype="int64")
     page_table = L.reshape(L.cast(L.range(1, n + 1, 1, "int64"), "int32"),
                            shape=[1, n])
     q_start = L.fill_constant(shape=[1], value=0, dtype="int32")

@@ -271,6 +271,7 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
     per-step logprob row within a tight bound and agree on every greedy
     argmax — quantization happens once per append, so the error does
     not compound across steps."""
+    from paddle_tpu.serving import lane
     from paddle_tpu.serving.kv_pool import KVPool
     from paddle_tpu.serving.lane import kv_rows
 
@@ -305,14 +306,12 @@ def check_int8_kv_logprob_drift(cfg, scope, prompts, ref_ids):
             main, logp_name = progs[dtype]
             rows = []
             for t in range(steps):
-                feed = {
-                    "dec_tok": np.array([[toks[t]]], np.int64),
-                    "dec_pos": np.array([[t]], np.int64),
-                    "dec_page_table": table,
-                    "dec_write_page": np.array(
-                        [table[0, t // page_size]], np.int32),
-                    "dec_write_off": np.array([t % page_size], np.int32),
-                }
+                feed = lane.decode_feed(
+                    np.array([[toks[t]]], np.int64),
+                    np.array([[t]], np.int64), {lane.FULL: table},
+                    {lane.FULL: np.array([table[0, t // page_size]],
+                                         np.int32)},
+                    np.array([t % page_size], np.int32))
                 (lp,) = exe.run(main, feed=feed, fetch_list=[logp_name])
                 rows.append(np.asarray(lp)[0])
             logps[dtype] = np.stack(rows)

@@ -21,6 +21,7 @@ from paddle_tpu import observability as obs
 from paddle_tpu.kernels import primitives as prims
 from paddle_tpu.kernels.primitives import grouped
 from paddle_tpu.models import glm
+from paddle_tpu.serving import lane
 from paddle_tpu.serving.kv_pool import KVPool
 from paddle_tpu.serving.lane import CacheRow, lane_padded
 
@@ -122,27 +123,22 @@ def test_prefill_then_decode_matches_the_reference(weights, force):
             tok = np.zeros((1, CHUNK), np.int64)
             tok[0, :valid] = tokens[pos0:pos0 + valid]
             main, fetch = progs["pf"]
-            out = exe.run(main, feed={
-                "pf_tok": tok,
-                "pf_pos": (pos0 + np.arange(CHUNK, dtype=np.int64))[None],
-                "pf_page_table": table,
-                "pf_write_pages": table[0, pos0 // PAGE:
-                                        (pos0 + CHUNK) // PAGE],
-                "pf_qstart": np.asarray([pos0], np.int32),
-                "pf_last_idx": np.asarray([valid - 1], np.int64)},
-                fetch_list=fetch)
+            out = exe.run(main, feed=lane.prefill_feed(
+                tok, (pos0 + np.arange(CHUNK, dtype=np.int64))[None],
+                {lane.FULL: table},
+                {lane.FULL: table[0, pos0 // PAGE:(pos0 + CHUNK) // PAGE]},
+                np.asarray([pos0], np.int32),
+                np.asarray([valid - 1], np.int64)), fetch_list=fetch)
             got_logp[pos0 + valid - 1] = np.asarray(out[0])[0]
             for t in range(valid):
                 got_sel[pos0 + t] = [np.asarray(m)[0, t] for m in out[1:]]
         for p in range(n_prompt, len(tokens)):
             main, fetch = progs["dec"]
-            out = exe.run(main, feed={
-                "dec_tok": np.asarray([[tokens[p]]], np.int64),
-                "dec_pos": np.asarray([[p]], np.int64),
-                "dec_page_table": table,
-                "dec_write_page": table[0, p // PAGE:p // PAGE + 1],
-                "dec_write_off": np.asarray([p % PAGE], np.int32)},
-                fetch_list=fetch)
+            out = exe.run(main, feed=lane.decode_feed(
+                np.asarray([[tokens[p]]], np.int64),
+                np.asarray([[p]], np.int64), {lane.FULL: table},
+                {lane.FULL: table[0, p // PAGE:p // PAGE + 1]},
+                np.asarray([p % PAGE], np.int32)), fetch_list=fetch)
             got_logp[p] = np.asarray(out[0])[0]
             got_sel[p] = [np.asarray(m)[0, 0] for m in out[1:]]
     rows = sorted(got_logp)

@@ -393,7 +393,7 @@ def test_more_than_sixteen_slots_give_every_block_and_page_back(weights):
         eng.start()
         outs = [r.future.result(timeout=600) for r in reqs]
         stats = eng.stats()
-        feed = eng._decode_feed([])
+        feed = eng._dec_layout.unpack(eng._decode_feed([]))
     finally:
         eng.close()
     assert [len(o) for o in outs] == new

@@ -1,36 +1,34 @@
-"""A model with one cache kind builds the programs and feeds the names it
-did before the pool had kinds (PR 31): the decode lane's two executables
-of `models/gpt.py` (float32 and int8 pools) and `models/glm.py` lower to
-the HLO they lowered at the parent commit, compared BY TEXT — both in the
-XLA form of their attention and with the Pallas kernels interpreted
-(`attn_force="pallas"`), where the text holds the paged kernel's own
-body: plain multi-head attention over the whole context traces what it
-traced.
+"""The decode lane's executables of all seven models lower to the HLO
+they lowered, compared BY TEXT: the two served programs of
+`models/gpt.py` (float32 and int8 pools), `models/glm.py`,
+`models/trinity.py`, `models/kimi_vl.py` (and its image encoder),
+`models/olmo_hybrid.py`, `models/mimo.py` and `models/kimi_linear.py`,
+both in the XLA form of their attention and with the Pallas kernels
+interpreted (`attn_force="pallas"`), where the text holds the kernels'
+own bodies.  A PR that means to change no executable proves it here; a
+PR that changes one re-makes the digests it moved and says why.
 
-The digests below are of `lowered.as_text()` at commit 67a584c (PR 30's
-tree), made by this file's `digests()` there, but for `glm.pallas.*`,
-which PR 32 and PR 37 moved by design: their text holds the interpreted
-grouped product, whose grid ends at the live visits (a traced extent,
-PR 32), and the interpreted `sparse_mla_attention`, which makes one
-score / softmax / value update a grid step over all the step's pages
-(PR 37); the ten others are the proof that nothing else moved.  The `trinity.*` four
-are of commit 3ff1d2d (PR 32's tree), made before PR 33 gave a lane its
-optional encoder: a lane that declares none builds what it built; but
-for `trinity.pallas.prefill`, which PR 43 moved by design: its text holds
-the interpreted grouped chunk body, whose query tile and keys a step now
-come from the shapes together and whose step axis ends at the last step
-the chunk reaches (`trinity.pallas.decode` stands: the decode row did
-not move).  The `kimi_vl.*` six (its third executable is the image
-encoder of its one declared shape), the `olmo_hybrid.*` four and the
-`mimo.*` four are of commit e98f100 (PR 43's tree), made before PR 44
-moved the lanes' program scaffold out of the model files into
-`serving/lane.py`: all thirty are the proof that the move changed no
-executable.  PR 46 moved kimi_vl.py's latent attention and expert
-layer to `models/decode_blocks.py` under public names (`kimi_vl.*`
-stand) and widened the delta-rule ops (`olmo_hybrid.*` stand); the
-`kimi_linear.*` four are PR 46's own lane (KDA layers beside a latent
-layer, 4 of 8 experts held), pinned as it was added.  After a deliberate change
-to what these models compile, run `python tests/test_lane_hlo_unchanged.py`
+The digests' history.  The gpt and glm twelve were made at commit
+67a584c (PR 30's tree), before the pool had kinds (PR 31), the
+`trinity.*` four at 3ff1d2d (PR 32's tree), before a lane could have an
+encoder (PR 33), the `kimi_vl.*` six, `olmo_hybrid.*` four and `mimo.*`
+four at e98f100 (PR 43's tree), before PR 44 moved the lanes' program
+scaffold out of the model files into `serving/lane.py`, and the
+`kimi_linear.*` four with its lane (PR 46); on the way `glm.pallas.*`
+moved by design in PR 32 and PR 37 (the interpreted grouped product's
+traced grid extent; one score / softmax / value update a grid step of
+`sparse_mla_attention`) and `trinity.pallas.prefill` in PR 43 (the
+grouped chunk body's geometry from the shapes).  **PR 47 re-made every
+`*.prefill` and `*.decode` digest, all 32**: a served program takes ONE
+packed int32 feed (`dec_feed` / `pf_feed`) in place of its five to
+eight, so each text's parameter list is shorter and a slice, a reshape
+and, for the three int64 pieces, a convert stand behind the one
+parameter; nothing else of a program was touched (`models/`,
+`kernels/` and `ops/` are the parent's), which the two
+`kimi_vl.*.encoder` digests, whose program keeps its own feeds, hold:
+they are PR 43's still.  What the packed feed carries and how it is laid
+out is held by `tests/test_lane_feed.py`.  After a deliberate change to
+what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
 
@@ -49,40 +47,40 @@ from paddle_tpu.models import (glm, gpt, kimi_linear, kimi_vl, mimo,
                                olmo_hybrid, trinity)
 
 GOLDEN = {
-    "gpt.float32.None.prefill": "e4de139bba244c34c30e3378cea1230b90f392d45e9ccf67a0d043c67755d999",
-    "gpt.float32.None.decode": "8535d14ebf7212bdae543d6edac2532ed15a3d0596735d6f6c4335bc33cda3f8",
-    "gpt.int8.None.prefill": "12933a5e197e4aa7654c2e35518d1f01aecd462ade5f36f12413b0007bcd8c5e",
-    "gpt.int8.None.decode": "0892ca85181c45072ab0d457155baa5a1a467e22d81c67888cac767bf54f69b6",
-    "glm.None.prefill": "a42a84a33db2b0b0b740b907c65e7f89d9ac9e7dc67a53b8abb99e50fa945b22",
-    "glm.None.decode": "246eecbf9f8d71f6aa3c18bdf96f4272caad508300e3604a8d278bc211a19dc5",
-    "gpt.float32.pallas.prefill": "4dc7dc62aab88691a43f8bd2292b9e7eafe0f0c3d1ad1ddd3bf911c7f9887706",
-    "gpt.float32.pallas.decode": "931f17d5b326722c8c62cc0222397248c73d072475d72e437d830427d102cfd8",
-    "gpt.int8.pallas.prefill": "6c60c7216eb5c3bdf1ddf181932dce89b726895a1a580d4007cfcf4796f59142",
-    "gpt.int8.pallas.decode": "5b1236c43d90c101518a07f823c0b878bcd2344b3f6cc813df068e8a3a84ae6c",
-    "glm.pallas.prefill": "d2a0f7d7b30df9bdf2511c7b4f2244290a4776f329925830d162d7a55b64c807",
-    "glm.pallas.decode": "578e2a3223d4468c4e64b81fbf6adce62130beace5189ff432f7ae0a23dbd310",
-    "trinity.None.prefill": "6a0acaf7ee2c08877896d839f89c39e243d3bc10d4e001c30b769978814375b6",
-    "trinity.None.decode": "ce29f09c2160a3c759dab784c8df9ca47db24d0ccc773a70bb2dfbefbf9175fb",
-    "trinity.pallas.prefill": "f4a43df07a6f72f3aee87bad50ed93c0158aeb46c08ee96774dfe571d37cf65e",
-    "trinity.pallas.decode": "901a6a9d530abca4c35470742261f788f866b9a119c3b2f2da2f720e07e03861",
-    "kimi_vl.None.prefill": "3ab5440d0790ab5dbd49c3ce241c53186efc8adcd1a8b45b9db7f450c54a1a9b",
-    "kimi_vl.None.decode": "6bd8524d832d625f9037c5c262c620436d68837a5cb31cebf32aa58982da8042",
+    "gpt.float32.None.prefill": "144ad6592526cc91fdeafd56f5f9c391f27f83e4dcbd53136bd8d030c33febd8",
+    "gpt.float32.None.decode": "c4315b4216b00cc6d20f7075317007a2d62957ddcfe28626552bcc5ec722d567",
+    "gpt.int8.None.prefill": "509cc9052ce6e1af543c3893c4779941f16416cdb11cb594f38bdc1cf72d40ff",
+    "gpt.int8.None.decode": "fcc118898d68aee07cf5dc7c957d99123b04b864bb4e8258bfc50dfd1afafff8",
+    "glm.None.prefill": "16f574ed11ebf0b5ed5d0adce9041960a84bf467699c724bf8f368c02782f550",
+    "glm.None.decode": "e717ec4e4af8f2e2182ea0b7f864d17463ccc61c0b1eaf94be4b432d048bc191",
+    "gpt.float32.pallas.prefill": "e52f48bbfdc9da2fc670d8d6da64d99c75795212bc39c3f413f9b429acb40534",
+    "gpt.float32.pallas.decode": "b8b9b9cf1f332b41c3fdcad335c8e5bc051bdfa6ad4bed3c2b7e2f51e16235bc",
+    "gpt.int8.pallas.prefill": "fbc44627b30fe2a2d02652d157ae817177de62cc4eac0d85d1366d7acb4e62fb",
+    "gpt.int8.pallas.decode": "b6392bbacd705f4f6f30dad2d1d967f229bdfe8187c72d8d92534dc430f91e77",
+    "glm.pallas.prefill": "91b9c1065e4437de77894ff596fdefc95cd090c81bf1b5ec982875bf1cc9d55e",
+    "glm.pallas.decode": "a905524dbcb69117e35504829da2a46c4202f53112c10a10cd9719ac0de36693",
+    "trinity.None.prefill": "9e13a9c7c32e1e20e47e85fe9c2cec15d1de88b4d0e9d45163fa03c718f9d54e",
+    "trinity.None.decode": "cc8d6fd21f5065d7415394414831a96d22e792dcf2fe55080fc45a52cadb4df9",
+    "trinity.pallas.prefill": "7a044d31eaee0f83ef79dc1dfb379e6e385605748a604a62c3f8b1ef36edb97e",
+    "trinity.pallas.decode": "a202c1c5b5ab89aa1e4e25ce4111e91d77ab9141c59b12ed180e77048a8ac203",
+    "kimi_vl.None.prefill": "7dbacc3e480ba6dda9a119f8840ec9bd1c64d9f38d02aefad4e2b8275075c423",
+    "kimi_vl.None.decode": "6994807fd51d4e1eb51814692a08014bc0664dc8cfc17e4c30c5667630b04e42",
     "kimi_vl.None.encoder": "6b20bcce5d7cebedc5d6ae00b8dd48f61001971a6e3ba52c451e2397133014b4",
-    "kimi_vl.pallas.prefill": "b00e232159c740b15f90228784930c5925f0c4c1cff80abe9e5e4c2edf21f589",
-    "kimi_vl.pallas.decode": "e7a624f22e7d2c22b49ac339a5451104796cf8e436ce423d597e811b4f336a03",
+    "kimi_vl.pallas.prefill": "6bd8dc2344287e9651964fc5e8eff8863299982613833ca3cbff26d9a0d25866",
+    "kimi_vl.pallas.decode": "a83e021cbfe4a63173eb7903cf49fcc366808a292c2118ae3bbc38a10fa3b85e",
     "kimi_vl.pallas.encoder": "45645d79bbe5ee2fb023ea669853f92f3f037814b83388a0422c786d8f168d92",
-    "olmo_hybrid.None.prefill": "00de766abf0286e0f1a461d822489f2f3a3f370fb2e08b8e61660724f77a7dca",
-    "olmo_hybrid.None.decode": "e2110da57faecdbf733e9abe407fe5031f6eaff67363957ebe44be4bd6e2c3d2",
-    "olmo_hybrid.pallas.prefill": "6b6526dda50c081d66b7c5d86d551a603aef9a9c005d020d8940da97948e47df",
-    "olmo_hybrid.pallas.decode": "50690e7dce8990a9e437fd7c4c7e322c8e28e5f106a29f16cbc760bb1b6d2f42",
-    "mimo.None.prefill": "3c2ece044c67fda4e36e33b3ae2b728bb1251c295704a6f781a555a290e90e71",
-    "mimo.None.decode": "aa38eae686b3abd65a3cbbcc60fef6e4b5ce9004cefb869562b8a65f09bb7a4a",
-    "mimo.pallas.prefill": "948323c8f183e13c9742b70dd73a21b84939d27192a01347bb70d8776ed30d46",
-    "mimo.pallas.decode": "de58ea6e91449e77cdc780da162099b5ebee6d66b73e5ff677966152b2d1c245",
-    "kimi_linear.None.prefill": "1da69d78a406e226867b65aea9171145f7de7d372153abce0de31929fcefd685",
-    "kimi_linear.None.decode": "5267acacf672a7a037aae8b01cbed62f5b65afd7e4b72088f5b11c28cc74ed0d",
-    "kimi_linear.pallas.prefill": "bbcde65d990121b1f031f763f59a7cbf494b43021dafb5893d71c3ec67525e2b",
-    "kimi_linear.pallas.decode": "c32d82a3bd91aea4551e5c6496dffd1d9a61c90ddce978b94c401203afefe0da"
+    "olmo_hybrid.None.prefill": "d172ad775fbd5add4a370fbe5eebce9803963bb47990391c80c0922e6775df5a",
+    "olmo_hybrid.None.decode": "7b60445602a67a9990cf19862ac8e2e3149c6b87e3f163fc2a5b3f0720cf5bc6",
+    "olmo_hybrid.pallas.prefill": "8e78a12ad239d34a51d0d617a0027342349173be12278ed76b4f72ae0111e81e",
+    "olmo_hybrid.pallas.decode": "22fa1302f64eb2e96c9eb09fae00a75a97f5b50303bb65ecb056f187ca47ad12",
+    "mimo.None.prefill": "9de4dfb3b9da96dee4c85d4c36b004d2ffaa30c8f3ed78996245def5e2efa571",
+    "mimo.None.decode": "fb8d239f74ce651284c231af36d52cb160b0b115192c1d280db1fdae5a6af4f8",
+    "mimo.pallas.prefill": "2a03645e50f971e6e430b981632d186462b4baf3e54b57715845e5a9e654ceff",
+    "mimo.pallas.decode": "c42947622e1a395273405b1da73c4ff6ef662e232571cfdb6193969776326fb4",
+    "kimi_linear.None.prefill": "98b4dba1650993eb8f487c07c2f33828fdfcbf29868f7b6a64cc6f97fd24572f",
+    "kimi_linear.None.decode": "d622ff070875cae920e4471d00899bedd0ccff3eee02ed7547c09bcb0663e881",
+    "kimi_linear.pallas.prefill": "d149dfa1eb811df4f3feeb2e94d479d530df8b415d007a71213b825b44d32b24",
+    "kimi_linear.pallas.decode": "8512086f341e77354003c507d3b9520087affad4073f0affdeb47dbce80fa476"
 }
 
 
@@ -180,24 +178,6 @@ def test_one_kind_lanes_lower_the_hlo_they_lowered(model, force):
     got = {case: hashlib.sha256(text.encode()).hexdigest()
            for case, text in texts(model, force).items()}
     assert got and got == {case: GOLDEN[case] for case in got}
-
-
-def test_one_kind_lanes_feed_the_names_they_fed():
-    cfg = gpt.GPTConfig.tiny()
-    eng = serving.DecodeEngine(
-        cfg, scope=_zero_scope(lambda: gpt.build_gpt_lm(cfg, is_test=True)),
-        place=fluid.CPUPlace(), pool_slots=3, page_size=4, max_len=32,
-        auto_start=False, name="feeds")
-    try:
-        assert eng.pool.kinds == ["full"]
-        assert list(eng._decode_feed([])) == [
-            "dec_tok", "dec_pos", "dec_page_table", "dec_write_page",
-            "dec_write_off"]
-        assert list(eng._prefill_feed(**eng._warm_prefill_args())) == [
-            "pf_tok", "pf_pos", "pf_page_table", "pf_write_pages",
-            "pf_qstart", "pf_last_idx"]
-    finally:
-        eng.close()
 
 
 def test_the_feed_contract_has_one_owner_and_model_files_no_private_siblings():

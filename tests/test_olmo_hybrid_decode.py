@@ -217,10 +217,11 @@ def test_eviction_and_replay_cover_the_state_kind(weights):
 def test_inactive_slots_write_the_trash_block_only(weights):
     eng, prompts, outs, _ = _generate(weights, prompts=(11,))
     assert max(_served_gaps(weights, prompts, outs)) < 1e-3
-    feed = eng._decode_feed([])
-    assert list(feed["dec_state_block"]) == [TRASH_PAGE] * 3
-    assert eng._prefill_feed(**eng._warm_prefill_args())[
-        "pf_state_block"].tolist() == [TRASH_PAGE]
+    fed = eng._dec_layout.unpack(eng._decode_feed([]))
+    assert list(fed["dec_state_block"]) == [TRASH_PAGE] * 3
+    assert eng._pf_layout.unpack(eng._prefill_feed(
+        **eng._warm_prefill_args()))["pf_state_block"].tolist() == [
+            TRASH_PAGE]
 
 
 def test_a_lane_without_state_feeds_what_it_fed():
@@ -240,9 +241,11 @@ def test_a_lane_without_state_feeds_what_it_fed():
     try:
         assert eng.pool.state_blocks == 0 and eng.pool.seq_state == []
         assert set(eng.pool.kind_stats()) == {"full"}
-        assert "dec_state_block" not in eng._decode_feed([])
-        assert "pf_state_block" not in eng._prefill_feed(
-            **eng._warm_prefill_args())
+        assert "dec_state_block" not in eng._dec_layout.pieces
+        assert "pf_state_block" not in eng._pf_layout.pieces
+        # and its feeds are as long as the pieces it always fed
+        assert eng._decode_feed([])["dec_feed"].shape == (
+            2 + 2 + 2 * eng.pool.max_pages_per_seq + 2 + 2,)
     finally:
         eng.close()
 

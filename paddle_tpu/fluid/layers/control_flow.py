@@ -109,16 +109,18 @@ class ConditionalBlock:
     def __init__(self, inputs, is_scalar_condition=True, name=None):
         self.inputs = inputs if isinstance(inputs, (list, tuple)) else [inputs]
         self.helper = LayerHelper("conditional_block", name=name)
+        self._parent_block = None
 
     @contextlib.contextmanager
     def block(self):
         program = self.helper.main_program
-        parent_block = program.current_block()
+        parent_block = self._parent_block = program.current_block()
         sub_block = program._create_block()
         try:
             yield
         finally:
             program._rollback()
+            self._parent_block = None
         cond = self.inputs[0]
         carries, extras, extras_ng = _analyze_sub_block(
             sub_block, extra_exclude={cond.name})
@@ -129,6 +131,31 @@ class ConditionalBlock:
             outputs={"Out": list(carries)},
             attrs={"sub_block": sub_block.idx, "carry_names": list(carries),
                    "extra_names": extras, "extra_ng_names": extras_ng})
+
+    def output(self, inner):
+        """Inside ``block()``: an outer variable of ``inner``'s shape and
+        dtype that reads ``inner`` where the block ran and zeros where it
+        did not.  Its initialisation goes into the parent block, ahead of
+        the conditional op, so a block can yield a value whose shape only
+        its own ops know."""
+        parent = self._parent_block
+        if parent is None:
+            raise ValueError(
+                "ConditionalBlock.output must be called inside block()")
+        if inner.shape is None or any(d is None or d < 0 for d in inner.shape):
+            raise ValueError(
+                f"ConditionalBlock.output: {inner.name!r} has no static "
+                f"shape ({inner.shape}) to initialise its outer value by")
+        outer = parent.create_var(
+            name=unique_name.generate(inner.name + "@cond_out"),
+            shape=inner.shape, dtype=inner.dtype, stop_gradient=True)
+        parent.append_op(
+            "fill_constant", outputs={"Out": [outer]},
+            attrs={"shape": list(inner.shape), "dtype": inner.dtype,
+                   "value": 0.0})
+        self.helper.append_op("assign", inputs={"X": [inner]},
+                              outputs={"Out": [outer]})
+        return outer
 
 
 class Switch:

@@ -169,6 +169,16 @@ def _m_prefill_chunks():
         "ceil(P/chunk) of these)", labels=("engine",))
 
 
+def _m_prefill_head_runs():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_prefill_head_runs_total",
+        "Prefill chunk executions that ran the vocabulary head (a fresh "
+        "prompt's final chunk; every other chunk skips it)",
+        labels=("engine",))
+
+
 def _m_encoder_runs():
     from paddle_tpu import observability as obs
 
@@ -631,6 +641,7 @@ class DecodeEngine:
             k: _m_prompt_tokens().labels(engine=e, kind=k)
             for k in ("image", "text")}
         self._chunks = _m_prefill_chunks().labels(engine=e)
+        self._head_runs = _m_prefill_head_runs().labels(engine=e)
         self._occupancy = _m_slot_occupancy().labels(engine=e)
         self._pages_gauge = _m_pages_in_use().labels(engine=e)
         self._evict_ctr = _m_evictions().labels(engine=e)
@@ -876,7 +887,8 @@ class DecodeEngine:
         trash = np.zeros(self.prefill_chunk // self.pool.page_size, np.int32)
         return dict(
             tokens=[0], pos0=0, seq_id=None,
-            write_pages={k: trash for k in self.pool.kinds}, valid=1)
+            write_pages={k: trash for k in self.pool.kinds}, valid=1,
+            final=True)
 
     def warmup(self):
         """Compile (or AOT-load) every executable outside the request
@@ -1192,15 +1204,18 @@ class DecodeEngine:
             row_idx = self._stage_image_rows(req, ctx_len, valid)
             if row_idx is None:
                 return  # the chunk needs one more image: the next turn's
+        # the one chunk whose next token is read: a fresh prompt's last
+        # (a resumed request replays tokens it already has)
+        seeds = ctx_len + valid == total and not req.generated
         next_tok = self._run_prefill_feed(
             tokens=tokens[ctx_len:ctx_len + valid], pos0=ctx_len,
             seq_id=req.seq_id, write_pages=write_pages, valid=valid,
-            row_idx=row_idx)
+            final=seeds, row_idx=row_idx)
         req.prefilled = ctx_len + valid
         self._release_below_window("prefill.pages", [(req, req.prefilled)])
         with _profiling.span("emit", "decode"):
             if req.prefilled == total:
-                if not req.generated:
+                if seeds:
                     # fresh prompt: the prefill's argmax seeds the
                     # stream.  First token exists now: TTFT anchor
                     # (resumed requests arrive with a prefix, so theirs
@@ -1371,7 +1386,7 @@ class DecodeEngine:
         return idx
 
     def _prefill_feed(self, tokens, pos0, seq_id, write_pages, valid,
-                      row_idx=None):
+                      final, row_idx=None):
         """One chunk's feed (``lane.prefill_feed`` packs it into the one
         buffer): a page table and the chunk's write pages a cache kind;
         for a lane with an image encoder also the staged row a position
@@ -1394,13 +1409,16 @@ class DecodeEngine:
              for k in kinds},
             {k: write_pages[k].astype(np.int32) for k in kinds},
             np.asarray([pos0], np.int32),
-            np.asarray([max(valid - 1, 0)], np.int64), block, row_idx)
+            np.asarray([max(valid - 1, 0)], np.int64),
+            np.asarray([final], np.int32), block, row_idx)
 
     def _run_prefill_feed(self, tokens, pos0, seq_id, write_pages,
-                          valid, warm=False, row_idx=None):
+                          valid, final, warm=False, row_idx=None):
+        """One chunk through the prefill executable; its next token
+        where ``final`` (the head ran), else 0."""
         with _profiling.span("prefill.feed_build", "decode"):
             feed = self._prefill_feed(tokens, pos0, seq_id, write_pages,
-                                      valid, row_idx)
+                                      valid, final, row_idx)
         with self._exec_lock:
             with _profiling.span("prefill.run", "decode") as run:
                 (out,) = self._exe.run(self._pf_prog, feed=feed,
@@ -1411,6 +1429,8 @@ class DecodeEngine:
             self._turn_part["prefill_run"].inc(run.seconds)
             self._turn_run_s += run.seconds
             self._chunks.inc()
+            if final:
+                self._head_runs.inc()
         return int(np.asarray(out).reshape(-1)[0])
 
     # -- decode -------------------------------------------------------------

@@ -419,7 +419,7 @@ _DEC_TOK, _DEC_POS, _DEC_WRITE_OFF = "dec_tok", "dec_pos", "dec_write_off"
 _DEC_TABLE, _DEC_WRITE_PAGE = "dec_page_table", "dec_write_page"
 _PF_TOK, _PF_POS, _PF_QSTART = "pf_tok", "pf_pos", "pf_qstart"
 _PF_TABLE, _PF_WRITE_PAGES = "pf_page_table", "pf_write_pages"
-_PF_LAST_IDX = "pf_last_idx"
+_PF_LAST_IDX, _PF_FINAL = "pf_last_idx", "pf_final"
 
 _INT32 = np.iinfo(np.int32)
 
@@ -507,16 +507,18 @@ def prefill_layout(kinds, chunk_len, chunk_pages, max_pages, state,
     """The layout of a prefill chunk's feed ``pf_feed``: ``pf_tok`` /
     ``pf_pos`` [1, C] int64; per cache kind ``pf_page_table``
     [1, max_pages] int32 and ``pf_write_pages`` [``chunk_pages``] int32;
-    ``pf_qstart`` [1] int32; ``pf_last_idx`` [1] int64; with ``state``
-    ``pf_state_block`` [1] int32; for a lane with an image encoder its
-    ``index_feed`` [1, C] int32.  Cached, as ``decode_layout``."""
+    ``pf_qstart`` [1] int32; ``pf_last_idx`` [1] int64; ``pf_final`` [1]
+    int32; with ``state`` ``pf_state_block`` [1] int32; for a lane with
+    an image encoder its ``index_feed`` [1, C] int32.  Cached, as
+    ``decode_layout``."""
     c = int(chunk_len)
     pieces = [(_PF_TOK, (1, c), "int64"), (_PF_POS, (1, c), "int64")]
     for kind in kinds:
         pieces += [(kind_feed(_PF_TABLE, kind), (1, max_pages), "int32"),
                    (kind_feed(_PF_WRITE_PAGES, kind), (chunk_pages,),
                     "int32")]
-    pieces += [(_PF_QSTART, (1,), "int32"), (_PF_LAST_IDX, (1,), "int64")]
+    pieces += [(_PF_QSTART, (1,), "int32"), (_PF_LAST_IDX, (1,), "int64"),
+               (_PF_FINAL, (1,), "int32")]
     if state:
         pieces.append((STATE_FEEDS["prefill"], (1,), "int32"))
     if index_feed is not None:
@@ -541,22 +543,25 @@ def decode_feed(tok, pos, tables, write_page, write_off, state_block=None):
     return layout.pack(values)
 
 
-def prefill_feed(tok, pos, tables, write_pages, q_start, last_idx,
+def prefill_feed(tok, pos, tables, write_pages, q_start, last_idx, final,
                  state_block=None, row_idx=None):
     """One prefill chunk's feed: the one entry ``pf_feed``, packed as
     ``prefill_layout`` lays it out.  ``tok`` / ``pos`` [1, C]; per cache
     kind ``tables`` {kind: [1, max_pages]} and ``write_pages`` {kind:
     [C / page_size]}; ``q_start`` [1] (tokens already in the pool);
-    ``last_idx`` [1] (the chunk's last valid row); for a lane with
-    per-sequence state ``state_block`` [1]; for a lane with an image
-    encoder ``row_idx`` = (the encoder's index feed, [1, C])."""
+    ``last_idx`` [1] (the chunk's last valid row); ``final`` [1] (nonzero
+    where the token after that row is read: the chunk then runs the
+    head); for a lane with per-sequence state ``state_block`` [1]; for a
+    lane with an image encoder ``row_idx`` = (the encoder's index feed,
+    [1, C])."""
     kind = next(iter(tables))
     layout = prefill_layout(
         tuple(tables), np.shape(tok)[1], len(write_pages[kind]),
         tables[kind].shape[1], state_block is not None,
         None if row_idx is None else row_idx[0])
     values = {_PF_TOK: tok, _PF_POS: pos, _PF_QSTART: q_start,
-              _PF_LAST_IDX: last_idx, STATE_FEEDS["prefill"]: state_block}
+              _PF_LAST_IDX: last_idx, _PF_FINAL: final,
+              STATE_FEEDS["prefill"]: state_block}
     for kind, table in tables.items():
         values[kind_feed(_PF_TABLE, kind)] = table
         values[kind_feed(_PF_WRITE_PAGES, kind)] = write_pages[kind]
@@ -731,8 +736,12 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
     ``pf_page_table`` [1, max_pages] int32 and ``pf_write_pages``
     [C / page_size] int32 (the trash page 0 past the valid tail);
     ``pf_qstart`` [1] int32, the tokens already in the pool;
-    ``pf_last_idx`` [1] int64, the last VALID row of the chunk (only the
-    final chunk's next token is consumed); in a lane with state
+    ``pf_last_idx`` [1] int64, the last VALID row of the chunk;
+    ``pf_final`` [1] int32, nonzero where the token after that row is
+    consumed (a prompt's final chunk): ``head`` runs under it, in the
+    true branch of ONE conditional of the one executable, so every other
+    chunk reads no head weight and returns token 0 and zeros for the
+    log-probabilities; in a lane with state
     ``pf_state_block`` [1] int32, read as zeros where ``pf_qstart`` is 0
     and carried to the next chunk (rows past ``pf_last_idx`` leave it
     alone); in a lane with an image encoder its index piece [1, C] int32
@@ -766,11 +775,15 @@ def build_prefill_chunk(decl, decoder, head, chunk_len, num_pages, page_size,
     x = _chunk(decoder, tok, pos, tables, write_pages, q_start, last_idx,
                pools, states, block, staged, "prefill", attn_force)
     width = int(x.shape[-1])
-    # an exact copy of the last valid row: the final chunk's output seeds
-    # the decode loop's first token
-    h_last = L.reshape(L.gather(L.reshape(x, shape=[-1, width]), last_idx),
-                       shape=[-1, 1, width])
-    return (layout, *head(h_last))
+    read = L.ConditionalBlock([fed[_PF_FINAL]])
+    with read.block():
+        # an exact copy of the last valid row: the final chunk's output
+        # seeds the decode loop's first token
+        h_last = L.reshape(
+            L.gather(L.reshape(x, shape=[-1, width]), last_idx),
+            shape=[-1, 1, width])
+        next_tok, logp = (read.output(out) for out in head(h_last))
+    return layout, next_tok, logp
 
 
 def build_whole_sequence(decl, decoder, head, seq_len, page_size=None,

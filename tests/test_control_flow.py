@@ -167,6 +167,36 @@ def test_static_rnn_trains():
     assert not np.allclose(w_before, w_after)
 
 
+def test_conditional_block_output_reads_the_block_or_zeros():
+    """`ConditionalBlock.output(inner)`: the block's own value where it
+    ran, zeros where it did not, initialised AHEAD of the
+    conditional op though declared inside the block; a value of unknown
+    shape, and a call outside `block()`, are refused."""
+    main, startup = _fresh()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.data("x", [2, 3], False, dtype="float32")
+        flag = fluid.data("flag", [1], False, dtype="int32")
+        cb = fluid.layers.ConditionalBlock([flag])
+        with cb.block():
+            doubled = cb.output(layers.scale(x, scale=2.0))
+            best = cb.output(layers.argmax(x, axis=-1))
+            ragged = fluid.data("ragged", [-1, 3], False, dtype="float32")
+            with pytest.raises(ValueError, match="static shape"):
+                cb.output(ragged)
+        with pytest.raises(ValueError, match="inside block"):
+            cb.output(x)
+    ops = [op.type for op in main.global_block().ops]
+    assert ops[-3:] == ["fill_constant", "fill_constant", "conditional_block"]
+    assert tuple(doubled.shape) == (2, 3) and tuple(best.shape) == (2,)
+    x_np = np.arange(6, dtype="float32").reshape(2, 3)
+    exe = fluid.Executor(fluid.CPUPlace())
+    for on, want in ((1, (2 * x_np, [2, 2])), (0, (np.zeros((2, 3)), [0, 0]))):
+        got = exe.run(main, feed={"x": x_np, "flag": np.array([on], "int32")},
+                      fetch_list=[doubled.name, best.name], scope=Scope())
+        np.testing.assert_array_equal(np.asarray(got[0]), want[0])
+        np.testing.assert_array_equal(np.asarray(got[1]), want[1])
+
+
 def test_conditional_block_grad():
     """Grad flows through lax.cond into weights used inside the block."""
     main, startup = _fresh()

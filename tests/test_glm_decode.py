@@ -128,7 +128,8 @@ def test_prefill_then_decode_matches_the_reference(weights, force):
                 {lane.FULL: table},
                 {lane.FULL: table[0, pos0 // PAGE:(pos0 + CHUNK) // PAGE]},
                 np.asarray([pos0], np.int32),
-                np.asarray([valid - 1], np.int64)), fetch_list=fetch)
+                np.asarray([valid - 1], np.int64),
+                np.asarray([1], np.int32)), fetch_list=fetch)
             got_logp[pos0 + valid - 1] = np.asarray(out[0])[0]
             for t in range(valid):
                 got_sel[pos0 + t] = [np.asarray(m)[0, t] for m in out[1:]]

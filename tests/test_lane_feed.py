@@ -7,7 +7,10 @@ declaration takes (one cache kind; two; per-sequence state; state beside
 latent rows; an image encoder's index) by a decoder that computes
 nothing, so that the program's own variables can be fetched; and on tiny
 engines, by the executor's counter: one host array a run of either
-served program, nothing compiled after warm-up."""
+served program, nothing compiled after warm-up.  Since PR 48 the chunk's
+feed holds ``pf_final``, the condition the chunk's head runs under: with
+1 the chunk's token is the whole-sequence form's argmax at the chunk's
+last row, with 0 it is 0 and pools and state are written as with 1."""
 
 import numpy as np
 import pytest
@@ -95,13 +98,15 @@ def _arguments(decl, which, rng):
     else:
         q_start = rng.randint(0, 40000, 1).astype(np.int32)
         last = rng.randint(0, CHUNK, 1).astype(np.int64)
+        final = rng.randint(0, 2, 1).astype(np.int32)
         row_idx = None
         if decl.encoder is not None:
             row_idx = (decl.encoder.index_feed,
                        rng.randint(-1, IMAGE_ROWS, (1, CHUNK)).astype(np.int32))
             want[row_idx[0]] = row_idx[1]
-        args = [tok, pos, tables, writes, q_start, last, block, row_idx]
-        want.update(pf_qstart=q_start, pf_last_idx=last)
+        args = [tok, pos, tables, writes, q_start, last, final, block,
+                row_idx]
+        want.update(pf_qstart=q_start, pf_last_idx=last, pf_final=final)
         for k in kinds:
             want[lane.kind_feed("pf_write_pages", k)] = writes[k]
     if block is not None:
@@ -161,8 +166,10 @@ def test_a_served_program_takes_one_packed_feed(form, which):
         piece_of[f"table.{kind}"] = lane.kind_feed(f"{head}_page_table",
                                                    kind)
     handed = {piece_of[k]: v for k, v in handed.items() if v is not None}
+    # the page writers hold the write pieces, the frame's conditional
+    # around the head the chunk's pf_final
     assert set(want) - set(handed) <= {
-        n for n in want if "write" in n}     # the page writers hold those
+        n for n in want if "write" in n or n == "pf_final"}
     fetched = fluid.Executor(fluid.CPUPlace()).run(
         main, feed=feed, fetch_list=[v.name for v in handed.values()],
         scope=fluid.Scope())
@@ -258,5 +265,95 @@ def test_one_host_array_a_run_and_nothing_compiles_after_warmup(model):
         assert _compile_misses() == misses
         assert _staged("host") - host == len(runs)
         assert [len(r.future.result()) for r in reqs] == [5, 5]
+    finally:
+        eng.close()
+
+
+def _snapshot(eng):
+    """{name: value} of every pool and state tensor of the engine."""
+    return {name: np.asarray(eng.scope.get(name)).copy()
+            for names in (*eng.pool.var_names, *eng.pool.state_var_names)
+            for name in names}
+
+
+def _restore(eng, held):
+    import jax.numpy as jnp
+
+    for name, value in held.items():
+        eng.scope.set(name, jnp.asarray(value))
+
+
+@pytest.mark.parametrize("model", ["gpt", "trinity", "olmo_hybrid"])
+def test_the_head_runs_where_pf_final_says_and_nothing_else_moves(model):
+    """A prompt of two chunks (8 + 5 tokens): the engine feeds 0 on the
+    first chunk and 1 on the last.  Either chunk fed 1 returns the
+    whole-sequence form's argmax at its last row; fed 0 it returns 0 and
+    leaves every pool and state tensor bit-equal to the run fed 1."""
+    from paddle_tpu.models import gpt
+
+    if model == "gpt":
+        cfg = gpt.GPTConfig.tiny()
+        builds = [lambda: gpt.build_gpt_lm(cfg, is_test=True)]
+    else:
+        cfg, builds = _later_model(model)
+    rng = np.random.RandomState(48)
+    eng = serving.DecodeEngine(
+        cfg, scope=_zero_scope(*builds, rng=rng), place=fluid.CPUPlace(),
+        pool_slots=3, page_size=4, prefill_chunk=8, max_len=32,
+        auto_start=False, name=f"final-{model}")
+    try:
+        prompt = rng.randint(1, cfg.vocab_size, 13).tolist()
+        whole, start = fluid.Program(), fluid.Program()
+        with fluid.program_guard(whole, start), fluid.unique_name.guard():
+            logp = eng.lane.build_whole_sequence(16, page_size=4)
+        tok = np.zeros((1, 16), np.int64)
+        tok[0, :13] = prompt
+        (logp,) = fluid.Executor(fluid.CPUPlace()).run(
+            whole, feed={"pf_tok": tok,
+                         "pf_pos": np.arange(16, dtype=np.int64)[None]},
+            fetch_list=[logp.name], scope=eng.scope)
+        want = np.asarray(logp).argmax(-1)
+        assert want[7] != 0 and want[12] != 0   # 0 is what a skipped head reads
+
+        eng.warmup()
+        fed, inner = [], eng._prefill_feed
+
+        def recorded(*args, **kw):
+            fed.append(inner(*args, **kw))
+            return fed[-1]
+
+        eng._prefill_feed = recorded
+        toks, run = [], eng._run_prefill_feed
+
+        def ran(**kw):
+            toks.append(run(**kw))
+            return toks[-1]
+
+        eng._run_prefill_feed = ran
+        final = eng._pf_layout.pieces["pf_final"].offset
+        req = eng.submit_request(prompt, 1)
+        for chunk, row in enumerate((7, 12)):
+            before = _snapshot(eng)
+            eng._prefill_one_chunk()
+            (name, packed), = fed[chunk].items()
+            flag = bool(packed[final])
+            assert flag == (chunk == 1)
+            got = {flag: toks[chunk]}
+            after = {flag: _snapshot(eng)}
+            # the same feed over the same pool with the flag turned (the
+            # engine has given window pages back since: its tables moved)
+            _restore(eng, before)
+            turned = packed.copy()
+            turned[final] = not flag
+            (tok,) = eng._exe.run(eng._pf_prog, feed={name: turned},
+                                  fetch_list=[eng._pf_fetch], scope=eng.scope)
+            got[not flag] = int(np.asarray(tok).reshape(-1)[0])
+            after[not flag] = _snapshot(eng)
+            assert got == {True: want[row], False: 0}
+            assert any(np.any(v != before[k]) for k, v in after[True].items())
+            for key, value in after[True].items():
+                np.testing.assert_array_equal(value, after[False][key], key)
+            _restore(eng, after[True])
+        assert req.generated == [want[12]] and len(fed) == 2
     finally:
         eng.close()

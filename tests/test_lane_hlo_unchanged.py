@@ -27,12 +27,22 @@ parameter; nothing else of a program was touched (`models/`,
 `kernels/` and `ops/` are the parent's), which the two
 `kimi_vl.*.encoder` digests, whose program keeps its own feeds, hold:
 they are PR 43's still.  What the packed feed carries and how it is laid
-out is held by `tests/test_lane_feed.py`.  After a deliberate change to
+out is held by `tests/test_lane_feed.py`.  **PR 48 re-made the sixteen
+`*.prefill` digests and no other**: the chunk's feed is one int32
+longer (`pf_final`) and the chunk's head (the gather of the last valid
+row, the final norm, the `[1, D] x [D, V]` product, log-softmax and
+argmax) stands inside the true region of ONE `stablehlo.case` on that
+piece, whose false region returns the token 0 and zeros
+(`test_a_chunks_head_lies_in_the_true_region_only` reads the text for
+it); the sixteen `*.decode` and two `*.encoder` digests are PR 47's and
+PR 43's still, which is the proof that the decode step and the tower
+were left alone.  After a deliberate change to
 what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
 
 import ast
+import functools
 import hashlib
 import json
 import pathlib
@@ -47,39 +57,39 @@ from paddle_tpu.models import (glm, gpt, kimi_linear, kimi_vl, mimo,
                                olmo_hybrid, trinity)
 
 GOLDEN = {
-    "gpt.float32.None.prefill": "144ad6592526cc91fdeafd56f5f9c391f27f83e4dcbd53136bd8d030c33febd8",
+    "gpt.float32.None.prefill": "558a7809cf01862028c6dea6430fa34ac0de8a4a4913b2bfb96bde8855b3e5ec",
     "gpt.float32.None.decode": "c4315b4216b00cc6d20f7075317007a2d62957ddcfe28626552bcc5ec722d567",
-    "gpt.int8.None.prefill": "509cc9052ce6e1af543c3893c4779941f16416cdb11cb594f38bdc1cf72d40ff",
+    "gpt.int8.None.prefill": "0a585d5e4b4a29e58ee09c174fb9c1ebc8bcbeb7e4e6b61db0e32a10e9f31ac5",
     "gpt.int8.None.decode": "fcc118898d68aee07cf5dc7c957d99123b04b864bb4e8258bfc50dfd1afafff8",
-    "glm.None.prefill": "16f574ed11ebf0b5ed5d0adce9041960a84bf467699c724bf8f368c02782f550",
+    "glm.None.prefill": "bbc886e9b81f4423733191e527a77ca30801f2c999c2ef49812a0336ef0b49c6",
     "glm.None.decode": "e717ec4e4af8f2e2182ea0b7f864d17463ccc61c0b1eaf94be4b432d048bc191",
-    "gpt.float32.pallas.prefill": "e52f48bbfdc9da2fc670d8d6da64d99c75795212bc39c3f413f9b429acb40534",
+    "gpt.float32.pallas.prefill": "5d11e38d32806cbb5223686c3253dbf188447c46bffcd5756b8a074b3d0a564d",
     "gpt.float32.pallas.decode": "b8b9b9cf1f332b41c3fdcad335c8e5bc051bdfa6ad4bed3c2b7e2f51e16235bc",
-    "gpt.int8.pallas.prefill": "fbc44627b30fe2a2d02652d157ae817177de62cc4eac0d85d1366d7acb4e62fb",
+    "gpt.int8.pallas.prefill": "33b00be68a19cd1c166d1b5973a26ff282d4654446a3990aa845bdf9b2cc733c",
     "gpt.int8.pallas.decode": "b6392bbacd705f4f6f30dad2d1d967f229bdfe8187c72d8d92534dc430f91e77",
-    "glm.pallas.prefill": "91b9c1065e4437de77894ff596fdefc95cd090c81bf1b5ec982875bf1cc9d55e",
+    "glm.pallas.prefill": "ab5265b36cff15dc4296fd2133af4625ec773080acf0e39a6e13c38ead2b3167",
     "glm.pallas.decode": "a905524dbcb69117e35504829da2a46c4202f53112c10a10cd9719ac0de36693",
-    "trinity.None.prefill": "9e13a9c7c32e1e20e47e85fe9c2cec15d1de88b4d0e9d45163fa03c718f9d54e",
+    "trinity.None.prefill": "d164041e74316e06bb80246019e7c7e43a53c2889a5ff04031388d0e82ae9997",
     "trinity.None.decode": "cc8d6fd21f5065d7415394414831a96d22e792dcf2fe55080fc45a52cadb4df9",
-    "trinity.pallas.prefill": "7a044d31eaee0f83ef79dc1dfb379e6e385605748a604a62c3f8b1ef36edb97e",
+    "trinity.pallas.prefill": "afdff4f1c430b6311ac7068980f65cbdca634a9d57a8af66ffc3ba464676c254",
     "trinity.pallas.decode": "a202c1c5b5ab89aa1e4e25ce4111e91d77ab9141c59b12ed180e77048a8ac203",
-    "kimi_vl.None.prefill": "7dbacc3e480ba6dda9a119f8840ec9bd1c64d9f38d02aefad4e2b8275075c423",
+    "kimi_vl.None.prefill": "0d1f98d236faa636b625381e9845b131251ad1cf764aa156b3ee0081410287f0",
     "kimi_vl.None.decode": "6994807fd51d4e1eb51814692a08014bc0664dc8cfc17e4c30c5667630b04e42",
     "kimi_vl.None.encoder": "6b20bcce5d7cebedc5d6ae00b8dd48f61001971a6e3ba52c451e2397133014b4",
-    "kimi_vl.pallas.prefill": "6bd8dc2344287e9651964fc5e8eff8863299982613833ca3cbff26d9a0d25866",
+    "kimi_vl.pallas.prefill": "5764d7b16274a73b652e8cfbf466c14094387e711c8754ae31f389e1aef5ec58",
     "kimi_vl.pallas.decode": "a83e021cbfe4a63173eb7903cf49fcc366808a292c2118ae3bbc38a10fa3b85e",
     "kimi_vl.pallas.encoder": "45645d79bbe5ee2fb023ea669853f92f3f037814b83388a0422c786d8f168d92",
-    "olmo_hybrid.None.prefill": "d172ad775fbd5add4a370fbe5eebce9803963bb47990391c80c0922e6775df5a",
+    "olmo_hybrid.None.prefill": "1f251e786c696c45f33be955a143ae553cb1d4304a3ab770cd0ae0e52aefd242",
     "olmo_hybrid.None.decode": "7b60445602a67a9990cf19862ac8e2e3149c6b87e3f163fc2a5b3f0720cf5bc6",
-    "olmo_hybrid.pallas.prefill": "8e78a12ad239d34a51d0d617a0027342349173be12278ed76b4f72ae0111e81e",
+    "olmo_hybrid.pallas.prefill": "85840af3796b2e1f3a99eeb08369b2eeaf55db49bb3e9af7cdaf19c64fc7d6d9",
     "olmo_hybrid.pallas.decode": "22fa1302f64eb2e96c9eb09fae00a75a97f5b50303bb65ecb056f187ca47ad12",
-    "mimo.None.prefill": "9de4dfb3b9da96dee4c85d4c36b004d2ffaa30c8f3ed78996245def5e2efa571",
+    "mimo.None.prefill": "fd123cc8f22b9547556249bf9d2b3cb558668f2dda17aa25acfa791e64a8c373",
     "mimo.None.decode": "fb8d239f74ce651284c231af36d52cb160b0b115192c1d280db1fdae5a6af4f8",
-    "mimo.pallas.prefill": "2a03645e50f971e6e430b981632d186462b4baf3e54b57715845e5a9e654ceff",
+    "mimo.pallas.prefill": "bff28455be5e737260e73ba489aeb6ceb3ceb30903c61e8c93c3bc11545cb569",
     "mimo.pallas.decode": "c42947622e1a395273405b1da73c4ff6ef662e232571cfdb6193969776326fb4",
-    "kimi_linear.None.prefill": "98b4dba1650993eb8f487c07c2f33828fdfcbf29868f7b6a64cc6f97fd24572f",
+    "kimi_linear.None.prefill": "349a66b613bdc8973252960687c6ff123f59fde285f3bbddc9fe571a640ad5e0",
     "kimi_linear.None.decode": "d622ff070875cae920e4471d00899bedd0ccff3eee02ed7547c09bcb0663e881",
-    "kimi_linear.pallas.prefill": "d149dfa1eb811df4f3feeb2e94d479d530df8b415d007a71213b825b44d32b24",
+    "kimi_linear.pallas.prefill": "ee332d3c173c5a25ffa2ee57597047d180efbae6ae8630b4f93d190c0a0e0061",
     "kimi_linear.pallas.decode": "8512086f341e77354003c507d3b9520087affad4073f0affdeb47dbce80fa476"
 }
 
@@ -88,10 +98,11 @@ MODELS = ("gpt", "glm", "trinity", "kimi_vl", "olmo_hybrid", "mimo",
           "kimi_linear")
 
 
-def _zero_scope(*builds):
-    """Zeros under every parameter of the programs ``builds`` build (a
-    builder that returns ``(feeds, prepare program)``, the image
-    encoder's, gives that program's too)."""
+def _zero_scope(*builds, rng=None):
+    """Zeros (with ``rng``: normal values of deviation 0.3) under every
+    parameter of the programs ``builds`` build (a builder that returns
+    ``(feeds, prepare program)``, the image encoder's, gives that
+    program's too)."""
     scope = fluid.Scope()
     for build in builds:
         lm, start = fluid.Program(), fluid.Program()
@@ -103,7 +114,9 @@ def _zero_scope(*builds):
                   for p in prog.global_block().all_parameters()):
             dtype = (ml_dtypes.bfloat16 if p.dtype == "bfloat16"
                      else np.dtype(p.dtype))
-            scope.set(p.name, np.zeros(tuple(p.shape), dtype))
+            value = (np.zeros(tuple(p.shape)) if rng is None
+                     else rng.normal(0.0, 0.3, tuple(p.shape)))
+            scope.set(p.name, value.astype(dtype))
     return scope
 
 
@@ -148,6 +161,7 @@ def _later_model(model):
     return cfg, [lambda: mimo.build_mimo_lm(cfg)]
 
 
+@functools.lru_cache(maxsize=None)
 def texts(model, force):
     """{case: HLO text} of one model's executables."""
     force = None if force == "None" else force
@@ -178,6 +192,29 @@ def test_one_kind_lanes_lower_the_hlo_they_lowered(model, force):
     got = {case: hashlib.sha256(text.encode()).hexdigest()
            for case, text in texts(model, force).items()}
     assert got and got == {case: GOLDEN[case] for case in got}
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_a_chunks_head_lies_in_the_true_region_only(model):
+    """The lowered chunk holds ONE `stablehlo.case`; its false region
+    returns what it was handed, its true region holds the program's only
+    product of one row by the vocabulary; the decode step holds no
+    case."""
+    for case, text in texts(model, "None").items():
+        if not case.endswith(".prefill"):
+            assert '"stablehlo.case"' not in text
+            continue
+        before, cased = text.split('"stablehlo.case"')
+        skipped, ran = cased.split("\n    }, {\n", 1)
+        ran, after = ran.split("\n    }) :", 1)
+        assert [ln.split()[0] for ln in skipped.splitlines()[1:]] == [
+            "stablehlo.return"]
+        one_row = re.compile(
+            r"stablehlo\.dot_general .*-> tensor<(?:1x)+(\d+)xf32>$", re.M)
+        assert len(one_row.findall(ran)) == 1
+        assert "@log_softmax" in ran and "@argmax" in ran
+        assert not one_row.findall(before) and not one_row.findall(
+            after.split("func.func", 1)[0])
 
 
 def test_the_feed_contract_has_one_owner_and_model_files_no_private_siblings():

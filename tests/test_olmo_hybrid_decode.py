@@ -214,6 +214,46 @@ def test_eviction_and_replay_cover_the_state_kind(weights):
     assert stats["kv_pool"]["kinds"]["state"]["freed"]["evict"] > 0
 
 
+def _booked(family, engine):
+    """What `family` reads for the engine of that name (several tests'
+    engines share one: a caller takes the difference around its run)."""
+    return obs.snapshot().get(family, {}).get("samples", {}).get(
+        (engine,), 0)
+
+
+_HEAD_FAMILIES = ("pt_decode_prefill_chunks_total",
+                  "pt_decode_prefill_head_runs_total")
+
+
+def test_a_prompt_of_n_chunks_runs_the_head_once(weights):
+    """45 tokens in chunks of 8: six chunk executions, ONE of them (the
+    last) under `pf_final` = 1, and the tokens the same prompt serves
+    through one chunk of 48."""
+    served = {}
+    for chunk, n_chunks in ((8, 6), (48, 1)):
+        name = f"olmo-None-1-{[('prefill_chunk', chunk)]}"
+        before = [_booked(f, name) for f in _HEAD_FAMILIES]
+        eng, _, served[chunk], _ = _generate(
+            weights, n_new=6, prompts=(45,), prefill_chunk=chunk)
+        assert eng.name == name
+        assert [_booked(f, name) - n for f, n in zip(
+            _HEAD_FAMILIES, before)] == [n_chunks, 1]
+    assert served[8] == served[48] and len(served[8][0]) == 6
+
+
+def test_a_replayed_prompt_runs_no_head(weights):
+    """An evicted request replays prompt + served tokens through the
+    chunk program and reads no token of it: as many head runs as
+    prompts, however many replays."""
+    name = f"olmo-None-{len(PROMPTS)}-{[('state_blocks', 3)]}"
+    before = [_booked(f, name) for f in _HEAD_FAMILIES]
+    eng, prompts, _, stats = _generate(weights, state_blocks=3)
+    assert eng.name == name and stats["evictions"] > 0
+    chunks, heads = [_booked(f, name) - n
+                     for f, n in zip(_HEAD_FAMILIES, before)]
+    assert heads == len(prompts) < chunks
+
+
 def test_inactive_slots_write_the_trash_block_only(weights):
     eng, prompts, outs, _ = _generate(weights, prompts=(11,))
     assert max(_served_gaps(weights, prompts, outs)) < 1e-3

@@ -1596,13 +1596,19 @@ def headwise_matmul(x, size, param_attr=None, dtype="float32", name=None):
     return _out_f32(helper, "headwise_matmul", {"X": [x], "W": [w]})
 
 
-def rms_norm(x, epsilon=1e-5, param_attr=None, dtype="float32", name=None):
+def rms_norm(x, epsilon=1e-5, param_attr=None, dtype="float32", name=None,
+             gain_offset=0.0):
+    """x rsqrt(mean x^2 + eps) (``gain_offset`` + scale) over the last
+    dimension; ``gain_offset`` 1 is a zero-centred gain (``1 + w``, the
+    stored ``w`` starting at 0)."""
     helper = LayerHelper("rms_norm", name=name)
-    scale = helper.create_parameter(param_attr, shape=[x.shape[-1]],
-                                    dtype=dtype,
-                                    default_initializer=Constant(1.0))
-    return _out_f32(helper, "rms_norm", {"X": [x], "Scale": [scale]},
-                    {"epsilon": float(epsilon)})
+    scale = helper.create_parameter(
+        param_attr, shape=[x.shape[-1]], dtype=dtype,
+        default_initializer=Constant(1.0 - float(gain_offset)))
+    attrs = {"epsilon": float(epsilon)}
+    if gain_offset:
+        attrs["gain_offset"] = float(gain_offset)
+    return _out_f32(helper, "rms_norm", {"X": [x], "Scale": [scale]}, attrs)
 
 
 def swiglu(gate, up, name=None):
@@ -1783,7 +1789,7 @@ def short_conv(x, kernel, tail, block, q_start=None, last_idx=None,
 
 def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
                epsilon=1e-6, row_valid=None, a_log_attr=None,
-               dt_bias_attr=None, name=None):
+               dt_bias_attr=None, key_heads=None, name=None):
     """The gated delta rule's operands from the convolved projections
     qkv [B, T, 2 H d_k + H d_v] and the gate projections a, b [B, T, H]:
     (q [B, T, H, d_k] L2-normalised and scaled by d_k^-1/2, k
@@ -1791,8 +1797,18 @@ def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
     dt_bias), beta = ``beta_scale`` sigmoid(b)).  With a [B, T, H d_k]
     the decay is one number a key channel: dt_bias is [H d_k] (A_log
     stays [H]) and g [B, T, H, d_k].  ``row_valid`` [T]: rows marked 0
-    get beta = 0 and g = 0."""
+    get beta = 0 and g = 0.  ``key_heads`` (H_k, a divisor of H; H where
+    None): q and k have H_k heads, qkv is [B, T, 2 H_k d_k + H d_v], and
+    value head h reads key head h // (H / H_k) in ``gated_delta_rule``."""
     helper = LayerHelper("gdn_inputs", name=name)
+    hk = int(heads if key_heads is None else key_heads)
+    if int(heads) % hk or int(qkv.shape[-1]) != (
+            2 * hk * int(key_dim) + int(heads) * int(value_dim)):
+        raise ValueError(
+            f"gdn_inputs: {heads} value heads on {hk} key heads of "
+            f"{key_dim} / {value_dim} want whole groups and qkv "
+            f"{2 * hk * int(key_dim) + int(heads) * int(value_dim)} wide, "
+            f"got {qkv.shape[-1]}")
     if int(a.shape[-1]) not in (int(heads), int(heads) * int(key_dim)):
         raise ValueError(
             f"gdn_inputs: the gate projection is {a.shape[-1]} wide, wanted "
@@ -1811,13 +1827,15 @@ def gdn_inputs(qkv, a, b, heads, key_dim, value_dim, beta_scale=1.0,
               "DtBias": [dt_bias]}
     if row_valid is not None:
         inputs["RowValid"] = [row_valid]
+    attrs = {"heads": int(heads), "key_dim": int(key_dim),
+             "value_dim": int(value_dim), "beta_scale": float(beta_scale),
+             "epsilon": float(epsilon)}
+    if hk != int(heads):
+        attrs["key_heads"] = hk
     helper.append_op(
         "gdn_inputs", inputs=inputs,
         outputs=dict(zip(("Q", "K", "V", "G", "Beta"),
-                         ([o] for o in outs))),
-        attrs={"heads": int(heads), "key_dim": int(key_dim),
-               "value_dim": int(value_dim), "beta_scale": float(beta_scale),
-               "epsilon": float(epsilon)})
+                         ([o] for o in outs))), attrs=attrs)
     return outs
 
 
@@ -1826,8 +1844,9 @@ def gated_delta_rule(q, k, v, g, beta, state, block, q_start=None,
     """The gated delta rule over the per-sequence state var ``state``
     [blocks, d_k, H * d_v] (kernels/primitives/gdn.py; kda.py where g
     is [B, T, H, d_k], a decay a key channel), updated in place
-    -> [B, T, H, d_v] float32.  With ``q_start`` [1] the operands are one
-    sequence's chunk [1, C, H, .] and ``block`` [1] its block (read as
+    -> [B, T, H, d_v] float32; q and k may have fewer heads than v (whole
+    groups of value heads a key head).  With ``q_start`` [1] the operands
+    are one sequence's chunk [1, C, H, .] and ``block`` [1] its block (read as
     zeros where ``q_start`` is 0); without it they are one token a slot
     [B, 1, H, .] and ``block`` [B] each slot's block."""
     helper = LayerHelper("gated_delta_rule", name=name)
@@ -1864,16 +1883,21 @@ def gated_rms_norm(x, gate, epsilon=1e-6, param_attr=None,
 def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  row_valid=None, stats=None, dtype="float32", force=None,
-                 name=None):
+                 name=None, score_func="sigmoid"):
     """One chip's share of an expert-parallel SwiGLU expert layer over
     x [B, T, D] (ops/mla_ops.py moe_ffn_held): the router scores all
-    ``num_experts`` (sigmoid, selection bias, ``top_k`` picks,
-    normalised, scaled); this chip holds experts ``first_expert ..
+    ``num_experts`` (``score_func`` "sigmoid": sigmoid, selection bias,
+    ``top_k`` picks, normalised, scaled; "softmax": a softmax over all of
+    them in float32, its ``top_k`` largest, normalised, no bias
+    parameter); this chip holds experts ``first_expert ..
     first_expert + held_experts`` and adds up the picks that land on
     them.  ``stats`` [held_experts + 2] int32 persistable, added to in
     place: the picks each held expert got and the picks that went
     elsewhere, over the rows ``row_valid`` marks (> 0), and the held
     experts this call touched."""
+    if score_func not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_ffn_held: score_func {score_func!r}, wanted "
+                         f"'sigmoid' or 'softmax'")
     helper = LayerHelper("moe_ffn_held", name=name)
     d = x.shape[-1]
     pname = name or helper.name
@@ -1884,15 +1908,15 @@ def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,
             ParamAttr(name=f"{pname}_{suffix}", initializer=initializer),
             shape=shape, dtype=dt)
 
-    inputs = {
-        "X": [x],
-        "RouterW": [param("router.w_0", [d, num_experts])],
-        "RouterBias": [param("router.b_0", [num_experts],
-                             initializer=Constant(0.0))],
+    inputs = {"X": [x], "RouterW": [param("router.w_0", [d, num_experts])]}
+    if score_func == "sigmoid":
+        inputs["RouterBias"] = [param("router.b_0", [num_experts],
+                                      initializer=Constant(0.0))]
+    inputs.update({
         "WGate": [param("experts_gate.w_0", [held_experts, d, d_ff])],
         "WUp": [param("experts_up.w_0", [held_experts, d, d_ff])],
         "WDown": [param("experts_down.w_0", [held_experts, d_ff, d])],
-    }
+    })
     out = helper.create_variable_for_type_inference("float32")
     outputs = {"Out": [out]}
     if row_valid is not None:
@@ -1903,6 +1927,8 @@ def moe_ffn_held(x, num_experts, held_experts, d_ff, top_k, first_expert=0,
     attrs = {"top_k": int(top_k), "first_expert": int(first_expert),
              "routed_scaling_factor": float(routed_scaling_factor),
              "norm_topk_prob": bool(norm_topk_prob)}
+    if score_func != "sigmoid":
+        attrs["score_func"] = score_func
     if force is not None:
         attrs["force"] = force
     helper.append_op("moe_ffn_held", inputs=inputs, outputs=outputs,

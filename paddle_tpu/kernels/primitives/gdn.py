@@ -14,6 +14,16 @@ the log of the decay (<= 0) and ``beta`` in (0, 2); a position with
 tail).  Everything here is float32: the state is stored and updated in
 float32 and every product is taken at full precision.
 
+Keys may have FEWER heads than values (``q``, ``k`` [.., H_k, d_k] beside
+``v``, ``g``, ``beta`` of H = r H_k value heads): value head h reads the q
+and k of key head ``h // r``, with a decay, a beta and a state of its own
+(Qwen3-Next: 32 value heads on 16 key heads).  Neither kernel repeats q
+or k in memory: the chunk kernel's q and k blocks are found at ``h // r``
+by their index maps, and the step kernel's 0/1 pick matrix gives a key
+head the lanes of its r value heads, so its products have 1 / r of the
+rows.  With H_k = H every operand and every traced operation is what it
+was.
+
 ``g`` has one of two shapes.  One number a HEAD, ``[.., H]``: the rule
 above, and the two kernels of this file.  One number a KEY CHANNEL,
 ``[.., H, d_k]`` (Kimi Delta Attention): ``alpha`` is then
@@ -98,7 +108,7 @@ def _stored(s):
 
 
 def _check(op, q, k, v, state):
-    heads, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    heads, dk, dv = v.shape[-2], q.shape[-1], v.shape[-1]
     if (state.ndim != 3 or state.shape[1] != dk
             or state.shape[2] != heads * dv or state.dtype != jnp.float32):
         raise ValueError(
@@ -106,8 +116,25 @@ def _check(op, q, k, v, state):
             f"{tuple(state.shape)}, wanted float32 [blocks, {dk}, "
             f"{heads * dv}] (d_k rows, the heads' d_v columns side by "
             f"side)")
-    if k.shape != q.shape or v.shape[:-1] != q.shape[:-1]:
-        raise ValueError(f"{op}: q {q.shape}, k {k.shape}, v {v.shape}")
+    if (k.shape != q.shape or v.shape[:-2] != q.shape[:-2]
+            or heads % q.shape[-2]):
+        raise ValueError(
+            f"{op}: q {q.shape}, k {k.shape}, v {v.shape} (value heads in "
+            f"whole groups a key head)")
+
+
+def _per_value_head(x, heads, axis=-2):
+    """q or k [.., H_k, d_k] (its heads on ``axis``) as [.., H, d_k]:
+    value head h reads key head h // (H / H_k).  As it was where H_k =
+    H."""
+    r = heads // x.shape[axis]
+    return x if r == 1 else jnp.repeat(x, r, axis=axis)
+
+
+def _group_suffix(q, v):
+    """"" or ``_vk<r>``: r value heads read one key head."""
+    r = v.shape[-2] // q.shape[-2]
+    return "" if r == 1 else f"_vk{r}"
 
 
 def _book_form(primitive, form):
@@ -119,7 +146,8 @@ def _book_form(primitive, form):
         "pt_gated_delta_form_total",
         "Trace-time choices of the delta-rule kernels' bodies: the chunk "
         "kernel's sub-chunk length (sub<N>), the step kernel's heads a "
-        "lane tile (heads<N>)", labels=("primitive", "form"),
+        "lane tile (heads<N>); _vk<r> behind either where r value heads "
+        "read one key head", labels=("primitive", "form"),
     ).labels(primitive=primitive, form=form).inc()
 
 
@@ -145,26 +173,29 @@ def _f32(*xs):
 
 
 def gated_delta_chunk_reference(q, k, v, g, beta, state, block, fresh):
-    """The recurrence over one sequence's tokens: q, k [C, H, d_k], v
+    """The recurrence over one sequence's tokens: q, k [C, H_k, d_k], v
     [C, H, d_v], beta [C, H], g [C, H] or [C, H, d_k]; ``state``
     [blocks, d_k, H * d_v],
     ``block`` the sequence's block (a scalar), ``fresh`` (a scalar bool)
     reads the block as zeros.  -> (o [C, H, d_v], the state tensor with
     the block written)."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
-    s0 = jnp.where(fresh, 0.0, _heads_first(state[block], q.shape[1]))
+    heads = v.shape[1]
+    q, k = _per_value_head(q, heads), _per_value_head(k, heads)
+    s0 = jnp.where(fresh, 0.0, _heads_first(state[block], heads))
     s, out = jax.lax.scan(lambda s, x: _rule(s, *x), s0,
                           (q, k, v, g, beta))
     return out, state.at[block].set(_stored(s))
 
 
 def gated_delta_step_reference(q, k, v, g, beta, state, blocks):
-    """One token a slot: q, k [B, H, d_k], v [B, H, d_v], beta [B, H], g
+    """One token a slot: q, k [B, H_k, d_k], v [B, H, d_v], beta [B, H], g
     [B, H] or [B, H, d_k], ``blocks`` [B] each slot's state block.  -> (o
     [B, H, d_v], the state tensor with the slots' blocks written; slots
     that share the trash block write it in turn)."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
-    heads = q.shape[1]
+    heads = v.shape[1]
+    q, k = _per_value_head(q, heads), _per_value_head(k, heads)
     s0 = jax.vmap(lambda b: _heads_first(b, heads))(state[blocks])
     s, out = jax.vmap(_rule)(s0, q, k, v, g, beta)
     return out, state.at[blocks].set(jax.vmap(_stored)(s))
@@ -241,11 +272,14 @@ def _chunk_kernel(q_ref, k_ref, kb_ref, kdt_ref, vb_ref, eg_ref, el_ref,
 
 def _chunk_operands(q, k, v, g, beta, sub):
     """The kernel's operands from the chunk's [C, H, .] inputs, heads
-    first and by sub-chunk; every exponent is <= 0."""
-    c, heads, _ = q.shape
+    first and by sub-chunk; every exponent is <= 0.  q and k stay at
+    their own H_k heads (the launch finds a value head's at h // r); the
+    products with beta and the decay are a value head's."""
+    c, heads, _ = v.shape
     n = c // sub
     q, k, v = (x.transpose(1, 0, 2) for x in (q, k, v))     # [H, C, .]
     g, beta = g.T, beta.T                                   # [H, C]
+    kv = _per_value_head(k, heads, axis=0)            # a value head's keys
     gamma = jnp.cumsum(g.reshape(heads, n, sub), axis=-1)   # [H, n, sub]
     diff = gamma[..., :, None] - gamma[..., None, :]        # t, j
     lower = jnp.tril(jnp.ones((sub, sub), bool))
@@ -253,20 +287,25 @@ def _chunk_operands(q, k, v, g, beta, sub):
     eg = jnp.exp(gamma).reshape(heads, c, 1)
     elast = jnp.exp(gamma[..., -1])[..., None, None]        # [H, n, 1, 1]
     to_end = jnp.exp(gamma[..., -1:] - gamma).reshape(heads, c, 1)
-    kdt = (k * to_end).reshape(heads, n, sub, -1).transpose(0, 1, 3, 2)
-    return (q, k, k * beta[..., None], kdt, v * beta[..., None], eg, elast,
+    kdt = (kv * to_end).reshape(heads, n, sub, -1).transpose(0, 1, 3, 2)
+    return (q, k, kv * beta[..., None], kdt, v * beta[..., None], eg, elast,
             d)
 
 
 def _pallas_chunk(q, k, v, g, beta, s0, sub, interpret):
     """s0 [H, d_k, d_v] -> (o [C, H, d_v], s [H, d_k, d_v])."""
-    c, heads, dk = q.shape
-    dv = v.shape[-1]
+    c, heads_k, dk = q.shape
+    heads, dv = v.shape[1:]
+    r = heads // heads_k
     n = c // sub
     ops = _chunk_operands(q, k, v, g, beta, sub)
 
     def rows(width):
         return Block((1, sub, width), lambda h, i: (h, i, 0))
+
+    # q and k of value head h: key head h // r's rows
+    qk_rows = rows(dk) if r == 1 else Block(
+        (1, sub, dk), lambda h, i: (h // r, i, 0))
 
     def tile(a, b):
         return Block((1, 1, a, b), lambda h, i: (h, i, 0, 0))
@@ -275,7 +314,7 @@ def _pallas_chunk(q, k, v, g, beta, s0, sub, interpret):
     spec = contract.make_spec(
         "gated_delta_chunk",
         grid=(heads, n),
-        in_specs=[rows(dk), rows(dk), rows(dk), tile(dk, sub), rows(dv),
+        in_specs=[qk_rows, qk_rows, rows(dk), tile(dk, sub), rows(dv),
                   rows(1), tile(1, 1), tile(sub, sub), whole],
         out_specs=[rows(dv), whole],
         out_shape=[((heads, c, dv), jnp.float32),
@@ -301,13 +340,13 @@ def _run_chunk(op, pallas_chunk, form, q, k, v, g, beta, state, block, fresh,
         return gated_delta_chunk_reference(q, k, v, g, beta, state, block,
                                            fresh)
     q, k, v, g, beta = _f32(q, k, v, g, beta)
-    c, heads = q.shape[0], q.shape[1]
+    c, heads = v.shape[0], v.shape[1]
     sub = SUB if c >= SUB else BASE
     pad = -c % sub
     if pad:  # beta = 0, g = 0: the tail leaves the state alone
         q, k, v, g, beta = (jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
                             for x in (q, k, v, g, beta))
-    _book_form(op, form(sub))
+    _book_form(op, form(sub) + _group_suffix(q, v))
     s0 = jnp.where(fresh, 0.0, _heads_first(state[block], heads))
     o, s = pallas_chunk(q, k, v, g, beta, s0, sub, interpret)
     return o[:c], state.at[block].set(_stored(s))
@@ -315,7 +354,9 @@ def _run_chunk(op, pallas_chunk, form, q, k, v, g, beta, state, block, fresh,
 
 def gated_delta_chunk(q, k, v, g, beta, state, block, fresh, *, force=None):
     """The gated delta rule over one sequence's C tokens, all heads: q, k
-    [C, H, d_k], v [C, H, d_v], g, beta [C, H] -> (o [C, H, d_v] float32,
+    [C, H_k, d_k] (H_k = H, or H a multiple of it: value head h reads key
+    head h // (H / H_k)), v [C, H, d_v], g, beta [C, H] -> (o [C, H, d_v]
+    float32,
     the state tensor [blocks, d_k, H * d_v] with block ``block`` (a
     scalar) written).  ``fresh`` (a scalar bool): the block is read as
     zeros (a sequence's first chunk; nothing clears a block on the
@@ -334,11 +375,12 @@ def gated_delta_chunk(q, k, v, g, beta, state, block, fresh, *, force=None):
 # ---------------------------------------------------------------------------
 
 
-def _heads_per_tile(heads, dk, dv):
+def _heads_per_tile(heads, dk, dv, group=1):
     """Heads of one lane tile of the step kernel: the most whose state
     block stays under STEP_TILE_BYTES and whose d_v columns fill whole
-    lane tiles (else every head: a block as wide as the tensor)."""
-    fits = [n for n in range(1, heads + 1)
+    lane tiles (else every head: a block as wide as the tensor), in whole
+    groups of the ``group`` value heads that read one key head."""
+    fits = [n for n in range(group, heads + 1, group)
             if heads % n == 0 and (n * dv) % 128 == 0
             and 4 * dk * n * dv <= STEP_TILE_BYTES]
     return max(fits) if fits else heads
@@ -346,8 +388,9 @@ def _heads_per_tile(heads, dk, dv):
 
 def _step_update(s, pick, q_ref, k_ref, kt_ref, v_ref, b_ref, o_ref, so_ref):
     """The token's update of one (slot, tile of heads) from its DECAYED
-    state s [d_k, L]; ``pick`` [hp, L] 0 / 1 says which lanes are which
-    head's."""
+    state s [d_k, L]; ``pick`` [hp, L] 0 / 1 says which lanes read which
+    row of q and k (a value head's d_v lanes; with r value heads a key
+    head, the r d_v lanes of a key head's group)."""
     r = jnp.sum(_mm(k_ref[0, 0], s) * pick, axis=0, keepdims=True)
     u = b_ref[0] * (v_ref[0] - r)                      # [1, L]
     s = s + _mm(kt_ref[0, 0], pick) * u
@@ -370,16 +413,18 @@ def _launch_step(op, kernel, decay, q, k, v, beta, state, blocks, interpret):
     ``decay(by_tile, lanes_of, head_rows, lane_row)`` -> (the decay's
     operand, its block): the one operand the two kernels take in
     different shapes, the sixth of ``kernel``."""
-    b, heads, dk = q.shape
-    dv = v.shape[-1]
-    hg = _heads_per_tile(heads, dk, dv)
-    _book_form(op, f"heads{hg}")
+    b, heads_k, dk = q.shape
+    heads, dv = v.shape[1:]
+    r = heads // heads_k
+    hg = _heads_per_tile(heads, dk, dv, r)
+    _book_form(op, f"heads{hg}" + _group_suffix(q, v))
     tiles, lanes = heads // hg, hg * dv
-    hp = -(-hg // 8) * 8
-    pad = ((0, 0), (0, 0), (0, hp - hg), (0, 0))
+    hk = hg // r                             # rows of q and k a tile
+    hp = -(-hk // 8) * 8
+    pad = ((0, 0), (0, 0), (0, hp - hk), (0, 0))
 
-    def by_tile(x):                          # [B, H, dk] -> [B, tiles, hp, dk]
-        return jnp.pad(x.reshape(b, tiles, hg, dk), pad)
+    def by_tile(x):                        # [B, H_k, dk] -> [B, tiles, hp, dk]
+        return jnp.pad(x.reshape(b, tiles, hk, dk), pad)
 
     qx = by_tile(q)
     kx = by_tile(k)
@@ -387,7 +432,7 @@ def _launch_step(op, kernel, decay, q, k, v, beta, state, blocks, interpret):
     def lanes_of(x):                                   # [B, H] -> [B, 1, H dv]
         return jnp.repeat(x, dv, axis=-1)[:, None, :]
 
-    pick = (jnp.arange(lanes)[None, :] // dv
+    pick = (jnp.arange(lanes)[None, :] // (r * dv)
             == jnp.arange(hp)[:, None]).astype(jnp.float32)
 
     def head_rows(a, c):
@@ -427,8 +472,9 @@ def _pallas_step(q, k, v, g, beta, state, blocks, interpret):
 
 
 def gated_delta_step(q, k, v, g, beta, state, blocks, *, force=None):
-    """The gated delta rule for one token a slot: q, k [B, H, d_k], v
-    [B, H, d_v], g, beta [B, H], ``blocks`` [B] int32 each slot's state
+    """The gated delta rule for one token a slot: q, k [B, H_k, d_k] (H_k
+    = H, or H a multiple of it), v [B, H, d_v], g, beta [B, H],
+    ``blocks`` [B] int32 each slot's state
     block (inactive slots name the trash block 0) -> (o [B, H, d_v]
     float32, the state tensor with those blocks rewritten in place).
 

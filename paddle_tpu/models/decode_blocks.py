@@ -1,13 +1,16 @@
 """The parts the decode-lane decoders share (models/glm.py, trinity.py,
-kimi_vl.py, olmo_hybrid.py, mimo.py, kimi_linear.py): a matrix stored in
-``cfg.dtype``, an RMSNorm, a SwiGLU, the latent-attention RoPE, the head
-and, under public names, the two blocks kimi_vl.py and kimi_linear.py
-both run: dense latent attention in its two forms (``latent_attention``)
-and the sigmoid-routed expert layer beside a shared expert
-(``expert_ffn``).  ``cfg`` is the model's config: ``dtype``,
-``initializer_range``, ``rms_norm_eps``, ``hidden_size`` and what each
-part names below.  The programs around a decoder (feeds, pools, page
-writers) are serving/lane.py's."""
+kimi_vl.py, olmo_hybrid.py, mimo.py, kimi_linear.py, qwen3_next.py): a
+matrix stored in ``cfg.dtype``, an RMSNorm, a SwiGLU, the
+latent-attention RoPE, the head and, under public names, the two blocks
+more than one model runs: dense latent attention in its two forms
+(``latent_attention``; kimi_vl.py, kimi_linear.py) and the routed expert
+layer beside a shared expert (``expert_ffn``; those two and
+qwen3_next.py).  ``cfg`` is the model's config: ``dtype``,
+``initializer_range``, ``rms_norm_eps``, ``hidden_size``, optionally
+``norm_gain_offset`` (1 where every RMSNorm's gain is ``1 + w`` with
+``w`` stored; absent or 0: a plain gain) and what each part names below.
+The programs around a decoder (feeds, pools, page writers) are
+serving/lane.py's."""
 
 from __future__ import annotations
 
@@ -29,10 +32,11 @@ def _linear(x, size, name, cfg, head_dim=None):
 
 
 def _rms(x, name, cfg):
+    offset = float(getattr(cfg, "norm_gain_offset", 0.0))
     return layers.rms_norm(
-        x, epsilon=cfg.rms_norm_eps,
+        x, epsilon=cfg.rms_norm_eps, gain_offset=offset,
         param_attr=ParamAttr(name=name + ".scale",
-                             initializer=Constant(1.0)))
+                             initializer=Constant(1.0 - offset)))
 
 
 def _swiglu_ffn(x, width, name, cfg):
@@ -113,14 +117,18 @@ def latent_attention(x, pos, page_table, q_start, pool, write, shape, cfg,
                    cfg.hidden_size, name + "_o", cfg)
 
 
-def expert_ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
+def expert_ffn(x, layer, row_valid, counted_as, cfg, name, attn_force,
+               score_func="sigmoid", shared_width=None, shared_gate=False):
     """The block's feed-forward half over x [B, T, D], after its norm: a
     SwiGLU in the first ``cfg.first_k_dense_replace`` layers; in the
-    others the picks of the sigmoid router that land on the
+    others the picks of the router (``score_func``: sigmoid scores and a
+    selection bias, or a softmax) that land on the
     ``held_experts`` experts this process holds (ops/mla_ops.py
     ``moe_ffn_held``; counted on the device where ``counted_as`` names
-    the executable) plus ONE shared SwiGLU of width ``n_shared_experts``
-    x ``moe_intermediate_size``.  ``cfg``: those, ``intermediate_size``,
+    the executable) plus ONE shared SwiGLU of width ``shared_width``
+    (``n_shared_experts`` x ``moe_intermediate_size`` where None), times
+    ``sigmoid(u w_sg)``, one number a token (``<name>_shared_expert_gate``
+    [D, 1]), where ``shared_gate``.  ``cfg``: those, ``intermediate_size``,
     ``n_routed_experts``, ``num_experts_per_tok``, ``first_expert``,
     ``routed_scaling_factor``, ``norm_topk_prob``."""
     xf = _rms(x, name + "_ffn_norm", cfg)
@@ -134,8 +142,12 @@ def expert_ffn(x, layer, row_valid, counted_as, cfg, name, attn_force):
         first_expert=cfg.first_expert,
         routed_scaling_factor=cfg.routed_scaling_factor,
         norm_topk_prob=cfg.norm_topk_prob, row_valid=row_valid, stats=stats,
-        dtype=cfg.dtype, force=attn_force, name=name + "_moe")
+        dtype=cfg.dtype, force=attn_force, name=name + "_moe",
+        score_func=score_func)
     shared = _swiglu_ffn(
-        xf, cfg.n_shared_experts * cfg.moe_intermediate_size,
+        xf, shared_width or cfg.n_shared_experts * cfg.moe_intermediate_size,
         name + "_shared", cfg)
+    if shared_gate:
+        shared = layers.sigmoid_gate(
+            shared, _linear(xf, 1, name + "_shared_expert_gate", cfg))
     return layers.elementwise_add(routed, shared)

@@ -21,10 +21,12 @@ full-attention layers (3 : 1 as published), a SwiGLU in every block.
   both     y = a + RMS_post_ff(W_down(silu(a W_gate) * a W_up))
   head     final RMSNorm, untied lm_head.
 
-Where each norm stands is no key of the published config: the
-benchmark's configuration file lists every such choice under ``assumed``
-and the plain reference (benchmark/reference/olmo_hybrid.py) is written
-from the same entries.
+A linear-attention layer here has as many key heads as value heads; the
+kernels take fewer (value head h on key head h // r), which is
+models/qwen3_next.py's layer.  Where each norm stands is no key of the
+published config: the benchmark's configuration file lists every such
+choice under ``assumed`` and the plain reference
+(benchmark/reference/olmo_hybrid.py) is written from the same entries.
 
 What a layer leaves behind (serving/lane.py): a full-attention layer a K
 and a V row a TOKEN (the pool's page kind ``full``; the lane numbers its
@@ -93,8 +95,10 @@ class OlmoHybridConfig:
             raise ValueError("OlmoHybridConfig: plain multi-head attention "
                              "(num_key_value_heads = num_attention_heads)")
         if linear_num_key_heads != linear_num_value_heads:
-            raise ValueError("OlmoHybridConfig: as many key heads as value "
-                             "heads in a linear-attention layer")
+            raise ValueError(
+                "OlmoHybridConfig: as many key heads as value heads in a "
+                "linear-attention layer (the delta-rule layer with value "
+                "heads in groups a key head is models/qwen3_next.py's)")
         if (len(self.layer_types) != num_hidden_layers
                 or set(self.layer_types) - {LINEAR, FULL}):
             raise ValueError(

@@ -1,5 +1,6 @@
 """Ops of a linear-attention (gated delta rule) layer served through the
-decode lane (models/olmo_hybrid.py, models/kimi_linear.py), beside
+decode lane (models/olmo_hybrid.py, models/kimi_linear.py,
+models/qwen3_next.py), beside
 ops/mla_ops.py (whose
 ``weight_matmul``, ``rms_norm`` and ``swiglu`` it shares):
 
@@ -12,7 +13,10 @@ ops/mla_ops.py (whose
                      the rule's operands: L2-normalised q (scaled) and k,
                      v, g = log alpha, beta.  ``g`` is one number a
                      head, [B, T, H], or, where the gate projection is
-                     H d_k wide, one a key channel, [B, T, H, d_k]
+                     H d_k wide, one a key channel, [B, T, H, d_k].
+                     With ``key_heads`` < ``heads`` q and k have that
+                     many heads and value head h reads key head
+                     h // (heads / key_heads)
   gated_delta_chunk  the gated delta rule over a per-sequence state: a
   gated_delta_step   prefill chunk's form and a decode step's, the state
                      tensor updated in place.  ``g`` [.., H] runs
@@ -85,8 +89,9 @@ def _short_conv_step(ctx, x, w, tails, blocks, attrs):
 @simple_op("gdn_inputs", ["QKV", "A", "B", "ALog", "DtBias", "RowValid"],
            ["Q", "K", "V", "G", "Beta"], optional=("RowValid",), grad=None)
 def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
-    """qkv [B, T, 2 H d_k + H d_v] (after the convolution and its SiLU),
-    a, b [B, T, H] the two gate projections -> q [B, T, H, d_k] =
+    """qkv [B, T, 2 H_k d_k + H d_v] (after the convolution and its SiLU;
+    H_k = ``key_heads``, H where the attr is absent), a, b [B, T, H] the
+    two gate projections -> q [B, T, H_k, d_k] =
     l2norm(q') / sqrt(d_k), k = l2norm(k'), v [B, T, H, d_v], g = -exp(
     ALog) softplus(a + DtBias), beta = beta_scale sigmoid(b).  l2norm(x) =
     x rsqrt(sum x^2 + eps).  With a [B, T, H d_k] and DtBias [H d_k] (a
@@ -95,6 +100,7 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
     the state alone there."""
     heads, dk, dv = (int(attrs[k]) for k in ("heads", "key_dim",
                                              "value_dim"))
+    hk = int(attrs.get("key_heads", heads))
     eps = float(attrs["epsilon"])
     qkv = _f32(qkv)
     lead = qkv.shape[:2]
@@ -103,9 +109,9 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
         return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
                                  + eps)
 
-    q = l2norm(qkv[..., :heads * dk].reshape(*lead, heads, dk)) * dk ** -0.5
-    k = l2norm(qkv[..., heads * dk:2 * heads * dk].reshape(*lead, heads, dk))
-    v = qkv[..., 2 * heads * dk:].reshape(*lead, heads, dv)
+    q = l2norm(qkv[..., :hk * dk].reshape(*lead, hk, dk)) * dk ** -0.5
+    k = l2norm(qkv[..., hk * dk:2 * hk * dk].reshape(*lead, hk, dk))
+    v = qkv[..., 2 * hk * dk:].reshape(*lead, heads, dv)
     if a.shape[-1] == heads:
         g = -jnp.exp(_f32(a_log)) * jax.nn.softplus(_f32(a) + _f32(dt_bias))
     else:                             # a decay a key channel
@@ -123,9 +129,9 @@ def _gdn_inputs(ctx, qkv, a, b, a_log, dt_bias, row_valid, attrs):
            ["Q", "K", "V", "G", "Beta", "State", "Block", "QStart"],
            ["Out", "StateOut"], grad=None, inplace={"StateOut": "State"})
 def _gated_delta_chunk(ctx, q, k, v, g, beta, state, block, q_start, attrs):
-    """One sequence's chunk [1, C, H, .]; its state block ``block`` [1]
-    is read as zeros where ``q_start`` [1] is 0.  g [1, C, H], or
-    [1, C, H, d_k] for a decay a key channel."""
+    """One sequence's chunk [1, C, H, .] (q and k [1, C, H_k, d_k]); its
+    state block ``block`` [1] is read as zeros where ``q_start`` [1] is
+    0.  g [1, C, H], or [1, C, H, d_k] for a decay a key channel."""
     from paddle_tpu.kernels import primitives as _prims
 
     rule = _prims.gated_delta_chunk if g.ndim == 3 else _prims.kda_chunk
@@ -140,8 +146,9 @@ def _gated_delta_chunk(ctx, q, k, v, g, beta, state, block, q_start, attrs):
            ["Q", "K", "V", "G", "Beta", "State", "Block"],
            ["Out", "StateOut"], grad=None, inplace={"StateOut": "State"})
 def _gated_delta_step(ctx, q, k, v, g, beta, state, blocks, attrs):
-    """One token a slot [B, 1, H, .]; ``blocks`` [B].  g [B, 1, H], or
-    [B, 1, H, d_k] for a decay a key channel."""
+    """One token a slot [B, 1, H, .] (q and k [B, 1, H_k, d_k]);
+    ``blocks`` [B].  g [B, 1, H], or [B, 1, H, d_k] for a decay a key
+    channel."""
     from paddle_tpu.kernels import primitives as _prims
 
     rule = _prims.gated_delta_step if g.ndim == 3 else _prims.kda_step

@@ -15,7 +15,8 @@ decode lane (models/glm.py): what the older zoo had no op for.
                      in latent space (decode step) and in head space
                      (prefill chunk) (kernels/primitives/mla.py)
   moe_ffn_held       the expert layer of ONE chip of an expert-parallel
-                     deployment: routes over every expert, computes the
+                     deployment: routes over every expert (sigmoid scores
+                     and a selection bias, or a softmax), computes the
                      picks that land on the experts it holds
 
 All inference-only (grad=None), like every decode-lane op.  Activations
@@ -88,9 +89,15 @@ def _headwise_matmul(ctx, x, w, attrs):
 
 @simple_op("rms_norm", ["X", "Scale"], ["Out"], grad=None)
 def _rms_norm(ctx, x, scale, attrs):
+    """x rsqrt(mean x^2 + eps) (``gain_offset`` + Scale): a plain gain,
+    or, with ``gain_offset`` 1, a zero-centred one (``1 + w`` with ``w``
+    stored)."""
     x = _f32(x)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + attrs.get("epsilon", 1e-5)) * _f32(scale)
+    gain = _f32(scale)
+    if attrs.get("gain_offset"):
+        gain = float(attrs["gain_offset"]) + gain
+    return x * jax.lax.rsqrt(var + attrs.get("epsilon", 1e-5)) * gain
 
 
 def _silu_times(gate, up):
@@ -194,17 +201,35 @@ def route_sigmoid_topk(x2, router_w, router_b, top_k, scaling, normalize):
     return picks.astype(jnp.int32), gates * scaling
 
 
+def route_softmax_topk(x2, router_w, top_k, scaling, normalize):
+    """Softmax scores over every expert (the router's product, the
+    softmax and the top-k in float32 at full precision), the ``top_k``
+    largest, gates the picked scores over their sum (``normalize``) or as
+    they are: (picks [N, k] int32, gates [N, k] float32).  No selection
+    bias."""
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.softmax(jnp.dot(
+            _f32(x2), _f32(router_w), precision=jax.lax.Precision.HIGHEST),
+            axis=-1)
+        gates, picks = jax.lax.top_k(scores, top_k)
+        if normalize:
+            gates = gates / jnp.sum(gates, axis=1, keepdims=True)
+        return picks.astype(jnp.int32), gates * scaling
+
+
 @simple_op("moe_ffn_held",
            ["X", "RouterW", "RouterBias", "WGate", "WUp", "WDown",
             "RowValid", "Stats"], ["Out", "StatsOut"],
-           optional=("RowValid", "Stats"), grad=None,
+           optional=("RouterBias", "RowValid", "Stats"), grad=None,
            inplace={"StatsOut": "Stats"})
 def _moe_ffn_held(ctx, x, router_w, router_b, w_gate, w_up, w_down,
                   row_valid, stats, attrs):
     """One chip's share of an expert-parallel SwiGLU expert layer.
 
     The router keeps its full width (``RouterW`` [D, E], ``RouterBias``
-    [E]); this chip holds experts ``first_expert .. first_expert + Eh``
+    [E]; ``score_func`` "sigmoid", the default, or "softmax", which has
+    no bias: ``route_softmax_topk``); this chip holds experts
+    ``first_expert .. first_expert + Eh``
     (``WGate``/``WUp`` [Eh, D, F], ``WDown`` [Eh, F, D]).  Every token
     picks ``top_k`` of all E; the picks that land on held experts are
     sorted by expert and go through a grouped product
@@ -226,10 +251,14 @@ def _moe_ffn_held(ctx, x, router_w, router_b, w_gate, w_up, w_down,
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
-    picks, gates = route_sigmoid_topk(
-        x2, router_w, router_b, top_k,
-        float(attrs.get("routed_scaling_factor", 1.0)),
-        bool(attrs.get("norm_topk_prob", True)))
+    scaling = float(attrs.get("routed_scaling_factor", 1.0))
+    normalize = bool(attrs.get("norm_topk_prob", True))
+    if attrs.get("score_func", "sigmoid") == "softmax":
+        picks, gates = route_softmax_topk(x2, router_w, top_k, scaling,
+                                          normalize)
+    else:
+        picks, gates = route_sigmoid_topk(x2, router_w, router_b, top_k,
+                                          scaling, normalize)
     local = picks - first
     held = (local >= 0) & (local < held_n)
     valid = jnp.ones((n, 1), bool) if row_valid is None else (

@@ -1,8 +1,9 @@
-"""The decode lane's executables of all seven models lower to the HLO
+"""The decode lane's executables of all eight models lower to the HLO
 they lowered, compared BY TEXT: the two served programs of
 `models/gpt.py` (float32 and int8 pools), `models/glm.py`,
 `models/trinity.py`, `models/kimi_vl.py` (and its image encoder),
-`models/olmo_hybrid.py`, `models/mimo.py` and `models/kimi_linear.py`,
+`models/olmo_hybrid.py`, `models/mimo.py`, `models/kimi_linear.py` and
+`models/qwen3_next.py`,
 both in the XLA form of their attention and with the Pallas kernels
 interpreted (`attn_force="pallas"`), where the text holds the kernels'
 own bodies.  A PR that means to change no executable proves it here; a
@@ -36,7 +37,14 @@ piece, whose false region returns the token 0 and zeros
 (`test_a_chunks_head_lies_in_the_true_region_only` reads the text for
 it); the sixteen `*.decode` and two `*.encoder` digests are PR 47's and
 PR 43's still, which is the proof that the decode step and the tower
-were left alone.  After a deliberate change to
+were left alone.  **PR 49 added the `qwen3_next.*` four with its lane and
+moved no other**: `gdn_inputs` (key heads beside value heads), both
+delta-rule kernels (q and k found at `h // r`), `moe_ffn_held` (a
+softmax router), `expert_ffn` (a gate on the shared expert) and
+`rms_norm` (an offset on the gain) take their new behaviour from
+arguments whose defaults trace what they traced, which the other 34
+digests hold (`olmo_hybrid.pallas.*` and `kimi_linear.pallas.*` the
+interpreted kernels' bodies among them).  After a deliberate change to
 what these models compile, run `python tests/test_lane_hlo_unchanged.py`
 and paste its output over GOLDEN, saying in the commit why they moved.
 """
@@ -54,7 +62,7 @@ import pytest
 
 from paddle_tpu import fluid, serving
 from paddle_tpu.models import (glm, gpt, kimi_linear, kimi_vl, mimo,
-                               olmo_hybrid, trinity)
+                               olmo_hybrid, qwen3_next, trinity)
 
 GOLDEN = {
     "gpt.float32.None.prefill": "558a7809cf01862028c6dea6430fa34ac0de8a4a4913b2bfb96bde8855b3e5ec",
@@ -90,12 +98,16 @@ GOLDEN = {
     "kimi_linear.None.prefill": "349a66b613bdc8973252960687c6ff123f59fde285f3bbddc9fe571a640ad5e0",
     "kimi_linear.None.decode": "d622ff070875cae920e4471d00899bedd0ccff3eee02ed7547c09bcb0663e881",
     "kimi_linear.pallas.prefill": "ee332d3c173c5a25ffa2ee57597047d180efbae6ae8630b4f93d190c0a0e0061",
-    "kimi_linear.pallas.decode": "8512086f341e77354003c507d3b9520087affad4073f0affdeb47dbce80fa476"
+    "kimi_linear.pallas.decode": "8512086f341e77354003c507d3b9520087affad4073f0affdeb47dbce80fa476",
+    "qwen3_next.None.prefill": "36764fc3c5b75b895cbd780327e9feca92ab5c5934619e5abf1d9823284490db",
+    "qwen3_next.None.decode": "25438bc7d7c325227de456244947876122ea040bb719d5411f85110d74b8cfe0",
+    "qwen3_next.pallas.prefill": "a9566c62edfa817ac480aecd28b4dd540347d65af9f8221298d9e97b454be13d",
+    "qwen3_next.pallas.decode": "f92217445a9a6b2777e738d650afd2b67c26369ff270153b293f5ffc97b8e670"
 }
 
 
 MODELS = ("gpt", "glm", "trinity", "kimi_vl", "olmo_hybrid", "mimo",
-          "kimi_linear")
+          "kimi_linear", "qwen3_next")
 
 
 def _zero_scope(*builds, rng=None):
@@ -153,6 +165,9 @@ def _later_model(model):
         cfg = kimi_linear.KimiLinearConfig.tiny(held_experts=4,
                                                 first_expert=2)
         return cfg, [lambda: kimi_linear.build_kimi_linear_lm(cfg)]
+    if model == "qwen3_next":
+        cfg = qwen3_next.Qwen3NextConfig.tiny(held_experts=4, first_expert=4)
+        return cfg, [lambda: qwen3_next.build_qwen3_next_lm(cfg)]
     # K heads of 192 beside V heads of 128, the published widths: the
     # asymmetric Pallas forms read whole 128-lane tiles and take no other
     cfg = mimo.MiMoConfig.tiny(

@@ -403,10 +403,21 @@ def _kimi_linear_cut(config):
     return cfg, [lambda: kimi_linear.build_kimi_linear_lm(cfg)]
 
 
+def _qwen3_next_cut(config):
+    from paddle_tpu.models import qwen3_next
+
+    # one period of its two: linear, linear, linear, full; four expert
+    # layers
+    cfg = qwen3_next.Qwen3NextConfig(**dict(_config_args(config),
+                                            num_hidden_layers=4))
+    return cfg, [lambda: qwen3_next.build_qwen3_next_lm(cfg)]
+
+
 _CUTS = {"glm-5-ep16": _glm_cut, "trinity-large-ep8": _trinity_cut,
          "kimi-vl-a3b-ep1": _kimi_cut, "olmo-hybrid-7b-pp4": _olmo_cut,
          "mimo-v2.5-ep16": _mimo_cut,
-         "kimi-linear-48b-ep4": _kimi_linear_cut}
+         "kimi-linear-48b-ep4": _kimi_linear_cut,
+         "qwen3-next-80b-ep4": _qwen3_next_cut}
 
 
 @functools.lru_cache(maxsize=None)
@@ -982,6 +993,121 @@ def test_mimo_decode_engine_executables():
 
 
 # ---------------------------------------------------------------------------
+# Qwen3-Next through the decode lane (benchmark/configs/
+# qwen3-next-80b-ep4.json): the delta-rule kernels' grouped-head bodies
+# (32 value heads on 16 key heads), the grouped paged kernel at heads of
+# 256 and 8 queries a K/V head, the grouped product at 128 experts 512
+# wide, all at 64 SLOTS, and the engine's two executables
+# ---------------------------------------------------------------------------
+
+_Q3N_PAGES, _Q3N_BLOCKS = 64 * 140 + 1, 66
+_Q3N_STATE = ((_Q3N_BLOCKS, 128, 32 * 128), jnp.float32)
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_gated_delta_kernels_at_qwen3_next_widths(chip, form):
+    """32 value heads on 16 key heads of 128 x 128 over the float32 state
+    tensor [66, 128, 4096]: the step over 64 slots (the tensor aliased,
+    rewritten where it lies; eight rows of q and k a tile of sixteen
+    value heads) and the chunk over 512 tokens (q and k blocks found at
+    h // 2).  Neither copies the tensor, and q and k are not repeated in
+    memory: no [.., 32, 128] tensor of theirs is made."""
+    f32 = jnp.float32
+    n = 64 if form == "step" else 512
+    rows = [((n, 16, 128), f32), ((n, 16, 128), f32), ((n, 32, 128), f32),
+            ((n, 32), f32), ((n, 32), f32), _Q3N_STATE]
+    if form == "step":
+        fn = prims.gated_delta_step
+        rows.append(((64,), jnp.int32))
+    else:
+        def fn(q, k, v, g, beta, state, block, fresh):
+            return prims.gated_delta_chunk(q, k, v, g, beta, state,
+                                           block[0], fresh[0])
+        rows += [((1,), jnp.int32), ((1,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in rows]
+    with lowering_for("tpu"):
+        hlo = jax.jit(fn, donate_argnums=(5,)).lower(*args).compile() \
+            .as_text()
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(rf"%gated_delta_{form}[.\d]* = ", hlo)) == 1
+    assert _state_copies(hlo, "66,128,4096") == []
+    assert 5 in _aliased_parameters(hlo)
+
+
+@pytest.mark.parametrize("b,t", [(64, 1), (1, 512)])
+def test_paged_attention_grouped_at_qwen3_next_widths(chip, b, t):
+    """16 query heads on 2 K/V heads of 256 (8 queries a K/V head, the
+    widest head and the largest group the grouped bodies have run) over a
+    bf16 pool [8961, 128, 512] at a 17 920-token page table, as a decode
+    step of 64 slots and as a 512-token chunk see them."""
+    pool = ((_Q3N_PAGES, 128, 512), jnp.bfloat16)
+    hlo = _compile(
+        lambda q, kp, vp, pt, qs: prims.paged_attention(
+            q, kp, vp, pt, qs, sm_scale=256 ** -0.5), chip,
+        ((b, 16, t, 256), jnp.float32), pool, pool, ((b, 140), jnp.int32),
+        ((b,), jnp.int32))
+    assert _mosaic_calls(hlo) == 1
+    assert len(re.findall(r"%paged_attention_grouped[.\d]* = ", hlo)) == 1
+    assert _pool_copies(hlo, _Q3N_PAGES, 128) == []
+
+
+@pytest.mark.parametrize("rows,k,n", [(640, 2048, 512), (640, 512, 2048),
+                                      (5120, 2048, 512), (5120, 512, 2048)])
+def test_grouped_matmul_at_qwen3_next_widths(chip, rows, k, n):
+    """The expert layer's product over 128 held experts 512 wide: a
+    64-row decode step's 640 pick rows and a chunk's 5120 (10 picks a
+    token), blocks of [2048, 512] and [512, 2048], 2 MiB each."""
+    hlo = _compile(lambda x, w, g: prims.grouped_matmul(x, w, g), chip,
+                   ((rows, k), jnp.bfloat16), ((128, k, n), jnp.bfloat16),
+                   ((128,), jnp.int32))
+    assert _mosaic_calls(hlo) == 1 and "%grouped_matmul" in hlo
+
+
+def test_qwen3_next_decode_engine_executables():
+    """The prefill chunk and the decode step of Qwen3-Next at the
+    benchmark's widths, pool and 64 slots (one period of its two: linear,
+    linear, linear, full; four expert layers): a delta-rule call a linear
+    layer under the names ``gdn_chunk_mxu_share.serve`` and
+    ``gdn_step_roofline.serve`` read, one grouped paged call under the
+    name ``full_attn_roofline.serve`` reads, three grouped products an
+    expert layer, and both kinds of cache (K/V pages and per-sequence
+    state blocks) donated, row-major and UNCOPIED."""
+    served = _served("qwen3-next-80b-ep4")
+    assert served.prefill_chunk == 512
+    assert served.pool.num_pages == _Q3N_PAGES
+    assert served.pool.state_blocks == _Q3N_BLOCKS
+    gdn = {form: re.compile(harness_json(_ROOT, f"gdn_{metric}.serve")
+                            ["pattern"])
+           for form, metric in (("chunk", "chunk_mxu_share"),
+                                ("step", "step_roofline"))}
+    full = re.compile(harness_json(_ROOT, "full_attn_roofline.serve")
+                      ["pattern"])
+    grouped = re.compile(harness_json(_ROOT, "moe_ffn_roofline.serve")
+                         ["pattern"])
+    for form in ("chunk", "step"):
+        hlo, lines = served.exes[form]
+        other = "step" if form == "chunk" else "chunk"
+        assert sum(bool(gdn[form].search(x)) for x in lines) == 3
+        assert sum(bool(gdn[other].search(x)) for x in lines) == 0
+        assert sum(bool(full.search(x)) for x in lines) == 1
+        assert sum(bool(grouped.search(x)) for x in lines) == 3 * 4
+        assert _mosaic_calls(hlo) == 3 + 1 + 12
+        assert _pool_copies(hlo, _Q3N_PAGES, 128) == []
+        kv = _pool_parameters(hlo, f"{_Q3N_PAGES},128,512")
+        state = _pool_parameters(hlo, "66,128,4096")
+        tails = _pool_parameters(hlo, "66,24576")
+        assert len(kv) == 2                        # K and V
+        assert len(state) == len(tails) == 3       # a linear layer
+        assert _state_copies(hlo, "66,128,4096") == []
+        assert _state_copies(hlo, "66,24576") == []
+        assert [lay for _, lay in kv + state
+                if not lay.startswith("{2,1,0")] == []
+        assert {num for num, _ in kv + state + tails} <= \
+            _aliased_parameters(hlo)
+
+
+
+# ---------------------------------------------------------------------------
 # What the compiled executables COPY (PR 42).  Where a product is reshaped
 # into heads that are no whole lane tiles, XLA:TPU's layout assignment
 # pays with a copy of the read-only WEIGHT through HBM in every run
@@ -1053,6 +1179,21 @@ _COPY_MB = {
     # first for the KDA kernel)
     ("kimi-linear-48b-ep4", "chunk"): (424.4, 424.4),
     ("kimi-linear-48b-ep4", "step"): (23.2, 23.2),
+    # new in PR 49 (no parent): what the cut's executables copy today; of
+    # the step's 62.1 MB 33.6 are XLA's fetch of the full layer's W_q
+    # into fast memory and 18.9 the convolution's carried inputs laid out
+    # for its update
+    ("qwen3-next-80b-ep4", "chunk"): (288.4, 288.4),
+    ("qwen3-next-80b-ep4", "step"): (62.2, 62.2),
+}
+# {(config, executable): shapes of weights of 1 MiB or more that a decode
+# step still relays through HBM}.  Qwen3-Next's full layer: XLA:TPU
+# transposes W_k [2048, 512] (2 MiB of the ~6 GB a step reads) on its way
+# into the product; its head of 256 is whole lane tiles, so
+# `_pin_product`'s rule does not apply, and the copy is left to XLA
+# (PERF.md section 7)
+_WEIGHT_RELAYS_LEFT = {
+    ("qwen3-next-80b-ep4", "step"): [(512, 2048)],
 }
 _PINNED_CHUNKS = {("mimo-v2.5-ep16", "chunk"), ("kimi-vl-a3b-ep1", "chunk"),
                   ("kimi-linear-48b-ep4", "chunk")}
@@ -1062,10 +1203,12 @@ _PINNED_CHUNKS = {("mimo-v2.5-ep16", "chunk"), ("kimi-vl-a3b-ep1", "chunk"),
 # arrive with the hidden dimension minor-most, the product inside the
 # branch wants them row-major, and XLA:TPU copies them there, once a
 # prompt, where the parent streamed them once a chunk; the four others'
-# head matrices go in uncopied
+# head matrices go in uncopied.  Qwen3-Next's 37984 columns (PR 49) are
+# no whole lane tiles either
 _COPY_MB_FINAL_CHUNK = {
     ("trinity-large-ep8", "chunk"): 153.7,    # bf16[3072,25024]
     ("glm-5-ep16", "chunk"): 237.9,           # bf16[6144,19360]
+    ("qwen3-next-80b-ep4", "chunk"): 155.6,   # bf16[2048,37984]
 }
 
 
@@ -1091,7 +1234,8 @@ def test_served_executables_copy_no_more_than_they_did(name, exe):
         weights = _matrix_parameters(hlo)
         assert len(weights) >= 8
         assert [dims for dims, n, fast in copies
-                if dims in weights and n >= 2 ** 20 and not fast] == []
+                if dims in weights and n >= 2 ** 20 and not fast] == \
+            _WEIGHT_RELAYS_LEFT.get((name, exe), [])
 
 
 @pytest.mark.parametrize("name", list(_CUTS))

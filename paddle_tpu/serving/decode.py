@@ -91,6 +91,20 @@ run that moved the sequence on; the
 executor's own phases are children of the two ``*.run`` spans, which also
 feed ``pt_decode_step_seconds`` and ``pt_decode_phase_seconds_total``.
 
+Which runs block (docs/SERVING.md "Which runs block" has the whole of
+it).  A ``*.run`` span is a run that ends in a blocking fetch: the decode
+step always, a prefill chunk only where its token is read, which is a
+fresh prompt's LAST chunk (``final``).  Every other chunk is ENQUEUED, not
+waited for: the same program and fetch list with ``return_numpy=False``
+and no ``prefill.run`` span, so the executor's ``lookup`` .. ``fetch_sync``
+lie directly under the turn, and admission, the step's feed and its
+dispatch happen while the chunk runs (the device runs programs in
+dispatch order and the pool's buffers chain through the scope).  The
+engine keeps the outputs of at most two such chunks (``_in_flight``) and
+waits for them before a third goes out, in the drain's flush, when the
+scheduler fails and when it closes.  Booked on
+``pt_decode_prefill_unawaited_total``.
+
 Eviction under pool pressure: when a page allocation fails (of any
 cache kind), the YOUNGEST other live sequence is evicted — its pages of
 every kind return to the pool,
@@ -179,6 +193,16 @@ def _m_prefill_head_runs():
         labels=("engine",))
 
 
+def _m_prefill_unawaited():
+    from paddle_tpu import observability as obs
+
+    return obs.counter(
+        "pt_decode_prefill_unawaited_total",
+        "Prefill chunk executions enqueued and not waited for: nobody "
+        "reads their token (every chunk but a fresh prompt's final one), "
+        "so the turn goes on while they run", labels=("engine",))
+
+
 def _m_encoder_runs():
     from paddle_tpu import observability as obs
 
@@ -214,8 +238,9 @@ def _m_turn_seconds():
     return obs.counter(
         "pt_decode_turn_seconds_total",
         "Wall seconds of scheduler turns by part: prefill_run and "
-        "decode_run are the two blocking executor runs (the *.run "
-        "spans), sched is the rest of the turn — pages, feed building, "
+        "decode_run are the host's time in the two executor runs (the "
+        "blocking *.run spans; of a chunk that is not waited for, its "
+        "enqueue), sched is the rest of the turn — pages, feed building, "
         "admission, token emission", labels=("engine", "part"))
 
 
@@ -609,10 +634,13 @@ class DecodeEngine:
         # step would run two Executor.run calls over the same scope's
         # DONATED pool buffers — use-after-donate / silent corruption
         self._exec_lock = threading.Lock()
+        # outputs of the chunks enqueued and not waited for, oldest first
+        # (never more than two); the scheduler thread's alone
+        self._in_flight = collections.deque()
         self._next_seq = 0
         self._steps = 0
         self._turns = 0
-        # seconds inside the *.run spans of the turn under way
+        # seconds inside the executor's runs of the turn under way
         self._turn_run_s = 0.0
         self._tokens = 0
         self._evictions = 0
@@ -642,6 +670,7 @@ class DecodeEngine:
             for k in ("image", "text")}
         self._chunks = _m_prefill_chunks().labels(engine=e)
         self._head_runs = _m_prefill_head_runs().labels(engine=e)
+        self._unawaited = _m_prefill_unawaited().labels(engine=e)
         self._occupancy = _m_slot_occupancy().labels(engine=e)
         self._pages_gauge = _m_pages_in_use().labels(engine=e)
         self._evict_ctr = _m_evictions().labels(engine=e)
@@ -935,7 +964,9 @@ class DecodeEngine:
         prefill-pending future typed (their pool pages return), and let
         the sequences already IN decode slots run to completion instead
         of dying mid-batch.  Blocks up to `timeout` seconds for the
-        in-flight work to finish (None = return immediately).  The
+        in-flight work to finish (None = return immediately): the
+        sequences in decode slots, and a prefill chunk enqueued and not
+        waited for (the flush waits for it first).  The
         scheduler thread stays alive — the process snapshots/LEAVEs
         before the DrainHandler re-delivers the signal — and the flush
         itself runs ON the scheduler thread, preserving its single-
@@ -958,6 +989,9 @@ class DecodeEngine:
             self._cv.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=30)
+        if self._thread is None or not self._thread.is_alive():
+            # a running scheduler settles its chunks as it leaves
+            self._settle_chunks()
         leftovers = []
         with self._cv:
             leftovers.extend(self._queue)
@@ -1005,8 +1039,10 @@ class DecodeEngine:
                         self._drained.set()
                     # deliberately unbounded: close()/submit() notify
                     self._cv.wait()  # resilience: allow
-                if self._closed:
-                    return
+                closed = self._closed
+            if closed:
+                self._settle_chunks()  # closed means nothing in flight
+                return
             try:
                 self._step_once()
             except BaseException as e:  # resilience: allow — fanned out
@@ -1022,6 +1058,7 @@ class DecodeEngine:
         futures typed, free any pool pages a mid-prefill victim held,
         and leave slot-admitted sequences running.  Runs on the
         scheduler thread — the only owner of pool/slot state."""
+        self._await_chunks()  # drained means nothing in flight
         with self._cv:
             victims = list(self._queue) + list(self._ready)
             self._queue.clear()
@@ -1046,6 +1083,7 @@ class DecodeEngine:
             self._drained.set()
 
     def _fail_all(self, exc):
+        self._settle_chunks()
         with self._cv:
             self._failed = exc  # latch: submit() rejects typed from now on
             reqs = list(self._queue) + list(self._ready) + [
@@ -1204,8 +1242,9 @@ class DecodeEngine:
             row_idx = self._stage_image_rows(req, ctx_len, valid)
             if row_idx is None:
                 return  # the chunk needs one more image: the next turn's
-        # the one chunk whose next token is read: a fresh prompt's last
-        # (a resumed request replays tokens it already has)
+        # the one chunk whose next token is read, and so the one that is
+        # waited for: a fresh prompt's last (a resumed request replays
+        # tokens it already has)
         seeds = ctx_len + valid == total and not req.generated
         next_tok = self._run_prefill_feed(
             tokens=tokens[ctx_len:ctx_len + valid], pos0=ctx_len,
@@ -1414,24 +1453,63 @@ class DecodeEngine:
 
     def _run_prefill_feed(self, tokens, pos0, seq_id, write_pages,
                           valid, final, warm=False, row_idx=None):
-        """One chunk through the prefill executable; its next token
-        where ``final`` (the head ran), else 0."""
+        """One chunk through the prefill executable.  Where ``final``
+        (the head ran: somebody reads the token) the run is waited for
+        and its next token returned.  Any other chunk is enqueued and the
+        caller goes on at once, None returned: what follows it on the
+        device is ordered behind it."""
         with _profiling.span("prefill.feed_build", "decode"):
             feed = self._prefill_feed(tokens, pos0, seq_id, write_pages,
                                       valid, final, row_idx)
-        with self._exec_lock:
-            with _profiling.span("prefill.run", "decode") as run:
+        if final:
+            with self._exec_lock:
+                with _profiling.span("prefill.run", "decode") as run:
+                    (out,) = self._exe.run(self._pf_prog, feed=feed,
+                                           fetch_list=[self._pf_fetch],
+                                           scope=self.scope)
+            seconds = run.seconds
+            token = int(np.asarray(out).reshape(-1)[0])
+        else:
+            # no `*.run` span: that name says the run ends in a blocking
+            # fetch.  The host's time here is the enqueue and, in a turn
+            # with no blocking step, the wait that holds it to two chunks
+            t0 = time.perf_counter()  # observability: allow
+            self._await_chunks(keep=1)
+            with self._exec_lock:
                 (out,) = self._exe.run(self._pf_prog, feed=feed,
                                        fetch_list=[self._pf_fetch],
-                                       scope=self.scope)
+                                       scope=self.scope, return_numpy=False)
+            self._in_flight.append(out)
+            seconds = time.perf_counter() - t0  # observability: allow
+            token = None
         if not warm:
-            self._phase["prefill"].inc(run.seconds)
-            self._turn_part["prefill_run"].inc(run.seconds)
-            self._turn_run_s += run.seconds
+            self._phase["prefill"].inc(seconds)
+            self._turn_part["prefill_run"].inc(seconds)
+            self._turn_run_s += seconds
             self._chunks.inc()
             if final:
                 self._head_runs.inc()
-        return int(np.asarray(out).reshape(-1)[0])
+                self._await_chunks()  # finished before this one: no wait
+            else:
+                self._unawaited.inc()
+        return token
+
+    def _await_chunks(self, keep=0):
+        """Wait for the chunks enqueued and not waited for, all but the
+        newest ``keep``; a device error of one of them is raised here."""
+        import jax
+
+        while len(self._in_flight) > keep:
+            jax.block_until_ready(self._in_flight.popleft())
+
+    def _settle_chunks(self):
+        """`_await_chunks` where the engine is failing or closing
+        already: nothing is left in flight, and no error is raised."""
+        while self._in_flight:
+            try:
+                self._await_chunks()
+            except Exception:
+                continue  # the engine is going down on an error of its own
 
     # -- decode -------------------------------------------------------------
 
@@ -1544,6 +1622,7 @@ class DecodeEngine:
             self._turn_part["decode_run"].inc(run.seconds)
             self._turn_run_s += run.seconds
             self._steps += 1
+            self._await_chunks()  # finished before the step: no wait
         return np.asarray(out).reshape(-1)
 
     def _install_device_counters(self):

@@ -627,3 +627,251 @@ def test_decode_drain_on_sigterm_opt_out(monkeypatch):
         eng.submit([1, 2], 2)  # admission unaffected
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# which prefill chunks are waited for (ISSUE 50): a chunk whose token
+# nobody reads is enqueued, and the turn goes on while it runs.  Untrained
+# weights, in-process, tiny: what is checked is the scheduler, not the model
+# ---------------------------------------------------------------------------
+
+S_NAME, S_ID, S_PARENT = 0, 4, 5
+
+
+def _overlap_engine(name, auto_start=False, **kw):
+    cfg = gpt.GPTConfig.tiny(**CFG)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        gpt.build_gpt_lm(cfg)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        fluid.Executor(fluid.CPUPlace()).run(startup)
+    kw = dict(dict(pool_slots=2, page_size=4, prefill_chunk=4, max_len=32),
+              **kw)
+    eng = serving.DecodeEngine(cfg, scope=scope, name=name,
+                               auto_start=False, **kw)
+    eng.warmup()
+    if auto_start:
+        eng.start()
+    return eng
+
+
+def _counts(name):
+    from paddle_tpu import observability as obs
+
+    snap = obs.snapshot()
+    return {k: snap.get(f"pt_decode_prefill_{k}_total", {}).get(
+        "samples", {}).get((name,), 0)
+        for k in ("chunks", "unawaited", "head_runs")}
+
+
+def _drive(eng, *futures, turns=400):
+    for _ in range(turns):
+        if all(f.done() for f in futures):
+            return
+        eng._step_once()
+    raise AssertionError("the requests did not finish")
+
+
+class _Handle:
+    """A chunk's output as the engine keeps it, with what became of it."""
+
+    def __init__(self, out, fail=None, delay=0.0):
+        self.out, self.fail, self.delay = out, fail, delay
+        self.awaited = False
+
+    def block_until_ready(self):
+        import time
+
+        import jax
+
+        time.sleep(self.delay)
+        jax.block_until_ready(self.out)
+        self.awaited = True
+        if self.fail is not None:
+            raise self.fail
+        return self
+
+
+def _wrap_unawaited(monkeypatch, eng, make):
+    """Every run the engine does not wait for hands back ``make(out, k)``
+    (k counts such runs from 0) in place of its one output; returns the
+    list that holds them as they are made."""
+    run, made = eng._exe.run, []
+
+    def wrapped(*a, **kw):
+        outs = run(*a, **kw)
+        if kw.get("return_numpy", True):
+            return outs
+        made.append(make(outs[0], len(made)))
+        return [made[-1]]
+
+    monkeypatch.setattr(eng._exe, "run", wrapped)
+    return made
+
+
+@pytest.mark.parametrize("prompt_len, prefix, n_chunks, awaited", [
+    (6, None, 2, 1),      # a fresh prompt: its last chunk seeds the stream
+    (15, None, 4, 1),
+    # a resumed request replays prompt + prefix[:-1] = 9 tokens and reads
+    # no chunk's token: none of its chunks is waited for
+    (7, [9, 8, 7], 3, 0),
+])
+def test_prefill_waits_only_for_the_chunk_whose_token_is_read(
+        prompt_len, prefix, n_chunks, awaited):
+    from paddle_tpu.observability import profiling
+
+    eng = _overlap_engine(f"unawaited-{prompt_len}")
+    try:
+        before = _counts(eng.name)
+        profiling.reset()
+        req = eng.submit_request(list(range(1, prompt_len + 1)), 6,
+                                 prefix=prefix)
+        _drive(eng, req.future)
+        assert len(req.future.result()) == 6
+        got = {k: v - before[k] for k, v in _counts(eng.name).items()}
+        assert got == {"chunks": n_chunks, "unawaited": n_chunks - awaited,
+                       "head_runs": awaited}
+        spans = profiling.spans()
+        runs = {s[S_ID] for s in spans if s[S_NAME] == "prefill.run"}
+        assert len(runs) == awaited
+        waits = [s for s in spans if s[S_NAME] == "fetch_wait"
+                 and s[S_PARENT] in runs]
+        assert len(waits) == awaited
+        # the other chunks' executor spans lie directly under their turn
+        turns = {s[S_ID] for s in spans if s[S_NAME] == "turn"}
+        enqueued = [s for s in spans if s[S_NAME] == "dispatch"
+                    and s[S_PARENT] in turns]
+        assert len(enqueued) == n_chunks - awaited
+        # every fetch_wait of the window belongs to a `*.run` span
+        run_ids = {s[S_ID] for s in spans if s[S_NAME].endswith(".run")}
+        assert all(s[S_PARENT] in run_ids for s in spans
+                   if s[S_NAME] == "fetch_wait")
+        assert not eng._in_flight  # the steps that followed settled them
+    finally:
+        eng.close()
+
+
+def test_overlapped_chunks_serve_the_tokens_of_blocking_chunks(monkeypatch):
+    """Multi-chunk prompts interleaved with live decode rows on a pool too
+    small for them, so sequences are evicted while a chunk is in flight:
+    token for token what the same engine serves when every chunk blocks."""
+    eng = _overlap_engine("overlap-parity", pool_slots=3, num_pages=13,
+                          max_len=28)
+    rng = np.random.RandomState(50)
+    prompts = [list(rng.randint(1, 50, size=n)) for n in
+               (5, 14, 9, 17, 3, 11)]
+    try:
+        in_flight_at_eviction = []
+        evict = eng._evict_one
+
+        def evicting(protect):
+            in_flight_at_eviction.append(len(eng._in_flight))
+            return evict(protect)
+
+        monkeypatch.setattr(eng, "_evict_one", evicting)
+
+        def serve():
+            futs = []
+            for p in prompts:  # arrivals spread over the turns
+                futs.append(eng.submit(p, 10))
+                eng._step_once()
+                eng._step_once()
+            _drive(eng, *futs)
+            return [f.result() for f in futs]
+
+        before = _counts(eng.name)
+        overlapped = serve()
+        assert _counts(eng.name)["unawaited"] > before["unawaited"]
+        assert eng.stats()["evictions"] > 0
+        assert max(in_flight_at_eviction) >= 1
+        # the same engine with every chunk's run made a blocking one
+        run = eng._exe.run
+        monkeypatch.setattr(
+            eng._exe, "run",
+            lambda *a, **kw: run(*a, **dict(kw, return_numpy=True)))
+        blocking = serve()
+        assert overlapped == blocking
+        assert all(len(t) == 10 for t in overlapped)
+    finally:
+        eng.close()
+
+
+def test_no_more_than_two_chunks_run_ahead_of_the_host(monkeypatch):
+    """A prompt of seven chunks and no live slot: no decode step blocks,
+    so the engine itself waits for the older chunk before a third is
+    enqueued."""
+    eng = _overlap_engine("two-ahead")
+    try:
+        made = _wrap_unawaited(monkeypatch, eng, lambda out, k: _Handle(out))
+        fut = eng.submit(list(range(1, 27)), 2)
+        ahead = []
+        for _ in range(6):  # the six chunks nobody reads
+            eng._step_once()
+            ahead.append(sum(not h.awaited for h in made))
+        assert ahead == [1, 2, 2, 2, 2, 2] and len(made) == 6
+        assert len(eng._in_flight) == 2
+        _drive(eng, fut)
+        assert all(h.awaited for h in made)
+        assert len(fut.result()) == 2
+    finally:
+        eng.close()
+
+
+def test_a_failed_unawaited_chunk_fails_the_live_futures(monkeypatch):
+    """The device error of a chunk nobody waited for is raised on the
+    scheduler thread at its next blocking call (here the decode step of
+    the same turn) and goes the way of any run error: every live future
+    fails with it, and the engine takes no more work."""
+    eng = _overlap_engine("chunk-fails")
+    try:
+        boom = RuntimeError("the chunk fell over on the device")
+        made = _wrap_unawaited(monkeypatch, eng, lambda out, k: _Handle(
+            out, fail=boom if k == 1 else None))
+        live = eng.submit([1, 2, 3], 25)  # one chunk, then decoding
+        eng._step_once()
+        long = eng.submit(list(range(1, 20)), 4)   # five chunks
+        queued = eng.submit([4, 5], 4)
+        eng.start()
+        for fut in (live, long, queued):
+            with pytest.raises(RuntimeError, match="fell over") as ei:
+                fut.result(timeout=60)
+            assert ei.value is boom
+        eng._thread.join(timeout=30)
+        assert not eng._thread.is_alive() and not eng._in_flight
+        assert "fell over" in eng.stats()["failed"]
+        assert len(made) == 2  # no chunk went out after the error
+        with pytest.raises(serving.ServingOverloadError,
+                           match="scheduler died"):
+            eng.submit([1, 2], 2)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("how", ["drain", "close"])
+def test_drain_and_close_return_after_the_chunk_in_flight(monkeypatch, how):
+    """`drain(timeout)` and `close()` mean nothing is in flight: both
+    return only once the chunk that nobody waited for has run."""
+    import time
+
+    eng = _overlap_engine(f"settle-{how}", auto_start=True)
+    try:
+        made = _wrap_unawaited(monkeypatch, eng,
+                               lambda out, k: _Handle(out, delay=0.3))
+        fut = eng.submit(list(range(1, 27)), 2)  # seven chunks
+        deadline = time.monotonic() + 60
+        while not made and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert made, "no chunk was enqueued"
+        if how == "drain":
+            assert eng.drain(timeout=60) is True
+            assert eng.pool.pages_in_use() == 0  # the victim's came back
+        else:
+            eng.close()
+        assert all(h.awaited for h in made)
+        assert not eng._in_flight
+        with pytest.raises(serving.ServingOverloadError) as ei:
+            fut.result(timeout=10)
+        assert ei.value.reason == {"drain": "draining", "close": "closed"}[how]
+    finally:
+        eng.close()

@@ -646,6 +646,11 @@ class BlockPlan:
         self.host_fetch_names = [n for n in self.fetch_names if n in host_out]
         self.jit_fetch_names = [n for n in self.fetch_names
                                 if n not in host_out]
+        # a fetch some device op computed: reading it on the host waits
+        # for the program (one that is a plain scope read or a feed may
+        # be handed back without it)
+        self.fetch_proves_done = any(n in jit_produced
+                                     for n in self.jit_fetch_names)
         bad_fetch = [n for n in self.fetch_names
                      if n not in produced and n not in host_out]
         # a fetch no op produces but that LIVES in the scope is a plain
@@ -952,7 +957,10 @@ class _JitExecutable:
     (`_CompiledBlock` per-step, `_CompiledChain` n-steps-per-call):
     abstract arg specs for AOT lowering, XLA cost/memory analysis, and
     the FLAGS_check_nan_inf scan.  Subclasses provide `plan`, `label`,
-    `_jitted`, `donated_names`, `readonly_names`."""
+    `_jitted`, `donated_names`, `readonly_names`, `jit_name` (the module
+    name a device trace shows) and, once run, `ordinal` (the in-flight
+    ledger's number of the program the last run enqueued:
+    observability/profiling.py)."""
 
     def _jit_args(self, scope, feeds, step, shardings=(None, None)):
         """The (donated, readonly, feeds, step) pytrees run() passes to the
@@ -1059,6 +1067,7 @@ class _CompiledBlock(_JitExecutable):
                                donate_argnums=(0,))
         self.place = place
         self.label = f"program@{id(program):x}/v{program._version}"
+        self.jit_name = "jit_" + plan.name
         self._prof_state = {"ran": False}
         self._kept = kept  # the executor's, see _stage_scope_reads
         # AOT-loaded/compiled executable (fluid/aot_cache.py) — when
@@ -1123,27 +1132,35 @@ class _CompiledBlock(_JitExecutable):
                     self.plan.run_host_pre_ops(scope, feeds, self.place)
                     donated, readonly, feed_vals = _stage_args(
                         "single", self, scope, feeds)
-                with ph.phase("dispatch"):
+                with ph.phase("dispatch") as dispatch:
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU backend
                         fetches, out_writes = (self._aot or self._jitted)(
                             donated, readonly, feed_vals, np.uint32(step)
                         )
-                with ph.phase("device_wait"):
+                    # the in-flight ledger: which program this span enqueued
+                    self.ordinal = k = _profiling.enqueued(self.jit_name)
+                    dispatch.note = f"{self.jit_name}#{k}"
+                with ph.phase("device_wait") as waited:
                     ph.wait((fetches, out_writes))
+                    if ph.blocked:
+                        waited.note = _profiling.done(k)
                 with ph.phase("fetch_sync"):
                     _write_back(self._kept, scope, out_writes)
                     # block on scope writes too — a run with an empty
                     # fetch_list (or a startup run) would otherwise
                     # record async-dispatch time only
                     timer.done(fetches, out_writes)
-            with ph.phase("fetch_sync"):
+            with ph.phase("fetch_sync") as tail:
                 from . import flags as _flags
 
                 if _flags.flag("benchmark"):
                     # force completion each step (reference operator.cc:949
                     # forces a dev_ctx->Wait() per op under FLAGS_benchmark)
                     jax.block_until_ready((fetches, out_writes))
+                    tail.note = _profiling.done(k)
+                elif timer.enabled:  # timed_run blocked as it closed
+                    tail.note = _profiling.done(k)
                 if _flags.flag("check_nan_inf"):
                     self._check_nan_inf(out_writes, fetches)
                 # RPC/IO ops run host-side after the device step, in
@@ -1259,6 +1276,7 @@ class _CompiledChain(_JitExecutable):
         self._jitted = jax.jit(chained, donate_argnums=(0,))
         self.label = (f"program@{id(program):x}/v{program._version}"
                       f"/chain{n}")
+        self.jit_name = "jit_" + CHAIN_NAME
         self._prof_state = {"ran": False}
         self._kept = kept  # the executor's, see _stage_scope_reads
 
@@ -1275,17 +1293,22 @@ class _CompiledChain(_JitExecutable):
                 with ph.phase("feed_prep"):
                     donated, readonly, feed_vals = _stage_args(
                         "chain", self, scope, feeds)
-                with ph.phase("dispatch"):
+                with ph.phase("dispatch") as dispatch:
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU
                         fetches, out_writes = self._jitted(
                             donated, readonly, feed_vals, np.uint32(step))
-                with ph.phase("device_wait"):
+                    # the in-flight ledger: which program this span enqueued
+                    self.ordinal = k = _profiling.enqueued(self.jit_name)
+                    dispatch.note = f"{self.jit_name}#{k}"
+                with ph.phase("device_wait") as waited:
                     ph.wait((fetches, out_writes))
+                    if ph.blocked:
+                        waited.note = _profiling.done(k)
                 with ph.phase("fetch_sync"):
                     _write_back(self._kept, scope, out_writes)
                     timer.done(fetches, out_writes)
-            with ph.phase("fetch_sync"):
+            with ph.phase("fetch_sync") as tail:
                 # the host tail rides the trailing fetch_sync bracket
                 # like every other lane — a large stacked fetch list's
                 # host conversion must not vanish from the phase sum
@@ -1293,6 +1316,9 @@ class _CompiledChain(_JitExecutable):
 
                 if _flags.flag("benchmark"):
                     jax.block_until_ready((fetches, out_writes))
+                    tail.note = _profiling.done(k)
+                elif timer.enabled:  # timed_run blocked as it closed
+                    tail.note = _profiling.done(k)
                 if _flags.flag("check_nan_inf"):
                     # chain granularity: a NaN born mid-chain propagates
                     # through the remaining iterations (params/opt-state
@@ -1319,6 +1345,11 @@ class Executor:
         self._cache: dict = {}
         self._kept = _Kept()  # what its executables staged last
         self._step = 0
+        # the in-flight ledger's ordinal of the program the last run()
+        # / run_steps() enqueued (observability/profiling.py): a caller
+        # that takes `return_numpy=False` and waits on the outputs
+        # itself marks `profiling.done(ordinal)` there
+        self.ordinal = 0
         self._sentinels: dict = {}  # id(program) -> HealthSentinel|None
         # opt-in /metricsz endpoint (FLAGS_metrics_port): every process
         # that runs programs — trainer, pserver, benchmark runner — exposes
@@ -1585,10 +1616,14 @@ class Executor:
                 if aot is not None:
                     cb._obs_ran = True  # first run has no lazy compile
                 fetches = run_guarded(sent, scope, fetch_names, attempt)
+        self.ordinal = cb.ordinal
         if return_numpy:
             # where a lane that fetches really waits for the device
-            with _profiling.span("fetch_wait", "single", number=step):
-                return [np.asarray(f) for f in fetches]
+            with _profiling.span("fetch_wait", "single", number=step) as sp:
+                out = [np.asarray(f) for f in fetches]
+                if cb.plan.fetch_proves_done:
+                    sp.note = _profiling.done(cb.ordinal)
+                return out
         return fetches
 
     def run_steps(
@@ -1695,9 +1730,13 @@ class Executor:
                                             phase="trace").inc(trace_s)
                 fetches = run_guarded(sent, scope, fetch_names, attempt,
                                       chain=int(n_steps) > 1)
+        self.ordinal = cc.ordinal
         if return_numpy:
-            with _profiling.span("fetch_wait", "chain", number=step):
-                return [np.asarray(f) for f in fetches]
+            with _profiling.span("fetch_wait", "chain", number=step) as sp:
+                out = [np.asarray(f) for f in fetches]
+                if cc.plan.fetch_proves_done:
+                    sp.note = _profiling.done(cc.ordinal)
+                return out
         return fetches
 
     # ------------------------------------------------------------------

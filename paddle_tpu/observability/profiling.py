@@ -39,6 +39,20 @@ pairs anywhere else):
                  (benchmark/readers/host_gap.py lays it on the device
                  trace's).
 
+- in-flight      what the program KNOWS of the device without a trace:
+  ledger         `enqueued(program)` numbers every program a lane hands
+                 to the device (the ``dispatch`` span's note,
+                 ``jit_<name>#<ordinal>``), `done(ordinal)` says a host
+                 wait returned on that program's outputs (the waiting
+                 span's note, ``done#<ordinal>``: the executor's
+                 ``fetch_wait``, the decode scheduler's
+                 ``prefill.await``).  From the wait that proved the
+                 newest program finished until the next `enqueued` the
+                 chip stands empty, and those seconds are booked on
+                 ``pt_device_starved_seconds_total{under}`` under the
+                 innermost span open meanwhile (``any`` for all of
+                 them).
+
 - runtime hooks  what the Python runtime and jax do INSIDE a span, and
                  report through hooks of their own
                  (`install_runtime_hooks`, on like the spans are): XLA's
@@ -109,7 +123,8 @@ from . import tracing as _tracing
 
 __all__ = [
     "step_phases", "NullRecorder", "span", "child_span", "spans",
-    "span_clock", "install_runtime_hooks", "RING_MIN_NS",
+    "span_clock", "enqueued", "done", "install_runtime_hooks",
+    "RING_MIN_NS",
     "set_span_export", "SPAN_RING", "note_step", "note_cost",
     "note_collectives",
     "note_health_event", "device_peaks", "roofline",
@@ -285,15 +300,23 @@ class _PhaseSpan:
 
             self._ann = jax.profiler.TraceAnnotation(self.name)
             self._ann.__enter__()
-        self.t0 = time.perf_counter_ns()
+        self.t0 = t0 = time.perf_counter_ns()
+        empty = _ledger.empty
+        if empty is not None and empty.stack is stack:
+            # the stretch up to here was the parent's own time
+            empty.split(stack[-2].name if len(stack) > 1 else "none", t0)
         return self
 
     def __exit__(self, et, ev, tb):
         self.t1 = t1 = time.perf_counter_ns()
+        stack = _tls.stack
+        empty = _ledger.empty
+        if empty is not None and empty.stack is stack:
+            empty.split(self.name, t1)
         if self._ann is not None:
             self._ann.__exit__(et, ev, tb)
-        if _tls.stack:  # empty only after a reset() under an open span
-            _tls.stack.pop()
+        if stack:  # empty only after a reset() under an open span
+            stack.pop()
         _ring.append((self.name, self.lane, self.t0, t1, self.id,
                       self.parent, self.number, self.note))
         if self._rec is not None:
@@ -345,6 +368,122 @@ def child_span(name, lane, t0_ns, t1_ns, note=None):
     if _export[0]:
         _chrome_record(name, lane, t0_ns, t1_ns)
     return sid
+
+
+# ---------------------------------------------------------------------------
+# the in-flight ledger: what the program knows of the device without a trace
+# ---------------------------------------------------------------------------
+
+
+class _Empty:
+    """One stretch in which the chip is known to stand empty.
+    ``mark_ns``: the `time.perf_counter_ns` up to which it is split into
+    ``under``, starting at the instant the wait returned.  ``under``:
+    {span name: ns} of the stretch so far, added to the counter when it
+    ends.  ``stack``: the span stack of the thread whose wait began it,
+    the one thread whose spans split it, so `split` takes no lock."""
+
+    __slots__ = ("mark_ns", "under", "stack")
+
+    def __init__(self, mark_ns, stack):
+        self.mark_ns, self.under, self.stack = mark_ns, {}, stack
+
+    def split(self, under, now_ns):
+        """A span of ``stack``'s thread opened or closed at ``now_ns``:
+        the stretch since the last mark was spent under ``under``.  (The
+        mark moves before the dict grows: an `enqueued` on ANOTHER
+        thread, which copies the dict and then reads the mark, can so
+        lose this piece and never count it twice.)"""
+        mark, self.mark_ns = self.mark_ns, now_ns
+        parts = self.under
+        parts[under] = parts.get(under, 0) + now_ns - mark
+
+
+class _Ledger:
+    """``enqueued`` / ``done``: the ordinal of the newest program handed to
+    the device and of the newest one a host wait proved finished.
+    ``empty``: while the two are equal, the `_Empty` stretch that began
+    when that became true; None otherwise."""
+
+    __slots__ = ("enqueued", "done", "empty")
+
+    def __init__(self):
+        self.enqueued = self.done = 0
+        self.empty = None
+
+
+_ledger = _Ledger()
+_ledger_lock = threading.Lock()
+
+
+def enqueued(program):
+    """A lane just handed ``program`` (the ``jit_*`` name a device trace
+    shows) to the device: returns its ordinal, a process-wide count from
+    1 of the programs every executor lane dispatches.  Called right
+    after the jitted call returns, inside the ``dispatch`` span, whose
+    note the lanes `single` and `chain` set to ``<program>#<ordinal>``.
+    Where the ledger was empty, the known-empty stretch ends here: its
+    rest goes under the innermost span open on the calling thread, and
+    the whole of it onto ``pt_device_starved_seconds_total``.
+
+    The ordinal is taken AFTER the jitted call, under no lock that spans
+    both, so ordinal order is dispatch order only where ONE thread (or
+    one caller's lock, as the decode engine's) dispatches to ONE device
+    (or one mesh) in the process.  Two dispatching threads, or executors
+    on different devices, can number their programs out of order:
+    `done` then calls the chip empty while an earlier-numbered program
+    runs, and the counter reads too HIGH."""
+    led = _ledger
+    with _ledger_lock:
+        led.enqueued = k = led.enqueued + 1
+        empty, led.empty = led.empty, None
+    if empty is not None:
+        parts = dict(empty.under)  # before the mark: see `_Empty.split`
+        stack = getattr(_tls, "stack", None)
+        name = stack[-1].name if stack else "none"
+        # a negative rest: two threads' clock readings out of order
+        parts[name] = parts.get(name, 0) + max(
+            time.perf_counter_ns() - empty.mark_ns, 0)
+        series = _starved_series(parts)
+        for name, ns in parts.items():
+            series[name].inc(ns / 1e9)
+        series["any"].inc(sum(parts.values()) / 1e9)
+    return k
+
+
+def done(ordinal):
+    """A host wait just returned on the outputs of program ``ordinal``.
+    ONE CHIP RUNS ITS PROGRAMS IN DISPATCH ORDER, so every program up to
+    that ordinal has finished too: the whole record rests on that
+    assumption (a lane over several chips dispatches one program to all
+    of them, and it holds for each), and on ordinal order BEING dispatch
+    order, which holds for one dispatching thread or lock and one device
+    or mesh a process (see `enqueued`).  Where it is the newest enqueued,
+    the chip stands empty from this instant until the next `enqueued`:
+    ``pt_device_starved_seconds_total`` counts from here.  A wait on an
+    older program than one already proved done changes nothing.  Returns
+    the note of the span that waited, ``done#<ordinal>``."""
+    led = _ledger
+    with _ledger_lock:
+        if ordinal > led.done:
+            led.done = ordinal
+            if ordinal == led.enqueued:
+                stack = getattr(_tls, "stack", None)
+                if stack is None:
+                    stack = _tls.stack = []
+                led.empty = _Empty(time.perf_counter_ns(), stack)
+    return f"done#{ordinal}"
+
+
+def _starved_series(names):
+    """{under: series} of ``pt_device_starved_seconds_total`` holding at
+    least ``names`` and ``any``."""
+    runtime = _runtime_series()
+    series = runtime["starved"]
+    for name in names:
+        if name not in series:
+            series[name] = runtime["starved_family"].labels(under=name)
+    return series
 
 
 class _NullSpan:
@@ -482,6 +621,9 @@ _XLA_STAGES = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
 }
 _XLA_UNDER = ("compile", "dispatch", "other", "none")
+# the spans of the executor's own lanes; a scheduler's appear as they book
+_STARVED_UNDER = ("any", "none", "lookup", "compile", "feed_prep",
+                  "dispatch", "device_wait", "fetch_sync", "fetch_wait")
 
 _xla_listening = [False]  # jax keeps no public list of its listeners
 _hooks_lock = threading.Lock()
@@ -490,9 +632,9 @@ _runtime = [None, -1]  # the bound series, the registry epoch they are of
 
 
 def _runtime_series():
-    """The hooks' counter series, every one created at 0 (a window in
-    which nothing was collected or compiled reads 0, not nothing) and
-    bound again after a registry reset."""
+    """The hooks' and the ledger's counter series, every one created at 0
+    (a window in which nothing was collected, compiled or starved reads
+    0, not nothing) and bound again after a registry reset."""
     reg = _metrics.REGISTRY
     if _runtime[1] == reg._epoch and _runtime[0] is not None:
         return _runtime[0]
@@ -511,10 +653,21 @@ def _runtime_series():
         "under = compile or dispatch where a span of that name was open "
         "on the thread (compile first), other under another span, none "
         "under none", labels=("stage", "under"))
+    starved_s = _metrics.counter(
+        "pt_device_starved_seconds_total",
+        "Seconds the device is KNOWN to have stood empty: from the host "
+        "wait that proved the newest enqueued program finished "
+        "(profiling.done) until the next program was enqueued "
+        "(profiling.enqueued), by the innermost span open meanwhile on "
+        "the thread that waited (none where no span was) and under=any "
+        "for all of it.  Idle time while work is believed in flight is "
+        "not in it", labels=("under",))
     series = {
         "gc": {g: gc_s.labels(generation=g) for g in _GC_GENERATIONS},
         "xla": {(st, u): xla_s.labels(stage=st, under=u)
                 for st in _XLA_STAGES.values() for u in _XLA_UNDER},
+        "starved": {u: starved_s.labels(under=u) for u in _STARVED_UNDER},
+        "starved_family": starved_s,
     }
     _runtime[0], _runtime[1] = series, reg._epoch
     return series
@@ -1255,8 +1408,11 @@ def ensure_profilez_page():
 
 def reset():
     """Drop all attribution state (tests).  The runtime hooks stay
-    installed."""
+    installed; the ledger keeps its ordinals (a program enqueued before
+    may be waited for after) and forgets that the chip stood empty."""
     global _flight
+    with _ledger_lock:
+        _ledger.empty = None
     with _lock:
         _signatures.clear()
         _lane_ema.clear()

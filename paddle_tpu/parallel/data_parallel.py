@@ -848,6 +848,9 @@ class _ShardedBlock(_JitExecutable):
                         fetches, out_writes = self._jitted(
                             donated, readonly, dict(feeds),
                             np.uint32(step))
+                    # counted in the in-flight ledger and never marked done:
+                    # the chip is not known empty while this lane runs
+                    _profiling.enqueued(self.label)
                 with ph.phase("device_wait"):
                     ph.wait((fetches, out_writes))
                 with ph.phase("fetch_sync"):

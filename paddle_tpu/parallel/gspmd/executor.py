@@ -413,6 +413,9 @@ class _GSPMDBlock(_JitExecutable):
                     with warnings.catch_warnings():
                         warnings.simplefilter("ignore")  # donation unsupported on CPU
                         fetches, out_writes = self._jitted(*args)
+                    # counted in the in-flight ledger and never marked done:
+                    # the chip is not known empty while this lane runs
+                    _profiling.enqueued(self.label)
                 with ph.phase("device_wait"):
                     ph.wait((fetches, out_writes))
                 with ph.phase("fetch_sync"):

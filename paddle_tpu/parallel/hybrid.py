@@ -906,6 +906,9 @@ class HybridParallelRunner:
                             fetches, out_writes = jitted(
                                 don_vals, ro_vals, dict(feeds),
                                 np.uint32(step))
+                        # counted in the in-flight ledger and never marked done:
+                        # the chip is not known empty while this lane runs
+                        _profiling.enqueued("hybrid_block")
                     with ph.phase("device_wait"):
                         ph.wait((fetches, out_writes))
                     with ph.phase("fetch_sync"):

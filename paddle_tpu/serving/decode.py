@@ -100,10 +100,19 @@ and no ``prefill.run`` span, so the executor's ``lookup`` .. ``fetch_sync``
 lie directly under the turn, and admission, the step's feed and its
 dispatch happen while the chunk runs (the device runs programs in
 dispatch order and the pool's buffers chain through the scope).  The
-engine keeps the outputs of at most two such chunks (``_in_flight``) and
-waits for them before a third goes out, in the drain's flush, when the
-scheduler fails and when it closes.  Booked on
-``pt_decode_prefill_unawaited_total``.
+engine keeps the outputs of at most two such chunks (``_in_flight``, each
+with its ordinal in the in-flight ledger) and waits for them before a
+third goes out, in the drain's flush, when the scheduler fails and when
+it closes: each such wait is a ``prefill.await`` span with the note
+``done#<ordinal>``.  Booked on ``pt_decode_prefill_unawaited_total``.
+
+What the device holds (observability/profiling.py "in-flight ledger").
+Every run's ``dispatch`` span carries ``jit_prefill_chunk#<ordinal>`` /
+``jit_decode_step#<ordinal>``, and every wait marks what it proved
+finished: the two ``*.run`` spans' ``fetch_wait`` (the executor's) and
+``prefill.await`` (the scheduler's own).  From the wait on the newest
+program until the next dispatch the chip stands empty, and the turn's
+spans book those seconds on ``pt_device_starved_seconds_total{under}``.
 
 Eviction under pool pressure: when a page allocation fails (of any
 cache kind), the YOUNGEST other live sequence is evicted — its pages of
@@ -634,8 +643,9 @@ class DecodeEngine:
         # step would run two Executor.run calls over the same scope's
         # DONATED pool buffers — use-after-donate / silent corruption
         self._exec_lock = threading.Lock()
-        # outputs of the chunks enqueued and not waited for, oldest first
-        # (never more than two); the scheduler thread's alone
+        # (in-flight ledger ordinal, outputs) of the chunks enqueued and
+        # not waited for, oldest first (never more than two); the
+        # scheduler thread's alone
         self._in_flight = collections.deque()
         self._next_seq = 0
         self._steps = 0
@@ -1473,13 +1483,15 @@ class DecodeEngine:
             # no `*.run` span: that name says the run ends in a blocking
             # fetch.  The host's time here is the enqueue and, in a turn
             # with no blocking step, the wait that holds it to two chunks
+            # (the clock pair stays: `prefill.await` is the wait alone,
+            # and the three counters below take wait + enqueue)
             t0 = time.perf_counter()  # observability: allow
             self._await_chunks(keep=1)
             with self._exec_lock:
                 (out,) = self._exe.run(self._pf_prog, feed=feed,
                                        fetch_list=[self._pf_fetch],
                                        scope=self.scope, return_numpy=False)
-            self._in_flight.append(out)
+                self._in_flight.append((self._exe.ordinal, out))
             seconds = time.perf_counter() - t0  # observability: allow
             token = None
         if not warm:
@@ -1496,11 +1508,17 @@ class DecodeEngine:
 
     def _await_chunks(self, keep=0):
         """Wait for the chunks enqueued and not waited for, all but the
-        newest ``keep``; a device error of one of them is raised here."""
+        newest ``keep``; a device error of one of them is raised here.
+        Each wait is a ``prefill.await`` span that marks its chunk done
+        in the in-flight ledger (a chunk the step behind it has already
+        proved finished costs the span and moves nothing)."""
         import jax
 
         while len(self._in_flight) > keep:
-            jax.block_until_ready(self._in_flight.popleft())
+            ordinal, out = self._in_flight.popleft()
+            with _profiling.span("prefill.await", "decode") as wait:
+                jax.block_until_ready(out)
+                wait.note = _profiling.done(ordinal)
 
     def _settle_chunks(self):
         """`_await_chunks` where the engine is failing or closing

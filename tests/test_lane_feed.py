@@ -330,7 +330,7 @@ def test_the_head_runs_where_pf_final_says_and_nothing_else_moves(model):
             # a chunk fed 0 is not waited for and hands back None (PR 50):
             # what its program returned is the output the engine keeps
             toks.append(tok if tok is not None else int(
-                np.asarray(eng._in_flight[-1]).reshape(-1)[0]))
+                np.asarray(eng._in_flight[-1][1]).reshape(-1)[0]))
             return tok
 
         eng._run_prefill_feed = ran

@@ -212,9 +212,18 @@ def test_a_span_costs_under_5_microseconds():
                 pass
         return (time.perf_counter_ns() - t0) / n
 
+    # both states of the in-flight ledger: with a program in flight a span
+    # pays it one comparison at each end; after this thread's wait proved
+    # the newest finished, every span splits a known-empty stretch
+    k = profiling.enqueued("jit_in_flight")
     batch(200)  # the histogram child exists now
-    best = min(batch() for _ in range(7))
-    assert best < 5000, f"a span cost {best:.0f} ns"
+    in_flight = min(batch() for _ in range(7))
+    profiling.done(k)
+    known_empty = min(batch() for _ in range(7))
+    assert profiling._ledger.empty.under["cost"] > 0  # it did split
+    assert in_flight < 5000, f"a span cost {in_flight:.0f} ns"
+    assert known_empty < 5000, f"a span cost {known_empty:.0f} ns"
+    profiling.reset()  # the stretch goes unbooked: no {under="cost"} series
 
 
 def test_spans_nest_per_thread_and_survive_an_exception():
